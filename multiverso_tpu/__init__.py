@@ -17,11 +17,13 @@ from typing import List, Optional
 from .runtime.net import PeerLostError
 from .runtime.zoo import (ClusterAborted, Zoo, current_zoo,
                           set_default_zoo, set_thread_zoo)
+from .sharding.mesh import describe_backend
 from .tables import (ArrayTableOption, KVTableOption, MatrixTableOption,
                      create_array_table, create_kv_table,
                      create_matrix_table, create_table)
 from .tables.table_interface import RpcTimeoutError, TableRequestError
 from .updater import AddOption, GetOption
+from .util import log
 from .util.configure import set_flag as _set_flag
 
 __version__ = "0.1.0"
@@ -29,6 +31,7 @@ __version__ = "0.1.0"
 
 def init(argv: Optional[List[str]] = None) -> List[str]:
     """MV_Init (ref: src/multiverso.cpp:11-14). Returns remaining argv."""
+    log.info("jax backend: %s", describe_backend())
     zoo = Zoo()
     set_default_zoo(zoo)
     return zoo.start(argv)
@@ -114,7 +117,7 @@ def net_bind(rank: int, endpoint: str) -> None:
 def init_distributed(coordinator_address=None, num_processes=None,
                      process_id=None, argv=None, control_port=None):
     """Multi-host bootstrap: jax.distributed (data plane) + the TCP
-    control mesh rendezvoused through its coordinator + init. See
+    control mesh rendezvoused by an all-gather over it + init. See
     runtime/bootstrap.py."""
     from .runtime.bootstrap import init_distributed as _impl
     return _impl(coordinator_address, num_processes, process_id,

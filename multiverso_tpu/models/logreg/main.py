@@ -16,7 +16,8 @@ import numpy as np
 
 from ... import init as mv_init, shutdown as mv_shutdown
 from ...io import StreamFactory
-from ...util import log
+from ...sharding.mesh import describe_backend
+from ...util import compile_cache, log
 from .config import Configure
 from .model import create_model
 from .reader import PrefetchReader, make_batches, iter_samples
@@ -28,7 +29,9 @@ class LogReg:
     def __init__(self, config_path: str):
         self.config = Configure.from_file(config_path)
         if self.config.use_ps:
-            mv_init([])
+            mv_init([])  # logs the backend
+        else:
+            log.info("jax backend: %s", describe_backend())
         self.model = create_model(self.config)
         if self.config.init_model_file:
             with StreamFactory.get_stream(self.config.init_model_file,
@@ -100,6 +103,7 @@ def main(argv=None) -> int:
         print("usage: python -m multiverso_tpu.models.logreg.main "
               "<config-file>", file=sys.stderr)
         return 2
+    compile_cache.enable()
     app = LogReg(argv[0])
     app.train()
     app.test()

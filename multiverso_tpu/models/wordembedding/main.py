@@ -19,7 +19,8 @@ import sys
 import time
 
 from ... import init as mv_init, shutdown as mv_shutdown
-from ...util import log
+from ...sharding.mesh import describe_backend
+from ...util import compile_cache, log
 from ...util.configure import (define_bool, define_double, define_int,
                                define_string, get_flag, parse_cmd_flags)
 from .data import BlockLoader, TokenizedCorpus, iter_pair_batches
@@ -98,9 +99,10 @@ def run(argv=None) -> Word2Vec:
              dictionary.total_count)
 
     if config.use_ps:
-        mv_init([])
+        mv_init([])  # logs the backend
         model: Word2Vec = PSWord2Vec(config, dictionary)
     else:
+        log.info("jax backend: %s", describe_backend())
         model = Word2Vec(config, dictionary)
 
     corpus = TokenizedCorpus.build(dictionary, train_file)
@@ -152,4 +154,5 @@ def run(argv=None) -> Word2Vec:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     run()

@@ -16,12 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.31 exports it at the top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental module is the API
-    from jax.experimental.shard_map import shard_map
 
 from ..sharding import mesh as meshlib
 

@@ -27,11 +27,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:  # jax >= 0.4.31 exports it at the top level
-    from jax import shard_map
-except ImportError:  # older jax: the experimental module is the API
-    from jax.experimental.shard_map import shard_map
 
 from ..runtime import thread_roles
 from ..runtime.zoo import current_zoo

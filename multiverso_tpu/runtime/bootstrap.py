@@ -8,10 +8,10 @@ On a TPU pod each host runs one process; two meshes must come up:
   barriers, table RPC), which needs every process's endpoint.
 
 The reference leaves placement to mpirun/machine files
-(ref: include/multiverso/net/zmq_net.h:20-28). Here the coordinator
-service jax.distributed already runs doubles as the rendezvous: each
-process publishes its control endpoint in the coordinator's key-value
-store and reads everyone else's — no machine file, no second launcher.
+(ref: include/multiverso/net/zmq_net.h:20-28). Here the data plane
+jax.distributed already brought up doubles as the rendezvous: the
+processes all-gather their control endpoints over it — no machine file,
+no second launcher.
 
     import multiverso_tpu as mv
     mv.init_distributed(coordinator_address="host0:9777",
@@ -35,7 +35,7 @@ from ..util import log
 from ..util.net_util import outbound_address, reserve_listen_port
 from .tcp import net_bind, net_connect
 
-_KEY_PREFIX = "multiverso_tpu/control_endpoint/"
+_ENDPOINT_BYTES = 256  # fixed-width slot per process in the allgather
 
 
 def _reachable_address() -> str:
@@ -50,51 +50,22 @@ def _reachable_address() -> str:
         return "127.0.0.1"
 
 
-def _coordinator_client():
-    """The process-level coordination-service client jax.distributed
-    keeps after initialize(); exposed only via the internal state object,
-    so probe defensively and fail with a clear message."""
-    try:
-        from jax._src.distributed import global_state
-        client = getattr(global_state, "client", None)
-    except Exception:  # noqa: BLE001 - jax internals moved
-        client = None
-    if client is None:
-        raise RuntimeError(
-            "jax.distributed has no coordination client; pass a "
-            "-machine_file or use net_bind/net_connect for the control "
-            "mesh instead")
-    return client
+def exchange_endpoints(my_endpoint: str) -> List[str]:
+    """All-gather of control endpoints over the data plane
+    jax.distributed just brought up: each process contributes its
+    ``host:port`` as a fixed-width byte row and
+    ``multihost_utils.process_allgather`` returns every row in process
+    order."""
+    import numpy as np
+    from jax.experimental import multihost_utils
 
-
-def exchange_endpoints(process_id: int, num_processes: int,
-                      my_endpoint: str,
-                      timeout_ms: int = 120_000) -> List[str]:
-    """All-gather of control endpoints through the jax.distributed
-    coordinator's key-value store.
-
-    Keys are deleted after a coordinator barrier confirms every process
-    has read the full set: a re-init against a still-running coordinator
-    (restart without a fresh coordinator) must not read the previous
-    run's stale endpoints, and the coordinator KV store rejects
-    overwrites of live keys."""
-    client = _coordinator_client()
-    my_key = f"{_KEY_PREFIX}{process_id}"
-    try:  # clear a leftover from a run that died mid-bootstrap
-        client.key_value_delete(my_key)
-    except Exception:  # noqa: BLE001 - absent key / older jax
-        pass
-    client.key_value_set(my_key, my_endpoint)
-    endpoints = [
-        client.blocking_key_value_get(f"{_KEY_PREFIX}{i}", timeout_ms)
-        for i in range(num_processes)]
-    try:
-        client.wait_at_barrier("multiverso_tpu_bootstrap", timeout_ms)
-        if process_id == 0:
-            client.key_value_delete(_KEY_PREFIX)  # directory delete
-    except Exception as exc:  # noqa: BLE001 - cleanup is best-effort
-        log.info("bootstrap key cleanup skipped: %s", exc)
-    return endpoints
+    raw = my_endpoint.encode()
+    if len(raw) > _ENDPOINT_BYTES:
+        raise ValueError(f"control endpoint too long: {my_endpoint!r}")
+    row = np.zeros(_ENDPOINT_BYTES, np.uint8)
+    row[:len(raw)] = np.frombuffer(raw, np.uint8)
+    rows = np.asarray(multihost_utils.process_allgather(row))
+    return [bytes(r).rstrip(b"\0").decode() for r in rows]
 
 
 def init_distributed(coordinator_address: Optional[str] = None,
@@ -103,18 +74,12 @@ def init_distributed(coordinator_address: Optional[str] = None,
                      argv: Optional[List[str]] = None,
                      control_port: Optional[int] = None) -> List[str]:
     """Initialize jax.distributed (data plane), rendezvous the TCP
-    control mesh through its coordinator, and mv.init. Arguments default
+    control mesh with an all-gather over it, and mv.init. Arguments default
     to jax's own cluster-environment auto-detection (TPU pods fill them
     from the runtime). Returns the argv remainder from mv.init."""
     import jax
 
-    already_up = False
-    try:
-        from jax._src.distributed import global_state
-        already_up = getattr(global_state, "client", None) is not None
-    except Exception:  # noqa: BLE001 - jax internals moved
-        pass
-    if not already_up:
+    if not jax.distributed.is_initialized():
         jax.distributed.initialize(coordinator_address=coordinator_address,
                                    num_processes=num_processes,
                                    process_id=process_id)
@@ -137,8 +102,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
         reserved, port = reserve_listen_port()
     try:
         my_endpoint = f"{addr}:{port}"
-        endpoints = exchange_endpoints(process_id, num_processes,
-                                       my_endpoint)
+        endpoints = exchange_endpoints(my_endpoint)
         log.info("control mesh (%d processes): %s", num_processes,
                  endpoints)
         net_bind(process_id, my_endpoint)

@@ -75,7 +75,7 @@ class Server(Actor):
     #: SCOPED to device-backed tables only (``needs_device_lock``):
     #: host-only table logic (KV control plane) must not serialize two
     #: in-process server shards against each other — that regression
-    #: put ps_two_servers at 0.809x of single-server in BENCH_r05.
+    #: put ps_two_servers at 0.809x of single-server in round 5's run.
     #: The lock object itself is the process-wide device-dispatch lock
     #: (runtime/device_lock.py): in multi-zoo mode trainer and worker
     #: dispatch sites serialize on the SAME lock.
@@ -89,7 +89,7 @@ class Server(Actor):
         wedge class the lock exists for cannot occur (no inter-device
         rendezvous to deadlock the execution pool), and process-wide
         serialization of sibling server actors was the measured bulk of
-        the two-server regression (BENCH_r05 0.809x). Inactive mode
+        the two-server regression (round 5: 0.809x). Inactive mode
         falls back to the table's per-instance state lock, which still
         pairs (state, version) against the async snapshotter. Host-only
         tables always take their own state lock — cheap (uncontended

@@ -24,11 +24,25 @@ SHARD_AXIS = "shard"
 
 @functools.lru_cache(maxsize=None)
 def local_mesh(num_devices: Optional[int] = None) -> Mesh:
-    """A 1-D mesh over (a prefix of) the local devices."""
-    devices = jax.devices()
+    """A 1-D mesh over (a prefix of) THIS process's devices. Under
+    jax.distributed ``jax.devices()`` also lists the other processes'
+    devices; a table laid over those would turn every server-side jit
+    into a multi-process program that all ranks must launch in
+    lockstep, which independent server actors do not."""
+    devices = jax.local_devices()
     if num_devices is not None:
         devices = devices[:num_devices]
     return Mesh(np.array(devices), (SHARD_AXIS,))
+
+
+def describe_backend() -> str:
+    """The default backend as JAX reports it — what every entry point
+    logs at start, so a run on the CPU cannot pass for a run on the
+    chip."""
+    devices = jax.devices()
+    return (f"platform={devices[0].platform} "
+            f"device_kind={devices[0].device_kind} "
+            f"devices={len(devices)}")
 
 
 def make_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str],

@@ -45,6 +45,9 @@ class TestResultEmission:
         assert out is None
         doc = _last_json(capsys)
         assert "phase exploded" in doc["detail"]["exploding_phase_error"]
+        # ... and counted: main() returns len(failed) as the exit code.
+        assert r.run("fine_phase", lambda: 1) == 1
+        assert r.failed == ["exploding_phase"]
 
     def test_wall_budget_skips_instead_of_starting(self, capsys,
                                                    monkeypatch):

@@ -388,7 +388,7 @@ class TestAddCoalescing:
 class TestServerLockScoping:
     def test_two_servers_progress_concurrently_on_host_paths(self,
                                                              monkeypatch):
-        # Regression (BENCH_r05 ps_two_servers at 0.809x of single):
+        # Regression (round 5: ps_two_servers at 0.809x of single):
         # the process-wide table lock exists for multi-device jitted
         # dispatch; two LocalFabric servers doing HOST-side control
         # work (KV tables) must not serialize on it. Each server's

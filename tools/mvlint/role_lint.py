@@ -89,7 +89,7 @@ def _func_name(call: ast.Call) -> Optional[str]:
 
 def classify_blocking(call: ast.Call) -> Optional[str]:
     """A short description when ``call`` is a blocking primitive,
-    else None. Mirrors the lock-discipline taxonomy plus the
+    else None. Mirrors the lock-discipline classification plus the
     transport shapes the send-discipline pass bans."""
     fn = call.func
     if isinstance(fn, ast.Name):
