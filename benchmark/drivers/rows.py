@@ -1,0 +1,113 @@
+"""Driver for row traffic on one dense matrix table: a closed loop of
+one client that makes the round a traffic mix names (``get_rows`` and
+``add_rows`` with host ids and host numpy buffers) through the worker
+and server actors of one in-process zoo."""
+
+import time
+
+import numpy as np
+
+from benchmark.lib.rowtraffic import RowTraffic
+from benchmark.reference import rows_replay
+
+
+class Driver:
+    def __init__(self, ctx):
+        self.ctx = ctx
+        self.config = ctx.config
+        self.round_index = 0
+        self.log = []          # every acknowledged request, in order
+        self.kept = None       # sampled rows of each Get's reply
+        self.gets_kept = 0
+        self.problems = []     # requests that failed
+
+    # -- set-up ---------------------------------------------------------
+    def build(self):
+        import multiverso_tpu as mv
+        c = self.config
+        self.traffic = RowTraffic(self.ctx.traffic, c["rows"], c["cols"],
+                                  self.ctx.seed)
+        mv.init([f"-rpc_timeout_s={self.ctx.deadline_s}"])
+        self.table = mv.create_matrix_table(
+            c["rows"], c["cols"], dtype=np.dtype(c["dtype"]),
+            updater_type=c["updater"])
+        n = int(self.ctx.traffic["ids_per_request"])
+        self.reply = np.empty((n, c["cols"]), np.dtype(c["dtype"]))
+        # Room for the sampled rows of every Get's reply, made once: the
+        # loop itself allocates nothing that outlives a round, so the
+        # allocator's state in round 1000 is its state in round 10.
+        widest = max(p.size for p in self.traffic.positions)
+        self.kept = np.empty((int(self.ctx.traffic["gets_checked"]), widest,
+                              c["cols"]), self.reply.dtype)
+        self.ctx.shapes.update(rows_per_request=n, cols=c["cols"],
+                               value_bytes=np.dtype(c["dtype"]).itemsize)
+
+    def warm(self):
+        for _ in range(int(self.ctx.traffic["warm_rounds"])):
+            self._round(None)
+        self._sync()
+
+    # -- the loop ---------------------------------------------------------
+    def _sync(self):
+        """An acknowledged Add has been queued on the device, not run.
+        A Get of one row comes back only when everything before it on
+        the table has."""
+        self.table.get_rows(np.zeros(1, np.int32))
+
+    def _round(self, window):
+        request = self.traffic.request(self.round_index)
+        ids = self.traffic.ids[request]
+        for op in self.traffic.ops:
+            t0 = time.perf_counter()
+            try:
+                with self.ctx.span(f"{op}_rows"):
+                    if op == "get":
+                        self.table.get_rows(ids, self.reply)
+                    else:
+                        self.table.add_rows(ids, self.traffic.delta(request))
+            except Exception as exc:  # noqa: BLE001 - counted, reported
+                self.problems.append(f"{op} {len(self.log)}: {exc!r}")
+                if window is not None:
+                    window.failed += 1
+                    window.attempted += 1
+                continue
+            ms = (time.perf_counter() - t0) * 1e3
+            kept = None
+            if op == "get" and self.gets_kept < self.kept.shape[0]:
+                where = self.traffic.positions[request]
+                kept = self.kept[self.gets_kept, :where.size]
+                np.take(self.reply, where, axis=0, out=kept)
+                self.gets_kept += 1
+            self.log.append((op, request, kept))
+            if window is not None:
+                window.attempted += 1
+                window.samples.setdefault(f"{op}_ms", []).append(ms)
+                window.work["rows"] = window.work.get("rows", 0) + ids.size
+        self.round_index += 1
+
+    def measure(self, seconds: float):
+        window = self.ctx.open_window()
+        deadline = window.t_start + seconds
+        while time.monotonic() < deadline:
+            self._round(window)
+            window.rounds += 1
+        self._sync()
+        return self.ctx.close_window(window)
+
+    # -- after the window ---------------------------------------------------
+    def check(self) -> list:
+        final = self.table.get_rows(self.traffic.sample)
+        wrong = list(self.problems)
+        gets = sum(1 for op, _, _ in self.log if op == "get")
+        print(f"[bench] replies compared with the replay: "
+              f"{self.gets_kept} of {gets} Gets", flush=True)
+        if not np.isfinite(final).all():
+            wrong.append("final table: non-finite values")
+        wrong += rows_replay.replay(self.traffic, self.log, final,
+                                    self.config["cols"])
+        return wrong
+
+    def close(self):
+        import multiverso_tpu as mv
+        del self.table
+        mv.shutdown()

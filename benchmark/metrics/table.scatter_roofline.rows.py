@@ -1,0 +1,24 @@
+"""The table's scatter-add program (an Add): percent of the HBM-bandwidth bound. The least
+time the chip could take to move the bytes the request needs (from its
+shapes, benchmark/lib/shapes.py, at the table's logical width) over the
+program's device time in the trace. Bound by memory bandwidth: a row
+scatter does no arithmetic to speak of."""
+
+from benchmark.lib import shapes
+from benchmark.lib import tableprograms as tp
+
+
+def read(obs):
+    if obs.trace is None:
+        return None
+    took = tp.seconds(obs.trace, (tp.SCATTER_ADD,))
+    # requests of the round's size, from the caller's own count: the
+    # one-row Get that closes the window runs the same stem
+    count = len(obs.traced.samples.get("add_ms", []))
+    if not count or not took:
+        return None
+    needed = count * shapes.scatter_add_bytes(
+        obs.shapes["rows_per_request"], obs.shapes["cols"],
+        obs.shapes["value_bytes"])
+    return shapes.roofline_share(needed, took,
+                                 obs.peaks["hbm_bytes_per_s"])
