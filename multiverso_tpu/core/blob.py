@@ -156,7 +156,16 @@ class Blob:
         if self._parts is not None:
             self._materialize_parts()
         if not isinstance(self._data, np.ndarray):
-            self._data = np.asarray(self._data)
+            if is_device_array(self._data):
+                # THE host boundary of a device reply: np.asarray waits
+                # for the program that produces the array, then copies
+                # device to host into a fresh buffer.
+                from ..util.dashboard import count, monitor
+                with monitor("BLOB_D2H"):
+                    self._data = np.asarray(self._data)
+                count("BLOB_D2H_BYTES", self._data.nbytes)
+            else:
+                self._data = np.asarray(self._data)
         return self._data
 
     @property

@@ -148,7 +148,7 @@ HEADER_SIZE = 10  # ints (8 in the reference; slot 8 added for
 
 
 class Message:
-    __slots__ = ("header", "data")
+    __slots__ = ("header", "data", "enqueued_ns")
 
     def __init__(self, src: int = -1, dst: int = -1,
                  msg_type: MsgType = MsgType.Default,
@@ -160,6 +160,9 @@ class Message:
         self.header[3] = table_id
         self.header[4] = msg_id
         self.data: List[Blob] = []
+        # time.monotonic_ns() at the push into an actor's mailbox
+        # (Actor.receive); local to the process, never on the wire.
+        self.enqueued_ns = 0
 
     # -- header accessors (ref: message.h:28-38) --
     @property

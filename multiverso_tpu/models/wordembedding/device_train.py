@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...runtime import device_lock
+from ...util.dashboard import monitor
 from .data import TokenizedCorpus
 from .model import _MAX_EXP, _sigmoid_xent
 
@@ -60,17 +61,22 @@ from .model import _MAX_EXP, _sigmoid_xent
 # -- per-epoch subsample + stable compaction (shape-polymorphic jit) --
 @jax.jit
 def _prep(flat, sent, keep, key):
-    mask = jax.random.uniform(key, flat.shape) < keep[flat]
+    # The scopes name the program's three steps in a device trace
+    # (tools/trace_spans.py sums device time by them).
+    with jax.named_scope("mv.prep.mask"):
+        mask = jax.random.uniform(key, flat.shape) < keep[flat]
     # Stable: kept tokens keep corpus order, so positional distance in
     # the compacted array IS the word2vec window distance over the
     # subsampled sentence.
-    order = jnp.argsort(jnp.where(mask, 0, 1).astype(jnp.int8),
-                        stable=True)
-    kept = flat[order]
-    # Dropped tail gets sentence -1: it can never match a real sentence
-    # id, so windows cannot cross into it.
-    ksent = jnp.where(mask[order], sent[order], -1)
-    return kept, ksent, mask.sum(dtype=jnp.int32)
+    with jax.named_scope("mv.prep.argsort"):
+        order = jnp.argsort(jnp.where(mask, 0, 1).astype(jnp.int8),
+                            stable=True)
+    with jax.named_scope("mv.prep.take"):
+        kept = flat[order]
+        # Dropped tail gets sentence -1: it can never match a real
+        # sentence id, so windows cannot cross into it.
+        ksent = jnp.where(mask[order], sent[order], -1)
+        return kept, ksent, mask.sum(dtype=jnp.int32)
 
 
 def _pad_stream(C, W, kept, ksent):
@@ -580,10 +586,11 @@ class DeviceCorpusTrainer:
         mode and trained centers in CBOW mode (one prediction per
         center)."""
         model, C, G = self.model, self._C, self._G
-        key = jax.random.PRNGKey(seed)
-        key, prep_key = jax.random.split(key)
-        kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
-        n_kept = int(n_kept_dev)  # the one host fetch per epoch
+        with monitor("TRAINER_EPOCH_PREP"):
+            key = jax.random.PRNGKey(seed)
+            key, prep_key = jax.random.split(key)
+            kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
+            n_kept = int(n_kept_dev)  # the one host fetch per epoch
         steps = max(math.ceil(n_kept / C), 1)
         if max_steps:
             steps = min(steps, max_steps)
@@ -689,6 +696,9 @@ def _block_ids_fn(C: int, W: int, K: int, cbow: bool = False,
     out=[centers (C) | negs (C//B*K)]. The band replaces the [C, 2W]
     context id matrix — 2W-fold fewer pulled/pushed rows."""
 
+    # The scope names the program's operations in a device trace; the
+    # jitted function keeps its own name.
+    @jax.named_scope("mv.sgns.ids")
     def ids(kept_pad, ksent_pad, neg_prob, neg_alias, key, base,
             n_kept):
         k_shrink, k_idx, k_keep = jax.random.split(key, 3)
@@ -727,6 +737,7 @@ def _block_step_fn(C: int, W: int, K: int, cbow: bool = False,
     delta is the net local change over all sub-steps, / num_workers."""
     nb = C // neg_block
 
+    @jax.named_scope("mv.sgns.step")
     def step(v, u, pmask, lr, inv_workers):
         # Multi-server pulls arrive as per-server shard tuples (foreign
         # rows zero-filled); summing them HERE folds the reassembly into
@@ -1073,16 +1084,17 @@ class PSDeviceCorpusTrainer:
         the trailing drain)."""
         model, C, G = self.model, self._C, self._G
         in_table, out_table = model._in_table, model._out_table
-        key = jax.random.PRNGKey(seed)
-        key, prep_key = jax.random.split(key)
-        kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
-        # Pad ONCE per epoch; the per-step ids program then slices the
-        # padded stream directly (padding per step would re-copy the
-        # whole ~24 MB stream every block).
-        with device_lock.guard():
-            kept_pad, ksent_pad = device_lock.settle(
-                self._pad(kept, ksent))
-        n_kept = int(n_kept_dev)
+        with monitor("TRAINER_EPOCH_PREP"):
+            key = jax.random.PRNGKey(seed)
+            key, prep_key = jax.random.split(key)
+            kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
+            # Pad ONCE per epoch; the per-step ids program then slices
+            # the padded stream directly (padding per step would
+            # re-copy the whole ~24 MB stream every block).
+            with device_lock.guard():
+                kept_pad, ksent_pad = device_lock.settle(
+                    self._pad(kept, ksent))
+            n_kept = int(n_kept_dev)
         steps = max(math.ceil(n_kept / C), 1)
         if max_steps:
             steps = min(steps, max_steps)

@@ -12,10 +12,12 @@ over verbatim.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Dict, Optional
 
 from ..core.message import Message
 from ..util import log
+from ..util.dashboard import Dashboard
 from ..util.mt_queue import MtQueue
 from . import thread_roles
 
@@ -36,6 +38,8 @@ class Actor:
         self.name = name
         self._zoo = zoo
         self.mailbox: MtQueue = MtQueue()
+        # MAILBOX_WAIT[*] family: one monitor per actor.
+        self._wait_metric = f"MAILBOX_WAIT[{name}]"
         self._handlers: Dict[int, Callable[[Message], None]] = {}
         self._thread: Optional[threading.Thread] = None
         zoo.register_actor(self)
@@ -55,7 +59,16 @@ class Actor:
 
     # -- messaging --
     def receive(self, msg: Message) -> None:
+        msg.enqueued_ns = time.monotonic_ns()
         self.mailbox.push(msg)
+
+    def _popped(self, msg: Message) -> None:
+        """Close the message's MAILBOX_WAIT: receive() to this pop.
+        With the handler monitors it splits a request into queued and
+        served. Two clock reads and one Monitor.add a message; every
+        loop that pops the mailbox calls it."""
+        Dashboard.get(self._wait_metric).add(
+            (time.monotonic_ns() - msg.enqueued_ns) * 1e-6)
 
     def send_to(self, name: str, msg: Message) -> None:
         self._zoo.send_to(name, msg)
@@ -69,6 +82,7 @@ class Actor:
             msg = self.mailbox.pop()
             if msg is None:
                 break
+            self._popped(msg)
             self._safe_dispatch(msg)
 
     def _safe_dispatch(self, msg: Message) -> None:

@@ -236,6 +236,8 @@ class Server(Actor):
                 size_of=fusion.message_nbytes)
             if not batch:
                 break
+            for msg in batch:   # every message of a fused batch waited
+                self._popped(msg)
             if len(batch) == 1:
                 self._safe_dispatch(batch[0])
                 continue
@@ -309,11 +311,15 @@ class Server(Actor):
             # dedup bookkeeping) with nothing to amortize it over —
             # run the exact serial path; replies, stamps and metrics
             # are identical to an unfused dispatch.
-            with monitor(name):
+            with monitor(name, msg_id=entries[0].msg_id,
+                         table=entries[0].table_id):
                 self._replay_serial(table, is_get, entries)
             return
         try:
-            with monitor(name):
+            # one span for the group: the first request's id, and how
+            # many requests the one program serves
+            with monitor(name, msg_id=entries[0].msg_id,
+                         table=entries[0].table_id, fused=len(entries)):
                 if is_get:
                     with self._lock_for(table):
                         results = table.process_fused_get(
@@ -439,7 +445,8 @@ class Server(Actor):
 
     # ref: src/server.cpp:36-46
     def _process_get(self, msg: Message) -> None:
-        with monitor("SERVER_PROCESS_GET"), \
+        with monitor("SERVER_PROCESS_GET", msg_id=msg.msg_id,
+                     table=msg.table_id), \
                 tracing.span(trace_of(msg), "server_process_get",
                              self._zoo.rank,
                              args={"table": msg.table_id}):
@@ -652,7 +659,8 @@ class Server(Actor):
         accounting keys on the shard it actually sent to, and the
         moved rows ride the reply as a replica group attributed to
         THIS shard (core/message.py Request_FwdGet)."""
-        with monitor("SERVER_PROCESS_GET"), \
+        with monitor("SERVER_PROCESS_GET", msg_id=msg.msg_id,
+                     table=msg.table_id), \
                 tracing.span(trace_of(msg), "server_process_fwd_get",
                              self._zoo.rank,
                              args={"table": msg.table_id}):
@@ -694,7 +702,8 @@ class Server(Actor):
         stamping it under the source's identity would fire the
         generation-regression guard spuriously). msg_id < 0 marks a
         secondary-window forward: applied, never acked."""
-        with monitor("SERVER_PROCESS_ADD"), \
+        with monitor("SERVER_PROCESS_ADD", msg_id=msg.msg_id,
+                     table=msg.table_id), \
                 tracing.span(trace_of(msg), "server_process_fwd_add",
                              self._zoo.rank,
                              args={"table": msg.table_id}):
@@ -726,7 +735,8 @@ class Server(Actor):
 
     # ref: src/server.cpp:48-58
     def _process_add(self, msg: Message) -> None:
-        with monitor("SERVER_PROCESS_ADD"), \
+        with monitor("SERVER_PROCESS_ADD", msg_id=msg.msg_id,
+                     table=msg.table_id), \
                 tracing.span(trace_of(msg), "server_process_add",
                              self._zoo.rank,
                              args={"table": msg.table_id}):

@@ -146,6 +146,7 @@ class Worker(Actor):
                 # — a worker stopping with unsent adds would lose them.
                 self._flush_pending()
                 break
+            self._popped(msg)
             self._safe_dispatch(msg)
             if self._pending and self.mailbox.empty():
                 # The mailbox just went idle: the requester is (or is
@@ -155,7 +156,8 @@ class Worker(Actor):
 
     # ref: src/worker.cpp:30-51
     def _process_get(self, msg: Message) -> None:
-        with monitor("WORKER_PROCESS_GET"):
+        with monitor("WORKER_PROCESS_GET", msg_id=msg.msg_id,
+                     table=msg.table_id):
             # Per-connection FIFO only orders what is actually ON the
             # wire: staged adds must flush before a Get so the server
             # observes add-before-get program order.
@@ -164,7 +166,8 @@ class Worker(Actor):
 
     # ref: src/worker.cpp:53-76
     def _process_add(self, msg: Message) -> None:
-        with monitor("WORKER_PROCESS_ADD"):
+        with monitor("WORKER_PROCESS_ADD", msg_id=msg.msg_id,
+                     table=msg.table_id):
             self._partition_and_send(msg, MsgType.Request_Add)
 
     def request_counts(self) -> Dict[int, int]:
@@ -456,8 +459,10 @@ class Worker(Actor):
                     # which blocks on server-produced computations —
                     # holding the lock across that wait starves the
                     # producing side.
-                    with tracing.span(trace_of(msg), "reply_handle:get",
-                                      self._zoo.rank):
+                    with monitor("WORKER_REPLY_GET", msg_id=msg.msg_id,
+                                 table=msg.table_id), \
+                            tracing.span(trace_of(msg), "reply_handle:get",
+                                         self._zoo.rank):
                         table.process_reply_get(msg.data)
                 finally:
                     table._end_reply()

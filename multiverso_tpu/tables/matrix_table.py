@@ -47,6 +47,7 @@ from ..runtime import shard_map as shard_map_mod
 from ..runtime.zoo import CONTROLLER_RANK
 from ..util import chaos
 from ..util.dashboard import count as count_event
+from ..util.dashboard import monitor
 from . import client_cache
 from .client_cache import RowCache
 from ..sharding import mesh as meshlib
@@ -1497,8 +1498,9 @@ class MatrixWorker(WorkerTable):
             # repeat ids — power-of-two padded row sets repeat the last
             # id thousands of times, so per-position Python loops go
             # quadratic and a single reply can burn minutes.
-            client_cache.place_rows(keys, values, self._dest_rows,
-                                    self._dest)
+            with monitor("CLIENT_PLACE_ROWS"):
+                client_cache.place_rows(keys, values, self._dest_rows,
+                                        self._dest)
 
     # -- hot-shard replication: worker side (runtime/replica.py,
     #    docs/SHARDING.md; all on the worker actor thread) --
@@ -1574,8 +1576,9 @@ class MatrixWorker(WorkerTable):
                     and self._dest_rows is not None:
                 self._row_cache.store(gkeys, gvals, version, owner)
             if self._dest is not None and self._dest_rows is not None:
-                client_cache.place_rows(gkeys, gvals, self._dest_rows,
-                                        self._dest)
+                with monitor("CLIENT_PLACE_ROWS"):
+                    client_cache.place_rows(gkeys, gvals,
+                                            self._dest_rows, self._dest)
 
         self._serve_reply_groups(keys, values, reply_blobs, requested,
                                  place)
@@ -1601,14 +1604,15 @@ class MatrixWorker(WorkerTable):
                 self._row_cache.store(gkeys, gvals, version, owner)
             if gkeys.size == 0:
                 return
-            pos = np.minimum(np.searchsorted(entry.rows, gkeys),
-                             entry.rows.size - 1)
-            ok = entry.rows[pos] == gkeys  # repairs may widen to rows
-            pos = pos[ok]                  # outside this entry's set
-            entry.out[pos] = gvals[ok]
-            if version >= 0:
-                entry.versions[pos] = np.maximum(entry.versions[pos],
-                                                 int(version))
+            with monitor("CLIENT_PLACE_ROWS"):
+                pos = np.minimum(np.searchsorted(entry.rows, gkeys),
+                                 entry.rows.size - 1)
+                ok = entry.rows[pos] == gkeys  # repairs may widen to
+                pos = pos[ok]          # rows outside this entry's set
+                entry.out[pos] = gvals[ok]
+                if version >= 0:
+                    entry.versions[pos] = np.maximum(
+                        entry.versions[pos], int(version))
 
         self._serve_reply_groups(keys, values, reply_blobs, requested,
                                  place)
@@ -2759,8 +2763,11 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
     @functools.cached_property
     def _gather(self):
         n_col = self.num_col
-        return jax.jit(lambda data, rows: data.at[rows].get(
-            mode="fill", fill_value=0)[..., :n_col])
+        # The scope names the gather's operations in a device trace;
+        # the program keeps the name its lambda gives it.
+        return jax.jit(jax.named_scope("mv.table.gather")(
+            lambda data, rows: data.at[rows].get(
+                mode="fill", fill_value=0)[..., :n_col]))
 
     @property
     def _shard_bounds(self):
@@ -2785,6 +2792,7 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
         n_col = self.num_col
         import jax.numpy as jnp
 
+        @jax.named_scope("mv.table.gather")
         def gather(data, rows):
             local = jnp.where((rows >= ofs) & (rows < ofs + n),
                               rows - ofs, padded)

@@ -259,8 +259,11 @@ class WorkerTable:
             configured = float(get_flag("rpc_timeout_s", 0.0))
             if configured > 0:
                 flag_timeout = configured
-        ok = waiter.wait(timeout=timeout if timeout is not None
-                         else flag_timeout)
+        # Only the blocking itself: a request already complete (the
+        # early return above) never counts.
+        with monitor("TABLE_WAIT"):
+            ok = waiter.wait(timeout=timeout if timeout is not None
+                             else flag_timeout)
         self._check_aborted()
         if ok:
             with self._mutex:

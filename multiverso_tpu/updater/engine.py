@@ -62,13 +62,19 @@ class UpdateEngine:
                 delta = jax.numpy.pad(delta, pad)
             return delta
 
+        # The named scopes mark the programs' steps in a device trace
+        # (tools/trace_spans.py sums device time by them): the pads,
+        # the rule, and inside a rule's rows form the scatter-add
+        # (rules.py). They change no operation and no program's name.
         def dense_padded(data, st, delta, hyp, worker_id):
-            delta = pad_cols(data, delta)
-            if data.shape[0] != delta.shape[0]:
-                pad = ((0, data.shape[0] - delta.shape[0]),) \
-                    + ((0, 0),) * (delta.ndim - 1)
-                delta = jax.numpy.pad(delta, pad)
-            return self.rule.dense(data, st, delta, hyp, worker_id)
+            with jax.named_scope("mv.update.pad"):
+                delta = pad_cols(data, delta)
+                if data.shape[0] != delta.shape[0]:
+                    pad = ((0, data.shape[0] - delta.shape[0]),) \
+                        + ((0, 0),) * (delta.ndim - 1)
+                    delta = jax.numpy.pad(delta, pad)
+            with jax.named_scope("mv.update.rule"):
+                return self.rule.dense(data, st, delta, hyp, worker_id)
 
         def pad_row_count(row_ids, delta):
             """Zero-extend a [k, ...] delta to the padded id count —
@@ -83,9 +89,11 @@ class UpdateEngine:
             return delta
 
         def rows_padded(data, st, row_ids, delta, hyp, worker_id):
-            delta = pad_row_count(row_ids, pad_cols(data, delta))
-            return self.rule.rows(data, st, row_ids, delta, hyp,
-                                  worker_id)
+            with jax.named_scope("mv.update.pad"):
+                delta = pad_row_count(row_ids, pad_cols(data, delta))
+            with jax.named_scope("mv.update.rule"):
+                return self.rule.rows(data, st, row_ids, delta, hyp,
+                                      worker_id)
 
         self._pad_cols = pad_cols
         self._pad_row_count = pad_row_count
@@ -144,9 +152,11 @@ class UpdateEngine:
                 # padding where a later masked gather would read it.
                 row_ids = jnp.where((row_ids >= ofs) & (row_ids < ofs + n),
                                     row_ids - ofs, padded)
-                return rule_rows(data, st, row_ids,
-                                 self._pad_cols(data, delta), hyp,
-                                 worker_id)
+                with jax.named_scope("mv.update.pad"):
+                    delta = self._pad_cols(data, delta)
+                with jax.named_scope("mv.update.rule"):
+                    return rule_rows(data, st, row_ids, delta, hyp,
+                                     worker_id)
 
             fn = jax.jit(rows_fn, donate_argnums=(0, 1))
             self._rows_bounded[bounds] = fn
@@ -178,11 +188,14 @@ class UpdateEngine:
             pad_row_count = self._pad_row_count
 
             def f(data, st, row_ids, delta, hyp, wid, get_ids):
-                delta = pad_row_count(row_ids, pad_cols(data, delta))
-                data, st = rule_rows(data, st, row_ids, delta, hyp,
-                                     wid)
-                values = data.at[get_ids].get(
-                    mode="fill", fill_value=0)[..., :n_col]
+                with jax.named_scope("mv.update.pad"):
+                    delta = pad_row_count(row_ids, pad_cols(data, delta))
+                with jax.named_scope("mv.update.rule"):
+                    data, st = rule_rows(data, st, row_ids, delta, hyp,
+                                         wid)
+                with jax.named_scope("mv.table.gather"):
+                    values = data.at[get_ids].get(
+                        mode="fill", fill_value=0)[..., :n_col]
                 return data, st, values
 
             fn = jax.jit(f, donate_argnums=(0, 1))

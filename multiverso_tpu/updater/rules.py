@@ -62,6 +62,14 @@ def _safe_lr(lr):
     return jnp.maximum(lr, jnp.asarray(1e-12, lr.dtype))
 
 
+def _scatter_add(data, row_ids, step):
+    """``data[row_ids] += step``, out-of-range ids dropped: the last
+    operation of every rule's rows form, under one scope name so that a
+    device trace shows it apart from the rule's arithmetic."""
+    with jax.named_scope("mv.update.scatter_add"):
+        return data.at[row_ids].add(step, mode="drop")
+
+
 class UpdaterRule:
     """A pure update rule: (data, state, delta, hyp, worker_id) -> (data, state)."""
 
@@ -92,7 +100,7 @@ class DefaultRule(UpdaterRule):
         return data + delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return data.at[row_ids].add(delta, mode="drop"), state
+        return _scatter_add(data, row_ids, delta), state
 
 
 class SGDRule(UpdaterRule):
@@ -102,7 +110,7 @@ class SGDRule(UpdaterRule):
         return data - delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return data.at[row_ids].add(-delta, mode="drop"), state
+        return _scatter_add(data, row_ids, -delta), state
 
 
 class MomentumRule(UpdaterRule):
@@ -122,7 +130,7 @@ class MomentumRule(UpdaterRule):
         smooth_rows = (m * state.at[row_ids].get(mode="fill", fill_value=0)
                        + (1 - m) * delta)
         state = state.at[row_ids].set(smooth_rows, mode="drop")
-        return data.at[row_ids].add(-smooth_rows, mode="drop"), state
+        return _scatter_add(data, row_ids, -smooth_rows), state
 
 
 class AdaGradRule(UpdaterRule):
@@ -148,7 +156,7 @@ class AdaGradRule(UpdaterRule):
         g_sqr = g_rows + grad * grad
         step = rho * grad * jax.lax.rsqrt(g_sqr + ADAGRAD_EPS)
         state = state.at[worker_id, row_ids].set(g_sqr, mode="drop")
-        return data.at[row_ids].add(-step, mode="drop"), state
+        return _scatter_add(data, row_ids, -step), state
 
 
 class DCASGDRule(UpdaterRule):
@@ -192,7 +200,7 @@ class DCASGDRule(UpdaterRule):
         # same pre-update rows for each duplicate, like momentum/adagrad's
         # once-per-unique-row state). The backup records one step for a
         # duplicated row — second-order staleness error, documented.
-        data = data.at[row_ids].add(-step, mode="drop")
+        data = _scatter_add(data, row_ids, -step)
         state = state.at[worker_id, row_ids].set(rows_now - step,
                                                  mode="drop")
         return data, state

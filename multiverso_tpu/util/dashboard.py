@@ -4,9 +4,12 @@ TPU-native equivalent of the reference's ``Dashboard``/``Monitor``
 (ref: include/multiverso/dashboard.h:16-74, src/dashboard.cpp:14-49): global
 registry of named monitors, each accumulating call count and elapsed ms;
 ``Dashboard.display()`` dumps all. The MONITOR_BEGIN/END macro pair becomes a
-context manager (``with monitor("name"):``); on TPU, ``jax.profiler`` traces
-can be layered on via ``trace=True`` which opens a profiler ``TraceAnnotation``
-so monitored regions show up in xprof.
+context manager (``with monitor("name"):``) that is also a span on the
+profiler's clock: every entry opens a ``jax.profiler.TraceAnnotation``
+named ``mv:<name>``, so a monitored region lies beside the device's
+operations in any trace captured around it (``trace_to``, or the
+benchmark's ``--trace 1``). With no profiler session open the annotation
+is a disabled TraceMe and records nothing.
 """
 
 from __future__ import annotations
@@ -14,7 +17,7 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 from .lock_witness import named_lock
 
@@ -34,6 +37,13 @@ METRIC_NAMES: Dict[str, str] = {
     "WORKER_COALESCE_FLUSH": "coalesced BatchAdd flushes packed",
     "WORKER_TABLE_SYNC_GET": "blocking table get_raw issue-to-reply",
     "WORKER_TABLE_SYNC_ADD": "blocking table add_raw issue-to-ack",
+    "WORKER_REPLY_GET": "worker actor Get reply handling: materialise, "
+                        "reshape, place",
+    "TABLE_WAIT": "calling thread blocked in WorkerTable.wait on replies",
+    "CLIENT_PLACE_ROWS": "Get reply rows placed into the caller's buffer",
+    "BLOB_D2H": "device payload copied to host (np.asarray of a "
+                "jax.Array: waits for its program, then copies)",
+    "BLOB_D2H_BYTES": "bytes those device-to-host copies moved",
     # -- server actor --
     "SERVER_PROCESS_GET": "server-side Get table op + reply",
     "SERVER_PROCESS_ADD": "server-side Add apply + ack",
@@ -134,6 +144,13 @@ METRIC_NAMES: Dict[str, str] = {
                          "controller",
     # -- actor mailboxes (util/mt_queue.py track_depth) --
     "MAILBOX_DEPTH[*]": "actor mailbox depth at each push",
+    # -- actor mailboxes (runtime/actor.py: stamped in receive, closed
+    #    at the pop) --
+    "MAILBOX_WAIT[*]": "enqueue-to-dequeue time of each message, per "
+                       "actor ([server], [worker], ...)",
+    # -- device-corpus trainers (models/wordembedding/device_train.py) --
+    "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
+                          "dispatch: _prep, pad, kept-count readback",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"
@@ -217,6 +234,13 @@ class Dashboard:
 
     @classmethod
     def get(cls, name: str) -> Monitor:
+        # A registered monitor is read without the registry lock (one
+        # dict lookup, atomic under the GIL): every hot site re-resolves
+        # here per entry. Only a first use, or the first after a
+        # reset(), takes the lock.
+        mon = cls._monitors.get(name)
+        if mon is not None:
+            return mon
         with cls._lock:
             mon = cls._monitors.get(name)
             if mon is None:
@@ -262,38 +286,53 @@ class Dashboard:
             cls._monitors.clear()
 
 
-class monitor:
-    """Context manager replacing MONITOR_BEGIN/END macro pair.
+#: Prefix of every monitor's span in a profiler trace. The benchmark's
+#: own spans are ``bench:``; its reduction keeps only those.
+SPAN_PREFIX = "mv:"
 
-    With ``trace=True`` also emits a jax.profiler TraceAnnotation so the
-    region is visible in xprof traces captured on TPU.
+_trace_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _bind_annotation():
+    global _trace_annotation
+    from jax.profiler import TraceAnnotation
+    _trace_annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class monitor:
+    """Context manager replacing MONITOR_BEGIN/END macro pair, and a
+    ``mv:<name>`` span in any profiler trace captured around it.
+
+    ``args`` (a request's ``msg_id`` and ``table``) go to the span
+    only, so that a span in a trace can be matched to its request;
+    the Monitor counts and times the same whatever they are. "Tracing
+    off" is "no profiler session open": the annotation is then a
+    disabled TraceMe.
     """
 
-    def __init__(self, name: str, trace: bool = False):
+    __slots__ = ("_name", "_args", "_monitor", "_span", "_begin")
+
+    def __init__(self, name: str, **args):
         self._name = name
-        self._monitor: Optional[Monitor] = None
-        self._trace_ctx = None
-        if trace:
-            import jax.profiler
-            self._trace_ctx = jax.profiler.TraceAnnotation(name)
+        self._args = args
 
     def __enter__(self) -> Monitor:
-        if self._trace_ctx is not None:
-            self._trace_ctx.__enter__()
+        self._span = (_trace_annotation or _bind_annotation())(
+            SPAN_PREFIX + self._name, **self._args)
+        self._span.__enter__()
         # Re-resolved per entry, NOT cached at construction: a
         # ``Dashboard.reset()`` (every bench phase does one) replaces
         # the registry, and a long-lived ``monitor(...)`` instance
         # caching its Monitor would keep writing to an unregistered
         # orphan that no display()/snapshot ever sees.
         self._monitor = Dashboard.get(self._name)
-        self._monitor.begin()
+        self._begin = time.perf_counter()
         return self._monitor
 
     def __exit__(self, *exc) -> None:
-        if self._monitor is not None:
-            self._monitor.end()
-        if self._trace_ctx is not None:
-            self._trace_ctx.__exit__(*exc)
+        self._monitor.add((time.perf_counter() - self._begin) * 1e3)
+        self._span.__exit__(*exc)
         return None
 
 
@@ -425,8 +464,9 @@ def count(name: str, n: int = 1) -> None:
 
 def trace_to(log_dir: str):
     """Whole-program xprof capture: everything inside the block —
-    including ``monitor(..., trace=True)`` annotations — lands in a
-    TensorBoard-loadable trace under ``log_dir``. The TPU-native
+    including every ``monitor(...)`` region, as an ``mv:<name>`` span on
+    the host's lines — lands in a TensorBoard-loadable trace under
+    ``log_dir`` (``tools/trace_spans.py`` reads it). The TPU-native
     counterpart of reading Dashboard.display() next to an MPI profile
     (SURVEY.md section 5.1). Thin lazy-import alias of
     ``jax.profiler.trace`` so future jax trace features are inherited.

@@ -1,0 +1,428 @@
+"""The program's spans and counters (docs/OBSERVABILITY.md "Spans on the
+device trace"): every Dashboard monitor is an ``mv:<NAME>`` span in a
+profiler capture, and the monitors that say where a request's time goes
+(TABLE_WAIT, MAILBOX_WAIT[*], WORKER_REPLY_GET, BLOB_D2H(+_BYTES),
+CLIENT_PLACE_ROWS, TRAINER_EPOCH_PREP) move by what one request does."""
+
+import glob
+import inspect
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import multiverso_tpu as mv
+from benchmark.lib import counters
+from multiverso_tpu.runtime import actor as actors
+from multiverso_tpu.util import dashboard
+from multiverso_tpu.util.dashboard import Dashboard, monitor, trace_to
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROWS, COLS, IDS = 1000, 8, 100
+SLEEP_S = 0.05
+
+
+def _snapshot():
+    return counters.snapshot()
+
+
+def _delta(before, after):
+    """``{name: (count, ms)}`` over an interval, as the benchmark's
+    harness reads the monitors around its window."""
+    return {name: (moved["count"], moved["ms"])
+            for name, moved in counters.delta(before, after).items()}
+
+
+def _slow_pops(server):
+    """Every pop of the server's mailbox sleeps before its MAILBOX_WAIT
+    closes: the caller is then surely blocked in wait() when the reply
+    comes, and the wait the monitor reads has a known floor."""
+    popped = server._popped
+
+    def slow(msg):
+        time.sleep(SLEEP_S)
+        popped(msg)
+
+    server._popped = slow
+
+
+@pytest.fixture(scope="module")
+def one_get_one_add():
+    """Monitor deltas over one get_rows and one add_rows of a small
+    MatrixTable in a one-process zoo, programs compiled beforehand."""
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = np.arange(IDS, dtype=np.int32)
+        out = np.empty((IDS, COLS), np.float32)
+        delta = np.ones((IDS, COLS), np.float32)
+        table.get_rows(ids, out)
+        table.add_rows(ids, delta)
+        server = mv.current_zoo()._actors[actors.SERVER]
+        _slow_pops(server)
+        before = _snapshot()
+        table.get_rows(ids, out)
+        table.add_rows(ids, delta)
+        moved = _delta(before, _snapshot())
+        del server._popped
+    finally:
+        mv.shutdown()
+    return moved
+
+
+@pytest.mark.parametrize("name, count", [
+    ("TABLE_WAIT", 2),                 # one wait a request
+    ("WORKER_REPLY_GET", 1),
+    ("CLIENT_PLACE_ROWS", 1),
+    ("BLOB_D2H", 1),
+    ("BLOB_D2H_BYTES", IDS * COLS * 4),   # the reply's bytes
+    ("MAILBOX_WAIT[server]", 2),       # the Get and the Add
+    ("MAILBOX_WAIT[worker]", 4),       # two requests, two replies
+])
+def test_one_get_and_one_add_move_each_monitor(one_get_one_add, name, count):
+    assert one_get_one_add[name][0] == count
+
+
+def test_mailbox_wait_counts_what_the_server_handled(one_get_one_add):
+    handled = (one_get_one_add["SERVER_PROCESS_GET"][0]
+               + one_get_one_add["SERVER_PROCESS_ADD"][0])
+    assert one_get_one_add["MAILBOX_WAIT[server]"][0] == handled == 2
+
+
+def test_mailbox_wait_holds_the_sleep_before_the_pop(one_get_one_add):
+    assert one_get_one_add["MAILBOX_WAIT[server]"][1] >= 2 * SLEEP_S * 1e3
+    # the caller was blocked at least as long, the worker's pops were not
+    assert one_get_one_add["TABLE_WAIT"][1] >= 2 * SLEEP_S * 1e3
+    assert one_get_one_add["MAILBOX_WAIT[worker]"][1] \
+        < one_get_one_add["MAILBOX_WAIT[server]"][1]
+
+
+def test_the_pieces_of_a_get_nest(one_get_one_add):
+    d2h = one_get_one_add["BLOB_D2H"][1]
+    place = one_get_one_add["CLIENT_PLACE_ROWS"][1]
+    reply = one_get_one_add["WORKER_REPLY_GET"][1]
+    assert 0 < d2h + place <= reply <= one_get_one_add["TABLE_WAIT"][1]
+
+
+def test_every_message_of_a_fused_batch_counts():
+    """Adds to three tables pile up behind a slowed first pop; the next
+    pop_batch drains them together, and each has waited."""
+    mv.init([])
+    try:
+        tables = [mv.create_matrix_table(ROWS, COLS) for _ in range(3)]
+        ids = np.arange(IDS, dtype=np.int32)
+        delta = np.ones((IDS, COLS), np.float32)
+        for table in tables:
+            table.add_rows(ids, delta)
+        server = mv.current_zoo()._actors[actors.SERVER]
+        received = []
+        receive = server.receive
+        server.receive = lambda msg: (received.append(msg), receive(msg))
+        _slow_pops(server)
+        fused = dashboard.samples("SERVER_FUSE_BATCH").count
+        before = _snapshot()
+        pending = [(t, t.add_rows_async(ids, delta))
+                   for t in tables for _ in range(2)]
+        for table, msg_id in pending:
+            table.wait(msg_id)
+        moved = _delta(before, _snapshot())
+        assert dashboard.samples("SERVER_FUSE_BATCH").count > fused
+        assert moved["MAILBOX_WAIT[server]"][0] == len(received) >= 3
+        # those that queued behind the first waited out its sleep too
+        assert moved["MAILBOX_WAIT[server]"][1] >= 2 * SLEEP_S * 1e3
+        for table in tables:
+            np.testing.assert_array_equal(
+                table.get_rows(ids), np.full((IDS, COLS), 3.0, np.float32))
+    finally:
+        mv.shutdown()
+
+
+def _host_spans(trace_dir):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``mv:`` spans on the
+    host's lines of the capture under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(dashboard.SPAN_PREFIX):
+                    spans.append((e.name, e.start_ns,
+                                  e.start_ns + e.duration_ns,
+                                  dict(e.stats)))
+    return spans
+
+
+def test_a_capture_around_one_get_holds_the_servers_span(tmp_path):
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = np.arange(IDS, dtype=np.int32)
+        out = np.empty((IDS, COLS), np.float32)
+        # no session open: the same calls raise nothing, record nothing
+        table.get_rows(ids, out)
+        table.add_rows(ids, np.ones((IDS, COLS), np.float32))
+        with trace_to(str(tmp_path)):
+            with monitor("caller_window"):  # mvlint: ignore[metric-name]
+                table.get_rows(ids, out)
+            msg_id = table._msg_id
+    finally:
+        mv.shutdown()
+    spans = _host_spans(str(tmp_path))
+    by_name = {}
+    for span in spans:
+        by_name.setdefault(span[0], []).append(span)
+    # only what ran inside the session is there: one Get, no Add
+    assert "mv:SERVER_PROCESS_ADD" not in by_name
+    (_, lo, hi, _), = by_name["mv:caller_window"]
+    (_, start, end, stats), = by_name["mv:SERVER_PROCESS_GET"]
+    assert lo <= start <= end <= hi
+    assert stats["msg_id"] == msg_id and stats["table"] == table.table_id
+    # the caller's wait and the worker's reply handling lie in the same
+    # window, on threads of their own
+    for name in ("mv:TABLE_WAIT", "mv:WORKER_PROCESS_GET",
+                 "mv:WORKER_REPLY_GET", "mv:BLOB_D2H",
+                 "mv:CLIENT_PLACE_ROWS"):
+        (_, a, b, _), = by_name[name]
+        assert lo <= a <= b <= hi, name
+    (_, a, b, reply_stats), = by_name["mv:WORKER_REPLY_GET"]
+    (_, c, d, _), = by_name["mv:BLOB_D2H"]
+    assert a <= c <= d <= b and reply_stats["msg_id"] == msg_id
+
+
+def test_monitor_has_no_trace_parameter():
+    params = inspect.signature(monitor.__init__).parameters
+    assert "trace" not in params
+    assert params["args"].kind is inspect.Parameter.VAR_KEYWORD
+
+
+def test_span_arguments_do_not_reach_the_monitor():
+    before = Dashboard.get("SPAN_ARGS").count
+    with monitor("SPAN_ARGS",  # mvlint: ignore[metric-name]
+                 msg_id=7, table=1) as mon:
+        pass
+    assert mon is Dashboard.get("SPAN_ARGS")
+    assert mon.count == before + 1
+
+
+def test_concurrent_entries_lose_no_count():
+    """Dashboard.get reads a registered monitor without the registry
+    lock: threads racing through a first use must still share ONE
+    Monitor, and no entry may be lost."""
+    import threading
+    threads, entries = 16, 2000
+    Dashboard.reset()
+    start = threading.Barrier(threads)
+
+    def work():
+        start.wait(timeout=30)
+        for _ in range(entries):
+            with monitor("RACED_REGION"):  # mvlint: ignore[metric-name]
+                pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        workers = [threading.Thread(target=work) for _ in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(w.is_alive() for w in workers)
+    assert Dashboard.get("RACED_REGION").count == threads * entries
+    Dashboard.reset()
+
+
+# -- TRAINER_EPOCH_PREP -----------------------------------------------------
+
+def _corpus(tmp_path):
+    from multiverso_tpu.models.wordembedding import (Dictionary,
+                                                     TokenizedCorpus)
+    rng = np.random.default_rng(0)
+    words = [f"w{i}" for i in range(16)]
+    path = tmp_path / "corpus.txt"
+    path.write_text("\n".join(
+        " ".join(rng.choice(words, size=12)) for _ in range(60)))
+    d = Dictionary.build(str(path), min_count=1)
+    return d, TokenizedCorpus.build(d, str(path))
+
+
+@pytest.mark.parametrize("use_ps", [False, True], ids=["local", "ps"])
+def test_epoch_prep_counts_one_an_epoch(use_ps, tmp_path):
+    from multiverso_tpu.models.wordembedding import (
+        DeviceCorpusTrainer, PSDeviceCorpusTrainer, PSWord2Vec, Word2Vec,
+        Word2VecConfig)
+    d, tok = _corpus(tmp_path)
+    config = Word2VecConfig(embedding_size=8, window=2, epochs=1,
+                            init_learning_rate=0.01, batch_size=512,
+                            sample=0, use_ps=use_ps)
+    if use_ps:
+        mv.init([])
+    try:
+        if use_ps:
+            trainer = PSDeviceCorpusTrainer(PSWord2Vec(config, d), tok,
+                                            centers_per_step=64)
+        else:
+            trainer = DeviceCorpusTrainer(Word2Vec(config, d), tok,
+                                          centers_per_step=64)
+        before = Dashboard.get("TRAINER_EPOCH_PREP").count
+        blocks = []
+        hook = {"block_hook" if use_ps else "group_hook": blocks.append}
+        for epoch in range(2):
+            ms = Dashboard.get("TRAINER_EPOCH_PREP").elapse
+            t0 = time.perf_counter()
+            trainer.train_epoch(seed=epoch, **hook)
+            whole = (time.perf_counter() - t0) * 1e3
+            # the preparation only: it ends before the first block
+            assert 0 < Dashboard.get("TRAINER_EPOCH_PREP").elapse - ms < whole
+        assert Dashboard.get("TRAINER_EPOCH_PREP").count == before + 2
+        assert blocks
+    finally:
+        if use_ps:
+            mv.shutdown()
+
+
+# -- named scopes -------------------------------------------------------------
+
+def _lowered(fn, *args):
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+def test_prep_names_its_three_steps():
+    from multiverso_tpu.models.wordembedding import device_train
+    flat = jnp.zeros(64, jnp.int32)
+    text = _lowered(device_train._prep, flat, flat,
+                    jnp.ones(4, jnp.float32), jax.random.PRNGKey(0))
+    for scope in ("mv.prep.mask", "mv.prep.argsort", "mv.prep.take"):
+        assert scope in text
+    assert "module @jit__prep" in text   # the program's name is as it was
+
+
+def test_update_programs_name_their_steps_and_keep_their_names():
+    from multiverso_tpu.updater.engine import UpdateEngine
+    engine = UpdateEngine(None, (128, 128), np.float32, 1)
+    data = jnp.zeros((128, 128), jnp.float32)
+    hyp, wid = np.zeros(4, np.float32), np.int32(0)
+    rows = _lowered(engine._rows, data, None, jnp.zeros(8, jnp.int32),
+                    jnp.zeros((8, 50), jnp.float32), hyp, wid)
+    for scope in ("mv.update.pad", "mv.update.rule",
+                  "mv.update.scatter_add"):
+        assert scope in rows
+    assert "module @jit_rows_padded" in rows
+    dense = _lowered(engine._dense, data, None,
+                     jnp.zeros((100, 50), jnp.float32), hyp, wid)
+    assert "mv.update.pad" in dense and "mv.update.rule" in dense
+    assert "module @jit_dense_padded" in dense
+
+
+def test_the_gather_is_scoped_and_keeps_its_lambdas_name():
+    mv.init([])
+    try:
+        mv.create_matrix_table(ROWS, COLS)
+        server_table = mv.current_zoo()._actors[actors.SERVER]._store[0]
+        text = _lowered(server_table._gather, server_table._data,
+                        jnp.zeros(8, jnp.int32))
+    finally:
+        mv.shutdown()
+    assert "mv.table.gather" in text
+    # benchmark/lib/tableprograms.py keys on this stem
+    assert "module @jit__lambda" in text
+
+
+def test_the_ps_block_programs_are_scoped():
+    from multiverso_tpu.models.wordembedding import device_train
+    C, W, K = 8, 2, 2
+    ids = device_train._block_ids_fn(C, W, K)
+    stream = jnp.zeros(C + 2 * W + C, jnp.int32)
+    text = _lowered(ids, stream, stream, jnp.ones(16, jnp.float32),
+                    jnp.zeros(16, jnp.int32), jax.random.PRNGKey(0),
+                    np.int32(0), np.int32(C))
+    assert "mv.sgns.ids" in text and "module @jit_ids" in text
+    step = device_train._block_step_fn(C, W, K)
+    text = _lowered(step, jnp.zeros((C, 4)), jnp.zeros((C + 2 * W + C * K, 4)),
+                    jnp.ones((C, 2 * W)), jnp.float32(0.1), jnp.float32(1.0))
+    assert "mv.sgns.step" in text and "module @jit_step" in text
+
+
+# -- tools/trace_spans.py -------------------------------------------------------
+
+RECORDED = os.path.join(ROOT, "benchmark", "tests", "data",
+                        "rows_traced.xplane.pb")
+
+
+def test_trace_spans_falls_back_to_the_bench_spans_of_the_recorded_trace():
+    """The recorded trace (PR 23) holds no ``mv:`` span: every gap falls
+    back to the harness's span, and the totals are xplane.reduce's."""
+    from benchmark.lib import xplane
+    from tools import trace_spans
+    report = trace_spans.read(RECORDED)
+    reduced = xplane.reduce(xplane.load(RECORDED))
+    assert report["gaps"]["mv_share"] == 0.0
+    want = {f"bench:{name}": seconds
+            for name, seconds in reduced["gap_totals"].items()
+            if name != xplane.NO_SPAN}
+    got = {name: seconds for name, seconds in report["gaps"]["totals"].items()
+           if name != trace_spans.NO_SPAN}
+    assert got == pytest.approx(want, abs=1e-9)
+    assert report["gaps"]["idle_s"] == pytest.approx(
+        reduced["window_s"] - reduced["busy_s"], abs=1e-9)
+    # no scope path in a trace trimmed of its stats: time by program only
+    assert set(report["scopes"]) == set(reduced["programs"])
+    for stem, program in reduced["programs"].items():
+        assert sum(report["scopes"][stem].values()) <= \
+            program["seconds"] + 1e-9
+
+
+def test_trace_spans_cuts_a_gap_by_the_innermost_working_mv_span():
+    from tools import trace_spans
+    ms = 1_000_000
+    device = {"modules": [("jit_rows_padded(123456)", 0, 10 * ms),
+                          ("jit_rows_padded(123456)", 90 * ms, 100 * ms)],
+              "ops": [("%fusion.1", 0, 10 * ms,
+                       "jit(rows_padded)/mv.update.rule/"
+                       "mv.update.scatter_add/scatter-add:"),
+                      ("%while.2", 90 * ms, 100 * ms, ""),
+                      ("%fusion.3", 92 * ms, 96 * ms,   # inside the loop
+                       "jit(rows_padded)/mv.update.pad/pad:")]}
+    spans = [  # (name, start, end, thread)
+        ("bench:window", 0, 100 * ms, 1),
+        ("bench:get_rows", 5 * ms, 95 * ms, 1),
+        ("mv:TABLE_WAIT", 12 * ms, 94 * ms, 1),      # only waits
+        ("mv:WORKER_REPLY_GET", 20 * ms, 90 * ms, 2),
+        ("mv:BLOB_D2H", 22 * ms, 70 * ms, 2),        # innermost
+        ("mv:CLIENT_PLACE_ROWS", 72 * ms, 89 * ms, 2)]
+    report = trace_spans.report({"/device:TPU:0": device}, spans)
+    # one gap, 10 to 90 ms, cut at the spans' edges
+    assert report["gaps"]["totals"] == pytest.approx({
+        "bench:get_rows": 0.002,          # before any mv: span opens
+        "mv:TABLE_WAIT": 0.008,           # nothing but the wait yet
+        "mv:WORKER_REPLY_GET": 0.005,     # 20-22, 70-72, 89-90
+        "mv:BLOB_D2H": 0.048,
+        "mv:CLIENT_PLACE_ROWS": 0.017})
+    assert report["gaps"]["idle_s"] == pytest.approx(0.080)
+    assert report["gaps"]["mv_share"] == pytest.approx(0.078 / 0.080)
+    # an operation under two scopes goes to the inner one; one that
+    # encloses another counts its own time only
+    assert report["scopes"] == {"jit_rows_padded": pytest.approx({
+        "mv.update.scatter_add": 0.010, "mv.update.pad": 0.004,
+        trace_spans.NO_SCOPE: 0.006})}
+
+
+def test_trace_spans_command_prints_both_tables():
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "trace_spans.py"),
+         RECORDED], capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "bench:get_rows" in done.stdout
+    assert "jit_rows_padded" in done.stdout

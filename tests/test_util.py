@@ -303,20 +303,35 @@ class TestCheck:
 
 
 class TestTraceTo:
-    def test_trace_capture_writes_xplane(self, tmp_path):
+    def test_trace_capture_holds_the_monitors_span(self, tmp_path):
         # Whole-program xprof capture (the TPU-side tracing complement
-        # to the Dashboard counters, SURVEY.md section 5.1).
+        # to the Dashboard counters, SURVEY.md section 5.1): every
+        # monitor is an mv:<name> span in it, with no option to set.
         import glob
 
         import jax.numpy as jnp
+        from jax.profiler import ProfileData
 
         from multiverso_tpu.util import monitor, trace_to
         with trace_to(str(tmp_path)):
-            with monitor("TRACE_REGION",  # mvlint: ignore[metric-name]
-                         trace=True):
+            with monitor("TRACE_REGION"):  # mvlint: ignore[metric-name]
                 jnp.ones((32, 32)) @ jnp.ones((32, 32))
-        files = glob.glob(str(tmp_path) + "/**/*", recursive=True)
-        assert any("xplane" in f or "trace" in f for f in files), files
+        path, = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
+        names = {e.name for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for e in line.events}
+        assert "mv:TRACE_REGION" in names
+
+    def test_monitor_outside_a_capture_counts_and_times(self):
+        # "Tracing off" is "no profiler session": the same monitor, no
+        # trace, the Dashboard's count and milliseconds as ever.
+        from multiverso_tpu.util import monitor
+        Dashboard.reset()
+        with monitor("UNTRACED_REGION") as mon:  # mvlint: ignore[metric-name]
+            time.sleep(0.01)
+        assert mon is Dashboard.get("UNTRACED_REGION")
+        assert mon.count == 1 and mon.elapse >= 10.0
+        Dashboard.reset()
 
 
 class TestMonitorResetRegression:
