@@ -150,7 +150,9 @@ METRIC_NAMES: Dict[str, str] = {
                        "actor ([server], [worker], ...)",
     # -- device-corpus trainers (models/wordembedding/device_train.py) --
     "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
-                          "dispatch: _prep, pad, kept-count readback",
+                          "dispatch: _prep (subsample mask, one sort "
+                          "that carries tokens and sentence ids), pad, "
+                          "kept-count readback",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"

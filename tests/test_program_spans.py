@@ -298,13 +298,14 @@ def _lowered(fn, *args):
     return fn.lower(*args).as_text(debug_info=True)
 
 
-def test_prep_names_its_three_steps():
+def test_prep_names_its_two_steps():
     from multiverso_tpu.models.wordembedding import device_train
     flat = jnp.zeros(64, jnp.int32)
     text = _lowered(device_train._prep, flat, flat,
                     jnp.ones(4, jnp.float32), jax.random.PRNGKey(0))
-    for scope in ("mv.prep.mask", "mv.prep.argsort", "mv.prep.take"):
+    for scope in ("mv.prep.mask", "mv.prep.sort"):
         assert scope in text
+    assert "mv.prep.take" not in text and "mv.prep.argsort" not in text
     assert "module @jit__prep" in text   # the program's name is as it was
 
 
