@@ -99,21 +99,30 @@ def build_alias(probs: np.ndarray):
     probs = np.asarray(probs, np.float64)
     n = probs.size
     scaled = probs * (n / probs.sum())
-    prob = np.ones(n, np.float32)
-    alias = np.arange(n, dtype=np.int32)
-    # The pairing sweep is a Python O(n) loop: ~1 s per 1M entries, so
-    # ~20 s one-time at the reference's 21M-word vocab — accepted, since
-    # it buys O(1) in-jit sampling every batch (the device searchsorted
-    # it replaces cost ~26 ms per 160K draws, i.e. seconds per epoch).
-    small = list(np.flatnonzero(scaled < 1.0)[::-1])
-    large = list(np.flatnonzero(scaled >= 1.0)[::-1])
+    below = scaled < 1.0
+    # The pairing sweep is a sequential Python O(n) loop, run on lists
+    # of Python floats (the same IEEE doubles, so the same tables as on
+    # the arrays: tests/test_alias_build.py) because indexing a list
+    # costs a fraction of indexing an array: 8 s at the reference's
+    # 21M-word vocabulary where the arrays took 18, once a model —
+    # accepted, since it buys O(1) in-jit sampling every batch (the
+    # device searchsorted it replaces cost ~26 ms per 160K draws, i.e.
+    # seconds per epoch).
+    small = np.flatnonzero(below)[::-1].tolist()
+    large = np.flatnonzero(~below)[::-1].tolist()
+    scaled = scaled.tolist()
+    prob = [1.0] * n
+    alias = list(range(n))
     while small and large:
-        s, g = int(small.pop()), int(large.pop())
-        prob[s] = scaled[s]
+        s, g = small.pop(), large.pop()
+        left = scaled[s]
+        prob[s] = left
         alias[s] = g
-        scaled[g] = scaled[g] + scaled[s] - 1.0
-        (small if scaled[g] < 1.0 else large).append(g)
-    return prob, alias
+        left = scaled[g] + left - 1.0
+        scaled[g] = left
+        (small if left < 1.0 else large).append(g)
+    return (np.array(prob, np.float64).astype(np.float32),
+            np.array(alias, np.int32))
 
 
 def _alias_draw_np(prob: np.ndarray, alias: np.ndarray,
@@ -699,9 +708,10 @@ class PSWord2Vec(Word2Vec):
     def _init_embeddings(self) -> None:
         """No full local matrices: the input table is random-initialized
         SERVER-side (the reference's random-init server ctor,
-        ref: matrix_table.cpp:372-384), so no V x D array ever
-        materializes on a worker — at reference scale (21M x D) it could
-        not."""
+        ref: matrix_table.cpp:372-384), and there on the server's own
+        devices, each shard drawing its rows (MatrixServer): no V x D
+        array ever materializes on a worker or on any host — at
+        reference scale (21M x D, 10.75 GB at D = 128) it could not."""
         config = self.config
         vocab, dim = self.dictionary.size, config.embedding_size
         bound = 0.5 / dim

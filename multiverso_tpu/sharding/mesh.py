@@ -89,3 +89,49 @@ def zeros_sharded(shape: Tuple[int, ...], dtype, sharding: NamedSharding):
     The underlying jitted constructor is cached per (shape, dtype,
     sharding) so repeated table creation does not retrace."""
     return _zeros_fn(tuple(shape), np.dtype(dtype).name, sharding)()
+
+
+@functools.lru_cache(maxsize=None)
+def _uniform_fn(shape: Tuple[int, int], dtype, sharding: NamedSharding,
+                rows: int, cols: int):
+    def init(seed_words, stream, lo, hi):
+        with jax.named_scope("mv.table.init"):
+            key = jax.random.fold_in(
+                jax.random.wrap_key_data(seed_words, impl="threefry2x32"),
+                stream)
+            drawn = jax.random.uniform(key, shape, jax.numpy.float32, lo, hi)
+            # uniform() rounds lo + u * (hi - lo) to nearest, which can
+            # reach hi itself; the interval is half-open.
+            drawn = jax.numpy.minimum(drawn, jax.numpy.nextafter(hi, lo))
+            inside = ((jax.lax.broadcasted_iota(np.int32, shape, 0) < rows)
+                      & (jax.lax.broadcasted_iota(np.int32, shape, 1) < cols))
+            return jax.numpy.where(inside, drawn, 0).astype(dtype)
+
+    return jax.jit(init, out_shardings=sharding)
+
+
+def uniform_sharded(shape: Tuple[int, int], dtype, sharding: NamedSharding,
+                    rows: int, cols: int, lo: float, hi: float,
+                    seed: int, stream: int):
+    """A ``shape`` array laid out shard-wise whose ``[:rows, :cols]`` is
+    uniform in ``[lo, hi)`` and whose padding is zero, drawn on the
+    devices by one program: every shard writes its own rows, nothing is
+    drawn or staged on the host and nothing crosses devices.
+
+    The values are float32 draws cast to ``dtype``, a function of
+    ``(seed, stream)``, the element's position and the stored width
+    ``shape[1]`` only: the same on one device, on several, and on any
+    backend, whatever the row padding (tests/test_table_init.py holds
+    that). They are JAX's threefry stream, not numpy's. The jitted
+    program is cached per layout; seed, stream and bounds are its
+    arguments, so a new seed compiles nothing."""
+    words = np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF],
+                     np.uint32)
+    draw = _uniform_fn(tuple(shape), np.dtype(dtype).name, sharding,
+                       int(rows), int(cols))
+    # The partitionable threefry derives an element's bits from its flat
+    # index alone, so each shard computes its own rows with no
+    # collective. JAX's default; held on here (tracing and lowering both
+    # read it) whatever the process has set.
+    with jax.threefry_partitionable(True):
+        return draw(words, np.uint32(stream), np.float32(lo), np.float32(hi))

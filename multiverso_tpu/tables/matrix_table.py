@@ -1667,6 +1667,23 @@ class MatrixWorker(WorkerTable):
 
 
 class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
+    """One server's shard of a matrix table (ref: matrix_table.cpp):
+    ``my_rows`` consecutive rows, stored as one array row-sharded over
+    this process's devices (``local_mesh()``), rows padded to a multiple
+    of the devices and columns to the 128 lanes where that costs at most
+    4x.
+
+    ``random_init=(lo, hi)`` (the reference's random-init server ctor)
+    is drawn ON THE DEVICES by one program, each shard writing its own
+    rows (``meshlib.uniform_sharded``): float32 draws in ``[lo, hi)``
+    cast to ``dtype`` in ``[:my_rows, :num_col]``, zero in the padding
+    rows and lanes. The values are a function of ``(seed, server id)``
+    and the element's position only: the same table on one device, on
+    four and on the CPU, and never numpy's stream. No host array of the
+    table's size is made at any point, so a shard larger than the
+    host's free memory still starts (tests/test_table_init.py holds all
+    of this). Monitor ``TABLE_INIT``, scope ``mv.table.init``."""
+
     def __init__(self, num_row: int, num_col: int, dtype=np.float32,
                  is_sparse: bool = False, is_pipeline: bool = False,
                  zoo=None, updater_type: Optional[str] = None,
@@ -1706,21 +1723,19 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
             col_padded = ((self.num_col + 127) // 128) * 128
             if col_padded <= 4 * self.num_col:
                 self._col_store = col_padded
-        self._data = meshlib.zeros_sharded((padded, self._col_store),
-                                           self.dtype, self._sharding)
-        if random_init is not None:
+        if random_init is None:
+            self._data = meshlib.zeros_sharded(
+                (padded, self._col_store), self.dtype, self._sharding)
+        else:
             # Server ctor variant with uniform random init
-            # (ref: matrix_table.cpp:372-384).
+            # (ref: matrix_table.cpp:372-384), drawn on the devices.
             lo, hi = random_init
-            rng = np.random.default_rng(seed + sid)
-            host = np.zeros((padded, self._col_store), self.dtype)
-            host[:self.my_rows, :self.num_col] = rng.uniform(
-                lo, hi, (self.my_rows, self.num_col)).astype(self.dtype)
             # Table construction (CreateTable barrier) can overlap a
             # sibling rank's in-flight program in multi-zoo mode.
-            with device_lock.guard():
-                self._data = device_lock.settle(
-                    jax.device_put(host, self._sharding))
+            with monitor("TABLE_INIT"), device_lock.guard():
+                self._data = jax.block_until_ready(meshlib.uniform_sharded(
+                    (padded, self._col_store), self.dtype, self._sharding,
+                    self.my_rows, self.num_col, lo, hi, seed, sid))
         rule = None if updater_type is None \
             else create_rule(updater_type, dtype)
         num_workers = max(self._zoo.num_workers, 1)

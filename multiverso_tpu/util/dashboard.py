@@ -148,6 +148,10 @@ METRIC_NAMES: Dict[str, str] = {
     #    at the pop) --
     "MAILBOX_WAIT[*]": "enqueue-to-dequeue time of each message, per "
                        "actor ([server], [worker], ...)",
+    # -- server table construction (tables/matrix_table.py) --
+    "TABLE_INIT": "MatrixServer random_init: the uniform draw on the "
+                  "devices, one program a table, each shard its own "
+                  "rows (sharding/mesh.py uniform_sharded), to ready",
     # -- device-corpus trainers (models/wordembedding/device_train.py) --
     "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
                           "dispatch: _prep (subsample mask, one sort "

@@ -419,6 +419,42 @@ def test_trace_spans_cuts_a_gap_by_the_innermost_working_mv_span():
         trace_spans.NO_SCOPE: 0.006})}
 
 
+def test_trace_spans_reports_collectives_by_scope_and_each_chip():
+    """A table laid over two chips: the gather's all-reduce (both
+    halves of the asynchronous pair) is counted under its scope on the
+    busiest chip, beside the scope's total over the chips."""
+    from tools import trace_spans
+    ms = 1_000_000
+    gather = "jit(_lambda)/mv.table.gather/"
+
+    def chip(work_ms):
+        return {"modules": [("jit__lambda(123456)", 0, 40 * ms)],
+                "ops": [("%fusion.1", 0, work_ms * ms, gather + "gather:"),
+                        ("%all-reduce-start.2", 20 * ms, 22 * ms,
+                         gather + "all-reduce:"),
+                        ("%all-reduce-done.2", 30 * ms, 36 * ms,
+                         gather + "all-reduce:"),
+                        ("%copy.3", 36 * ms, 40 * ms, "")]}
+    devices = {"/device:TPU:0": chip(20), "/device:TPU:1": chip(10)}
+    spans = [("bench:window", 0, 50 * ms, 1)]
+    report = trace_spans.report(devices, spans)
+    assert report["gaps"]["busiest"] == "/device:TPU:0"
+    assert report["scopes"] == {"jit__lambda": pytest.approx({
+        "mv.table.gather": 0.020 + 0.010 + 2 * 0.008,
+        trace_spans.NO_SCOPE: 0.008})}
+    assert report["collectives"] == {"jit__lambda": pytest.approx({
+        "mv.table.gather": 0.008})}
+    assert report["chips"] == {
+        "/device:TPU:0": pytest.approx(
+            {"busy_s": 0.032, "collective_s": 0.008}),
+        "/device:TPU:1": pytest.approx(
+            {"busy_s": 0.022, "collective_s": 0.008})}
+    text = trace_spans.render(report)
+    assert "| `jit__lambda` | `mv.table.gather` | 0.0460 | 85.2 | 0.0080 |" \
+        in text
+    assert "| `/device:TPU:1` | 0.0220 | 0.0080 |" in text
+
+
 def test_trace_spans_command_prints_both_tables():
     done = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "trace_spans.py"),

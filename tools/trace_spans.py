@@ -4,7 +4,7 @@
     python3 tools/trace_spans.py <file.xplane.pb>
 
 for a trace kept with ``benchmark/run.py --trace 1 --keep-trace <dir>``
-or captured with ``multiverso_tpu.util.trace_to``. Two tables:
+or captured with ``multiverso_tpu.util.trace_to``. Three tables:
 
 (i) The idle time of the busiest chip by what the host was doing. The
     window and the chip's idle gaps are ``benchmark/lib/xplane.py``'s.
@@ -21,7 +21,16 @@ or captured with ``multiverso_tpu.util.trace_to``. Two tables:
     ``xplane.stem`` leaves of its name), over the whole trace and not
     only the window: an epoch's ``_prep`` runs before it. The scopes are
     the ``jax.named_scope`` names that start with ``mv.``; an operation
-    under several is counted under the innermost.
+    under several is counted under the innermost. Beside each total,
+    the part of it that collective operations took on the busiest chip
+    (``xplane.is_collective``: all-reduce, all-gather, collective-permute
+    and the rest, their ``-start`` and ``-done`` halves too): what a
+    scope's work costs in crossing chips on a table laid over several.
+
+(iii) With more than one chip, each chip's busy and collective seconds in
+    the window: which chips a block's programs keep busy (the corpus
+    and its `_prep` live on the first chip only, the tables' shards on
+    all).
 
 Where a scope's name lives (looked at on a v5e trace, PR 24): not on the
 events of the ``XLA Ops`` line but on their metadata, in the stat
@@ -264,36 +273,77 @@ def scope_of(path: str) -> str:
     return ours[-1] if ours else NO_SCOPE
 
 
+def _own_times(lines):
+    """``(program stem, scope, operation, seconds)`` for every operation
+    of one chip, over the whole trace. An operation that encloses others
+    (a loop) counts its own time only: each enclosed one also yields its
+    overlap, negated, under the encloser's names."""
+    modules = sorted(lines["modules"], key=lambda m: m[1])
+    begins = [m[1] for m in modules]
+    stack = []   # enclosing operations: (end, stem, scope, name)
+    for name, a, b, path in sorted(lines["ops"],
+                                   key=lambda op: (op[1], -op[2])):
+        at = bisect.bisect_right(begins, a) - 1
+        if at < 0 or modules[at][2] < a:
+            continue     # no program encloses it
+        while stack and stack[-1][0] <= a:
+            stack.pop()
+        if stack:
+            end, *parent = stack[-1]
+            yield (*parent, -(min(b, end) - a) * 1e-9)
+        mine = (xplane.stem(modules[at][0]), scope_of(path), name)
+        yield (*mine, (b - a) * 1e-9)
+        stack.append((b, *mine))
+
+
+def _add(out, stem, scope, seconds):
+    slot = out.setdefault(stem, {})
+    slot[scope] = slot.get(scope, 0.0) + seconds
+
+
 def device_by_scope(devices) -> dict:
-    """``{program stem: {scope: seconds}}`` summed over the chips. An
-    operation that encloses others (a loop) counts its own time only."""
+    """``{program stem: {scope: seconds}}`` summed over the chips."""
     out = {}
     for lines in devices.values():
-        modules = sorted(lines["modules"], key=lambda m: m[1])
-        begins = [m[1] for m in modules]
-        stack = []   # enclosing operations: [end, scopes slot, scope]
-        for name, a, b, path in sorted(lines["ops"],
-                                       key=lambda op: (op[1], -op[2])):
-            at = bisect.bisect_right(begins, a) - 1
-            if at < 0 or modules[at][2] < a:
-                continue     # no program encloses it
-            slot = out.setdefault(xplane.stem(modules[at][0]), {})
-            scope = scope_of(path)
-            while stack and stack[-1][0] <= a:
-                stack.pop()
-            if stack:
-                parent = stack[-1]
-                parent[1][parent[2]] -= (min(b, parent[0]) - a) * 1e-9
-            slot[scope] = slot.get(scope, 0.0) + (b - a) * 1e-9
-            stack.append([b, slot, scope])
+        for stem, scope, _, seconds in _own_times(lines):
+            _add(out, stem, scope, seconds)
     return out
 
 
-# -- both ---------------------------------------------------------------------
+def collectives_by_scope(lines) -> dict:
+    """``{program stem: {scope: seconds}}`` of one chip's collective
+    operations only."""
+    out = {}
+    for stem, scope, name, seconds in _own_times(lines):
+        if xplane.is_collective(name):
+            _add(out, stem, scope, seconds)
+    return out
+
+
+# -- (iii) each chip ------------------------------------------------------------
+
+def by_chip(devices, spans) -> dict:
+    """``{plane: {"busy_s", "collective_s"}}`` inside the window."""
+    lo, hi = _window(devices, spans)
+    out = {}
+    for plane, lines in devices.items():
+        collective = xplane._union(
+            (a, b) for _, a, b in xplane._clip(
+                [op[:3] for op in lines["ops"]
+                 if xplane.is_collective(op[0])], lo, hi))
+        out[plane] = {
+            "busy_s": xplane._length(_busy(lines, lo, hi)) * 1e-9,
+            "collective_s": xplane._length(collective) * 1e-9}
+    return out
+
+
+# -- all three ----------------------------------------------------------------
 
 def report(devices, spans) -> dict:
-    return {"gaps": idle_by_span(devices, spans),
-            "scopes": device_by_scope(devices)}
+    gaps = idle_by_span(devices, spans)
+    return {"gaps": gaps, "scopes": device_by_scope(devices),
+            "collectives": collectives_by_scope(devices[gaps["busiest"]]),
+            "chips": by_chip(devices, spans)}
 
 
 def read(path: str) -> dict:
@@ -312,15 +362,24 @@ def render(found: dict) -> str:
     for name, s in sorted(gaps["totals"].items(), key=lambda x: -x[1]):
         lines.append(f"| `{name}` | {s:.4f} | "
                      f"{100 * s / gaps['idle_s']:.1f} |")
-    lines += ["", "| program | scope | device s | % of program |",
-              "| --- | --- | --- | --- |"]
+    lines += ["", "| program | scope | device s | % of program | "
+              "collectives on the busiest chip, s |",
+              "| --- | --- | --- | --- | --- |"]
     programs = sorted(found["scopes"].items(),
                       key=lambda x: -sum(x[1].values()))
     for stem, scopes in programs:
         whole = sum(scopes.values())
         for scope, s in sorted(scopes.items(), key=lambda x: -x[1]):
+            crossing = found["collectives"].get(stem, {}).get(scope, 0.0)
             lines.append(f"| `{stem}` | `{scope}` | {s:.4f} | "
-                         f"{100 * s / whole if whole else 0:.1f} |")
+                         f"{100 * s / whole if whole else 0:.1f} | "
+                         f"{crossing:.4f} |")
+    if len(found["chips"]) > 1:
+        lines += ["", "| chip | busy s in the window | collectives s |",
+                  "| --- | --- | --- |"]
+        for plane, chip in sorted(found["chips"].items()):
+            lines.append(f"| `{plane}` | {chip['busy_s']:.4f} | "
+                         f"{chip['collective_s']:.4f} |")
     return "\n".join(lines)
 
 
