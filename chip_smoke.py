@@ -3,7 +3,7 @@
 
     python3 chip_smoke.py        (from the root of a checkout; no arguments)
 
-One process, which holds the chip for all three arms:
+One process, which holds the chip for all four arms:
 
 1. PS trainer: word2vec SGNS trained through the parameter server
    (Dictionary, TokenizedCorpus, mv.init, PSWord2Vec,
@@ -13,6 +13,8 @@ One process, which holds the chip for all three arms:
 3. Table API: a server that answers a few requests. Array, dense (sgd,
    adagrad), sparse and KV tables; every reply compared with a numpy
    shadow.
+4. Scatter-add: the rows programs' sorted-runs kernel against XLA's
+   scatter, on one device and row-sharded over all of them.
 
 All at the width the repo benchmarks: a little over 1,000,000 vocabulary
 rows x dim 128, window 5, 5 negatives, neg_block 8. The corpus is made
@@ -415,6 +417,61 @@ def refused_request(table, dim: int) -> str:
                        "answered instead of refused")
 
 
+def scatter_arm(rows: int, dim: int, platform: str) -> dict:
+    """The rows programs' scatter-add against XLA's scatter on the same
+    table, ids and deltas: on the first device alone, and row-sharded
+    over every device where there are several. On a TPU (and a row of
+    whole 128-lane tiles) the engine takes the sorted-runs kernel
+    (updater/row_scatter.py), which this holds to XLA's result: bit for
+    bit where the ids are distinct, to rounding where they repeat."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.sharding import mesh as meshlib
+    from multiverso_tpu.updater import UpdateEngine
+    from multiverso_tpu.updater.rules import fast_rows
+
+    rng = np.random.default_rng(28)
+    k = 40_000
+    n_devices = len(jax.local_devices())
+    padded = meshlib.padded_size(rows, n_devices)
+    distinct = rng.permutation(padded + k // 8)[:k].astype(np.int32)
+    repeated = rng.integers(0, padded, (2, k // 2)).astype(np.int32)
+    repeated[0, :5000] = padded - 1      # one long run, on the last shard
+    took = {}
+    for n in sorted({1, n_devices}):
+        mesh = meshlib.local_mesh(n)
+        sharding = meshlib.row_sharded(mesh)
+        fast = fast_rows((padded, dim), np.float32, k, mesh)
+        check(fast == (platform == "tpu" and dim % 128 == 0),
+              f"the path on {n} device(s) follows the platform and the row")
+        engine = UpdateEngine(None, (padded, dim), np.float32, 1, sharding)
+        plain = jax.jit(lambda t, i, d: t.at[i].add(d, mode="drop"),
+                        out_shardings=sharding)
+        for name, ids, exact in (("distinct", distinct, True),
+                                 ("repeated", repeated, False)):
+            table = jax.device_put(rng.normal(size=(padded, dim)).astype(
+                np.float32), sharding)
+            ids = jax.device_put(ids, meshlib.replicated(mesh))
+            delta = jax.device_put(rng.normal(size=ids.shape + (dim,)).astype(
+                np.float32), meshlib.replicated(mesh))
+            want = np.asarray(plain(table, ids, delta))
+            got = engine.apply_rows(table, ids, delta)
+            check_on_backend(f"scatter-add result ({n} devices)", got,
+                             platform)
+            check(len(devices_of(got)) == n, "the table kept its devices")
+            got = np.asarray(got)
+            if exact:
+                check(np.array_equal(got, want),
+                      f"{name} ids on {n} device(s): bit-equal to XLA's")
+            else:
+                check(np.allclose(got, want, rtol=1e-4, atol=1e-4),
+                      f"{name} ids on {n} device(s): equal to XLA's to "
+                      "rounding")
+        took[f"{n}_devices"] = "sorted_runs_kernel" if fast else "xla_scatter"
+    return {"path": took}
+
+
 def peak_memory():
     import jax
     peaks = {str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")
@@ -423,7 +480,7 @@ def peak_memory():
 
 
 def run_arms(workdir: str, rows: int, dim: int, sentences: int) -> dict:
-    """The three arms on whatever backend JAX has (main() has already
+    """The four arms on whatever backend JAX has (main() has already
     refused anything but a TPU; tests call this at a toy size on the
     CPU). Raises on the first failed check."""
     import jax
@@ -440,7 +497,9 @@ def run_arms(workdir: str, rows: int, dim: int, sentences: int) -> dict:
                       ("local", lambda: local_arm(corpus, rows, dim, builds,
                                                   platform)),
                       ("tables", lambda: table_arm(rows, dim, builds,
-                                                   platform))):
+                                                   platform)),
+                      ("scatter", lambda: scatter_arm(rows, dim,
+                                                      platform))):
         with deadline(ARM_DEADLINE_S):
             report[name] = arm()
         report[name]["peak_bytes_in_use"] = peak_memory()

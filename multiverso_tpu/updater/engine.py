@@ -9,6 +9,16 @@ HBM — the TPU equivalent of the reference server's in-place OpenMP loops
 Row-sparse calls are padded to power-of-two bucket sizes so XLA compiles a
 small, bounded set of scatter programs instead of one per distinct row
 count (the host-variable-shape hazard called out in SURVEY.md §7).
+
+The rows programs end in the rule's scatter-add, which takes one of two
+forms by what is static in the call (``rules.fast_rows``; no flag): on a
+TPU, for a float32 table whose stored row is whole 128-lane tiles and
+``rules.FAST_MIN_IDS`` ids or more, the ids are sorted, equal ids'
+deltas summed in float32 in the order of their positions, and every
+row read and written once by ``row_scatter.py``'s kernel, on a
+row-sharded table under ``shard_map`` with each device visiting its own
+rows; otherwise XLA's scatter applies the ids as they come. Every
+dispatch counts ``UPDATE_ROWS_FAST`` or ``UPDATE_ROWS_XLA``.
 """
 
 from __future__ import annotations
@@ -21,7 +31,8 @@ import numpy as np
 
 from ..sharding import mesh as meshlib
 from .options import AddOption
-from .rules import UpdaterRule, create_rule
+from ..util.dashboard import count
+from .rules import UpdaterRule, create_rule, fast_rows
 
 _DEFAULT_HYP = AddOption().hyper_array()
 
@@ -48,6 +59,12 @@ class UpdateEngine:
             # per-worker leading axis (adagrad) is replicated.
             state = jax.device_put(state, _state_sharding(state, sharding))
         self._state = state
+        # The rows form's scatter-add picks its path by the table's
+        # shape, dtype, mesh and the request's id count (rules.py
+        # fast_rows): the sorted-runs kernel on a TPU, under shard_map
+        # where the mesh has several devices, XLA's scatter otherwise.
+        self._mesh = getattr(sharding, "mesh", None)
+        self.rule.mesh = self._mesh
 
         # Table storage is padded to the mesh shard count (uneven shardings
         # are not device_put-able) and possibly to the 128-lane tile width
@@ -131,11 +148,19 @@ class UpdateEngine:
                   "(default/sgd): duplicate ids must sum")
         else:
             row_ids, delta = pad_rows(row_ids, delta, self.shape[0])
+        self._count_path(row_ids)
         rows_fn = self._rows if bounds is None \
             else self._bounded_rows_fn(bounds)
         data, self._state = rows_fn(data, self._state, row_ids, delta,
                                     hyp, worker_id)
         return data
+
+    def _count_path(self, row_ids) -> None:
+        """One count a dispatch, by the path its shapes chose."""
+        n_ids = int(np.prod(np.shape(row_ids)))
+        count("UPDATE_ROWS_FAST" if fast_rows(
+            self.shape, self.dtype, n_ids, self._mesh)
+            else "UPDATE_ROWS_XLA")
 
     def _bounded_rows_fn(self, bounds):
         fn = self._rows_bounded.get(bounds)
@@ -181,6 +206,7 @@ class UpdateEngine:
         CHECK(k == bucket_size(k),
               "apply_rows_gather ids must be bucket-padded "
               "(pad_ids on the host, a pad_ids-built device mirror)")
+        self._count_path(row_ids)
         fn = self._rows_gather.get(n_col)
         if fn is None:
             rule_rows = self.rule.rows

@@ -1,0 +1,201 @@
+"""Row scatter-add that writes every table row once: sorted runs, the
+duplicates of a row summed before the table is touched, the rows
+read-modify-written by DMAs in flight.
+
+XLA's TPU scatter walks the ids one after another, each row's
+read-modify-write waiting on the last, whatever the ids are and whatever
+it is told about them (73-78 ns a 128-lane float32 row on a v5e,
+tools/scatter_bench.py; PERF.md section 6, PR 28). Here the ids are
+sorted first with their positions as payload, so that equal ids are
+adjacent (a *run*) and ids outside the table's rows sort to the end and
+are never visited. The sorted positions are then taken a chunk at a
+time: XLA gathers the chunk's delta rows into sorted order, and a Pallas
+kernel (row_scatter_kernel.py) walks them a tile at a time: it reads
+the table row of every run into VMEM by a DMA of its own, sums a run's
+delta rows in sorted-position order in float32, adds the sum to the
+table row and writes the row back. No table row depends on another, so
+a tile's reads, and the writes of the tile before it, are all in flight
+together. A run of one row is ``table + delta``, bit for bit what XLA's
+scatter-add gives.
+
+The table is aliased to the kernel's output and stays in HBM. The
+temporaries are the sorted ids and positions (8 bytes an id) and one
+chunk of delta rows (``CHUNK`` x columns), whatever the id count.
+
+On a row-sharded table the same code runs under ``shard_map`` over the
+table's mesh with the ids and deltas replicated: every chip sorts the
+ids with the rows it does not own keyed out of range, so its own runs
+come first and are all it visits. No collective.
+"""
+
+from __future__ import annotations
+
+import functools
+import hashlib
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax import export
+from jax.sharding import PartitionSpec
+
+from ..util import log
+
+#: Sorted positions a grid step handles: its delta rows, table rows and
+#: results are VMEM tiles of this many rows (4 x TILE x columns x 4 B).
+TILE = 1024
+#: Sorted positions whose delta rows are gathered at a time: the Add's
+#: temporaries are CHUNK x columns x 4 B (8 MB at 128 columns).
+CHUNK = 16 * TILE
+#: ``row * 4 + head * 2 + end`` has to fit an int32.
+MAX_ROWS = 1 << 29
+_FAR = jnp.iinfo(jnp.int32).max
+
+
+def sorted_runs(row_ids, lo, num_rows):
+    """Sort flat ``row_ids`` and mark the runs of equal ids.
+
+    Ids are global; the rows ``[lo, lo + num_rows)`` are the caller's
+    (``lo`` may be traced: a shard's first row). Returns ``(code, perm,
+    n_live)``, the first two of the ids' length padded to whole tiles
+    (whole chunks past one chunk): ``perm[i]`` is
+    the position in ``row_ids`` of the i-th smallest id (ties by
+    position, so the order is a function of the ids alone),
+    ``code[i] = (id - lo) * 4 + 2 * head + end`` where ``head``/``end``
+    say whether i is the first/last position of its run, and -1 at the
+    positions of ids outside the rows, which all sort behind the
+    ``n_live`` positions of the ids inside."""
+    k = row_ids.shape[0]
+    ids = row_ids.astype(jnp.int32)
+    local = ids - lo
+    inside = (local >= 0) & (local < num_rows)
+    key = jnp.where(inside, local, _FAR)
+    pos = lax.iota(jnp.int32, k)
+    key, perm = lax.sort((key, pos), num_keys=2, is_stable=False)
+    step = key[1:] != key[:-1]
+    edge = jnp.ones((1,), bool)
+    head = jnp.concatenate([edge, step])
+    end = jnp.concatenate([step, edge])
+    live = key != _FAR
+    code = jnp.where(live, key * 4 + head * 2 + end, -1)
+    pad = -k % (CHUNK if k > CHUNK else TILE)
+    if pad:
+        code = jnp.concatenate([code, jnp.full((pad,), -1, jnp.int32)])
+        perm = jnp.concatenate([perm, jnp.zeros((pad,), jnp.int32)])
+    return code, perm, jnp.sum(live, dtype=jnp.int32)
+
+
+def _artifact(cache_dir: str, *key) -> str:
+    """Where a built chunk program is kept: in a directory of its own
+    in the process' compile cache, named by everything the program is
+    a function of, this package's kernel source included."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    digest = hashlib.sha256(repr((key, jax.__version__)).encode())
+    for name in ("row_scatter.py", "row_scatter_kernel.py"):
+        with open(os.path.join(here, name), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(cache_dir, "mv_row_scatter",
+                        digest.hexdigest()[:32] + ".jaxexport")
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_program(shape, dtype, chunk: int, cache_dir: str):
+    """``(table, code, rows, carry) -> (table, carry)``: the kernel on
+    one chunk of a ``shape`` table, as an exported program.
+
+    Tracing and lowering the kernel is Python work of a second or two
+    in every process that builds a rows program, beside the import of
+    Pallas; the lowered program is a few kilobytes. So where the
+    process has a compile cache (``cache_dir``, "" when not), the first
+    process writes the program there and the later ones read it back
+    and import no Pallas."""
+    path = cache_dir and _artifact(cache_dir, shape, dtype, chunk)
+    if path and os.path.exists(path):
+        try:
+            with open(path, "rb") as f:
+                return export.deserialize(bytearray(f.read())).call
+        except Exception as e:  # a torn or foreign file: build it again
+            log.error("row_scatter: %s unreadable (%r); rebuilding", path, e)
+    from . import row_scatter_kernel
+
+    def on_chunk(table, code, rows, carry):
+        with jax.named_scope("mv.update.scatter_add"):
+            return row_scatter_kernel.rmw_chunk(table, code, rows, carry,
+                                                False)
+
+    shaped = jax.ShapeDtypeStruct
+    program = export.export(jax.jit(on_chunk), platforms=["tpu"])(
+        shaped(shape, dtype), shaped((chunk,), jnp.int32),
+        shaped((chunk, shape[1]), dtype), shaped((8, shape[1]), dtype))
+    if path:
+        scratch = f"{path}.{os.getpid()}.{threading.get_ident()}"
+        try:
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            with open(scratch, "wb") as f:
+                f.write(program.serialize())
+            os.replace(scratch, path)
+        except OSError as e:  # a cache that cannot be written is no cache
+            log.error("row_scatter: %s not kept (%r)", path, e)
+    return program.call
+
+
+def _rmw(table, code, perm, n_live, delta, interpret):
+    """The live positions a chunk at a time: a chunk's delta rows are
+    gathered into sorted order (XLA's gather moves a row in a tenth of
+    the time a DMA of the kernel's own would take to issue), so the
+    temporaries are a chunk's rows whatever the id count, and the
+    chunks behind the last live position are not visited at all."""
+    chunk = min(CHUNK, code.shape[0])
+    if interpret:
+        from . import row_scatter_kernel
+        on_chunk = functools.partial(row_scatter_kernel.rmw_chunk,
+                                     interpret=True)
+    else:
+        on_chunk = _chunk_program(
+            table.shape, table.dtype.name, chunk,
+            jax.config.jax_compilation_cache_dir or "")
+
+    def one(i, state):
+        table, carry = state
+        at = i * chunk
+        rows = delta[lax.dynamic_slice(perm, (at,), (chunk,))]
+        return on_chunk(table, lax.dynamic_slice(code, (at,), (chunk,)),
+                        rows, carry)
+
+    carry = jnp.zeros((8, table.shape[1]), table.dtype)
+    table, _ = lax.fori_loop(0, -(-n_live // chunk), one, (table, carry))
+    return table
+
+
+def _scatter_add_runs(table, row_ids, delta, lo, interpret):
+    with jax.named_scope("mv.update.dedup"):
+        code, perm, n_live = sorted_runs(row_ids, lo, table.shape[0])
+    with jax.named_scope("mv.update.scatter_add"):
+        return _rmw(table, code, perm, n_live, delta, interpret)
+
+
+def scatter_add(table, row_ids, delta, mesh=None, interpret=False):
+    """``table[row_ids] += delta``, ids outside the table dropped:
+    ``table`` is ``[rows, columns]`` float32 with ``columns`` a multiple
+    of 128 and fewer than ``MAX_ROWS`` rows, ``row_ids`` flat int32,
+    ``delta`` one table-width row an id. With a ``mesh`` of more than
+    one device the table is row-sharded over its one axis and each
+    device takes the replicated ids and deltas and visits its own rows'
+    runs. ``interpret`` runs the kernel in Pallas' interpreter (the CPU
+    tests)."""
+    if mesh is None or mesh.size == 1:
+        return _scatter_add_runs(table, row_ids, delta, 0, interpret)
+    (axis,) = mesh.axis_names
+    shard_rows = table.shape[0] // mesh.size
+
+    def own_rows(shard, row_ids, delta):
+        lo = lax.axis_index(axis) * shard_rows
+        return _scatter_add_runs(shard, row_ids, delta, lo, interpret)
+
+    rows = PartitionSpec(axis, None)
+    return jax.shard_map(
+        own_rows, mesh=mesh, in_specs=(rows, PartitionSpec(),
+                                       PartitionSpec()),
+        out_specs=rows, check_vma=False)(table, row_ids, delta)

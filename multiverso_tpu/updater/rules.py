@@ -5,7 +5,10 @@ TPU-native re-design of the reference's updater family
 reference applies per-element OpenMP loops on the server thread; here each
 rule is a pure function over whole (sharded) arrays, jit-compiled once per
 table with donated buffers so updates happen in-place in HBM, and a `rows`
-variant using XLA scatter for row-sparse traffic.
+variant for row-sparse traffic whose last step is a row scatter-add
+(``_scatter_add``: the sorted-runs kernel of row_scatter.py on a TPU for
+``FAST_MIN_IDS`` ids or more, XLA's scatter otherwise; ``fast_rows`` is
+the rule, read from shapes, dtype and the table's mesh alone).
 
 Hyperparameters arrive as a traced float32[4] array ``hyp`` =
 [momentum, learning_rate, rho, lambda] (from ``AddOption.hyper_array``) so
@@ -31,7 +34,9 @@ Formulas (and deviations):
   this updater permanently disabled; here it works).
 
 Duplicate row indices within one row-sparse Add compound correctly for
-default/sgd (scatter-add); for momentum/adagrad/dcasgd the state update
+default/sgd (scatter-add: XLA's form adds them one after another, the
+sorted-runs form adds their float32 sum, taken in the order of their
+positions in the request, once); for momentum/adagrad/dcasgd the state update
 applies once per unique row (the reference's sequential loop compounds
 instead — callers there dedupe rows per block, e.g. WordEmbedding's
 DataBlock).
@@ -45,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import row_scatter
 from ..util import log
 from ..util.configure import define_string, get_flag
 
@@ -62,12 +68,53 @@ def _safe_lr(lr):
     return jnp.maximum(lr, jnp.asarray(1e-12, lr.dtype))
 
 
-def _scatter_add(data, row_ids, step):
+#: Static id count from which the sorted-runs kernel beats XLA's
+#: scatter on a v5e (tools/scatter_bench.py --small; PERF.md section 6,
+#: PR 28): under it the sort costs more than the serial rows it saves.
+FAST_MIN_IDS = 2048
+
+
+def _platform(mesh) -> str:
+    """Where the table lives: its mesh's devices, or with no mesh the
+    default backend's."""
+    device = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    return device.platform
+
+
+def fast_rows(shape, dtype, n_ids: int, mesh=None) -> bool:
+    """The path a row Add of ``n_ids`` ids on a table of this stored
+    shape takes, from what is static: the sorted-runs kernel
+    (row_scatter.py) when the table is on a TPU, float32, two
+    dimensional with a row of whole 128-lane tiles, and the id count is
+    at or above ``FAST_MIN_IDS``; XLA's scatter otherwise. ``mesh`` is
+    the table's."""
+    return (_platform(mesh) == "tpu" and np.dtype(dtype) == np.float32
+            and len(shape) == 2 and shape[1] % 128 == 0
+            and shape[0] < row_scatter.MAX_ROWS
+            and n_ids >= FAST_MIN_IDS)
+
+
+def _scatter_add(data, row_ids, step, mesh=None):
     """``data[row_ids] += step``, out-of-range ids dropped: the last
     operation of every rule's rows form, under one scope name so that a
-    device trace shows it apart from the rule's arithmetic."""
-    with jax.named_scope("mv.update.scatter_add"):
-        return data.at[row_ids].add(step, mode="drop")
+    device trace shows it apart from the rule's arithmetic.
+
+    One algorithm in two forms, chosen by ``fast_rows``. Small id
+    counts, other dtypes and other backends take XLA's scatter, which
+    applies the ids as they come. Otherwise the ids are sorted (scope
+    ``mv.update.dedup``), the deltas of equal ids are summed in float32
+    in the order of their positions in ``row_ids``, and each table row
+    is read and written once: a row named once gets ``row + delta`` bit
+    for bit as XLA's scatter gives it; a row named n times gets
+    ``row + (d1 + ... + dn)`` where XLA's gives ``((row + d1) + ...)``."""
+    n_ids = int(np.prod(row_ids.shape))
+    if not fast_rows(data.shape, data.dtype, n_ids, mesh):
+        with jax.named_scope("mv.update.scatter_add"):
+            return data.at[row_ids].add(step, mode="drop")
+    ids = row_ids.reshape(n_ids).astype(jnp.int32)
+    ids = jnp.where(ids < 0, ids + data.shape[0], ids)  # as .at[] wraps
+    step = step.reshape(n_ids, data.shape[1]).astype(data.dtype)
+    return row_scatter.scatter_add(data, ids, step, mesh)
 
 
 class UpdaterRule:
@@ -79,6 +126,9 @@ class UpdaterRule:
     # consults this through create_rule so it cannot drift from the
     # engine's state handling.
     stateless = True
+    # The table's mesh, set by the engine that binds the rule to a
+    # table: the rows form's scatter-add picks its path by it.
+    mesh = None
 
     def init_state(self, shape, dtype, num_workers: int) -> Any:
         return None
@@ -100,7 +150,7 @@ class DefaultRule(UpdaterRule):
         return data + delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return _scatter_add(data, row_ids, delta), state
+        return _scatter_add(data, row_ids, delta, self.mesh), state
 
 
 class SGDRule(UpdaterRule):
@@ -110,7 +160,7 @@ class SGDRule(UpdaterRule):
         return data - delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return _scatter_add(data, row_ids, -delta), state
+        return _scatter_add(data, row_ids, -delta, self.mesh), state
 
 
 class MomentumRule(UpdaterRule):
@@ -130,7 +180,7 @@ class MomentumRule(UpdaterRule):
         smooth_rows = (m * state.at[row_ids].get(mode="fill", fill_value=0)
                        + (1 - m) * delta)
         state = state.at[row_ids].set(smooth_rows, mode="drop")
-        return _scatter_add(data, row_ids, -smooth_rows), state
+        return _scatter_add(data, row_ids, -smooth_rows, self.mesh), state
 
 
 class AdaGradRule(UpdaterRule):
@@ -156,7 +206,7 @@ class AdaGradRule(UpdaterRule):
         g_sqr = g_rows + grad * grad
         step = rho * grad * jax.lax.rsqrt(g_sqr + ADAGRAD_EPS)
         state = state.at[worker_id, row_ids].set(g_sqr, mode="drop")
-        return _scatter_add(data, row_ids, -step), state
+        return _scatter_add(data, row_ids, -step, self.mesh), state
 
 
 class DCASGDRule(UpdaterRule):
@@ -200,7 +250,7 @@ class DCASGDRule(UpdaterRule):
         # same pre-update rows for each duplicate, like momentum/adagrad's
         # once-per-unique-row state). The backup records one step for a
         # duplicated row — second-order staleness error, documented.
-        data = _scatter_add(data, row_ids, -step)
+        data = _scatter_add(data, row_ids, -step, self.mesh)
         state = state.at[worker_id, row_ids].set(rows_now - step,
                                                  mode="drop")
         return data, state

@@ -50,6 +50,9 @@ def test_smoke_arms_at_toy_size(tmp_path):
     assert report["ps"]["built_after_warmup"] == 0
     assert report["tables"]["built_after_warmup"] == 0
     assert report["local"]["placement"]["trainer_embeddings"]
+    # Off the TPU the rows programs keep XLA's scatter, on 1 and 8 devices.
+    assert set(report["scatter"]["path"].values()) == {"xla_scatter"}
+    assert len(report["scatter"]["path"]) == 2
 
 
 # Prints [the directory enable() reports, how many directory settings it

@@ -4,6 +4,7 @@ profiler capture, and the monitors that say where a request's time goes
 (TABLE_WAIT, MAILBOX_WAIT[*], WORKER_REPLY_GET, BLOB_D2H(+_BYTES),
 CLIENT_PLACE_ROWS, TRAINER_EPOCH_PREP) move by what one request does."""
 
+import functools
 import glob
 import inspect
 import os
@@ -324,6 +325,31 @@ def test_update_programs_name_their_steps_and_keep_their_names():
                      jnp.zeros((100, 50), jnp.float32), hyp, wid)
     assert "mv.update.pad" in dense and "mv.update.rule" in dense
     assert "module @jit_dense_padded" in dense
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_the_sorted_runs_form_keeps_the_name_and_splits_its_scopes(
+        monkeypatch, devices):
+    """On a TPU the rows program sorts the ids under ``mv.update.dedup``
+    and writes the rows under ``mv.update.scatter_add``, in the same
+    ``jit_rows_padded`` (benchmark/lib/tableprograms.py keys on the
+    stem), on one device and on a row-sharded table."""
+    from multiverso_tpu.sharding import mesh as meshlib
+    from multiverso_tpu.updater import row_scatter, rules
+    from multiverso_tpu.updater.engine import UpdateEngine
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    monkeypatch.setattr(rules.row_scatter, "scatter_add", functools.partial(
+        row_scatter.scatter_add, interpret=True))
+    sharding = meshlib.row_sharded(meshlib.local_mesh(devices))
+    engine = UpdateEngine(None, (4096, 128), np.float32, 1, sharding)
+    data = jax.device_put(jnp.zeros((4096, 128), jnp.float32), sharding)
+    text = _lowered(engine._rows, data, None, jnp.zeros((2, 1024), jnp.int32),
+                    jnp.zeros((2, 1024, 50), jnp.float32),
+                    np.zeros(4, np.float32), np.int32(0))
+    for scope in ("mv.update.pad", "mv.update.rule", "mv.update.dedup",
+                  "mv.update.scatter_add"):
+        assert scope in text
+    assert "module @jit_rows_padded" in text
 
 
 def test_the_gather_is_scoped_and_keeps_its_lambdas_name():

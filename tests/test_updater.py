@@ -212,3 +212,281 @@ class TestDCASGD:
 
     def test_momentum_sgd_alias(self):
         assert create_rule("momentum_sgd").name == "momentum"
+
+
+# -- the sorted-runs row scatter-add (updater/row_scatter.py) ----------
+#
+# On a TPU the rows form's scatter-add sorts the ids, sums the deltas of
+# equal ids and writes every row once (rules.fast_rows picks the path).
+# Here the same kernel runs in Pallas' interpreter on the CPU and is
+# held to XLA's scatter: bit for bit where every id is named once,
+# within float32 rounding where ids repeat (the sum's order differs).
+
+import functools  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from multiverso_tpu.updater import row_scatter, rules  # noqa: E402
+from multiverso_tpu.util import dashboard  # noqa: E402
+
+ROWS, COLS = 3000, 128
+TILE = row_scatter.TILE
+
+
+def _run_across_a_tile(rng):
+    """A run of 100 equal ids whose sorted positions straddle the
+    kernel's first tile boundary."""
+    low = rng.permutation(1500)[:TILE - 50]
+    high = 1600 + rng.permutation(1000)[:400]
+    return rng.permutation(np.concatenate([low, np.full(100, 1550), high]))
+
+
+RUNS_CASES = {
+    # name: (ids from a generator, bit-equal expected)
+    "duplicates": (lambda rng: rng.integers(0, 400, 1500), False),
+    "distinct_unsorted": (lambda rng: rng.permutation(ROWS)[:1500], True),
+    "distinct_sorted": (lambda rng: np.sort(rng.permutation(ROWS)[:1500]),
+                        True),
+    "rank_2": (lambda rng: rng.integers(0, 200, (3, 500)), False),
+    "rank_2_distinct": (lambda rng: rng.permutation(ROWS)[:1500].reshape(
+        5, 300), True),
+    "out_of_range_dropped": (lambda rng: rng.permutation(2 * ROWS)[:2000],
+                             True),
+    "negative_ids_wrap_once": (lambda rng: rng.permutation(ROWS)[:1200]
+                               - ROWS, True),
+    "far_negative_ids_dropped": (lambda rng: np.concatenate(
+        [rng.permutation(ROWS)[:1000], np.full(30, -ROWS - 7)]), True),
+    "all_out_of_range": (lambda rng: np.full(1100, ROWS), True),
+    "run_across_a_tile": (_run_across_a_tile, False),
+    "one_long_run": (lambda rng: np.full(2 * TILE + 5, 17), False),
+    # 19,000 sorted positions are two chunks of the kernel; six draws a
+    # row, so a run lies across the chunks' boundary (the carry).
+    "more_than_a_chunk": (lambda rng: rng.integers(
+        0, ROWS, row_scatter.CHUNK + 2616), False),
+    "fewer_ids_than_a_tile": (lambda rng: rng.integers(0, 50, 40), False),
+}
+
+
+def _table_and_delta(rng, ids):
+    table = rng.normal(size=(ROWS, COLS)).astype(np.float32)
+    delta = rng.normal(size=ids.shape + (COLS,)).astype(np.float32)
+    return table, delta
+
+
+def _interpreted(table, ids, delta, mesh=None):
+    """rules._scatter_add's fast form with the kernel interpreted."""
+    flat = jnp.asarray(ids, jnp.int32).reshape(-1)
+    flat = jnp.where(flat < 0, flat + table.shape[0], flat)
+    return row_scatter.scatter_add(
+        jnp.asarray(table), flat, jnp.asarray(delta).reshape(-1, COLS),
+        mesh, interpret=True)
+
+
+@pytest.mark.parametrize("case", sorted(RUNS_CASES))
+def test_sorted_runs_scatter_add_equals_xlas(case):
+    make_ids, exact = RUNS_CASES[case]
+    rng = np.random.default_rng(28)
+    ids = np.asarray(make_ids(rng), np.int32)
+    table, delta = _table_and_delta(rng, ids)
+    want = np.asarray(jnp.asarray(table).at[ids].add(delta, mode="drop"))
+    got = np.asarray(jax.jit(_interpreted)(table, ids, delta))
+    if case == "more_than_a_chunk":
+        at = np.sort(ids)
+        assert at[row_scatter.CHUNK - 1] == at[row_scatter.CHUNK]
+    if exact:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        once = np.setdiff1d(np.arange(ROWS), ids[ids >= 0])
+        np.testing.assert_array_equal(got[once], want[once])
+
+
+def test_a_run_sums_in_the_order_of_its_positions():
+    """Not XLA's order, but a stated one: row + (d1 + d2 + ...)."""
+    rng = np.random.default_rng(3)
+    ids = np.full(1100, 5, np.int32)
+    table, delta = _table_and_delta(rng, ids)
+    acc = delta[0].copy()
+    for d in delta[1:]:
+        acc = acc + d
+    got = np.asarray(jax.jit(_interpreted)(table, ids, delta))
+    np.testing.assert_array_equal(got[5], table[5] + acc)
+
+
+@pytest.mark.parametrize("devices", [2, 4])
+def test_row_sharded_table_equals_the_one_device_result(devices):
+    """Each device sorts the replicated ids with the rows it does not
+    own keyed out, and visits its own: no collective, the same table."""
+    from multiverso_tpu.sharding import mesh as meshlib
+    mesh = meshlib.local_mesh(devices)
+    rng = np.random.default_rng(devices)
+    ids = rng.integers(-5, ROWS + 40, 2500).astype(np.int32)
+    ids[:300] = 749  # a run on the last row of the first of four shards
+    table, delta = _table_and_delta(rng, ids)
+    sharded = jax.device_put(table, meshlib.row_sharded(mesh))
+    got = jax.jit(functools.partial(_interpreted, mesh=mesh))(
+        sharded, ids, delta)
+    assert got.sharding.is_equivalent_to(meshlib.row_sharded(mesh), 2)
+    one = np.asarray(jax.jit(_interpreted)(table, ids, delta))
+    np.testing.assert_array_equal(np.asarray(got), one)
+    text = jax.jit(functools.partial(_interpreted, mesh=mesh)).lower(
+        sharded, ids, delta).compile().as_text()
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text
+
+
+@pytest.fixture
+def fast_path_on_the_cpu(monkeypatch):
+    """What a test steers: the platform rules.fast_rows sees, and the
+    kernel interpreted. The program has no option for either."""
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    monkeypatch.setattr(
+        rules.row_scatter, "scatter_add", functools.partial(
+            row_scatter.scatter_add, interpret=True))
+
+
+def _path_counts():
+    return tuple(dashboard.Dashboard.get(name).count for name in
+                 ("UPDATE_ROWS_FAST", "UPDATE_ROWS_XLA"))
+
+
+@pytest.mark.parametrize("k, fast", [
+    (rules.FAST_MIN_IDS // 2, False),
+    # Host ids pad to the next power-of-two bucket before the rule sees
+    # them, so one id past a bucket is the next bucket's count.
+    (rules.FAST_MIN_IDS // 2 + 1, True),
+    (rules.FAST_MIN_IDS, True),
+    (rules.FAST_MIN_IDS + 1, True),
+])
+@pytest.mark.parametrize("rule", ["default", "sgd"])
+def test_the_id_count_picks_the_path_and_both_agree(
+        fast_path_on_the_cpu, rule, k, fast):
+    """50-of-128-lane deltas through the engine, around the crossover,
+    with sgd's sign: the counters say which form ran."""
+    rng = np.random.default_rng(k)
+    ids = rng.integers(0, ROWS, k).astype(np.int32)
+    delta = rng.normal(size=(k, 50)).astype(np.float32)
+    table = rng.normal(size=(ROWS, COLS)).astype(np.float32)
+    before = _path_counts()
+    got = np.asarray(make_engine(rule, (ROWS, COLS)).apply_rows(
+        jnp.asarray(table), ids, delta))
+    after = _path_counts()
+    assert (after[0] - before[0], after[1] - before[1]) == \
+        ((1, 0) if fast else (0, 1))
+    want = table.copy()
+    np.add.at(want[:, :50], ids, -delta if rule == "sgd" else delta)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[:, 50:], table[:, 50:])
+
+
+@pytest.mark.parametrize("shape, dtype, k, fast", [
+    ((ROWS, 128), np.float32, 4096, True),
+    ((ROWS, 256), np.float32, 4096, True),
+    ((ROWS, 50), np.float32, 4096, False),     # not whole lanes
+    ((ROWS, 128), np.int32, 4096, False),      # integer table
+    ((ROWS, 128), np.float64, 4096, False),
+    ((ROWS,), np.float32, 4096, False),        # array table
+    ((row_scatter.MAX_ROWS, 128), np.float32, 4096, False),
+    ((ROWS, 128), np.float32, 8, False),       # a tiny bucket
+])
+def test_fast_rows_reads_only_what_is_static(monkeypatch, shape, dtype, k,
+                                             fast):
+    assert not rules.fast_rows(shape, dtype, k)  # this is a CPU
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    assert rules.fast_rows(shape, dtype, k) is fast
+
+
+def test_bounded_foreign_device_ids_are_dropped_on_the_fast_path(
+        fast_path_on_the_cpu):
+    """The multi-server variant maps global ids to this shard's rows
+    inside the jit; foreign rows go out of range and are never
+    visited."""
+    rng = np.random.default_rng(11)
+    ofs, n = 1000, 1500
+    ids = rng.integers(0, 4000, (2, 1024)).astype(np.int32)
+    delta = rng.normal(size=(2, 1024, COLS)).astype(np.float32)
+    table = rng.normal(size=(ROWS, COLS)).astype(np.float32)
+    before = _path_counts()
+    got = np.asarray(make_engine("default", (ROWS, COLS)).apply_rows(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(delta),
+        bounds=(ofs, n)))
+    assert _path_counts()[0] - before[0] == 1
+    own = (ids >= ofs) & (ids < ofs + n)
+    want = table.copy()
+    np.add.at(want, ids[own] - ofs, delta[own])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[n:], table[n:])
+
+
+def test_the_fused_add_and_gather_counts_and_reads_the_new_rows(
+        fast_path_on_the_cpu):
+    from multiverso_tpu.updater.engine import pad_ids
+    rng = np.random.default_rng(12)
+    ids = pad_ids(rng.permutation(ROWS)[:1500], ROWS)
+    delta = rng.normal(size=(1500, COLS)).astype(np.float32)
+    table = rng.normal(size=(ROWS, COLS)).astype(np.float32)
+    before = _path_counts()
+    data, values = make_engine("default", (ROWS, COLS)).apply_rows_gather(
+        jnp.asarray(table), ids, delta, None, ids, COLS)
+    assert _path_counts()[0] - before[0] == 1
+    want = table.copy()
+    want[ids[:1500]] += delta
+    np.testing.assert_array_equal(np.asarray(data), want)
+    np.testing.assert_array_equal(np.asarray(values)[:1500],
+                                  want[ids[:1500]])
+
+
+@pytest.fixture
+def build_chunk_program():
+    """``build(cache_dir)``: the chunk program of a ROWS x COLS table as
+    a process would get it that had built none yet."""
+    def build(cache_dir, rows=ROWS):
+        row_scatter._chunk_program.cache_clear()
+        return row_scatter._chunk_program((rows, COLS), "float32", 2048,
+                                          str(cache_dir))
+    yield build
+    row_scatter._chunk_program.cache_clear()
+
+
+def _lowers_for_the_tpu(program):
+    shaped = jax.ShapeDtypeStruct
+    text = jax.jit(program).trace(
+        shaped((ROWS, COLS), np.float32), shaped((2048,), jnp.int32),
+        shaped((2048, COLS), np.float32), shaped((8, COLS), np.float32)
+    ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    return "tpu_custom_call" in text and "mv.update.scatter_add" in text
+
+
+def test_a_built_chunk_program_is_read_back_without_the_kernels_source(
+        build_chunk_program, tmp_path, monkeypatch):
+    """The first process lowers the Pallas kernel and keeps the program
+    in its compile cache; the next reads it and imports no Pallas."""
+    from multiverso_tpu.updater import row_scatter_kernel
+    assert _lowers_for_the_tpu(build_chunk_program(tmp_path))
+    (kept,) = (tmp_path / "mv_row_scatter").iterdir()
+    with monkeypatch.context() as patched:
+        patched.setattr(row_scatter_kernel, "rmw_chunk", None)  # not called
+        assert _lowers_for_the_tpu(build_chunk_program(tmp_path))
+    # another shape is another program
+    build_chunk_program(tmp_path, rows=2 * ROWS)
+    assert len(list(kept.parent.iterdir())) == 2
+    # a torn file is built again, in place
+    kept.write_bytes(b"torn")
+    assert _lowers_for_the_tpu(build_chunk_program(tmp_path))
+    assert kept.stat().st_size > 1000
+
+
+def test_without_a_compile_cache_nothing_is_written(
+        build_chunk_program, tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    assert _lowers_for_the_tpu(build_chunk_program(""))
+    assert not list(tmp_path.iterdir())
+
+
+def test_a_compile_cache_that_cannot_be_written_is_no_cache(
+        build_chunk_program, tmp_path):
+    blocked = tmp_path / "a_file_where_the_directory_would_be"
+    blocked.write_text("")
+    assert _lowers_for_the_tpu(build_chunk_program(blocked))
