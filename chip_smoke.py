@@ -18,8 +18,8 @@ One process, which holds the chip for all four arms:
 
 All at the width the repo benchmarks: a little over 1,000,000 vocabulary
 rows x dim 128, window 5, 5 negatives, neg_block 8. The corpus is made
-from a seed. Weights are random. Nothing is read from the network, git,
-bench.py or a cache of an earlier machine's results.
+from a seed. Weights are random. Nothing is read from the network, git
+or a cache of an earlier machine's results.
 
 It selects no platform. It prints what JAX found and exits non-zero at
 once unless that is a TPU; any failed check, exception or missed
@@ -47,7 +47,7 @@ DIM = 128
 SENTENCES = 100_000   # topic text after one pass over the vocabulary
 WINDOW, NEGATIVE, NEG_BLOCK = 5, 5, 8
 SENTENCE_LEN = 40
-WARM_GROUPS = 4       # PS groups treated as warm-up (bench.py warms 4)
+WARM_GROUPS = 4       # PS groups treated as warm-up
 ARM_DEADLINE_S = 330  # three arms + corpus stay inside the 1200 s limit
 
 _BUILD_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -22,13 +22,13 @@ echo "== mvlint static-analysis gate =="
 # shows per-pass counts. (`python -m tools.mvlint --baseline ...`
 # prints the same counts WITHOUT failing — drift-at-a-glance for PRs.)
 # See docs/STATIC_ANALYSIS.md.
-python -m tools.mvlint multiverso_tpu tests bench.py
+python -m tools.mvlint multiverso_tpu tests
 
 # Stale-suppression review line, NOT a gate: pragmas that suppressed
 # zero findings are listed for cleanup but never fail the build (a
 # pragma can be load-bearing only on certain trees).
 python -m tools.mvlint --report-unused-pragmas \
-    multiverso_tpu tests bench.py | grep '^warning:' || true
+    multiverso_tpu tests | grep '^warning:' || true
 
 echo "== mvlint self-check (seeded fixtures must still fail) =="
 # The analyzers are regression-protected: a pass that silently stops
@@ -239,7 +239,7 @@ fi
 
 echo "== unit + in-process integration tests =="
 # Virtual 8-device CPU mesh (tests/conftest.py forces the platform).
-# Slow chaos/bench extras stay behind the -m slow gate above.
+# Slow chaos extras stay behind the -m slow gate above.
 # test_fault_tolerance.py already ran in its named gate above — its
 # kill-a-server integration proof spawns two full subprocess word2vec
 # cluster runs, far too heavy to pay twice per CI pass.
@@ -255,11 +255,6 @@ python -m pytest tests/test_binding.py -x -q
 
 echo "== runnable distributed example (2 OS processes, machine file) =="
 python binding/python/examples/distributed_word2vec.py -n 2
-
-echo "== CPU perf baseline builds and runs =="
-g++ -O3 -fopenmp -o /tmp/w2v_baseline_ci native/baseline/word2vec_baseline.cpp
-printf 'a b c d\nb a d c\n' > /tmp/w2v_ci_corpus.txt
-/tmp/w2v_baseline_ci /tmp/w2v_ci_corpus.txt - 1 8 2 2 0 0.025 1
 
 echo "== driver entry points =="
 python -c "import __graft_entry__ as g; fn, a = g.entry(); fn(*a)"

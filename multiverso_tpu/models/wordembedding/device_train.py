@@ -276,10 +276,8 @@ def _seq_pair_step(C, W, K, emb_in, emb_out, kept_pad, ksent_pad,
     update before the next pair trains). The 2W offsets run as
     sequential sub-steps against the LIVE tables, so each offset's C
     pairs see every earlier offset's updates. ~8x the row traffic of
-    the shared-negative banded step — measured on the bench corpus it
-    is what closes the last topic-separation gap to the sequential C++
-    baseline (0.79 -> 1.03 at equal epochs), so the bench uses it for
-    the time-to-quality record and the banded step for raw words/s."""
+    the shared-negative banded step; it trades that for the reference's
+    per-epoch quality (no cell runs it: not measured on the chip)."""
     k_shrink, k_idx, k_keep = jax.random.split(key, 3)
     centers, band, pmask = _band_former(C, W, n_kept, kept_pad,
                                         ksent_pad, k_shrink, base)
@@ -591,7 +589,7 @@ class DeviceCorpusTrainer:
                     max_steps: int = 0) -> Tuple[float, float]:
         """One full epoch on device. ``group_hook(words)`` is called
         after each dispatched group with the raw-word count it covered
-        (bench timing); ``max_steps`` truncates the epoch (warmup).
+        (the benchmark's word count); ``max_steps`` truncates the epoch (warmup).
         Returns (loss_sum, examples) as floats — fetched ONCE at epoch
         end. ``examples`` counts (center, context) pairs in skip-gram
         mode and trained centers in CBOW mode (one prediction per
@@ -831,116 +829,6 @@ def _grouped_step_fn(step_fn, G: int):
     return step
 
 
-@functools.lru_cache(maxsize=None)
-def _segmented_ids_fn(ids_fn, offsets: tuple, caps_in: tuple,
-                      caps_out: tuple, oor: int):
-    """Wrap a (possibly grouped) block-ids program with PER-SERVER
-    SEGMENTATION: sort each id set, searchsorted the server row offsets
-    for segment bounds, and emit one fixed-capacity dynamic slice per
-    server — so each server receives (and gathers/scatters) only ~its
-    share of the ids instead of the full broadcast set (ref per-server
-    key bucketing: src/table/matrix_table.cpp:234-315). Capacities are
-    static (calibrated by the trainer); an id set whose true segment
-    exceeds its capacity raises the OVERFLOW flag, which the trainer
-    accumulates on device and checks at epoch end — entries beyond a
-    segment's capacity would silently miss their owner otherwise.
-
-    Slice slack needs no masking: an entry past its server's bound
-    belongs to the NEXT server, whose own slice also carries it — the
-    owner applies it, everyone else range-masks it out."""
-    offs = np.asarray(offsets[1:-1], np.int32)
-
-    def prep(ids_nd, caps):
-        flat = ids_nd.reshape(-1)
-        n = flat.shape[0]
-        order = jnp.argsort(flat)
-        sorted_ids = flat[order]
-        # Inverse permutation by scatter (one pass) — a second argsort
-        # would pay a full sort for what is just order[j] -> j.
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n, dtype=order.dtype))
-        bounds = jnp.concatenate([
-            jnp.zeros(1, jnp.int32),
-            jnp.searchsorted(sorted_ids, jnp.asarray(offs)).astype(
-                jnp.int32),
-            jnp.full(1, n, jnp.int32)])
-        padded = jnp.concatenate(
-            [sorted_ids, jnp.full((max(caps),), oor, jnp.int32)])
-        segs = []
-        overflow = jnp.int32(0)
-        for s, cap in enumerate(caps):
-            segs.append(jax.lax.dynamic_slice(padded, (bounds[s],),
-                                              (cap,)))
-            overflow = overflow | (
-                bounds[s + 1] - bounds[s] > cap).astype(jnp.int32)
-        return tuple(segs), (order, inv, bounds), overflow
-
-    def ids(*args):
-        in_ids, out_ids, aux = ids_fn(*args)
-        segs_in, meta_in, ovf_i = prep(in_ids, caps_in)
-        segs_out, meta_out, ovf_o = prep(out_ids, caps_out)
-        return (segs_in, segs_out, aux, meta_in, meta_out,
-                ovf_i | ovf_o)
-
-    return jax.jit(ids)
-
-
-@functools.lru_cache(maxsize=None)
-def _segmented_step_fn(step_fn, caps_in: tuple, caps_out: tuple,
-                       in_shape: tuple, out_shape: tuple):
-    """Wrap a PS block step for segmented pulls/pushes: reassemble the
-    per-server reply slices into sorted order (increasing-server
-    dynamic_update_slice — for any row the LAST writer covering it is
-    its owner, so slack rows never survive), un-permute back to the
-    step's positional layout, run the step, then re-permute the deltas
-    and slice per-server push segments — all in ONE program, so the
-    reorder passes ride the step's launch."""
-    n_in = int(np.prod(in_shape))
-    n_out = int(np.prod(out_shape))
-
-    def reassemble(parts, bounds, n, caps):
-        buf = jnp.zeros((n + max(caps), parts[0].shape[-1]),
-                        parts[0].dtype)
-        for s, part in enumerate(parts):
-            buf = jax.lax.dynamic_update_slice(buf, part,
-                                               (bounds[s], 0))
-        return buf[:n]
-
-    def resort(delta, order, bounds, n, caps):
-        d = delta.reshape(n, delta.shape[-1])[order]
-        d = jnp.pad(d, ((0, max(caps)), (0, 0)))
-        return tuple(
-            jax.lax.dynamic_slice(d, (bounds[s], 0),
-                                  (cap, d.shape[-1]))
-            for s, cap in enumerate(caps))
-
-    def step(parts_v, parts_u, meta_in, meta_out, aux, lr,
-             inv_workers):
-        order_i, inv_i, bounds_i = meta_in
-        order_o, inv_o, bounds_o = meta_out
-        dim = parts_v[0].shape[-1]
-        v = reassemble(parts_v, bounds_i, n_in, caps_in)[inv_i] \
-            .reshape(in_shape + (dim,))
-        u = reassemble(parts_u, bounds_o, n_out, caps_out)[inv_o] \
-            .reshape(out_shape + (dim,))
-        d_v, d_u, loss, examples = step_fn(v, u, aux, lr, inv_workers)
-        return (resort(d_v, order_i, bounds_i, n_in, caps_in),
-                resort(d_u, order_o, bounds_o, n_out, caps_out),
-                loss, examples)
-
-    return jax.jit(step)
-
-
-def _segment_caps(counts, total: int) -> tuple:
-    """Static per-server segment capacities from one calibration
-    sample: 2x slack + headroom, power-of-two bucketed, clamped to the
-    full id count (a capacity beyond that cannot help)."""
-    from ...updater.engine import bucket_size
-    cap_total = bucket_size(total)
-    return tuple(min(bucket_size(int(c) * 2 + 64), cap_total)
-                 for c in counts)
-
-
 class PSDeviceCorpusTrainer:
     """The PS twin of ``DeviceCorpusTrainer``: same HBM-resident corpus
     pipeline, but the embeddings live in PARAMETER-SERVER matrix tables
@@ -961,8 +849,7 @@ class PSDeviceCorpusTrainer:
 
     def __init__(self, model, tokenized: TokenizedCorpus,
                  centers_per_step: int = 32768,
-                 blocks_per_dispatch: int = 1,
-                 segment_keys: bool = False):
+                 blocks_per_dispatch: int = 1):
         """``blocks_per_dispatch`` (G) batches G blocks' ids into ONE
         pull/step/push round trip — G-fold fewer program launches (the
         per-dispatch launch cost, not measured on the current machine), at
@@ -971,19 +858,7 @@ class PSDeviceCorpusTrainer:
         makes with -is_pipeline prefetch and sync_frequency > 1
         (ref: distributed_wordembedding.cpp:203-224,
         LogisticRegression configure.h sync_frequency). G=1 keeps exact
-        per-block semantics.
-
-        ``segment_keys`` sends each server a calibrated-capacity SLICE
-        of the sorted ids instead of broadcasting the full set —
-        per-server gather/scatter work follows the segment size (ref
-        per-server key bucketing: src/table/matrix_table.cpp:234-315).
-        Default OFF: on one chip with Zipf-skewed ids the reorder
-        passes (sort + two [k, D] permutes + reassembly) cost more
-        than the per-server savings — measured 0.59x vs broadcast's
-        0.83x same-window on the bench corpus in round 5 (not measured
-        on the current machine); it pays off when ids spread evenly
-        across servers (balanced / hashed tables), so it stays
-        available as an opt-in."""
+        per-block semantics."""
         config = model.config
         if not getattr(model, "_device_path", False):
             raise ValueError("PS device pipeline needs in-process "
@@ -1043,48 +918,7 @@ class PSDeviceCorpusTrainer:
         if self._G > 1:
             self._ids = _grouped_ids_fn(self._ids, self._G)
             self._step = _grouped_step_fn(self._step, self._G)
-        num_server = model._in_table._num_server
-        self._segment_keys = bool(segment_keys) and num_server > 1
-        self._seg_ids = None
-        self._seg_step = None
-        self._overflow = None
         self.kept_words_trained = 0
-
-    def _build_segment_programs(self, kept_pad, ksent_pad, key,
-                                n_kept_dev, n_kept: int) -> None:
-        """One-time calibration for segment mode: run the raw ids
-        program on a representative group, read the per-server id
-        counts back ONCE (setup cost, ~a readback), and fix static
-        per-server capacities with 2x slack. Shapes from the same
-        sample parameterize the reassembling step wrapper."""
-        in_table, out_table = self.model._in_table, self.model._out_table
-        offsets = tuple(in_table._offsets)
-        if tuple(out_table._offsets) != offsets \
-                or in_table.num_row != out_table.num_row:
-            raise ValueError("segment mode expects same-shape in/out "
-                             "tables")
-        base_host = np.minimum(np.arange(self._G) * self._C,
-                               max(n_kept, 1)).astype(np.int32)
-        with device_lock.guard():
-            # The base-vector upload is a dispatch too — keep it inside
-            # the same critical section as the ids program it feeds.
-            base = np.int32(0) if self._G == 1 else \
-                device_lock.settle(jnp.asarray(base_host))
-            in_ids, out_ids, _aux = device_lock.settle(self._ids(
-                kept_pad, ksent_pad, self._aux_tables[0],
-                self._aux_tables[1], key, base, n_kept_dev))
-
-        def caps(ids_nd):
-            flat = np.sort(np.asarray(ids_nd).ravel())
-            counts = np.diff(np.searchsorted(flat, np.asarray(offsets)))
-            return _segment_caps(counts, flat.size)
-
-        caps_in, caps_out = caps(in_ids), caps(out_ids)
-        self._seg_ids = _segmented_ids_fn(
-            self._ids, offsets, caps_in, caps_out, in_table.num_row)
-        self._seg_step = _segmented_step_fn(
-            self._step, caps_in, caps_out,
-            tuple(in_ids.shape), tuple(out_ids.shape))
 
     def train_epoch(self, seed: int, block_hook=None,
                     max_steps: int = 0) -> Tuple[float, float]:
@@ -1140,95 +974,42 @@ class PSDeviceCorpusTrainer:
                 lr = device_lock.settle(jnp.asarray(lr_host))
                 inv_w = device_lock.settle(
                     jnp.float32(1.0 / model._num_workers))
-            if self._segment_keys:
-                if self._seg_ids is None:
-                    self._build_segment_programs(kept_pad, ksent_pad,
-                                                 step_key, n_kept_dev,
-                                                 n_kept)
-                # Segmented form: each server pulls/pushes only its
-                # calibrated slice of the sorted ids; the step wrapper
-                # reassembles replies and re-slices the push deltas in
-                # the same program.
-                with device_lock.guard():
-                    segs_i, segs_o, pmask, meta_i, meta_o, ovf = \
-                        device_lock.settle(self._seg_ids(
-                            kept_pad, ksent_pad,
-                            self._aux_tables[0],
-                            self._aux_tables[1], step_key, base,
-                            n_kept_dev))
-                mid_in = in_table.get_rows_device_segments_async(segs_i)
-                mid_out = out_table.get_rows_device_segments_async(
-                    segs_o)
-                in_table.wait(mid_in)
-                out_table.wait(mid_out)
-                v = tuple(in_table.take_device_row_parts())
-                u = tuple(out_table.take_device_row_parts())
-                with device_lock.guard():
-                    d_v_segs, d_u_segs, loss, pairs = device_lock.settle(
-                        self._seg_step(
-                            v, u, meta_i, meta_o, pmask, lr, inv_w))
-                model._pending_pushes.append(
-                    (in_table, in_table.add_rows_device_segments_async(
-                        segs_i, d_v_segs)))
-                model._pending_pushes.append(
-                    (out_table,
-                     out_table.add_rows_device_segments_async(
-                         segs_o, d_u_segs)))
-                self._overflow = ovf if self._overflow is None \
-                    else self._overflow + ovf
-            else:
-                # in_ids: centers (skip-gram) or the band (CBOW);
-                # out_ids: [band | negs] / [centers | negs] / Huffman
-                # path rows — see _block_ids_fn / _block_ids_fn_hs;
-                # leading G axis when grouped.
-                with device_lock.guard():
-                    in_ids, out_ids, pmask = device_lock.settle(self._ids(
-                        kept_pad, ksent_pad, self._aux_tables[0],
-                        self._aux_tables[1], step_key, base, n_kept_dev))
-                # Device-key pulls ride the worker->server actor round
-                # trip; the replies are lazy device arrays (no host
-                # sync).
-                mid_in = in_table.get_rows_device_async(in_ids)
-                mid_out = out_table.get_rows_device_async(out_ids)
-                in_table.wait(mid_in)
-                out_table.wait(mid_out)
-                # Per-server shard tuples; the step jit sums them
-                # (fused — no separate reassembly dispatch on
-                # multi-server tables).
-                v = tuple(in_table.take_device_row_parts())
-                u = tuple(out_table.take_device_row_parts())
-                with device_lock.guard():
-                    d_v, d_u, loss, pairs = device_lock.settle(
-                        self._step(v, u, pmask, lr, inv_w))
-                # Fire-and-forget pushes: waiters self-reap on ack; the
-                # trailing drain below bounds the epoch.
-                model._pending_pushes.append(
-                    (in_table, in_table.add_rows_async(in_ids, d_v)))
-                model._pending_pushes.append(
-                    (out_table, out_table.add_rows_async(out_ids, d_u)))
+            # in_ids: centers (skip-gram) or the band (CBOW);
+            # out_ids: [band | negs] / [centers | negs] / Huffman
+            # path rows — see _block_ids_fn / _block_ids_fn_hs;
+            # leading G axis when grouped.
+            with device_lock.guard():
+                in_ids, out_ids, pmask = device_lock.settle(self._ids(
+                    kept_pad, ksent_pad, self._aux_tables[0],
+                    self._aux_tables[1], step_key, base, n_kept_dev))
+            # Device-key pulls ride the worker->server actor round
+            # trip; the replies are lazy device arrays (no host
+            # sync).
+            mid_in = in_table.get_rows_device_async(in_ids)
+            mid_out = out_table.get_rows_device_async(out_ids)
+            in_table.wait(mid_in)
+            out_table.wait(mid_out)
+            # Per-server shard tuples; the step jit sums them
+            # (fused — no separate reassembly dispatch on
+            # multi-server tables).
+            v = tuple(in_table.take_device_row_parts())
+            u = tuple(out_table.take_device_row_parts())
+            with device_lock.guard():
+                d_v, d_u, loss, pairs = device_lock.settle(
+                    self._step(v, u, pmask, lr, inv_w))
+            # Fire-and-forget pushes: waiters self-reap on ack; the
+            # trailing drain below bounds the epoch.
+            model._pending_pushes.append(
+                (in_table, in_table.add_rows_async(in_ids, d_v)))
+            model._pending_pushes.append(
+                (out_table, out_table.add_rows_async(out_ids, d_u)))
             loss_acc = loss if loss_acc is None else loss_acc + loss
             pair_acc = pairs if pair_acc is None else pair_acc + pairs
-            self.last_loss = loss  # device scalar; bench sync point
+            self.last_loss = loss  # device scalar; callers sync on it
             if block_hook is not None:
                 block_hook(raw_per_step * real)
         model._drain_pushes()
         model._flush_word_count()
-        if self._overflow is not None:
-            # One readback per epoch (the drain already synced): a
-            # segment that outgrew its calibrated capacity means some
-            # ids never reached their owner — fail loud, never train
-            # silently wrong.
-            if int(self._overflow):
-                raise RuntimeError(
-                    "segmented device keys overflowed a calibrated "
-                    "per-server capacity (id distribution shifted "
-                    ">2x from the calibration sample). Overflowed "
-                    "blocks pulled zero rows and pushed corrupted "
-                    "deltas THIS epoch — the tables are polluted: "
-                    "restore from a checkpoint (or reinit), then "
-                    "rebuild the trainer to recalibrate or pass "
-                    "segment_keys=False")
-            self._overflow = None
         model._in_table.zoo.barrier()
         return (0.0 if loss_acc is None else float(loss_acc),
                 0.0 if pair_acc is None else float(pair_acc))

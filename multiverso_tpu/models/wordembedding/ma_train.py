@@ -17,8 +17,7 @@ pipeline:
   corrected by the local progress made meanwhile (``MAAverager``
   semantics). Sync and overlapped runs apply the SAME update at the
   SAME point — bit-identical trajectories when ``-allreduce_lossy`` is
-  off; only the ``MA_COMM_STALL`` wall time differs, which is exactly
-  what the bench compares.
+  off; only the ``MA_COMM_STALL`` wall time differs.
 """
 
 from __future__ import annotations

@@ -813,7 +813,7 @@ class PSWord2Vec(Word2Vec):
         with monitor("PS_GET_STALL"):
             # The trainer's pull-stall: wire latency NOT hidden by the
             # pipeline (cache hits and completed prefetches make this
-            # ~zero; the bench's client_cache phase reads it).
+            # ~zero).
             self._in_table.wait(prep.mid_in)
             self._out_table.wait(prep.mid_out)
         if self._device_path:

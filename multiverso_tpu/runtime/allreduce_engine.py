@@ -86,8 +86,8 @@ define_int("allreduce_chunk_kb", 512,
            "KB; each chunk is an independent ring whose frames pipeline "
            "on the transport writer threads. Smaller chunks overlap "
            "more but pay more per-frame overhead (~0.3-1.5 ms each on "
-           "a single-core host); 512 is the measured sweet spot for "
-           "4-16 MB buffers on the bench wire")
+           "a single-core host); 512 suited 4-16 MB buffers on a "
+           "200 Mbit/s wire (a CPU host; not measured on the chip)")
 define_int("allreduce_window", 4,
            "ring path: max in-flight (sent but not yet matched by a "
            "receive) chunks per ring step")
@@ -252,7 +252,7 @@ class AllreduceEngine:
         self._codec = (not net.in_process
                        and bool(get_flag("wire_codec")))
         #: Algorithm the last public collective ran
-        #: (bruck/ring/rhalving/sparse/sharded) — bench + tests read it.
+        #: (bruck/ring/rhalving/sparse/sharded) — tests read it.
         self.last_algo: Optional[str] = None
         #: Bytes of reduce-state this rank held during the last
         #: collective: the buffer(s) that accumulate reduced values

@@ -20,7 +20,7 @@ rejected at broadcast time.
 Every decision is observable: ``mv_autotune_*`` gauges ride the
 controller's ``/metrics`` scrape surface (current value, last-change
 epoch, latest policy verdict, per-rank acked epoch), and the full
-decision trajectory is exported for the bench JSON.
+decision trajectory is kept (``AutotuneManager.trajectory``).
 
 Adaptive-decision precedent: SparCML's density break-even and EQuARX's
 quantization-tier selection (PAPERS.md) pick their operating point from
@@ -130,7 +130,7 @@ QUEUE_SHALLOW = 8.0
 #: tcp_send mean-ms thresholds for the allreduce chunk step.
 SEND_SLOW_MS = 4.0
 SEND_FAST_MS = 0.5
-#: Decision-trajectory retention (bench JSON export).
+#: Decision-trajectory retention.
 TRAJECTORY_CAP = 512
 
 
@@ -167,8 +167,8 @@ class AutotuneManager:
 
     Constructed unconditionally with the controller actor (cheap); the
     evaluation thread only starts when ``-autotune_interval_s > 0``.
-    ``evaluate``/``tick_once`` are exposed for tests and the bench —
-    they run the same code path the thread does.
+    ``evaluate`` is exposed for tests — it is the code the thread's
+    ``tick_once`` runs.
     """
 
     def __init__(self, zoo, cluster_metrics) -> None:
@@ -177,7 +177,7 @@ class AutotuneManager:
         self._metrics = cluster_metrics
         self._state_lock = named_lock(f"autotune[r{zoo.rank}].state")
         # Epoch continues from whatever this process already applied:
-        # a fresh manager (bench re-init) must outrank the previous
+        # a fresh manager (a second ``mv.init`` in one process) must outrank the previous
         # run's broadcasts or its first update would be ignored as a
         # replay.
         self._epoch = configure.applied_config_epoch()  # guarded_by: _state_lock
@@ -249,8 +249,7 @@ class AutotuneManager:
     # -- one evaluation round --
     def tick_once(self) -> Dict[str, Any]:
         """Evaluate every policy against the current cluster view and
-        broadcast the changes (if any). Returns the changed-knob map —
-        tests and the bench call this directly for determinism."""
+        broadcast the changes (if any). Returns the changed-knob map."""
         view = self._metrics.cluster_view()
         changes = self.evaluate(view)
         if changes:
@@ -418,8 +417,7 @@ class AutotuneManager:
         rate = sig["get_rate"]
         if p99 is None or rate is None or rate < MIN_READ_RATE:
             # "idle", not "hold": hold means "judged at its operating
-            # point" (consumers like the bench convergence gate key on
-            # it); a quiet window judges nothing.
+            # point"; a quiet window judges nothing.
             return cur, "idle", "no read traffic to judge"
         if p99 > sig["slo_ms"]:
             return cur // 2, "down", (
@@ -604,7 +602,7 @@ class AutotuneManager:
             return self._epoch
 
     def trajectory(self) -> List[Dict]:
-        """Every applied decision, oldest first (bench JSON export)."""
+        """Every applied decision, oldest first."""
         with self._state_lock:
             return list(self._trajectory)
 

@@ -93,7 +93,7 @@ class Communicator:
         self._zoo.deregister_actor(self)
 
     def queue_depths(self) -> dict:
-        """Live per-destination outbound queue depths (bench/monitor
+        """Live per-destination outbound queue depths (monitor
         observability; empty on transports without peer queues)."""
         return getattr(self._net, "queue_depths", lambda: {})()
 

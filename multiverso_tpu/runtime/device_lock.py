@@ -1,7 +1,7 @@
 """Process-wide serialization of multi-device dispatch (multi-zoo mode).
 
 XLA's CPU runtime executes dispatched computations on a small shared
-thread pool (sized to the host's cores — ONE on the bench container).
+thread pool (sized to the host's cores — possibly ONE in a container).
 A multi-device program (8 virtual CPU shards) can partially occupy the
 pool; two such programs in flight from different threads can each hold
 resources the other needs and wedge forever. One zoo per process (the
@@ -72,7 +72,7 @@ def _single_device() -> bool:
     supports from concurrent threads — so serializing (and settling,
     which kills async pipelining) would only cost throughput. Tests run
     under the 8-virtual-device conftest mesh and therefore KEEP the
-    lock; a plain CPU/one-chip bench process drops it."""
+    lock; a plain one-device process (CPU or one chip) drops it."""
     return _local_devices()[0] == 1
 
 

@@ -92,8 +92,8 @@ class NetInterface:
         on the wire. No-op on transports whose send is synchronous."""
 
     #: Total payload bytes this endpoint has pushed toward peers
-    #: (wire-framing included where the transport serializes). Bench
-    #: instrumentation; transports that care override/maintain it.
+    #: (wire-framing included where the transport serializes). Tests count
+    #: bytes on the wire with it; transports that care override/maintain it.
     @property
     def bytes_sent(self) -> int:
         return 0

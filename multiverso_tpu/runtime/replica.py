@@ -608,8 +608,7 @@ class ReplicaCoordinator:
         # only by cooling below the retention threshold. Without both,
         # boundary rows swap in and out on per-report count noise, and
         # every swap costs a map broadcast plus the owner's initial
-        # value push to every holder — measured at ~20% of the hot
-        # owner's paced link in the N-server bench before this policy.
+        # value push to every holder.
         incumbents = sorted(
             (r for r, c in table.items()
              if r in old_set and c >= threshold / 2.0),

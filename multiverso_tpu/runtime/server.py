@@ -74,8 +74,7 @@ class Server(Actor):
     #: server's drain paths re-enter through Server._process_*.
     #: SCOPED to device-backed tables only (``needs_device_lock``):
     #: host-only table logic (KV control plane) must not serialize two
-    #: in-process server shards against each other — that regression
-    #: put ps_two_servers at 0.809x of single-server in round 5's run.
+    #: in-process server shards against each other.
     #: The lock object itself is the process-wide device-dispatch lock
     #: (runtime/device_lock.py): in multi-zoo mode trainer and worker
     #: dispatch sites serialize on the SAME lock.
@@ -88,8 +87,8 @@ class Server(Actor):
         (``device_lock.active()``): on a single-device process the
         wedge class the lock exists for cannot occur (no inter-device
         rendezvous to deadlock the execution pool), and process-wide
-        serialization of sibling server actors was the measured bulk of
-        the two-server regression (round 5: 0.809x). Inactive mode
+        serialization of sibling server actors was the bulk of what
+        two servers in one process lost to one. Inactive mode
         falls back to the table's per-instance state lock, which still
         pairs (state, version) against the async snapshotter. Host-only
         tables always take their own state lock — cheap (uncontended
@@ -105,7 +104,7 @@ class Server(Actor):
         super().__init__(actors.SERVER, zoo)
         # Mailbox pressure is the admission-control signal of the
         # serving tier (serving/admission.py sheds over the high
-        # watermark) and a bench observable (docs/SERVING.md) — record
+        # watermark; docs/SERVING.md) — record
         # per-push depth into the MAILBOX_DEPTH[*] Samples family.
         # Gated: a training-only deployment must not pay a reservoir
         # append per message for samples nobody reads.
@@ -490,9 +489,8 @@ class Server(Actor):
                         device_lock.settle([b.data for b in reply.data
                                             if b.on_device])
                 if table.needs_device_lock:
-                    # One gather program per serial Get — the
-                    # denominator the fusion bench divides down
-                    # (docs/SERVER_ENGINE.md).
+                    # One gather program per serial Get — the count
+                    # fusion divides down (docs/SERVER_ENGINE.md).
                     count("SERVER_DEVICE_DISPATCHES", 1)
                 # Version stamp: the shard state this Get observed
                 # (client-cache freshness anchor). Error replies stay

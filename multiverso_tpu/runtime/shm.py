@@ -104,7 +104,7 @@ from ..util.dashboard import count, monitor
 from ..util.lock_witness import named_condition, named_lock
 from . import thread_roles
 from .net import NetInterface, PeerLostError
-from .tcp import _LEN, TcpNet, _deserialize_frame, _frame_views
+from .tcp import _LEN, TcpNet, _deserialize_frame, serialize_views
 
 try:  # POSIX shared memory; absent on exotic builds — gate, don't crash
     from multiprocessing import resource_tracker, shared_memory
@@ -392,7 +392,7 @@ class _OutRing:
 
     def write_frame(self, views: List[memoryview], nbytes: int) -> None:
         """Copy one serialized frame into ring slots — THE one copy of
-        the shm data path. ``views`` is the ``_frame_views`` list;
+        the shm data path. ``views`` is the ``serialize_views`` list;
         the wire length prefix is dropped (slot metadata carries
         sizes), so the slot body is exactly the TCP frame body and
         ``tcp._deserialize_frame`` parses it unchanged. Frames larger
@@ -937,7 +937,7 @@ class ShmNet(NetInterface):
             return self._tcp.send(msg)
         writer = self._writer(dst)
         with monitor("tcp_serialize"):
-            views, nbytes = _frame_views(msg)
+            views, nbytes = serialize_views(msg)
         # One queue per destination keeps sync frames FIFO with queued
         # async ones; the flush makes this blocking like TcpNet.send.
         writer.submit(views, nbytes)
@@ -964,7 +964,7 @@ class ShmNet(NetInterface):
         if dst not in self._ring_peers:  # a held chaos frame may outlive
             return self._tcp.send_async(msg)  # the peer's ring
         with monitor("tcp_serialize"):
-            views, nbytes = _frame_views(msg)
+            views, nbytes = serialize_views(msg)
         self._writer(dst).submit(views, nbytes)
         return nbytes
 

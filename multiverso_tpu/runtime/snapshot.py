@@ -99,7 +99,7 @@ class SnapshotManager:
         self._interval = float(get_flag("snapshot_interval_s"))
         self._tables: List[Tuple[int, object]] = []
         self._seq = 0
-        self.rounds_written = 0   # test/bench observability
+        self.rounds_written = 0   # test observability
         self.tables_restored = 0
         self._stop_cond = named_condition(
             f"snapshot[r{zoo.rank}].stop")

@@ -19,9 +19,7 @@ and no libzmq:
   out of the Blobs' own memory), and the receive side leases a pooled
   buffer (``util/buffer_pool.py``), fills it with ``recv_into``, and
   cuts read-only Blob views directly from the frame. Device blobs still
-  materialize to host bytes at the wire boundary. ``-zero_copy=0``
-  falls back to the flat join/copy path (byte-identical frames — the
-  bench baseline and the mixed-build escape hatch);
+  materialize to host bytes at the wire boundary;
 - all socket I/O — accepts, nonblocking connects, frame reads, frame
   writes — multiplexes onto ONE ``selectors`` event-loop thread per
   endpoint (``_EventLoop``). Each destination is a ``_Peer`` state
@@ -63,8 +61,8 @@ from ..core.blob import Blob
 from ..core.message import HEADER_SIZE, Message, trace_of
 from ..util import chaos, log, tracing
 from ..util.buffer_pool import BufferPool
-from ..util.configure import (define_bool, define_double, define_int,
-                              define_string, get_flag)
+from ..util.configure import (define_double, define_int, define_string,
+                              get_flag)
 from ..util.dashboard import count, monitor, samples
 from ..util.lock_witness import named_condition, named_lock
 from ..util.mt_queue import MtQueue
@@ -89,24 +87,6 @@ define_double("connect_timeout_s", 30.0,
               "process binds, then delivers). The retries are "
               "nonblocking timers on the event loop: an unreachable "
               "peer costs zero blocked threads")
-define_bool("zero_copy", True,
-            "scatter-gather wire path: serialize outbound frames as "
-            "view lists drained by sendmsg vectored writes (no flat "
-            "join), and deserialize inbound frames as read-only Blob "
-            "views into pooled receive buffers (-buffer_pool_mb). "
-            "Frames are byte-identical either way (golden-tested) — "
-            "0 restores the legacy join/copy path as the bench "
-            "baseline and a diagnostics escape hatch")
-define_double("net_pace_mbps", 0.0,
-              "emulate a constrained wire: pace outbound frames to this "
-              "many megabits/s. Each frame reserves its transmission "
-              "slot on a shared busy-until deadline and is held on an "
-              "event-loop timer until the slot opens, so a frame "
-              "occupies the emulated wire for its transmission time and "
-              "its ARRIVAL is delayed accordingly — no thread sleeps. "
-              "Bench/test knob for reproducing DCN-speed behavior on "
-              "localhost; 0 = off")
-
 _HDR = struct.Struct(f"<{HEADER_SIZE}i")
 _LEN = struct.Struct("<Q")
 _NBLOBS = struct.Struct("<I")
@@ -170,9 +150,7 @@ def serialize_views(msg: Message) -> Tuple[List[memoryview], int]:
     reads straight through ``Blob.wire_views()`` into the payload's own
     memory — no per-blob ``tobytes``, no ``b"".join``, no prefix
     concat. Drained by ``sendmsg`` vectored writes; joining the views
-    reproduces ``_serialize``'s frame byte for byte (golden-tested),
-    so the wire format is unchanged and mixed -zero_copy builds
-    interoperate."""
+    reproduces ``_serialize``'s frame byte for byte (golden-tested)."""
     views: List[memoryview] = [memoryview(b"")]  # head placeholder
     sizes: List[int] = []
     payload = 0
@@ -212,12 +190,6 @@ def serialize_views(msg: Message) -> Tuple[List[memoryview], int]:
 #: Linux); a frame with more views loops.
 _IOV_CAP = 64
 
-#: Emulated-wire catch-up window (s): how far behind its busy-until
-#: timeline the pacing bucket lets a sender fall before slots anchor
-#: to wall time again (``_pace_reserve``). Absorbs ms-scale wake
-#: jitter without banking unbounded burst across idle gaps.
-_PACE_CREDIT_S = 0.005
-
 
 def _sendmsg_all(sock: socket.socket, views: List[memoryview]) -> None:
     """Drain ``views`` through vectored writes, handling partial sends
@@ -247,22 +219,11 @@ def _sendmsg_all(sock: socket.socket, views: List[memoryview]) -> None:
                 sent = 0
 
 
-def _frame_views(msg: Message) -> Tuple[List[memoryview], int]:
-    """The outbound frame as vectored-write views: scatter-gather by
-    default, a single view of the legacy flat frame under
-    ``-zero_copy=0`` (identical bytes either way)."""
-    if bool(get_flag("zero_copy")):
-        return serialize_views(msg)
-    frame = _serialize(msg)
-    return [memoryview(frame)], len(frame)
-
-
 def _serialize(msg: Message) -> bytes:
-    """Flat-buffer serializer — the LEGACY path (``-zero_copy=0``), the
-    golden reference the scatter-gather framer is byte-compared
-    against, and the bench baseline whose copy count the zero-copy path
-    is measured by. Each payload byte is copied ~3x here (per-blob
-    tobytes, the join, the length-prefix concat)."""
+    """Flat-buffer serializer: the golden reference that
+    tests/test_zero_copy.py byte-compares ``serialize_views`` against;
+    no transport path calls it. Each payload byte is copied ~3x here
+    (per-blob tobytes, the join, the length-prefix concat)."""
     parts: List[bytes] = []
     blobs: List[bytes] = []
     payload = 0
@@ -283,9 +244,10 @@ def _serialize(msg: Message) -> bytes:
 
 
 def _deserialize(body) -> Message:
-    """Flat-buffer parser — the LEGACY path (``-zero_copy=0``): every
-    payload byte is copied out of the frame into a private Blob
-    array."""
+    """Flat-buffer parser: the golden reference that
+    tests/test_zero_copy.py compares ``_deserialize_frame`` against; no
+    transport path calls it. Every payload byte is copied out of the
+    frame into a private Blob array."""
     header = _HDR.unpack_from(body, 0)
     msg = Message()
     msg.header = list(header)
@@ -602,9 +564,9 @@ class _Listener:
 class _Conn:
     """One inbound connection's receive state machine (loop-thread
     only). Buffers and protocol are exactly the old reader thread's:
-    an 8-byte length prefix, then either a pooled lease filled by
-    ``recv_into`` (zero-copy) or a legacy bytearray (``-zero_copy=0``);
-    a length-0 frame is the peer's goodbye (graceful close), EOF
+    an 8-byte length prefix, then a pooled lease filled by
+    ``recv_into``; a length-0 frame is the peer's goodbye (graceful
+    close), EOF
     without one is a dirty close and reports the peer. The difference
     is shape: the fill tolerates partial reads and resumes whenever the
     selector reports readability instead of parking a thread in
@@ -621,8 +583,7 @@ class _Conn:
         self._head = memoryview(bytearray(_LEN.size))
         self._head_got = 0
         self._total = 0
-        self._lease = None  # pooled frame lease (zero-copy path)
-        self._legacy: Optional[bytearray] = None  # -zero_copy=0 path
+        self._lease = None  # pooled frame lease
         self._body: Optional[memoryview] = None  # fill target
         self._body_got = 0
         self._t0_ns = 0
@@ -657,12 +618,8 @@ class _Conn:
                     return
                 self._total = total
                 self._t0_ns = tracing.now_ns()
-                if bool(get_flag("zero_copy")):
-                    self._lease = self._net._pool.lease(total)
-                    self._body = self._lease.view(total)
-                else:
-                    self._legacy = bytearray(total)
-                    self._body = memoryview(self._legacy)
+                self._lease = self._net._pool.lease(total)
+                self._body = self._lease.view(total)
                 self._body_got = 0
             # Body phase: progressive fill of the leased buffer.
             with monitor("tcp_recv"):
@@ -679,14 +636,10 @@ class _Conn:
     def _finish_frame(self) -> None:
         total = self._total
         lease, self._lease = self._lease, None
-        legacy, self._legacy = self._legacy, None
         self._body = None
         self._total = 0
         with monitor("tcp_deserialize"):
-            if legacy is None:
-                msg = _deserialize_frame(lease.view(total), lease)
-            else:
-                msg = _deserialize(legacy)
+            msg = _deserialize_frame(lease.view(total), lease)
         tid = trace_of(msg)
         if tid:
             # The trace id is only known after the parse; the span
@@ -716,12 +669,11 @@ class _Conn:
             pass
         lease, self._lease = self._lease, None
         self._body = None
-        self._legacy = None
         if lease is not None:
             lease.release()  # mid-frame teardown: recycle the buffer
         # Racy teardown check by design: worst case is one spurious
         # peer-lost report during finalize, which abort ignores.
-        if not clean and not self._net._closed:  # mvlint: ignore[guarded-by]
+        if not clean and not self._net._closed:
             # A peer hung up while the mesh is live: report it so the
             # zoo can abort blocked waits (the reference has no such
             # detection — a dead MPI rank hangs the cluster).
@@ -754,15 +706,6 @@ class _Peer:
     #: parked thread).
     _DRAIN_FRAMES = 64
 
-    #: Pacing burst slack (s): epoll timers have ~1 ms granularity, so
-    #: parking for a sub-millisecond pace gap wakes late and the
-    #: chunked pipelines bleed a timer-quantum per frame. A frame due
-    #: within this window sends immediately instead — the token
-    #: bucket's absolute busy-until arithmetic keeps the long-run rate
-    #: exact, this only trades ms-scale smoothness (the old sleeping
-    #: writer's overshoot, in the other direction).
-    _PACE_SLACK = 0.002
-
     def __init__(self, net: "TcpNet", dst: int):
         self._net = net
         self._loop = net._loop
@@ -780,7 +723,6 @@ class _Peer:
         self._registered = False
         self._want_write = False
         self._cur: Optional[list] = None  # [views, i, off, nbytes, t0, bye]
-        self._pace_until = 0.0
         self._deadline = 0.0  # connect-epoch deadline (0 = not dialing)
         self._retry_at = 0.0
         self._retry_delay = 0.02
@@ -993,16 +935,6 @@ class _Peer:
                         self._frames.popleft()
                     self._inflight = True
                 cur = self._cur = [views, 0, 0, nbytes, t_submit, goodbye]
-                self._pace_until = self._net._pace_reserve(nbytes)
-            if self._pace_until:
-                now = time.monotonic()
-                if now + self._PACE_SLACK < self._pace_until:
-                    # Paced frame not due yet: park on a loop timer,
-                    # not a sleep — every other fd keeps being served.
-                    self._set_want_write(False)
-                    self._loop.call_later(self._pace_until - now, self)
-                    return
-                self._pace_until = 0.0
             views, i, off, nbytes, t_submit, goodbye = cur
             n = len(views)
             try:
@@ -1072,7 +1004,6 @@ class _Peer:
             return
         self._teardown_socket()
         self._cur = None
-        self._pace_until = 0.0
         self._set_state(_ST_DEAD)
         with self._cond:
             if self.error is None:
@@ -1148,7 +1079,6 @@ class TcpNet(NetInterface):
         self._closed = False  # guarded_by: _lifecycle
         self._stats_lock = named_lock(f"tcp[r{rank}].stats")
         self._bytes_sent = 0  # guarded_by: _stats_lock
-        self._wire_free_at = 0.0  # guarded_by: _stats_lock
         # Receive-frame pool shared by every inbound connection of this
         # endpoint (the leases are what recycle the buffers; the pool
         # itself only caps what is RETAINED, so reads never block).
@@ -1175,8 +1105,8 @@ class TcpNet(NetInterface):
     def on_misc_timer(self) -> None:
         """Housekeeping tick (~2s on the loop): record the transport
         thread gauge — O(1) in peer count is the point of the
-        event-loop core, and TRANSPORT_THREADS is how the bench's
-        many-connection arm proves it."""
+        event-loop core, and TRANSPORT_THREADS is the gauge that shows
+        it on a live rank."""
         alive = thread_roles.roles_alive()
         self._transport_gauge.add(
             alive.get(thread_roles.EVENTLOOP, 0)
@@ -1208,7 +1138,7 @@ class TcpNet(NetInterface):
         tid = trace_of(msg)
         with monitor("tcp_serialize"), \
                 tracing.span(tid, "tcp_serialize", self._rank):
-            views, nbytes = _frame_views(msg)
+            views, nbytes = serialize_views(msg)
         with tracing.span(tid, "tcp_send", self._rank,
                           args={"dst": dst, "bytes": nbytes}
                           if tid else None):
@@ -1242,7 +1172,7 @@ class TcpNet(NetInterface):
         tid = trace_of(msg)
         with monitor("tcp_serialize"), \
                 tracing.span(tid, "tcp_serialize", self._rank):
-            views, nbytes = _frame_views(msg)
+            views, nbytes = serialize_views(msg)
         if tid:
             # The actual socket write happens on the event loop, which
             # only sees bytes — the submit marker is the async path's
@@ -1266,7 +1196,7 @@ class TcpNet(NetInterface):
 
     def queue_depths(self) -> Dict[int, int]:
         """Outbound frames queued (or mid-write) per destination — the
-        live-introspection port autotune and the bench read."""
+        live-introspection port autotune reads."""
         with self._lifecycle:
             peers = list(self._out_peers.items())
         return {dst: peer.depth() for dst, peer in peers}
@@ -1347,33 +1277,6 @@ class TcpNet(NetInterface):
         with self._stats_lock:
             self._bytes_sent += nbytes
 
-    def _pace_reserve(self, nbytes: float) -> float:
-        """Emulated-wire pacing (-net_pace_mbps): one shared outbound
-        link per endpoint, modeled as an absolute busy-until deadline.
-        Each frame reserves its transmission slot and returns the
-        monotonic time before which it must not be written (0.0 when
-        pacing is off); the event loop holds the frame on a timer until
-        then. An overrun on one frame credits the next instead of
-        accumulating — same arithmetic the sleeping version used, just
-        parked on a timer instead of a thread."""
-        mbps = float(get_flag("net_pace_mbps"))
-        if mbps <= 0:
-            return 0.0
-        tx = nbytes * 8.0 / (mbps * 1e6)
-        with self._stats_lock:
-            # Bounded catch-up credit: the loop wakes for a paced frame
-            # with ms-scale jitter (epoll granularity + GIL handoff),
-            # and anchoring each slot at max(now, busy-until) would
-            # compound every late wake into all later slots — the
-            # emulated wire would run measurably under its configured
-            # rate. Let the bucket keep its own timeline instead,
-            # unless the sender falls more than the credit window
-            # behind (idle links still never bank unbounded burst).
-            start = max(time.monotonic() - _PACE_CREDIT_S,
-                        self._wire_free_at)
-            self._wire_free_at = target = start + tx
-        return target
-
     def recv(self, timeout: Optional[float] = None) -> Optional[Message]:
         item = self._inbox.pop(timeout=timeout)
         if item is _RECV_INTERRUPT:
@@ -1416,15 +1319,11 @@ class TcpNet(NetInterface):
         self._loop.run_sync(
             lambda: [self._begin_drain(p) for p in peers.values()],
             timeout=5.0)
-        # Bounded drain per peer, scaled by what is queued (wire-rate
-        # paced frames can legitimately take many seconds); a wedged or
+        # Bounded drain per peer, scaled by what is queued; a wedged or
         # dead peer is force-killed below.
-        pace = float(get_flag("net_pace_mbps"))
         for peer in peers.values():
             pending = peer.queued_bytes
             drain = 2.0 + pending / (4 << 20)  # >=4 MB/s of real wire
-            if pace > 0:
-                drain += pending * 8.0 / (pace * 1e6)
             try:
                 peer.flush(timeout=drain)
             except (PeerLostError, RuntimeError):

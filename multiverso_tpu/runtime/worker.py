@@ -68,8 +68,8 @@ define_int("coalesce_max_kb", 4096,
 class Worker(Actor):
     def __init__(self, zoo) -> None:
         super().__init__(actors.WORKER, zoo)
-        # Depth samples feed the serving tier's pressure surface and
-        # the bench's mailbox report (docs/SERVING.md); gated so a
+        # Depth samples feed the serving tier's pressure surface
+        # (docs/SERVING.md); gated so a
         # training-only run pays nothing per push.
         if mt_queue.depth_sampling_enabled():
             self.mailbox.track_depth("MAILBOX_DEPTH[worker]")
@@ -124,10 +124,6 @@ class Worker(Actor):
         # tables on THIS thread (the same thread that partitions).
         self.register_handler(MsgType.Control_Shard_Map,
                               self._process_shard_map)
-        # Per-destination-server shard counters (bench observability:
-        # per-server request counts localize a hot shard). Plain dict,
-        # actor-thread only; read via snapshot copy.
-        self._reqs_by_dst: Dict[int, int] = {}
 
     def register_table(self, worker_table) -> int:
         self._cache.append(worker_table)
@@ -169,11 +165,6 @@ class Worker(Actor):
         with monitor("WORKER_PROCESS_ADD", msg_id=msg.msg_id,
                      table=msg.table_id):
             self._partition_and_send(msg, MsgType.Request_Add)
-
-    def request_counts(self) -> Dict[int, int]:
-        """Shards sent per destination rank (bench observability;
-        snapshot copy — the actor thread owns the dict)."""
-        return dict(self._reqs_by_dst)
 
     def _process_replica_map(self, msg: Message) -> None:
         """Promoted-row map broadcast from the controller: each table's
@@ -305,7 +296,6 @@ class Worker(Actor):
             if blobs is not None:
                 shard.data = list(blobs)
             self._track((dst, msg.table_id, msg.msg_id))
-            self._reqs_by_dst[dst] = self._reqs_by_dst.get(dst, 0) + 1
             if (self._coalesce and msg_type == MsgType.Request_Add
                     and dst != self._zoo.rank):
                 self._stage_add(dst, shard)
@@ -496,7 +486,6 @@ class Worker(Actor):
                             table_id=msg.table_id, msg_id=msg.msg_id)
             shard.data = list(blobs)
             self._track((dst, msg.table_id, msg.msg_id))
-            self._reqs_by_dst[dst] = self._reqs_by_dst.get(dst, 0) + 1
             count_event(replica_mod.REPLICA_REPAIR)
             self.send_to(actors.COMMUNICATOR, shard)
         return True

@@ -683,11 +683,6 @@ class Zoo:
             f"did not commit within {wait_s}s (layout now: "
             f"{table.shard_layout()}, wanted {expected})")
 
-    def table_shard_epoch(self, table) -> int:
-        """The shard-map epoch ``table`` has adopted (-1 = frozen
-        creation layout). Bench/test observability."""
-        return table.shard_epoch()
-
     def finish_train(self) -> None:
         """Retire this rank's worker from the BSP clocks on all servers."""
         if self.worker_id < 0:

@@ -182,7 +182,7 @@ class AdmissionController:
     def configure(self, max_inflight: Optional[int] = None,
                   shed_depth: Optional[int] = None,
                   retry_after_s: Optional[float] = None) -> None:
-        """Re-knob a live controller (bench overload arms and tests;
+        """Re-knob a live controller (autotune's apply hook and tests;
         production sets the flags before init)."""
         with self._lock:
             if max_inflight is not None:

@@ -15,8 +15,9 @@ staleness-bounded snapshot the brute scan used (docs/SERVING.md):
 
 Recall is a knob, not a constant: embedding tables are clustered by
 construction (that is what training does), so small ``nprobe``
-reaches high recall; the bench measures recall@10 against the brute
-scan and the endpoint keeps a ``brute=1`` escape hatch. The index is
+reaches high recall; tests/test_serving_fleet.py holds recall@10
+against the brute scan on clustered data, and the endpoint keeps a
+``brute=1`` escape hatch. The index is
 a DERIVED cache: it rebuilds under the same pre-fetch-anchored
 version rule as the brute snapshot, plus forced invalidation on a
 data-generation change (reshard / server rejoin — see

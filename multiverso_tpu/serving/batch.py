@@ -131,7 +131,7 @@ class BatchedTableReader:
         self._pending_row_set: set = set()  # guarded_by: _lock
         self._open_t = 0.0  # guarded_by: _lock
         self._stopping = False  # guarded_by: _lock
-        self.batches = 0      # observability (tests/bench)
+        self.batches = 0      # observability (tests)
         self.requests = 0
         self._thread = None
         if self._window > 0:

@@ -82,13 +82,6 @@ _ALL_KEY = np.array([-1], dtype=np.int32)
 # (in-process extension; -1 keeps the reference's host-reply semantics,
 # ref: matrix_table.cpp:267-276 sentinel handling).
 _ALL_KEY_DEVICE_REPLY = np.array([-2], dtype=np.int32)
-# Sentinel -3: PRE-SEGMENTED device-key request — the caller already
-# split its (sorted) device ids into one slice per server, so each
-# server receives ONLY its segment instead of the full broadcast id set
-# (the device twin of the reference's per-server key bucketing,
-# ref: matrix_table.cpp:267-276; the round-4 broadcast+mask form made
-# every server process every key).
-_SEGMENTED_KEY = np.array([-3], dtype=np.int32)
 # Sentinel -4: FUSED sparse add + dirty get — semantically the exact
 # composition of add_rows and get_dirty_device, executed as ONE device
 # program server-side (the 2-program roundtrip pays two per-dispatch
@@ -841,86 +834,13 @@ class MatrixWorker(WorkerTable):
                     functools.reduce(jnp.add, ordered))
             return device_lock.settle(jnp.concatenate(ordered, axis=0))
 
-    def get_rows_device_segments_async(self, segments) -> int:
-        """Pre-segmented device row pull: ``segments`` is one device id
-        vector PER SERVER (the caller computed per-server slices of its
-        sorted ids — e.g. inside the program that produced them, where
-        the searchsorted bounds are free). Each server receives ONLY
-        its segment; out-of-range entries (slice slack / padding)
-        gather as zero rows via the server's bounded gather. Replies
-        come back keyed by server id — consume with
-        ``take_device_row_parts`` and reassemble in the consumer's jit.
-
-        This is the per-server work-conserving form of the device-key
-        protocol: per-server gather cost follows the SEGMENT size, not
-        the full id count (ref per-server bucketing contract:
-        matrix_table.cpp:234-315)."""
-        self._check_frozen_layout("segmented device gets")
-        CHECK(self._zoo.servers_in_process,
-              "segmented device gets need the servers in this process")
-        CHECK(len(segments) == self._num_server,
-              "one segment per server")
-        CHECK(all(is_device_array(s) for s in segments),
-              "segments must be device arrays")
-        # Shape/dtype violations would otherwise surface inside the
-        # server actor, where _safe_dispatch swallows the exception and
-        # the caller hangs in wait() forever — fail in the CALLER.
-        for seg in segments:
-            CHECK(np.dtype(seg.dtype) == np.int32 and len(seg.shape) == 1,
-                  "segments must be 1-D int32 id vectors")
-        CHECK(not self._compress, "device gets bypass wire compression")
-        self._dest, self._dest_rows = None, None
-        self._device_shards = {}
-        self._device_sum = False
-        return self.get_async_raw(Blob(_SEGMENTED_KEY.view(np.uint8)),
-                                  [Blob(s) for s in segments])
-
-    def add_rows_device_segments_async(self, segments, deltas,
-                                       option: Optional[AddOption] = None
-                                       ) -> int:
-        """Pre-segmented device row push: per-server (ids, delta) pairs;
-        each server scatter-adds only its segment (foreign/padding rows
-        mask out-of-range and drop). Same stateless-updater contract as
-        ``add_rows_async`` device keys."""
-        self._check_frozen_layout("segmented device adds")
-        CHECK(self._zoo.servers_in_process,
-              "segmented device adds need the servers in this process")
-        CHECK(len(segments) == self._num_server
-              and len(deltas) == self._num_server,
-              "one (segment, delta) pair per server")
-        CHECK(self._updater_stateless,
-              "device-key row adds need a stateless updater "
-              "(default/sgd): duplicate ids must sum")
-        for seg, delta in zip(segments, deltas):
-            CHECK(is_device_array(seg) and is_device_array(delta),
-                  "segments and deltas must be device arrays")
-            # Fail in the CALLER: inside the server actor these would
-            # be swallowed by _safe_dispatch and the Add ack never
-            # comes, hanging the caller in wait().
-            CHECK(np.dtype(seg.dtype) == np.int32 and len(seg.shape) == 1,
-                  "segments must be 1-D int32 id vectors")
-            CHECK(np.dtype(delta.dtype) == self.dtype,
-                  "segment delta dtype must match the table dtype")
-            CHECK(tuple(delta.shape) ==
-                  tuple(seg.shape) + (self.num_col,),
-                  "bad segment delta shape")
-        blobs = ([Blob(_SEGMENTED_KEY.view(np.uint8))]
-                 + [Blob(s) for s in segments]
-                 + [Blob(d) for d in deltas]
-                 + [self._option_blob(option)])
-        tok = self._cache_begin_add(None)  # device ids: block globally
-        mid = self.request_async_raw(MsgType.Request_Add, blobs)
-        self._cache_resolve_on(mid, tok)
-        return mid
-
     def take_device_row_parts(self):
         """The raw per-server reply shards of the last device get
         WITHOUT assembling them — a consumer that feeds them into its
         own jit can fold the multi-server sum into that program instead
         of paying a separate device op (one more per-dispatch launch
-        cost). Replies carry the origin
-        server id, so parts return in SERVER order (segmented pulls
-        rely on this; the broadcast sum is order-independent)."""
+        cost). Replies carry the origin server id, so parts return in
+        SERVER order (the broadcast sum is order-independent)."""
         shards = self._device_shards
         CHECK(shards is not None and len(shards) > 0,
               "no device row get outstanding")
@@ -1100,22 +1020,6 @@ class MatrixWorker(WorkerTable):
             return {sid: list(blobs) for sid in range(self._num_server)}
         keys = blobs[0].as_array(np.int32)
         out: Dict[int, List[Blob]] = {}
-        if keys.size == 1 and keys[0] == -3:
-            # Pre-segmented device-key request: the caller already
-            # split its ids per server — route segment s (and its delta
-            # for adds) to server s ONLY. Layout:
-            # Get: [-3, seg_0..seg_{S-1}]
-            # Add: [-3, seg_0..seg_{S-1}, delta_0..delta_{S-1}, option]
-            S = self._num_server
-            rest = blobs[1:]
-            if msg_type == MsgType.Request_Get:
-                CHECK(len(rest) == S, "segmented get: one id blob "
-                      "per server")
-                return {s: [rest[s]] for s in range(S)}
-            CHECK(len(rest) == 2 * S + 1, "segmented add: per-server "
-                  "ids + deltas + option")
-            return {s: [rest[s], rest[S + s], rest[2 * S]]
-                    for s in range(S)}
         if keys.size == 1 and keys[0] == -4 \
                 and msg_type == MsgType.Request_Get:
             # Fused add+dirty-get (a Get — it replies): single-server
@@ -1412,9 +1316,7 @@ class MatrixWorker(WorkerTable):
         if reply_blobs[0].on_device:
             # Device-key reply: values arrive shaped
             # row_ids.shape + (num_col,), still in HBM — keyed by the
-            # origin server id (broadcast replies sum, order-free;
-            # segmented replies reassemble positionally, so server
-            # attribution matters).
+            # origin server id (broadcast replies sum, order-free).
             CHECK(self._device_shards is not None,
                   "device reply with no device get outstanding")
             sid = int(reply_blobs[2].as_array(np.int32)[0]) \
@@ -1923,8 +1825,7 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
             gather = self._gather if self._shard_bounds is None \
                 else self._gather_bounded
             # The server id rides along so the worker can key the reply
-            # shard by ORIGIN server — segmented pulls reassemble
-            # positionally and cannot rely on arrival order.
+            # shard by ORIGIN server, not by arrival order.
             return [blobs[0], Blob(gather(self._data, rows)),
                     Blob(np.array([self.server_id], dtype=np.int32))]
         keys = blobs[0].as_array(np.int32)

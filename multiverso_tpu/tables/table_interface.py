@@ -192,7 +192,7 @@ class WorkerTable:
                           blobs: Sequence[Blob]) -> int:
         """Generic async request with an arbitrary blob layout — the
         table subclass's ``partition`` defines what the blobs mean
-        (e.g. the matrix table's pre-segmented device-key requests)."""
+        (e.g. the matrix table's fused add + dirty-get request)."""
         msg_id = self._new_request()
         self._send_request(msg_type, blobs, msg_id)
         return msg_id

@@ -27,7 +27,7 @@ a process at an exact protocol instant.
 the named :func:`kill_point` is reached (default n=1). Points are
 documented where they are placed (grep ``chaos.kill_point``).
 
-Test/bench harness only — never enable in production. Everything here
+Test harness only — never enable in production. Everything here
 is process-local and thread-safe via one small lock.
 """
 

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py`` and its
-children, the app CLIs): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+One rule for every entry point (``chip_smoke.py``, ``benchmark/run.py``,
+the app CLIs): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 reads it itself and this module touches no directory setting; where it
 is not, the cache is ``<checkout>/.jax_cache`` (gitignored), derived
 from this file's location so the path is the same from any working

@@ -34,10 +34,8 @@ CANONICAL_FLAGS: Dict[str, Any] = {
     "port": 55555,
     "rank": -1,
     "send_queue_mb": 32,
-    "net_pace_mbps": 0.0,
     # -- zero-copy wire path (runtime/tcp.py, util/buffer_pool.py;
     #    docs/MEMORY.md) --
-    "zero_copy": True,
     "buffer_pool_mb": 32,
     "buffer_pool_classes": 12,
     # -- shared-memory transport for co-located ranks (runtime/shm.py;
@@ -200,8 +198,8 @@ TUNABLE_FLAGS: Dict[str, str] = {
 
 
 #: Registered apply hooks per tunable flag. Bound methods are held as
-#: ``weakref.WeakMethod`` so a dead owner (a table dropped between
-#: bench phases) silently unregisters instead of leaking or firing on
+#: ``weakref.WeakMethod`` so a dead owner (a table dropped when its
+#: zoo shuts down) silently unregisters instead of leaking or firing on
 #: a corpse; plain functions are held strongly. Guarded by
 #: ``_tunable_lock`` together with the applied-epoch watermark.
 _tunable_hooks: Dict[str, List] = {}
@@ -226,7 +224,7 @@ def register_tunable_hook(name: str,
     ref: Any
     try:
         # Bound methods are held weakly so a dead owner (a table
-        # dropped between bench phases) unregisters itself; plain
+        # dropped when its zoo shuts down) unregisters itself; plain
         # functions and builtin bound methods hold strongly.
         ref = weakref.WeakMethod(hook)
     except TypeError:

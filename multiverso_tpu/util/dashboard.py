@@ -73,8 +73,8 @@ METRIC_NAMES: Dict[str, str] = {
     # -- zero-copy wire path (runtime/tcp.py, util/buffer_pool.py;
     #    docs/MEMORY.md) --
     "WIRE_BYTES_COPIED": "payload+framing bytes memcpy'd by "
-                         "serialize/deserialize (the zero-copy "
-                         "bench signal)",
+                         "serialize/deserialize (near zero on the "
+                         "transport's path)",
     "WIRE_PAYLOAD_BYTES": "payload bytes that crossed "
                           "serialize/deserialize (the copy-ratio "
                           "denominator)",
@@ -335,7 +335,7 @@ class monitor:
             SPAN_PREFIX + self._name, **self._args)
         self._span.__enter__()
         # Re-resolved per entry, NOT cached at construction: a
-        # ``Dashboard.reset()`` (every bench phase does one) replaces
+        # ``Dashboard.reset()`` (tests do one between cases) replaces
         # the registry, and a long-lived ``monitor(...)`` instance
         # caching its Monitor would keep writing to an unregistered
         # orphan that no display()/snapshot ever sees.
@@ -354,7 +354,7 @@ class Samples:
     depths) with percentile readout — the p50/p99 companion to the
     cumulative ``Monitor``. Ring-buffer overwrite past ``cap`` keeps the
     cost O(1) per sample and the memory bounded; percentiles are then
-    over the most recent ``cap`` observations, which is what a bench
+    over the most recent ``cap`` observations, which is what a measured
     window wants anyway."""
 
     def __init__(self, name: str, cap: int = 8192):
@@ -398,7 +398,7 @@ class Samples:
         return self._nearest_rank(data, p)
 
     def snapshot(self) -> dict:
-        """Bench-friendly summary: count + p50/p90/p99/max."""
+        """Summary: count + p50/p90/p99/max."""
         with self._lock:
             data = sorted(self._buf)
             total = self._total

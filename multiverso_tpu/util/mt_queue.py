@@ -44,8 +44,8 @@ class MtQueue(Generic[T]):
         # (the mvlint guarded-by alias group).
         self._buffer: Deque[T] = collections.deque()  # guarded_by: _mutex
         self._exit = False  # guarded_by: _mutex
-        # Depth observability (docs/SERVING.md admission control +
-        # bench mailbox-pressure reporting): the high watermark is
+        # Depth observability (docs/SERVING.md admission control):
+        # the high watermark is
         # always tracked (one compare per push); per-push depth
         # SAMPLES (p50/p99 via util/dashboard.py Samples) only when a
         # metric name was opted in via track_depth — the reservoir's
@@ -59,7 +59,7 @@ class MtQueue(Generic[T]):
         """Record every post-push depth into the named Dashboard
         ``Samples`` reservoir (``MAILBOX_DEPTH[*]`` family). The server
         and worker actors opt their mailboxes in: admission-control
-        decisions and the serving bench both read mailbox pressure."""
+        decisions read mailbox pressure."""
         self._depth_metric = metric_name
 
     def push(self, item: T) -> None:
@@ -72,8 +72,9 @@ class MtQueue(Generic[T]):
         if self._depth_metric is not None:
             # Outside the queue lock: the reservoir has its own, and a
             # sampler must never extend this queue's critical section.
-            # Re-resolved per push (not cached) so a bench-phase
-            # reset_samples() cannot orphan the writer (the
+            # Re-resolved per push (not cached) so a
+            # reset_samples() (tests do one between cases) cannot
+            # orphan the writer (the
             # dashboard.monitor re-resolve precedent).
             samples(self._depth_metric).add(depth)
 
@@ -85,8 +86,8 @@ class MtQueue(Generic[T]):
             return self._depth_high
 
     def reset_depth_watermark(self) -> None:
-        """Re-anchor the watermark at the current depth (bench windows
-        measure per-phase pressure, not lifetime)."""
+        """Re-anchor the watermark at the current depth (a test reads the
+        pressure of its own window, not the lifetime's)."""
         with self._mutex:
             self._depth_high = len(self._buffer)
 

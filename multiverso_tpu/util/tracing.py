@@ -229,7 +229,7 @@ def drain_since(last_seq: int) -> List[Dict]:
 
 
 def reset() -> None:
-    """Drop buffered events (tests / bench phase isolation); the next
+    """Drop buffered events (isolation between tests); the next
     record re-reads -trace_buffer."""
     global _events
     with _lock:

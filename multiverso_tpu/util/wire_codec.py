@@ -133,10 +133,6 @@ from ..core.message import CODEC_SLOT  # noqa: E402
 CAP_WIRE_CODEC = 1
 
 
-def tier_name(tier: int) -> str:
-    return _TIER_NAMES.get(tier, f"tier{tier}")
-
-
 def _dtype_code(dtype: np.dtype) -> Optional[int]:
     return _DTYPE_CODE.get(np.dtype(dtype))
 
@@ -194,8 +190,8 @@ def encode_blob(arr, *, lossy: bool = False,
     """
     parts, residual = encode_blob_views(arr, lossy=lossy, clip=clip)
     frame = b"".join(  # mvlint: ignore[copy-lint] - the FLAT form IS
-        # this wrapper's contract (table-level codec frames, tests,
-        # bench); the wire path rides the unjoined parts
+        # this wrapper's contract (table-level codec frames, tests);
+        # the wire path rides the unjoined parts
         p if isinstance(p, (bytes, bytearray))
         else p.tobytes() for p in parts)  # mvlint: ignore[copy-lint]
     return frame, residual

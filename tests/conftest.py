@@ -25,7 +25,7 @@ import pytest  # noqa: E402
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "slow: heavy chaos/bench tests, excluded from the tier-1 run "
+        "slow: heavy chaos tests, excluded from the tier-1 run "
         "(-m 'not slow')")
 
 

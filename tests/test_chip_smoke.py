@@ -12,12 +12,9 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 sys.path.insert(0, REPO)
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 
 
@@ -91,8 +88,3 @@ def test_dryrun_raises_with_too_few_devices():
     assert "needs 8 devices" in out.stderr
     assert "xla_force_host_platform_device_count=8" in out.stderr
     assert "OK" not in out.stdout  # no child ran the dry run for it
-
-
-def test_utilization_refuses_an_unknown_device_kind():
-    with pytest.raises(ValueError, match="device_kind 'cpu'"):
-        bench.utilization(1.0, 1.0)

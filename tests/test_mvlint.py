@@ -233,7 +233,7 @@ class TestCleanTree:
     def test_final_tree_is_clean(self):
         # The acceptance gate: the shipped tree has zero non-pragma'd
         # violations across all ten passes.
-        result = run(("multiverso_tpu", "tests", "bench.py"), REPO_ROOT)
+        result = run(("multiverso_tpu", "tests"), REPO_ROOT)
         assert not result.failed, \
             "\n".join(v.render() for v in result.violations)
 
@@ -442,7 +442,7 @@ class TestCli:
     def test_clean_tree_exits_zero(self):
         proc = subprocess.run(
             [sys.executable, "-m", "tools.mvlint",
-             "multiverso_tpu", "tests", "bench.py"],
+             "multiverso_tpu", "tests"],
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stdout + proc.stderr
         assert "mvlint: OK" in proc.stdout

@@ -388,7 +388,7 @@ class TestAddCoalescing:
 class TestServerLockScoping:
     def test_two_servers_progress_concurrently_on_host_paths(self,
                                                              monkeypatch):
-        # Regression (round 5: ps_two_servers at 0.809x of single):
+        # Regression (two servers in one process slower than one):
         # the process-wide table lock exists for multi-device jitted
         # dispatch; two LocalFabric servers doing HOST-side control
         # work (KV tables) must not serialize on it. Each server's

@@ -533,6 +533,65 @@ class TestFusedDispatch:
         assert [m.msg_id for m in _replies(zoo)] == [1, 2, 3, 4]
 
 
+    def test_fused_window_is_one_device_dispatch(self):
+        # The dispatch cut, counted: four Gets queued on a device-backed
+        # table are ONE program where the serial server runs four.
+        from multiverso_tpu.util.dashboard import Dashboard
+
+        class DeviceStub(_StubTable):
+            needs_device_lock = True
+
+        def dispatches(fuse_max):
+            set_flag("server_fuse_max", fuse_max)
+            zoo, server = _server_env()
+            t = DeviceStub(zoo)
+            before = Dashboard.get("SERVER_DEVICE_DISPATCHES").count
+            for i in range(1, 5):
+                server.receive(_get(t.table_id, i))
+            server.mailbox.exit()
+            server._main()
+            assert [m.msg_id for m in _replies(zoo)] == [1, 2, 3, 4]
+            return Dashboard.get("SERVER_DEVICE_DISPATCHES").count - before
+
+        assert dispatches(1) == 4
+        assert dispatches(16) == 1
+
+
+def test_fused_get_dedups_shared_rows_bit_identically():
+    # The real table's fused gather: rows asked for by more than one
+    # request are gathered once (SERVER_FUSE_DEDUP_ROWS counts them) and
+    # every request still gets the bits its own serial Get returns.
+    from multiverso_tpu.util.dashboard import Dashboard
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(64, 8, np.float32)
+        rows = np.arange(64, dtype=np.int32)
+        table.add_rows(rows, np.arange(64 * 8, dtype=np.float32)
+                       .reshape(64, 8))
+        server_table = mv.current_zoo()._server_tables[0]
+        asks = [np.array([3, 9, 9, 40], np.int32),
+                np.array([9, 3, 63], np.int32),
+                np.array([0, 40], np.int32)]
+
+        def request(ids):
+            return [Blob(ids.copy())]
+
+        for ids in asks:
+            assert server_table.fuse_eligible(request(ids), True)
+        serial = [server_table.process_get(request(ids)) for ids in asks]
+        before = Dashboard.get("SERVER_FUSE_DEDUP_ROWS").count
+        fused = server_table.process_fused_get(
+            [request(ids) for ids in asks])
+        # 9 positions asked for, 5 distinct rows
+        assert Dashboard.get("SERVER_FUSE_DEDUP_ROWS").count - before == 4
+        for one, many in zip(serial, fused):
+            np.testing.assert_array_equal(
+                np.asarray(many[1].as_array(np.float32)),
+                np.asarray(one[1].as_array(np.float32)))
+    finally:
+        mv.shutdown()
+
+
 class TestSyncForceDisable:
     def test_sync_server_pins_fuse_max_to_one(self):
         set_flag("server_fuse_max", 16)

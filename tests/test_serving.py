@@ -459,6 +459,41 @@ class TestServingFrontend:
         finally:
             frontend.admission.release("rows")
 
+    def test_many_keepalive_connections_held_open(self, serving_env):
+        # The many-connection shape: clients that hold keep-alive
+        # connections open and mostly sit idle. Every connection is
+        # established at once, each answers on its own socket more
+        # than once, and none of them costs a transport thread (the
+        # event loops and ring writers are counted per peer rank).
+        import http.client
+        from multiverso_tpu.runtime import thread_roles
+        frontend, table, base = serving_env
+
+        def transport_threads():
+            alive = thread_roles.roles_alive()
+            return (alive.get(thread_roles.EVENTLOOP, 0)
+                    + alive.get(thread_roles.WRITER, 0))
+
+        before = transport_threads()
+        conns = [http.client.HTTPConnection("127.0.0.1", frontend.port,
+                                            timeout=30)
+                 for _ in range(32)]
+        try:
+            for conn in conns:
+                conn.connect()
+            for _ in range(2):
+                for conn in conns:
+                    conn.request("GET", "/v1/tables/emb/rows?ids=1,5,9")
+                    resp = conn.getresponse()
+                    doc = json.loads(resp.read())
+                    assert resp.status == 200, doc
+                    assert doc["ids"] == [1, 5, 9]
+                    assert doc["max_staleness"] <= doc["staleness_bound"]
+            assert transport_threads() == before
+        finally:
+            for conn in conns:
+                conn.close()
+
     def test_graceful_drain_finishes_inflight(self, serving_env):
         frontend, table, base = serving_env
         orig = table.read_rows_versioned

@@ -231,8 +231,8 @@ class TestReplicaCoordinator:
         # 4 servers reporting once each is ONE round: counts must decay
         # once, not 4 times — a per-report decay would scale the decay
         # rate with the server count and crush every row toward the
-        # threshold exactly on big clusters (the N=4 regression the
-        # bench caught).
+        # threshold exactly on big clusters (a regression seen
+        # at N=4).
         set_flag("replica_hot_rows", 4)
         set_flag("replica_min_gets", 4)
         c = rm.ReplicaCoordinator()

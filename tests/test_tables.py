@@ -433,15 +433,12 @@ class TestDeviceResidentPath:
         with pytest.raises(Exception, match="out of range"):
             table.get_rows(np.array([16], np.int32))
         # Defense in depth: partition itself also rejects non-sentinels
-        # (-3/-4 are the segmented / fused-dirty markers, so the stray
-        # probe uses -5; a bare -3 with no segment blobs fails its own
-        # layout CHECK).
-        with pytest.raises(Exception, match="sentinel"):
-            table.partition([Blob(np.array([-5], np.int32).view(np.uint8))],
-                            MsgType.Request_Get)
-        with pytest.raises(Exception, match="one id blob per server"):
-            table.partition([Blob(np.array([-3], np.int32).view(np.uint8))],
-                            MsgType.Request_Get)
+        # (-4 is the fused-dirty marker; -3 is no longer a sentinel).
+        for stray in (-5, -3):
+            with pytest.raises(Exception, match="sentinel"):
+                table.partition(
+                    [Blob(np.array([stray], np.int32).view(np.uint8))],
+                    MsgType.Request_Get)
 
     def test_sync_server_ticks_clock_on_error(self):
         # BSP: a failed add must still tick the vector clock — otherwise

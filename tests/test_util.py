@@ -338,7 +338,7 @@ class TestMonitorResetRegression:
     def test_monitor_ctx_survives_dashboard_reset(self):
         # Regression (ISSUE 9 satellite): the context manager used to
         # cache its Monitor at CONSTRUCTION, so a Dashboard.reset()
-        # (every bench phase does one) left long-lived monitor(...)
+        # (tests do one between cases) left long-lived monitor(...)
         # instances writing to unregistered orphans invisible to
         # display()/snapshots.
         ctx = monitor("reset_survivor")  # mvlint: ignore[metric-name]

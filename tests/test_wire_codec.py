@@ -2,7 +2,7 @@
 error feedback, and batch-add coalescing framing.
 
 Tier-1 (fast, host-only) coverage for the compact wire format — codec
-regressions fail here instead of only showing up as a bench-phase drift.
+regressions fail here, on the host, before any run on the chip.
 """
 
 import numpy as np

@@ -703,7 +703,7 @@ class TestPSDevicePipeline:
     @pytest.mark.parametrize("mode", ["per_pair", "hs", "two_servers"])
     def test_ps_device_pipeline_grouped_variants(self, tmp_path, mode):
         # The grouped-dispatch wrappers vmap every step variant: the
-        # per-pair quality step (the bench's quality-PS config), the HS
+        # per-pair quality step (`-per_pair` through the PS), the HS
         # step (tuple aux pytree), and multi-server reply tuples.
         from multiverso_tpu.models.wordembedding import (
             PSDeviceCorpusTrainer, PSWord2Vec, TokenizedCorpus)
@@ -784,14 +784,14 @@ class TestPSDevicePipeline:
         assert seps[0] is not None and seps[0] > 0.3, seps
 
     @pytest.mark.parametrize("grouped", [1, 2])
-    def test_ps_device_segmented_matches_broadcast(self, tmp_path,
-                                                   grouped):
-        # Round 5: per-server SEGMENTED device keys (each server gets a
-        # calibrated slice of the sorted ids) must train to the same
-        # tables as the broadcast+mask form — same update math, leaner
-        # routing (ref: src/table/matrix_table.cpp:234-315). Pulled
-        # rows reassemble to identical values; only duplicate-id
-        # scatter-add order may differ, so allow float slop.
+    def test_ps_device_two_servers_match_one_server(self, tmp_path,
+                                                    grouped):
+        # Device keys broadcast to every server, each masks the rows it
+        # does not own, and the step sums the per-server parts: over
+        # two servers that must train to the tables one server gives
+        # (ref: src/table/matrix_table.cpp:234-315). Pulled rows
+        # reassemble to identical values; only duplicate-id scatter-add
+        # order may differ, so allow float slop.
         from multiverso_tpu.models.wordembedding import (
             PSDeviceCorpusTrainer, PSWord2Vec, TokenizedCorpus)
         from multiverso_tpu.runtime.cluster import LocalCluster
@@ -799,8 +799,13 @@ class TestPSDevicePipeline:
         write_topic_corpus(path)
         d = Dictionary.build(str(path), min_count=1)
         tok = TokenizedCorpus.build(d, str(path))
+        rows = np.arange(d.size, dtype=np.int32)
+        # A table's random init is a function of (seed, server id):
+        # start both layouts from the same input rows.
+        start = np.random.default_rng(5).uniform(
+            -0.03, 0.03, (d.size, 16)).astype(np.float32)
 
-        def run(segment):
+        def run(roles):
             def body(rank):
                 config = Word2VecConfig(embedding_size=16, window=3,
                                         epochs=2,
@@ -811,20 +816,20 @@ class TestPSDevicePipeline:
                     for _ in range(2):
                         mv.current_zoo().barrier()
                     return None
+                table = model._in_table
+                assert table._num_server == len(roles)
+                table.add_rows(rows, start - table.get_rows(rows))
                 trainer = PSDeviceCorpusTrainer(
                     model, tok, centers_per_step=128,
-                    blocks_per_dispatch=grouped,
-                    segment_keys=segment)
+                    blocks_per_dispatch=grouped)
                 for epoch in range(2):
                     trainer.train_epoch(seed=epoch)
-                assert (trainer._seg_ids is not None) == segment
-                return model._in_table.get_rows(
-                    np.arange(d.size, dtype=np.int32))
-            return LocalCluster(2, roles=["all", "server"]).run(body)[0]
+                return np.array(table.get_rows(rows), copy=True)
+            return LocalCluster(len(roles), roles=roles).run(body)[0]
 
-        broadcast, segmented = run(False), run(True)
-        np.testing.assert_allclose(segmented, broadcast, rtol=1e-4,
-                                   atol=1e-6)
+        one, two = run(["all"]), run(["all", "server"])
+        assert np.abs(one - start).max() > 1e-3  # it trained
+        np.testing.assert_allclose(two, one, rtol=1e-4, atol=1e-6)
 
 
 class TestBatchGroup:

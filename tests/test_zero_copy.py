@@ -5,9 +5,9 @@ lists drained by vectored ``sendmsg`` writes; the receive side leases
 pooled frame buffers and cuts READ-ONLY Blob views out of them. The
 contract under test:
 
-* frames are BYTE-IDENTICAL to the legacy flat serializer's across the
-  whole header-slot space, codec frames and batch descriptors — no
-  wire break, mixed ``-zero_copy`` builds interoperate;
+* frames are BYTE-IDENTICAL to the reference flat serializer's
+  (``tcp._serialize``) across the whole header-slot space, codec frames
+  and batch descriptors, so the wire format is the documented one;
 * the pool recycles only export-free buffers (a blob-outlived array can
   never be scribbled), leases always succeed, hit/miss/resident
   accounting holds, and concurrent lease/release survives
@@ -390,31 +390,35 @@ class TestTcpZeroCopy:
                     got.data[i].as_array(np.int32),
                     np.full(17, i, np.int32))
 
-    def test_legacy_mode_interop(self):
-        # -zero_copy=0 endpoints speak the identical wire format: a
-        # frame sent by the legacy serializer parses on the view path
-        # and vice versa (flags are process-global, so flip between
-        # directions).
+    def test_echo_copies_framing_bytes_only(self):
+        # What the wire path copies per payload byte, counted: the
+        # framer builds only the length prefix, header and size table,
+        # the parser cuts views out of leased frames, and an echo
+        # re-sends those views. Released leases come back as pool hits.
+        from multiverso_tpu.util.dashboard import Dashboard
+        counters = ("WIRE_BYTES_COPIED", "WIRE_PAYLOAD_BYTES", "POOL_HIT")
+        before = {n: Dashboard.get(n).count for n in counters}
+        payload = np.arange(64 << 10, dtype=np.float32)  # 256 KB
         with _Pair() as (a, b):
-            msg = Message(src=0, dst=1, msg_type=MsgType.Request_Add,
-                          msg_id=4)
-            msg.push(Blob(np.arange(512, dtype=np.float32)))
-            set_flag("zero_copy", False)
-            try:
+            for i in range(8):
+                msg = Message(src=0, dst=1,
+                              msg_type=MsgType.Request_Get, msg_id=i)
+                msg.push(Blob(payload))
                 a.send(msg)
                 got = b.recv(timeout=30)
-            finally:
-                set_flag("zero_copy", True)
-            np.testing.assert_array_equal(
-                got.data[0].as_array(np.float32),
-                np.arange(512, dtype=np.float32))
-            reply = got.create_reply_message()
-            reply.data = list(got.data)
-            b.send(reply)  # zero-copy side echoes
-            back = a.recv(timeout=30)
-            np.testing.assert_array_equal(
-                back.data[0].as_array(np.float32),
-                np.arange(512, dtype=np.float32))
+                reply = got.create_reply_message()
+                reply.data = list(got.data)
+                b.send(reply)
+                back = a.recv(timeout=30)
+                np.testing.assert_array_equal(
+                    back.data[0].as_array(np.float32), payload)
+                del got, reply, back
+                gc.collect()
+        grew = {n: Dashboard.get(n).count - before[n] for n in counters}
+        # Send and receive each count the payload: 8 echoes, 4 passes.
+        assert grew["WIRE_PAYLOAD_BYTES"] == 8 * 4 * payload.nbytes
+        assert grew["WIRE_BYTES_COPIED"] < 1e-3 * grew["WIRE_PAYLOAD_BYTES"]
+        assert grew["POOL_HIT"] > 0
 
 
 class TestLeaseViewHelpers:

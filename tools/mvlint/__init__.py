@@ -1,6 +1,6 @@
 """mvlint: project-invariant static analysis for the actor/PS runtime.
 
-Twelve passes over ``multiverso_tpu/``, ``bench.py`` and ``tests/``
+Twelve passes over ``multiverso_tpu/`` and ``tests/``
 (see each module's docstring for the precise rules):
 
 * ``flag-lint`` — every flag access names a canonical registered flag
@@ -47,7 +47,7 @@ Twelve passes over ``multiverso_tpu/``, ``bench.py`` and ``tests/``
   the state checks and the park, in the lexical order the event loop
   uses post-PR-19 (the lost-wakeup ordering is rejected).
 
-Run locally: ``python -m tools.mvlint multiverso_tpu tests bench.py``
+Run locally: ``python -m tools.mvlint multiverso_tpu tests``
 (``--baseline`` prints per-pass counts without failing;
 ``--report-unused-pragmas`` lists suppressions that matched nothing).
 The runtime complement — the ``-debug_locks`` lock-order witness and
@@ -82,7 +82,7 @@ from .wire_slot_lint import (WireSlotLint, load_msg_types,
 #: Repo root = two levels above this package (tools/mvlint/__init__.py).
 REPO_ROOT = Path(__file__).resolve().parent.parent.parent
 
-DEFAULT_PATHS = ("multiverso_tpu", "tests", "bench.py")
+DEFAULT_PATHS = ("multiverso_tpu", "tests")
 
 
 def build_passes(root: Path = REPO_ROOT) -> List[LintPass]:

@@ -1,6 +1,6 @@
 """CLI: ``python -m tools.mvlint [--baseline] [paths...]``.
 
-Default paths: ``multiverso_tpu tests bench.py`` relative to the repo
+Default paths: ``multiverso_tpu tests`` relative to the repo
 root. Exit status: 0 when no (non-pragma'd) violation was found, 1
 otherwise. ``--baseline`` prints the per-pass violation + suppression
 counts and always exits 0 — the drift-at-a-glance mode future PRs diff

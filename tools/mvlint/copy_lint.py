@@ -13,8 +13,9 @@ reintroduce a payload copy are banned ON THE WIRE-PATH MODULES:
   so both forms are flagged and sanctioned sites carry the pragma);
 * ``b"...".join(...)`` — the flat-frame join.
 
-Sanctioned sites (the legacy ``-zero_copy=0`` serializer kept as the
-golden baseline, the codec's flat-frame compat wrapper) carry
+Sanctioned sites (``tcp._serialize``, the flat serializer kept as the
+golden reference tests/test_zero_copy.py byte-compares the framer
+against; the codec's flat-frame compat wrapper) carry
 ``# mvlint: ignore[copy-lint]`` pragmas — counted, visible exceptions.
 Everything outside the wire-path module list is out of scope: tables,
 models and snapshots copy for their own good reasons.

@@ -526,7 +526,7 @@ class MsgFlowLint(LintPass):
     # -- framework hook ----------------------------------------------
     def check(self, module: ModuleInfo) -> Iterator[Violation]:
         rel = module.rel
-        if rel.startswith("tests/") or rel == "bench.py":
+        if rel.startswith("tests/"):
             return
         if rel.startswith(PKG_PREFIX):
             yield from self._by_module.get(rel, [])

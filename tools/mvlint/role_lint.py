@@ -8,8 +8,8 @@ This pass is the interprocedural version, built on
 :mod:`tools.mvlint.callgraph`:
 
 * **Spawn discipline** — raw ``threading.Thread(...)`` inside
-  ``multiverso_tpu`` is banned (``runtime/thread_roles.py`` itself,
-  tests and bench are exempt); threads start through
+  ``multiverso_tpu`` is banned (``runtime/thread_roles.py`` itself
+  and tests are exempt); threads start through
   ``thread_roles.spawn(ROLE, target=...)``.
 * **Role resolution** — the role argument must be a literal role
   constant, or ``self.ROLE``: then the *binding* decides, and the
@@ -423,7 +423,7 @@ class ThreadRoleLint(LintPass):
     # -- framework hook ----------------------------------------------
     def check(self, module: ModuleInfo) -> Iterator[Violation]:
         rel = module.rel
-        if rel.startswith("tests/") or rel == "bench.py":
+        if rel.startswith("tests/"):
             return
         if rel.startswith(PKG_PREFIX):
             yield from self._by_module.get(rel, [])
