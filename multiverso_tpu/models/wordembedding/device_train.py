@@ -43,7 +43,10 @@ Measured v5e cost model (see PROGRESS notes, round 4): full-table
 sweeps run near peak (~680 GB/s), row gathers ~50-100 GB/s, random-row
 scatter-adds are the slowest path (~13 GB/s at 32K rows) — so the
 design minimizes SCATTERED ROWS first, gathered rows second, and
-never sweeps.
+never sweeps. Every row update of every group program goes through
+``updater.rules.scatter_add``, the function the tables' rows programs
+end in: on a TPU, from 2048 ids, sorted runs and one visit a distinct
+row (updater/row_scatter.py; PERF.md section 6, PRs 28 and 30).
 """
 
 from __future__ import annotations
@@ -57,7 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...runtime import device_lock
-from ...util.dashboard import monitor
+from ...updater.rules import fast_rows, scatter_add
+from ...util.dashboard import count, monitor
 from .data import TokenizedCorpus
 from .model import _MAX_EXP, _sigmoid_xent
 
@@ -210,14 +214,29 @@ def _banded_cbow_loss_and_grads(u_band, u_center, u_neg, pmask):
     return (loss,) + grads + (has_ctx.sum(),)
 
 
+def _scatter_add_pair(table, ids_a, step_a, ids_b, step_b):
+    """Two id sets of one table as ONE scatter-add, as a PS block's Add
+    of its output ids has it: the ids flat, their delta rows
+    concatenated (one sort and one walk of the runs where two calls
+    make two; a row named in both gets ``row + (d_a + d_b)``)."""
+    dim = table.shape[-1]
+    ids = jnp.concatenate([ids_a.reshape(-1), ids_b.reshape(-1)])
+    step = jnp.concatenate([step_a.reshape(-1, dim),
+                            step_b.reshape(-1, dim)])
+    return scatter_add(table, ids, step)
+
+
 def _apply_step(C, W, K, cbow, emb_in, emb_out, kept_pad, ksent_pad,
                 neg_prob, neg_alias, key, base, lr, n_kept,
                 neg_block: int = 1):
     """One full in-jit banded training step against local table arrays
     — band former + objective + scatter-add updates of C+2W band rows,
     C center rows and C//B negative rows (vs the C*(2W+K) scattered
-    rows of the row-matrix form). Shared by the single-device group
-    scan and the MA mesh path so the update math cannot diverge.
+    rows of the row-matrix form), one ``rules.scatter_add`` a table as
+    a PS block has one Add a table: XLA's scatter or the sorted-runs
+    kernel by what ``rules.fast_rows`` reads from the shapes. Shared
+    by the single-device group scan and the MA mesh path so the update
+    math cannot diverge.
     ``kept_pad``/``ksent_pad`` must come from ``_pad_stream``. Returns
     (emb_in, emb_out, loss, examples)."""
     k_shrink, k_idx, k_keep = jax.random.split(key, 3)
@@ -232,18 +251,18 @@ def _apply_step(C, W, K, cbow, emb_in, emb_out, kept_pad, ksent_pad,
         u_neg = emb_out[negs]                 # [C//B, K, D]
         loss, g_band, g_center, g_neg, examples = \
             _banded_cbow_loss_and_grads(u_band, u_center, u_neg, pmask)
-        emb_in = emb_in.at[band].add(-lr * g_band)
-        emb_out = emb_out.at[centers].add(-lr * g_center)
-        emb_out = emb_out.at[negs].add(-lr * g_neg)
+        emb_in = scatter_add(emb_in, band, -lr * g_band)
+        emb_out = _scatter_add_pair(emb_out, centers, -lr * g_center,
+                                    negs, -lr * g_neg)
         return emb_in, emb_out, loss, examples
     v = emb_in[centers]              # [C, D]
     u_band = emb_out[band]           # [C+2W, D]
     u_neg = emb_out[negs]            # [C//B, K, D]
     loss, g_v, g_band, g_neg = _banded_sgns_loss_and_grads(
         v, u_band, u_neg, pmask)
-    emb_in = emb_in.at[centers].add(-lr * g_v)
-    emb_out = emb_out.at[band].add(-lr * g_band)
-    emb_out = emb_out.at[negs].add(-lr * g_neg)
+    emb_in = scatter_add(emb_in, centers, -lr * g_v)
+    emb_out = _scatter_add_pair(emb_out, band, -lr * g_band,
+                                negs, -lr * g_neg)
     return emb_in, emb_out, loss, pmask.sum()
 
 
@@ -293,9 +312,9 @@ def _seq_pair_step(C, W, K, emb_in, emb_out, kept_pad, ksent_pad,
         negs = negs_all[w]                       # [C, K]
         loss, g_v, g_pos, g_neg = _pair_offset_loss_and_grads(
             emb_in[centers], emb_out[ctx], emb_out[negs], pmask[:, w])
-        emb_in = emb_in.at[centers].add(-lr * g_v)
-        emb_out = emb_out.at[ctx].add(-lr * g_pos)
-        emb_out = emb_out.at[negs].add(-lr * g_neg)
+        emb_in = scatter_add(emb_in, centers, -lr * g_v)
+        emb_out = _scatter_add_pair(emb_out, ctx, -lr * g_pos,
+                                    negs, -lr * g_neg)
         loss_acc = loss_acc + loss
     return emb_in, emb_out, loss_acc, pmask.sum()
 
@@ -414,8 +433,8 @@ def _group_fn_hs(C: int, W: int, cbow: bool = False):
             u_path = emb_out[out_ids]             # [C, L, D]
             loss, g_band, g_path, examples = _hs_cbow_loss_and_grads(
                 u_band, u_path, path, code, pmask)
-            emb_in = emb_in.at[band].add(-lr * g_band)
-            emb_out = emb_out.at[out_ids].add(-lr * g_path)
+            emb_in = scatter_add(emb_in, band, -lr * g_band)
+            emb_out = scatter_add(emb_out, out_ids, -lr * g_path)
             return emb_in, emb_out, loss, examples
         path_band = points[band]                  # [C+2W, L]
         code_band = codes[band]
@@ -424,8 +443,8 @@ def _group_fn_hs(C: int, W: int, cbow: bool = False):
         u_band_path = emb_out[out_ids]            # [C+2W, L, D]
         loss, g_v, g_band_path = _hs_sg_loss_and_grads(
             v, u_band_path, path_band, code_band, pmask)
-        emb_in = emb_in.at[centers].add(-lr * g_v)
-        emb_out = emb_out.at[out_ids].add(-lr * g_band_path)
+        emb_in = scatter_add(emb_in, centers, -lr * g_v)
+        emb_out = scatter_add(emb_out, out_ids, -lr * g_band_path)
         return emb_in, emb_out, loss, pmask.sum()
 
     return _make_group(step, pad=(C, W))
@@ -475,11 +494,6 @@ def _ma_group_fn(mesh, C: int, W: int, K: int, neg_block: int = 1):
                      keys, bases, lrs, n_kept_local):
         key = keys[0]
         n_kept = n_kept_local[0]
-        # The replicated tables DIVERGE per device once local training
-        # starts — annotate them device-varying so the scan carry types
-        # line up (pmean at the end collapses them back).
-        emb_in = jax.lax.pcast(emb_in, axis, to="varying")
-        emb_out = jax.lax.pcast(emb_out, axis, to="varying")
         # Pad each device's LOCAL stream for the banded slices (inside
         # shard_map, so this is a per-shard local op).
         kept_pad, ksent_pad = _pad_stream(C, W, kept, ksent)
@@ -506,7 +520,13 @@ def _ma_group_fn(mesh, C: int, W: int, K: int, neg_block: int = 1):
         device_group, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(), P(),
                   P(axis), P(), P(), P(axis)),
-        out_specs=(P(), P(), P(), P(), P(axis)))
+        out_specs=(P(), P(), P(), P(), P(axis)),
+        # The replicated tables DIVERGE per device once local training
+        # starts and pmean collapses them back; the step's scatter-add
+        # may be an exported kernel program (row_scatter.py), whose
+        # call carries no varying types, so they are not checked here,
+        # as in row_scatter's own shard_map.
+        check_vma=False)
     return jax.jit(mapped, donate_argnums=(0, 1))
 
 
@@ -581,6 +601,38 @@ class DeviceCorpusTrainer:
                                     config.negative, bool(config.cbow),
                                     B, per_pair)
             self._aux = (model._neg_prob_dev, model._neg_alias_dev)
+        # What a dispatch counts, as the tables' engine counts its Adds:
+        # one a scatter-add call of a block, by the path the call takes.
+        # rules.fast_rows reads only what is static, so it is known
+        # here, from the id count of the input table's call and of the
+        # output table's (one call each a step; -per_pair one an offset).
+        band, calls = self._C + 2 * config.window, 1
+        if config.hs:
+            in_ids, out_ids = ((band, self._C * path_len) if config.cbow
+                               else (self._C, band * path_len))
+        elif per_pair:
+            in_ids, out_ids = self._C, self._C * (1 + config.negative)
+            calls = 2 * config.window
+        else:
+            negs = self._C // B * config.negative
+            in_ids, out_ids = ((band, self._C + negs) if config.cbow
+                               else (self._C, band + negs))
+        self._add_counts = tuple(
+            ("UPDATE_ROWS_FAST" if fast_rows(table.shape, table.dtype, n)
+             else "UPDATE_ROWS_XLA", calls)
+            for table, n in ((model._emb_in, in_ids),
+                             (model._emb_out, out_ids)))
+        # One signature for every dispatch of the group. A program that
+        # calls an exported one (the kernel's chunk program) hands back
+        # arrays COMMITTED to their device, so tables or a key that go
+        # in uncommitted the first time would make the same program a
+        # second and a third one to jit, each compiled, one of them in
+        # the second epoch. The tables are committed here and each
+        # epoch's key below: no copy, they are on that device.
+        with device_lock.guard():
+            model._emb_in, model._emb_out = device_lock.settle(
+                jax.device_put((model._emb_in, model._emb_out),
+                               model._emb_in.sharding))
         # Post-subsampling tokens actually trained (centers), across
         # epochs — the exact basis for utilization accounting.
         self.kept_words_trained = 0
@@ -600,6 +652,9 @@ class DeviceCorpusTrainer:
             key, prep_key = jax.random.split(key)
             kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
             n_kept = int(n_kept_dev)  # the one host fetch per epoch
+            with device_lock.guard():  # committed, as a group returns it
+                key = device_lock.settle(
+                    jax.device_put(key, model._emb_in.sharding))
         steps = max(math.ceil(n_kept / C), 1)
         if max_steps:
             steps = min(steps, max_steps)
@@ -624,6 +679,8 @@ class DeviceCorpusTrainer:
                     model._emb_in, model._emb_out, kept, ksent,
                     self._aux[0], self._aux[1], key,
                     jnp.asarray(bases), jnp.asarray(lrs), n_kept_dev))
+            for path, calls in self._add_counts:
+                count(path, real * calls)
             loss_acc = loss if loss_acc is None else loss_acc + loss
             pair_acc = pairs if pair_acc is None else pair_acc + pairs
             if group_hook is not None:

@@ -6,7 +6,7 @@ reference applies per-element OpenMP loops on the server thread; here each
 rule is a pure function over whole (sharded) arrays, jit-compiled once per
 table with donated buffers so updates happen in-place in HBM, and a `rows`
 variant for row-sparse traffic whose last step is a row scatter-add
-(``_scatter_add``: the sorted-runs kernel of row_scatter.py on a TPU for
+(``scatter_add``: the sorted-runs kernel of row_scatter.py on a TPU for
 ``FAST_MIN_IDS`` ids or more, XLA's scatter otherwise; ``fast_rows`` is
 the rule, read from shapes, dtype and the table's mesh alone).
 
@@ -94,10 +94,12 @@ def fast_rows(shape, dtype, n_ids: int, mesh=None) -> bool:
             and n_ids >= FAST_MIN_IDS)
 
 
-def _scatter_add(data, row_ids, step, mesh=None):
+def scatter_add(data, row_ids, step, mesh=None):
     """``data[row_ids] += step``, out-of-range ids dropped: the last
-    operation of every rule's rows form, under one scope name so that a
-    device trace shows it apart from the rule's arithmetic.
+    operation of every rule's rows form and every row update of the
+    word2vec trainers' group programs (device_train.py), under one
+    scope name so that a device trace shows it apart from the
+    arithmetic before it.
 
     One algorithm in two forms, chosen by ``fast_rows``. Small id
     counts, other dtypes and other backends take XLA's scatter, which
@@ -150,7 +152,7 @@ class DefaultRule(UpdaterRule):
         return data + delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return _scatter_add(data, row_ids, delta, self.mesh), state
+        return scatter_add(data, row_ids, delta, self.mesh), state
 
 
 class SGDRule(UpdaterRule):
@@ -160,7 +162,7 @@ class SGDRule(UpdaterRule):
         return data - delta, state
 
     def rows(self, data, state, row_ids, delta, hyp, worker_id):
-        return _scatter_add(data, row_ids, -delta, self.mesh), state
+        return scatter_add(data, row_ids, -delta, self.mesh), state
 
 
 class MomentumRule(UpdaterRule):
@@ -180,7 +182,7 @@ class MomentumRule(UpdaterRule):
         smooth_rows = (m * state.at[row_ids].get(mode="fill", fill_value=0)
                        + (1 - m) * delta)
         state = state.at[row_ids].set(smooth_rows, mode="drop")
-        return _scatter_add(data, row_ids, -smooth_rows, self.mesh), state
+        return scatter_add(data, row_ids, -smooth_rows, self.mesh), state
 
 
 class AdaGradRule(UpdaterRule):
@@ -206,7 +208,7 @@ class AdaGradRule(UpdaterRule):
         g_sqr = g_rows + grad * grad
         step = rho * grad * jax.lax.rsqrt(g_sqr + ADAGRAD_EPS)
         state = state.at[worker_id, row_ids].set(g_sqr, mode="drop")
-        return _scatter_add(data, row_ids, -step, self.mesh), state
+        return scatter_add(data, row_ids, -step, self.mesh), state
 
 
 class DCASGDRule(UpdaterRule):
@@ -250,7 +252,7 @@ class DCASGDRule(UpdaterRule):
         # same pre-update rows for each duplicate, like momentum/adagrad's
         # once-per-unique-row state). The backup records one step for a
         # duplicated row — second-order staleness error, documented.
-        data = _scatter_add(data, row_ids, -step, self.mesh)
+        data = scatter_add(data, row_ids, -step, self.mesh)
         state = state.at[worker_id, row_ids].set(rows_now - step,
                                                  mode="drop")
         return data, state

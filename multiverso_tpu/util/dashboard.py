@@ -152,13 +152,18 @@ METRIC_NAMES: Dict[str, str] = {
     "TABLE_INIT": "MatrixServer random_init: the uniform draw on the "
                   "devices, one program a table, each shard its own "
                   "rows (sharding/mesh.py uniform_sharded), to ready",
-    # -- row Adds by the path their shapes chose (updater/engine.py) --
-    "UPDATE_ROWS_FAST": "apply_rows / apply_rows_gather dispatches whose "
-                        "scatter-add is the sorted-runs kernel "
-                        "(updater/row_scatter.py; rules.fast_rows)",
-    "UPDATE_ROWS_XLA": "apply_rows / apply_rows_gather dispatches whose "
-                       "scatter-add is XLA's scatter (off the TPU, other "
-                       "dtypes, under rules.FAST_MIN_IDS ids)",
+    # -- row scatter-adds by the path their shapes chose: a table's Add
+    # (updater/engine.py), a block of the local word2vec trainer's
+    # group (models/wordembedding/device_train.py) --
+    "UPDATE_ROWS_FAST": "apply_rows / apply_rows_gather dispatches, and "
+                        "the scatter-add calls of each block a "
+                        "DeviceCorpusTrainer group trained (two a "
+                        "block), whose scatter-add is the sorted-runs "
+                        "kernel (updater/row_scatter.py; "
+                        "rules.fast_rows)",
+    "UPDATE_ROWS_XLA": "the same dispatches and calls whose scatter-add "
+                       "is XLA's scatter (off the TPU, other dtypes, "
+                       "under rules.FAST_MIN_IDS ids)",
     # -- device-corpus trainers (models/wordembedding/device_train.py) --
     "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
                           "dispatch: _prep (subsample mask, one sort "

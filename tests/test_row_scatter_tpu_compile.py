@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from multiverso_tpu.models.wordembedding import device_train
 from multiverso_tpu.sharding import mesh as meshlib
 from multiverso_tpu.updater import UpdateEngine, rules
 
@@ -55,3 +56,63 @@ def test_the_rows_program_compiles_for_a_v5e(topo, chips):
     # the table is updated in place (its tiles pad the rows to eights)
     assert memory.alias_size_in_bytes >= ROWS * COLS * 4 // chips
     assert memory.temp_size_in_bytes < 50e6
+
+
+# The local trainer's group program (device_train._group_fn) and the
+# model-average one (_ma_group_fn) end every step in the same
+# scatter-add, with no mesh: the platform rules.fast_rows asks for is
+# the default backend's, the CPU's here, so the test steers it. The
+# functions behind the lru_caches are called, so that no program traced
+# this way is left where a CPU test would find it.
+C, W, K, NEG_BLOCK, GROUP = 32768, 5, 5, 8, 8
+STREAM = 1_000_000
+
+
+def _group_args(table, stream, whole, keys, n_kept):
+    """The arguments of a group program as shapes: tables, the kept
+    stream and its sentences, the alias tables, key(s), the group's
+    bases and learning rates, the kept count(s)."""
+    shaped = jax.ShapeDtypeStruct
+    return (table, table, stream, stream,
+            shaped((ROWS,), jnp.float32, sharding=whole),
+            shaped((ROWS,), jnp.int32, sharding=whole), keys,
+            shaped((GROUP,), jnp.int32, sharding=whole),
+            shaped((GROUP,), jnp.float32, sharding=whole), n_kept)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_trainers_group_program_compiles_for_a_v5e(
+        topo, monkeypatch, chips):
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    assert rules.fast_rows((ROWS, COLS), np.float32, C)
+    mesh = Mesh(np.array(topo.devices[:chips]), (meshlib.SHARD_AXIS,))
+    whole = NamedSharding(mesh, P())
+    split = NamedSharding(mesh, P(meshlib.SHARD_AXIS))
+    shaped = jax.ShapeDtypeStruct
+    table = shaped((ROWS, COLS), jnp.float32, sharding=whole)
+    if chips == 1:
+        group = device_train._group_fn.__wrapped__(
+            C, W, K, False, NEG_BLOCK)
+        args = _group_args(
+            table, shaped((STREAM,), jnp.int32, sharding=whole),
+            whole, shaped((2,), jnp.uint32, sharding=whole),
+            shaped((), jnp.int32, sharding=whole))
+    else:  # every chip a replica and a quarter of the stream
+        group = device_train._ma_group_fn.__wrapped__(
+            mesh, C, W, K, NEG_BLOCK)
+        args = _group_args(
+            table,
+            shaped((chips * STREAM,), jnp.int32, sharding=split), whole,
+            shaped((chips, 2), jnp.uint32, sharding=split),
+            shaped((chips,), jnp.int32, sharding=split))
+    compiled = group.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    # Both tables are updated in place through the scan and the
+    # kernel's loop: a copy of one would be a table's size (512 MB) of
+    # temporaries. What is there is what the program had with XLA's
+    # scatter (121.6 MB then, 122.1 now): the padded stream and its
+    # sentences, a step's gathered rows and gradients, and now the
+    # concatenated deltas and one chunk of sorted delta rows.
+    assert memory.alias_size_in_bytes >= 2 * ROWS * COLS * 4
+    assert memory.temp_size_in_bytes < 150e6

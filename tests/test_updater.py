@@ -275,7 +275,7 @@ def _table_and_delta(rng, ids):
 
 
 def _interpreted(table, ids, delta, mesh=None):
-    """rules._scatter_add's fast form with the kernel interpreted."""
+    """rules.scatter_add's fast form with the kernel interpreted."""
     flat = jnp.asarray(ids, jnp.int32).reshape(-1)
     flat = jnp.where(flat < 0, flat + table.shape[0], flat)
     return row_scatter.scatter_add(

@@ -19,9 +19,21 @@ Cases: 32,768 and 53,258 Zipf(1.0) ids (the input and output tables'
 ids of one SGNS block of ``sgns8m.ps``; the share that is distinct is
 printed) and 131,072 sorted distinct ids (``mperf16m.rows``' bucket),
 128 float32 a row. ``--small`` adds the sweep over small id counts that
-gives ``rules.FAST_MIN_IDS``, the crossover. Every way's table is
-compared with (a)'s. One JSON line a reading; the table of PERF.md
-section 6 (PR 28) is this output.
+gives ``rules.FAST_MIN_IDS``, the crossover. ``--hs`` adds the output
+ids of one hierarchical-softmax step of the local trainer: 32,778 band
+words by the 24 nodes of their paths down a binary tree, 786,672 ids
+of which about an eighth are distinct. Every way's table is compared
+with (a)'s. One JSON line a reading; the table of PERF.md section 6
+(PR 28) is this output.
+
+``--scan`` times (a) and (c) on the two Zipf cases as the local
+trainer's group program has them: ``SCAN_STEPS`` steps of a
+``lax.scan`` whose carry is the table, each step gathering the rows it
+is about to add into (a trainer's step reads them for its gradients),
+the table donated to the whole. ``ms`` is a step; ``in scan: the
+gather alone`` is the step without its scatter-add, so the difference
+stands beside the standalone reading and a copy of the table in the
+loop (5 ms at 4.1 GB) would show.
 """
 
 import argparse
@@ -98,12 +110,56 @@ def dedup_only(table, ids, delta):
         table.dtype) * 0)
 
 
+SCAN_STEPS = 8
+
+
+def in_scan(fn):
+    """``fn`` as the last operation of a scan step that carries the
+    table and has read the rows first."""
+
+    def group(table, ids, delta):
+        def body(table, _):
+            step = delta + 1e-6 * table[ids]
+            return fn(table, ids, step), None
+
+        return lax.scan(body, table, None, length=SCAN_STEPS)[0]
+
+    return group
+
+
+def no_add(table, ids, step):
+    """A step's reads and arithmetic with no scatter-add behind them."""
+    return table.at[0].add(0.0 * step.sum(axis=0))
+
+
+#: One HS step of the local trainer: the band's words, the nodes a path.
+HS_WORDS, HS_PATH = 32768 + 10, 24
+
+
+def hs_ids(rng, num_rows):
+    """The inner nodes on the paths of ``HS_WORDS`` Zipf(1.0) words
+    down a binary tree over the rows, root first, the last node of
+    every path a padding that names row 0 (the trainer's
+    ``max(point, 0)`` of a -1)."""
+    depth = HS_PATH - 1
+    rank = zipf_ranks(rng, HS_WORDS, num_rows)
+    level = np.arange(depth)
+    nodes = (1 << level) - 1 + (rank[:, None] >> (depth - level))
+    nodes = np.concatenate([nodes % num_rows,
+                            np.zeros((HS_WORDS, 1), np.int64)], axis=1)
+    return nodes.reshape(-1).astype(np.int32)
+
+
+def zipf_ranks(rng, k, num_rows):
+    """k draws of Zipf(1.0) ranks over the rows."""
+    return np.exp(rng.random(k) * np.log(num_rows)).astype(np.int64) - 1
+
+
 def zipf_ids(rng, k, num_rows):
-    """k draws of Zipf(1.0) ranks over the rows, the ranks scattered
-    over the table by a fixed odd multiplier."""
-    u = rng.random(k)
-    rank = np.exp(u * np.log(num_rows)).astype(np.int64) - 1
-    return ((rank * 2654435761) % num_rows).astype(np.int32)
+    """k Zipf(1.0) ranks, scattered over the table by a fixed odd
+    multiplier."""
+    return ((zipf_ranks(rng, k, num_rows) * 2654435761)
+            % num_rows).astype(np.int32)
 
 
 @jax.jit
@@ -127,6 +183,8 @@ def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--rows", type=int, default=8_000_008)
     parser.add_argument("--small", action="store_true")
+    parser.add_argument("--hs", action="store_true")
+    parser.add_argument("--scan", action="store_true")
     parser.add_argument("--seed", type=int, default=28)
     args = parser.parse_args()
     device = jax.devices()[0]
@@ -139,10 +197,16 @@ def main() -> None:
     cases = [("zipf", 32768), ("zipf", 53258), ("sorted_distinct", 131072)]
     if args.small:
         cases += [("zipf", k) for k in (256, 1024, 2048, 4096, 8192)]
+    if args.hs:
+        cases.append(("hs_paths", HS_WORDS * HS_PATH))
+    if args.scan:
+        cases += [("zipf_in_scan", 32768), ("zipf_in_scan", 53258)]
     fresh = jax.jit(lambda: jnp.zeros((args.rows, COLS), jnp.float32))
     for kind, k in cases:
-        if kind == "zipf":
+        if kind.startswith("zipf"):
             host_ids = zipf_ids(rng, k, args.rows)
+        elif kind == "hs_paths":
+            host_ids = hs_ids(rng, args.rows)
         else:
             host_ids = np.sort(rng.choice(args.rows, k, replace=False)
                                ).astype(np.int32)
@@ -153,18 +217,24 @@ def main() -> None:
                 ("kernel", kernel), ("kernel's sort alone", dedup_only)]
         if kind == "sorted_distinct":
             ways.insert(1, ("xla+hints", xla_hints))
-        if k < 32768:
+        if k < 32768 or kind == "hs_paths":
             ways = [ways[0], ways[-2]]
+        if kind == "zipf_in_scan":
+            ways = [("in scan: xla", in_scan(xla)),
+                    ("in scan: kernel", in_scan(kernel)),
+                    ("in scan: the gather alone", in_scan(no_add))]
         want = None
         for name, fn in ways:
             seconds, table = timed(fn, fresh(), ids, delta)
+            if kind == "zipf_in_scan":
+                seconds /= SCAN_STEPS
             line = {"case": kind, "k": k,
                     "distinct_share": len(np.unique(host_ids)) / k,
                     "way": name, "ms": seconds * 1e3,
                     "ns_per_row": seconds / k * 1e9}
-            if name == "xla":
+            if want is None:
                 want = table
-            elif fn is not dedup_only:
+            elif "alone" not in name:
                 # REPEATS + 2 applications of the same Add on zeros.
                 diff, equal = compare(table, want)
                 line["max_abs_diff"], line["equal"] = float(diff), bool(equal)
