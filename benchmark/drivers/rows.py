@@ -20,6 +20,7 @@ class Driver:
         self.kept = None       # sampled rows of each Get's reply
         self.gets_kept = 0
         self.problems = []     # requests that failed
+        self.compared = {}     # what check() compared: [value, limit]
 
     # -- set-up ---------------------------------------------------------
     def build(self):
@@ -103,9 +104,13 @@ class Driver:
               f"{self.gets_kept} of {gets} Gets", flush=True)
         if not np.isfinite(final).all():
             wrong.append("final table: non-finite values")
-        wrong += rows_replay.replay(self.traffic, self.log, final,
+        differ = rows_replay.replay(self.traffic, self.log, final,
                                     self.config["cols"])
-        return wrong
+        self.compared = {
+            "requests_failed": [len(self.problems), 0],
+            "replies_and_tables_that_differ": [len(differ),
+                                               rows_replay.TOLERANCE]}
+        return wrong + differ
 
     def close(self):
         import multiverso_tpu as mv

@@ -9,7 +9,8 @@ says why).
 
 A block is one step of ``centers_per_block`` centers. The measured
 window opens before an epoch's call, so the epoch's own preparation
-(the subsampling argsort of the corpus, `_prep`) is inside it, and
+(`_prep`: the subsampling mask and the one sort that compacts the
+corpus by it) is inside it, and
 closes inside the trainer's hook at the first block boundary after
 ``seconds``, with a forced sync. The corpus is sized so that the window
 ends inside its first epoch; the hook then leaves the epoch by an
@@ -46,6 +47,7 @@ class Driver:
         self.epoch = 0
         self.block_losses = []   # PS: device scalars, one a block
         self.epochs_done = []    # (loss_sum, pairs) of finished epochs
+        self.compared = {}       # what check() compared: [value, limit]
 
     # -- set-up ---------------------------------------------------------
     def build(self):
@@ -163,7 +165,6 @@ class Driver:
         for t, blocks in state["ticks"]:
             slices[min(int((t - window.t_start) // SLICE_S),
                        len(slices) - 1)] += blocks
-        window.samples["epoch_start_ms"] = [t * 1e3 for t in first_tick]
         print(f"[bench] epochs begun: {len(first_tick)}, call to first "
               f"block {[round(t, 3) for t in first_tick]} s; blocks in "
               f"each {SLICE_S:g} s of the window: {slices}", flush=True)
@@ -182,7 +183,8 @@ class Driver:
         if self.use_ps and not all(
                 math.isfinite(float(x)) for x in self.block_losses):
             wrong.append("non-finite block loss")
-        wrong += sgns_block.check(self)
+        self.compared["non_finite_losses"] = [len(wrong), 0]
+        wrong += sgns_block.check(self)   # adds the block's three
         return wrong
 
     def close(self):

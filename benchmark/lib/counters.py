@@ -25,3 +25,11 @@ def ms_per_request(counters: dict, names) -> float:
     if not count:
         return None
     return sum(counters.get(n, {}).get("ms", 0.0) for n in names) / count
+
+
+def share(counters: dict, name: str, other: str) -> float:
+    """Percent of ``name``'s count in the two counts together; None where
+    neither counted."""
+    mine = counters.get(name, {}).get("count", 0)
+    both = mine + counters.get(other, {}).get("count", 0)
+    return 100.0 * mine / both if both else None

@@ -19,7 +19,9 @@ class Window:
     def __init__(self, builds):
         self._builds = builds
         self._mark = builds.mark()
-        self._before = counters.snapshot()
+        # every monitor as it stood when the window opened; the first
+        # window's is what set-up counted
+        self.at_open = counters.snapshot()
         self.rounds = 0
         self.work = {}
         self.attempted = 0
@@ -50,7 +52,7 @@ class Window:
     def close(self):
         self.seconds = time.monotonic() - self.t_start
         gc.callbacks.remove(self._on_gc)
-        self.counters = counters.delta(self._before, counters.snapshot())
+        self.counters = counters.delta(self.at_open, counters.snapshot())
         self.builds = self._builds.since(self._mark)
         return self
 

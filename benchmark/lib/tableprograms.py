@@ -1,7 +1,8 @@
 """The table's device programs by the stems their hashed names leave
-(`jit__lambda_4628...` -> `jit__lambda`). The program gives them no
-stable scope names yet (the `tracing` issue), so these stems are what a
-metric can key on:
+(`jit__lambda_4628...` -> `jit__lambda`). The metrics of PR 23 key on
+these; since PR 24 the programs' operations also carry `jax.named_scope`
+names (`mv.table.gather`, `mv.update.scatter_add`, ...), which
+`xplane.reduce` gives as `scopes` and newer metrics read:
 
 - ``jit__lambda``      MatrixServer._gather: the row gather of a Get
 - ``jit_rows_padded``  UpdateEngine's rows program: the scatter-add of an Add

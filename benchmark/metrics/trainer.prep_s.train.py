@@ -1,8 +1,8 @@
 """Seconds an epoch's preparation took inside the trainer (Dashboard
 TRAINER_EPOCH_PREP over its count: `train_epoch`'s entry to its first
 block's dispatch, so `_prep`, `_pad` and the readback of the kept count;
-measured window, profiler off). `trainer.epoch_start_s.train` times the
-same from outside, with the first block in it."""
+measured window, profiler off). The driver's log line "call to first
+block" times the same from outside, with the first block in it."""
 
 from benchmark.lib import counters
 

@@ -129,9 +129,74 @@ def _relative(observed, expected, ids, near) -> float:
                  / max(np.linalg.norm(expected), 1e-30)), int(out.sum())
 
 
+def _explain(table, ids, observed, expected, out, pair_logits):
+    """A failed comparison explains itself: the ten rows of ``table``
+    whose change differs most, each with the logits of the pairs it takes
+    part in that lie nearest the clip, their distance from it, and the
+    rounding bound of each (2**-8 of sum |v_i u_i|: what a bfloat16 pass
+    of the product can move it by). A switched gradient shows as a row
+    whose nearest pair is about as far from the clip as its bound."""
+    ids = np.asarray(ids).reshape(-1)
+    off = np.linalg.norm(observed - expected, axis=1)
+    off[out] = 0.0
+    whole = max(np.linalg.norm(expected[~out]), 1e-30)
+    seen = set()
+    for at in np.argsort(-off):
+        if len(seen) == 10 or not off[at]:
+            break
+        if int(ids[at]) in seen:
+            continue
+        seen.add(int(ids[at]))
+        logits, bounds = (np.concatenate(x) for x in zip(*(
+            pair_logits(int(i)) for i in np.flatnonzero(ids == ids[at]))))
+        nearest = np.argsort(np.abs(np.abs(logits) - MAX_EXP))[:4]
+        pairs = ", ".join(
+            f"{logits[j]:+.4f} ({abs(abs(logits[j]) - MAX_EXP):.4f} from "
+            f"the clip, rounding bound {bounds[j]:.4f})" for j in nearest)
+        print(f"[bench] reference block: {table} row {int(ids[at])} "
+              f"({int((ids == ids[at]).sum())} occurrences, {logits.size} "
+              f"pairs) is off by {off[at]:.3e}, {off[at] / whole:.2e} of "
+              f"the change's norm; its change {np.linalg.norm(expected[at]):.3e}"
+              f"; logits nearest the clip: {pairs}", flush=True)
+
+
+def _pair_logits(v, u_band, u_neg, pmask, W, B):
+    """For the failure log: ``(of_center, of_context)``, each a function
+    from an occurrence's position (in ``in_ids``; in ``out_ids`` = [band |
+    negatives]) to the float64 logits of the live pairs it takes part in
+    and their rounding bounds."""
+    v, u_band, u_neg = (np.asarray(x, np.float64) for x in (v, u_band, u_neg))
+    pmask = np.asarray(pmask) > 0
+    C, (nb, K, _) = v.shape[0], u_neg.shape
+    offsets = [o for o in range(-W, W + 1) if o != 0]
+    live_center = pmask.any(axis=1)
+
+    def dot(a, b):
+        return np.sum(a * b, axis=-1), np.sum(np.abs(a * b), axis=-1) / 256
+
+    def of_center(c):
+        ctx = np.stack([u_band[W + off + c] for off in offsets])[pmask[c]]
+        negs = u_neg[c // B] if live_center[c] else u_neg[c // B][:0]
+        return [np.concatenate(x) for x in zip(dot(v[c], ctx),
+                                               dot(v[c], negs))]
+
+    def of_context(j):
+        if j >= C + 2 * W:                       # a negative's row
+            n, k = divmod(j - (C + 2 * W), K)
+            centers = np.arange(n * B, (n + 1) * B)
+            return dot(v[centers[live_center[centers]]], u_neg[n, k])
+        centers = np.array([(j - W - off, w) for w, off in enumerate(offsets)
+                            if 0 <= j - W - off < C], int).reshape(-1, 2)
+        centers = centers[pmask[centers[:, 0], centers[:, 1]], 0]
+        return dot(v[centers], u_band[j])
+
+    return of_center, of_context
+
+
 def check(driver) -> list:
     """One block after the window, through the trainer's own programs,
-    against ``reference_block``. Returns what disagreed."""
+    against ``reference_block``. Returns what disagreed, and puts the
+    numbers compared, each beside its limit, into ``driver.compared``."""
     import jax
     import jax.numpy as jnp
     from multiverso_tpu.models.wordembedding import device_train as dt
@@ -183,14 +248,15 @@ def check(driver) -> list:
         v_after, u_after = model._emb_in[in_ids], model._emb_out[out_ids]
 
     return compare(v, u, v_after, u_after, in_ids, out_ids, pmask, lr,
-                   float(loss), W, K, B)
+                   float(loss), W, K, B, driver.compared)
 
 
 def compare(v, u, v_after, u_after, in_ids, out_ids, pmask, lr, loss,
-            W, K, B) -> list:
+            W, K, B, compared=None) -> list:
     """The rows a block read (``v`` at ``in_ids``, ``u`` at ``out_ids`` =
     [band | negatives]) and the same rows after it, against
-    ``reference_block`` on the rows read. Returns what disagreed."""
+    ``reference_block`` on the rows read. Returns what disagreed, and
+    puts each number compared into ``compared`` as ``[value, limit]``."""
     C = pmask.shape[0]
     n_band = C + 2 * W
     ref_loss, r_v, r_band, r_neg, near = reference_block(
@@ -202,22 +268,33 @@ def compare(v, u, v_after, u_after, in_ids, out_ids, pmask, lr, loss,
     loss_err = abs(loss - ref_loss) / max(abs(ref_loss), 1e-30)
     near_u = np.concatenate([np.asarray(near[1]),
                              np.asarray(near[2]).reshape(-1)])
-    err_in, out_in = _relative(
-        np.asarray(v_after, np.float64) - np.asarray(v, np.float64),
-        _summed(in_ids, np.asarray(r_v)), in_ids, near[0])
-    err_out, out_out = _relative(
-        np.asarray(u_after, np.float64) - np.asarray(u, np.float64),
-        _summed(out_ids, r_u), out_ids, near_u)
-    errs = {"input": err_in, "output": err_out}
+    changes = {
+        "input": (np.asarray(v_after, np.float64) - np.asarray(v, np.float64),
+                  _summed(in_ids, np.asarray(r_v)), in_ids, near[0]),
+        "output": (np.asarray(u_after, np.float64)
+                   - np.asarray(u, np.float64),
+                   _summed(out_ids, r_u), out_ids, near_u)}
+    errs = {table: _relative(*change) for table, change in changes.items()}
+    (err_in, out_in), (err_out, out_out) = errs["input"], errs["output"]
     print(f"[bench] reference block: loss {loss:.3f} vs {ref_loss:.3f} "
           f"(rel {loss_err:.2e}); row change rel L2 error input "
           f"{err_in:.2e} output {err_out:.2e}; rows left out as near the "
           f"clip: {out_in} of {np.asarray(in_ids).size} input, {out_out} of "
           f"{np.asarray(out_ids).size} output", flush=True)
+    if compared is not None:
+        compared.update(loss_rel=[loss_err, LOSS_RTOL],
+                        input_change_rel=[err_in, CHANGE_RTOL],
+                        output_change_rel=[err_out, CHANGE_RTOL])
     if not loss_err <= LOSS_RTOL:
         wrong.append(f"reference block: loss {loss} vs {ref_loss}")
-    for table, err in errs.items():
+    logits = _pair_logits(v, u[:n_band], u[n_band:].reshape(C // B, K, -1),
+                          pmask, W, B)
+    for (table, (err, _)), pair_logits in zip(errs.items(), logits):
         if not err <= CHANGE_RTOL:
             wrong.append(f"reference block: {table} rows' change is off by "
                          f"{err:.3e} of its norm (bound {CHANGE_RTOL})")
+            observed, expected, ids, close = changes[table]
+            ids = np.asarray(ids).reshape(-1)
+            out = np.isin(ids, ids[np.asarray(close).reshape(-1)])
+            _explain(table, ids, observed, expected, out, pair_logits)
     return wrong

@@ -186,7 +186,9 @@ def main(argv=None) -> int:
             finally:
                 ctx.annotate(False)
                 jax.profiler.stop_trace()
+            t_stopped = time.monotonic()
             path = xplane.find_xplane(trace_dir)
+            file_bytes = os.path.getsize(path)
             if args.keep_trace:
                 os.makedirs(args.keep_trace, exist_ok=True)
                 shutil.copy(path, args.keep_trace)
@@ -195,7 +197,9 @@ def main(argv=None) -> int:
             shutil.rmtree(trace_dir, ignore_errors=True)
         log("traced " + json.dumps({
             "seconds": traced.seconds, "rounds": traced.rounds,
-            "device_planes": reduced and reduced["device_count"]}))
+            "device_planes": reduced and reduced["device_count"],
+            "file_bytes": file_bytes,
+            "reduction_s": time.monotonic() - t_stopped}))
 
     # the peak after the window, before the checks allocate their own
     peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -233,8 +237,15 @@ def main(argv=None) -> int:
               "metrics": metrics, "device": device}
     if reduced is not None:
         result["breakdown"] = xplane.breakdown(reduced)
+    # each number the check compared, beside its limit: last in the line,
+    # and the last lines on standard error
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, (value, limit) in driver.compared.items()}
     faulthandler.cancel_dump_traceback_later()
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in driver.compared.items():
+        print(f"[bench] compared {name} {value!r} limit {limit!r}",
+              file=sys.stderr, flush=True)
     return 0
 
 

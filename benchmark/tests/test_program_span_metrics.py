@@ -1,18 +1,21 @@
 """The ten readers of the program's own monitors (PR 24), each on a
 hand-built ``Observations``: counts and milliseconds in, the value out;
 nothing where the count is 0, and nothing where the program has no such
-monitor (the parent commit, which the driver also runs them on)."""
+monitor (the parent commit, which the driver also runs them on). And
+what every entry of ``BENCHMARK.json`` has to have, wherever it stands:
+``per_layer`` grows at its end, so no test may count from there."""
 
-import json
 import os
 
 import pytest
 
 from benchmark.lib.harness import Observations
 from benchmark.run import load_module
+from benchmark.tests import entries
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+BENCH = entries.bench_of(ROOT)
 
 COUNTERS = {
     "TABLE_WAIT": {"count": 250, "ms": 19000.0},
@@ -60,16 +63,22 @@ def test_reader(name):
     assert _read(name, {"SERVER_PROCESS_GET": {"count": 9, "ms": 1.0}}) is None
 
 
-def test_the_ten_are_the_last_entries_of_the_benchmark():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    added = bench["per_layer"][-len(WANT):]
-    assert {m["name"] for m in added} == set(WANT)
-    # each in one cell, under a layer and an end-to-end metric that exist
-    layers = {m["layer"] for m in bench["per_layer"][:-len(WANT)]}
-    cells = {w["name"] for w in bench["workloads"]}
-    for m in added:
-        assert m["source"] == "program_span"
-        assert m["layer"] in layers and set(m["workloads"]) <= cells
-        moved, = [e for e in bench["end_to_end"] if e["name"] == m["moves"]]
-        assert set(m["workloads"]) <= set(moved["workloads"])
+@pytest.mark.parametrize("name", sorted(WANT))
+def test_the_ten_are_entries_of_the_benchmark(name):
+    """Found by name: a later PR appends its entries after them."""
+    entries.check_the_ten(BENCH, [name])
+
+
+@pytest.mark.parametrize(
+    "kind, metric", entries.entries(BENCH),
+    ids=[f"{kind}:{m['name']}" for kind, m in entries.entries(BENCH)])
+def test_every_entry_has_a_reader_a_source_and_cells(kind, metric):
+    entries.check_entry(ROOT, BENCH, kind, metric)
+
+
+def test_no_name_twice_and_no_retired_metric():
+    bench, without_entry = entries.check_all(ROOT)
+    names = {m["name"] for _, m in entries.entries(bench)}
+    assert "trainer.epoch_start_s.train" not in names | without_entry
+    # every reader on disk has its entry (PR 27's waited four PRs for one)
+    assert without_entry == set()
