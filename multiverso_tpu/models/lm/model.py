@@ -1,0 +1,623 @@
+"""A sparse-expert decoder language model, one chip's share of it, as
+pure functions over its tensors: what ``PSLMTrainer`` (ps_train.py)
+pulls from the parameter server, steps and pushes back.
+
+The block is SmallThinker's (PowerInfer, 2025): for a layer's input
+``x`` [T, hidden],
+
+    p = softmax(x W_r) over all routed experts;  S = the top-k of p;
+    w_e = p_e / sum_S p                  (the router reads the layer's
+                                          raw input, BEFORE attention)
+    a = x + Attn(RMSNorm(x))             (grouped-query, causal; a layer
+                                          is either full attention with
+                                          no rotary positions or rotary
+                                          with a sliding window)
+    y = a + sum_{e in S, e held} w_e W_d,e (relu(h W_g,e) * (h W_u,e)),
+        h = RMSNorm(a)
+
+**The share.** ``LMConfig.experts_held = (first, count)`` says which of
+the ``n_experts`` routed experts this chip holds. The router keeps all
+its outputs and its top-k, ``w_e`` is normalised over all k, and the
+layer adds the part of the sum that its own experts give; what the
+absent experts would add is left out (on a deployment it arrives from
+the chips that hold them: no code here stands in for them). The four
+shares of 16 add up to the uncut layer (tests/test_lm_model.py). The
+vocabulary is a slice too: ``vocab`` rows of embedding and of head, the
+loss over the slice.
+
+**No token is dropped.** The (token, expert) assignments that fall on
+held experts are sorted by expert and the three products run as grouped
+products over the ragged groups (``grouped_product``: megablox's kernel
+on a TPU, ``jax.lax.ragged_dot`` elsewhere), in buffers sized for every
+assignment (``T * top_k`` rows), so whatever the routing every
+assignment is computed. Rows past the held ones belong to no group: the
+kernel visits the tiles that hold a group's rows and no other, so the
+products' cost follows the assignments that fell on held experts.
+
+**Precision.** Matrix products take bfloat16 inputs and accumulate in
+float32 (``mm``, ``grouped_mm``; their weight gradients come out in
+float32 through a ``sink``, see ``mm``); the residual stream, norms,
+softmaxes, the router's product and the loss are float32.
+
+**Attention.** On a TPU the Pallas splash-attention kernel of
+``jax.experimental.pallas.ops.tpu`` with a causal or a local mask,
+which visits no block that is wholly masked, so a window layer costs
+less than a full one; elsewhere ``blockwise_attention``, the same sum
+over the same unmasked blocks in ``jax.numpy``. Neither materialises a
+[heads, T, T] array.
+
+Scopes (``jax.named_scope``; the backward pass runs under the same
+names, layer_grads): ``mv.lm.router``, ``mv.lm.attn.full`` /
+``mv.lm.attn.window`` (norm, projections, rotary, output projection)
+with the attention proper under ``mv.lm.attn.full.kernel`` /
+``mv.lm.attn.window.kernel``, ``mv.lm.experts``, ``mv.lm.head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+BF16 = jnp.bfloat16
+F32 = jnp.float32
+
+#: The tensors of a layer that are matrices (pulled as bfloat16 copies,
+#: their gradients float32) and the small ones kept in float32.
+LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+LAYER_SMALL = ("router", "norm_attn", "norm_ffn")
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    hidden: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    n_experts: int                  # the router's outputs
+    top_k: int
+    expert_width: int
+    experts_held: Tuple[int, int]   # (first, count) held on this chip
+    vocab: int                      # rows of embedding and head held here
+    rope_layout: Tuple[int, ...]    # per layer: 1 rotary, 0 none
+    window_layout: Tuple[int, ...]  # per layer: 1 sliding window, 0 full
+    window: int
+    rope_theta: float
+    eps: float
+    loss_block: int = 2048          # tokens a block of the head's loss
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.rope_layout)
+
+    @classmethod
+    def from_dict(cls, c: dict) -> "LMConfig":
+        """From a configuration in the published ``config.json``'s keys
+        (benchmark/configs/smallthinker-21ba3b-l4.json):
+        ``moe_num_primary_experts`` is the number HELD, ``router_outputs``
+        the published number the router still has."""
+        n = int(c["num_hidden_layers"])
+        return cls(
+            hidden=int(c["hidden_size"]),
+            n_heads=int(c["num_attention_heads"]),
+            n_kv_heads=int(c["num_key_value_heads"]),
+            head_dim=int(c["head_dim"]),
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["moe_num_active_primary_experts"]),
+            expert_width=int(c["moe_ffn_hidden_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["moe_num_primary_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=tuple(int(v) for v in c["rope_layout"][:n]),
+            window_layout=tuple(
+                int(v) for v in c["sliding_window_layout"][:n]),
+            window=int(c["sliding_window_size"]),
+            rope_theta=float(c["rope_theta"]),
+            eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)))
+
+    def layer_shapes(self) -> dict:
+        """Every tensor of one layer as the server stores it: a matrix
+        table's (rows, columns) or a norm's (size,). The experts' three
+        are stacked by expert along the rows."""
+        h, e, w = self.hidden, self.experts_held[1], self.expert_width
+        return {
+            "wq": (h, self.n_heads * self.head_dim),
+            "wk": (h, self.n_kv_heads * self.head_dim),
+            "wv": (h, self.n_kv_heads * self.head_dim),
+            "wo": (self.n_heads * self.head_dim, h),
+            "router": (h, self.n_experts),
+            "norm_attn": (h,), "norm_ffn": (h,),
+            "w_gate": (e * h, w), "w_up": (e * h, w), "w_down": (e * w, h)}
+
+    def parameters(self) -> int:
+        per_layer = sum(int(np.prod(s)) for s in self.layer_shapes().values())
+        return (self.n_layers * per_layer + 2 * self.vocab * self.hidden
+                + self.hidden)
+
+
+# -- products -------------------------------------------------------------
+
+@jax.custom_vjp
+def mm(x, w, sink):
+    """``x @ w`` with bfloat16 inputs and a float32 result. ``w`` is the
+    worker's bfloat16 copy of a table and is not differentiated;
+    ``sink`` is a float32 array of ``w``'s shape that is never read and
+    whose cotangent IS the weight gradient, accumulated and returned in
+    float32 (a bfloat16 operand's own cotangent would be rounded to
+    bfloat16). Built as zeros inside the program that differentiates,
+    it costs no memory."""
+    del sink
+    return jnp.dot(x.astype(BF16), w, preferred_element_type=F32)
+
+
+def _mm_fwd(x, w, sink):
+    return mm(x, w, sink), (x.astype(BF16), w, jnp.zeros((), x.dtype))
+
+
+def _mm_bwd(res, g):
+    xb, w, like = res
+    gb = g.astype(BF16)
+    dx = jnp.dot(gb, w.T, preferred_element_type=F32).astype(like.dtype)
+    dw = jnp.dot(xb.reshape(-1, xb.shape[-1]).T,
+                 gb.reshape(-1, gb.shape[-1]), preferred_element_type=F32)
+    return dx, jnp.zeros_like(w), dw
+
+
+mm.defvjp(_mm_fwd, _mm_bwd)
+
+
+@jax.custom_vjp
+def mm_nt(x, w, sink):
+    """``x @ w.T`` for a ``w`` stored [n, k] (the head, rows by
+    vocabulary id), as ``mm``: no transposed copy of ``w`` or of its
+    gradient is made."""
+    del sink
+    return jax.lax.dot_general(x.astype(BF16), w, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
+
+
+def _mm_nt_fwd(x, w, sink):
+    return mm_nt(x, w, sink), (x.astype(BF16), w, jnp.zeros((), x.dtype))
+
+
+def _mm_nt_bwd(res, g):
+    xb, w, like = res
+    gb = g.astype(BF16)
+    dx = jnp.dot(gb, w, preferred_element_type=F32).astype(like.dtype)
+    return dx, jnp.zeros_like(w), jnp.dot(gb.T, xb,
+                                          preferred_element_type=F32)
+
+
+mm_nt.defvjp(_mm_nt_fwd, _mm_nt_bwd)
+
+
+_RAGGED_CONTRACT = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+#: Rows a tile of the TPU's grouped product holds, and its widest tile
+#: along a width (the first of these that divides it).
+GROUPED_TILE_ROWS = 512
+_GROUPED_TILE_WIDTHS = (1024, 768, 640, 512, 384, 256, 128)
+
+
+def _tile(width: int) -> int:
+    return next((t for t in _GROUPED_TILE_WIDTHS if width % t == 0), 0)
+
+
+def _use_gmm(rows: int, k: int, n: int) -> bool:
+    """Whether the grouped products of this shape take the Pallas kernel
+    (``jax.experimental.pallas.ops.tpu.megablox``): on a TPU, whole tiles.
+    It visits the tiles that hold a group's rows and no other, so rows
+    past the groups cost nothing; XLA's own ragged product computes every
+    row of its buffer (measured, PERF.md section 6, PR 32) and is the
+    form everywhere else."""
+    return (jax.default_backend() == "tpu" and rows % GROUPED_TILE_ROWS == 0
+            and _tile(k) > 0 and _tile(n) > 0)
+
+
+def grouped_product(x, w, group_sizes, transpose_w=False, kernel=None,
+                    interpret=False):
+    """``x`` [rows, k] bfloat16 times its group's ``w[g]`` ([groups, k, n],
+    or [groups, n, k] with ``transpose_w``), float32 result. Rows past
+    the groups' sum are the caller's to mask: XLA's form gives zeros
+    there, the kernel leaves them unwritten. ``kernel`` None picks by
+    ``_use_gmm``."""
+    k, n = (w.shape[2], w.shape[1]) if transpose_w else w.shape[1:]
+    if kernel is None:
+        kernel = _use_gmm(x.shape[0], k, n)
+    if not kernel:
+        return jax.lax.ragged_dot(x, w.swapaxes(1, 2) if transpose_w else w,
+                                  group_sizes, preferred_element_type=F32)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return gmm(x, w, group_sizes, F32,
+               (GROUPED_TILE_ROWS, _tile(k), _tile(n)),
+               transpose_rhs=transpose_w, interpret=interpret)
+
+
+def grouped_outer(x, g, group_sizes, kernel=None, interpret=False):
+    """Each group's ``x[rows of g].T @ g[rows of g]``: [groups, k, n]
+    float32 from ``x`` [rows, k] and ``g`` [rows, n], bfloat16 (a grouped
+    product's weight gradient)."""
+    if kernel is None:
+        kernel = _use_gmm(x.shape[0], x.shape[1], g.shape[1])
+    if not kernel:
+        return jax.lax.ragged_dot_general(x, g, group_sizes, _RAGGED_CONTRACT,
+                                          preferred_element_type=F32)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+    return tgmm(x.swapaxes(0, 1), g, group_sizes, F32,
+                (GROUPED_TILE_ROWS, _tile(x.shape[1]), _tile(g.shape[1])),
+                interpret=interpret)
+
+
+@jax.custom_vjp
+def grouped_mm(x, w, sink, group_sizes):
+    """Row ``i`` of ``x`` times the matrix of its group: ``w`` is
+    [groups, k, n], the rows of ``x`` lie group after group with
+    ``group_sizes`` rows each, and rows past their sum give nothing.
+    Inputs, result and ``sink`` as in ``mm``."""
+    del sink
+    return grouped_product(x.astype(BF16), w, group_sizes)
+
+
+def _grouped_fwd(x, w, sink, group_sizes):
+    return (grouped_mm(x, w, sink, group_sizes),
+            (x.astype(BF16), w, group_sizes, jnp.zeros((), x.dtype)))
+
+
+def _grouped_bwd(res, g):
+    xb, w, group_sizes, like = res
+    gb = g.astype(BF16)
+    dx = grouped_product(gb, w, group_sizes, transpose_w=True).astype(
+        like.dtype)
+    return dx, jnp.zeros_like(w), grouped_outer(xb, gb, group_sizes), None
+
+
+grouped_mm.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def rmsnorm(x, scale, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+# -- attention -------------------------------------------------------------
+
+def _rotary(x, theta):
+    """Rotary positions on [T, heads, d] (the halves paired, as the
+    published model's ``rotate_half``), float32."""
+    t, _, d = x.shape
+    inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(angle), F32)[:, None, :]
+    sin = jnp.asarray(np.sin(angle), F32)[:, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def visible(i, j, window):
+    """Whether query position ``i`` sees key position ``j``: causal, and
+    with a ``window`` only the last ``window`` positions, itself
+    included (``i - window < j <= i``)."""
+    seen = j <= i
+    return seen if not window else seen & (j > i - window)
+
+
+def blockwise_attention(q, k, v, window: int, block: int = 512):
+    """Causal (and windowed) attention in blocks of queries, each over
+    the key blocks it can see and no other: q [groups, per_group, T, d]
+    (already scaled), k and v [groups, T, d], bfloat16; returns q's
+    shape. Scores and softmax float32, the probabilities rounded to
+    bfloat16 for the product with v, as the kernel does."""
+    t = q.shape[2]
+    block = min(block, t)
+    out = []
+    for lo in range(0, t, block):
+        hi = min(lo + block, t)
+        first = 0 if not window else max(lo - window + 1, 0) // block * block
+        kk, vv = k[:, first:hi], v[:, first:hi]
+        s = jnp.einsum("ghqd,gkd->ghqk", q[:, :, lo:hi], kk,
+                       preferred_element_type=F32)
+        i = jnp.arange(lo, hi)[:, None]
+        j = jnp.arange(first, hi)[None, :]
+        s = jnp.where(visible(i, j, window), s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        out.append(jnp.einsum("ghqk,gkd->ghqd", p.astype(BF16), vv,
+                              preferred_element_type=F32))
+    return jnp.concatenate(out, axis=2).astype(q.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash(t: int, per_group: int, window: int):
+    """The TPU kernel for one key-value head and its ``per_group`` query
+    heads at length ``t``. Its mask is worked out on the host, once, and
+    kept as arrays; the first call comes from inside a program's trace,
+    so they are made under ``ensure_compile_time_eval``: a tracer kept
+    here would leak into the next program that takes the kernel."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as masks)
+    one = masks.LocalMask((t, t), (window - 1, 0), 0) if window \
+        else masks.CausalMask((t, t))
+    b = min(512, t)
+    sizes = kernel.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    with jax.ensure_compile_time_eval():
+        return kernel.make_splash_mqa_single_device(
+            masks.MultiHeadMask([one] * per_group), block_sizes=sizes)
+
+
+def attention_core(q, k, v, window: int):
+    """The attention proper: q [groups, per group, T, d] (scaled), k and
+    v [groups, T, d], bfloat16."""
+    t = q.shape[2]
+    if jax.default_backend() == "tpu" and t % 128 == 0:
+        return jax.vmap(_splash(t, q.shape[1], window))(q, k, v)
+    return blockwise_attention(q, k, v, window)
+
+
+def attention_inputs(cfg: LMConfig, rope: bool, mats, sinks, norm, x):
+    """Norm, the three projections, rotary positions, the scale: ``(q, k,
+    v)`` laid out for ``attention_core``."""
+    t = x.shape[0]
+    g, per = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    h = rmsnorm(x, norm, cfg.eps)
+    q = mm(h, mats["wq"], sinks["wq"]).reshape(t, cfg.n_heads, cfg.head_dim)
+    k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, cfg.head_dim)
+    v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, cfg.head_dim)
+    if rope:
+        q, k = _rotary(q, cfg.rope_theta), _rotary(k, cfg.rope_theta)
+    q = (q * (1.0 / math.sqrt(cfg.head_dim))).astype(BF16)
+    # query head i reads key-value head i // per
+    q = q.reshape(t, g, per, cfg.head_dim).transpose(1, 2, 0, 3)
+    return q, k.astype(BF16).transpose(1, 0, 2), v.astype(BF16).transpose(
+        1, 0, 2)
+
+
+def attention_output(cfg: LMConfig, mats, sinks, x, o):
+    """``x`` plus the heads' outputs through the output projection."""
+    t = x.shape[0]
+    o = o.transpose(2, 0, 1, 3).reshape(t, cfg.n_heads * cfg.head_dim)
+    return x + mm(o, mats["wo"], sinks["wo"])
+
+
+def attention_block(cfg: LMConfig, rope: bool, window: int, mats, sinks,
+                    norm, x):
+    """``x + Attn(RMSNorm(x))`` for one sequence ``x`` [T, hidden]."""
+    q, k, v = attention_inputs(cfg, rope, mats, sinks, norm, x)
+    with jax.named_scope(_attn_scope(window) + ".kernel"):
+        o = attention_core(q, k, v, window)
+    return attention_output(cfg, mats, sinks, x, o)
+
+
+def _attn_scope(window: int) -> str:
+    return "mv.lm.attn.window" if window else "mv.lm.attn.full"
+
+
+# -- router and experts -----------------------------------------------------
+
+def route(cfg: LMConfig, router, x):
+    """The top-k experts of each token and their weights, normalised
+    over the k: ``(ids [T, k] int32, weights [T, k] float32)``."""
+    logits = jnp.dot(x.astype(F32), router, precision="highest")
+    p = jax.nn.softmax(logits, axis=-1)
+    ids = jax.lax.top_k(p, cfg.top_k)[1].astype(jnp.int32)
+    # the chosen probabilities by a one-hot product, not a gather: its
+    # backward pass is then a product too, where a gather's is a scatter
+    # (serial on a TPU)
+    picks = (ids[..., None] == jnp.arange(cfg.n_experts)).astype(F32)
+    top = jnp.einsum("tke,te->tk", picks, p)
+    return ids, top / jnp.sum(top, -1, keepdims=True)
+
+
+def held_groups(cfg: LMConfig, ids):
+    """The (token, expert) assignments that fall on held experts, sorted
+    by expert: ``(order, group_sizes)``. ``order`` [T*k] lists the
+    flattened assignments, the held ones first and grouped by expert;
+    ``group_sizes`` [held] counts each held expert's."""
+    first, count = cfg.experts_held
+    local = ids.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return order, sizes
+
+
+def _rows_of(x, order, k):
+    return x[order // k]
+
+
+def _sum_back(rows, back, k):
+    return rows[back].reshape(-1, k, rows.shape[-1]).astype(F32).sum(axis=1)
+
+
+@jax.custom_vjp
+def permute(x, order, back):
+    """``x[order]`` for a permutation ``order`` with inverse ``back``:
+    backward ``g[back]``."""
+    return x[order]
+
+
+permute.defvjp(lambda x, order, back: (x[order], back),
+               lambda back, g: (g[back], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch(h, order, back, k):
+    """Each sorted assignment's token row: ``h[order // k]``. ``order``
+    is a permutation of the ``T * k`` assignments and ``back`` its
+    inverse, so the backward pass is ``combine``'s sum, a gather, where
+    the gather's own transpose would be a scatter-add (serial on a
+    TPU)."""
+    return _rows_of(h, order, k)
+
+
+def _dispatch_fwd(h, order, back, k):
+    return _rows_of(h, order, k), (back, jnp.zeros((), h.dtype))
+
+
+def _dispatch_bwd(k, res, g):
+    back, like = res
+    return _sum_back(g, back, k).astype(like.dtype), None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def combine(rows, order, back, k):
+    """Each token's ``k`` assignment rows summed in float32 (``rows``
+    lie in sorted order): the transpose of ``dispatch``, and its
+    backward pass is ``dispatch``'s gather."""
+    return _sum_back(rows, back, k)
+
+
+def _combine_fwd(rows, order, back, k):
+    return _sum_back(rows, back, k), (order, jnp.zeros((), rows.dtype))
+
+
+def _combine_bwd(k, res, g):
+    order, like = res
+    return _rows_of(g, order, k).astype(like.dtype), None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
+    """``a + sum over held experts`` for one sequence ``a`` [T, hidden]
+    with its routing."""
+    t, k = ids.shape
+    count = cfg.experts_held[1]
+    order, sizes = held_groups(cfg, ids)
+    back = jnp.argsort(order).astype(jnp.int32)     # assignment -> its row
+    live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+    h = rmsnorm(a, norm, cfg.eps).astype(BF16)
+    rows = jnp.where(live, dispatch(h, order, back, k), 0)
+
+    def product(rows, name, n_in, n_out):
+        return grouped_mm(rows, mats[name].reshape(count, n_in, n_out),
+                          sinks[name].reshape(count, n_in, n_out), sizes)
+
+    gate = product(rows, "w_gate", cfg.hidden, cfg.expert_width)
+    up = product(rows, "w_up", cfg.hidden, cfg.expert_width)
+    act = jnp.where(live, jax.nn.relu(gate) * up, 0)
+    out = product(act, "w_down", cfg.expert_width, cfg.hidden)
+    # each row weighted by its assignment's w_e (the [T, k] weights one a
+    # row, in the rows' order), rounded to bfloat16 for the way back to
+    # the tokens and summed there in float32
+    w_rows = permute(weights.reshape(t * k, 1), order, back)
+    out = (jnp.where(live, out, 0) * w_rows).astype(BF16)
+    return a + combine(out, order, back, k), sizes
+
+
+# -- a layer, forward and with its gradients -----------------------------------
+
+def _zeros_like_f32(mats):
+    return {name: jnp.zeros(w.shape, F32) for name, w in mats.items()}
+
+
+def layer_forward(cfg: LMConfig, rope: bool, window: int, mats, small, x):
+    """One sequence through one layer: ``(y, stats, ids)``, ``stats``
+    int32[2] = (assignments on held experts, the fullest held expert's)
+    and ``ids`` [T, k] each token's experts (a check hands them to its
+    reference; a step drops them)."""
+    sinks = _zeros_like_f32(mats)
+    with jax.named_scope("mv.lm.router"):
+        ids, weights = route(cfg, small["router"], x)
+    with jax.named_scope(_attn_scope(window)):
+        a = attention_block(cfg, rope, window, mats, sinks,
+                            small["norm_attn"], x)
+    with jax.named_scope("mv.lm.experts"):
+        y, sizes = experts_block(cfg, mats, sinks, small["norm_ffn"], a,
+                                 ids, weights)
+    return y, jnp.stack([jnp.sum(sizes), jnp.max(sizes)]), ids
+
+
+def layer_grads(cfg: LMConfig, rope: bool, window: int, mats, small, x, dy):
+    """The layer recomputed from its input ``x`` and differentiated:
+    ``(dx, matrix gradients, small gradients)`` for one sequence. Each
+    part's backward pass runs under the scope of its forward pass, so a
+    device trace reads the two together."""
+    sinks = _zeros_like_f32(mats)
+    attn_names = ("wq", "wk", "wv", "wo")
+    with jax.named_scope("mv.lm.router"):
+        (ids, weights), pull_router = jax.vjp(
+            lambda r, x: route(cfg, r, x), small["router"], x)
+    scope = _attn_scope(window)
+    qkv = {n: sinks[n] for n in ("wq", "wk", "wv")}
+    # A scope names a backward pass only where it is entered OUTSIDE the
+    # differentiated function (inside, JAX writes it as transpose(jvp(..)),
+    # which no reader takes for a scope): so the attention's three parts
+    # are differentiated one by one, the kernel under its own name.
+    with jax.named_scope(scope):
+        (q, k, v), pull_inputs = jax.vjp(
+            lambda s, norm, x: attention_inputs(cfg, rope, mats, s, norm, x),
+            qkv, small["norm_attn"], x)
+    with jax.named_scope(scope + ".kernel"):
+        o, pull_core = jax.vjp(
+            lambda q, k, v: attention_core(q, k, v, window), q, k, v)
+    with jax.named_scope(scope):
+        a, pull_output = jax.vjp(
+            lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),
+            sinks["wo"], x, o)
+    with jax.named_scope("mv.lm.experts"):
+        y, pull_experts = jax.vjp(
+            lambda s, norm, a, w: experts_block(cfg, mats, s, norm, a, ids,
+                                                w)[0],
+            {n: s for n, s in sinks.items() if n not in attn_names},
+            small["norm_ffn"], a, weights)
+        d_experts, d_norm_ffn, da, dw = pull_experts(dy.astype(y.dtype))
+    with jax.named_scope(scope):
+        d_wo, dx, do = pull_output(da)
+    with jax.named_scope(scope + ".kernel"):
+        d_qkv = pull_core(do)
+    with jax.named_scope(scope):
+        d_attn, d_norm_attn, dx_inputs = pull_inputs(d_qkv)
+    d_attn["wo"], dx = d_wo, dx + dx_inputs
+    with jax.named_scope("mv.lm.router"):
+        d_router, dx_router = pull_router(
+            (np.zeros(ids.shape, jax.dtypes.float0), dw))
+    return (dx + dx_router, {**d_attn, **d_experts},
+            {"router": d_router, "norm_attn": d_norm_attn,
+             "norm_ffn": d_norm_ffn})
+
+
+# -- the head: final norm, logits over the slice, the loss, its gradients ------
+
+def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets):
+    """Mean next-token cross entropy over ``x`` [N, hidden] and ``targets``
+    [N], and its gradients, a block of ``loss_block`` tokens at a time so
+    that no [N, vocab] array exists: ``(loss, dx [N, hidden], d_head
+    [vocab, hidden] float32, d_norm)``. ``head`` is the bfloat16 copy,
+    rows by vocabulary id."""
+    n = x.shape[0]
+    block = min(cfg.loss_block, n)
+    assert n % block == 0, (n, block)
+
+    def block_loss(x, norm, sink, targets):
+        h = rmsnorm(x, norm, cfg.eps)
+        logits = mm_nt(h, head, sink)
+        picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked) / n
+
+    def one(carry, xs):
+        loss, d_head, d_norm = carry
+        x, targets = xs
+        more, (dx, dn, dh) = jax.value_and_grad(block_loss, (0, 1, 2))(
+            x, norm, jnp.zeros(head.shape, F32), targets)
+        return (loss + more, d_head + dh, d_norm + dn), dx
+
+    with jax.named_scope("mv.lm.head"):
+        (loss, d_head, d_norm), dx = jax.lax.scan(
+            one, (jnp.zeros((), F32), jnp.zeros(head.shape, F32),
+                  jnp.zeros(norm.shape, F32)),
+            (x.reshape(n // block, block, -1),
+             targets.reshape(n // block, block)))
+    return loss, dx.reshape(x.shape), d_head, d_norm

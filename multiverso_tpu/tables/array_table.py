@@ -202,7 +202,9 @@ class ArrayWorker(WorkerTable):
 
 class ArrayServer(ServerTable):
     def __init__(self, size: int, dtype=np.float32, zoo=None,
-                 updater_type: Optional[str] = None):
+                 updater_type: Optional[str] = None, fill: float = 0.0):
+        """``fill`` is the value every element starts at (a norm's
+        scale starts at 1), written on the devices."""
         super().__init__(zoo=zoo)
         self.dtype = np.dtype(dtype)
         num_servers = self._zoo.num_servers
@@ -219,6 +221,10 @@ class ArrayServer(ServerTable):
         padded = meshlib.padded_size(my_size, meshlib.device_count(mesh))
         self._data = meshlib.zeros_sharded((padded,), self.dtype,
                                            self._sharding)
+        if fill:
+            with device_lock.guard():
+                self._data = device_lock.settle(
+                    self._data + self.dtype.type(fill))
         rule = None if updater_type is None \
             else create_rule(updater_type, dtype)
         self._engine = UpdateEngine(
@@ -314,7 +320,12 @@ class ArrayServer(ServerTable):
     @functools.cached_property
     def _snapshot(self):
         n = self.size
-        return jax.jit(lambda x: jax.numpy.copy(x[:n]))
+
+        def snapshot(x):
+            with jax.named_scope("mv.table.snapshot"):
+                return jax.numpy.copy(x[:n])
+
+        return jax.jit(snapshot)
 
     # -- checkpoint (ref: array_table.cpp:143-151) --
     def store(self, stream) -> None:

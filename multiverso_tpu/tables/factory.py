@@ -49,13 +49,14 @@ class KVTableOption:
 
 def create_array_table(size: int, dtype=np.float32,
                        updater_type: Optional[str] = None,
-                       zoo=None) -> Optional[ArrayWorker]:
+                       zoo=None, fill: float = 0.0) -> Optional[ArrayWorker]:
     zoo = zoo if zoo is not None else current_zoo()
     role = _table_role(zoo)
     worker = None
     if is_server(role):
         zoo.server_table_ready(
-            ArrayServer(size, dtype, zoo=zoo, updater_type=updater_type))
+            ArrayServer(size, dtype, zoo=zoo, updater_type=updater_type,
+                        fill=fill))
     if is_worker(role):
         worker = ArrayWorker(size, dtype, zoo=zoo)
     if not zoo.rejoining:

@@ -52,7 +52,7 @@ from . import client_cache
 from .client_cache import RowCache
 from ..sharding import mesh as meshlib
 from ..updater import AddOption, GetOption, UpdateEngine, create_rule
-from ..updater.engine import bucket_size, pad_ids
+from ..updater.engine import DEVICE_KEYS_REFUSED, bucket_size, pad_ids
 from ..util import log, wire_codec
 from ..util.configure import define_bool, get_flag
 from ..util.log import CHECK
@@ -228,8 +228,10 @@ class MatrixWorker(WorkerTable):
         # deriving statelessness from the rule registry so this cannot
         # drift from the engine's actual state handling (e.g. int tables
         # and unknown names both resolve to the stateless default adder).
-        self._updater_stateless = create_rule(updater_type,
-                                              self.dtype).stateless
+        rule = create_rule(updater_type, self.dtype)
+        self._updater_stateless = rule.stateless
+        self._updater_name = rule.name
+        self._device_keys_ok = rule.sums_duplicates
         # Wire compression for sparse traffic, both directions, as the
         # reference does unconditionally (sparse_matrix_table.cpp:148-153);
         # here behind a flag read at table-construction time — and only
@@ -915,8 +917,9 @@ class MatrixWorker(WorkerTable):
         communicator.cpp:157-249). DEVICE row_ids (single-server,
         in-process tables) keep the ids in HBM too: any shape; delta
         must be shaped ``row_ids.shape + (num_col,)``. Duplicate ids
-        SUM only under stateless updaters (default/sgd) — the engine
-        rejects stateful rules on this path."""
+        sum under default, sgd and adam (``UpdaterRule.sums_duplicates``)
+        — the engine rejects momentum, adagrad and dcasgd on this
+        path."""
         if is_device_array(row_ids):
             # Multi-server: the same ids+delta blobs go to every server;
             # each scatter-adds only its own rows (foreign rows masked
@@ -926,9 +929,8 @@ class MatrixWorker(WorkerTable):
             CHECK(self._zoo.servers_in_process,
                   "device-key row adds need the servers in this "
                   "process")
-            CHECK(self._updater_stateless,
-                  "device-key row adds need a stateless updater "
-                  "(default/sgd): duplicate ids must sum")
+            CHECK(self._device_keys_ok,
+                  DEVICE_KEYS_REFUSED % self._updater_name)
             CHECK(is_device_array(delta),
                   "device-key adds need a device delta")
             CHECK(tuple(delta.shape) ==
@@ -2725,7 +2727,12 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
     @functools.cached_property
     def _snapshot(self):
         n, n_col = self.my_rows, self.num_col
-        return jax.jit(lambda x: jax.numpy.copy(x[:n, :n_col]))
+
+        def snapshot(x):
+            with jax.named_scope("mv.table.snapshot"):
+                return jax.numpy.copy(x[:n, :n_col])
+
+        return jax.jit(snapshot)
 
     # -- checkpoint (ref: matrix_table.cpp:456-464) --
     def store(self, stream) -> None:

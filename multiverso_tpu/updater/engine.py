@@ -36,6 +36,13 @@ from .rules import UpdaterRule, create_rule, fast_rows
 
 _DEFAULT_HYP = AddOption().hyper_array()
 
+#: Why a device-key row Add is refused, for the engine's CHECK and the
+#: worker table's (which raises in the caller's thread).
+DEVICE_KEYS_REFUSED = (
+    "device-key row adds under -updater_type=%s: duplicate ids in one "
+    "request must sum, and this rule updates its state once a unique "
+    "row from one duplicate's delta (default, sgd and adam take them)")
+
 
 def bucket_size(n: int, minimum: int = 8) -> int:
     """Smallest power of two >= n (>= minimum)."""
@@ -56,8 +63,13 @@ class UpdateEngine:
         state = self.rule.init_state(self.shape, self.dtype, num_workers)
         if state is not None and sharding is not None:
             # Optimizer state lives shard-aligned with the data; the
-            # per-worker leading axis (adagrad) is replicated.
-            state = jax.device_put(state, _state_sharding(state, sharding))
+            # per-worker leading axis (adagrad) is replicated. A state of
+            # several parts (adam's two moments and its step count) is
+            # placed part by part: a table-shaped part like the table, a
+            # smaller one (the count) on every device.
+            state = jax.tree_util.tree_map(
+                lambda part: jax.device_put(
+                    part, _state_sharding(part, sharding)), state)
         self._state = state
         # The rows form's scatter-add picks its path by the table's
         # shape, dtype, mesh and the request's id count (rules.py
@@ -138,14 +150,15 @@ class UpdateEngine:
         hyp, worker_id = _unpack(option)
         from ..core.blob import is_device_array
         if is_device_array(row_ids):
-            # Device-key ids may carry duplicates, which only SUM
-            # correctly under stateless rules (default/sgd scatter-add);
-            # stateful rules apply .set per unique row and would corrupt
-            # their state silently.
+            # Device-key ids may carry duplicates that no caller can
+            # take out without a host sync. default/sgd scatter-add
+            # them and adam sums equal ids' deltas before it touches a
+            # row; momentum, adagrad and dcasgd write their state once
+            # per unique row from ONE duplicate's delta, which would
+            # drop the others from the state silently.
             from ..util.log import CHECK
-            CHECK(self._state is None,
-                  "device-key row adds need a stateless updater "
-                  "(default/sgd): duplicate ids must sum")
+            CHECK(self.rule.sums_duplicates, DEVICE_KEYS_REFUSED
+                  % self.rule.name)
         else:
             row_ids, delta = pad_rows(row_ids, delta, self.shape[0])
         self._count_path(row_ids)
@@ -283,4 +296,6 @@ def _state_sharding_cached(ndim_state: int, data_sharding):
 
 
 def _state_sharding(state, data_sharding):
+    if np.ndim(state) < len(data_sharding.spec):     # a count: everywhere
+        return meshlib.replicated(data_sharding.mesh)
     return _state_sharding_cached(np.ndim(state), data_sharding)

@@ -32,6 +32,8 @@ Formulas (and deviations):
 
 - dcasgd: delay-compensated ASGD — see DCASGDRule (the reference ships
   this updater permanently disabled; here it works).
+- adam: the delta is the raw gradient; the rule owns both moments and
+  the table's step count — see AdamRule (no reference twin).
 
 Duplicate row indices within one row-sparse Add compound correctly for
 default/sgd (scatter-add: XLA's form adds them one after another, the
@@ -39,7 +41,9 @@ sorted-runs form adds their float32 sum, taken in the order of their
 positions in the request, once); for momentum/adagrad/dcasgd the state update
 applies once per unique row (the reference's sequential loop compounds
 instead — callers there dedupe rows per block, e.g. WordEmbedding's
-DataBlock).
+DataBlock). adam sums the deltas of equal ids before it touches a row
+(``distinct_rows``), so it alone of the stateful rules takes device-key
+row Adds (``UpdaterRule.sums_duplicates``).
 """
 
 from __future__ import annotations
@@ -56,7 +60,7 @@ from ..util.configure import define_string, get_flag
 
 define_string("updater_type", "default",
               "server updater: default / sgd / momentum / adagrad / "
-              "dcasgd")
+              "dcasgd / adam")
 
 ADAGRAD_EPS = 1e-6  # ref: adagrad_updater.h:18
 
@@ -67,6 +71,10 @@ def _safe_lr(lr):
     table — clamp away from zero (delta is 0 whenever lr is)."""
     return jnp.maximum(lr, jnp.asarray(1e-12, lr.dtype))
 
+
+#: The stored row the sorted-runs kernel takes: one 128-lane tile. On a
+#: wider table the TPU's compiler refuses its one-row DMAs (fast_rows).
+KERNEL_LANES = 128
 
 #: Static id count from which the sorted-runs kernel beats XLA's
 #: scatter on a v5e (tools/scatter_bench.py --small; PERF.md section 6,
@@ -85,11 +93,15 @@ def fast_rows(shape, dtype, n_ids: int, mesh=None) -> bool:
     """The path a row Add of ``n_ids`` ids on a table of this stored
     shape takes, from what is static: the sorted-runs kernel
     (row_scatter.py) when the table is on a TPU, float32, two
-    dimensional with a row of whole 128-lane tiles, and the id count is
-    at or above ``FAST_MIN_IDS``; XLA's scatter otherwise. ``mesh`` is
-    the table's."""
+    dimensional with a stored row of exactly one 128-lane tile, and the
+    id count is at or above ``FAST_MIN_IDS``; XLA's scatter otherwise.
+    A wider row (a language model's embedding, 20 tiles) takes XLA's
+    scatter: the kernel's one-row DMAs are refused by the TPU's compiler
+    on a table more than one tile wide ("slice shape along dimension 0
+    must be aligned to tiling (8)"; tests/test_row_scatter_tpu_compile.py).
+    ``mesh`` is the table's."""
     return (_platform(mesh) == "tpu" and np.dtype(dtype) == np.float32
-            and len(shape) == 2 and shape[1] % 128 == 0
+            and len(shape) == 2 and shape[1] == KERNEL_LANES
             and shape[0] < row_scatter.MAX_ROWS
             and n_ids >= FAST_MIN_IDS)
 
@@ -124,10 +136,20 @@ class UpdaterRule:
 
     name = "base"
     # True when init_state returns None — i.e. duplicate row ids in one
-    # scatter-add SUM correctly. Worker-side device-key validation
-    # consults this through create_rule so it cannot drift from the
-    # engine's state handling.
+    # scatter-add SUM correctly, requests fold before one apply (server
+    # fusion) and rows migrate live. A stateful rule is refused all
+    # three; what it may still take is device-key row Adds, if
+    # ``sums_duplicates``.
     stateless = True
+    # True when the rows form gives duplicate ids in one request the
+    # update their summed delta would get: every stateless rule, and a
+    # stateful one that sums equal ids' deltas before it touches a row
+    # (adam). Device keys cannot be deduplicated by the caller, so the
+    # worker's and the engine's CHECKs on device-key row Adds read this
+    # (through create_rule). momentum, adagrad and dcasgd update their
+    # state once per unique row from ONE of the duplicates' deltas and
+    # stay refused.
+    sums_duplicates = True
     # The table's mesh, set by the engine that binds the rule to a
     # table: the rows form's scatter-add picks its path by it.
     mesh = None
@@ -168,6 +190,7 @@ class SGDRule(UpdaterRule):
 class MomentumRule(UpdaterRule):
     name = "momentum"
     stateless = False
+    sums_duplicates = False
 
     def init_state(self, shape, dtype, num_workers: int):
         return jnp.zeros(shape, dtype)
@@ -188,6 +211,7 @@ class MomentumRule(UpdaterRule):
 class AdaGradRule(UpdaterRule):
     name = "adagrad"
     stateless = False
+    sums_duplicates = False
 
     def init_state(self, shape, dtype, num_workers: int):
         # Per-worker historic squared gradients, leading worker axis
@@ -230,6 +254,7 @@ class DCASGDRule(UpdaterRule):
 
     name = "dcasgd"
     stateless = False
+    sums_duplicates = False
 
     def init_state(self, shape, dtype, num_workers: int):
         return jnp.zeros((num_workers,) + tuple(shape), dtype)
@@ -258,8 +283,100 @@ class DCASGDRule(UpdaterRule):
         return data, state
 
 
+
+def distinct_rows(row_ids, delta, num_rows: int):
+    """The distinct ids of a row request and the summed delta of each:
+    ``(ids, sums)``, both of the request's length. ``ids`` holds the
+    distinct in-range ids in rising order and then ``num_rows`` (out of
+    range: a gather fills, a scatter drops); ``sums[i]`` is the float32
+    sum of the deltas of ``ids[i]``'s positions. One sort of the ids and
+    one scatter-add with sorted indices (scope ``mv.update.dedup``); no
+    host value, whatever the ids."""
+    n = int(np.prod(row_ids.shape))
+    ids = row_ids.reshape(n).astype(jnp.int32)
+    ids = jnp.where(ids < 0, ids + num_rows, ids)       # as .at[] wraps
+    key = jnp.where((ids >= 0) & (ids < num_rows), ids, num_rows)
+    key, perm = jax.lax.sort((key, jax.lax.iota(jnp.int32, n)), num_keys=2)
+    head = jnp.concatenate([jnp.ones((1,), bool), key[1:] != key[:-1]])
+    run = jnp.cumsum(head, dtype=jnp.int32) - 1     # the run of each position
+    # a run's id, written by each of its positions alike
+    unique = jnp.full((n,), num_rows, jnp.int32).at[run].set(
+        key, indices_are_sorted=True)
+    sums = jnp.zeros((n,) + delta.shape[row_ids.ndim:], delta.dtype).at[
+        run].add(delta.reshape((n,) + delta.shape[row_ids.ndim:])[perm],
+                 indices_are_sorted=True)
+    return unique, sums
+
+
+class AdamRule(UpdaterRule):
+    """Adam (Kingma & Ba 2015) in the server: the Add carries the raw
+    gradient ``g`` and the rule owns the rest.
+
+    State, three parts ``(m, v, t)``: first and second moment, each
+    shaped, typed and sharded like the table, and ``t``, an int32 scalar
+    that counts the table's Adds (dense or rows alike), replicated.
+    Hyperparameters ride ``AddOption`` as every rule's do, in the slots
+    it has: ``momentum`` = beta1, ``learning_rate`` = lr, ``rho`` =
+    beta2, ``lambda_`` = eps. No weight decay.
+
+        t += 1;  m = b1 m + (1-b1) g;  v = b2 v + (1-b2) g g
+        w -= lr * (m / (1-b1^t)) / (sqrt(v / (1-b2^t)) + eps)
+
+    The rows form is *lazy* Adam: the deltas of equal ids are summed
+    first (``distinct_rows``), then each distinct row named, its ``m``
+    row and its ``v`` row are read, updated by the formula above with
+    the table's ``t``, and written once; a row not named keeps its
+    weights and its moments (no decay of ``m`` toward zero), and ``t``
+    still advances. One worker's state: ``worker_id`` is not read."""
+
+    name = "adam"
+    stateless = False
+    sums_duplicates = True
+
+    def init_state(self, shape, dtype, num_workers: int):
+        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+                jnp.zeros((), jnp.int32))
+
+    @staticmethod
+    def _step(w, m, v, g, t, hyp):
+        b1, lr, b2, eps = (hyp[i].astype(w.dtype) for i in range(4))
+        m = b1 * m + (1 - b1) * g
+        v = b2 * v + (1 - b2) * g * g
+        tf = t.astype(w.dtype)
+        m_hat = m / (1 - b1 ** tf)
+        v_hat = v / (1 - b2 ** tf)
+        return w - lr * m_hat / (jnp.sqrt(v_hat) + eps), m, v
+
+    def dense(self, data, state, delta, hyp, worker_id):
+        m, v, t = state
+        t = t + 1
+        data, m, v = self._step(data, m, v, delta, t, hyp)
+        return data, (m, v, t)
+
+    def rows(self, data, state, row_ids, delta, hyp, worker_id):
+        m, v, t = state
+        t = t + 1
+        with jax.named_scope("mv.update.dedup"):
+            ids, g = distinct_rows(row_ids, delta, data.shape[0])
+
+        def read(x):
+            return x.at[ids].get(mode="fill", fill_value=0,
+                                 indices_are_sorted=True)
+
+        def write(x, rows):
+            return x.at[ids].set(rows, mode="drop",
+                                 indices_are_sorted=True)
+
+        w_rows, m_rows, v_rows = self._step(read(data), read(m), read(v),
+                                            g, t, hyp)
+        with jax.named_scope("mv.update.scatter_add"):
+            return write(data, w_rows), (write(m, m_rows),
+                                         write(v, v_rows), t)
+
+
 _RULES = {cls.name: cls for cls in
-          (DefaultRule, SGDRule, MomentumRule, AdaGradRule, DCASGDRule)}
+          (DefaultRule, SGDRule, MomentumRule, AdaGradRule, DCASGDRule,
+           AdamRule)}
 # The reference's flag value for the momentum updater is "momentum_sgd"
 # (ref: src/updater/updater.cpp:47-58); accept both spellings.
 _RULES["momentum_sgd"] = MomentumRule

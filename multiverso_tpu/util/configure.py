@@ -154,6 +154,13 @@ CANONICAL_FLAGS: Dict[str, Any] = {
     "per_pair": False,
     "is_pipeline": True,
     "device_pipeline": True,
+    # -- language-model app (models/lm/main.py) --
+    "lm_config": "",
+    "lm_steps": 10,
+    "lm_seq_len": 8192,
+    "lm_sequences": 2,
+    "lm_seed": 0,
+    "lm_warmup_steps": 2000,
 }
 
 #: LIVE-RETUNABLE FLAG REGISTRY — the subset of ``CANONICAL_FLAGS`` the

@@ -169,6 +169,21 @@ METRIC_NAMES: Dict[str, str] = {
                           "dispatch: _prep (subsample mask, one sort "
                           "that carries tokens and sentence ids), pad, "
                           "kept-count readback",
+    # -- language-model trainer (models/lm/ps_train.py) --
+    "LM_STEP": "PSLMTrainer.step: Gets, programs and Adds dispatched "
+               "(the wait for the last step's programs included)",
+    "LM_GET_PARAMS": "a step's Gets: the embedding rows by device keys, "
+                     "a layer's ten tables whole, head and final norm",
+    "LM_ADD_GRADS": "a step's Adds: a layer's ten gradients whole as "
+                    "device deltas, head and norm, the embedding rows",
+    "LM_TOKENS": "tokens trained",
+    "LM_GET_BYTES": "bytes of the whole-table device Gets' replies",
+    "LM_ADD_BYTES": "bytes of the whole-table device Adds' deltas",
+    "LM_HELD_ASSIGNMENTS": "(token, expert) assignments that fell on held "
+                           "experts, every layer and sequence",
+    "LM_EXPERT_MAX_TOKENS": "the fullest held expert's tokens, summed "
+                            "over layers and sequences",
+    "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"

@@ -116,3 +116,96 @@ def test_the_trainers_group_program_compiles_for_a_v5e(
     # concatenated deltas and one chunk of sorted delta rows.
     assert memory.alias_size_in_bytes >= 2 * ROWS * COLS * 4
     assert memory.temp_size_in_bytes < 150e6
+
+
+# -- a language model's tables and kernels (multiverso_tpu/models/lm) ----------
+# The embedding of benchmark/configs/smallthinker-21ba3b-l4.json: 37,984
+# rows of 2560 floats, twenty lane tiles a row, under Adam with the
+# step's 16,384 token ids as device keys.
+LM_ROWS, LM_COLS, LM_IDS = 37_984, 2560, (2, 8192)
+
+
+def test_the_kernel_is_refused_a_row_wider_than_one_tile(topo, monkeypatch):
+    """Why ``rules.fast_rows`` sends a wide table to XLA's scatter: on a
+    table more than 128 lanes wide the TPU's compiler refuses the
+    kernel's one-row DMAs."""
+    from multiverso_tpu.updater import row_scatter
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    assert not rules.fast_rows((LM_ROWS, LM_COLS), np.float32, 16384)
+    assert not rules.fast_rows((LM_ROWS, 256), np.float32, 16384)
+    assert rules.fast_rows((LM_ROWS, 128), np.float32, 16384)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(row_scatter.scatter_add).lower(
+            shaped((LM_ROWS, 256), jnp.float32, sharding=one),
+            shaped((16384,), jnp.int32, sharding=one),
+            shaped((16384, 256), jnp.float32, sharding=one)).compile()
+
+
+def test_adams_rows_program_compiles_for_a_v5e_at_the_embeddings_width(
+        topo, monkeypatch):
+    monkeypatch.setattr(rules, "_platform", lambda mesh: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    engine = UpdateEngine(rules.create_rule("adam"), (LM_ROWS, LM_COLS),
+                          np.float32, 1)
+    shaped = jax.ShapeDtypeStruct
+    table = shaped((LM_ROWS, LM_COLS), jnp.float32, sharding=one)
+    compiled = engine._rows.lower(
+        table, (table, table, shaped((), jnp.int32, sharding=one)),
+        shaped(LM_IDS, jnp.int32, sharding=one),
+        shaped(LM_IDS + (LM_COLS,), jnp.float32, sharding=one),
+        np.zeros(4, np.float32), np.int32(0)).compile()
+    memory = compiled.memory_analysis()
+    # the table and both moments are updated in place
+    assert memory.alias_size_in_bytes >= 3 * LM_ROWS * LM_COLS * 4
+    # the summed deltas and the three tables' gathered and stepped rows
+    assert memory.temp_size_in_bytes < 8 * 16384 * LM_COLS * 4
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_the_attention_kernel_compiles_for_a_v5e_at_8192_positions(
+        topo, window):
+    """Splash attention forward and backward for one sequence of the
+    published head counts (4 key-value heads, 7 query heads each)."""
+    from multiverso_tpu.models.lm import model as lm
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    t = 8192
+
+    def loss(q, k, v):
+        out = jax.vmap(lm._splash(t, 7, window))(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shaped((4, 7, t, 128), jnp.bfloat16, sharding=one),
+        shaped((4, t, 128), jnp.bfloat16, sharding=one),
+        shaped((4, t, 128), jnp.bfloat16, sharding=one)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no [heads, T, T] array: 28 x 8192 x 8192 x 4 B would be 7.5 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_the_grouped_products_compile_for_a_v5e_at_the_experts_widths(
+        topo, monkeypatch):
+    """``grouped_mm`` forward and backward through the Pallas kernel a TPU
+    takes: 16 experts of 2560 x 768 and of 768 x 2560 over a sequence's
+    49,152 assignment rows, float32 weight gradients."""
+    from multiverso_tpu.models.lm import model as lm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+
+    def loss(x, sink, w, sizes):
+        return jnp.sum(lm.grouped_mm(x, w, sink, sizes))
+
+    for k, n in ((2560, 768), (768, 2560)):
+        assert lm._use_gmm(49152, k, n)
+        compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+            shaped((49152, k), jnp.bfloat16, sharding=one),
+            shaped((16, k, n), jnp.float32, sharding=one),
+            shaped((16, k, n), jnp.bfloat16, sharding=one),
+            shaped((16,), jnp.int32, sharding=one)).compile()
+        # the rows' gradient and the weights' gradient
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 700e6

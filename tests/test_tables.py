@@ -410,13 +410,14 @@ class TestDeviceResidentPath:
                                       4 * np.ones((2, 4), np.float32))
 
     def test_device_keys_rejected_stateful_updater(self, env):
-        # Duplicate device ids only SUM correctly under stateless rules;
+        # Duplicate device ids only SUM correctly under rules that sum
+        # them (default, sgd, adam: UpdaterRule.sums_duplicates);
         # the misconfiguration must raise in the CALLER (the server-side
         # CHECK fires inside the actor, which swallows it and the ack
         # never comes — a silent hang).
         import jax.numpy as jnp
         table = mv.create_matrix_table(16, 4, updater_type="momentum")
-        with pytest.raises(Exception, match="stateless"):
+        with pytest.raises(Exception, match="updater_type=momentum"):
             table.add_rows(jnp.asarray(np.array([1, 2], np.int32)),
                            jnp.ones((2, 4), jnp.float32))
 

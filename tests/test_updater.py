@@ -383,7 +383,9 @@ def test_the_id_count_picks_the_path_and_both_agree(
 
 @pytest.mark.parametrize("shape, dtype, k, fast", [
     ((ROWS, 128), np.float32, 4096, True),
-    ((ROWS, 256), np.float32, 4096, True),
+    # two tiles a row: the TPU's compiler refuses the kernel's one-row
+    # DMAs on it (tests/test_row_scatter_tpu_compile.py), so XLA's scatter
+    ((ROWS, 256), np.float32, 4096, False),
     ((ROWS, 50), np.float32, 4096, False),     # not whole lanes
     ((ROWS, 128), np.int32, 4096, False),      # integer table
     ((ROWS, 128), np.float64, 4096, False),
