@@ -195,6 +195,19 @@ class Blob:
             return arr
         return arr.reshape(-1).view(dtype)
 
+    def as_rows(self, dtype, n_rows: int, n_col: int) -> np.ndarray:
+        """The payload as ``[n_rows, n_col]`` rows of ``dtype``. A host
+        payload that already is that array (a device reply's rows after
+        the host boundary) is handed back as it is, strides and all: a
+        consumer that copies the rows once reads them in place, and a
+        payload that is not C-contiguous is never copied just to be
+        flattened. Anything else is ``as_array`` reshaped. Read-only
+        wherever ``as_array`` is (docs/MEMORY.md)."""
+        arr = self._host()
+        if arr.shape == (n_rows, n_col) and arr.dtype == np.dtype(dtype):
+            return arr
+        return self.as_array(dtype).reshape(n_rows, n_col)
+
     def materialize(self) -> "Blob":
         """Copy-on-write escape hatch: replace a pool-backed (or
         otherwise read-only) payload with a private writable copy and

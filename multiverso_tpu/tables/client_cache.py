@@ -82,6 +82,8 @@ HIT = "CLIENT_CACHE_HIT"
 MISS = "CLIENT_CACHE_MISS"
 JOIN = "CLIENT_CACHE_JOIN"
 PREFETCH = "CLIENT_CACHE_PREFETCH"
+DIRECT = "GET_REPLY_ROWS_DIRECT"
+PLACED = "GET_REPLY_ROWS_PLACED"
 
 
 def staleness_bound() -> int:
@@ -100,15 +102,56 @@ def cache_enabled() -> bool:
     return staleness_bound() > 0
 
 
+def _run_start(keys: np.ndarray, req: np.ndarray) -> int:
+    """Where ``keys`` lies in ``req`` as ONE run that holds every
+    position of its ids, or -1. Two shapes qualify: the shard is the
+    whole request in its order (any ``req``, repeats included — one
+    server's reply to ``partition``'s ``keys[mask]``), or ``req`` is
+    non-decreasing and the shard is a maximal slice of it (one server's
+    bucket of a sorted request). Maximal matters: ``[5, 7]`` is a slice
+    of ``[5, 7, 7]`` (a de-duplicated partial hit) that would leave the
+    third position unfilled."""
+    k, n = keys.size, req.size
+    if k == n:
+        return 0 if np.array_equal(keys, req) else -1
+    if k > n:
+        return -1
+    # Meaningful only for a sorted req; that is the last (O(n)) test,
+    # made only once the O(k) ones have passed.
+    a = int(np.searchsorted(req, keys[0], side="left"))
+    b = a + k
+    if b > n or not np.array_equal(keys, req[a:b]):
+        return -1
+    if b < n and req[b] == keys[-1]:
+        return -1
+    if not bool((req[1:] >= req[:-1]).all()):
+        return -1
+    return a
+
+
 def place_rows(keys: np.ndarray, values, req: np.ndarray, out) -> None:
-    """Vectorized subset placement: every position of ``req`` whose row
-    id appears in ``keys`` receives that id's row of ``values``;
-    positions for absent ids are left untouched. Shared by the cache's
-    partial-hit fill and the table reply path — ``req`` may repeat ids
-    thousands of times (power-of-two padded row sets), so per-position
-    Python loops are pathological here."""
+    """Subset placement: every position of ``req`` whose row id appears
+    in ``keys`` receives that id's row of ``values``; positions for
+    absent ids are left untouched. Shared by the cache's partial-hit
+    fill and the table reply path — ``req`` may repeat ids thousands of
+    times (power-of-two padded row sets), so per-position Python loops
+    are pathological here.
+
+    The cheapest form is chosen from ``keys`` and ``req`` alone. A shard
+    that is the request, or a run of a sorted request (``_run_start``),
+    is one copy of ``values`` into ``out``, read through whatever
+    strides ``values`` has (GET_REPLY_ROWS_DIRECT). Anything else —
+    subset keys of a partial hit, replica groups, an unsorted request
+    over several servers — is sorted, searched, gathered and scattered
+    (GET_REPLY_ROWS_PLACED)."""
     if len(keys) == 0 or len(req) == 0:
         return
+    start = _run_start(keys, req)
+    if start >= 0:
+        count(DIRECT)
+        out[start:start + len(keys)] = values
+        return
+    count(PLACED)
     sorter = np.argsort(keys, kind="stable")
     sorted_keys = keys[sorter]
     slot = np.searchsorted(sorted_keys, req)

@@ -1368,8 +1368,8 @@ class MatrixWorker(WorkerTable):
             CHECK(not self._compress or len(reply_blobs) == 2,
                   "legacy float64-pair reply: the pre-codec wire "
                   "format was removed (docs/WIRE_FORMAT.md)")
-            values = reply_blobs[1].as_array(self.dtype).reshape(
-                keys.size, self.num_col)
+            values = reply_blobs[1].as_rows(self.dtype, keys.size,
+                                            self.num_col)
         requested = None
         ent = self._replica_sent.get(self._reply_msg_id)
         if ent is not None:
@@ -1394,11 +1394,13 @@ class MatrixWorker(WorkerTable):
             # Sparse whole-table get: dirty rows land at their global index.
             self._dest[keys] = values
         else:
-            # Vectorized placement: every requested position whose row id
-            # appears in THIS reply shard gets that row's value (a shard
-            # carries one server's key subset — possibly only the cache-
-            # missing rows of a partial hit; other positions are left
-            # for sibling shards or were cache-filled). Requests may
+            # Every requested position whose row id appears in THIS
+            # reply shard gets that row's value (a shard carries one
+            # server's key subset — possibly only the cache-missing rows
+            # of a partial hit; other positions are left for sibling
+            # shards or were cache-filled). place_rows picks the form
+            # from the shard: the request itself, or a run of a sorted
+            # one, is one copy; anything else is searched. Requests may
             # repeat ids — power-of-two padded row sets repeat the last
             # id thousands of times, so per-position Python loops go
             # quadratic and a single reply can burn minutes.

@@ -38,9 +38,13 @@ METRIC_NAMES: Dict[str, str] = {
     "WORKER_TABLE_SYNC_GET": "blocking table get_raw issue-to-reply",
     "WORKER_TABLE_SYNC_ADD": "blocking table add_raw issue-to-ack",
     "WORKER_REPLY_GET": "worker actor Get reply handling: materialise, "
-                        "reshape, place",
+                        "place",
     "TABLE_WAIT": "calling thread blocked in WorkerTable.wait on replies",
     "CLIENT_PLACE_ROWS": "Get reply rows placed into the caller's buffer",
+    "GET_REPLY_ROWS_DIRECT": "placed shards that were the request, or a "
+                             "run of a sorted one: one copy",
+    "GET_REPLY_ROWS_PLACED": "placed shards that took the general "
+                             "sort-search-gather-scatter",
     "BLOB_D2H": "device payload copied to host (np.asarray of a "
                 "jax.Array: waits for its program, then copies)",
     "BLOB_D2H_BYTES": "bytes those device-to-host copies moved",

@@ -398,3 +398,72 @@ class TestErrorReaping:
             Blob(np.array([-9], np.int32).view(np.uint8)))
         with pytest.raises(TableRequestError):
             table.wait(mid)
+
+
+# -- place_rows: a reply shard into the caller's buffer -----------------------
+
+#: name -> (request, the shard's keys, the counter one call moves)
+_PLACEMENTS = {
+    "whole, distinct sorted": ([1, 3, 5, 9], [1, 3, 5, 9], "DIRECT"),
+    "whole, padded tail of repeats": ([2, 4, 9, 9, 9], [2, 4, 9, 9, 9],
+                                      "DIRECT"),
+    "whole, unsorted request": ([9, 2, 4, 2], [9, 2, 4, 2], "DIRECT"),
+    "run at the head": ([1, 3, 5, 7, 9], [1, 3], "DIRECT"),
+    "run in the middle": ([1, 3, 5, 7, 9], [3, 5, 7], "DIRECT"),
+    "run at the tail": ([1, 3, 5, 7, 9], [7, 9], "DIRECT"),
+    "run that holds its repeats": ([1, 3, 3, 5, 5, 8], [3, 3, 5, 5],
+                                   "DIRECT"),
+    # a bisection of this request for 5 lands on the run at position 2
+    "run refused: unsorted request": ([5, 1, 5, 7, 9], [5, 7], "PLACED"),
+    "run refused: last id repeats past it": ([5, 7, 7], [5, 7], "PLACED"),
+    "same ids, another order": ([1, 3, 5], [5, 1, 3], "PLACED"),
+    "subset keys": ([1, 3, 5, 7, 9, 3], [9, 3], "PLACED"),
+    "keys absent from the request": ([1, 3, 5], [2, 4], "PLACED"),
+    "more keys than positions": ([3, 5], [1, 3, 5, 7], "PLACED"),
+    "empty keys": ([1, 3, 5], [], None),
+    "empty request": ([], [1, 3], None),
+}
+
+_COLS = 6
+
+
+def _row_values(keys, layout):
+    """One row a key, a function of the key (so equal ids carry equal
+    rows, as a table's do), in the memory layout named."""
+    rows = (np.asarray(keys, np.float32)[:, None] * 10
+            + np.arange(_COLS, dtype=np.float32))
+    if layout == "fortran":
+        rows = np.asfortranarray(rows)
+        assert len(keys) < 2 or not rows.flags["C_CONTIGUOUS"]
+    elif layout == "column slice":
+        wide = np.full((len(keys), 128), -7.0, np.float32)
+        wide[:, 40:40 + _COLS] = rows
+        rows = wide[:, 40:40 + _COLS]
+        assert not len(keys) or not rows.flags["C_CONTIGUOUS"]
+    return rows
+
+
+@pytest.mark.parametrize("layout", ["c", "fortran", "column slice"])
+@pytest.mark.parametrize("case", sorted(_PLACEMENTS))
+def test_place_rows_is_the_per_position_loop(case, layout):
+    """Whichever form place_rows picks from ``keys`` and ``req``, every
+    position whose id is in the shard holds that id's row, every other
+    keeps what it held, and the counter says which form ran."""
+    from multiverso_tpu.tables import client_cache
+    req, keys, counted = _PLACEMENTS[case]
+    req = np.asarray(req, np.int32)
+    keys = np.asarray(keys, np.int32)
+    values = _row_values(keys, layout)
+    sentinel = -1.0
+    want = np.full((req.size, _COLS), sentinel, np.float32)
+    for pos, row_id in enumerate(req.tolist()):
+        where = np.flatnonzero(keys == row_id)
+        if where.size:
+            want[pos] = values[where[0]]
+    out = np.full((req.size, _COLS), sentinel, np.float32)
+    names = {"DIRECT": client_cache.DIRECT, "PLACED": client_cache.PLACED}
+    before = {k: Dashboard.get(n).count for k, n in names.items()}
+    client_cache.place_rows(keys, values, req, out)
+    moved = {k: Dashboard.get(n).count - before[k] for k, n in names.items()}
+    np.testing.assert_array_equal(out, want)
+    assert moved == {k: int(k == counted) for k in names}

@@ -80,6 +80,7 @@ def one_get_one_add():
     ("TABLE_WAIT", 2),                 # one wait a request
     ("WORKER_REPLY_GET", 1),
     ("CLIENT_PLACE_ROWS", 1),
+    ("GET_REPLY_ROWS_DIRECT", 1),      # the one shard is the request
     ("BLOB_D2H", 1),
     ("BLOB_D2H_BYTES", IDS * COLS * 4),   # the reply's bytes
     ("MAILBOX_WAIT[server]", 2),       # the Get and the Add

@@ -723,3 +723,83 @@ class TestOneBitPush:
         finally:
             reset_flags()
             mv.shutdown()
+
+
+# -- a host Get's reply is written once (tables/client_cache.place_rows) -----
+
+def _counts():
+    from multiverso_tpu.util.dashboard import Dashboard
+    return {name: Dashboard.get(name).count for name in (
+        "CLIENT_PLACE_ROWS", "GET_REPLY_ROWS_DIRECT",
+        "GET_REPLY_ROWS_PLACED", "WORKER_REPLY_GET")}
+
+
+@pytest.mark.parametrize("out_given", [True, False], ids=["out", "no-out"])
+@pytest.mark.parametrize("servers, order, direct", [
+    (1, "sorted", 1),      # the one shard is the request
+    (1, "shuffled", 1),    # ... in whatever order it was asked
+    (2, "sorted", 2),      # each server's bucket is a run of the request
+    (2, "shuffled", 0),    # a bucket of an unsorted request is searched
+])
+def test_get_rows_places_each_reply_shard_once(servers, order, direct,
+                                               out_given):
+    """``get_rows`` with host ids and a host buffer: the values are the
+    table's, CLIENT_PLACE_ROWS counts one a shard, and the direct
+    counter says how many shards were copied straight in."""
+    rows, cols = 40, 3
+    base = np.arange(rows * cols, dtype=np.float32).reshape(rows, cols)
+    ids = np.array([1, 3, 4, 17, 21, 22, 38, 38], np.int32)  # both halves
+    if order == "shuffled":
+        ids = ids[[5, 0, 7, 2, 6, 1, 4, 3]]
+
+    def body(rank):
+        table = mv.create_matrix_table(rows, cols)
+        zoo = mv.current_zoo()
+        if rank == 0:
+            table.add(base)
+        zoo.barrier()
+        moved = got = None
+        if rank == 0:
+            table.get_rows(ids)                 # programs built
+            out = np.full((ids.size, cols), -1.0, np.float32) \
+                if out_given else None
+            before = _counts()
+            got = table.get_rows(ids, out)
+            moved = {k: v - before[k] for k, v in _counts().items()}
+            assert out is None or got is out
+        zoo.barrier()
+        return got, moved
+
+    if servers == 1:
+        mv.init([])
+        try:
+            got, moved = body(0)
+        finally:
+            mv.shutdown()
+    else:
+        got, moved = LocalCluster(servers).run(body)[0]
+    np.testing.assert_array_equal(got, base[ids])
+    assert moved == {"CLIENT_PLACE_ROWS": servers,
+                     "WORKER_REPLY_GET": servers,
+                     "GET_REPLY_ROWS_DIRECT": direct,
+                     "GET_REPLY_ROWS_PLACED": servers - direct}
+
+
+def test_blob_as_rows_shares_a_2d_host_payload():
+    """A payload that already is the rows is handed back in place;
+    ``as_array`` on the same blob is still the flat typed view, and a
+    flat payload comes back reshaped."""
+    rows = np.arange(12, dtype=np.float32).reshape(4, 3)
+    blob = Blob(rows)
+    got = blob.as_rows(np.float32, 4, 3)
+    assert got.shape == (4, 3) and np.shares_memory(got, rows)
+    np.testing.assert_array_equal(got, rows)
+    flat = blob.as_array(np.float32)
+    assert flat.shape == (12,) and np.shares_memory(flat, rows)
+    np.testing.assert_array_equal(flat, rows.reshape(-1))
+    # bytes off the wire, or another shape of the same bytes: reshaped
+    wire = Blob(rows.tobytes())
+    np.testing.assert_array_equal(wire.as_rows(np.float32, 4, 3), rows)
+    np.testing.assert_array_equal(blob.as_rows(np.float32, 2, 6),
+                                  rows.reshape(2, 6))
+    assert not wire.as_rows(np.float32, 4, 3).flags.writeable
