@@ -12,11 +12,12 @@ window opens before an epoch's call, so the epoch's own preparation
 (`_prep`: the subsampling mask and the one sort that compacts the
 corpus by it) is inside it, and
 closes inside the trainer's hook at the first block boundary after
-``seconds``, with a forced sync. The corpus is sized so that the window
-ends inside its first epoch; the hook then leaves the epoch by an
+``seconds``, with a forced sync. The hook then leaves the epoch by an
 exception and the driver does what the epoch's end does (drain the
 pushes, flush the word count, barrier). An epoch that ends inside the
-window is followed by the next.
+window is followed by the next: the 8M cells' window holds one epoch
+and most of a second (two `_prep`s), the 21M cell's ends inside its
+first.
 
 The traced window opens at the epoch's first block instead: three
 seconds that began with `_prep` would be mostly `_prep`. Every run

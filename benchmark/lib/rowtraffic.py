@@ -6,7 +6,10 @@ and values differ.
 
 Parameters a mix may set (all read here, nowhere else):
 
-``ops``               the closed loop's round, e.g. ``["get", "add"]``
+``ops``               the closed loop's round, e.g. ``["get", "add"]`` (host
+                      ids and numpy buffers) or ``["get_device",
+                      "add_device"]`` (the same ids and deltas as
+                      ``jax.Array``s; ``drivers/rows.py`` places them)
 ``ids_per_request``   distinct row ids in one request
 ``id_distribution``   ``{"kind": "zipf", "s": 1.0}`` or ``{"kind": "uniform"}``
 ``id_order``          ``"sorted"`` (what a worker sends after np.unique) or
@@ -97,6 +100,8 @@ class RowTraffic:
         extra = rng.integers(0, rows, int(mix["untouched_rows"]))
         self.sample = np.unique(np.concatenate(picked + [extra])) \
             .astype(np.int32)
+        # the most rows a sample can have, whatever the seed
+        self.sample_most = pool * k + extra.size
         # For each request: which of its positions are sampled rows, and
         # where those rows sit in ``sample``.
         self.positions, self.sample_index = [], []

@@ -3,19 +3,15 @@ hand-built ``Observations``: counts and milliseconds in, the value out;
 nothing where the count is 0, and nothing where the program has no such
 monitor (the parent commit, which the driver also runs them on). And
 what every entry of ``BENCHMARK.json`` has to have, wherever it stands:
-``per_layer`` grows at its end, so no test may count from there."""
-
-import os
+every list grows at its end, so no test may count from there, and each
+of these runs on the repo and on the copy a later PR appended to."""
 
 import pytest
 
 from benchmark.lib.harness import Observations
 from benchmark.run import load_module
 from benchmark.tests import entries
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-BENCH = entries.bench_of(ROOT)
+from benchmark.tests.later_pr import ROOTS, bench_at
 
 COUNTERS = {
     "TABLE_WAIT": {"count": 250, "ms": 19000.0},
@@ -64,20 +60,26 @@ def test_reader(name):
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
-def test_the_ten_are_entries_of_the_benchmark(name):
+def test_the_ten_are_entries_of_the_benchmark(name, root):
     """Found by name: a later PR appends its entries after them."""
-    entries.check_the_ten(BENCH, [name])
+    entries.check_the_ten(entries.bench_of(root), [name])
 
 
-@pytest.mark.parametrize(
-    "kind, metric", entries.entries(BENCH),
-    ids=[f"{kind}:{m['name']}" for kind, m in entries.entries(BENCH)])
-def test_every_entry_has_a_reader_a_source_and_cells(kind, metric):
-    entries.check_entry(ROOT, BENCH, kind, metric)
+EVERY_ENTRY = [(which, kind, metric["name"]) for which in ROOTS
+               for kind, metric in entries.entries(bench_at(which))]
 
 
-def test_no_name_twice_and_no_retired_metric():
-    bench, without_entry = entries.check_all(ROOT)
+@pytest.mark.parametrize("which, kind, name", EVERY_ENTRY,
+                         ids=[":".join(e) for e in EVERY_ENTRY])
+def test_every_entry_has_a_reader_a_source_and_cells(
+        which, kind, name, root_of):
+    root = root_of(which)
+    bench = entries.bench_of(root)
+    entries.check_entry(root, bench, kind, entries.named(bench, kind, name))
+
+
+def test_no_name_twice_and_no_retired_metric(root):
+    bench, without_entry = entries.check_all(root)
     names = {m["name"] for _, m in entries.entries(bench)}
     assert "trainer.epoch_start_s.train" not in names | without_entry
     # every reader on disk has its entry (PR 27's waited four PRs for one)

@@ -1,28 +1,23 @@
 """Every cell rehearsed end to end at a tiny size on the CPU (four
-virtual devices for the four-chip cell), and one cell made only of new
+virtual devices for the four-chip cell), on the repo and on the copy a
+later PR appended a cell to (`later_pr.py`), which is made only of new
 files and new entries. Each run is the real command in a process of its
 own, as the driver runs it, plus --rehearse."""
 
+import copy
 import json
 import os
-import shutil
 import subprocess
 import sys
 
 import pytest
 
 from benchmark.lib.harness import Observations
-from benchmark.tests import entries
+from benchmark.tests import entries, later_pr
+from benchmark.tests.later_pr import ROOT, ROOTS, bench_at
 from benchmark.tests.test_program_span_metrics import WANT as THE_TEN
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-
-
-def _bench(root=ROOT):
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        return json.load(f)
 
 
 def _run(cell, trace, tmp_path, root=ROOT, rehearse=True, devices=1):
@@ -30,7 +25,7 @@ def _run(cell, trace, tmp_path, root=ROOT, rehearse=True, devices=1):
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                BENCH_RUN="1")
-    command = [sys.executable] + _bench(root)["command"][1:] + [
+    command = [sys.executable] + entries.bench_of(root)["command"][1:] + [
         "--workload", cell, "--seed", str(2**31 + 11), "--seconds", "1",
         "--trace", str(trace)] + (["--rehearse"] if rehearse else [])
     return subprocess.run(command, cwd=root, env=env, text=True,
@@ -42,11 +37,14 @@ def _last_line(done):
     return json.loads(done.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in _bench()["workloads"]])
-def test_cell_rehearsed_end_to_end(cell, tmp_path):
-    bench = _bench()
-    chips = next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
-    done = _run(cell, 1, tmp_path, devices=chips)
+@pytest.mark.parametrize("which, cell", [
+    (which, w["name"]) for which in ROOTS
+    for w in bench_at(which)["workloads"]])
+def test_cell_rehearsed_end_to_end(which, cell, root_of, tmp_path):
+    root = root_of(which)
+    bench = entries.bench_of(root)
+    chips = entries.named(bench, "workloads", cell)["chips"]
+    done = _run(cell, 1, tmp_path, root=root, devices=chips)
     result = _last_line(done)
     assert RESULT_KEYS <= set(result)
     assert result["correct"] is True, done.stdout[-3000:]
@@ -86,63 +84,47 @@ def test_without_a_tpu_the_command_fails_and_prints_no_result(tmp_path):
     assert not any(line.startswith("{") for line in done.stdout.splitlines())
 
 
-def _copy_of_the_benchmark(tmp_path):
-    """A checkout of the benchmark's own files, for a test to add to."""
-    root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(ROOT, "multiverso_tpu"), root / "multiverso_tpu")
-    return root
-
-
-X4 = {"configs": {"name": "sgns-21m-d128-x4", "source": "x", "reduced": [],
-                  "file": "benchmark/configs/sgns-21m-d128-x4.json",
-                  "why": "kept for later"},
-      "workloads": {"name": "sgns21m-x4.ps", "config": "sgns-21m-d128-x4",
-                    "traffic": "sgns-ps-block", "chips": 4,
-                    "why": "kept for later"},
-      "per_layer": {"name": "device.collective_share.train", "unit": "%",
-                    "better": "lower", "source": "device_trace",
-                    "layer": "device", "moves": "words_per_s",
-                    "workloads": ["sgns21m-x4.ps"]}}
-LOCAL = {"workloads": {"name": "sgns8m.local", "config": "sgns-8m-d128",
-                       "traffic": "sgns-local", "chips": 1,
-                       "why": "kept for later"}}
-
-
-def _with_kept_cell(tmp_path, entries):
-    """A checkout whose BENCHMARK.json has lost a cell (as before PR 27
-    entered it) and gets it back as a later PR would add it: the entries
-    above, and the cell's name in every metric that `sgns8m.ps` reports
-    and the cell can."""
-    root = _copy_of_the_benchmark(tmp_path)
-    bench = _bench()
-    name = entries["workloads"]["name"]
-    for key, entry in entries.items():
-        bench[key] = [e for e in bench[key] if e["name"] != entry["name"]]
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if name in metric.get("workloads", []):
-            metric["workloads"].remove(name)
+def _taken_out_and_put_back(tmp_path, root, name):
+    """A checkout whose BENCHMARK.json has lost a cell (as before the PR
+    that entered it) and gets it back as a later PR would add it: its
+    entry, its configuration and the metrics that only it reports, each
+    appended last, and its name last in every list it was in. The
+    entries are the repo's own, found by name."""
+    bench = entries.bench_of(root)
+    cell = entries.named(bench, "workloads", name)
+    taken = {"workloads": [cell], "configs": [], "per_layer": [
+        m for m in bench["per_layer"] if m.get("workloads") == [name]]}
+    if not any(w["config"] == cell["config"] and w is not cell
+               for w in bench["workloads"]):
+        taken["configs"] = [entries.named(bench, "configs", cell["config"])]
+    for key, gone in taken.items():
+        bench[key] = [e for e in bench[key] if e not in gone]
+    was_in = [m for m in bench["end_to_end"] + bench["per_layer"]
+              if name in m.get("workloads", [])]
+    for metric in was_in:
+        metric["workloads"].remove(name)
     assert name not in json.dumps(bench)
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "sgns8m.ps" in metric.get("workloads", []):
-            metric["workloads"].append(name)
-    for key, entry in entries.items():
-        bench[key].append(entry)
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    return str(root), name
+    for metric in was_in:
+        metric["workloads"].append(name)
+    for key, gone in taken.items():
+        bench[key].extend(gone)
+    checkout = later_pr.copy_of_the_benchmark(tmp_path, root)
+    (checkout / "BENCHMARK.json").write_text(json.dumps(bench))
+    entries.check_all(str(checkout))
+    return str(checkout)
 
 
-@pytest.mark.parametrize("entries, devices, runs", [
-    (X4, 4, True), (X4, 1, False), (LOCAL, 1, True)])
-def test_a_cell_kept_for_later_needs_only_its_entries(
-        entries, devices, runs, tmp_path):
-    """`sgns21m-x4.ps` and `sgns8m.local` were kept for a later PR with
+@pytest.mark.parametrize("name, devices, runs", [
+    ("sgns21m-x4.ps", 4, True), ("sgns21m-x4.ps", 1, False),
+    ("sgns8m.local", 1, True)])
+def test_a_cell_needs_only_its_entries(name, devices, runs, root, tmp_path):
+    """`sgns21m-x4.ps` and `sgns8m.local` waited for a later PR with
     their configuration, traffic mix and metric readers here already (PR
-    27 entered them). With only its entries added such a cell runs, and
-    the four-chip one fails on fewer devices."""
-    root, name = _with_kept_cell(tmp_path, entries)
-    done = _run(name, 1 if runs else 0, tmp_path, root=root, devices=devices)
+    27 entered them). With only its entries added, last, such a cell
+    runs, and the four-chip one fails on fewer devices."""
+    checkout = _taken_out_and_put_back(tmp_path, root, name)
+    done = _run(name, 1 if runs else 0, tmp_path, root=checkout,
+                devices=devices)
     if runs:
         result = _last_line(done)
         assert result["correct"] is True, done.stdout[-3000:]
@@ -156,64 +138,19 @@ def test_a_cell_kept_for_later_needs_only_its_entries(
 
 def test_a_cell_added_as_files_runs_with_no_edit_to_an_existing_file(
         tmp_path):
-    """A later PR's view: a copy of the benchmark, plus a configuration,
-    a traffic mix and two metrics as NEW files and NEW entries, appended
-    last; every structural check holds on the copy too."""
-    root = _copy_of_the_benchmark(tmp_path)
+    """A later PR's view, made here as `conftest.py` makes it for every
+    structural test: a copy of the benchmark, plus a configuration, a
+    traffic mix and two metrics as NEW files and NEW entries, appended
+    last. Every structural check holds on it, its cell runs, and no file
+    that was there changed."""
+    root = later_pr.copy_of_the_benchmark(tmp_path)
     before = {p: p.read_bytes() for p in (root / "benchmark").rglob("*")
               if p.is_file()}
-
-    with open(root / "benchmark" / "configs" / "mperf-16m-c50.json") as f:
-        config = json.load(f)
-    config.update(name="mperf-tiny-c20", cols=20)
-    config["rehearsal"] = {"rows": 5000}
-    with open(root / "benchmark" / "traffic" / "rows-host-100k.json") as f:
-        mix = json.load(f)
-    mix.update(name="rows-uniform-add2", ops=["get", "add", "add"],
-               id_distribution={"kind": "uniform"}, id_order="drawn")
-    (root / "benchmark" / "configs" / "mperf-tiny-c20.json").write_text(
-        json.dumps(config))
-    (root / "benchmark" / "traffic" / "rows-uniform-add2.json").write_text(
-        json.dumps(mix))
-    (root / "benchmark" / "metrics" / "client.adds_per_get.rows.py") \
-        .write_text('"""Adds over Gets the caller made."""\n\n\n'
-                    'def read(obs):\n'
-                    '    s = obs.window.samples\n'
-                    '    return len(s["add_ms"]) / len(s["get_ms"])\n')
-    (root / "benchmark" / "metrics" / "table.unscoped_share.rows.py") \
-        .write_text('"""Percent of the programs\' device time under no '
-                    '`mv.` scope."""\n\n\ndef read(obs):\n'
-                    '    if obs.trace is None:\n        return None\n'
-                    '    by = [s for p in obs.trace["scopes"].values()\n'
-                    '          for s in p.items()]\n'
-                    '    whole = sum(t for _, t in by)\n'
-                    '    bare = sum(t for s, t in by if s == "no-scope")\n'
-                    '    return 100.0 * bare / whole if whole else None\n')
-    bench = _bench()
-    bench["configs"].append({
-        "name": "mperf-tiny-c20", "source": config["source"],
-        "file": "benchmark/configs/mperf-tiny-c20.json",
-        "reduced": ["rows"], "why": "a test's configuration"})
-    bench["workloads"].append({
-        "name": "tiny.add2", "config": "mperf-tiny-c20",
-        "traffic": "rows-uniform-add2", "chips": 1, "why": "a test's cell"})
-    for metric in bench["end_to_end"]:     # what the rows cell reports
-        if "mperf16m.rows" in metric.get("workloads", []):
-            metric["workloads"].append("tiny.add2")
-    bench["per_layer"].append({
-        "name": "client.adds_per_get.rows", "unit": "adds/get",
-        "better": "lower", "source": "program_counter",
-        "layer": "worker actor and client", "moves": "rows_per_s",
-        "workloads": ["tiny.add2"]})
-    bench["per_layer"].append({
-        "name": "table.unscoped_share.rows", "unit": "%",
-        "better": "lower", "source": "device_trace",
-        "layer": "table programs", "moves": "rows_per_s",
-        "workloads": ["tiny.add2", "mperf16m.rows"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    copy, without_entry = entries.check_all(str(root))
-    assert copy == bench and without_entry == set()
-    entries.check_the_ten(copy, sorted(THE_TEN))
+    later_pr.append_to(root)
+    bench = later_pr.appended_bench()
+    copy_, without_entry = entries.check_all(str(root))
+    assert copy_ == bench and without_entry == set()
+    entries.check_the_ten(copy_, sorted(THE_TEN))
     scoped = entries.reader_of(str(root), "table.unscoped_share.rows")
     assert scoped.read(Observations(trace={"scopes": {
         "jit_rows_padded": {"mv.update.scatter_add": 0.9, "no-scope": 0.05},
@@ -221,7 +158,7 @@ def test_a_cell_added_as_files_runs_with_no_edit_to_an_existing_file(
                         "no-scope": 0.05}}})) == pytest.approx(8.0)
     assert scoped.read(Observations()) is None
 
-    done = _run("tiny.add2", 1, tmp_path, root=str(root))
+    done = _run(later_pr.APPENDED_CELL, 1, tmp_path, root=str(root))
     result = _last_line(done)
     assert result["correct"] is True, done.stdout[-3000:]
     assert result["metrics"]["client.adds_per_get.rows"]["value"] == 2.0
@@ -231,11 +168,94 @@ def test_a_cell_added_as_files_runs_with_no_edit_to_an_existing_file(
     assert after == before, "an existing file of the benchmark was edited"
 
 
+# -- what a later PR may not do: pin a place, or break a rule ------------------
+
+PINS = {
+    "the last cell": lambda b, last: b["workloads"][-1]["name"]
+    == last["workloads"],
+    "the last configuration": lambda b, last: b["configs"][-1]["name"]
+    == last["configs"],
+    "the last per-layer entries": lambda b, last: [
+        m["name"] for m in b["per_layer"]][-len(last["per_layer"]):]
+    == last["per_layer"],
+    "the last name in a metric's list of cells": lambda b, last: entries.named(
+        b, "end_to_end", "peak_hbm_gb")["workloads"][-1] == last["workloads"],
+    "how many cells": lambda b, last: len(b["workloads"]) == last["cells"]}
+
+
+@pytest.mark.parametrize("pin", sorted(PINS))
+def test_a_pin_on_a_place_fails_on_the_appended_copy(pin, appended_root):
+    """PR 32's test asserted that its entries were the LAST of their
+    lists, and so no later PR could append. Such a pin, written for
+    whatever the repo's file holds last today, holds on the repo and
+    fails on the copy: a test that carries one fails in the run of the
+    PR that writes it. (Only this demonstration counts from an end.)"""
+    repo = entries.bench_of(ROOT)
+    last = {"workloads": repo["workloads"][-1]["name"],
+            "configs": repo["configs"][-1]["name"],
+            "per_layer": [m["name"] for m in repo["per_layer"][-3:]],
+            "cells": len(repo["workloads"])}
+    assert PINS[pin](repo, last)
+    assert not PINS[pin](entries.bench_of(appended_root), last)
+
+
+def _a_second_four_chip_cell(bench):
+    entries.named(bench, "workloads", "sgns8m.local")["chips"] = 4
+
+
+def _a_long_why(bench):
+    entries.named(bench, "workloads", "sgns8m.ps")["why"] = "w" * 201
+
+
+def _a_name_with_a_space(bench):
+    entries.named(bench, "workloads", "sgns8m.ps")["name"] = "sgns8m ps"
+
+
+def _a_name_twice(bench):
+    bench["workloads"].append(copy.deepcopy(
+        entries.named(bench, "workloads", "sgns8m.ps")))
+
+
+def _a_mix_that_is_no_file(bench):
+    entries.named(bench, "workloads", "sgns8m.ps")["traffic"] = "none-such"
+
+
+def _a_configuration_no_cell_uses(bench):
+    bench["configs"].append(dict(
+        entries.named(bench, "configs", "sgns-8m-d128"), name="unused",
+        file="benchmark/configs/unused.json"))
+
+
+def _twenty_five_cells(bench):
+    cell = entries.named(bench, "workloads", "sgns8m.ps")
+    while len(bench["workloads"]) < 25:
+        n = len(bench["workloads"])
+        bench["workloads"].append(dict(cell, name=f"cell{n}",
+                                       traffic=f"mix{n}"))
+
+
+@pytest.mark.parametrize("breach", [
+    _a_second_four_chip_cell, _a_long_why, _a_name_with_a_space,
+    _a_name_twice, _a_mix_that_is_no_file, _a_configuration_no_cell_uses,
+    _twenty_five_cells], ids=lambda f: f.__name__.strip("_"))
+def test_the_rules_for_cells_refuse(breach, root):
+    """`entries.check_cells` holds for every entry what PR 32's pin meant
+    for its own: the room a `why` has, names, at most 24 cells, a
+    quarter of them on four chips (one always), files that exist."""
+    bench = entries.bench_of(root)
+    entries.check_cells(root, bench)
+    breach(bench)
+    with pytest.raises(AssertionError):
+        entries.check_cells(root, bench)
+
+
+@pytest.mark.parametrize("cell", ["mperf16m.rows", "mperf16m.rows-dev"])
 def test_a_lost_add_underneath_a_run_comes_out_not_correct(
-        monkeypatch, capfd, tmp_path):
+        cell, monkeypatch, capfd, tmp_path):
     """The harness past its look for a chip, driven in this process with
     the timed path broken underneath: every fifth Add is acknowledged
-    and lost. The run ends, and says it is not correct."""
+    and lost (host buffers in one cell, device arrays in the other: both
+    are `add_rows`). The run ends, and says it is not correct."""
     import multiverso_tpu as mv
     from benchmark import run
     real = mv.create_matrix_table
@@ -253,7 +273,7 @@ def test_a_lost_add_underneath_a_run_comes_out_not_correct(
 
     monkeypatch.setattr(mv, "create_matrix_table", lossy)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    code = run.main(["--workload", "mperf16m.rows", "--seed", "5",
+    code = run.main(["--workload", cell, "--seed", "5",
                      "--seconds", "1", "--trace", "0", "--rehearse"])
     out = capfd.readouterr().out
     result = json.loads(out.strip().splitlines()[-1])

@@ -7,6 +7,7 @@ import pytest
 
 from benchmark.lib.harness import Observations
 from benchmark.run import load_module
+from benchmark.tests import entries
 
 TRACE = {
     "window_s": 3.0, "collective_s": 0.132,
@@ -49,8 +50,12 @@ WANT = {
 
 
 @pytest.mark.parametrize("name", sorted(WANT))
-def test_reader(name):
-    assert _read(name, _obs()) == pytest.approx(WANT[name])
+def test_reader(name, root):
+    """The reader as the root's `BENCHMARK.json` names it, found by name
+    wherever its entry stands."""
+    entries.named(entries.bench_of(root), "per_layer", name)
+    assert entries.reader_of(root, name).read(_obs()) \
+        == pytest.approx(WANT[name])
 
 
 @pytest.mark.parametrize("name", [
@@ -78,6 +83,27 @@ def test_the_path_share_counts_both_paths(name):
             "UPDATE_ROWS_XLA": {"count": 0, "ms": 0.0}}
     assert _read(name, _obs(counters=none)) is None
     assert _read(name, _obs(counters={"TABLE_WAIT": {"count": 1}})) is None
+
+
+def test_the_reply_share_reads_100_0_and_nothing():
+    """`client.reply_direct_share.rows` (PR 33's counters): every shard
+    of a reply copied straight in, every one searched for, and a window
+    without a host Get (the device cell's) or a program without the
+    counters."""
+    name = "client.reply_direct_share.rows"
+    direct = {"GET_REPLY_ROWS_DIRECT": {"count": 210, "ms": 0.0},
+              "GET_REPLY_ROWS_PLACED": {"count": 0, "ms": 0.0}}
+    assert _read(name, _obs(counters=direct)) == 100.0
+    placed = {"GET_REPLY_ROWS_DIRECT": {"count": 0, "ms": 0.0},
+              "GET_REPLY_ROWS_PLACED": {"count": 35, "ms": 0.0}}
+    assert _read(name, _obs(counters=placed)) == 0.0
+    both = {"GET_REPLY_ROWS_DIRECT": {"count": 30, "ms": 0.0},
+            "GET_REPLY_ROWS_PLACED": {"count": 10, "ms": 0.0}}
+    assert _read(name, _obs(counters=both)) == pytest.approx(75.0)
+    none = {"GET_REPLY_ROWS_DIRECT": {"count": 0, "ms": 0.0},
+            "GET_REPLY_ROWS_PLACED": {"count": 0, "ms": 0.0}}
+    assert _read(name, _obs(counters=none)) is None
+    assert _read(name, _obs(counters={"TABLE_WAIT": {"count": 9}})) is None
 
 
 def test_table_init_reads_set_up_and_not_the_window():
