@@ -1,0 +1,20 @@
+"""Model FLOP/s utilization of a block-diffusion cell's measured window,
+percent of peaks.json's `bf16_flops_per_s`: the operations the window's
+steps require (benchmark/lib/bdshapes.py: the dense products of both
+copies' positions, attention over the block mask's unmasked pairs, the
+head over the noised copy, the experts' products for the assignments the
+counter `LM_HELD_ASSIGNMENTS` saw; backward at twice the forward, the
+recomputed layer not counted) over the window's seconds. An end-to-end
+utilization, not a kernel's roofline share: idle time is in it."""
+
+from benchmark.lib import bdshapes, lmshapes
+
+
+def read(obs):
+    counts = lmshapes.window_counts(obs.window,
+                                    ("LM_STEP", "LM_HELD_ASSIGNMENTS"))
+    if counts is None or "block_length" not in obs.shapes:
+        return None
+    flops = bdshapes.step_flops(counts[0], counts[1], obs.shapes)
+    return lmshapes.share_of_peak(flops, obs.window.seconds,
+                                  obs.peaks["bf16_flops_per_s"])

@@ -8,10 +8,13 @@ Usage::
         [-lm_steps=10] [-lm_seq_len=8192] [-lm_sequences=2] \
         [-lm_warmup_steps=2000] [-lm_seed=0]
 
-``-lm_config`` is a JSON file in the published ``config.json``'s keys
-(``LMConfig.from_dict``). Tokens are a Zipf(1.0) stream drawn on the
-device. The learning rate rises linearly to 3e-4 over
-``-lm_warmup_steps`` steps (0: constant from the first step, at which
+``-lm_config`` is a JSON file in a published ``config.json``'s keys
+(``LMConfig.from_dict``: SmallThinker's, or Qwen3-MoE's as
+benchmark/configs/sdar-30b-a3b-l6.json has them, whose ``objective``
+makes the run block diffusion: ``-lm_seq_len`` clean tokens a sequence,
+twice as many positions). Tokens are a Zipf(1.0) stream drawn on the
+device (under block diffusion over every id but the mask token's). The
+learning rate rises linearly to 3e-4 over ``-lm_warmup_steps`` steps (0: constant from the first step, at which
 the share's routers send every token of a layer to the same experts
 within some thirty steps, docs/LM_TRAINER.md). The updater is ``adam``
 unless ``-updater_type`` says otherwise on the command line, and the
@@ -60,8 +63,10 @@ def run(argv=None) -> PSLMTrainer:
     key = jax.random.PRNGKey(get_flag("lm_seed"))
     start = time.perf_counter()
     for step in range(get_flag("lm_steps")):
-        tokens = zipf_tokens(jax.random.fold_in(key, step),
-                             (batch, seq_len + 1), cfg.vocab)
+        tokens = zipf_tokens(
+            jax.random.fold_in(key, step),
+            (batch, seq_len + (not trainer.diffusion)),
+            cfg.vocab - trainer.diffusion)
         loss = float(trainer.step(tokens))
         log.info("step %d: loss %.4f, %.0f tokens/s", step, loss,
                  (step + 1) * batch * seq_len

@@ -2,28 +2,50 @@
 pure functions over its tensors: what ``PSLMTrainer`` (ps_train.py)
 pulls from the parameter server, steps and pushes back.
 
-The block is SmallThinker's (PowerInfer, 2025): for a layer's input
-``x`` [T, hidden],
+**The layer is described, not assumed.** ``LMConfig`` says, for a layer's
+input ``x`` [T, hidden] at positions ``pos`` [T]:
 
-    p = softmax(x W_r) over all routed experts;  S = the top-k of p;
-    w_e = p_e / sum_S p                  (the router reads the layer's
-                                          raw input, BEFORE attention)
-    a = x + Attn(RMSNorm(x))             (grouped-query, causal; a layer
-                                          is either full attention with
-                                          no rotary positions or rotary
-                                          with a sliding window)
-    y = a + sum_{e in S, e held} w_e W_d,e (relu(h W_g,e) * (h W_u,e)),
-        h = RMSNorm(a)
+    a = x + Attn(RMSNorm(x))             grouped-query; ``qk_norm``: each
+                                          head's q and k through an RMSNorm
+                                          of their own (g_q, g_k [head_dim])
+                                          before the rotary turn; a layer is
+                                          rotary or not; its ``Mask`` is a
+                                          kind with its parameters
+    p = softmax(r W_r) over all routed experts;  S = the top-k of p;
+    w_e = p_e / sum_S p                  ``router_input``: r is the layer's
+                                          raw input x ("input", BEFORE
+                                          attention) or h below ("ffn_norm")
+    y = a + sum_{e in S, e held} w_e W_d,e (act(h W_g,e) * (h W_u,e)),
+        h = RMSNorm(a)                   ``activation``: relu or silu
+
+Two published blocks are constructed from their own ``config.json`` keys
+(``LMConfig.from_dict``): SmallThinker's (PowerInfer, 2025: relu, the
+router on the raw input, full layers without rotary positions and rotary
+layers with a sliding window) and Qwen3-MoE's as SDAR uses it (JetLM,
+2025, ``model_type: sdar_moe``: silu, the router on the normed
+post-attention stream, q and k norms, every layer rotary).
+
+**Two objectives** (``LMConfig.objective``). ``next_token``: causal or
+window masks, the loss the mean cross entropy of the next token.
+``block_diffusion``: a sequence of ``L`` clean tokens is cut into blocks
+of ``block_length``; ``noise`` masks each block's positions with the
+block's own probability ``t``; the model's input is the noised copy and
+the clean copy side by side, ``2L`` positions of which copy ``i`` and
+``i + L`` share rotary position ``i`` (``Mask.positions``), under
+``Mask.blockdiff``: a noised block sees itself and every clean block
+before it, the clean copy is causal by blocks and never sees the noised
+one. The loss is over the masked positions of the noised half alone,
+each weighted ``1/t`` (``head_loss_and_grads``'s ``weights``).
 
 **The share.** ``LMConfig.experts_held = (first, count)`` says which of
 the ``n_experts`` routed experts this chip holds. The router keeps all
 its outputs and its top-k, ``w_e`` is normalised over all k, and the
 layer adds the part of the sum that its own experts give; what the
 absent experts would add is left out (on a deployment it arrives from
-the chips that hold them: no code here stands in for them). The four
-shares of 16 add up to the uncut layer (tests/test_lm_model.py). The
-vocabulary is a slice too: ``vocab`` rows of embedding and of head, the
-loss over the slice.
+the chips that hold them: no code here stands in for them). The shares
+add up to the uncut layer (tests/test_lm_model.py, four of 16;
+tests/test_lm_blockdiff.py, eight of 16). The vocabulary is a slice
+too: ``vocab`` rows of embedding and of head, the loss over the slice.
 
 **No token is dropped.** The (token, expert) assignments that fall on
 held experts are sorted by expert and the three products run as grouped
@@ -40,17 +62,20 @@ float32 through a ``sink``, see ``mm``); the residual stream, norms,
 softmaxes, the router's product and the loss are float32.
 
 **Attention.** On a TPU the Pallas splash-attention kernel of
-``jax.experimental.pallas.ops.tpu`` with a causal or a local mask,
-which visits no block that is wholly masked, so a window layer costs
-less than a full one; elsewhere ``blockwise_attention``, the same sum
-over the same unmasked blocks in ``jax.numpy``. Neither materialises a
-[heads, T, T] array.
+``jax.experimental.pallas.ops.tpu`` with the library's causal or local
+mask or ``_BlockDiffusionMask`` (the same predicate computed in the
+kernel from index arithmetic), which visits no block that is wholly
+masked, so a window layer costs less than a full one and a
+block-diffusion layer 80 of 256 tiles at 8192 positions; elsewhere
+``blockwise_attention``, the same sum over the same unmasked blocks in
+``jax.numpy``. Neither materialises a [heads, T, T] array.
 
 Scopes (``jax.named_scope``; the backward pass runs under the same
 names, layer_grads): ``mv.lm.router``, ``mv.lm.attn.full`` /
-``mv.lm.attn.window`` (norm, projections, rotary, output projection)
-with the attention proper under ``mv.lm.attn.full.kernel`` /
-``mv.lm.attn.window.kernel``, ``mv.lm.experts``, ``mv.lm.head``.
+``mv.lm.attn.window`` / ``mv.lm.attn.blockdiff`` (norms, projections,
+rotary, output projection) with the attention proper under
+``<scope>.kernel``, ``mv.lm.experts``, ``mv.lm.head``; ``mv.lm.noise``
+is the trainer's (``noise`` runs in its batch-preparation program).
 """
 
 from __future__ import annotations
@@ -64,6 +89,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...util.log import CHECK
+
 BF16 = jnp.bfloat16
 F32 = jnp.float32
 
@@ -71,6 +98,7 @@ F32 = jnp.float32
 #: their gradients float32) and the small ones kept in float32.
 LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 LAYER_SMALL = ("router", "norm_attn", "norm_ffn")
+QK_NORMS = ("norm_q", "norm_k")     # with ``LMConfig.qk_norm``, float32 too
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,17 +118,45 @@ class LMConfig:
     rope_theta: float
     eps: float
     loss_block: int = 2048          # tokens a block of the head's loss
+    activation: str = "relu"        # the experts': "relu" | "silu"
+    router_input: str = "input"     # "input": the layer's raw input;
+    #                                 "ffn_norm": the normed post-attention
+    #                                 stream that feeds the experts
+    qk_norm: bool = False           # per-head RMSNorm of q and k
+    objective: str = "next_token"   # | "block_diffusion"
+    block_length: int = 0           # block diffusion: positions a block
+    t_min: float = 0.0              # block diffusion: t ~ U(t_min, 1]
 
     @property
     def n_layers(self) -> int:
         return len(self.rope_layout)
 
+    @property
+    def small_names(self) -> Tuple[str, ...]:
+        """A layer's float32 tensors, by name."""
+        return LAYER_SMALL + (QK_NORMS if self.qk_norm else ())
+
+    @property
+    def mask_id(self) -> int:
+        """Block diffusion's mask token: the slice's last row."""
+        return self.vocab - 1
+
+    def layer_mask(self, windowed, seq_len: int) -> "Mask":
+        """The mask of a layer over ``seq_len`` tokens a sequence."""
+        if self.objective == "block_diffusion":
+            return Mask.blockdiff(seq_len, self.block_length)
+        return Mask.of(self.window if windowed else 0)
+
     @classmethod
     def from_dict(cls, c: dict) -> "LMConfig":
-        """From a configuration in the published ``config.json``'s keys
-        (benchmark/configs/smallthinker-21ba3b-l4.json):
-        ``moe_num_primary_experts`` is the number HELD, ``router_outputs``
-        the published number the router still has."""
+        """From a configuration in a published ``config.json``'s keys, the
+        family told by them: SmallThinker's
+        (benchmark/configs/smallthinker-21ba3b-l4.json; below) or, with
+        ``num_experts``, Qwen3-MoE's (``_from_qwen3_moe``). In both the
+        key that counts the experts is the number HELD and
+        ``router_outputs`` the published number the router still has."""
+        if "num_experts" in c:
+            return cls._from_qwen3_moe(c)
         n = int(c["num_hidden_layers"])
         return cls(
             hidden=int(c["hidden_size"]),
@@ -121,12 +177,48 @@ class LMConfig:
             eps=float(c["rms_norm_eps"]),
             loss_block=int(c.get("loss_block", 2048)))
 
+    @classmethod
+    def _from_qwen3_moe(cls, c: dict) -> "LMConfig":
+        """Qwen3-MoE's block as ``model_type: sdar_moe`` configures it
+        (benchmark/configs/sdar-30b-a3b-l6.json): every layer rotary under
+        one mask, silu experts routed on the normed post-attention stream,
+        q and k norms. ``objective`` (a dict with ``kind``,
+        ``block_length``, ``t_min``) is the training objective, which the
+        published config does not state."""
+        n = int(c["num_hidden_layers"])
+        CHECK(not c.get("use_sliding_window") and not c.get("mlp_only_layers")
+              and int(c.get("decoder_sparse_step", 1)) == 1
+              and c.get("norm_topk_prob", True),
+              "only the block every layer of which is sparse, unwindowed "
+              "and normalises its top-k is written down here")
+        objective = c.get("objective", {"kind": "next_token"})
+        return cls(
+            hidden=int(c["hidden_size"]),
+            n_heads=int(c["num_attention_heads"]),
+            n_kv_heads=int(c["num_key_value_heads"]),
+            head_dim=int(c["head_dim"]),
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_tok"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["num_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(1,) * n, window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]),
+            eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c["hidden_act"]), router_input="ffn_norm",
+            qk_norm=True,
+            objective=str(objective["kind"]),
+            block_length=int(objective.get("block_length", 0)),
+            t_min=float(objective.get("t_min", 0.0)))
+
     def layer_shapes(self) -> dict:
         """Every tensor of one layer as the server stores it: a matrix
         table's (rows, columns) or a norm's (size,). The experts' three
         are stacked by expert along the rows."""
         h, e, w = self.hidden, self.experts_held[1], self.expert_width
-        return {
+        shapes = {
             "wq": (h, self.n_heads * self.head_dim),
             "wk": (h, self.n_kv_heads * self.head_dim),
             "wv": (h, self.n_kv_heads * self.head_dim),
@@ -134,6 +226,9 @@ class LMConfig:
             "router": (h, self.n_experts),
             "norm_attn": (h,), "norm_ffn": (h,),
             "w_gate": (e * h, w), "w_up": (e * h, w), "w_down": (e * w, h)}
+        if self.qk_norm:
+            shapes.update({n: (self.head_dim,) for n in QK_NORMS})
+        return shapes
 
     def parameters(self) -> int:
         per_layer = sum(int(np.prod(s)) for s in self.layer_shapes().values())
@@ -288,44 +383,118 @@ def rmsnorm(x, scale, eps):
 
 # -- attention -------------------------------------------------------------
 
-def _rotary(x, theta):
+@dataclasses.dataclass(frozen=True)
+class Mask:
+    """Which keys a query sees: a kind with its parameters.
+
+    ``causal``: ``j <= i``. ``window``: the last ``window`` positions,
+    itself included (``i - window < j <= i``). ``blockdiff``: ``2 * half``
+    positions, the noised copy of a sequence at ``0 .. half - 1`` and the
+    clean copy at ``half .. 2 half - 1``, both cut into blocks of
+    ``block``; with ``blk(i) = (i mod half) // block``, a noised query
+    sees the noised keys of its own block and the clean keys of earlier
+    blocks, a clean query the clean keys of its own and earlier blocks
+    and no noised key."""
+    kind: str = "causal"
+    window: int = 0
+    half: int = 0
+    block: int = 0
+
+    @classmethod
+    def of(cls, mask) -> "Mask":
+        """``mask`` itself, or from the short form of the first two kinds:
+        an int, the window (0: causal)."""
+        if isinstance(mask, cls):
+            return mask
+        return cls("window", window=int(mask)) if mask else cls()
+
+    @classmethod
+    def blockdiff(cls, half: int, block: int) -> "Mask":
+        CHECK(block > 0 and half % block == 0,
+              f"blocks of {block} do not divide a sequence of {half}")
+        return cls("blockdiff", half=int(half), block=int(block))
+
+    @property
+    def scope(self) -> str:
+        return "mv.lm.attn." + {"causal": "full"}.get(self.kind, self.kind)
+
+    def visible(self, i, j):
+        """Whether query position ``i`` sees key position ``j`` (arrays
+        that broadcast, numpy's or JAX's, in or out of a kernel: integer
+        division, comparisons and ``&``/``|`` alone)."""
+        if self.kind == "blockdiff":
+            # blocks counted over both copies: the noised copy's are
+            # 0 .. n - 1, the clean copy's n .. 2n - 1
+            n, q, k = self.half // self.block, i // self.block, \
+                j // self.block
+            return (k == q) | ((k >= n) & (((q < n) & (k < q + n))
+                                           | (k <= q)))
+        seen = j <= i
+        return seen & (j > i - self.window) if self.kind == "window" else seen
+
+    def key_ranges(self, lo: int, hi: int):
+        """The runs of keys ``(first, last)`` outside of which no query in
+        ``lo .. hi - 1`` sees a key."""
+        if self.kind == "blockdiff":
+            b, half = self.block, self.half
+            end = -(-hi // b) * b           # where the last query's block ends
+            if lo >= half:                  # clean queries: clean keys alone
+                return ((half, end),)
+            if hi > half:                   # both kinds of query
+                return ((lo // b * b, 2 * half),)
+            # noised queries: their own blocks, the clean blocks before
+            return ((lo // b * b, end), (half, half + end - b))
+        return ((max(lo - self.window + 1, 0)
+                 if self.kind == "window" else 0, hi),)
+
+    def positions(self, t: int):
+        """Each of ``t`` positions' rotary position: under ``blockdiff``
+        the two copies of a token share one."""
+        if self.kind == "blockdiff":
+            assert t == 2 * self.half, (t, self.half)
+            return np.tile(np.arange(self.half), 2)
+        return np.arange(t)
+
+
+def _rotary(x, theta, pos=None):
     """Rotary positions on [T, heads, d] (the halves paired, as the
-    published model's ``rotate_half``), float32."""
+    published model's ``rotate_half``), float32. ``pos`` [T] gives each
+    row's position (``arange(T)`` when None)."""
     t, _, d = x.shape
     inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
-    angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+    pos = np.arange(t) if pos is None else np.asarray(pos)
+    angle = pos.astype(np.float64)[:, None] * inv[None, :]
     cos = jnp.asarray(np.cos(angle), F32)[:, None, :]
     sin = jnp.asarray(np.sin(angle), F32)[:, None, :]
     x1, x2 = x[..., :d // 2], x[..., d // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def visible(i, j, window):
-    """Whether query position ``i`` sees key position ``j``: causal, and
-    with a ``window`` only the last ``window`` positions, itself
-    included (``i - window < j <= i``)."""
-    seen = j <= i
-    return seen if not window else seen & (j > i - window)
+def visible(i, j, mask):
+    """Whether query position ``i`` sees key position ``j`` under
+    ``mask`` (a ``Mask``, or an int: a window, 0 causal)."""
+    return Mask.of(mask).visible(i, j)
 
 
-def blockwise_attention(q, k, v, window: int, block: int = 512):
-    """Causal (and windowed) attention in blocks of queries, each over
-    the key blocks it can see and no other: q [groups, per_group, T, d]
-    (already scaled), k and v [groups, T, d], bfloat16; returns q's
-    shape. Scores and softmax float32, the probabilities rounded to
-    bfloat16 for the product with v, as the kernel does."""
+def blockwise_attention(q, k, v, mask, block: int = 512):
+    """Masked attention in blocks of queries, each over the key blocks it
+    can see and no other: q [groups, per_group, T, d] (already scaled), k
+    and v [groups, T, d], bfloat16; returns q's shape. Scores and softmax
+    float32, the probabilities rounded to bfloat16 for the product with
+    v, as the kernel does."""
+    mask = Mask.of(mask)
     t = q.shape[2]
     block = min(block, t)
     out = []
     for lo in range(0, t, block):
         hi = min(lo + block, t)
-        first = 0 if not window else max(lo - window + 1, 0) // block * block
-        kk, vv = k[:, first:hi], v[:, first:hi]
+        runs = [r for r in mask.key_ranges(lo, hi) if r[1] > r[0]]
+        kk, vv, j = (jnp.concatenate([a[:, f:l] for f, l in runs], axis=1)
+                     for a in (k, v, jnp.arange(t)[None, :]))
         s = jnp.einsum("ghqd,gkd->ghqk", q[:, :, lo:hi], kk,
                        preferred_element_type=F32)
         i = jnp.arange(lo, hi)[:, None]
-        j = jnp.arange(first, hi)[None, :]
-        s = jnp.where(visible(i, j, window), s, -jnp.inf)
+        s = jnp.where(mask.visible(i, j), s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         out.append(jnp.einsum("ghqk,gkd->ghqd", p.astype(BF16), vv,
                               preferred_element_type=F32))
@@ -333,16 +502,52 @@ def blockwise_attention(q, k, v, window: int, block: int = 512):
 
 
 @functools.lru_cache(maxsize=None)
-def _splash(t: int, per_group: int, window: int):
+def _block_diffusion_mask_class():
+    """The splash kernel's mask object for ``Mask.blockdiff`` (the
+    library is imported when a kernel is first wanted)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks)
+
+    class BlockDiffusionMask(masks._ComputableMask):
+        """``mask.visible`` computed in the kernel from the positions'
+        indices, and on the host a tile at a time to find the tiles to
+        skip; no [T, T] array."""
+
+        def __init__(self, mask: Mask):
+            self.mask = mask
+            super().__init__(shape=(2 * mask.half, 2 * mask.half),
+                             mask_function=mask.visible)
+
+        def __eq__(self, other):
+            return isinstance(other, type(self)) and other.mask == self.mask
+
+        def __hash__(self):
+            return hash((type(self), self.mask))
+
+    return BlockDiffusionMask
+
+
+def _block_diffusion_mask(mask: Mask):
+    return _block_diffusion_mask_class()(mask)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash(t: int, per_group: int, mask):
     """The TPU kernel for one key-value head and its ``per_group`` query
-    heads at length ``t``. Its mask is worked out on the host, once, and
-    kept as arrays; the first call comes from inside a program's trace,
-    so they are made under ``ensure_compile_time_eval``: a tracer kept
-    here would leak into the next program that takes the kernel."""
+    heads at length ``t`` under ``mask`` (a ``Mask``, or an int: a window,
+    0 causal). Its mask is worked out on the host, once, and kept as
+    arrays; the first call comes from inside a program's trace, so they
+    are made under ``ensure_compile_time_eval``: a tracer kept here would
+    leak into the next program that takes the kernel."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
-    one = masks.LocalMask((t, t), (window - 1, 0), 0) if window \
-        else masks.CausalMask((t, t))
+    mask = Mask.of(mask)
+    if mask.kind == "blockdiff":
+        assert t == 2 * mask.half, (t, mask)
+        one = _block_diffusion_mask(mask)
+    else:
+        one = masks.LocalMask((t, t), (mask.window - 1, 0), 0) \
+            if mask.kind == "window" else masks.CausalMask((t, t))
     b = min(512, t)
     sizes = kernel.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
@@ -352,26 +557,36 @@ def _splash(t: int, per_group: int, window: int):
             masks.MultiHeadMask([one] * per_group), block_sizes=sizes)
 
 
-def attention_core(q, k, v, window: int):
+def attention_core(q, k, v, mask):
     """The attention proper: q [groups, per group, T, d] (scaled), k and
-    v [groups, T, d], bfloat16."""
+    v [groups, T, d], bfloat16, under ``mask`` (a ``Mask``, or an int: a
+    window, 0 causal)."""
     t = q.shape[2]
     if jax.default_backend() == "tpu" and t % 128 == 0:
-        return jax.vmap(_splash(t, q.shape[1], window))(q, k, v)
-    return blockwise_attention(q, k, v, window)
+        return jax.vmap(_splash(t, q.shape[1], mask))(q, k, v)
+    return blockwise_attention(q, k, v, mask)
 
 
-def attention_inputs(cfg: LMConfig, rope: bool, mats, sinks, norm, x):
-    """Norm, the three projections, rotary positions, the scale: ``(q, k,
-    v)`` laid out for ``attention_core``."""
+def attention_inputs(cfg: LMConfig, rope: bool, mats, sinks, norms, x,
+                     pos=None):
+    """Norm, the three projections, the heads' q and k norms
+    (``cfg.qk_norm``), rotary positions (``pos``, ``arange(T)`` when
+    None), the scale: ``(q, k, v)`` laid out for ``attention_core``.
+    ``norms`` is the attention norm's scale, or with ``cfg.qk_norm`` the
+    three ``(norm_attn, norm_q, norm_k)``."""
     t = x.shape[0]
     g, per = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    norm, *qk = norms if cfg.qk_norm else (norms,)
     h = rmsnorm(x, norm, cfg.eps)
     q = mm(h, mats["wq"], sinks["wq"]).reshape(t, cfg.n_heads, cfg.head_dim)
     k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, cfg.head_dim)
     v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, cfg.head_dim)
-    if rope:
-        q, k = _rotary(q, cfg.rope_theta), _rotary(k, cfg.rope_theta)
+    if qk:      # over a head's lanes: each head normed alone
+        q, k = rmsnorm(q, qk[0], cfg.eps), rmsnorm(k, qk[1], cfg.eps)
+    if rope:    # positions go only where given: ``_rotary``'s short form
+        at = () if pos is None else (pos,)
+        q = _rotary(q, cfg.rope_theta, *at)
+        k = _rotary(k, cfg.rope_theta, *at)
     q = (q * (1.0 / math.sqrt(cfg.head_dim))).astype(BF16)
     # query head i reads key-value head i // per
     q = q.reshape(t, g, per, cfg.head_dim).transpose(1, 2, 0, 3)
@@ -386,17 +601,21 @@ def attention_output(cfg: LMConfig, mats, sinks, x, o):
     return x + mm(o, mats["wo"], sinks["wo"])
 
 
-def attention_block(cfg: LMConfig, rope: bool, window: int, mats, sinks,
-                    norm, x):
+def _attention_norms(cfg: LMConfig, small):
+    """What ``attention_inputs`` takes as ``norms`` out of a layer's small
+    tensors."""
+    if cfg.qk_norm:
+        return (small["norm_attn"],) + tuple(small[n] for n in QK_NORMS)
+    return small["norm_attn"]
+
+
+def attention_block(cfg: LMConfig, rope: bool, mask, mats, sinks,
+                    norms, x, pos=None):
     """``x + Attn(RMSNorm(x))`` for one sequence ``x`` [T, hidden]."""
-    q, k, v = attention_inputs(cfg, rope, mats, sinks, norm, x)
-    with jax.named_scope(_attn_scope(window) + ".kernel"):
-        o = attention_core(q, k, v, window)
+    q, k, v = attention_inputs(cfg, rope, mats, sinks, norms, x, pos)
+    with jax.named_scope(Mask.of(mask).scope + ".kernel"):
+        o = attention_core(q, k, v, mask)
     return attention_output(cfg, mats, sinks, x, o)
-
-
-def _attn_scope(window: int) -> str:
-    return "mv.lm.attn.window" if window else "mv.lm.attn.full"
 
 
 # -- router and experts -----------------------------------------------------
@@ -490,6 +709,9 @@ def _combine_bwd(k, res, g):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
     """``a + sum over held experts`` for one sequence ``a`` [T, hidden]
     with its routing."""
@@ -507,7 +729,7 @@ def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
 
     gate = product(rows, "w_gate", cfg.hidden, cfg.expert_width)
     up = product(rows, "w_up", cfg.hidden, cfg.expert_width)
-    act = jnp.where(live, jax.nn.relu(gate) * up, 0)
+    act = jnp.where(live, ACTIVATIONS[cfg.activation](gate) * up, 0)
     out = product(act, "w_down", cfg.expert_width, cfg.hidden)
     # each row weighted by its assignment's w_e (the [T, k] weights one a
     # row, in the rows' order), rounded to bfloat16 for the way back to
@@ -523,34 +745,61 @@ def _zeros_like_f32(mats):
     return {name: jnp.zeros(w.shape, F32) for name, w in mats.items()}
 
 
-def layer_forward(cfg: LMConfig, rope: bool, window: int, mats, small, x):
-    """One sequence through one layer: ``(y, stats, ids)``, ``stats``
-    int32[2] = (assignments on held experts, the fullest held expert's)
-    and ``ids`` [T, k] each token's experts (a check hands them to its
-    reference; a step drops them)."""
+def _route_layer(cfg: LMConfig, router, norm_ffn, stream):
+    """``route`` on what the configuration's router reads of ``stream``:
+    the layer's raw input as it is, or the post-attention stream through
+    the experts' norm."""
+    if cfg.router_input == "ffn_norm":
+        stream = rmsnorm(stream, norm_ffn, cfg.eps)
+    return route(cfg, router, stream)
+
+
+def layer_forward(cfg: LMConfig, rope: bool, mask, mats, small, x, pos=None):
+    """One sequence through one layer under ``mask`` (a ``Mask``, or an
+    int: a window, 0 causal) at rotary positions ``pos``: ``(y, stats,
+    ids)``, ``stats`` int32[2] = (assignments on held experts, the
+    fullest held expert's) and ``ids`` [T, k] each token's experts (a
+    check hands them to its reference; a step drops them)."""
     sinks = _zeros_like_f32(mats)
-    with jax.named_scope("mv.lm.router"):
-        ids, weights = route(cfg, small["router"], x)
-    with jax.named_scope(_attn_scope(window)):
-        a = attention_block(cfg, rope, window, mats, sinks,
-                            small["norm_attn"], x)
+    early = cfg.router_input == "input"
+
+    def routed(stream):
+        with jax.named_scope("mv.lm.router"):
+            return _route_layer(cfg, small["router"], small["norm_ffn"],
+                                stream)
+
+    if early:
+        ids, weights = routed(x)
+    with jax.named_scope(Mask.of(mask).scope):
+        a = attention_block(cfg, rope, mask, mats, sinks,
+                            _attention_norms(cfg, small), x, pos)
+    if not early:
+        ids, weights = routed(a)
     with jax.named_scope("mv.lm.experts"):
         y, sizes = experts_block(cfg, mats, sinks, small["norm_ffn"], a,
                                  ids, weights)
     return y, jnp.stack([jnp.sum(sizes), jnp.max(sizes)]), ids
 
 
-def layer_grads(cfg: LMConfig, rope: bool, window: int, mats, small, x, dy):
+def layer_grads(cfg: LMConfig, rope: bool, mask, mats, small, x, dy,
+                pos=None):
     """The layer recomputed from its input ``x`` and differentiated:
     ``(dx, matrix gradients, small gradients)`` for one sequence. Each
     part's backward pass runs under the scope of its forward pass, so a
     device trace reads the two together."""
     sinks = _zeros_like_f32(mats)
     attn_names = ("wq", "wk", "wv", "wo")
-    with jax.named_scope("mv.lm.router"):
-        (ids, weights), pull_router = jax.vjp(
-            lambda r, x: route(cfg, r, x), small["router"], x)
-    scope = _attn_scope(window)
+    early = cfg.router_input == "input"
+
+    def routed(stream):     # the router's part, on what it reads
+        with jax.named_scope("mv.lm.router"):
+            return jax.vjp(
+                lambda r, n, s: _route_layer(cfg, r, n, s),
+                small["router"], small["norm_ffn"], stream)
+
+    if early:
+        (ids, weights), pull_router = routed(x)
+    scope = Mask.of(mask).scope
     qkv = {n: sinks[n] for n in ("wq", "wk", "wv")}
     # A scope names a backward pass only where it is entered OUTSIDE the
     # differentiated function (inside, JAX writes it as transpose(jvp(..)),
@@ -558,15 +807,18 @@ def layer_grads(cfg: LMConfig, rope: bool, window: int, mats, small, x, dy):
     # are differentiated one by one, the kernel under its own name.
     with jax.named_scope(scope):
         (q, k, v), pull_inputs = jax.vjp(
-            lambda s, norm, x: attention_inputs(cfg, rope, mats, s, norm, x),
-            qkv, small["norm_attn"], x)
+            lambda s, norms, x: attention_inputs(cfg, rope, mats, s, norms,
+                                                 x, pos),
+            qkv, _attention_norms(cfg, small), x)
     with jax.named_scope(scope + ".kernel"):
         o, pull_core = jax.vjp(
-            lambda q, k, v: attention_core(q, k, v, window), q, k, v)
+            lambda q, k, v: attention_core(q, k, v, mask), q, k, v)
     with jax.named_scope(scope):
         a, pull_output = jax.vjp(
             lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),
             sinks["wo"], x, o)
+    if not early:
+        (ids, weights), pull_router = routed(a)
     with jax.named_scope("mv.lm.experts"):
         y, pull_experts = jax.vjp(
             lambda s, norm, a, w: experts_block(cfg, mats, s, norm, a, ids,
@@ -574,44 +826,59 @@ def layer_grads(cfg: LMConfig, rope: bool, window: int, mats, small, x, dy):
             {n: s for n, s in sinks.items() if n not in attn_names},
             small["norm_ffn"], a, weights)
         d_experts, d_norm_ffn, da, dw = pull_experts(dy.astype(y.dtype))
+
+    def pull_routed():
+        with jax.named_scope("mv.lm.router"):
+            return pull_router((np.zeros(ids.shape, jax.dtypes.float0), dw))
+
+    if not early:   # the router read the stream the experts read
+        d_router, d_norm_router, d_stream = pull_routed()
+        d_norm_ffn, da = d_norm_ffn + d_norm_router, da + d_stream
     with jax.named_scope(scope):
         d_wo, dx, do = pull_output(da)
     with jax.named_scope(scope + ".kernel"):
         d_qkv = pull_core(do)
     with jax.named_scope(scope):
-        d_attn, d_norm_attn, dx_inputs = pull_inputs(d_qkv)
+        d_attn, d_norms, dx_inputs = pull_inputs(d_qkv)
     d_attn["wo"], dx = d_wo, dx + dx_inputs
-    with jax.named_scope("mv.lm.router"):
-        d_router, dx_router = pull_router(
-            (np.zeros(ids.shape, jax.dtypes.float0), dw))
-    return (dx + dx_router, {**d_attn, **d_experts},
-            {"router": d_router, "norm_attn": d_norm_attn,
-             "norm_ffn": d_norm_ffn})
+    if early:       # it read the layer's input: its norm argument unused
+        d_router, _, d_stream = pull_routed()
+        dx = dx + d_stream
+    d_small = {"router": d_router, "norm_ffn": d_norm_ffn}
+    if cfg.qk_norm:
+        d_small.update(zip(("norm_attn",) + QK_NORMS, d_norms))
+    else:
+        d_small["norm_attn"] = d_norms
+    return dx, {**d_attn, **d_experts}, d_small
 
 
 # -- the head: final norm, logits over the slice, the loss, its gradients ------
 
-def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets):
-    """Mean next-token cross entropy over ``x`` [N, hidden] and ``targets``
-    [N], and its gradients, a block of ``loss_block`` tokens at a time so
-    that no [N, vocab] array exists: ``(loss, dx [N, hidden], d_head
-    [vocab, hidden] float32, d_norm)``. ``head`` is the bfloat16 copy,
-    rows by vocabulary id."""
+def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets, weights=None,
+                        normaliser=None):
+    """The cross entropy of ``targets`` [N] over ``x`` [N, hidden], each
+    position weighted by ``weights`` [N] (1 when None), summed and divided
+    by ``normaliser`` (N when None: the plain mean), and its gradients, a
+    block of ``loss_block`` tokens at a time so that no [N, vocab] array
+    exists: ``(loss, dx [N, hidden], d_head [vocab, hidden] float32,
+    d_norm)``. ``head`` is the bfloat16 copy, rows by vocabulary id."""
     n = x.shape[0]
     block = min(cfg.loss_block, n)
     assert n % block == 0, (n, block)
+    over = n if normaliser is None else normaliser
 
-    def block_loss(x, norm, sink, targets):
+    def block_loss(x, norm, sink, targets, weights):
         h = rmsnorm(x, norm, cfg.eps)
         logits = mm_nt(h, head, sink)
         picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
-        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked) / n
+        each = jax.nn.logsumexp(logits, axis=-1) - picked
+        return jnp.sum(each if weights is None else each * weights) / over
 
     def one(carry, xs):
         loss, d_head, d_norm = carry
-        x, targets = xs
+        x, targets, weights = xs
         more, (dx, dn, dh) = jax.value_and_grad(block_loss, (0, 1, 2))(
-            x, norm, jnp.zeros(head.shape, F32), targets)
+            x, norm, jnp.zeros(head.shape, F32), targets, weights)
         return (loss + more, d_head + dh, d_norm + dn), dx
 
     with jax.named_scope("mv.lm.head"):
@@ -619,5 +886,29 @@ def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets):
             one, (jnp.zeros((), F32), jnp.zeros(head.shape, F32),
                   jnp.zeros(norm.shape, F32)),
             (x.reshape(n // block, block, -1),
-             targets.reshape(n // block, block)))
+             targets.reshape(n // block, block),
+             None if weights is None else weights.reshape(n // block, block)))
     return loss, dx.reshape(x.shape), d_head, d_norm
+
+
+# -- block diffusion's noise ---------------------------------------------------
+
+def noise(cfg: LMConfig, key, tokens):
+    """Block diffusion's noised copy of ``tokens`` [B, L] (clean ids,
+    none the mask token's): each block of ``cfg.block_length`` positions
+    draws ``t ~ U(t_min, 1]`` and each of its positions is masked with
+    probability ``t``. Returns ``(noised [B, L], masked [B, L] bool, t
+    [B, L // block_length] float32)``; the loss weighs a masked position
+    of block ``j`` by ``1 / t[j]`` (the linear schedule of masked
+    diffusion)."""
+    b, n = tokens.shape
+    CHECK(n % cfg.block_length == 0,
+          f"blocks of {cfg.block_length} do not divide a sequence of {n}")
+    key_t, key_mask = jax.random.split(key)
+    blocks = (b, n // cfg.block_length)
+    # uniform() is on [0, 1): 1 - it on (0, 1], so t on (t_min, 1]
+    t = cfg.t_min + (1.0 - cfg.t_min) * (
+        1.0 - jax.random.uniform(key_t, blocks, F32))
+    masked = jax.random.uniform(key_mask, (b, n), F32) < jnp.repeat(
+        t, cfg.block_length, axis=1)
+    return jnp.where(masked, cfg.mask_id, tokens), masked, t

@@ -177,10 +177,15 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_STEP": "PSLMTrainer.step: Gets, programs and Adds dispatched "
                "(the wait for the last step's programs included)",
     "LM_GET_PARAMS": "a step's Gets: the embedding rows by device keys, "
-                     "a layer's ten tables whole, head and final norm",
-    "LM_ADD_GRADS": "a step's Adds: a layer's ten gradients whole as "
+                     "a layer's tables whole, head and final norm",
+    "LM_ADD_GRADS": "a step's Adds: a layer's gradients whole as "
                     "device deltas, head and norm, the embedding rows",
     "LM_TOKENS": "tokens trained",
+    "LM_POSITIONS": "positions through the layers: the tokens trained, or "
+                    "twice as many under block diffusion (the noised and "
+                    "the clean copy)",
+    "LM_MASKED_TOKENS": "positions that carry a loss: every one under "
+                        "next-token, the masked ones under block diffusion",
     "LM_GET_BYTES": "bytes of the whole-table device Gets' replies",
     "LM_ADD_BYTES": "bytes of the whole-table device Adds' deltas",
     "LM_HELD_ASSIGNMENTS": "(token, expert) assignments that fell on held "

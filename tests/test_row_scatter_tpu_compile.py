@@ -209,3 +209,52 @@ def test_the_grouped_products_compile_for_a_v5e_at_the_experts_widths(
         # the rows' gradient and the weights' gradient
         assert compiled.as_text().count("tpu_custom_call") >= 2
         assert compiled.memory_analysis().temp_size_in_bytes < 700e6
+
+
+# -- block diffusion (benchmark/configs/sdar-30b-a3b-l6.json) --------------------
+
+def test_the_attention_kernel_compiles_under_the_block_diffusion_mask(topo):
+    """Splash attention forward and backward under the program's own mask
+    object (``lm.Mask.blockdiff``: integer division and comparisons on
+    the positions' indices inside the kernel) for one sequence's 2 x 4096
+    positions at the published head counts (4 key-value heads, 8 query
+    heads each)."""
+    from multiverso_tpu.models.lm import model as lm
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    t, mask = 8192, lm.Mask.blockdiff(4096, 4)
+
+    def loss(q, k, v):
+        out = jax.vmap(lm._splash(t, 8, mask))(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shaped((4, 8, t, 128), jnp.bfloat16, sharding=one),
+        shaped((4, t, 128), jnp.bfloat16, sharding=one),
+        shaped((4, t, 128), jnp.bfloat16, sharding=one)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # no [heads, T, T] array: 32 x 8192 x 8192 x 4 B would be 8.6 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_the_grouped_products_compile_at_the_block_diffusion_widths(
+        topo, monkeypatch):
+    """16 experts of 2048 x 768 and of 768 x 2048 over the 65,536
+    assignment rows of a sequence's two copies (8 a position)."""
+    from multiverso_tpu.models.lm import model as lm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+
+    def loss(x, sink, w, sizes):
+        return jnp.sum(lm.grouped_mm(x, w, sink, sizes))
+
+    for k, n in ((2048, 768), (768, 2048)):
+        assert lm._use_gmm(65536, k, n)
+        compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+            shaped((65536, k), jnp.bfloat16, sharding=one),
+            shaped((16, k, n), jnp.float32, sharding=one),
+            shaped((16, k, n), jnp.bfloat16, sharding=one),
+            shaped((16,), jnp.int32, sharding=one)).compile()
+        assert compiled.as_text().count("tpu_custom_call") >= 2
+        assert compiled.memory_analysis().temp_size_in_bytes < 900e6
