@@ -146,6 +146,30 @@ def _shaped_rows(values, n_rows: int, num_col: int):
     return values
 
 
+def _shard_cuts(dest: Optional[np.ndarray], n: int) -> List:
+    """How a host row-id request of ``n`` keys is cut into per-server
+    shards: ``[(server id, index)]``, decided from ``dest`` (each key's
+    server, None = one server holds them all) alone. Where ``dest`` is
+    non-decreasing — sorted keys under the division rule, or under a
+    shard map whose owners rise with the row — each server's shard is
+    one run of the request and the index is a ``slice``: indexing the
+    request's keys and values with it gives VIEWS, nothing is copied.
+    Anything else (unsorted keys over several servers, a map that
+    interleaves owners, re-routed replica rows) gets a boolean mask per
+    server, in server order: the gathered copy. Both forms give each
+    server the same bytes."""
+    if n == 0:
+        return []
+    if dest is None:
+        return [(0, slice(0, n))]
+    step = np.diff(dest)
+    if step.size == 0 or int(step.min()) >= 0:
+        edges = [0, *(np.flatnonzero(step) + 1).tolist(), n]
+        return [(int(dest[lo]), slice(lo, hi))
+                for lo, hi in zip(edges[:-1], edges[1:])]
+    return [(int(sid), dest == sid) for sid in np.unique(dest)]
+
+
 def _trim_rows(values, n_rows: int):
     """Slice gather output down to the real row count only when padding
     added rows (full-range device slices still dispatch)."""
@@ -1079,7 +1103,11 @@ class MatrixWorker(WorkerTable):
                                  and int(keys.max()) < self.num_row),
               "row ids out of range [0, num_row)")
         is_add = msg_type == MsgType.Request_Add
-        dest = self._server_of_rows(keys)
+        # One server under the frozen layout: the shard is the request.
+        # (A shard map, even over one active server, may name others.)
+        dest = None
+        if self._num_server > 1 or self._shard_map is not None:
+            dest = self._server_of_rows(keys)
         if (not is_add and self._replica_router is not None
                 and self._replica_router.active):
             # Replicated (hot) rows re-route to holder servers — the
@@ -1102,39 +1130,45 @@ class MatrixWorker(WorkerTable):
         if is_add:
             if blobs[1].on_device and not self._compress:
                 # Device delta: slice per-server segments in HBM (keys
-                # must be sorted for multi-server so segments are
-                # contiguous; single-server always passes whole).
+                # must be in server order so segments are contiguous;
+                # a lone shard passes whole: a full-range device slice
+                # would still dispatch).
                 dev_values = _shaped_rows(blobs[1].typed(self.dtype),
                                           keys.size, self.num_col)
-                if self._num_server > 1:
-                    CHECK(bool(np.all(np.diff(dest) >= 0)),
-                          "device row adds need sorted row ids")
             else:
                 values = blobs[1].as_array(self.dtype).reshape(
                     keys.size, self.num_col)
-        for sid in np.unique(dest):
-            mask = dest == sid
-            shard = [Blob(np.ascontiguousarray(keys[mask]).view(np.uint8))]
+        cuts = _shard_cuts(dest, keys.size)
+        for sid, cut in cuts:
+            # A slice is a VIEW of the request's keys and values (the
+            # caller's memory until the ack, docs/MEMORY.md "send side
+            # of an Add"); a mask gathers a copy.
+            run = isinstance(cut, slice)
+            shard_keys = keys[cut]
+            shard = [Blob(shard_keys.view(np.uint8))]
             if dev_values is not None:
-                lo, hi = np.searchsorted(dest, [sid, sid + 1])
-                shard.append(Blob(dev_values[lo:hi]))
+                CHECK(run, "device row adds need sorted row ids")
+                shard.append(Blob(dev_values if len(cuts) == 1
+                                  else dev_values[cut]))
                 if len(blobs) == 3:
                     shard.append(blobs[2])
             elif values is not None:
-                chunk = np.ascontiguousarray(values[mask])
+                count_event("ADD_ROWS_SHARD_VIEW" if run
+                            else "ADD_ROWS_SHARD_COPIED")
+                chunk = values[cut]
                 if self._compress:
                     shard.extend(self._codec_chunk(chunk, 0, 0,
-                                                   rows=keys[mask]))
+                                                   rows=shard_keys))
                 elif self._one_bit:
                     shard.extend(self._onebit_chunk(chunk, 0, 0,
-                                                    rows=keys[mask]))
+                                                    rows=shard_keys))
                 else:
                     shard.append(Blob(chunk))
                 if len(blobs) == 3:
                     shard.append(blobs[2])
             elif len(blobs) == 2:  # sparse GetOption
                 shard.append(blobs[1])
-            out[int(sid)] = shard
+            out[sid] = shard
         return out
 
     def get_dirty_device(self):

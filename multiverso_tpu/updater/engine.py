@@ -271,17 +271,28 @@ def pad_rows(row_ids, delta, num_rows: int):
     out-of-range so scatter drops them and gather fills zeros. DEVICE
     deltas pass through logical-sized — the engine's rows jit extends
     them to the id count internally (a separate device pad would cost a
-    full program launch per add)."""
+    full program launch per add).
+
+    A HOST delta always leaves here as an array the table owns: the
+    padded one, or at a bucket-sized k a copy. The jitted program that
+    takes it returns before the runtime has read the host buffer (it
+    reads the numpy argument's memory after the call, docs/MEMORY.md
+    "Send side of an Add"), and the buffer may be the caller's own
+    delta, which the caller may overwrite once the Add is
+    acknowledged."""
     row_ids = np.asarray(row_ids, dtype=np.int32)
     k = row_ids.shape[0]
     b = bucket_size(k)
     if b != k:
         row_ids = np.concatenate(
             [row_ids, np.full(b - k, num_rows, dtype=np.int32)])
-        from ..core.blob import is_device_array
-        if not is_device_array(delta):
+    from ..core.blob import is_device_array
+    if not is_device_array(delta):
+        if b != k:
             pad = ((0, b - k),) + ((0, 0),) * (len(np.shape(delta)) - 1)
             delta = np.pad(np.asarray(delta), pad)
+        else:
+            delta = np.array(delta)
     return row_ids, delta
 
 

@@ -45,6 +45,11 @@ METRIC_NAMES: Dict[str, str] = {
                              "run of a sorted one: one copy",
     "GET_REPLY_ROWS_PLACED": "placed shards that took the general "
                              "sort-search-gather-scatter",
+    "ADD_ROWS_SHARD_VIEW": "host row-Add shards partition cut as views "
+                           "of the request (one server, or a run of "
+                           "a request in server order): no copy",
+    "ADD_ROWS_SHARD_COPIED": "host row-Add shards partition gathered "
+                             "with a mask into a fresh array",
     "BLOB_D2H": "device payload copied to host (np.asarray of a "
                 "jax.Array: waits for its program, then copies)",
     "BLOB_D2H_BYTES": "bytes those device-to-host copies moved",
