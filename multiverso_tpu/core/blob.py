@@ -157,12 +157,19 @@ class Blob:
             self._materialize_parts()
         if not isinstance(self._data, np.ndarray):
             if is_device_array(self._data):
-                # THE host boundary of a device reply: np.asarray waits
-                # for the program that produces the array, then copies
-                # device to host into a fresh buffer.
+                # THE host boundary of a device reply: the wait for the
+                # program that produces the array, then the copy device
+                # to host into a fresh buffer; BLOB_D2H is both. The
+                # copy is queued behind the program first, as np.asarray
+                # alone queues it: waiting before asking for it would
+                # put a host wake-up between the two.
                 from ..util.dashboard import count, monitor
                 with monitor("BLOB_D2H"):
-                    self._data = np.asarray(self._data)
+                    self._data.copy_to_host_async()
+                    with monitor("BLOB_D2H_READY"):
+                        self._data.block_until_ready()
+                    with monitor("BLOB_D2H_COPY"):
+                        self._data = np.asarray(self._data)
                 count("BLOB_D2H_BYTES", self._data.nbytes)
             else:
                 self._data = np.asarray(self._data)

@@ -70,7 +70,8 @@ from .model import _MAX_EXP, _sigmoid_xent
 @jax.jit
 def _prep(flat, sent, keep, key):
     # The scopes name the program's two steps in a device trace
-    # (tools/trace_spans.py sums device time by them).
+    # (benchmark/lib/xplane.py reduce, which tools/trace_spans.py
+    # prints, sums device time by them).
     with jax.named_scope("mv.prep.mask"):
         mask = jax.random.uniform(key, flat.shape) < keep[flat]
     # Kept tokens keep corpus order, so positional distance in the
@@ -673,7 +674,7 @@ class DeviceCorpusTrainer:
             for i in range(real):
                 lrs[i] = model.learning_rate()
                 model.trained_words += raw_per_step
-            with device_lock.guard():
+            with monitor("TRAINER_GROUP_DISPATCH"), device_lock.guard():
                 (model._emb_in, model._emb_out, loss, pairs,
                  key) = device_lock.settle(self._group(
                     model._emb_in, model._emb_out, kept, ksent,
@@ -1004,38 +1005,43 @@ class PSDeviceCorpusTrainer:
         raw_per_step = self._n_tokens / max(math.ceil(n_kept / C), 1)
         loss_acc = None
         pair_acc = None
+        # The trainer's own dispatches are one monitor a kind of work a
+        # block; the client calls between them have CLIENT_ISSUE_*, the
+        # waits TABLE_WAIT.
         for g0 in range(0, steps, G):
-            real = min(G, steps - g0)
-            step_key = jax.random.fold_in(key, g0)
-            if G == 1:
-                base = np.int32(g0 * C)
-                lr_host = np.float32(model.learning_rate())
-                model._account_words(raw_per_step)
-            else:
-                # Padded tail blocks get base = n_kept (fully masked)
-                # and lr 0 — exact no-ops, so the program set stays one
-                # fixed shape.
-                bases = np.full(G, n_kept, np.int32)
-                bases[:real] = (np.arange(g0, g0 + real)
-                                * C).astype(np.int32)
-                lr_host = np.zeros(G, np.float32)
-                for i in range(real):
-                    lr_host[i] = model.learning_rate()
+            with monitor("TRAINER_BLOCK_UPLOAD"):
+                real = min(G, steps - g0)
+                step_key = jax.random.fold_in(key, g0)
+                if G == 1:
+                    base = np.int32(g0 * C)
+                    lr_host = np.float32(model.learning_rate())
                     model._account_words(raw_per_step)
-            with device_lock.guard():
-                # The per-group scalar/vector uploads are dispatches
-                # too — one guarded region keeps them from interleaving
-                # a sibling rank's program in multi-zoo mode.
-                if G != 1:
-                    base = device_lock.settle(jnp.asarray(bases))
-                lr = device_lock.settle(jnp.asarray(lr_host))
-                inv_w = device_lock.settle(
-                    jnp.float32(1.0 / model._num_workers))
+                else:
+                    # Padded tail blocks get base = n_kept (fully
+                    # masked) and lr 0 — exact no-ops, so the program
+                    # set stays one fixed shape.
+                    bases = np.full(G, n_kept, np.int32)
+                    bases[:real] = (np.arange(g0, g0 + real)
+                                    * C).astype(np.int32)
+                    lr_host = np.zeros(G, np.float32)
+                    for i in range(real):
+                        lr_host[i] = model.learning_rate()
+                        model._account_words(raw_per_step)
+                with device_lock.guard():
+                    # The per-group scalar/vector uploads are dispatches
+                    # too — one guarded region keeps them from
+                    # interleaving a sibling rank's program in
+                    # multi-zoo mode.
+                    if G != 1:
+                        base = device_lock.settle(jnp.asarray(bases))
+                    lr = device_lock.settle(jnp.asarray(lr_host))
+                    inv_w = device_lock.settle(
+                        jnp.float32(1.0 / model._num_workers))
             # in_ids: centers (skip-gram) or the band (CBOW);
             # out_ids: [band | negs] / [centers | negs] / Huffman
             # path rows — see _block_ids_fn / _block_ids_fn_hs;
             # leading G axis when grouped.
-            with device_lock.guard():
+            with monitor("TRAINER_BLOCK_IDS"), device_lock.guard():
                 in_ids, out_ids, pmask = device_lock.settle(self._ids(
                     kept_pad, ksent_pad, self._aux_tables[0],
                     self._aux_tables[1], step_key, base, n_kept_dev))
@@ -1049,20 +1055,24 @@ class PSDeviceCorpusTrainer:
             # Per-server shard tuples; the step jit sums them
             # (fused — no separate reassembly dispatch on
             # multi-server tables).
-            v = tuple(in_table.take_device_row_parts())
-            u = tuple(out_table.take_device_row_parts())
-            with device_lock.guard():
-                d_v, d_u, loss, pairs = device_lock.settle(
-                    self._step(v, u, pmask, lr, inv_w))
+            with monitor("TRAINER_BLOCK_STEP"):
+                v = tuple(in_table.take_device_row_parts())
+                u = tuple(out_table.take_device_row_parts())
+                with device_lock.guard():
+                    d_v, d_u, loss, pairs = device_lock.settle(
+                        self._step(v, u, pmask, lr, inv_w))
             # Fire-and-forget pushes: waiters self-reap on ack; the
             # trailing drain below bounds the epoch.
             model._pending_pushes.append(
                 (in_table, in_table.add_rows_async(in_ids, d_v)))
             model._pending_pushes.append(
                 (out_table, out_table.add_rows_async(out_ids, d_u)))
-            loss_acc = loss if loss_acc is None else loss_acc + loss
-            pair_acc = pairs if pair_acc is None else pair_acc + pairs
-            self.last_loss = loss  # device scalar; callers sync on it
+            # Two more dispatches, and the first calls after the Adds
+            # left that give up the GIL: the actors take it from here.
+            with monitor("TRAINER_BLOCK_LOSS"):
+                loss_acc = loss if loss_acc is None else loss_acc + loss
+                pair_acc = pairs if pair_acc is None else pair_acc + pairs
+                self.last_loss = loss  # device scalar; callers sync on it
             if block_hook is not None:
                 block_hook(raw_per_step * real)
         model._drain_pushes()

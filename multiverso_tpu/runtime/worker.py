@@ -492,20 +492,24 @@ class Worker(Actor):
 
     # ref: src/worker.cpp:86-88
     def _process_reply_add(self, msg: Message) -> None:
-        table = self._cache[msg.table_id]
-        self._untrack((msg.src, msg.table_id, msg.msg_id))
-        # The piggybacked version bump must land BEFORE the notify: the
-        # adder's completion callback reads the tracker to resolve its
-        # self-invalidated cache slots (read-your-writes); it also
-        # raises this worker's read-your-writes floor for the shard
-        # (replica groups below the floor repair to the owner).
-        table.note_add_ack(self._reply_server_id(msg), reply_version(msg))
-        error = take_error(msg)
-        if error is not None:
-            table.fail(msg.msg_id, error, count=False)
-        tracing.event(trace_of(msg), "waiter_notify", self._zoo.rank,
-                      args={"from": msg.src})
-        table.notify(msg.msg_id)
+        with monitor("WORKER_REPLY_ADD", msg_id=msg.msg_id,
+                     table=msg.table_id):
+            table = self._cache[msg.table_id]
+            self._untrack((msg.src, msg.table_id, msg.msg_id))
+            # The piggybacked version bump must land BEFORE the notify:
+            # the adder's completion callback reads the tracker to
+            # resolve its self-invalidated cache slots (read-your-
+            # writes); it also raises this worker's read-your-writes
+            # floor for the shard (replica groups below the floor repair
+            # to the owner).
+            table.note_add_ack(self._reply_server_id(msg),
+                               reply_version(msg))
+            error = take_error(msg)
+            if error is not None:
+                table.fail(msg.msg_id, error, count=False)
+            tracing.event(trace_of(msg), "waiter_notify", self._zoo.rank,
+                          args={"from": msg.src})
+            table.notify(msg.msg_id)
 
     def _process_reply_batch_add(self, msg: Message) -> None:
         """One coalesced ack: notify every sub-add's waiter, surfacing

@@ -34,7 +34,8 @@ from ..updater import AddOption, UpdateEngine, create_rule
 from ..util.log import CHECK
 from . import client_cache
 from .client_cache import BlobCache
-from .table_interface import ServerTable, WorkerTable
+from .table_interface import (ServerTable, WorkerTable, issues_add,
+                              issues_get)
 
 _ALL_KEY = np.array([-1], dtype=np.int32)
 
@@ -77,6 +78,7 @@ class ArrayWorker(WorkerTable):
         self.retrying_wait(lambda: self.get_async(out))
         return self._dest
 
+    @issues_get
     def get_async(self, out: Optional[np.ndarray] = None) -> int:
         if out is None:
             out = np.empty(self.size, self.dtype)
@@ -119,6 +121,7 @@ class ArrayWorker(WorkerTable):
             option: Optional[AddOption] = None) -> None:
         self.retrying_wait(lambda: self.add_async(delta, option))
 
+    @issues_add
     def add_async(self, delta, option: Optional[AddOption] = None) -> int:
         """Accepts host or device arrays; a device delta rides the whole
         stack without touching the host (the TPU-native hot path)."""
@@ -159,9 +162,7 @@ class ArrayWorker(WorkerTable):
     def get_device(self):
         """Whole-table Get returning a device array (no host transfer).
         The reply shards are the servers' jitted snapshots in HBM."""
-        self._dest, self._device_shards = None, {}
-        msg_id = self.get_async_raw(Blob(_ALL_KEY.view(np.uint8)))
-        self.wait(msg_id)
+        self.wait(self.get_device_async())
         shards = [self._device_shards[sid]
                   for sid in range(len(self._device_shards))]
         self._device_shards = None
@@ -172,6 +173,11 @@ class ArrayWorker(WorkerTable):
         # multi-device program (multi-zoo mode only; no-op otherwise).
         with device_lock.guard():
             return device_lock.settle(jnp.concatenate(shards))
+
+    @issues_get
+    def get_device_async(self) -> int:
+        self._dest, self._device_shards = None, {}
+        return self.get_async_raw(Blob(_ALL_KEY.view(np.uint8)))
 
     # -- reply (ref: array_table.cpp:95-106) --
     def process_reply_get(self, reply_blobs: List[Blob]) -> None:

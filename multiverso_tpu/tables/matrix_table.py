@@ -58,7 +58,8 @@ from ..util.configure import define_bool, get_flag
 from ..util.log import CHECK
 from ..util.quantization import OneBitFilter
 from .table_interface import (RpcTimeoutError, ServerTable,
-                              TableRequestError, WorkerTable)
+                              TableRequestError, WorkerTable, issues_add,
+                              issues_get)
 from ..runtime.net import PeerLostError
 
 define_bool("sparse_compress", True,
@@ -466,6 +467,7 @@ class MatrixWorker(WorkerTable):
         self.retrying_wait(lambda: self.get_async(out))
         return self._dest
 
+    @issues_get
     def get_async(self, out: Optional[np.ndarray] = None) -> int:
         if out is None:
             # Sparse whole-table gets return only dirty rows, so a fresh
@@ -482,8 +484,8 @@ class MatrixWorker(WorkerTable):
             # is layout-free). Costs the id vector on the wire; full-
             # table pulls on an elastically resharded table are not a
             # hot path (docs/SHARDING.md).
-            return self.get_rows_async(
-                np.arange(self.num_row, dtype=np.int32), out)
+            return MatrixWorker.get_rows_async.__wrapped__(  # one span
+                self, np.arange(self.num_row, dtype=np.int32), out)
         self._dest, self._dest_rows, self._device_shards = out, None, None
         return self._request_get(Blob(_ALL_KEY.view(np.uint8)))
 
@@ -492,6 +494,7 @@ class MatrixWorker(WorkerTable):
         self.retrying_wait(lambda: self.get_rows_async(row_ids, out))
         return self._dest
 
+    @issues_get
     def get_rows_async(self, row_ids,
                        out: Optional[np.ndarray] = None) -> int:
         row_ids = np.ascontiguousarray(row_ids, dtype=np.int32).reshape(-1)
@@ -794,6 +797,7 @@ class MatrixWorker(WorkerTable):
         self.wait(self.get_rows_device_async(row_ids))
         return self.take_device_rows()
 
+    @issues_get
     def get_rows_device_async(self, row_ids) -> int:
         """Async device row pull.
 
@@ -885,6 +889,7 @@ class MatrixWorker(WorkerTable):
     def add(self, delta, option: Optional[AddOption] = None) -> None:
         self.retrying_wait(lambda: self.add_async(delta, option))
 
+    @issues_add
     def add_async(self, delta, option: Optional[AddOption] = None) -> int:
         """Whole-table add; device arrays stay on device end to end."""
         if not is_device_array(delta):
@@ -896,8 +901,8 @@ class MatrixWorker(WorkerTable):
             # Dynamic map: the sentinel add slices per the frozen
             # offsets — route as an all-rows row Add instead (keys
             # travel, the partition buckets by the live map).
-            return self.add_rows_async(
-                np.arange(self.num_row, dtype=np.int32),
+            return MatrixWorker.add_rows_async.__wrapped__(  # one span
+                self, np.arange(self.num_row, dtype=np.int32),
                 delta.reshape(self.num_row, self.num_col), option)
         CHECK(self._shard_map is None or self.is_sparse
               or not is_device_array(delta),
@@ -933,6 +938,7 @@ class MatrixWorker(WorkerTable):
         self.retrying_wait(
             lambda: self.add_rows_async(row_ids, delta, option))
 
+    @issues_add
     def add_rows_async(self, row_ids, delta,
                        option: Optional[AddOption] = None) -> int:
         """Row-delta push. A ``jax.Array`` delta stays on device end to
@@ -1297,13 +1303,17 @@ class MatrixWorker(WorkerTable):
 
     # -- device-resident whole-table Get (shards stay in HBM) --
     def get_device(self):
+        self.wait(self.get_device_async())
+        return self.take_device_rows()
+
+    @issues_get
+    def get_device_async(self) -> int:
         self._check_frozen_layout("device whole-table gets")
         CHECK(not self.is_sparse,
               "device get is for dense tables (sparse replies are ragged)")
         self._dest, self._dest_rows, self._device_shards = None, None, {}
         self._device_sum = False
-        self.wait(self._request_get(Blob(_ALL_KEY.view(np.uint8))))
-        return self.take_device_rows()
+        return self._request_get(Blob(_ALL_KEY.view(np.uint8)))
 
     # -- replies (ref: matrix_table.cpp:317-341) --
     def process_reply_get(self, reply_blobs: List[Blob]) -> None:
@@ -1862,9 +1872,11 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
             rows = blobs[0].typed(np.int32)
             gather = self._gather if self._shard_bounds is None \
                 else self._gather_bounded
+            with monitor("TABLE_GATHER_DISPATCH"):
+                values = gather(self._data, rows)
             # The server id rides along so the worker can key the reply
             # shard by ORIGIN server, not by arrival order.
-            return [blobs[0], Blob(gather(self._data, rows)),
+            return [blobs[0], Blob(values),
                     Blob(np.array([self.server_id], dtype=np.int32))]
         keys = blobs[0].as_array(np.int32)
         if keys.size == 1 and keys[0] == -4:
@@ -1900,9 +1912,10 @@ class MatrixServer(shard_map_mod.ElasticServerMixin, ServerTable):
             if not bool(own_mask.all()):
                 return self._replica_row_get(keys, own_mask)
         local_rows = keys - self.row_offset
-        padded_rows = pad_ids(local_rows, self._data.shape[0])
-        values = _trim_rows(self._gather(self._data, padded_rows),
-                            keys.size)
+        with monitor("TABLE_GATHER_DISPATCH"):  # the trim's slice too
+            padded_rows = pad_ids(local_rows, self._data.shape[0])
+            values = _trim_rows(self._gather(self._data, padded_rows),
+                                keys.size)
         if self._up_to_date is not None and len(blobs) >= 2:
             opt = GetOption.from_blob(blobs[1])
             if 0 <= opt.worker_id < self._up_to_date.shape[0]:

@@ -19,6 +19,7 @@ state for checkpointing (ref: include/multiverso/table_interface.h:61-75).
 
 from __future__ import annotations
 
+import functools
 import io
 import itertools
 import threading
@@ -32,7 +33,7 @@ from ..runtime.net import PeerLostError
 from ..runtime.zoo import current_zoo
 from ..util import log, tracing
 from ..util.configure import get_flag
-from ..util.dashboard import monitor
+from ..util.dashboard import Dashboard, monitor
 from ..util.lock_witness import named_lock
 from ..util.waiter import Waiter
 from .client_cache import VersionTracker
@@ -46,6 +47,25 @@ _MAX_RETAINED_ERRORS = 128
 #: witness keys its graph by NAME, so instances must not share one
 #: (client_cache.py precedent).
 _state_lock_serial = itertools.count()
+
+
+def _issuing(span):
+    """Decorator for a table's public async entry points: the entry's
+    whole body, id checks and copies to the message in the worker
+    actor's mailbox, is one span of the CALLER's thread."""
+    def decorate(entry):
+        @functools.wraps(entry)
+        def issue(table, *args, **kwargs):
+            with span(table):
+                return entry(table, *args, **kwargs)
+        return issue
+    return decorate
+
+
+issues_get = _issuing(
+    lambda table: monitor("CLIENT_ISSUE_GET", table=table.table_id))
+issues_add = _issuing(
+    lambda table: monitor("CLIENT_ISSUE_ADD", table=table.table_id))
 
 
 class TableRequestError(RuntimeError):
@@ -261,9 +281,13 @@ class WorkerTable:
                 flag_timeout = configured
         # Only the blocking itself: a request already complete (the
         # early return above) never counts.
-        with monitor("TABLE_WAIT"):
+        with monitor("TABLE_WAIT", msg_id=msg_id, table=self.table_id):
             ok = waiter.wait(timeout=timeout if timeout is not None
                              else flag_timeout)
+        if waiter.woke_after_ms is not None:
+            # The hand-off back: the worker actor's completing notify
+            # to this thread running again (two threads, one GIL).
+            Dashboard.get("TABLE_WAKE").add(waiter.woke_after_ms)
         self._check_aborted()
         if ok:
             with self._mutex:

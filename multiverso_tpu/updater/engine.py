@@ -31,7 +31,7 @@ import numpy as np
 
 from ..sharding import mesh as meshlib
 from .options import AddOption
-from ..util.dashboard import count
+from ..util.dashboard import count, monitor
 from .rules import UpdaterRule, create_rule, fast_rows
 
 _DEFAULT_HYP = AddOption().hyper_array()
@@ -92,7 +92,8 @@ class UpdateEngine:
             return delta
 
         # The named scopes mark the programs' steps in a device trace
-        # (tools/trace_spans.py sums device time by them): the pads,
+        # (benchmark/lib/xplane.py reduce sums device time by them, for
+        # the benchmark and for tools/trace_spans.py): the pads,
         # the rule, and inside a rule's rows form the scatter-add
         # (rules.py). They change no operation and no program's name.
         def dense_padded(data, st, delta, hyp, worker_id):
@@ -133,8 +134,9 @@ class UpdateEngine:
 
     def apply_dense(self, data, delta, option: Optional[AddOption] = None):
         hyp, worker_id = _unpack(option)
-        data, self._state = self._dense(data, self._state, delta,
-                                        hyp, worker_id)
+        with monitor("UPDATE_DISPATCH"):  # a host delta's upload too
+            data, self._state = self._dense(data, self._state, delta,
+                                            hyp, worker_id)
         return data
 
     def apply_rows(self, data, row_ids, delta,
@@ -164,8 +166,9 @@ class UpdateEngine:
         self._count_path(row_ids)
         rows_fn = self._rows if bounds is None \
             else self._bounded_rows_fn(bounds)
-        data, self._state = rows_fn(data, self._state, row_ids, delta,
-                                    hyp, worker_id)
+        with monitor("UPDATE_DISPATCH"):  # a host delta's upload too
+            data, self._state = rows_fn(data, self._state, row_ids, delta,
+                                        hyp, worker_id)
         return data
 
     def _count_path(self, row_ids) -> None:
@@ -239,8 +242,9 @@ class UpdateEngine:
 
             fn = jax.jit(f, donate_argnums=(0, 1))
             self._rows_gather[n_col] = fn
-        data, self._state, values = fn(data, self._state, row_ids,
-                                       delta, hyp, worker_id, get_ids)
+        with monitor("UPDATE_DISPATCH"):
+            data, self._state, values = fn(data, self._state, row_ids,
+                                           delta, hyp, worker_id, get_ids)
         return data, values
 
     @property
@@ -288,11 +292,13 @@ def pad_rows(row_ids, delta, num_rows: int):
             [row_ids, np.full(b - k, num_rows, dtype=np.int32)])
     from ..core.blob import is_device_array
     if not is_device_array(delta):
-        if b != k:
-            pad = ((0, b - k),) + ((0, 0),) * (len(np.shape(delta)) - 1)
-            delta = np.pad(np.asarray(delta), pad)
-        else:
-            delta = np.array(delta)
+        with monitor("UPDATE_PAD_ROWS"):
+            if b != k:
+                pad = ((0, b - k),) \
+                    + ((0, 0),) * (len(np.shape(delta)) - 1)
+                delta = np.pad(np.asarray(delta), pad)
+            else:
+                delta = np.array(delta)
     return row_ids, delta
 
 

@@ -8,8 +8,8 @@ context manager (``with monitor("name"):``) that is also a span on the
 profiler's clock: every entry opens a ``jax.profiler.TraceAnnotation``
 named ``mv:<name>``, so a monitored region lies beside the device's
 operations in any trace captured around it (``trace_to``, or the
-benchmark's ``--trace 1``). With no profiler session open the annotation
-is a disabled TraceMe and records nothing.
+benchmark's ``--trace 1``). With no profiler session open no annotation
+is built.
 """
 
 from __future__ import annotations
@@ -39,7 +39,15 @@ METRIC_NAMES: Dict[str, str] = {
     "WORKER_TABLE_SYNC_ADD": "blocking table add_raw issue-to-ack",
     "WORKER_REPLY_GET": "worker actor Get reply handling: materialise, "
                         "place",
+    "WORKER_REPLY_ADD": "worker actor Add ack handling: version "
+                        "stamp, error, the waiter's notify",
+    "CLIENT_ISSUE_GET": "caller's thread, a table's public async Get "
+                        "entry to the message in the worker's mailbox",
+    "CLIENT_ISSUE_ADD": "the same for an Add (the cache's begin_add "
+                        "and the blobs included)",
     "TABLE_WAIT": "calling thread blocked in WorkerTable.wait on replies",
+    "TABLE_WAKE": "the completing notify on the worker actor's thread "
+                  "to a blocked WorkerTable.wait running again",
     "CLIENT_PLACE_ROWS": "Get reply rows placed into the caller's buffer",
     "GET_REPLY_ROWS_DIRECT": "placed shards that were the request, or a "
                              "run of a sorted one: one copy",
@@ -52,6 +60,10 @@ METRIC_NAMES: Dict[str, str] = {
                              "with a mask into a fresh array",
     "BLOB_D2H": "device payload copied to host (np.asarray of a "
                 "jax.Array: waits for its program, then copies)",
+    "BLOB_D2H_READY": "inside BLOB_D2H: the wait for the program that "
+                      "makes the array (block_until_ready)",
+    "BLOB_D2H_COPY": "inside BLOB_D2H: the copy (np.asarray of the "
+                     "ready array)",
     "BLOB_D2H_BYTES": "bytes those device-to-host copies moved",
     # -- server actor --
     "SERVER_PROCESS_GET": "server-side Get table op + reply",
@@ -173,11 +185,29 @@ METRIC_NAMES: Dict[str, str] = {
     "UPDATE_ROWS_XLA": "the same dispatches and calls whose scatter-add "
                        "is XLA's scatter (off the TPU, other dtypes, "
                        "under rules.FAST_MIN_IDS ids)",
+    # -- inside the server's handlers (updater/engine.py,
+    # tables/matrix_table.py process_get) --
+    "UPDATE_PAD_ROWS": "pad_rows on a HOST delta: np.pad to the bucket, "
+                       "or the copy at a bucket-sized k",
+    "UPDATE_DISPATCH": "the update's jitted call, dense or rows (a "
+                       "host delta's upload is inside it)",
+    "TABLE_GATHER_DISPATCH": "a row Get's pad_ids and the gather's "
+                             "jitted call, host ids or device keys",
     # -- device-corpus trainers (models/wordembedding/device_train.py) --
     "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
                           "dispatch: _prep (subsample mask, one sort "
                           "that carries tokens and sentence ids), pad, "
                           "kept-count readback",
+    "TRAINER_BLOCK_UPLOAD": "a PS block's host arithmetic and the "
+                            "uploads of base, lr and 1/workers",
+    "TRAINER_BLOCK_IDS": "a PS block's ids program dispatched",
+    "TRAINER_BLOCK_STEP": "a PS block's reply parts taken and its step "
+                          "program dispatched",
+    "TRAINER_BLOCK_LOSS": "a PS block's loss and pair counts added to "
+                          "the epoch's (two dispatches)",
+    "TRAINER_GROUP_DISPATCH": "the local trainer's group program "
+                              "dispatched with its two uploads (G "
+                              "blocks)",
     # -- language-model trainer (models/lm/ps_train.py) --
     "LM_STEP": "PSLMTrainer.step: Gets, programs and Adds dispatched "
                "(the wait for the last step's programs included)",
@@ -354,8 +384,8 @@ class monitor:
     ``args`` (a request's ``msg_id`` and ``table``) go to the span
     only, so that a span in a trace can be matched to its request;
     the Monitor counts and times the same whatever they are. "Tracing
-    off" is "no profiler session open": the annotation is then a
-    disabled TraceMe.
+    off" is "no profiler session open" (``TraceMe.is_enabled()``): no
+    annotation is built then.
     """
 
     __slots__ = ("_name", "_args", "_monitor", "_span", "_begin")
@@ -365,9 +395,14 @@ class monitor:
         self._args = args
 
     def __enter__(self) -> Monitor:
-        self._span = (_trace_annotation or _bind_annotation())(
-            SPAN_PREFIX + self._name, **self._args)
-        self._span.__enter__()
+        annotation = _trace_annotation or _bind_annotation()
+        # Built only under a profiler session: a disabled TraceMe
+        # records nothing, and building one is a third of an entry.
+        if annotation.is_enabled():
+            self._span = annotation(SPAN_PREFIX + self._name, **self._args)
+            self._span.__enter__()
+        else:
+            self._span = None
         # Re-resolved per entry, NOT cached at construction: a
         # ``Dashboard.reset()`` (tests do one between cases) replaces
         # the registry, and a long-lived ``monitor(...)`` instance
@@ -379,7 +414,8 @@ class monitor:
 
     def __exit__(self, *exc) -> None:
         self._monitor.add((time.perf_counter() - self._begin) * 1e3)
-        self._span.__exit__(*exc)
+        if self._span is not None:
+            self._span.__exit__(*exc)
         return None
 
 

@@ -8,6 +8,7 @@ TPU-native equivalent of the reference's ``Waiter``
 from __future__ import annotations
 
 import itertools
+import time
 
 from .lock_witness import monotonic, named_condition, named_lock
 
@@ -20,23 +21,35 @@ class Waiter:
         self._mutex = named_lock(name)
         self._cond = named_condition(f"{name}.cond", self._mutex)
         self._num_wait = num_wait
+        self._completed_at = None  # perf_counter() of the last notify
+        #: Milliseconds from the completing ``notify()`` to the last
+        #: ``wait()`` that BLOCKED returning on its own thread; None
+        #: where that wait found the count at zero, or timed out.
+        self.woke_after_ms = None
 
     def wait(self, timeout=None) -> bool:
         deadline = None if timeout is None else monotonic() + timeout
+        self.woke_after_ms = None
+        blocked = False
         with self._cond:
             while self._num_wait > 0:
+                blocked = True
                 remaining = None if deadline is None \
                     else deadline - monotonic()
                 if remaining is not None and remaining <= 0:
                     return False
                 if not self._cond.wait(timeout=remaining):
                     return False
-            return True
+            completed_at = self._completed_at
+        if blocked and completed_at is not None:
+            self.woke_after_ms = (time.perf_counter() - completed_at) * 1e3
+        return True
 
     def notify(self) -> None:
         with self._cond:
             self._num_wait -= 1
             if self._num_wait <= 0:
+                self._completed_at = time.perf_counter()
                 self._cond.notify_all()
 
     def add_waits(self, k: int) -> None:

@@ -2,7 +2,12 @@
 device trace"): every Dashboard monitor is an ``mv:<NAME>`` span in a
 profiler capture, and the monitors that say where a request's time goes
 (TABLE_WAIT, MAILBOX_WAIT[*], WORKER_REPLY_GET, BLOB_D2H(+_BYTES),
-CLIENT_PLACE_ROWS, TRAINER_EPOCH_PREP) move by what one request does."""
+CLIENT_PLACE_ROWS, TRAINER_EPOCH_PREP) move by what one request does.
+Since PR 37 the caller's thread has its own (CLIENT_ISSUE_GET/ADD,
+TABLE_WAKE, the trainers' TRAINER_BLOCK_*/TRAINER_GROUP_DISPATCH), the
+ack's way back has WORKER_REPLY_ADD, and the two wide spans are cut
+inside (BLOB_D2H_READY/COPY; UPDATE_PAD_ROWS/UPDATE_DISPATCH and
+TABLE_GATHER_DISPATCH in the server's handlers)."""
 
 import functools
 import glob
@@ -85,6 +90,17 @@ def one_get_one_add():
     ("BLOB_D2H_BYTES", IDS * COLS * 4),   # the reply's bytes
     ("MAILBOX_WAIT[server]", 2),       # the Get and the Add
     ("MAILBOX_WAIT[worker]", 4),       # two requests, two replies
+    ("CLIENT_ISSUE_GET", 1),           # the caller's thread, a request
+    ("CLIENT_ISSUE_ADD", 1),
+    ("TABLE_WAKE", 2),                 # both waits blocked (_slow_pops)
+    ("WORKER_REPLY_ADD", 1),
+    ("BLOB_D2H_READY", 1),             # the two halves of BLOB_D2H
+    ("BLOB_D2H_COPY", 1),
+    ("TABLE_GATHER_DISPATCH", 1),      # inside SERVER_PROCESS_GET
+    ("UPDATE_PAD_ROWS", 1),            # 100 host rows padded to 128
+    ("UPDATE_DISPATCH", 1),            # inside SERVER_PROCESS_ADD
+    ("WORKER_PROCESS_GET", 1),         # what was there counts as it did
+    ("WORKER_PROCESS_ADD", 1),
 ])
 def test_one_get_and_one_add_move_each_monitor(one_get_one_add, name, count):
     assert one_get_one_add[name][0] == count
@@ -109,6 +125,80 @@ def test_the_pieces_of_a_get_nest(one_get_one_add):
     place = one_get_one_add["CLIENT_PLACE_ROWS"][1]
     reply = one_get_one_add["WORKER_REPLY_GET"][1]
     assert 0 < d2h + place <= reply <= one_get_one_add["TABLE_WAIT"][1]
+
+
+def test_the_halves_of_the_two_wide_spans_lie_inside_them(one_get_one_add):
+    moved = one_get_one_add
+    halves = moved["BLOB_D2H_READY"][1] + moved["BLOB_D2H_COPY"][1]
+    assert 0 < halves <= moved["BLOB_D2H"][1]
+    halves = moved["UPDATE_PAD_ROWS"][1] + moved["UPDATE_DISPATCH"][1]
+    assert 0 < halves <= moved["SERVER_PROCESS_ADD"][1]
+    assert 0 < moved["TABLE_GATHER_DISPATCH"][1] \
+        <= moved["SERVER_PROCESS_GET"][1]
+
+
+def test_the_wake_up_is_the_tail_of_the_wait(one_get_one_add):
+    """TABLE_WAKE starts at the worker actor's notify, inside the
+    caller's TABLE_WAIT, and ends after it: a hand-off, not the wait."""
+    wake, wait = one_get_one_add["TABLE_WAKE"][1], \
+        one_get_one_add["TABLE_WAIT"][1]
+    assert 0 < wake < wait and wait >= 2 * SLEEP_S * 1e3
+
+
+def test_a_wait_that_finds_its_request_complete_wakes_nobody():
+    from multiverso_tpu.util.waiter import Waiter
+    done = Waiter(1)
+    done.notify()
+    assert done.wait() and done.woke_after_ms is None
+    import threading
+    blocked = Waiter(1)
+    threading.Timer(SLEEP_S, blocked.notify).start()
+    t0 = time.perf_counter()
+    assert blocked.wait(timeout=30)
+    assert 0 <= blocked.woke_after_ms < (time.perf_counter() - t0) * 1e3
+    assert not Waiter(1).wait(timeout=0.01)     # timed out: no wake-up
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = np.arange(IDS, dtype=np.int32)
+        table.add_rows(ids, np.ones((IDS, COLS), np.float32))
+        before = _snapshot()
+        msg_id = table.add_rows_async(ids, np.ones((IDS, COLS), np.float32))
+        deadline = time.monotonic() + 30
+        while msg_id in table._waitings and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert table.wait(msg_id)
+        moved = _delta(before, _snapshot())
+    finally:
+        mv.shutdown()
+    assert moved["CLIENT_ISSUE_ADD"][0] == moved["WORKER_REPLY_ADD"][0] == 1
+    assert moved["TABLE_WAIT"][0] == moved["TABLE_WAKE"][0] == 0
+
+
+def test_device_keys_pad_nothing_on_the_host():
+    """A device-key Add has no host delta: UPDATE_PAD_ROWS stays, the
+    dispatch and the caller's issue count; a device-key Get's gather is
+    a TABLE_GATHER_DISPATCH and copies nothing off the device."""
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = jnp.arange(IDS, dtype=jnp.int32)
+        delta = jnp.ones((IDS, COLS), jnp.float32)
+        table.add_rows(ids, delta)
+        table.get_rows_device(ids).block_until_ready()
+        before = _snapshot()
+        table.add_rows(ids, delta)
+        rows = table.get_rows_device(ids)
+        np.testing.assert_array_equal(
+            np.asarray(rows), np.full((IDS, COLS), 2.0, np.float32))
+        moved = _delta(before, _snapshot())
+    finally:
+        mv.shutdown()
+    assert moved["UPDATE_PAD_ROWS"][0] == 0
+    for name in ("UPDATE_DISPATCH", "TABLE_GATHER_DISPATCH",
+                 "CLIENT_ISSUE_ADD", "CLIENT_ISSUE_GET", "WORKER_REPLY_ADD"):
+        assert moved[name][0] == 1, name
+    assert moved.get("BLOB_D2H", (0, 0.0))[0] == 0
 
 
 def test_every_message_of_a_fused_batch_counts():
@@ -198,6 +288,52 @@ def test_a_capture_around_one_get_holds_the_servers_span(tmp_path):
     (_, a, b, reply_stats), = by_name["mv:WORKER_REPLY_GET"]
     (_, c, d, _), = by_name["mv:BLOB_D2H"]
     assert a <= c <= d <= b and reply_stats["msg_id"] == msg_id
+    # the copy's two halves nest in it, the wait before the copy
+    (_, r0, r1, _), = by_name["mv:BLOB_D2H_READY"]
+    (_, c0, c1, _), = by_name["mv:BLOB_D2H_COPY"]
+    assert c <= r0 <= r1 <= c0 <= c1 <= d
+    (_, g0, g1, _), = by_name["mv:TABLE_GATHER_DISPATCH"]
+    assert start <= g0 <= g1 <= end
+    # the caller's own spans carry the request's identifiers too: one
+    # request is matched across its three threads
+    (_, i0, i1, issue_stats), = by_name["mv:CLIENT_ISSUE_GET"]
+    (_, w0, w1, wait_stats), = by_name["mv:TABLE_WAIT"]
+    assert lo <= i0 <= i1 <= w0 <= w1 <= hi     # one thread's, in order
+    assert issue_stats["table"] == table.table_id
+    assert wait_stats["msg_id"] == msg_id \
+        and wait_stats["table"] == table.table_id
+    # ... and the Monitor never sees them
+    assert set(vars(Dashboard.get("TABLE_WAIT"))) == set(
+        vars(dashboard.Monitor("x")))
+
+
+def test_a_capture_around_one_add_holds_the_handler_s_halves(tmp_path):
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = np.arange(IDS, dtype=np.int32)
+        delta = np.ones((IDS, COLS), np.float32)
+        table.add_rows(ids, delta)
+        with trace_to(str(tmp_path)):
+            table.add_rows(ids, delta)
+            msg_id = table._msg_id
+    finally:
+        mv.shutdown()
+    by_name = {}
+    for span in _host_spans(str(tmp_path)):
+        by_name.setdefault(span[0], []).append(span)
+    (_, lo, hi, stats), = by_name["mv:SERVER_PROCESS_ADD"]
+    (_, p0, p1, _), = by_name["mv:UPDATE_PAD_ROWS"]
+    (_, d0, d1, _), = by_name["mv:UPDATE_DISPATCH"]
+    assert lo <= p0 <= p1 <= d0 <= d1 <= hi and stats["msg_id"] == msg_id
+    (_, i0, i1, issue_stats), = by_name["mv:CLIENT_ISSUE_ADD"]
+    # issued before the server has it, acknowledged after (the ack
+    # leaves inside the server's span, so only the starts are ordered)
+    assert i0 <= i1 and i0 <= lo and issue_stats["table"] == table.table_id
+    (_, a0, a1, ack_stats), = by_name["mv:WORKER_REPLY_ADD"]
+    assert lo <= a0 <= a1 and ack_stats["msg_id"] == msg_id \
+        and ack_stats["table"] == table.table_id
+    assert "mv:TABLE_WAKE" not in by_name     # a Monitor.add: no span
 
 
 def test_monitor_has_no_trace_parameter():
@@ -292,6 +428,52 @@ def test_epoch_prep_counts_one_an_epoch(use_ps, tmp_path):
     finally:
         if use_ps:
             mv.shutdown()
+
+
+@pytest.mark.parametrize("use_ps", [False, True], ids=["local", "ps"])
+def test_the_trainers_dispatches_count_one_a_block_or_a_group(
+        use_ps, tmp_path):
+    from multiverso_tpu.models.wordembedding import (
+        DeviceCorpusTrainer, PSDeviceCorpusTrainer, PSWord2Vec, Word2Vec,
+        Word2VecConfig)
+    d, tok = _corpus(tmp_path)
+    config = Word2VecConfig(embedding_size=8, window=2, epochs=1,
+                            init_learning_rate=0.01, batch_size=512,
+                            sample=0, use_ps=use_ps)
+    if use_ps:
+        mv.init([])
+    try:
+        if use_ps:
+            trainer = PSDeviceCorpusTrainer(PSWord2Vec(config, d), tok,
+                                            centers_per_step=64)
+        else:
+            trainer = DeviceCorpusTrainer(Word2Vec(config, d), tok,
+                                          centers_per_step=64)
+        ticks = []
+        before = _snapshot()
+        trainer.train_epoch(
+            seed=0, **{"block_hook" if use_ps else "group_hook":
+                       ticks.append})
+        moved = _delta(before, _snapshot())
+    finally:
+        if use_ps:
+            mv.shutdown()
+    assert len(ticks) > 1      # several blocks (ps) or groups (local)
+    own = {name: moved[name] for name in moved
+           if name.startswith("TRAINER_") and moved[name][0]}
+    if use_ps:
+        assert {n: c for n, (c, _) in own.items()} == {
+            "TRAINER_EPOCH_PREP": 1, "TRAINER_BLOCK_UPLOAD": len(ticks),
+            "TRAINER_BLOCK_IDS": len(ticks),
+            "TRAINER_BLOCK_STEP": len(ticks),
+            "TRAINER_BLOCK_LOSS": len(ticks)}
+        # a block's two Gets and two Adds are the client's to name
+        assert moved["CLIENT_ISSUE_GET"][0] == 2 * len(ticks)
+        assert moved["CLIENT_ISSUE_ADD"][0] == 2 * len(ticks)
+    else:
+        assert {n: c for n, (c, _) in own.items()} == {
+            "TRAINER_EPOCH_PREP": 1, "TRAINER_GROUP_DISPATCH": len(ticks)}
+    assert all(ms > 0 for _, ms in own.values())
 
 
 # -- named scopes -------------------------------------------------------------

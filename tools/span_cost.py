@@ -5,13 +5,16 @@ through both actors):
 
     python3 tools/span_cost.py
 
-A block enters 8 handler monitors with a request's id (WORKER_PROCESS_
-GET/ADD, SERVER_PROCESS_GET/ADD, two each), 2 WORKER_REPLY_GET, 2
-TABLE_WAIT, and stamps and closes 12 MAILBOX_WAIT (8 messages through
-the worker's mailbox, 4 through the server's). Run from a checkout of
-the commit before PR 24 it times what that tree has: the 8 handler
-monitors, without arguments. The difference of the two sums is what
-PR 24 added to a block, summed over the three threads.
+A block enters 18 monitors with a request's id or table (WORKER_PROCESS_
+GET/ADD and SERVER_PROCESS_GET/ADD two each, 2 WORKER_REPLY_GET, 2
+WORKER_REPLY_ADD, 2 TABLE_WAIT, 4 CLIENT_ISSUE_GET/ADD), 8 without
+(the trainer's TRAINER_BLOCK_UPLOAD/IDS/STEP/LOSS, 2 UPDATE_DISPATCH and 2
+TABLE_GATHER_DISPATCH inside the server's handlers), stamps and closes
+12 MAILBOX_WAIT (8 messages through the worker's mailbox, 4 through the
+server's) and adds 2 TABLE_WAKE (a stamp and a Monitor.add, as a
+mailbox's). Run from a checkout of an older commit it times the sites
+that tree has: before PR 37 10 with an id, 2 without, 12 MAILBOX_WAIT;
+before PR 24 the 8 handler monitors, without arguments.
 """
 
 import os
@@ -38,6 +41,11 @@ class _Zoo:
 def us(fn) -> float:
     fn()
     return min(timeit.repeat(fn, number=N, repeat=7)) / N * 1e6
+
+
+def _has_caller_spans() -> bool:
+    from multiverso_tpu.util.dashboard import METRIC_NAMES
+    return "CLIENT_ISSUE_GET" in METRIC_NAMES
 
 
 def plain():
@@ -68,9 +76,10 @@ def main() -> None:
 
         costs["monitor_with_request_id"] = us(with_args)
         costs["mailbox_wait"] = us(mailbox) - us(queue_only)
-        block = (2 * costs["monitor"]
-                 + 10 * costs["monitor_with_request_id"]
-                 + 12 * costs["mailbox_wait"])
+        issued = _has_caller_spans()
+        block = ((8 if issued else 2) * costs["monitor"]
+                 + (18 if issued else 10) * costs["monitor_with_request_id"]
+                 + (14 if issued else 12) * costs["mailbox_wait"])
     for name, cost in costs.items():
         print(f"{name}: {cost:.3f} us")
     print(f"sites of one block: {block:.2f} us")
