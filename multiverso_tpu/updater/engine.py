@@ -19,6 +19,12 @@ row read and written once by ``row_scatter.py``'s kernel, on a
 row-sharded table under ``shard_map`` with each device visiting its own
 rows; otherwise XLA's scatter applies the ids as they come. Every
 dispatch counts ``UPDATE_ROWS_FAST`` or ``UPDATE_ROWS_XLA``.
+
+A large HOST delta is padded into a staging buffer the engine keeps
+(``Staging``; docs/MEMORY.md "Send side of an Add"): a request copies
+its rows into the head of a bucket-shaped array that already exists and
+allocates nothing. Every host delta that enters ``pad_rows`` counts
+``UPDATE_PAD_STAGED`` or ``UPDATE_PAD_FRESH``.
 """
 
 from __future__ import annotations
@@ -52,6 +58,85 @@ def bucket_size(n: int, minimum: int = 8) -> int:
     return size
 
 
+#: Staging buffers an engine keeps per bucket shape. A request whose
+#: first is still being read by the runtime takes the second; where
+#: neither is free it pads into a fresh array and waits for nothing.
+STAGING_BUFFERS = 2
+
+#: Padded deltas under glibc's default mmap threshold keep ``np.pad``:
+#: the heap recycles an array that small, and from this size a fresh
+#: array is a fresh mapping, its page faults and its munmap.
+STAGING_MIN_BYTES = 128 * 1024
+
+
+def _zeros_no_client_adopts(shape, dtype) -> np.ndarray:
+    """A zeroed array that starts 16 bytes past a 64-byte boundary
+    (where glibc's large blocks start anyway). XLA's CPU client takes a
+    64-byte-aligned host array as the device buffer itself, zero copy,
+    and such an upload reads ready while the program has yet to read the
+    memory; an array that no client can adopt is copied by every client,
+    and the copy's readiness says the host memory has been read."""
+    nbytes = int(np.prod(shape)) * dtype.itemsize
+    raw = np.zeros(nbytes + 64, np.uint8)
+    skip = (16 - raw.ctypes.data) % 64
+    return raw[skip:skip + nbytes].view(dtype).reshape(shape)
+
+
+class StagingBuffer:
+    """One bucket-shaped host array and what guards it. ``array`` holds
+    the last request's ``filled`` rows, then zeros. ``guard`` is the
+    device array the last fill was uploaded as: it is no program's
+    donated argument, and once it is ready the runtime has read
+    ``array``, which may then be filled again."""
+
+    __slots__ = ("array", "filled", "guard")
+
+    def __init__(self, shape, dtype):
+        self.array = _zeros_no_client_adopts(shape, dtype)
+        self.filled = 0
+        self.guard = None
+
+    def free(self) -> bool:
+        if self.guard is not None and self.guard.is_ready():
+            self.guard = None       # and the device's copy with it
+        return self.guard is None
+
+    def fill(self, delta: np.ndarray) -> None:
+        k = delta.shape[0]
+        np.copyto(self.array[:k], delta)
+        if k < self.filled:         # the tail stays zero
+            self.array[k:self.filled] = 0
+        self.filled = k
+
+    def upload(self):
+        self.guard = jax.device_put(self.array)
+        return self.guard
+
+
+class Staging:
+    """The staging buffers of one engine: at most ``STAGING_BUFFERS`` per
+    padded shape it has seen, each allocated when first needed and kept
+    (host memory, not HBM)."""
+
+    def __init__(self):
+        self._buffers = {}
+
+    def take(self, shape, dtype) -> Optional[StagingBuffer]:
+        """A buffer of ``shape`` that nothing reads any more; None for a
+        small one or where every buffer is still being read. Never
+        waits."""
+        if int(np.prod(shape)) * dtype.itemsize < STAGING_MIN_BYTES:
+            return None
+        buffers = self._buffers.setdefault((shape, dtype), [])
+        for buffer in buffers:
+            if buffer.free():
+                return buffer
+        if len(buffers) < STAGING_BUFFERS:
+            buffers.append(StagingBuffer(shape, dtype))
+            return buffers[-1]
+        return None
+
+
 class UpdateEngine:
     """Applies a rule to a table's device array with donated buffers."""
 
@@ -77,6 +162,11 @@ class UpdateEngine:
         # where the mesh has several devices, XLA's scatter otherwise.
         self._mesh = getattr(sharding, "mesh", None)
         self.rule.mesh = self._mesh
+        # Host deltas are staged for a table on one device. Over several
+        # the compiled program places its host argument, each device its
+        # part; an array uploaded ahead of the call would sit on one.
+        self._staging = Staging() \
+            if self._mesh is None or self._mesh.size == 1 else None
 
         # Table storage is padded to the mesh shard count (uneven shardings
         # are not device_put-able) and possibly to the 128-lane tile width
@@ -151,6 +241,7 @@ class UpdateEngine:
         separate masking op per request."""
         hyp, worker_id = _unpack(option)
         from ..core.blob import is_device_array
+        staged = None
         if is_device_array(row_ids):
             # Device-key ids may carry duplicates that no caller can
             # take out without a host sync. default/sgd scatter-add
@@ -162,11 +253,16 @@ class UpdateEngine:
             CHECK(self.rule.sums_duplicates, DEVICE_KEYS_REFUSED
                   % self.rule.name)
         else:
-            row_ids, delta = pad_rows(row_ids, delta, self.shape[0])
+            row_ids, delta, staged = pad_rows(
+                row_ids, delta, self.shape[0], self._staging)
         self._count_path(row_ids)
         rows_fn = self._rows if bounds is None \
             else self._bounded_rows_fn(bounds)
         with monitor("UPDATE_DISPATCH"):  # a host delta's upload too
+            if staged is not None:
+                # Uploaded here and not by the call, for the guard: the
+                # program's own outputs are donated by the next dispatch.
+                delta = staged.upload()
             data, self._state = rows_fn(data, self._state, row_ids, delta,
                                         hyp, worker_id)
         return data
@@ -270,36 +366,48 @@ def pad_ids(row_ids, num_rows: int) -> np.ndarray:
     return row_ids
 
 
-def pad_rows(row_ids, delta, num_rows: int):
+def pad_rows(row_ids, delta, num_rows: int,
+             staging: Optional[Staging] = None):
     """Pad (row_ids, delta) to the next bucket size; padding rows index
     out-of-range so scatter drops them and gather fills zeros. DEVICE
     deltas pass through logical-sized — the engine's rows jit extends
     them to the id count internally (a separate device pad would cost a
     full program launch per add).
 
-    A HOST delta always leaves here as an array the table owns: the
-    padded one, or at a bucket-sized k a copy. The jitted program that
-    takes it returns before the runtime has read the host buffer (it
-    reads the numpy argument's memory after the call, docs/MEMORY.md
-    "Send side of an Add"), and the buffer may be the caller's own
-    delta, which the caller may overwrite once the Add is
-    acknowledged."""
+    A HOST delta always leaves here as an array the table owns, k rows
+    of it and then zero rows, at a bucket-sized k too. The jitted
+    program that takes it returns before the runtime has read the host
+    buffer (it reads the numpy argument's memory after the call,
+    docs/MEMORY.md "Send side of an Add"), and the buffer may be the
+    caller's own delta, which the caller may overwrite once the Add is
+    acknowledged. With ``staging`` a large delta is copied into a buffer
+    the engine keeps, returned third for the caller to ``upload()`` (the
+    upload guards the buffer's next fill); a small one, or one that
+    finds every buffer still being read, gets a fresh array as without
+    ``staging``, and None third."""
     row_ids = np.asarray(row_ids, dtype=np.int32)
     k = row_ids.shape[0]
     b = bucket_size(k)
     if b != k:
         row_ids = np.concatenate(
             [row_ids, np.full(b - k, num_rows, dtype=np.int32)])
+    staged = None
     from ..core.blob import is_device_array
     if not is_device_array(delta):
+        delta = np.asarray(delta)
         with monitor("UPDATE_PAD_ROWS"):
-            if b != k:
-                pad = ((0, b - k),) \
-                    + ((0, 0),) * (len(np.shape(delta)) - 1)
-                delta = np.pad(np.asarray(delta), pad)
+            if staging is not None:
+                staged = staging.take((b,) + delta.shape[1:], delta.dtype)
+            if staged is not None:
+                staged.fill(delta)
+                delta = staged.array
+            elif b != k:
+                pad = ((0, b - k),) + ((0, 0),) * (delta.ndim - 1)
+                delta = np.pad(delta, pad)
             else:
                 delta = np.array(delta)
-    return row_ids, delta
+        count("UPDATE_PAD_FRESH" if staged is None else "UPDATE_PAD_STAGED")
+    return row_ids, delta, staged
 
 
 @functools.lru_cache(maxsize=None)

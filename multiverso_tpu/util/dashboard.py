@@ -187,8 +187,16 @@ METRIC_NAMES: Dict[str, str] = {
                        "under rules.FAST_MIN_IDS ids)",
     # -- inside the server's handlers (updater/engine.py,
     # tables/matrix_table.py process_get) --
-    "UPDATE_PAD_ROWS": "pad_rows on a HOST delta: np.pad to the bucket, "
-                       "or the copy at a bucket-sized k",
+    "UPDATE_PAD_ROWS": "pad_rows on a HOST delta: its rows copied into "
+                       "the head of a staging buffer the engine keeps, "
+                       "or np.pad / the copy at a bucket-sized k into a "
+                       "new array",
+    "UPDATE_PAD_STAGED": "host deltas pad_rows copied into a staging "
+                         "buffer the engine keeps (nothing allocated)",
+    "UPDATE_PAD_FRESH": "host deltas pad_rows gave a new array: padded "
+                        "size under 128 KiB, every buffer of the bucket "
+                        "still being read by the runtime, or a table "
+                        "over several devices",
     "UPDATE_DISPATCH": "the update's jitted call, dense or rows (a "
                        "host delta's upload is inside it)",
     "TABLE_GATHER_DISPATCH": "a row Get's pad_ids and the gather's "

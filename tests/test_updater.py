@@ -124,8 +124,9 @@ class TestRowRules:
         assert data[0].sum() == 0  # untouched rows
 
     def test_padding_rows_are_dropped(self):
-        rows, delta = pad_rows(np.array([1], np.int32),
-                               np.ones((1, 2), np.float32), num_rows=4)
+        rows, delta, staged = pad_rows(
+            np.array([1], np.int32), np.ones((1, 2), np.float32), num_rows=4)
+        assert staged is None and delta.shape == (bucket_size(1), 2)
         assert len(rows) == bucket_size(1)
         assert (rows[1:] == 4).all()  # out-of-range sentinel
         eng = make_engine("default", (4, 2))
