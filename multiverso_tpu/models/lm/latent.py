@@ -1,0 +1,143 @@
+"""Multi-head latent attention (DeepSeek-V2/V3's, as ``model_type:
+xing4_0`` configures it), one chip's heads of it, as the sublayer ``F`` of
+the third family's layer (streams.py): for a sublayer's normed input
+``h`` [T, hidden]
+
+    c_q          = RMSNorm(h W_qa; g_qa)                 [q_lora_rank]
+    [q_n | q_r]  = c_q W_qb, a head                      [nope | rope]
+    [c_kv | k_r] = h W_kva                               [kv_lora_rank | rope]
+    [k_n | v]    = RMSNorm(c_kv; g_kva) W_kvb, a head    [nope | v]
+    q_r, k_r     = rotary(q_r), rotary(k_r)   YaRN's frequencies; ``k_r`` is
+                                              one for all heads
+    score        = (q_n . k_n + q_r . k_r) (nope + rope)^-0.5 m^2, causal,
+                   m = 0.1 mscale_all_dim ln(factor) + 1
+    F(h)         = softmax(score) v, the heads side by side, W_o
+
+**The share.** ``cfg.heads_held = (first, count)`` of the ``n_heads``:
+``W_qb``, ``W_kvb`` and ``W_o`` hold the held heads' columns and rows,
+``W_qa``, ``W_kva`` and the two latent norms are whole on every chip. The
+layer adds its own heads' part of ``W_o``'s sum; the shares add up to the
+uncut attention (tests/test_lm_mla.py, eight of eight).
+
+**The kernel.** A head's q and k are ``nope + rope`` = 192 wide and its v
+128: the library's splash kernel takes them as they are (it compiles for a
+v5e at 192 beside 128, tests/test_row_scatter_tpu_compile.py), each head a
+group of its own since its keys are its own; elsewhere
+``blockwise_attention``. No lane is padded.
+
+Scopes: ``mv.lm.attn.mla`` (projections, latent norms, rotary, ``W_o``),
+the attention proper under ``mv.lm.attn.mla.kernel``; the backward pass
+under the same names (``attention_vjp`` differentiates the three parts
+one by one, as ``model.layer_grads`` does).
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import model as lm
+from .model import LMConfig
+
+SCOPE = "mv.lm.attn.mla"
+MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+NORMS = ("norm_attn", "norm_q_a", "norm_kv_a")
+
+
+def yarn_frequencies(cfg: LMConfig) -> np.ndarray:
+    """The rotary pairs' frequencies under YaRN [rope / 2]: ``theta``'s own
+    where a pair turns more than ``beta_fast`` times over the original
+    context, divided by ``factor`` where fewer than ``beta_slow``, a
+    linear ramp between."""
+    factor, fast, slow, original = cfg.yarn[:4]
+    d = cfg.qk_rope_dim
+    own = 1.0 / cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def pair_of(turns):     # the pair that makes ``turns`` over the context
+        return d * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(pair_of(fast)), 0)
+    high = min(math.ceil(pair_of(slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return own / factor * ramp + own * (1 - ramp)
+
+
+def softmax_scale(cfg: LMConfig) -> float:
+    """``head_dim^-0.5 m^2``; the rotary's own ``mscale / mscale_all_dim``
+    is 1 when the two are equal, which ``inputs`` checks."""
+    factor, all_dim = cfg.yarn[0], cfg.yarn[5]
+    m = 0.1 * all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return m * m / math.sqrt(cfg.head_dim)
+
+
+def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None):
+    """The sublayer's norm, both low-rank projections with their norms,
+    rotary positions, the scale: ``(q [heads, 1, T, nope + rope], k
+    [heads, T, nope + rope], v [heads, T, v])`` bfloat16 for
+    ``model.attention_core``, each head a group. ``norms`` is
+    ``(norm_attn, norm_q_a, norm_kv_a)``."""
+    assert cfg.yarn[4] == cfg.yarn[5], "mscale != mscale_all_dim"
+    t, heads = u.shape[0], cfg.n_heads_held
+    nope, rope, latent = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
+    g_attn, g_q, g_kv = norms
+    h = lm.rmsnorm(u, g_attn, cfg.eps)
+    c_q = lm.rmsnorm(lm.mm(h, mats["wq_a"], sinks["wq_a"]), g_q, cfg.eps)
+    q = lm.mm(c_q, mats["wq_b"], sinks["wq_b"]).reshape(t, heads, nope + rope)
+    kv_a = lm.mm(h, mats["wkv_a"], sinks["wkv_a"])
+    c_kv = lm.rmsnorm(kv_a[:, :latent], g_kv, cfg.eps)
+    kv = lm.mm(c_kv, mats["wkv_b"], sinks["wkv_b"]).reshape(
+        t, heads, nope + cfg.v_head_dim)
+    inv = yarn_frequencies(cfg)
+    q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
+    k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
+    q = jnp.concatenate([q[..., :nope], q_r], -1) * softmax_scale(cfg)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r, (t, heads, rope))], -1)
+    return (q.astype(lm.BF16).transpose(1, 0, 2)[:, None],
+            k.astype(lm.BF16).transpose(1, 0, 2),
+            kv[..., nope:].astype(lm.BF16).transpose(1, 0, 2))
+
+
+def core(q, k, v):
+    """The causal attention proper."""
+    return lm.attention_core(q, k, v, 0)
+
+
+def output(cfg: LMConfig, mats, sinks, o):
+    """The held heads' outputs [heads, 1, T, v] through their rows of the
+    output projection: [T, hidden]."""
+    t = o.shape[2]
+    o = o[:, 0].transpose(1, 0, 2).reshape(t, -1)
+    return lm.mm(o, mats["wo"], sinks["wo"])
+
+
+def attention_vjp(cfg: LMConfig, mats, sinks, small, u, pos=None):
+    """``F(u)`` and what pulls a cotangent back through it: ``(v, pull)``,
+    ``pull(dv) -> (du, matrix gradients, small gradients)``. The three
+    parts are differentiated one by one so that each part's backward pass
+    runs under the scope of its forward pass."""
+    first = {n: sinks[n] for n in MATRICES[:-1]}
+    with jax.named_scope(SCOPE):
+        (q, k, v), pull_inputs = jax.vjp(
+            lambda s, norms, u: inputs(cfg, mats, s, norms, u, pos),
+            first, tuple(small[n] for n in NORMS), u)
+    with jax.named_scope(SCOPE + ".kernel"):
+        o, pull_core = jax.vjp(core, q, k, v)
+    with jax.named_scope(SCOPE):
+        out, pull_output = jax.vjp(
+            lambda s, o: output(cfg, mats, {"wo": s}, o), sinks["wo"], o)
+
+    def pull(d_out):
+        with jax.named_scope(SCOPE):
+            d_wo, do = pull_output(d_out)
+        with jax.named_scope(SCOPE + ".kernel"):
+            d_qkv = pull_core(do)
+        with jax.named_scope(SCOPE):
+            d_mats, d_norms, du = pull_inputs(d_qkv)
+        return du, {**d_mats, "wo": d_wo}, dict(zip(NORMS, d_norms))
+
+    return out, pull

@@ -65,7 +65,7 @@ def run(argv=None) -> PSLMTrainer:
     for step in range(get_flag("lm_steps")):
         tokens = zipf_tokens(
             jax.random.fold_in(key, step),
-            (batch, seq_len + (not trainer.diffusion)),
+            (batch, seq_len + (not trainer.diffusion) + cfg.mtp_layers),
             cfg.vocab - trainer.diffusion)
         loss = float(trainer.step(tokens))
         log.info("step %d: loss %.4f, %.0f tokens/s", step, loss,

@@ -25,6 +25,33 @@ layers with a sliding window) and Qwen3-MoE's as SDAR uses it (JetLM,
 2025, ``model_type: sdar_moe``: silu, the router on the normed
 post-attention stream, q and k norms, every layer rotary).
 
+**A third family** (``from_dict`` tells it by ``kv_lora_rank``:
+DeepSeek-V3's block as ``model_type: xing4_0`` has it, XingChen-AGI 2026)
+is described by kinds that the two above leave at their defaults, and its
+mechanisms live in modules of their own:
+
+    X [n C, T]: ``hc_mult`` residual streams     ``residual: "mhc"``,
+      a column a token; a sublayer F reads             streams.py: r = RMSNorm(X);
+      u = sum_j H_pre_j X_j, writes               [p, q, R] = r phi^T;
+      X'_i = sum_j H_res_ij X_j + H_post_i v,     H_pre = sigmoid(a p + b),
+      v = F(RMSNorm(u)); the streams are          H_post = 2 sigmoid(..),
+      summed before the final norm                H_res = Sinkhorn(exp(clamp))
+    F = latent attention, every layer           ``attention: "mla"``, latent.py:
+      c_q = RMSNorm(h W_qa), [q_n|q_r] = c_q W_qb, [c_kv|k_r] = h W_kva,
+      [k_n|v] = RMSNorm(c_kv) W_kvb; q_r, k_r rotated (YaRN), k_r one for
+      all heads; score (q_n.k_n + q_r.k_r) 192^-0.5 m^2; ``heads_held``
+    F = W_d (silu(h W_g) * h W_u)               ``ffn_layout`` 0: dense,
+                                                  ``dense_width``
+    F = sum_{e in S, e held} w_e E_e(h) + E_shared(h)   ``ffn_layout`` 1;
+      s = sigmoid(h W_r); S the top-k of s + bias; w_e = routed_scale s_e /
+      sum_S s (``scoring: "sigmoid_bias"``, ``route``); the bias gets no
+      gradient: the trainer pushes ``bias_rate sign(mean load - load)``
+    a multi-token module after the last layer   ``mtp_layers``, mtp.py
+
+``route``, ``routed_experts`` (what ``experts_block`` is around),
+``gated_mlp`` (dense MLP and shared expert) and the head are one code path
+for all three.
+
 **Two objectives** (``LMConfig.objective``). ``next_token``: causal or
 window masks, the loss the mean cross entropy of the next token.
 ``block_diffusion``: a sequence of ``L`` clean tokens is cut into blocks
@@ -75,7 +102,10 @@ names, layer_grads): ``mv.lm.router``, ``mv.lm.attn.full`` /
 ``mv.lm.attn.window`` / ``mv.lm.attn.blockdiff`` (norms, projections,
 rotary, output projection) with the attention proper under
 ``<scope>.kernel``, ``mv.lm.experts``, ``mv.lm.head``; ``mv.lm.noise``
-is the trainer's (``noise`` runs in its batch-preparation program).
+is the trainer's (``noise`` runs in its batch-preparation program). The
+third family's: ``mv.lm.attn.mla`` (+ ``.kernel``), ``mv.lm.hc``,
+``mv.lm.shared_expert``, ``mv.lm.dense_mlp``, ``mv.lm.mtp`` (+ ``.head``),
+and its backward programs' sums over the sequences ``mv.lm.grad_sum``.
 """
 
 from __future__ import annotations
@@ -126,6 +156,32 @@ class LMConfig:
     objective: str = "next_token"   # | "block_diffusion"
     block_length: int = 0           # block diffusion: positions a block
     t_min: float = 0.0              # block diffusion: t ~ U(t_min, 1]
+    # -- the third family's kinds (DeepSeek-V3's block as Xing4.0 has it) --
+    attention: str = "gqa"          # | "mla": latent attention (latent.py)
+    heads_held: Tuple[int, int] = (0, 0)    # mla: (first, count) of the
+    #                                 n_heads held here; (0, 0): all
+    q_lora_rank: int = 0            # mla: the five latent sizes
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Tuple[float, ...] = ()    # (factor, beta_fast, beta_slow, original
+    #                                 positions, mscale, mscale_all_dim)
+    ffn_layout: Tuple[int, ...] = ()    # per layer: 1 sparse, 0 dense MLP;
+    #                                 (): every layer sparse
+    dense_width: int = 0
+    shared_width: int = 0           # the shared expert's, 0: none
+    scoring: str = "softmax"        # | "sigmoid_bias": chosen by score +
+    #                                 bias, weighed by score alone
+    routed_scale: float = 1.0
+    bias_rate: float = 0.0          # the bias's step after each step
+    residual: str = "plain"         # | "mhc": hc_mult streams (streams.py)
+    hc_mult: int = 1
+    hc_iters: int = 0               # Sinkhorn rounds
+    hc_eps: float = 0.0
+    hc_clamp: Tuple[float, float] = (0.0, 0.0)
+    mtp_layers: int = 0             # multi-token modules held here (mtp.py)
+    mtp_weight: float = 0.0         # the second loss's weight
 
     @property
     def n_layers(self) -> int:
@@ -133,8 +189,32 @@ class LMConfig:
 
     @property
     def small_names(self) -> Tuple[str, ...]:
-        """A layer's float32 tensors, by name."""
+        """A layer's float32 tensors, by name (the first two families)."""
         return LAYER_SMALL + (QK_NORMS if self.qk_norm else ())
+
+    @property
+    def n_heads_held(self) -> int:
+        return self.heads_held[1] or self.n_heads
+
+    def sparse(self, layer: int) -> int:
+        return self.ffn_layout[layer] if self.ffn_layout else 1
+
+    def layer_kinds(self) -> Tuple[tuple, ...]:
+        """Per layer what its two programs are built from: ``(rotary,
+        window)``, and with an ``ffn_layout`` ``(rotary, window, sparse)``."""
+        kinds = tuple(zip(self.rope_layout, self.window_layout))
+        if not self.ffn_layout:
+            return kinds
+        return tuple(k + (s,) for k, s in zip(kinds, self.ffn_layout))
+
+    def matrices(self, layer: int = 0) -> Tuple[str, ...]:
+        """The layer's tensors pulled as bfloat16 copies, by name."""
+        if self.attention != "mla":
+            return LAYER_MATRICES
+        ffn = ("w_gate", "w_up", "w_down")
+        if self.sparse(layer) and self.shared_width:
+            ffn += ("ws_gate", "ws_up", "ws_down")
+        return ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo") + ffn
 
     @property
     def mask_id(self) -> int:
@@ -155,6 +235,8 @@ class LMConfig:
         ``num_experts``, Qwen3-MoE's (``_from_qwen3_moe``). In both the
         key that counts the experts is the number HELD and
         ``router_outputs`` the published number the router still has."""
+        if "kv_lora_rank" in c:
+            return cls._from_mla(c)
         if "num_experts" in c:
             return cls._from_qwen3_moe(c)
         n = int(c["num_hidden_layers"])
@@ -213,10 +295,73 @@ class LMConfig:
             block_length=int(objective.get("block_length", 0)),
             t_min=float(objective.get("t_min", 0.0)))
 
-    def layer_shapes(self) -> dict:
+    @classmethod
+    def _from_mla(cls, c: dict) -> "LMConfig":
+        """DeepSeek-V3's block as ``model_type: xing4_0`` configures it
+        (benchmark/configs/xing4-29b-a4b-l5.json): latent attention under
+        YaRN, ``first_k_dense_replace`` dense layers and then sparse ones
+        with a shared expert, a sigmoid router chosen through a bias,
+        ``hc_mult`` residual streams, a multi-token module. The keys that
+        count experts and heads give the numbers HELD; ``router_outputs``
+        and ``attention_heads`` the published ones. ``router_bias_rate`` and
+        ``mtp_loss_weight`` the published config does not state."""
+        n, dense = int(c["num_hidden_layers"]), int(c["first_k_dense_replace"])
+        CHECK(c["scoring_func"] == "sigmoid" and c["topk_method"] == "noaux_tc"
+              and int(c["n_group"]) == 1 and c["norm_topk_prob"]
+              and int(c.get("moe_layer_freq", 1)) == 1
+              and c["rope_scaling"]["type"] == "yarn",
+              "only the block whose router scores by sigmoid, chooses "
+              "through a bias in one group and normalises its top-k, under "
+              "YaRN, is written down here")
+        y = c["rope_scaling"]
+        held = int(c["num_attention_heads"])
+        return cls(
+            hidden=int(c["hidden_size"]),
+            n_heads=int(c.get("attention_heads", held)), n_kv_heads=0,
+            head_dim=int(c["qk_nope_head_dim"]) + int(c["qk_rope_head_dim"]),
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_tok"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["n_routed_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(1,) * n, window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]),
+            eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c["hidden_act"]), router_input="ffn_norm",
+            attention="mla",
+            heads_held=(int(c.get("first_head_held", 0)), held),
+            q_lora_rank=int(c["q_lora_rank"]),
+            kv_lora_rank=int(c["kv_lora_rank"]),
+            qk_nope_dim=int(c["qk_nope_head_dim"]),
+            qk_rope_dim=int(c["qk_rope_head_dim"]),
+            v_head_dim=int(c["v_head_dim"]),
+            yarn=(float(y["factor"]), float(y["beta_fast"]),
+                  float(y["beta_slow"]),
+                  float(y["original_max_position_embeddings"]),
+                  float(y["mscale"]), float(y["mscale_all_dim"])),
+            ffn_layout=(0,) * dense + (1,) * (n - dense),
+            dense_width=int(c["intermediate_size"]),
+            shared_width=int(c["n_shared_experts"])
+            * int(c["moe_intermediate_size"]),
+            scoring="sigmoid_bias",
+            routed_scale=float(c["routed_scaling_factor"]),
+            bias_rate=float(c["router_bias_rate"]),
+            residual="mhc", hc_mult=int(c["hc_mult"]),
+            hc_iters=int(c["hc_sinkhorn_iters"]), hc_eps=float(c["hc_eps"]),
+            hc_clamp=(float(c["mhc_h_res_clamp_min"]),
+                      float(c["mhc_h_res_clamp_max"])),
+            mtp_layers=int(c["num_nextn_predict_layers"]),
+            mtp_weight=float(c["mtp_loss_weight"]))
+
+    def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it: a matrix
         table's (rows, columns) or a norm's (size,). The experts' three
-        are stacked by expert along the rows."""
+        are stacked by expert along the rows. The first two families'
+        layers are all alike; the third's are ``_mla_layer_shapes``."""
+        if self.attention == "mla":
+            return self._mla_layer_shapes(self.sparse(layer))
         h, e, w = self.hidden, self.experts_held[1], self.expert_width
         shapes = {
             "wq": (h, self.n_heads * self.head_dim),
@@ -230,10 +375,56 @@ class LMConfig:
             shapes.update({n: (self.head_dim,) for n in QK_NORMS})
         return shapes
 
+    def _mla_layer_shapes(self, sparse: int) -> dict:
+        """The third family's layer: latent attention over the held heads
+        (``wq_b``, ``wkv_b``, ``wo`` cut by head; each head's columns lie
+        together, ``[nope | rope]`` and ``[k nope | v]``), the two
+        sublayers' stream mixers (``phi`` stored a coefficient a row,
+        ``[2n + n^2, n hidden]``; ``a`` the three scalars), and a dense MLP
+        or router, bias, held experts and shared expert."""
+        h, heads, n = self.hidden, self.n_heads_held, self.hc_mult
+        coefficients = 2 * n + n * n
+        shapes = {
+            "wq_a": (h, self.q_lora_rank), "norm_q_a": (self.q_lora_rank,),
+            "wq_b": (self.q_lora_rank, heads * self.head_dim),
+            "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
+            "norm_kv_a": (self.kv_lora_rank,),
+            "wkv_b": (self.kv_lora_rank,
+                      heads * (self.qk_nope_dim + self.v_head_dim)),
+            "wo": (heads * self.v_head_dim, h),
+            "norm_attn": (h,), "norm_ffn": (h,)}
+        for sub in ("hc_attn", "hc_ffn"):
+            shapes.update({f"{sub}_phi": (coefficients, n * h),
+                           f"{sub}_b": (coefficients,), f"{sub}_a": (3,)})
+        if not sparse:
+            w = self.dense_width
+            shapes.update({"w_gate": (h, w), "w_up": (h, w),
+                           "w_down": (w, h)})
+            return shapes
+        e, w, s = self.experts_held[1], self.expert_width, self.shared_width
+        shapes.update({
+            "router": (h, self.n_experts), "router_bias": (self.n_experts,),
+            "w_gate": (e * h, w), "w_up": (e * h, w), "w_down": (e * w, h)})
+        if s:
+            shapes.update({"ws_gate": (h, s), "ws_up": (h, s),
+                           "ws_down": (s, h)})
+        return shapes
+
+    def mtp_shapes(self) -> dict:
+        """The multi-token module's own tensors beside its sparse layer's:
+        the projection of ``[normed stream ; normed next embedding]``, the
+        two norms before it and the norm before the (shared) head."""
+        h = self.hidden
+        return {"proj": (2 * h, h), "norm_h": (h,), "norm_e": (h,),
+                "final_norm": (h,)}
+
     def parameters(self) -> int:
-        per_layer = sum(int(np.prod(s)) for s in self.layer_shapes().values())
-        return (self.n_layers * per_layer + 2 * self.vocab * self.hidden
-                + self.hidden)
+        def size(shapes):
+            return sum(int(np.prod(s)) for s in shapes.values())
+        layers = sum(size(self.layer_shapes(i)) for i in range(self.n_layers))
+        module = self.mtp_layers * (size(self.mtp_shapes()) + size(
+            self._mla_layer_shapes(1))) if self.mtp_layers else 0
+        return layers + module + 2 * self.vocab * self.hidden + self.hidden
 
 
 # -- products -------------------------------------------------------------
@@ -456,12 +647,14 @@ class Mask:
         return np.arange(t)
 
 
-def _rotary(x, theta, pos=None):
+def _rotary(x, theta, pos=None, inv=None):
     """Rotary positions on [T, heads, d] (the halves paired, as the
     published model's ``rotate_half``), float32. ``pos`` [T] gives each
-    row's position (``arange(T)`` when None)."""
+    row's position (``arange(T)`` when None); ``inv`` [d / 2] the pairs'
+    frequencies where they are not ``theta``'s own (YaRN's)."""
     t, _, d = x.shape
-    inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    if inv is None:
+        inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
     pos = np.arange(t) if pos is None else np.asarray(pos)
     angle = pos.astype(np.float64)[:, None] * inv[None, :]
     cos = jnp.asarray(np.cos(angle), F32)[:, None, :]
@@ -620,18 +813,36 @@ def attention_block(cfg: LMConfig, rope: bool, mask, mats, sinks,
 
 # -- router and experts -----------------------------------------------------
 
-def route(cfg: LMConfig, router, x):
+def route(cfg: LMConfig, router, x, bias=None):
     """The top-k experts of each token and their weights, normalised
-    over the k: ``(ids [T, k] int32, weights [T, k] float32)``."""
+    over the k: ``(ids [T, k] int32, weights [T, k] float32)``. Under
+    ``cfg.scoring == "sigmoid_bias"`` a score is the sigmoid of its logit,
+    the k are the largest of score + ``bias`` (which gets no gradient)
+    and the weights ``routed_scale`` times the chosen SCORES over their
+    sum."""
     logits = jnp.dot(x.astype(F32), router, precision="highest")
-    p = jax.nn.softmax(logits, axis=-1)
-    ids = jax.lax.top_k(p, cfg.top_k)[1].astype(jnp.int32)
+    if cfg.scoring == "sigmoid_bias":
+        p = jax.nn.sigmoid(logits)
+        chosen_by = jax.lax.stop_gradient(p + bias)
+    else:
+        p = chosen_by = jax.nn.softmax(logits, axis=-1)
+    ids = jax.lax.top_k(chosen_by, cfg.top_k)[1].astype(jnp.int32)
     # the chosen probabilities by a one-hot product, not a gather: its
     # backward pass is then a product too, where a gather's is a scatter
     # (serial on a TPU)
     picks = (ids[..., None] == jnp.arange(cfg.n_experts)).astype(F32)
     top = jnp.einsum("tke,te->tk", picks, p)
-    return ids, top / jnp.sum(top, -1, keepdims=True)
+    weights = top / jnp.sum(top, -1, keepdims=True)
+    if cfg.routed_scale != 1.0:
+        weights = cfg.routed_scale * weights
+    return ids, weights
+
+
+def router_load(cfg: LMConfig, ids):
+    """Each router output's assignments among ``ids`` [T, k]: int32
+    [n_experts]."""
+    return jnp.sum(ids.reshape(-1, 1) == jnp.arange(cfg.n_experts),
+                   axis=0, dtype=jnp.int32)
 
 
 def held_groups(cfg: LMConfig, ids):
@@ -715,12 +926,22 @@ ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
     """``a + sum over held experts`` for one sequence ``a`` [T, hidden]
     with its routing."""
+    out, sizes = routed_experts(cfg, mats, sinks, a, ids, weights, norm)
+    return a + out, sizes
+
+
+def routed_experts(cfg: LMConfig, mats, sinks, h, ids, weights, norm=None):
+    """The held experts' part of ``sum_e w_e E_e(h)`` for one sequence
+    with its routing: ``(sum [T, hidden] float32, each held expert's
+    assignments)``. ``h`` [T, hidden] is the experts' normed input in
+    bfloat16, or with ``norm`` the stream that is normed by it here."""
     t, k = ids.shape
     count = cfg.experts_held[1]
     order, sizes = held_groups(cfg, ids)
     back = jnp.argsort(order).astype(jnp.int32)     # assignment -> its row
     live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-    h = rmsnorm(a, norm, cfg.eps).astype(BF16)
+    if norm is not None:
+        h = rmsnorm(h, norm, cfg.eps).astype(BF16)
     rows = jnp.where(live, dispatch(h, order, back, k), 0)
 
     def product(rows, name, n_in, n_out):
@@ -736,7 +957,16 @@ def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
     # the tokens and summed there in float32
     w_rows = permute(weights.reshape(t * k, 1), order, back)
     out = (jnp.where(live, out, 0) * w_rows).astype(BF16)
-    return a + combine(out, order, back, k), sizes
+    return combine(out, order, back, k), sizes
+
+
+def gated_mlp(cfg: LMConfig, mats, sinks, names, h):
+    """``W_d (act(h W_g) * (h W_u))`` for ``h`` [T, hidden]: a dense
+    layer's MLP or the shared expert, ``names`` its three tables."""
+    gate, up, down = names
+    act = ACTIVATIONS[cfg.activation](mm(h, mats[gate], sinks[gate])) \
+        * mm(h, mats[up], sinks[up])
+    return mm(act, mats[down], sinks[down])
 
 
 # -- a layer, forward and with its gradients -----------------------------------
@@ -855,7 +1085,7 @@ def layer_grads(cfg: LMConfig, rope: bool, mask, mats, small, x, dy,
 # -- the head: final norm, logits over the slice, the loss, its gradients ------
 
 def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets, weights=None,
-                        normaliser=None):
+                        normaliser=None, scope="mv.lm.head"):
     """The cross entropy of ``targets`` [N] over ``x`` [N, hidden], each
     position weighted by ``weights`` [N] (1 when None), summed and divided
     by ``normaliser`` (N when None: the plain mean), and its gradients, a
@@ -881,7 +1111,7 @@ def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets, weights=None,
             x, norm, jnp.zeros(head.shape, F32), targets, weights)
         return (loss + more, d_head + dh, d_norm + dn), dx
 
-    with jax.named_scope("mv.lm.head"):
+    with jax.named_scope(scope):
         (loss, d_head, d_norm), dx = jax.lax.scan(
             one, (jnp.zeros((), F32), jnp.zeros(head.shape, F32),
                   jnp.zeros(norm.shape, F32)),

@@ -80,6 +80,8 @@ which waits for the last one's programs anyway: one step is in flight
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 from typing import Dict, List
 
 import jax
@@ -94,6 +96,7 @@ from ...updater.rules import create_rule
 from ...util.dashboard import count, monitor
 from ...util.log import CHECK
 from . import model as lm
+from . import mtp, streams
 from .model import LMConfig
 
 BF16 = jnp.bfloat16
@@ -124,9 +127,24 @@ def _kind(cfg: LMConfig, rope: int, window: int, seq_len: int):
                               if mask.kind == "blockdiff" else None)
 
 
-def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int):
+def bias_step(cfg: LMConfig, stats):
+    """What a sparse layer's router bias gets after a step whose forward
+    program counted ``stats`` [B, 2 + n_experts] (streams.layer_stats):
+    ``bias_rate * sign(mean load - load)`` over the step's sequences, a
+    DELTA that the server adds (the bias's table is under the plain
+    rule; no gradient goes to it)."""
+    load = jnp.sum(stats[:, 2:], axis=0).astype(jnp.float32)
+    return cfg.bias_rate * jnp.sign(jnp.mean(load) - load)
+
+
+def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
+                    sparse: int = 1):
     """``(float32 matrices, small, x [B, T, hidden]) -> (y, stats [B, 2],
-    the matrices' bfloat16 copies, each token's experts [B, T, k])``."""
+    the matrices' bfloat16 copies, each token's experts [B, T, k])``. The
+    third family's (``cfg.residual == "mhc"``; ``sparse``: the layer's
+    feed-forward) takes and gives [B, n hidden, T], its sparse layers'
+    ``stats`` also count every router output (streams.layer_stats) and
+    they give a fifth result, the bias's step (``bias_step``)."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
     def forward(mats32, small, x):
@@ -136,29 +154,108 @@ def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int):
                                          pos), x)
         return y, stats, mats, ids
 
-    return jax.jit(forward)
+    def forward_streams(mats32, small, x):
+        mats = {n: w.astype(BF16) for n, w in mats32.items()}
+        # a sequence's streams in and out of the loop are the streams' work
+        with jax.named_scope(streams.SCOPE):
+            y, stats, ids = jax.lax.map(
+                lambda seq: streams.layer_forward(cfg, sparse, mats, small,
+                                                  seq), x)
+        more = (bias_step(cfg, stats),) if sparse else ()
+        return (y, stats, mats, ids) + more
+
+    return jax.jit(forward_streams if cfg.residual == "mhc" else forward)
 
 
-def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int):
+def _summed_over_sequences(one, mats, small, sequences, loop_scope=""):
+    """``one(sequence) -> (carried cotangents, matrix gradients, small
+    gradients)`` over the step's sequences, one at a time, the gradients
+    float32 and summed. With a ``loop_scope`` the loop's own work is
+    named: a sequence's way in and out of it (slices of the step's widest
+    arrays) by that scope, the sums ``mv.lm.grad_sum``."""
+    def named(scope):
+        return jax.named_scope(scope) if loop_scope \
+            else contextlib.nullcontext()
+
+    def step(carry, seq):
+        out, d_mats, d_small = one(seq)
+        with named("mv.lm.grad_sum"):
+            carry = jax.tree_util.tree_map(jnp.add, carry, (d_mats, d_small))
+        return carry, out
+
+    zeros = ({n: jnp.zeros(w.shape, jnp.float32) for n, w in mats.items()},
+             jax.tree_util.tree_map(jnp.zeros_like, small))
+    with named(loop_scope):
+        (d_mats, d_small), out = jax.lax.scan(step, zeros, sequences)
+    return out, d_mats, d_small
+
+
+def _learned(small):
+    """A layer's float32 tensors that get a gradient: all but the
+    router's bias."""
+    return {n: g for n, g in small.items() if n != "router_bias"}
+
+
+def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
+                     sparse: int = 1):
     """``(bfloat16 matrices, small, x, dy) -> (dx, matrix gradients, small
-    gradients)``, the gradients float32 and summed over the sequences."""
+    gradients)``, the gradients float32 and summed over the sequences (the
+    third family's router bias gets none: ``small`` holds it, the
+    gradients do not)."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
     def backward(mats, small, x, dy):
-        def one(carry, seq):
-            x, dy = seq
-            dx, d_mats, d_small = lm.layer_grads(cfg, rope, mask, mats,
-                                                 small, x, dy, pos)
-            return jax.tree_util.tree_map(jnp.add, carry,
-                                          (d_mats, d_small)), dx
+        return _summed_over_sequences(
+            lambda seq: lm.layer_grads(cfg, rope, mask, mats, small, *seq,
+                                       pos), mats, small, (x, dy))
 
-        zeros = ({n: jnp.zeros(w.shape, jnp.float32)
-                  for n, w in mats.items()},
-                 jax.tree_util.tree_map(jnp.zeros_like, small))
-        (d_mats, d_small), dx = jax.lax.scan(one, zeros, (x, dy))
-        return dx, d_mats, d_small
+    def backward_streams(mats, small, x, dy):
+        return _summed_over_sequences(
+            lambda seq: streams.layer_grads(cfg, sparse, mats, small, *seq),
+            mats, _learned(small), (x, dy), loop_scope=streams.SCOPE)
 
-    return jax.jit(backward, donate_argnums=(3,))
+    return jax.jit(backward_streams if cfg.residual == "mhc" else backward,
+                   donate_argnums=(3,))
+
+
+def module_programs(cfg: LMConfig):
+    """The multi-token module's three programs (mtp.py):
+    ``forward(float32 matrices, small, xs [B, T, hidden], e_next) -> (y,
+    stats, bfloat16 copies, experts, the bias's step)``;
+    ``head(float32 head, the module's final norm, y, targets [B*T]) ->
+    (weighted loss, dy, head gradient, norm gradient)``, the loss and its
+    gradients times ``cfg.mtp_weight``; ``backward(bfloat16 matrices,
+    small, xs, e_next, dy) -> ((dxs, de_next), matrix gradients, small
+    gradients)``."""
+    def mtp_forward(mats32, small, xs, e_next):
+        mats = {n: w.astype(BF16) for n, w in mats32.items()}
+
+        def one(seq):
+            y, aux, _ = mtp.module_vjp(cfg, mats, small, *seq)
+            return (y,) + streams.layer_stats(cfg, 1, aux)
+
+        y, stats, ids = jax.lax.map(one, (xs, e_next))
+        return y, stats, mats, ids, bias_step(cfg, stats)
+
+    def mtp_head(head32, norm, y, targets):
+        loss, dy, d_head, d_norm = lm.head_loss_and_grads(
+            cfg, head32.astype(BF16), norm, y.reshape(-1, y.shape[-1]),
+            targets, normaliser=targets.size / cfg.mtp_weight,
+            scope="mv.lm.mtp.head")
+        return loss, dy.reshape(y.shape), d_head, d_norm
+
+    def mtp_backward(mats, small, xs, e_next, dy):
+        def one(seq):
+            xs, e_next, dy = seq
+            dxs, de, d_mats, d_small = mtp.module_vjp(
+                cfg, mats, small, xs, e_next)[2](dy)
+            return (dxs, de), d_mats, d_small
+
+        return _summed_over_sequences(one, mats, _learned(small),
+                                      (xs, e_next, dy), loop_scope=mtp.SCOPE)
+
+    return (jax.jit(mtp_forward), jax.jit(mtp_head, donate_argnums=(2,)),
+            jax.jit(mtp_backward, donate_argnums=(4,)))
 
 
 def head_program(cfg: LMConfig):
@@ -227,7 +324,7 @@ class PSLMTrainer:
         self.option = AddOption(worker_id=max(zoo.worker_id, 0),
                                 momentum=beta1, learning_rate=lr, rho=beta2,
                                 lambda_=eps)    # the step's: see step()
-        seeds = iter(range(seed * 64, seed * 64 + 64))
+        seeds = itertools.count(seed * 64)  # a matrix table each, in order
 
         def matrix(shape, std=init_std):
             # uniform on (-a, a) has the standard deviation a / sqrt(3): the
@@ -243,23 +340,59 @@ class PSLMTrainer:
         # position, and the routers of the later layers (they read the
         # residual stream raw) then send every token to the same experts
         # from the first step on (docs/LM_TRAINER.md).
+        def table(name, shape):
+            """A tensor's table by its name: a matrix drawn at ``init_std``
+            (a stream mixer's ``phi`` at ``1 / sqrt(n hidden)``, so that a
+            coefficient of a normed input is of order 1), a norm or a
+            mixer's scalars 1, a mixer's offsets 0; a router's bias 0 and
+            under the PLAIN rule, every other under the flag's Adam."""
+            if name == "router_bias":
+                return create_array_table(shape[0], updater_type="default")
+            if len(shape) == 2:
+                return matrix(shape, shape[1] ** -0.5
+                              if name.endswith("_phi") else init_std)
+            return create_array_table(
+                shape[0], fill=0.0 if name.endswith("_b") else 1.0)
+
         self.embedding = matrix((cfg.vocab, cfg.hidden), embedding_std)
         self.layers: List[Dict[str, object]] = []
-        for _ in range(cfg.n_layers):
-            tables = {}
-            for name, shape in cfg.layer_shapes().items():
-                tables[name] = matrix(shape) if len(shape) == 2 \
-                    else create_array_table(shape[0], fill=1.0)
-            self.layers.append(tables)
+        for i in range(cfg.n_layers):
+            self.layers.append({name: table(name, shape) for name, shape
+                                in cfg.layer_shapes(i).items()})
         self.final_norm = create_array_table(cfg.hidden, fill=1.0)
         self.head = matrix((cfg.vocab, cfg.hidden))
+        # the multi-token module: its own tensors, then its sparse layer's
+        self.module: Dict[str, object] = {}
+        if cfg.mtp_layers:
+            CHECK(cfg.mtp_layers == 1 and cfg.residual == "mhc"
+                  and not self.diffusion, "one multi-token module, after a "
+                  "stack of streams, under the next-token objective")
+            shapes = {**cfg.mtp_shapes(),
+                      **cfg.layer_shapes(cfg.n_layers - 1)}
+            self.module = {name: table(name, shape)
+                           for name, shape in shapes.items()}
+            self._module = module_programs(cfg)
         self._whole_bytes = 4 * (cfg.parameters() - cfg.vocab * cfg.hidden)
 
-        kinds = sorted(set(zip(cfg.rope_layout, cfg.window_layout)))
-        self._forward = {k: forward_program(cfg, *k, self.T) for k in kinds}
-        self._backward = {k: backward_program(cfg, *k, self.T)
+        kinds = sorted(set(cfg.layer_kinds()))
+        # a kind is (rotary, window) or (rotary, window, sparse)
+        self._forward = {k: forward_program(cfg, *k[:2], self.T, *k[2:])
+                         for k in kinds}
+        self._backward = {k: backward_program(cfg, *k[:2], self.T, *k[2:])
                           for k in kinds}
-        self._split = jax.jit(self._split_tokens)
+        self._split = jax.jit(self._split_more if cfg.mtp_layers
+                              else self._split_tokens)
+        self.streams = cfg.residual == "mhc"
+        if self.streams:
+            self._enter = jax.jit(self._enter_streams)
+            self._leave = jax.jit(self._leave_streams, donate_argnums=(0,))
+            self._leave_back = jax.jit(self._leave_streams_back,
+                                       donate_argnums=(0,))
+            self._sum = jax.jit(
+                lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
+                donate_argnums=(0, 1))
+            self._enter_back = jax.jit(self._enter_streams_back,
+                                       donate_argnums=(0,))
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
@@ -275,6 +408,9 @@ class PSLMTrainer:
         for i, layer in enumerate(self.layers):
             out.update({f"layer{i}.{name}": t for name, t in layer.items()})
         out.update({"final_norm": self.final_norm, "head": self.head})
+        own = self.cfg.mtp_shapes() if self.module else ()
+        out.update({f"mtp.{name}" if name in own else f"mtp.layer.{name}": t
+                    for name, t in self.module.items()})
         return out
 
     # -- programs -----------------------------------------------------------------
@@ -285,6 +421,42 @@ class PSLMTrainer:
             ids = tokens[:, :-1]
             distinct = _distinct(ids)
             return ids, tokens[:, 1:].reshape(-1), distinct
+
+    def _split_more(self, tokens):
+        """With a multi-token module: [B, T+2] tokens -> the ids to embed
+        [B, T+1] (the last only as a next token), the two targets ([B*T]
+        each: the next token and the one after) and the distinct ids."""
+        with jax.named_scope("mv.lm.embed"):
+            ids = tokens[:, :-1]
+            return ids, (tokens[:, 1:-1].reshape(-1),
+                         tokens[:, 2:].reshape(-1)), _distinct(ids)
+
+    # -- the streams' two ends (streams.py; under mv.lm.embed) -------------------
+    def _enter_streams(self, rows):
+        """The embedding's rows [B, T (+1), hidden] -> the first layer's
+        input, every stream alike, and the next tokens' rows (None without
+        a module)."""
+        with jax.named_scope("mv.lm.embed"):
+            x = streams.expand(self.cfg, rows[:, :self.T])
+            return x, (rows[:, 1:] if self.module else None)
+
+    def _leave_streams(self, x):
+        with jax.named_scope("mv.lm.embed"):
+            return streams.collapse(self.cfg, x)
+
+    def _leave_streams_back(self, dxs):
+        with jax.named_scope("mv.lm.embed"):
+            return streams.expand(self.cfg, dxs)
+
+    def _enter_streams_back(self, dx, de_next):
+        """The rows' gradient [B, T (+1), hidden] from the first layer's
+        and (with a module) the next tokens' rows'."""
+        with jax.named_scope("mv.lm.embed"):
+            d_rows = streams.collapse(self.cfg, dx)
+            if de_next is None:
+                return d_rows
+            return jnp.pad(d_rows, ((0, 0), (0, 1), (0, 0))) \
+                + jnp.pad(de_next, ((0, 0), (1, 0), (0, 0)))
 
     def noised(self, tokens):
         """Block diffusion's batch for ``tokens`` as the step about to run
@@ -300,16 +472,40 @@ class PSLMTrainer:
         if self.diffusion:
             return self.noised(tokens)[:5]
         ids, targets, distinct = _dispatch(self._split, tokens)
-        return ids, targets, None, distinct, targets.size
+        return ids, targets, None, distinct, self.B * self.T
 
     # -- Gets and Adds ---------------------------------------------------------------
-    def _pull_layer(self, i: int):
-        tables = self.layers[i]
+    def _pull(self, tables, shapes, matrices):
+        """``tables`` whole: ``(float32 matrices, small)``, a matrix in its
+        tensor's shape."""
         with monitor("LM_GET_PARAMS"):
-            mats = {n: tables[n].get_device().reshape(
-                self.cfg.layer_shapes()[n]) for n in lm.LAYER_MATRICES}
-            small = {n: tables[n].get_device() for n in self.cfg.small_names}
+            mats = {n: tables[n].get_device().reshape(shapes[n])
+                    for n in matrices}
+            small = {n: tables[n].get_device().reshape(shapes[n])
+                     for n in tables if n not in matrices}
         return mats, small
+
+    def _pull_layer(self, i: int):
+        return self._pull(self.layers[i], self.cfg.layer_shapes(i),
+                          self.cfg.matrices(i))
+
+    def _pull_module(self):
+        cfg = self.cfg
+        shapes = {**cfg.layer_shapes(cfg.n_layers - 1), **cfg.mtp_shapes()}
+        mats, small = self._pull(
+            {n: t for n, t in self.module.items() if n != "final_norm"},
+            shapes, cfg.matrices(cfg.n_layers - 1) + mtp.MATRICES)
+        return mats, small
+
+    def _push_layer(self, tables, grads, bias_step) -> None:
+        """A layer's gradients to their tables and, with a router, its
+        bias's step to the table under the plain rule."""
+        with monitor("LM_ADD_GRADS"):
+            for name, grad in grads.items():
+                self._push(tables[name], grad)
+            for delta in bias_step:
+                self._push(tables["router_bias"], delta)
+                count("LM_ROUTER_BIAS_ADDS")
 
     def _push(self, table, delta, ids=None) -> None:
         """One gradient to its table: whole, or by ``ids`` as device keys."""
@@ -326,12 +522,13 @@ class PSLMTrainer:
 
     # -- a step ------------------------------------------------------------------------
     def step(self, tokens):
-        """One step on ``tokens`` int32 on the device: [B, T+1], or
-        [B, T] clean tokens under block diffusion. Returns the loss, a
-        device scalar."""
+        """One step on ``tokens`` int32 on the device: [B, T+1] (with a
+        multi-token module [B, T+2]), or [B, T] clean tokens under block
+        diffusion. Returns the loss, a device scalar."""
         cfg = self.cfg
-        CHECK(tuple(tokens.shape) == (self.B, self.T + (not self.diffusion)),
-              "bad token shape")
+        CHECK(tuple(tokens.shape) == (
+            self.B, self.T + (not self.diffusion) + cfg.mtp_layers),
+            "bad token shape")
         with monitor("LM_STEP"):
             # One step in flight: a program's results are allocated when
             # it is dispatched, so a host that ran a step ahead would hold
@@ -349,33 +546,50 @@ class PSLMTrainer:
             ids, targets, weights, distinct, scored = self.prepare(tokens)
             with monitor("LM_GET_PARAMS"):
                 x = self.embedding.get_rows_device(ids)
-            kinds = list(zip(cfg.rope_layout, cfg.window_layout))
+            if self.streams:
+                x, e_next = _dispatch(self._enter, x)
+            kinds = cfg.layer_kinds()
             kept, stats = [], []
             for i, kind in enumerate(kinds):
                 mats32, small = self._pull_layer(i)
-                y, layer_stats, mats, _ = _dispatch(self._forward[kind],
-                                                    mats32, small, x)
+                y, layer_stats, mats, _, *bias_step = _dispatch(
+                    self._forward[kind], mats32, small, x)
                 del mats32
-                kept.append((mats, small, x))
+                kept.append((mats, small, x, bias_step))
                 stats.append(layer_stats)
                 x = y
+            if self.streams:
+                x = _dispatch(self._leave, x)
             with monitor("LM_GET_PARAMS"):
                 head32 = self.head.get_device()
                 norm = self.final_norm.get_device()
+            if self.module:     # targets: (the next token, the one after)
+                targets, after = targets
+                second = self._module_step(head32, x, e_next, after, stats)
             loss, dx, d_head, d_norm = _dispatch(
                 self._head_program, head32, norm, x, targets,
                 *(() if weights is None else (weights,)))
             del head32, x
+            if self.module:
+                # ONE Add a table a step: the head's two gradients, the
+                # stream's and the loss summed first (under Adam two Adds
+                # would be two steps of the moments)
+                (loss, dx, d_head), de_next = _dispatch(
+                    self._sum, (loss, dx, d_head), second[:3]), second[3]
             with monitor("LM_ADD_GRADS"):
                 self._push(self.head, d_head)
                 self._push(self.final_norm, d_norm)
+            if self.streams:
+                dx = _dispatch(self._leave_back, dx)
             for i in reversed(range(cfg.n_layers)):
-                mats, small, x_in = kept.pop()
+                mats, small, x_in, bias_step = kept.pop()
                 dx, d_mats, d_small = _dispatch(self._backward[kinds[i]],
                                                 mats, small, x_in, dx)
-                with monitor("LM_ADD_GRADS"):
-                    for name, grad in {**d_mats, **d_small}.items():
-                        self._push(self.layers[i][name], grad)
+                self._push_layer(self.layers[i], {**d_mats, **d_small},
+                                 bias_step)
+            if self.streams:    # and the next tokens' rows' gradient
+                dx = _dispatch(self._enter_back, dx,
+                               de_next if self.module else None)
             with monitor("LM_ADD_GRADS"):
                 self._push(self.embedding, dx, ids)
         self.steps += 1
@@ -384,14 +598,44 @@ class PSLMTrainer:
         count("LM_POSITIONS", ids.size)
         count("LM_GET_BYTES", self._whole_bytes)
         count("LM_ADD_BYTES", self._whole_bytes)
+        if self.module:
+            count("LM_MTP_TOKENS", self.B * self.T)
         self._stats.append((stats, distinct, scored))
         return loss
 
+    def _module_step(self, head32, xs, e_next, targets, stats):
+        """The multi-token module from the summed streams ``xs`` to its
+        Adds, all but the head's and the embedding's: ``(weighted second
+        loss, dxs, the head's second gradient, the next tokens' rows'
+        gradient)``. Its layer's counts join ``stats``."""
+        forward, head, backward = self._module
+        mats32, small = self._pull_module()
+        with monitor("LM_GET_PARAMS"):
+            norm = self.module["final_norm"].get_device()
+        y, layer_stats, mats, _, bias_step = _dispatch(
+            forward, mats32, small, xs, e_next)
+        del mats32
+        stats.append(layer_stats)
+        loss, dy, d_head, d_norm = _dispatch(head, head32, norm, y, targets)
+        (dxs, de_next), d_mats, d_small = _dispatch(
+            backward, mats, small, xs, e_next, dy)
+        self._push_layer(self.module,
+                         {**d_mats, **d_small, "final_norm": d_norm},
+                         [bias_step])
+        return loss, dxs, d_head, de_next
+
     def _count_stats(self, entry) -> None:
         stats, distinct, scored = entry
-        per_layer = np.stack([np.asarray(s) for s in stats])  # [L, B, 2]
-        count("LM_HELD_ASSIGNMENTS", int(per_layer[..., 0].sum()))
-        count("LM_EXPERT_MAX_TOKENS", int(per_layer[..., 1].sum()))
+        # a layer: [B, 2], or with every router output counted [B, 2 + E]
+        per_layer = [np.asarray(s) for s in stats]
+        count("LM_HELD_ASSIGNMENTS", int(sum(s[:, 0].sum()
+                                             for s in per_layer)))
+        count("LM_EXPERT_MAX_TOKENS", int(sum(s[:, 1].sum()
+                                              for s in per_layer)))
+        fullest = sum(int(s[:, 2:].sum(axis=0).max()) for s in per_layer
+                      if s.shape[1] > 2)
+        if fullest:
+            count("LM_ROUTER_LOAD_MAX", fullest)
         count("LM_EMBED_ROWS", int(distinct))
         count("LM_MASKED_TOKENS", int(scored))
 

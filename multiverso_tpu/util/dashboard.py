@@ -236,6 +236,13 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_EXPERT_MAX_TOKENS": "the fullest held expert's tokens, summed "
                             "over layers and sequences",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
+    "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
+                     "trainer that holds the module)",
+    "LM_ROUTER_BIAS_ADDS": "plain Adds of a router bias's step, one a "
+                           "sparse layer a step",
+    "LM_ROUTER_LOAD_MAX": "the fullest router output's assignments over "
+                          "ALL of a sparse layer's outputs, summed over "
+                          "layers",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"
