@@ -156,11 +156,18 @@ def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
 
     def forward_streams(mats32, small, x):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
-        # a sequence's streams in and out of the loop are the streams' work
+        # a sequence is read where it lies in the step's stack and its
+        # result written where it will lie (streams.Of): the loop's own
+        # work is the streams' work
+        def one(y, b):
+            y, stats, ids = streams.layer_forward(
+                cfg, sparse, mats, small, streams.Of(x, b),
+                into=streams.Of(y, b))
+            return y, (stats, ids)
+
         with jax.named_scope(streams.SCOPE):
-            y, stats, ids = jax.lax.map(
-                lambda seq: streams.layer_forward(cfg, sparse, mats, small,
-                                                  seq), x)
+            y, (stats, ids) = jax.lax.scan(one, jnp.zeros_like(x),
+                                           jnp.arange(x.shape[0]))
         more = (bias_step(cfg, stats),) if sparse else ()
         return (y, stats, mats, ids) + more
 
@@ -183,11 +190,15 @@ def _summed_over_sequences(one, mats, small, sequences, loop_scope=""):
             carry = jax.tree_util.tree_map(jnp.add, carry, (d_mats, d_small))
         return carry, out
 
-    zeros = ({n: jnp.zeros(w.shape, jnp.float32) for n, w in mats.items()},
-             jax.tree_util.tree_map(jnp.zeros_like, small))
     with named(loop_scope):
-        (d_mats, d_small), out = jax.lax.scan(step, zeros, sequences)
+        (d_mats, d_small), out = jax.lax.scan(
+            step, _zero_gradients(mats, small), sequences)
     return out, d_mats, d_small
+
+
+def _zero_gradients(mats, small):
+    return ({n: jnp.zeros(w.shape, jnp.float32) for n, w in mats.items()},
+            jax.tree_util.tree_map(jnp.zeros_like, small))
 
 
 def _learned(small):
@@ -210,9 +221,23 @@ def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
                                        pos), mats, small, (x, dy))
 
     def backward_streams(mats, small, x, dy):
-        return _summed_over_sequences(
-            lambda seq: streams.layer_grads(cfg, sparse, mats, small, *seq),
-            mats, _learned(small), (x, dy), loop_scope=streams.SCOPE)
+        # the donated cotangents' stack is the carry: a sequence's dx is
+        # written where its dy lay, once the layer has read that
+        def one(carry, b):
+            d, sums = carry
+            d, d_mats, d_small = streams.layer_grads(
+                cfg, sparse, mats, small, streams.Of(x, b), streams.Of(d, b),
+                into=streams.Of(d, b))
+            with jax.named_scope("mv.lm.grad_sum"):
+                sums = jax.tree_util.tree_map(jnp.add, sums,
+                                              (d_mats, d_small))
+            return (d, sums), None
+
+        with jax.named_scope(streams.SCOPE):
+            (dx, (d_mats, d_small)), _ = jax.lax.scan(
+                one, (dy, _zero_gradients(mats, _learned(small))),
+                jnp.arange(x.shape[0]))
+        return dx, d_mats, d_small
 
     return jax.jit(backward_streams if cfg.residual == "mhc" else backward,
                    donate_argnums=(3,))
@@ -515,10 +540,13 @@ class PSLMTrainer:
             msg_id = table.add_rows_async(ids, delta, self.option)
         self._pending.append((table, msg_id))
 
-    def _drain(self) -> None:
-        for table, msg_id in self._pending:
+    def _drain(self, upto=None) -> None:
+        """Wait for the pending Adds' acknowledgements: all, or the first
+        ``upto`` (which stay listed; waiting again costs nothing)."""
+        for table, msg_id in self._pending[:upto]:
             table.wait(msg_id)
-        self._pending.clear()
+        if upto is None:
+            self._pending.clear()
 
     # -- a step ------------------------------------------------------------------------
     def step(self, tokens):
@@ -581,8 +609,21 @@ class PSLMTrainer:
                 self._push(self.final_norm, d_norm)
             if self.streams:
                 dx = _dispatch(self._leave_back, dx)
+            pushed = []     # where each layer's Adds begin in _pending
             for i in reversed(range(cfg.n_layers)):
                 mats, small, x_in, bias_step = kept.pop()
+                if self.streams and len(pushed) >= 2:
+                    # A layer's gradients live until the server has taken
+                    # their Adds: with the mixers' passes as kernels a
+                    # layer's backward program is shorter than the
+                    # server's way through its two dozen Adds, and left
+                    # alone the trainer ends a step with every layer's
+                    # gradients waiting (0.7 GB more at the peak). So it
+                    # goes on only once the layer before the last one's
+                    # are acknowledged; the device has the last one's
+                    # program to run meanwhile.
+                    self._drain(pushed[-1])
+                pushed.append(len(self._pending))
                 dx, d_mats, d_small = _dispatch(self._backward[kinds[i]],
                                                 mats, small, x_in, dx)
                 self._push_layer(self.layers[i], {**d_mats, **d_small},

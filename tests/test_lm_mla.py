@@ -8,6 +8,7 @@ and in bfloat16 (the rounding), the share tests, Sinkhorn, and one step of
 ``PSLMTrainer`` through the tables."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -282,6 +283,282 @@ def test_expand_and_collapse():
     assert x.shape == (12, 2) and np.array_equal(x[3:6], h.T)
     assert np.array_equal(streams.collapse(cfg, x), 4 * h)
     assert streams.expand(cfg, jnp.stack([h, h])).shape == (2, 12, 2)
+
+
+# -- the mixers' pull, written out -------------------------------------------------
+
+def _plain_sinkhorn(logits, iters, eps):
+    """The rounds as the configuration words them: [row, column, token]."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (jnp.sum(m, 1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, 0, keepdims=True) + eps)
+    return m
+
+
+def _plain_sublayer(cfg, hc, x, f):
+    """streams.py's docstring, line for line, for ``x`` [n C, T] and an
+    ``F`` that takes and gives [C, T]: ``(x', v)``."""
+    n, c = cfg.hc_mult, cfg.hidden
+    r = x * jax.lax.rsqrt(jnp.mean(x * x, 0, keepdims=True) + cfg.eps)
+    raw = jnp.dot(hc["phi"], r, precision="highest")
+    b, a = hc["b"][:, None], hc["a"]
+    pre = jax.nn.sigmoid(a[0] * raw[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * raw[n:2 * n] + b[n:2 * n])
+    res = _plain_sinkhorn(
+        jnp.clip(a[2] * raw[2 * n:] + b[2 * n:], *cfg.hc_clamp).reshape(
+            n, n, -1), cfg.hc_iters, cfg.hc_eps)
+    xs = x.reshape(n, c, -1)
+    v = f(jnp.einsum("jt,jct->ct", pre, xs))
+    return (jnp.einsum("ijt,jct->ict", res, xs)
+            + post[:, None] * v[None]).reshape(x.shape), v
+
+
+TIGHT = dataclasses.replace(CFG, hc_clamp=(-0.5, 0.5))
+PULLED = ("dx", "phi", "b", "a", "dv", "w")
+
+
+@pytest.fixture(scope="module", params=streams.SUBLAYERS)
+def pulled(request):
+    """One sublayer's pull both ways, under a clamp that binds on some
+    entries: ``{tensor: (streams.sublayer_vjp's, jax.vjp's of the plain
+    formulas)}``; ``F`` is a stand-in with a weight of its own, another
+    one a kind."""
+    cfg, n, c = TIGHT, TIGHT.hc_mult, TIGHT.hidden
+    kind = streams.SUBLAYERS.index(request.param)
+    rng = np.random.default_rng(20 + kind)
+    hc = {k: v for k, v in zip(streams.MIXER, (
+        jnp.asarray(rng.normal(0, (n * c) ** -0.5, (2 * n + n * n, n * c)),
+                    jnp.float32),
+        jnp.asarray(rng.normal(0, 0.3, 2 * n + n * n), jnp.float32),
+        jnp.asarray([0.9, 1.1, 1.3], jnp.float32)))}
+    x = jnp.asarray(rng.normal(size=(n * c, T)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(n * c, T)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(c, 1)), jnp.float32)
+
+    def f(w, u):        # [C, T] -> [C, T]
+        return jnp.tanh(w * u) if kind else w * u * jax.nn.sigmoid(u)
+
+    seen = {}
+
+    def f_vjp(u):       # the program's F takes and gives [T, C]
+        v, pull = jax.vjp(lambda w, u: f(w, u.T).T, w, u)
+
+        def pull_f(dv):
+            seen["dv"] = dv.T
+            d_w, du = pull(dv)
+            return du, d_w
+
+        return v, None, pull_f
+
+    y, _, pull = streams.sublayer_vjp(cfg, hc, x, f_vjp)
+    dx, d_hc, d_w = pull(dy)
+    (want_y, v), pull_plain = jax.vjp(
+        lambda hc, x, w: _plain_sublayer(cfg, hc, x,
+                                         functools.partial(f, w)), hc, x, w)
+    want_hc, want_dx, want_w = pull_plain((dy, jnp.zeros_like(v)))
+    # dv: the same formulas with v an input of its own
+    want_dv = jax.grad(lambda v: jnp.sum(
+        dy * _plain_sublayer(cfg, hc, x, lambda u: v)[0]))(v)
+    kept = streams.coefficients(cfg, hc, x)[1]
+    return {"y": (y, want_y), "dx": (dx, want_dx), "dv": (seen["dv"],
+                                                          want_dv),
+            "w": (d_w, want_w), "inside": kept[2],
+            **{k: (d_hc[k], want_hc[k]) for k in streams.MIXER}}
+
+
+@pytest.mark.parametrize("name", PULLED)
+def test_the_pull_written_out_is_the_formulas_differentiated(pulled, name):
+    """``streams.sublayer_vjp``'s pull against ``jax.vjp`` of the module
+    docstring's formulas written plainly, float32, each tensor against its
+    own norm; the clamp holds some entries and lets others through."""
+    inside = np.asarray(pulled["inside"])
+    assert 0.1 < inside.mean() < 0.9
+    assert _relative(*pulled["y"]) < 1e-5
+    assert _relative(*pulled[name]) < 1e-5
+
+
+@pytest.mark.parametrize("stacked", ("x", "into"))
+def test_a_sequence_is_read_and_written_where_it_lies(stacked):
+    """Handed sequence ``b`` of a stack (``streams.Of``) a sublayer gives
+    what it gives that sequence alone, and told to leave its results in a
+    stack it changes that sequence of it and no other."""
+    cfg, n, c = CFG, CFG.hc_mult, CFG.hidden
+    rng = np.random.default_rng(31)
+    p = _draw({f"hc_{k}": s for k, s in zip(
+        streams.MIXER, ((2 * n + n * n, n * c), (2 * n + n * n,), (3,)))},
+        rng)
+    hc = {k: p[f"hc_{k}"] for k in streams.MIXER}
+    xs, dys, other = (jnp.asarray(rng.normal(size=(3, n * c, T)),
+                                  jnp.float32) for _ in range(3))
+
+    def f_vjp(u):
+        v, pull = jax.vjp(jnp.tanh, u)
+        return v, None, lambda dv: (pull(dv)[0], ())
+
+    y, _, pull = streams.sublayer_vjp(cfg, hc, xs[1], f_vjp)
+    dx, d_hc, _ = pull(dys[2])
+    b = jnp.int32(1)
+    if stacked == "x":
+        got_y, _, got_pull = streams.sublayer_vjp(
+            cfg, hc, streams.Of(xs, b), f_vjp)
+        got_dx, got_hc, _ = got_pull(streams.Of(dys, b + 1))
+    else:
+        got_y, _, got_pull = streams.sublayer_vjp(
+            cfg, hc, xs[1], f_vjp, into=streams.Of(other, b),
+            pull_into=streams.Of(other, b - 1))
+        got_dx, got_hc, _ = got_pull(dys[2])
+        assert np.array_equal(got_y[0], other[0])
+        assert np.array_equal(got_y[2], other[2])
+        assert np.array_equal(got_dx[1:], other[1:])
+        got_y, got_dx = got_y[1], got_dx[0]
+    np.testing.assert_allclose(got_y, y, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_dx, dx, rtol=1e-6, atol=1e-6)
+    for k in streams.MIXER:
+        np.testing.assert_allclose(got_hc[k], d_hc[k], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("iters", (0, 1, 20))
+@pytest.mark.parametrize("what", ("rounds", "pull"))
+def test_sinkhorn_by_slices_is_the_plain_loop(what, iters):
+    """The rounds as adds of slices and products with inverses against
+    ``sum(.., keepdims)`` and a quotient, and their hand-written pull
+    against autodiff through that loop, for the ``iters`` it is given."""
+    rng = np.random.default_rng(6)
+    logits = jnp.asarray(rng.normal(0, 1.5, (4, 4, 64)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=(4, 4, 64)), jnp.float32)
+    got, pull = jax.vjp(lambda z: streams.sinkhorn(z, iters, 1e-6), logits)
+    want, pull_plain = jax.vjp(lambda z: _plain_sinkhorn(z, iters, 1e-6),
+                               logits)
+    if what == "rounds":
+        np.testing.assert_allclose(got, want, rtol=2e-6)
+    else:
+        assert _relative(pull(g)[0], pull_plain(g)[0]) < 1e-5
+
+
+@pytest.mark.parametrize("seed", (0, 1))
+def test_the_norm_s_pull_needs_no_pass(seed):
+    """``g . r = d_raw . raw`` for ``g = phi^T d_raw``, ``r`` the normed
+    streams and ``raw = phi r``: the mean that RMSNorm's pull wants is a
+    sum over 2n + n^2 rows, not over the n C of a token's column."""
+    rng = np.random.default_rng(seed)
+    phi, x, d_raw = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                     for shape in ((24, 128), (128, T), (24, T)))
+    r = x * jax.lax.rsqrt(jnp.mean(x * x, 0, keepdims=True) + 1e-6)
+    with ref.PRECISION:
+        g, raw = phi.T @ d_raw, phi @ r
+    np.testing.assert_allclose(jnp.sum(g * r, 0), jnp.sum(d_raw * raw, 0),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_sinkhorn_lowers_to_no_reduction():
+    """Neither the rounds nor their pull holds a ``reduce``: a sum over
+    ``n`` slices is ``n - 1`` adds, which fuse with what is around them."""
+    z = jnp.ones((4, 4, 64))
+    rounds = jax.jit(lambda z: streams.sinkhorn(z, 20, 1e-6)).lower(z)
+    pull = jax.jit(lambda z: jax.vjp(
+        lambda z: streams.sinkhorn(z, 20, 1e-6), z)[1](z)).lower(z)
+    for text in (rounds.as_text(), pull.as_text()):
+        assert "stablehlo.reduce" not in text
+        assert "stablehlo.exponential" in text
+
+
+def test_a_sublayer_makes_three_arrays_as_large_as_the_streams():
+    """Of the operations of one sublayer, forward and pulled, three give
+    a whole [n C, T] float32 result: ``x'`` (the concatenation of its
+    streams), ``g = phi^T d`` (off the TPU a product's result; on it made
+    block by block inside the last pass) and ``dx`` (the concatenation of
+    its streams). Neither ``r = RMSNorm(X)`` nor any cotangent of it, nor
+    a stream padded out to the streams' size, is an array."""
+    n, c = CFG.hc_mult, CFG.hidden
+    k = 2 * n + n * n
+    hc = {"phi": jnp.ones((k, n * c)), "b": jnp.ones((k,)),
+          "a": jnp.ones((3,))}
+
+    def f_vjp(u):
+        v, pull = jax.vjp(jnp.tanh, u)
+        return v, None, lambda dv: (pull(dv)[0], ())
+
+    def both(hc, x, dy):
+        y, _, pull = streams.sublayer_vjp(CFG, hc, x, f_vjp)
+        return y, pull(dy)[:2]
+
+    x = jnp.ones((n * c, T))
+    text = jax.jit(both).lower(hc, x, x).as_text()
+    whole = f"tensor<{n * c}x{T}xf32>"
+    made = [line for line in text.splitlines()
+            if "stablehlo." in line and line.rstrip().endswith(whole)
+            and ("-> " + whole in line or ": " + whole in line)]
+    assert len(made) <= 3, made
+    assert sum("dot_general" in line for line in made) == 1
+    assert sum("concatenate" in line for line in made) == 2
+
+
+KERNELS = ("sinkhorn", "sinkhorn_pull", "stats", "write", "write_into",
+           "weighted", "sums", "dx", "dx_into")
+
+
+@pytest.fixture(scope="module")
+def interpreted():
+    """Every kernel of streams_kernels.py run by Pallas' interpreter on
+    one small block grid (2 x 2 blocks, 3 sequences a stack) beside the
+    same sums in ``jax.numpy``: ``{kernel: [(got, want)]}``."""
+    from jax.experimental.pallas import tpu as pltpu
+    from multiverso_tpu.models.lm import streams_kernels as kernels
+    n, c, t, k = 4, 2 * kernels.ROWS, 2 * kernels.TOKENS, 24
+    rng = np.random.default_rng(0)
+
+    def drawn(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    z, g, phi = drawn(n, n, t), drawn(n, n, t), drawn(k, n * c) * 0.05
+    xs, dys, into = drawn(2, n, c, t), drawn(3, n, c, t), drawn(2, n, c, t)
+    v, du, res, post = drawn(c, t), drawn(c, t), drawn(n * n, t), drawn(n, t)
+    pre, shrink, d = drawn(n, t), drawn(1, t), drawn(k, t)
+    x3, dy3, x = xs[1], dys[2], xs[1].reshape(n * c, t)
+    of_x, of_dy = (xs, jnp.int32(1)), (dys, jnp.int32(2))
+    rounds, pull = jax.vjp(lambda z: streams.sinkhorn(z, 20, 1e-6), z)
+    with ref.PRECISION:
+        y = jnp.einsum("ijt,jct->ict", res.reshape(n, n, t), x3) \
+            + post[:, None] * v[None]
+        dx = jnp.einsum("ijt,ict->jct", res.reshape(n, n, t), dy3) \
+            + pre[:, None] * du[None] + (phi.T @ d).reshape(n, c, t) \
+            - x3 * shrink[0]
+        all_sums = jnp.concatenate([
+            jnp.einsum("ct,jct->jt", du, x3),
+            jnp.einsum("ict,ct->it", dy3, v),
+            jnp.einsum("ict,jct->ijt", dy3, x3).reshape(n * n, t)])
+        product, d_phi = phi @ x, d @ x.T
+    spread = kernels.spread
+    with pltpu.force_tpu_interpret_mode():
+        got_product, squares = kernels.stats(phi, of_x)
+        written = kernels.write(of_x, v, spread(res), spread(post),
+                                (into, jnp.int32(0)))
+        got_dx, got_phi = kernels.dx(of_x, of_dy, du, phi, d, spread(res),
+                                     spread(pre), spread(shrink))
+        placed, _ = kernels.dx(of_x, of_dy, du, phi, d, spread(res),
+                               spread(pre), spread(shrink),
+                               (into, jnp.int32(1)))
+        return {
+            "sinkhorn": [(kernels.sinkhorn(z, 20, 1e-6), rounds)],
+            "sinkhorn_pull": [(kernels.sinkhorn_pull(z, g, 20, 1e-6),
+                               pull(g)[0])],
+            "stats": [(got_product, product),
+                      (squares.sum(0), (x * x).sum(0))],
+            "write": [(kernels.write(of_x, v, spread(res), spread(post))[0],
+                       y)],
+            "write_into": [(written[0], y), (written[1], into[1])],
+            "weighted": [(kernels.weighted(of_dy, spread(post)),
+                          jnp.einsum("it,ict->ct", post, dy3))],
+            "sums": [(kernels.sums(of_x, of_dy, v, du), all_sums)],
+            "dx": [(got_dx[0], dx), (got_phi, d_phi)],
+            "dx_into": [(placed[1], dx), (placed[0], into[0])]}
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_a_kernel_of_the_mixers_is_the_same_sums(interpreted, name):
+    for got, want in interpreted[name]:
+        assert _relative(got, want) < 2e-6
 
 
 # -- the shares add up to the uncut layer -------------------------------------------

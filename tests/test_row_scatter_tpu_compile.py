@@ -9,6 +9,9 @@ test of this kind lives in this one file: only one process at a time
 may load the TPU's library, and a worker keeps it until it exits.
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -258,3 +261,52 @@ def test_the_grouped_products_compile_at_the_block_diffusion_widths(
             shaped((16,), jnp.int32, sharding=one)).compile()
         assert compiled.as_text().count("tpu_custom_call") >= 2
         assert compiled.memory_analysis().temp_size_in_bytes < 900e6
+
+
+# -- the stream mixers (benchmark/configs/xing4-29b-a4b-l5.json) -----------------
+
+def test_the_mixers_passes_compile_for_a_v5e_at_the_published_widths(
+        topo, monkeypatch):
+    """One sublayer of four streams of 3584 over 4096 tokens, forward and
+    pulled, on sequence 1 of a step's two: each pass over the streams is a
+    Pallas kernel (streams_kernels.py: stats, write, weighted, sums, dx,
+    Sinkhorn's two), the sequence is read where it lies in the stack and
+    the results are written into the stacks the program returns, so
+    beside those two stacks the program holds no array as large as a
+    sequence's streams but the feed-forward's input."""
+    from multiverso_tpu.models.lm import model as lm, streams
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "xing4-29b-a4b-l5.json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    n, c, t = cfg.hc_mult, cfg.hidden, 4096
+    assert (n, c, cfg.hc_iters) == (4, 3584, 20)
+    k = 2 * n + n * n
+
+    def f_vjp(u):
+        v, pull = jax.vjp(jnp.tanh, u)
+        return v, None, lambda dv: (pull(dv)[0], ())
+
+    def sublayer(hc, xs, dys):
+        b = jnp.int32(1)
+        y, _, pull = streams.sublayer_vjp(
+            cfg, hc, streams.Of(xs, b), f_vjp, into=streams.Of(xs, b))
+        dx, d_hc, _ = pull(streams.Of(dys, b))
+        return y, d_hc, pull(dx)[0]
+
+    hc = {"phi": shaped((k, n * c), jnp.float32, sharding=one),
+          "b": shaped((k,), jnp.float32, sharding=one),
+          "a": shaped((3,), jnp.float32, sharding=one)}
+    stack = shaped((2, n * c, t), jnp.float32, sharding=one)
+    compiled = jax.jit(sublayer, donate_argnums=(1,)).lower(
+        hc, stack, stack).compile()
+    text = compiled.as_text()
+    for kernel in ("mv_hc_stats", "mv_hc_write", "mv_hc_weighted",
+                   "mv_hc_sums", "mv_hc_dx", "mv_hc_sinkhorn",
+                   "mv_hc_sinkhorn_pull"):
+        assert kernel in text, kernel
+    # no copy of a sequence's streams (235 MB) or of a stack
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
