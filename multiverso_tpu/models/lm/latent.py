@@ -7,8 +7,9 @@ the third family's layer (streams.py): for a sublayer's normed input
     [q_n | q_r]  = c_q W_qb, a head                      [nope | rope]
     [c_kv | k_r] = h W_kva                               [kv_lora_rank | rope]
     [k_n | v]    = RMSNorm(c_kv; g_kva) W_kvb, a head    [nope | v]
-    q_r, k_r     = rotary(q_r), rotary(k_r)   YaRN's frequencies; ``k_r`` is
-                                              one for all heads
+    q_r, k_r     = rotary(q_r), rotary(k_r)   YaRN's frequencies
+                                              (``model.yarn_frequencies``);
+                                              ``k_r`` is one for all heads
     score        = (q_n . k_n + q_r . k_r) (nope + rope)^-0.5 m^2, causal,
                    m = 0.1 mscale_all_dim ln(factor) + 1
     F(h)         = softmax(score) v, the heads side by side, W_o
@@ -37,33 +38,13 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from . import model as lm
 from .model import LMConfig
 
 SCOPE = "mv.lm.attn.mla"
-MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+MATRICES = lm.MLA_MATRICES
 NORMS = ("norm_attn", "norm_q_a", "norm_kv_a")
-
-
-def yarn_frequencies(cfg: LMConfig) -> np.ndarray:
-    """The rotary pairs' frequencies under YaRN [rope / 2]: ``theta``'s own
-    where a pair turns more than ``beta_fast`` times over the original
-    context, divided by ``factor`` where fewer than ``beta_slow``, a
-    linear ramp between."""
-    factor, fast, slow, original = cfg.yarn[:4]
-    d = cfg.qk_rope_dim
-    own = 1.0 / cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
-
-    def pair_of(turns):     # the pair that makes ``turns`` over the context
-        return d * math.log(original / (turns * 2 * math.pi)) / (
-            2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(pair_of(fast)), 0)
-    high = min(math.ceil(pair_of(slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return own / factor * ramp + own * (1 - ramp)
 
 
 def softmax_scale(cfg: LMConfig) -> float:
@@ -91,7 +72,7 @@ def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None):
     c_kv = lm.rmsnorm(kv_a[:, :latent], g_kv, cfg.eps)
     kv = lm.mm(c_kv, mats["wkv_b"], sinks["wkv_b"]).reshape(
         t, heads, nope + cfg.v_head_dim)
-    inv = yarn_frequencies(cfg)
+    inv = lm.yarn_frequencies(cfg.rope_theta, rope, *cfg.yarn[:4])
     q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
     k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
     q = jnp.concatenate([q[..., :nope], q_r], -1) * softmax_scale(cfg)

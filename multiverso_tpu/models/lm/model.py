@@ -48,9 +48,50 @@ mechanisms live in modules of their own:
       gradient: the trainer pushes ``bias_rate sign(mean load - load)``
     a multi-token module after the last layer   ``mtp_layers``, mtp.py
 
+**A fourth family** (``from_dict`` tells it by
+``num_attention_heads_per_layer``: the block of ``model_type: laguna``,
+poolside's Laguna-XS.2, 2026) recombines what the three above have, on
+the plain residual, for layer ``l``:
+
+    H_l = heads(l) query heads over n_kv_heads    ``heads_layout``: 48 on
+      key-value heads; q = h W_q [H_l, d]           full layers, 64 on window
+                                                    ones; ``wq``, ``wo`` and
+                                                    the gate differ by layer
+    q, k turned by the layer KIND's ``Rotary``    ``rotary_kinds`` (full,
+      (theta, the first ``lanes`` lanes turned,     window): YaRN on half a
+      YaRN's blend, a factor on cos and sin)        head's lanes with the
+                                                    factor | plain on all
+    o_i = softmax(q_i . k d^-0.5) v under the     ``Mask`` causal | window
+      kind's mask
+    o_i <- sigmoid(h W_g)_i o_i                   ``attn_gate: "head"``,
+                                                    W_g [hidden, H_l]
+    a = x + concat(o) W_o
+    y = a + F(RMSNorm(a)), F dense or             ``ffn_layout``,
+      sum_{e in S, e held} w_e E_e + E_shared;      ``router_input:
+      s = sigmoid(h W_r), S the top-k of s,         "ffn_input"``,
+      w_e = routed_scale s_e / sum_S s              ``scoring: "sigmoid"``:
+                                                    no bias, no bias table
+
+**A layer is described by three independent kinds**, and each selects
+functions, not a family's branch: its ATTENTION (``attention``: ``gqa`` ->
+``attention_inputs`` / ``attention_core`` / ``attention_gate`` /
+``attention_output`` under ``attention_vjp``, with ``heads_layout``,
+``rotary_kinds``, ``attn_gate``, ``qk_norm``; ``mla`` -> latent.py), its
+FEED-FORWARD (``ffn_layout``, ``dense_width``, ``shared_width``,
+``scoring``, ``routed_scale`` -> ``feed_forward_vjp``: ``dense_vjp`` |
+``sparse_vjp`` on ONE normed input, for every layer of ``router_input:
+"ffn_input"`` whatever its residual) and its RESIDUAL (``plain`` ->
+``layer_vjp`` adds the two sublayers' results; ``mhc`` ->
+streams.layer_vjp writes them into the streams). ``layer_shapes`` and
+``matrices`` are built from the kinds. The first two families' router and
+experts each norm their own input (``router_input: "input" |
+"ffn_norm"``: ``_route_layer``, ``experts_block``), kept to the operation
+so that their programs lower to the text they had
+(tests/test_lm_mixed.py).
+
 ``route``, ``routed_experts`` (what ``experts_block`` is around),
-``gated_mlp`` (dense MLP and shared expert) and the head are one code path
-for all three.
+``gated_mlp`` (dense MLP and shared expert), ``yarn_frequencies`` and the
+head are one code path for all four.
 
 **Two objectives** (``LMConfig.objective``). ``next_token``: causal or
 window masks, the loss the mean cross entropy of the next token.
@@ -104,8 +145,10 @@ rotary, output projection) with the attention proper under
 ``<scope>.kernel``, ``mv.lm.experts``, ``mv.lm.head``; ``mv.lm.noise``
 is the trainer's (``noise`` runs in its batch-preparation program). The
 third family's: ``mv.lm.attn.mla`` (+ ``.kernel``), ``mv.lm.hc``,
-``mv.lm.shared_expert``, ``mv.lm.dense_mlp``, ``mv.lm.mtp`` (+ ``.head``),
-and its backward programs' sums over the sequences ``mv.lm.grad_sum``.
+``mv.lm.shared_expert``, ``mv.lm.dense_mlp`` (the fourth's too),
+``mv.lm.mtp`` (+ ``.head``), and its backward programs' sums over the
+sequences ``mv.lm.grad_sum``. The fourth's gate, its product, sigmoid and
+multiply, forward and backward: ``mv.lm.attn.gate``.
 """
 
 from __future__ import annotations
@@ -124,11 +167,41 @@ from ...util.log import CHECK
 BF16 = jnp.bfloat16
 F32 = jnp.float32
 
-#: The tensors of a layer that are matrices (pulled as bfloat16 copies,
-#: their gradients float32) and the small ones kept in float32.
-LAYER_MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+#: A layer's tensors by the kind that brings them. The matrices are pulled
+#: as bfloat16 copies and their gradients are float32; the rest is small and
+#: kept in float32.
+GQA_MATRICES = ("wq", "wk", "wv", "wo")
+ATTN_GATE = "w_attn_gate"           # with ``LMConfig.attn_gate == "head"``
+MLA_MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+DENSE = ("w_gate", "w_up", "w_down")    # a dense MLP's, or the routed
+#                                     experts' stacked by expert
+SHARED = ("ws_gate", "ws_up", "ws_down")
+LAYER_MATRICES = GQA_MATRICES + DENSE   # the first two families' layer
 LAYER_SMALL = ("router", "norm_attn", "norm_ffn")
 QK_NORMS = ("norm_q", "norm_k")     # with ``LMConfig.qk_norm``, float32 too
+
+
+@dataclasses.dataclass(frozen=True, order=True)
+class Rotary:
+    """The rotary positions of one kind of layer: ``theta``'s frequencies
+    over the FIRST ``lanes`` lanes of a head (the others pass as they
+    are); with ``yarn`` = (factor, beta_fast, beta_slow, original
+    positions) blended as ``yarn_frequencies`` does; cos and sin times
+    ``factor`` (YaRN's attention factor, which a score's rotated part
+    then carries squared)."""
+    theta: float
+    lanes: int
+    yarn: Tuple[float, ...] = ()
+    factor: float = 1.0
+
+    def how(self) -> dict:
+        """What ``_rotary`` takes beside ``theta`` and the positions."""
+        how = {"lanes": self.lanes}
+        if self.yarn:
+            how["inv"] = yarn_frequencies(self.theta, self.lanes, *self.yarn)
+        if self.factor != 1.0:
+            how["factor"] = self.factor
+        return how
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,14 +223,25 @@ class LMConfig:
     loss_block: int = 2048          # tokens a block of the head's loss
     activation: str = "relu"        # the experts': "relu" | "silu"
     router_input: str = "input"     # "input": the layer's raw input;
-    #                                 "ffn_norm": the normed post-attention
-    #                                 stream that feeds the experts
+    #                                 "ffn_norm": the post-attention stream
+    #                                 through the experts' norm, applied by
+    #                                 the router itself; "ffn_input": the
+    #                                 feed-forward's ONE normed input, which
+    #                                 router, experts and shared expert all
+    #                                 read (``feed_forward_vjp``)
     qk_norm: bool = False           # per-head RMSNorm of q and k
     objective: str = "next_token"   # | "block_diffusion"
     block_length: int = 0           # block diffusion: positions a block
     t_min: float = 0.0              # block diffusion: t ~ U(t_min, 1]
-    # -- the third family's kinds (DeepSeek-V3's block as Xing4.0 has it) --
+    # -- a layer by three independent kinds: its attention, ... -------------
     attention: str = "gqa"          # | "mla": latent attention (latent.py)
+    heads_layout: Tuple[int, ...] = ()  # gqa, per layer: its query heads;
+    #                                 (): ``n_heads`` in every layer
+    rotary_kinds: Tuple[Rotary, ...] = ()   # gqa: (the full layers', the
+    #                                 window layers'); (): ``rope_theta``
+    #                                 over every lane
+    attn_gate: str = "none"         # | "head": each head's output times
+    #                                 sigmoid(h W_g), W_g [hidden, heads]
     heads_held: Tuple[int, int] = (0, 0)    # mla: (first, count) of the
     #                                 n_heads held here; (0, 0): all
     q_lora_rank: int = 0            # mla: the five latent sizes
@@ -167,14 +251,18 @@ class LMConfig:
     v_head_dim: int = 0
     yarn: Tuple[float, ...] = ()    # (factor, beta_fast, beta_slow, original
     #                                 positions, mscale, mscale_all_dim)
+    # -- ... its feed-forward, ... -------------------------------------------
     ffn_layout: Tuple[int, ...] = ()    # per layer: 1 sparse, 0 dense MLP;
     #                                 (): every layer sparse
     dense_width: int = 0
     shared_width: int = 0           # the shared expert's, 0: none
-    scoring: str = "softmax"        # | "sigmoid_bias": chosen by score +
-    #                                 bias, weighed by score alone
+    scoring: str = "softmax"        # | "sigmoid": chosen and weighed by
+    #                                 score | "sigmoid_bias": chosen by score
+    #                                 + a bias the server keeps, weighed by
+    #                                 score alone
     routed_scale: float = 1.0
     bias_rate: float = 0.0          # the bias's step after each step
+    # -- ... and its residual ------------------------------------------------
     residual: str = "plain"         # | "mhc": hc_mult streams (streams.py)
     hc_mult: int = 1
     hc_iters: int = 0               # Sinkhorn rounds
@@ -199,22 +287,46 @@ class LMConfig:
     def sparse(self, layer: int) -> int:
         return self.ffn_layout[layer] if self.ffn_layout else 1
 
+    def heads(self, layer: int) -> int:
+        """The layer's query heads (grouped-query attention)."""
+        return self.heads_layout[layer] if self.heads_layout \
+            else self.n_heads
+
+    def rotary(self, rope, window):
+        """What a layer of this kind hands ``attention_inputs`` as its
+        rotary positions: its ``Rotary``, or where the model has one kind
+        whether the layer is rotary at all."""
+        if rope and self.rotary_kinds:
+            return self.rotary_kinds[bool(window)]
+        return bool(rope)
+
+    @property
+    def one_ffn_input(self) -> bool:
+        """Whether router, experts and shared expert (or the dense MLP)
+        read one normed input: ``feed_forward_vjp``."""
+        return self.router_input == "ffn_input"
+
     def layer_kinds(self) -> Tuple[tuple, ...]:
         """Per layer what its two programs are built from: ``(rotary,
-        window)``, and with an ``ffn_layout`` ``(rotary, window, sparse)``."""
+        window)``; with an ``ffn_layout`` ``(rotary, window, sparse)``; and
+        with a ``heads_layout`` the layer's query heads fourth."""
         kinds = tuple(zip(self.rope_layout, self.window_layout))
-        if not self.ffn_layout:
-            return kinds
-        return tuple(k + (s,) for k, s in zip(kinds, self.ffn_layout))
+        if self.ffn_layout or self.heads_layout:
+            kinds = tuple(k + (self.sparse(i),) for i, k in enumerate(kinds))
+        if self.heads_layout:
+            kinds = tuple(k + (h,) for k, h in zip(kinds, self.heads_layout))
+        return kinds
 
     def matrices(self, layer: int = 0) -> Tuple[str, ...]:
-        """The layer's tensors pulled as bfloat16 copies, by name."""
-        if self.attention != "mla":
-            return LAYER_MATRICES
-        ffn = ("w_gate", "w_up", "w_down")
-        if self.sparse(layer) and self.shared_width:
-            ffn += ("ws_gate", "ws_up", "ws_down")
-        return ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo") + ffn
+        """The layer's tensors pulled as bfloat16 copies, by name: its
+        attention's, then its feed-forward's."""
+        if self.attention == "mla":
+            attention = MLA_MATRICES
+        else:
+            attention = GQA_MATRICES + (
+                (ATTN_GATE,) if self.attn_gate == "head" else ())
+        shared = self.sparse(layer) and self.shared_width
+        return attention + DENSE + (SHARED if shared else ())
 
     @property
     def mask_id(self) -> int:
@@ -237,6 +349,8 @@ class LMConfig:
         ``router_outputs`` the published number the router still has."""
         if "kv_lora_rank" in c:
             return cls._from_mla(c)
+        if "num_attention_heads_per_layer" in c:
+            return cls._from_laguna(c)
         if "num_experts" in c:
             return cls._from_qwen3_moe(c)
         n = int(c["num_hidden_layers"])
@@ -355,59 +469,120 @@ class LMConfig:
             mtp_layers=int(c["num_nextn_predict_layers"]),
             mtp_weight=float(c["mtp_loss_weight"]))
 
-    def layer_shapes(self, layer: int = 0) -> dict:
-        """Every tensor of one layer as the server stores it: a matrix
-        table's (rows, columns) or a norm's (size,). The experts' three
-        are stacked by expert along the rows. The first two families'
-        layers are all alike; the third's are ``_mla_layer_shapes``."""
-        if self.attention == "mla":
-            return self._mla_layer_shapes(self.sparse(layer))
-        h, e, w = self.hidden, self.experts_held[1], self.expert_width
-        shapes = {
-            "wq": (h, self.n_heads * self.head_dim),
-            "wk": (h, self.n_kv_heads * self.head_dim),
-            "wv": (h, self.n_kv_heads * self.head_dim),
-            "wo": (self.n_heads * self.head_dim, h),
-            "router": (h, self.n_experts),
-            "norm_attn": (h,), "norm_ffn": (h,),
-            "w_gate": (e * h, w), "w_up": (e * h, w), "w_down": (e * w, h)}
-        if self.qk_norm:
-            shapes.update({n: (self.head_dim,) for n in QK_NORMS})
-        return shapes
+    @classmethod
+    def _from_laguna(cls, c: dict) -> "LMConfig":
+        """The block of ``model_type: laguna`` (poolside's Laguna-XS.2,
+        benchmark/configs/laguna-xs2-33b-a3b-l5.json): grouped-query
+        attention whose layers are of two kinds (``layer_types``), each
+        with its own query heads (``num_attention_heads_per_layer``) and
+        its own rotary positions (``rope_parameters`` by kind), a per-head
+        output gate (``gating``); ``mlp_layer_types`` says which layers are
+        dense; the sparse ones score by sigmoid with no bias and have a
+        shared expert. ``num_experts`` gives the experts HELD and
+        ``router_outputs`` the published number."""
+        n = int(c["num_hidden_layers"])
+        kinds = {"full_attention": 0, "sliding_attention": 1}
+        CHECK(c["gating"] and not c["moe_apply_router_weight_on_input"]
+              and not c["attention_bias"],
+              "only the block with the output gate, the router's weights on "
+              "the experts' outputs and no attention bias is written down "
+              "here")
+        d = int(c["head_dim"])
 
-    def _mla_layer_shapes(self, sparse: int) -> dict:
-        """The third family's layer: latent attention over the held heads
-        (``wq_b``, ``wkv_b``, ``wo`` cut by head; each head's columns lie
-        together, ``[nope | rope]`` and ``[k nope | v]``), the two
-        sublayers' stream mixers (``phi`` stored a coefficient a row,
-        ``[2n + n^2, n hidden]``; ``a`` the three scalars), and a dense MLP
-        or router, bias, held experts and shared expert."""
-        h, heads, n = self.hidden, self.n_heads_held, self.hc_mult
-        coefficients = 2 * n + n * n
-        shapes = {
-            "wq_a": (h, self.q_lora_rank), "norm_q_a": (self.q_lora_rank,),
-            "wq_b": (self.q_lora_rank, heads * self.head_dim),
-            "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
-            "norm_kv_a": (self.kv_lora_rank,),
-            "wkv_b": (self.kv_lora_rank,
-                      heads * (self.qk_nope_dim + self.v_head_dim)),
-            "wo": (heads * self.v_head_dim, h),
-            "norm_attn": (h,), "norm_ffn": (h,)}
-        for sub in ("hc_attn", "hc_ffn"):
-            shapes.update({f"{sub}_phi": (coefficients, n * h),
-                           f"{sub}_b": (coefficients,), f"{sub}_a": (3,)})
-        if not sparse:
+        def rotary(p):
+            yarn = p["rope_type"] == "yarn"
+            CHECK(yarn or p["rope_type"] == "default",
+                  f"unknown rope_type {p['rope_type']!r}")
+            return Rotary(
+                theta=float(p["rope_theta"]),
+                lanes=int(d * float(p.get("partial_rotary_factor", 1))),
+                yarn=(float(p["factor"]), float(p["beta_fast"]),
+                      float(p["beta_slow"]),
+                      float(p["original_max_position_embeddings"]))
+                if yarn else (),
+                factor=float(p.get("attention_factor", 1.0)) if yarn else 1.0)
+
+        by_kind = tuple(rotary(c["rope_parameters"][k]) for k in kinds)
+        return cls(
+            hidden=int(c["hidden_size"]),
+            n_heads=int(c["num_attention_heads"]),
+            n_kv_heads=int(c["num_key_value_heads"]), head_dim=d,
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_tok"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["num_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(1,) * n,
+            window_layout=tuple(kinds[k] for k in c["layer_types"][:n]),
+            window=int(c["sliding_window"]),
+            rope_theta=by_kind[0].theta, eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c.get("hidden_act", "silu")),
+            router_input="ffn_input",
+            heads_layout=tuple(
+                int(h) for h in c["num_attention_heads_per_layer"][:n]),
+            rotary_kinds=by_kind, attn_gate="head",
+            ffn_layout=tuple(int(k == "sparse")
+                             for k in c["mlp_layer_types"][:n]),
+            dense_width=int(c["intermediate_size"]),
+            shared_width=int(c["shared_expert_intermediate_size"]),
+            scoring="sigmoid",
+            routed_scale=float(c["moe_routed_scaling_factor"]))
+
+    def layer_shapes(self, layer: int = 0) -> dict:
+        """Every tensor of one layer as the server stores it, built from
+        the layer's kinds: a matrix table's (rows, columns) or a small
+        tensor's (size,). In order: the attention's, the two norms, the
+        stream mixers' (``residual: "mhc"``), the feed-forward's, the q
+        and k norms. The experts' three are stacked by expert along the
+        rows."""
+        h, d = self.hidden, self.head_dim
+        if self.attention == "mla":
+            # over the held heads: ``wq_b``, ``wkv_b``, ``wo`` cut by head,
+            # each head's columns together, ``[nope | rope]``, ``[k nope | v]``
+            heads = self.n_heads_held
+            shapes = {
+                "wq_a": (h, self.q_lora_rank),
+                "norm_q_a": (self.q_lora_rank,),
+                "wq_b": (self.q_lora_rank, heads * d),
+                "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
+                "norm_kv_a": (self.kv_lora_rank,),
+                "wkv_b": (self.kv_lora_rank,
+                          heads * (self.qk_nope_dim + self.v_head_dim)),
+                "wo": (heads * self.v_head_dim, h)}
+        else:
+            heads = self.heads(layer)
+            shapes = {"wq": (h, heads * d), "wk": (h, self.n_kv_heads * d),
+                      "wv": (h, self.n_kv_heads * d), "wo": (heads * d, h)}
+            if self.attn_gate == "head":
+                shapes[ATTN_GATE] = (h, heads)
+        shapes.update({"norm_attn": (h,), "norm_ffn": (h,)})
+        if self.residual == "mhc":
+            # a sublayer's ``phi`` a coefficient a row, ``a`` three scalars
+            n = self.hc_mult
+            coefficients = 2 * n + n * n
+            for sub in ("hc_attn", "hc_ffn"):
+                shapes.update({f"{sub}_phi": (coefficients, n * h),
+                               f"{sub}_b": (coefficients,),
+                               f"{sub}_a": (3,)})
+        if not self.sparse(layer):
             w = self.dense_width
             shapes.update({"w_gate": (h, w), "w_up": (h, w),
                            "w_down": (w, h)})
-            return shapes
-        e, w, s = self.experts_held[1], self.expert_width, self.shared_width
-        shapes.update({
-            "router": (h, self.n_experts), "router_bias": (self.n_experts,),
-            "w_gate": (e * h, w), "w_up": (e * h, w), "w_down": (e * w, h)})
-        if s:
-            shapes.update({"ws_gate": (h, s), "ws_up": (h, s),
-                           "ws_down": (s, h)})
+        else:
+            e, w = self.experts_held[1], self.expert_width
+            shapes["router"] = (h, self.n_experts)
+            if self.scoring == "sigmoid_bias":
+                shapes["router_bias"] = (self.n_experts,)
+            shapes.update({"w_gate": (e * h, w), "w_up": (e * h, w),
+                           "w_down": (e * w, h)})
+            if self.shared_width:
+                s = self.shared_width
+                shapes.update({"ws_gate": (h, s), "ws_up": (h, s),
+                               "ws_down": (s, h)})
+        if self.qk_norm:
+            shapes.update({n: (d,) for n in QK_NORMS})
         return shapes
 
     def mtp_shapes(self) -> dict:
@@ -423,7 +598,7 @@ class LMConfig:
             return sum(int(np.prod(s)) for s in shapes.values())
         layers = sum(size(self.layer_shapes(i)) for i in range(self.n_layers))
         module = self.mtp_layers * (size(self.mtp_shapes()) + size(
-            self._mla_layer_shapes(1))) if self.mtp_layers else 0
+            self.layer_shapes(self.n_layers - 1))) if self.mtp_layers else 0
         return layers + module + 2 * self.vocab * self.hidden + self.hidden
 
 
@@ -647,20 +822,46 @@ class Mask:
         return np.arange(t)
 
 
-def _rotary(x, theta, pos=None, inv=None):
+def yarn_frequencies(theta: float, lanes: int, factor: float, fast: float,
+                     slow: float, original: float) -> np.ndarray:
+    """The rotary pairs' frequencies under YaRN [lanes / 2]: ``theta``'s
+    own where a pair turns more than ``fast`` times over the ``original``
+    context, divided by ``factor`` where fewer than ``slow``, a linear
+    ramp between."""
+    d = lanes
+    own = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def pair_of(turns):     # the pair that makes ``turns`` over the context
+        return d * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_of(fast)), 0)
+    high = min(math.ceil(pair_of(slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return own / factor * ramp + own * (1 - ramp)
+
+
+def _rotary(x, theta, pos=None, inv=None, lanes=None, factor=1.0):
     """Rotary positions on [T, heads, d] (the halves paired, as the
     published model's ``rotate_half``), float32. ``pos`` [T] gives each
-    row's position (``arange(T)`` when None); ``inv`` [d / 2] the pairs'
-    frequencies where they are not ``theta``'s own (YaRN's)."""
+    row's position (``arange(T)`` when None); ``inv`` the pairs'
+    frequencies where they are not ``theta``'s own (YaRN's); ``lanes``
+    how many of a head's FIRST lanes are turned (all when None; the
+    others pass as they are, bit for bit); ``factor`` multiplies cos and
+    sin."""
     t, _, d = x.shape
+    lanes = d if lanes is None else lanes
     if inv is None:
-        inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+        inv = 1.0 / theta ** (np.arange(0, lanes, 2, dtype=np.float64)
+                              / lanes)
     pos = np.arange(t) if pos is None else np.asarray(pos)
     angle = pos.astype(np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(angle), F32)[:, None, :]
-    sin = jnp.asarray(np.sin(angle), F32)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    cos = jnp.asarray(factor * np.cos(angle), F32)[:, None, :]
+    sin = jnp.asarray(factor * np.sin(angle), F32)[:, None, :]
+    x1, x2 = x[..., :lanes // 2], x[..., lanes // 2:lanes]
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    return jnp.concatenate(turned + ([x[..., lanes:]] if lanes < d else []),
+                           -1)
 
 
 def visible(i, j, mask):
@@ -760,37 +961,57 @@ def attention_core(q, k, v, mask):
     return blockwise_attention(q, k, v, mask)
 
 
-def attention_inputs(cfg: LMConfig, rope: bool, mats, sinks, norms, x,
-                     pos=None):
+def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     """Norm, the three projections, the heads' q and k norms
-    (``cfg.qk_norm``), rotary positions (``pos``, ``arange(T)`` when
-    None), the scale: ``(q, k, v)`` laid out for ``attention_core``.
-    ``norms`` is the attention norm's scale, or with ``cfg.qk_norm`` the
-    three ``(norm_attn, norm_q, norm_k)``."""
+    (``cfg.qk_norm``), rotary positions (``rope``: a ``Rotary``, or
+    whether ``cfg.rope_theta`` turns every lane; at ``pos``, ``arange(T)``
+    when None), the scale: ``(q, k, v)`` laid out for ``attention_core``,
+    and with ``cfg.attn_gate`` the normed input fourth (the gate reads
+    it). The layer's query heads are its ``wq``'s. ``norms`` is the
+    attention norm's scale, or with ``cfg.qk_norm`` the three
+    ``(norm_attn, norm_q, norm_k)``."""
     t = x.shape[0]
-    g, per = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    heads = mats["wq"].shape[1] // cfg.head_dim
+    g, per = cfg.n_kv_heads, heads // cfg.n_kv_heads
     norm, *qk = norms if cfg.qk_norm else (norms,)
     h = rmsnorm(x, norm, cfg.eps)
-    q = mm(h, mats["wq"], sinks["wq"]).reshape(t, cfg.n_heads, cfg.head_dim)
+    q = mm(h, mats["wq"], sinks["wq"]).reshape(t, heads, cfg.head_dim)
     k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, cfg.head_dim)
     v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, cfg.head_dim)
     if qk:      # over a head's lanes: each head normed alone
         q, k = rmsnorm(q, qk[0], cfg.eps), rmsnorm(k, qk[1], cfg.eps)
     if rope:    # positions go only where given: ``_rotary``'s short form
         at = () if pos is None else (pos,)
-        q = _rotary(q, cfg.rope_theta, *at)
-        k = _rotary(k, cfg.rope_theta, *at)
+        theta, how = (rope.theta, rope.how()) if isinstance(rope, Rotary) \
+            else (cfg.rope_theta, {})
+        q = _rotary(q, theta, *at, **how)
+        k = _rotary(k, theta, *at, **how)
     q = (q * (1.0 / math.sqrt(cfg.head_dim))).astype(BF16)
     # query head i reads key-value head i // per
     q = q.reshape(t, g, per, cfg.head_dim).transpose(1, 2, 0, 3)
-    return q, k.astype(BF16).transpose(1, 0, 2), v.astype(BF16).transpose(
-        1, 0, 2)
+    qkv = (q, k.astype(BF16).transpose(1, 0, 2),
+           v.astype(BF16).transpose(1, 0, 2))
+    return qkv + (h,) if cfg.attn_gate == "head" else qkv
+
+
+GATE_SCOPE = "mv.lm.attn.gate"
+
+
+def attention_gate(mats, sinks, h, o):
+    """Each head's output times its gate ``sigmoid(h W_g)`` (``W_g``
+    [hidden, heads], a logit a head from the layer's normed input ``h``):
+    ``(o [groups, per group, T, d] gated, the gates' sum over tokens and
+    heads)``."""
+    groups, per, t, _ = o.shape
+    gate = jax.nn.sigmoid(mm(h, mats[ATTN_GATE], sinks[ATTN_GATE]))
+    by_head = gate.reshape(t, groups, per).transpose(1, 2, 0)[..., None]
+    return (o.astype(F32) * by_head).astype(BF16), jnp.sum(gate)
 
 
 def attention_output(cfg: LMConfig, mats, sinks, x, o):
     """``x`` plus the heads' outputs through the output projection."""
     t = x.shape[0]
-    o = o.transpose(2, 0, 1, 3).reshape(t, cfg.n_heads * cfg.head_dim)
+    o = o.transpose(2, 0, 1, 3).reshape(t, mats["wo"].shape[0])
     return x + mm(o, mats["wo"], sinks["wo"])
 
 
@@ -805,9 +1026,12 @@ def _attention_norms(cfg: LMConfig, small):
 def attention_block(cfg: LMConfig, rope: bool, mask, mats, sinks,
                     norms, x, pos=None):
     """``x + Attn(RMSNorm(x))`` for one sequence ``x`` [T, hidden]."""
-    q, k, v = attention_inputs(cfg, rope, mats, sinks, norms, x, pos)
+    q, k, v, *h = attention_inputs(cfg, rope, mats, sinks, norms, x, pos)
     with jax.named_scope(Mask.of(mask).scope + ".kernel"):
         o = attention_core(q, k, v, mask)
+    if h:
+        with jax.named_scope(GATE_SCOPE):
+            o, _ = attention_gate(mats, sinks, h[0], o)
     return attention_output(cfg, mats, sinks, x, o)
 
 
@@ -816,14 +1040,17 @@ def attention_block(cfg: LMConfig, rope: bool, mask, mats, sinks,
 def route(cfg: LMConfig, router, x, bias=None):
     """The top-k experts of each token and their weights, normalised
     over the k: ``(ids [T, k] int32, weights [T, k] float32)``. Under
-    ``cfg.scoring == "sigmoid_bias"`` a score is the sigmoid of its logit,
-    the k are the largest of score + ``bias`` (which gets no gradient)
-    and the weights ``routed_scale`` times the chosen SCORES over their
-    sum."""
+    ``cfg.scoring == "sigmoid"`` a score is the sigmoid of its logit, the
+    k are the largest scores and the weights ``routed_scale`` times the
+    chosen scores over their sum; under ``"sigmoid_bias"`` the k are the
+    largest of score + ``bias`` (which gets no gradient) and the weights
+    still the chosen SCORES'."""
     logits = jnp.dot(x.astype(F32), router, precision="highest")
     if cfg.scoring == "sigmoid_bias":
         p = jax.nn.sigmoid(logits)
         chosen_by = jax.lax.stop_gradient(p + bias)
+    elif cfg.scoring == "sigmoid":
+        p = chosen_by = jax.nn.sigmoid(logits)
     else:
         p = chosen_by = jax.nn.softmax(logits, axis=-1)
     ids = jax.lax.top_k(chosen_by, cfg.top_k)[1].astype(jnp.int32)
@@ -969,6 +1196,98 @@ def gated_mlp(cfg: LMConfig, mats, sinks, names, h):
     return mm(act, mats[down], sinks[down])
 
 
+# -- the feed-forward on ONE normed input, with its pull --------------------------
+
+def dense_vjp(cfg: LMConfig, mats, sinks, small, u):
+    with jax.named_scope("mv.lm.dense_mlp"):
+        v, pull_mlp = jax.vjp(
+            lambda s, g, u: gated_mlp(cfg, mats, s, DENSE,
+                                      rmsnorm(u, g, cfg.eps)),
+            {n: sinks[n] for n in DENSE}, small["norm_ffn"], u)
+
+    def pull(dv):
+        with jax.named_scope("mv.lm.dense_mlp"):
+            d_mats, d_norm, du = pull_mlp(dv)
+        return du, (d_mats, {"norm_ffn": d_norm})
+
+    return v, None, pull
+
+
+def sparse_vjp(cfg: LMConfig, mats, sinks, small, u):
+    """Router, held routed experts and shared expert on one normed ``h``:
+    ``aux`` is ``(ids [T, k], held experts' assignments [held], every
+    router output's assignments [n_experts])``."""
+    routed = {n: sinks[n] for n in DENSE}
+    with jax.named_scope("mv.lm.experts"):
+        h, pull_norm = jax.vjp(lambda g, u: rmsnorm(u, g, cfg.eps),
+                               small["norm_ffn"], u)
+    with jax.named_scope("mv.lm.router"):
+        weights, pull_router, ids = jax.vjp(
+            lambda r, h: route(cfg, r, h, small.get("router_bias"))[::-1],
+            small["router"], h, has_aux=True)
+        load = router_load(cfg, ids)
+    with jax.named_scope("mv.lm.experts"):
+        y, pull_experts, sizes = jax.vjp(
+            lambda s, h, w: routed_experts(cfg, mats, s, h.astype(BF16),
+                                           ids, w),
+            routed, h, weights, has_aux=True)
+    pull_shared = None
+    if cfg.shared_width:
+        with jax.named_scope("mv.lm.shared_expert"):
+            shared, pull_shared = jax.vjp(
+                lambda s, h: gated_mlp(cfg, mats, s, SHARED, h),
+                {n: sinks[n] for n in SHARED}, h)
+        y = y + shared
+
+    def pull(dy):
+        with jax.named_scope("mv.lm.experts"):
+            d_mats, dh, dw = pull_experts(dy)
+        with jax.named_scope("mv.lm.router"):
+            d_router, dh_router = pull_router(dw)
+        dh = dh + dh_router
+        if pull_shared is not None:
+            with jax.named_scope("mv.lm.shared_expert"):
+                d_shared, dh_shared = pull_shared(dy)
+            d_mats, dh = {**d_mats, **d_shared}, dh + dh_shared
+        with jax.named_scope("mv.lm.experts"):
+            d_norm, du = pull_norm(dh)
+        return du, (d_mats, {"norm_ffn": d_norm, "router": d_router})
+
+    return y, (ids, sizes, load), pull
+
+
+def feed_forward_vjp(cfg: LMConfig, sparse: int, mats, sinks, small, u):
+    """A layer's feed-forward ``F(RMSNorm(u))`` for one sequence ``u`` [T,
+    hidden], of the layer's kind (``sparse``: router, held routed experts
+    and shared expert; else the dense MLP), and what pulls a cotangent
+    back through it: ``(v, aux, pull)``, ``pull(dv) -> (du, (matrix
+    gradients, small gradients))``. The residual is the caller's: the
+    plain one adds ``v`` (``layer_vjp``), the streams write it
+    (streams.sublayer_vjp)."""
+    return (sparse_vjp if sparse else dense_vjp)(cfg, mats, sinks, small, u)
+
+
+def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None):
+    """What a forward program reports of one sequence through a layer
+    whose feed-forward is ``feed_forward_vjp``'s: ``(stats, ids)``. A
+    sparse layer's ``stats`` int32 [2 + n_experts]: assignments on held
+    experts, the fullest held expert's, then every router output's; a
+    dense layer's two zeros and no ids. With a gate its ``gate_open``
+    (the gates' sum over heads of their mean over tokens) comes last, in
+    thousandths."""
+    if sparse:
+        ids, sizes, load = aux
+        stats = jnp.concatenate(
+            [jnp.stack([jnp.sum(sizes), jnp.max(sizes)]), load])
+    else:
+        stats = jnp.zeros((2,), jnp.int32)
+        ids = jnp.zeros((0, cfg.top_k), jnp.int32)
+    if gate_open is not None:
+        stats = jnp.concatenate(
+            [stats, jnp.round(1e3 * gate_open)[None].astype(jnp.int32)])
+    return stats, ids
+
+
 # -- a layer, forward and with its gradients -----------------------------------
 
 def _zeros_like_f32(mats):
@@ -984,12 +1303,90 @@ def _route_layer(cfg: LMConfig, router, norm_ffn, stream):
     return route(cfg, router, stream)
 
 
-def layer_forward(cfg: LMConfig, rope: bool, mask, mats, small, x, pos=None):
+def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
+                  pos=None):
+    """``a = x + Attn(RMSNorm(x))`` for one sequence and what pulls a
+    cotangent back through it: ``(a, gate_open, pull)``, ``pull(da) ->
+    (dx, matrix gradients, small gradients)``; ``gate_open`` is None
+    without a gate. A scope names a backward pass only where it is
+    entered OUTSIDE the differentiated function (inside, JAX writes it as
+    transpose(jvp(..)), which no reader takes for a scope): so the
+    attention's parts are differentiated one by one, the kernel and the
+    gate under their own names."""
+    scope = Mask.of(mask).scope
+    qkv = {n: sinks[n] for n in ("wq", "wk", "wv")}
+    with jax.named_scope(scope):
+        (q, k, v, *h), pull_inputs = jax.vjp(
+            lambda s, norms, x: attention_inputs(cfg, rope, mats, s, norms,
+                                                 x, pos),
+            qkv, _attention_norms(cfg, small), x)
+    with jax.named_scope(scope + ".kernel"):
+        o, pull_core = jax.vjp(
+            lambda q, k, v: attention_core(q, k, v, mask), q, k, v)
+    gate_open = pull_gate = None
+    if h:
+        with jax.named_scope(GATE_SCOPE):
+            o, pull_gate, gate_open = jax.vjp(
+                lambda s, h, o: attention_gate(mats, {ATTN_GATE: s}, h, o),
+                sinks[ATTN_GATE], h[0], o, has_aux=True)
+        gate_open = gate_open / x.shape[0]
+    with jax.named_scope(scope):
+        a, pull_output = jax.vjp(
+            lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),
+            sinks["wo"], x, o)
+
+    def pull(da):
+        with jax.named_scope(scope):
+            d_wo, dx, do = pull_output(da)
+        dh = ()
+        if pull_gate is not None:
+            with jax.named_scope(GATE_SCOPE):
+                d_gate, *dh, do = pull_gate(do)
+        with jax.named_scope(scope + ".kernel"):
+            d_qkv = pull_core(do)
+        with jax.named_scope(scope):
+            d_attn, d_norms, dx_inputs = pull_inputs(d_qkv + tuple(dh))
+        d_attn["wo"], dx = d_wo, dx + dx_inputs
+        if pull_gate is not None:
+            d_attn[ATTN_GATE] = d_gate
+        if cfg.qk_norm:
+            return dx, d_attn, dict(zip(("norm_attn",) + QK_NORMS, d_norms))
+        return dx, d_attn, {"norm_attn": d_norms}
+
+    return a, gate_open, pull
+
+
+def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
+              pos=None):
+    """One sequence through one layer of the plain residual whose
+    feed-forward reads one normed input (``cfg.one_ffn_input``): ``y = a
+    + F(RMSNorm(a))``, ``a = x + Attn(RMSNorm(x))``: ``(y, (stats, ids),
+    pull)``, ``pull(dy) -> (dx, matrix gradients, small gradients)``."""
+    sinks = _zeros_like_f32(mats)
+    a, gate_open, pull_attention = attention_vjp(cfg, rope, mask, mats,
+                                                 sinks, small, x, pos)
+    v, aux, pull_ffn = feed_forward_vjp(cfg, sparse, mats, sinks, small, a)
+
+    def pull(dy):
+        du, (d_mats_ffn, d_small_ffn) = pull_ffn(dy)
+        dx, d_mats, d_small = pull_attention(dy + du)
+        return dx, {**d_mats, **d_mats_ffn}, {**d_small, **d_small_ffn}
+
+    return a + v, layer_stats(cfg, sparse, aux, gate_open), pull
+
+
+def layer_forward(cfg: LMConfig, rope, mask, mats, small, x, pos=None,
+                  sparse: int = 1):
     """One sequence through one layer under ``mask`` (a ``Mask``, or an
     int: a window, 0 causal) at rotary positions ``pos``: ``(y, stats,
     ids)``, ``stats`` int32[2] = (assignments on held experts, the
-    fullest held expert's) and ``ids`` [T, k] each token's experts (a
-    check hands them to its reference; a step drops them)."""
+    fullest held expert's; ``layer_stats`` says what follows them where
+    the feed-forward is ``feed_forward_vjp``'s) and ``ids`` [T, k] each
+    token's experts (a check hands them to its reference; a step drops
+    them)."""
+    if cfg.one_ffn_input:
+        y, stats, _ = layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos)
+        return (y,) + stats
     sinks = _zeros_like_f32(mats)
     early = cfg.router_input == "input"
 
@@ -1011,14 +1408,15 @@ def layer_forward(cfg: LMConfig, rope: bool, mask, mats, small, x, pos=None):
     return y, jnp.stack([jnp.sum(sizes), jnp.max(sizes)]), ids
 
 
-def layer_grads(cfg: LMConfig, rope: bool, mask, mats, small, x, dy,
-                pos=None):
+def layer_grads(cfg: LMConfig, rope, mask, mats, small, x, dy, pos=None,
+                sparse: int = 1):
     """The layer recomputed from its input ``x`` and differentiated:
     ``(dx, matrix gradients, small gradients)`` for one sequence. Each
     part's backward pass runs under the scope of its forward pass, so a
     device trace reads the two together."""
+    if cfg.one_ffn_input:
+        return layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos)[2](dy)
     sinks = _zeros_like_f32(mats)
-    attn_names = ("wq", "wk", "wv", "wo")
     early = cfg.router_input == "input"
 
     def routed(stream):     # the router's part, on what it reads
@@ -1029,31 +1427,15 @@ def layer_grads(cfg: LMConfig, rope: bool, mask, mats, small, x, dy,
 
     if early:
         (ids, weights), pull_router = routed(x)
-    scope = Mask.of(mask).scope
-    qkv = {n: sinks[n] for n in ("wq", "wk", "wv")}
-    # A scope names a backward pass only where it is entered OUTSIDE the
-    # differentiated function (inside, JAX writes it as transpose(jvp(..)),
-    # which no reader takes for a scope): so the attention's three parts
-    # are differentiated one by one, the kernel under its own name.
-    with jax.named_scope(scope):
-        (q, k, v), pull_inputs = jax.vjp(
-            lambda s, norms, x: attention_inputs(cfg, rope, mats, s, norms,
-                                                 x, pos),
-            qkv, _attention_norms(cfg, small), x)
-    with jax.named_scope(scope + ".kernel"):
-        o, pull_core = jax.vjp(
-            lambda q, k, v: attention_core(q, k, v, mask), q, k, v)
-    with jax.named_scope(scope):
-        a, pull_output = jax.vjp(
-            lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),
-            sinks["wo"], x, o)
+    a, _, pull_attention = attention_vjp(cfg, rope, mask, mats, sinks, small,
+                                         x, pos)
     if not early:
         (ids, weights), pull_router = routed(a)
     with jax.named_scope("mv.lm.experts"):
         y, pull_experts = jax.vjp(
             lambda s, norm, a, w: experts_block(cfg, mats, s, norm, a, ids,
                                                 w)[0],
-            {n: s for n, s in sinks.items() if n not in attn_names},
+            {n: s for n, s in sinks.items() if n not in GQA_MATRICES},
             small["norm_ffn"], a, weights)
         d_experts, d_norm_ffn, da, dw = pull_experts(dy.astype(y.dtype))
 
@@ -1064,21 +1446,11 @@ def layer_grads(cfg: LMConfig, rope: bool, mask, mats, small, x, dy,
     if not early:   # the router read the stream the experts read
         d_router, d_norm_router, d_stream = pull_routed()
         d_norm_ffn, da = d_norm_ffn + d_norm_router, da + d_stream
-    with jax.named_scope(scope):
-        d_wo, dx, do = pull_output(da)
-    with jax.named_scope(scope + ".kernel"):
-        d_qkv = pull_core(do)
-    with jax.named_scope(scope):
-        d_attn, d_norms, dx_inputs = pull_inputs(d_qkv)
-    d_attn["wo"], dx = d_wo, dx + dx_inputs
+    dx, d_attn, d_small = pull_attention(da)
     if early:       # it read the layer's input: its norm argument unused
         d_router, _, d_stream = pull_routed()
         dx = dx + d_stream
-    d_small = {"router": d_router, "norm_ffn": d_norm_ffn}
-    if cfg.qk_norm:
-        d_small.update(zip(("norm_attn",) + QK_NORMS, d_norms))
-    else:
-        d_small["norm_attn"] = d_norms
+    d_small = {"router": d_router, "norm_ffn": d_norm_ffn, **d_small}
     return dx, {**d_attn, **d_experts}, d_small
 
 

@@ -120,16 +120,18 @@ def _dispatch(fn, *args):
 
 
 def _kind(cfg: LMConfig, rope: int, window: int, seq_len: int):
-    """``(rotary, mask, positions)`` of a layer's programs: the positions
-    are given where they are not the rows' own numbers."""
+    """``(rotary, mask, positions)`` of a layer's programs: the rotary
+    positions are the kind's own description where the model has one a
+    kind (``LMConfig.rotary``), and the positions are given where they
+    are not the rows' own numbers."""
     mask = cfg.layer_mask(window, seq_len)
-    return bool(rope), mask, (mask.positions(2 * seq_len)
-                              if mask.kind == "blockdiff" else None)
+    return cfg.rotary(rope, window), mask, (
+        mask.positions(2 * seq_len) if mask.kind == "blockdiff" else None)
 
 
 def bias_step(cfg: LMConfig, stats):
     """What a sparse layer's router bias gets after a step whose forward
-    program counted ``stats`` [B, 2 + n_experts] (streams.layer_stats):
+    program counted ``stats`` [B, 2 + n_experts] (model.layer_stats):
     ``bias_rate * sign(mean load - load)`` over the step's sequences, a
     DELTA that the server adds (the bias's table is under the plain
     rule; no gradient goes to it)."""
@@ -140,18 +142,20 @@ def bias_step(cfg: LMConfig, stats):
 def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
                     sparse: int = 1):
     """``(float32 matrices, small, x [B, T, hidden]) -> (y, stats [B, 2],
-    the matrices' bfloat16 copies, each token's experts [B, T, k])``. The
-    third family's (``cfg.residual == "mhc"``; ``sparse``: the layer's
-    feed-forward) takes and gives [B, n hidden, T], its sparse layers'
-    ``stats`` also count every router output (streams.layer_stats) and
-    they give a fifth result, the bias's step (``bias_step``)."""
+    the matrices' bfloat16 copies, each token's experts [B, T, k])``, for a
+    layer of the kind ``LMConfig.layer_kinds`` names (``sparse``: its
+    feed-forward; its query heads are its ``wq``'s).
+    Where the feed-forward is ``model.feed_forward_vjp``'s the ``stats``
+    hold more (model.layer_stats). The streams' (``cfg.residual ==
+    "mhc"``) takes and gives [B, n hidden, T], and its sparse layers give a
+    fifth result, the bias's step (``bias_step``)."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
     def forward(mats32, small, x):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
         y, stats, ids = jax.lax.map(
             lambda seq: lm.layer_forward(cfg, rope, mask, mats, small, seq,
-                                         pos), x)
+                                         pos, sparse), x)
         return y, stats, mats, ids
 
     def forward_streams(mats32, small, x):
@@ -210,15 +214,14 @@ def _learned(small):
 def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
                      sparse: int = 1):
     """``(bfloat16 matrices, small, x, dy) -> (dx, matrix gradients, small
-    gradients)``, the gradients float32 and summed over the sequences (the
-    third family's router bias gets none: ``small`` holds it, the
-    gradients do not)."""
+    gradients)``, the gradients float32 and summed over the sequences (a
+    router bias gets none: ``small`` holds it, the gradients do not)."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
     def backward(mats, small, x, dy):
         return _summed_over_sequences(
             lambda seq: lm.layer_grads(cfg, rope, mask, mats, small, *seq,
-                                       pos), mats, small, (x, dy))
+                                       pos, sparse), mats, small, (x, dy))
 
     def backward_streams(mats, small, x, dy):
         # the donated cotangents' stack is the carry: a sequence's dx is
@@ -257,7 +260,7 @@ def module_programs(cfg: LMConfig):
 
         def one(seq):
             y, aux, _ = mtp.module_vjp(cfg, mats, small, *seq)
-            return (y,) + streams.layer_stats(cfg, 1, aux)
+            return (y,) + lm.layer_stats(cfg, 1, aux)
 
         y, stats, ids = jax.lax.map(one, (xs, e_next))
         return y, stats, mats, ids, bias_step(cfg, stats)
@@ -371,7 +374,7 @@ class PSLMTrainer:
             coefficient of a normed input is of order 1), a norm or a
             mixer's scalars 1, a mixer's offsets 0; a router's bias 0 and
             under the PLAIN rule, every other under the flag's Adam."""
-            if name == "router_bias":
+            if name == "router_bias":   # ``scoring: "sigmoid_bias"`` alone
                 return create_array_table(shape[0], updater_type="default")
             if len(shape) == 2:
                 return matrix(shape, shape[1] ** -0.5
@@ -400,10 +403,11 @@ class PSLMTrainer:
         self._whole_bytes = 4 * (cfg.parameters() - cfg.vocab * cfg.hidden)
 
         kinds = sorted(set(cfg.layer_kinds()))
-        # a kind is (rotary, window) or (rotary, window, sparse)
-        self._forward = {k: forward_program(cfg, *k[:2], self.T, *k[2:])
+        # a kind is (rotary, window[, sparse[, query heads]]); the heads
+        # tell two kinds apart and are their tensors' to say
+        self._forward = {k: forward_program(cfg, *k[:2], self.T, *k[2:3])
                          for k in kinds}
-        self._backward = {k: backward_program(cfg, *k[:2], self.T, *k[2:])
+        self._backward = {k: backward_program(cfg, *k[:2], self.T, *k[2:3])
                           for k in kinds}
         self._split = jax.jit(self._split_more if cfg.mtp_layers
                               else self._split_tokens)
@@ -667,16 +671,22 @@ class PSLMTrainer:
 
     def _count_stats(self, entry) -> None:
         stats, distinct, scored = entry
-        # a layer: [B, 2], or with every router output counted [B, 2 + E]
+        # a layer: [B, 2]; with every router output counted [B, 2 + E];
+        # with a gate one more, last (model.layer_stats)
         per_layer = [np.asarray(s) for s in stats]
         count("LM_HELD_ASSIGNMENTS", int(sum(s[:, 0].sum()
                                              for s in per_layer)))
         count("LM_EXPERT_MAX_TOKENS", int(sum(s[:, 1].sum()
                                               for s in per_layer)))
-        fullest = sum(int(s[:, 2:].sum(axis=0).max()) for s in per_layer
-                      if s.shape[1] > 2)
+        outputs = self.cfg.n_experts
+        fullest = sum(int(s[:, 2:2 + outputs].sum(axis=0).max())
+                      for s in per_layer if s.shape[1] >= 2 + outputs)
         if fullest:
             count("LM_ROUTER_LOAD_MAX", fullest)
+        if self.cfg.attn_gate == "head":
+            # each layer's gates summed over its heads, the step's mean
+            count("LM_GATE_OPEN", int(round(sum(
+                s[:, -1].mean() for s in per_layer))))
         count("LM_EMBED_ROWS", int(distinct))
         count("LM_MASKED_TOKENS", int(scored))
 

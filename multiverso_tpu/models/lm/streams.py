@@ -30,7 +30,8 @@ and scalars ``a = (a_pre, a_post, a_res)``; per token
 The embedding enters every stream alike (``expand``) and the streams are
 summed before the final norm (``collapse``).
 
-The feed-forward ``F`` is a dense MLP (``mv.lm.dense_mlp``) or, in a
+The feed-forward ``F`` is ``model.feed_forward_vjp``, which the plain
+residual's layers call too: a dense MLP (``mv.lm.dense_mlp``) or, in a
 sparse layer, the router (sigmoid scores, chosen through the bias:
 ``model.route``), the held routed experts (``model.routed_experts``) and
 the shared expert (``mv.lm.shared_expert``), all on one normed ``h``.
@@ -96,8 +97,6 @@ from .model import F32, LMConfig
 SCOPE = "mv.lm.hc"
 SUBLAYERS = ("hc_attn", "hc_ffn")
 MIXER = ("phi", "b", "a")
-DENSE = ("w_gate", "w_up", "w_down")
-SHARED = ("ws_gate", "ws_up", "ws_down")
 
 
 def total(parts):
@@ -470,66 +469,6 @@ def sublayer_vjp(cfg: LMConfig, hc, x, f_vjp, into=None, pull_into=None):
     return y, aux, pull
 
 
-# -- the two feed-forwards -------------------------------------------------------
-
-def dense_vjp(cfg: LMConfig, mats, sinks, small, u):
-    with jax.named_scope("mv.lm.dense_mlp"):
-        v, pull_mlp = jax.vjp(
-            lambda s, g, u: lm.gated_mlp(cfg, mats, s, DENSE,
-                                         lm.rmsnorm(u, g, cfg.eps)),
-            {n: sinks[n] for n in DENSE}, small["norm_ffn"], u)
-
-    def pull(dv):
-        with jax.named_scope("mv.lm.dense_mlp"):
-            d_mats, d_norm, du = pull_mlp(dv)
-        return du, (d_mats, {"norm_ffn": d_norm})
-
-    return v, None, pull
-
-
-def sparse_vjp(cfg: LMConfig, mats, sinks, small, u):
-    """Router, held routed experts and shared expert on one normed ``h``:
-    ``aux`` is ``(ids [T, k], held experts' assignments [held], every
-    router output's assignments [n_experts])``."""
-    routed = {n: sinks[n] for n in DENSE}
-    with jax.named_scope("mv.lm.experts"):
-        h, pull_norm = jax.vjp(lambda g, u: lm.rmsnorm(u, g, cfg.eps),
-                               small["norm_ffn"], u)
-    with jax.named_scope("mv.lm.router"):
-        weights, pull_router, ids = jax.vjp(
-            lambda r, h: lm.route(cfg, r, h, small["router_bias"])[::-1],
-            small["router"], h, has_aux=True)
-        load = lm.router_load(cfg, ids)
-    with jax.named_scope("mv.lm.experts"):
-        y, pull_experts, sizes = jax.vjp(
-            lambda s, h, w: lm.routed_experts(cfg, mats, s, h.astype(lm.BF16),
-                                              ids, w),
-            routed, h, weights, has_aux=True)
-    pull_shared = None
-    if cfg.shared_width:
-        with jax.named_scope("mv.lm.shared_expert"):
-            shared, pull_shared = jax.vjp(
-                lambda s, h: lm.gated_mlp(cfg, mats, s, SHARED, h),
-                {n: sinks[n] for n in SHARED}, h)
-        y = y + shared
-
-    def pull(dy):
-        with jax.named_scope("mv.lm.experts"):
-            d_mats, dh, dw = pull_experts(dy)
-        with jax.named_scope("mv.lm.router"):
-            d_router, dh_router = pull_router(dw)
-        dh = dh + dh_router
-        if pull_shared is not None:
-            with jax.named_scope("mv.lm.shared_expert"):
-                d_shared, dh_shared = pull_shared(dy)
-            d_mats, dh = {**d_mats, **d_shared}, dh + dh_shared
-        with jax.named_scope("mv.lm.experts"):
-            d_norm, du = pull_norm(dh)
-        return du, (d_mats, {"norm_ffn": d_norm, "router": d_router})
-
-    return y, (ids, sizes, load), pull
-
-
 # -- the layer ----------------------------------------------------------------------
 
 def _mixer(small, sub):
@@ -545,7 +484,6 @@ def layer_vjp(cfg: LMConfig, sparse: int, mats, small, x, pos=None,
     ``y`` is [n C, T], or ``into``'s stack with it as that sequence;
     ``dx`` likewise by ``pull_into``."""
     sinks = {name: jnp.zeros(w.shape, F32) for name, w in mats.items()}
-    ffn = sparse_vjp if sparse else dense_vjp
 
     def attention(u):
         v, pull = latent.attention_vjp(cfg, mats, sinks, small, u, pos)
@@ -560,7 +498,8 @@ def layer_vjp(cfg: LMConfig, sparse: int, mats, small, x, pos=None,
                                         attention, pull_into=pull_into)
     y, aux, pull_ffn = sublayer_vjp(
         cfg, _mixer(small, "hc_ffn"), a,
-        lambda u: ffn(cfg, mats, sinks, small, u), into=into)
+        lambda u: lm.feed_forward_vjp(cfg, sparse, mats, sinks, small, u),
+        into=into)
 
     def pull(dy):
         da, d_hc_ffn, (d_mats_ffn, d_small_ffn) = pull_ffn(dy)
@@ -573,24 +512,11 @@ def layer_vjp(cfg: LMConfig, sparse: int, mats, small, x, pos=None,
     return y, aux, pull
 
 
-def layer_stats(cfg: LMConfig, sparse: int, aux):
-    """What a forward program reports of one sequence: ``(stats, ids)``.
-    A sparse layer's ``stats`` int32 [2 + n_experts]: assignments on held
-    experts, the fullest held expert's, then every router output's; a
-    dense layer's two zeros and no ids."""
-    if not sparse:
-        return jnp.zeros((2,), jnp.int32), jnp.zeros((0, cfg.top_k),
-                                                     jnp.int32)
-    ids, sizes, load = aux
-    return jnp.concatenate(
-        [jnp.stack([jnp.sum(sizes), jnp.max(sizes)]), load]), ids
-
-
 def layer_forward(cfg: LMConfig, sparse: int, mats, small, x, pos=None,
                   into=None):
     """``model.layer_forward``'s results for this family's layer."""
     y, aux, _ = layer_vjp(cfg, sparse, mats, small, x, pos, into)
-    return (y,) + layer_stats(cfg, sparse, aux)
+    return (y,) + lm.layer_stats(cfg, sparse, aux)
 
 
 def layer_grads(cfg: LMConfig, sparse: int, mats, small, x, dy, pos=None,

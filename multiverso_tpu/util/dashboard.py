@@ -243,6 +243,9 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_ROUTER_LOAD_MAX": "the fullest router output's assignments over "
                           "ALL of a sparse layer's outputs, summed over "
                           "layers",
+    "LM_GATE_OPEN": "the per-head attention gate's value: each layer's "
+                    "gates summed over its heads, the step's mean over "
+                    "tokens, summed over layers, in thousandths",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"

@@ -211,7 +211,7 @@ def test_latent_attention_equals_the_reference(float32_products):
 
 
 def test_yarn_s_frequencies_and_scale():
-    inv = latent.yarn_frequencies(CFG)
+    inv = lm.yarn_frequencies(CFG.rope_theta, CFG.qk_rope_dim, *CFG.yarn[:4])
     own = 1.0 / 10000 ** (np.arange(0, 8, 2) / 8)
     # the fastest pair turns more than beta_fast times in 64 positions and
     # keeps its frequency; the slowest is divided by the factor
@@ -610,7 +610,7 @@ def test_the_eight_expert_shares_add_up_to_the_uncut_feed_forward(
             assert {n: cut[n].shape for n in cut} == share.layer_shapes(1)
             mats, small = _split(share, cut, 1)
             sinks = {n: jnp.zeros_like(w_) for n, w_ in mats.items()}
-            y, (_, sizes, load), _ = streams.sparse_vjp(share, mats, sinks,
+            y, (_, sizes, load), _ = lm.sparse_vjp(share, mats, sinks,
                                                         small, u)
             total = total + (y - shared)
             seen += int(sizes.sum())

@@ -310,3 +310,30 @@ def test_the_mixers_passes_compile_for_a_v5e_at_the_published_widths(
         assert kernel in text, kernel
     # no copy of a sequence's streams (235 MB) or of a stack
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+# -- the fourth family's attention (benchmark/configs/laguna-xs2-33b-a3b-l5.json) --
+
+@pytest.mark.parametrize("per_group,window", [(6, 0), (8, 512)])
+def test_the_attention_kernel_compiles_at_both_of_laguna_s_kinds(
+        topo, per_group, window):
+    """Splash attention forward and backward for one sequence of 8192 at
+    the published head counts of each kind of layer (8 key-value heads: 6
+    query heads each under the causal mask, 8 each under a window of 512,
+    which is no wider than the kernel's block)."""
+    from multiverso_tpu.models.lm import model as lm
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    t = 8192
+
+    def loss(q, k, v):
+        out = jax.vmap(lm._splash(t, per_group, window))(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shaped((8, per_group, t, 128), jnp.bfloat16, sharding=one),
+        shaped((8, t, 128), jnp.bfloat16, sharding=one),
+        shaped((8, t, 128), jnp.bfloat16, sharding=one)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no [heads, T, T] array: 64 x 8192 x 8192 x 4 B would be 17 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 800e6
