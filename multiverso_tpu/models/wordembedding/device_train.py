@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...runtime import device_lock
+from ...sharding import mesh as meshlib
 from ...updater.rules import fast_rows, scatter_add
 from ...util.dashboard import count, monitor
 from .data import TokenizedCorpus
@@ -887,16 +888,60 @@ def _grouped_step_fn(step_fn, G: int):
     return step
 
 
+@functools.lru_cache(maxsize=None)
+def _loop_ids_fn(ids_fn, C: int, G: int):
+    """The ids program as ``PSDeviceCorpusTrainer.train_epoch`` runs it:
+    ``ids_fn`` (a ``_block_ids_fn*``, or its ``_grouped_ids_fn``) with
+    the block's key folded from the epoch's and its base (the [G]
+    bases) computed inside, from the block's number ``g0`` and the
+    group's count of real blocks: the key and the integers that
+    ``fold_in(key, g0)`` and ``g0 * C`` give on the host, so a block's
+    number is all the host hands the program."""
+
+    @jax.jit
+    def ids(kept_pad, ksent_pad, aux1, aux2, key, g0, real, n_kept):
+        with jax.named_scope("mv.sgns.ids"):
+            step_key = jax.random.fold_in(key, g0)
+            if G == 1:
+                base = g0 * C
+            else:
+                # Padded tail blocks get base = n_kept (fully masked).
+                i = jnp.arange(G, dtype=jnp.int32)
+                base = jnp.where(i < real, (g0 + i) * C, n_kept)
+        return ids_fn(kept_pad, ksent_pad, aux1, aux2, step_key, base,
+                      n_kept)
+
+    return ids
+
+
+@functools.lru_cache(maxsize=None)
+def _loop_step_fn(step_fn):
+    """The step program as the loop runs it: ``step_fn`` (a
+    ``_block_step_fn*``, or its ``_grouped_step_fn``) and the epoch's
+    running sums, which it takes (donated) and hands back beside the
+    block's own loss and examples: one float32 addition each a block,
+    in the blocks' order."""
+
+    @functools.partial(jax.jit, donate_argnums=(5, 6))
+    def step(v, u, pmask, lr, inv_workers, loss_acc, pair_acc):
+        d_v, d_u, loss, pairs = step_fn(v, u, pmask, lr, inv_workers)
+        return d_v, d_u, loss, pairs, loss_acc + loss, pair_acc + pairs
+
+    return step
+
+
 class PSDeviceCorpusTrainer:
     """The PS twin of ``DeviceCorpusTrainer``: same HBM-resident corpus
     pipeline, but the embeddings live in PARAMETER-SERVER matrix tables
     — every block pulls its rows through the full worker/server actor
     stack (device-key Gets), trains, and pushes ``-lr*grad/num_workers``
-    deltas back (device-key Adds). Nothing but learning-rate scalars
-    crosses the host boundary, which is what lets the PS path approach
-    local-mode throughput in-process (the reference's block protocol,
-    ref: Applications/WordEmbedding/src/communicator.cpp:117-249, with
-    the row list living in HBM).
+    deltas back (device-key Adds). Nothing but the block's number and
+    learning rate crosses the host boundary, as arguments of the two
+    programs a block dispatches (ids, step); that is what lets the PS
+    path approach local-mode throughput in-process (the reference's
+    block protocol, ref:
+    Applications/WordEmbedding/src/communicator.cpp:117-249, with the
+    row list living in HBM).
 
     Requires the in-process device path. Multi-server tables work —
     device keys broadcast to every server, which masks foreign rows on
@@ -927,6 +972,14 @@ class PSDeviceCorpusTrainer:
         self._G = max(int(blocks_per_dispatch), 1)
         self._corpus = _CorpusOnDevice(model, tokenized)
         self._n_tokens = self._corpus.n_tokens
+        # Where a block's rows are: replicated over the mesh the
+        # in-process servers lay their tables on. Whatever the loop's
+        # two programs read is placed so, once: the ids program then
+        # hands the tables ids, and the step a mask, that are where the
+        # table's programs and the step run, and no program has to
+        # place an argument again at every dispatch (over several
+        # devices that is a copy a device, made from Python).
+        self._on_rows = meshlib.replicated(meshlib.local_mesh())
         if config.hs:
             if not hasattr(model, "_points_dev"):
                 # PSWord2Vec keeps the Huffman tables host-side (its
@@ -934,10 +987,10 @@ class PSDeviceCorpusTrainer:
                 # derives paths in-jit, so upload them once (guarded:
                 # construction can overlap a sibling rank's step).
                 with device_lock.guard():
-                    model._points_dev = device_lock.settle(
-                        jnp.asarray(model._points_host))
-                    model._codes_dev = device_lock.settle(
-                        jnp.asarray(model._codes_host))
+                    model._points_dev, model._codes_dev = \
+                        device_lock.settle(jax.device_put(
+                            (model._points_host, model._codes_host),
+                            self._on_rows))
             path_len = max(int(model._points_host.shape[1]), 1)
             self._C = min(self._C, _hs_center_cap(
                 path_len, int(config.embedding_size)))
@@ -953,10 +1006,10 @@ class PSDeviceCorpusTrainer:
                 # samples in-jit, so upload them once (guarded:
                 # construction can overlap a sibling rank's step).
                 with device_lock.guard():
-                    model._neg_prob_dev = device_lock.settle(
-                        jnp.asarray(model._neg_prob_host))
-                    model._neg_alias_dev = device_lock.settle(
-                        jnp.asarray(model._neg_alias_host))
+                    model._neg_prob_dev, model._neg_alias_dev = \
+                        device_lock.settle(jax.device_put(
+                            (model._neg_prob_host, model._neg_alias_host),
+                            self._on_rows))
             B = max(int(getattr(config, "neg_block", 1)), 1)
             if self._C % B:
                 raise ValueError("neg_block must divide centers_per_step")
@@ -972,19 +1025,31 @@ class PSDeviceCorpusTrainer:
             self._aux_tables = (model._neg_prob_dev,
                                 model._neg_alias_dev)
         self._pad = jax.jit(functools.partial(_pad_stream, self._C,
-                                              config.window))
+                                              config.window),
+                            out_shardings=self._on_rows)
         if self._G > 1:
             self._ids = _grouped_ids_fn(self._ids, self._G)
             self._step = _grouped_step_fn(self._step, self._G)
+        # The loop's two programs: _ids and _step (which keep their
+        # signatures for callers that run one block by hand) with the
+        # block's key and base, and the epoch's sums, inside.
+        self._loop_ids = _loop_ids_fn(self._ids, self._C, self._G)
+        self._loop_step = _loop_step_fn(self._step)
+        with device_lock.guard():
+            self._inv_workers = device_lock.settle(jax.device_put(
+                np.float32(1.0 / model._num_workers), self._on_rows))
         self.kept_words_trained = 0
 
     def train_epoch(self, seed: int, block_hook=None,
                     max_steps: int = 0) -> Tuple[float, float]:
         """One epoch: per dispatch group (G blocks; G=1 default),
         compute ids on device -> device-key pulls -> jitted step ->
-        device-key delta pushes, all dispatched asynchronously (losses
-        accumulate as device scalars; pushes are fire-and-forget until
-        the trailing drain)."""
+        device-key delta pushes, all dispatched asynchronously (pushes
+        are fire-and-forget until the trailing drain). A block is two
+        dispatches of the trainer's own, ids and step: nothing but the
+        block's number and learning rate crosses the host boundary, as
+        numpy arguments of the two; the block's key is folded and the
+        epoch's loss and example sums are kept inside them."""
         model, C, G = self.model, self._C, self._G
         in_table, out_table = model._in_table, model._out_table
         with monitor("TRAINER_EPOCH_PREP"):
@@ -993,58 +1058,56 @@ class PSDeviceCorpusTrainer:
             kept, ksent, n_kept_dev = self._corpus.prep_epoch(prep_key)
             # Pad ONCE per epoch; the per-step ids program then slices
             # the padded stream directly (padding per step would
-            # re-copy the whole ~24 MB stream every block).
+            # re-copy the whole stream every block). The padded stream,
+            # the epoch's key (the ids program folds each block's from
+            # it), the kept count and the epoch's sums, which start at
+            # zero, are placed where the blocks' rows are.
             with device_lock.guard():
                 kept_pad, ksent_pad = device_lock.settle(
                     self._pad(kept, ksent))
+                del kept, ksent   # not held through the epoch
+                key, n_kept_dev, loss_acc, pair_acc = device_lock.settle(
+                    jax.device_put(
+                        (key, n_kept_dev, np.float32(0), np.float32(0)),
+                        self._on_rows))
             n_kept = int(n_kept_dev)
+            # The first block waits for the pad: dispatched before it
+            # has run, its rows would lie beside both streams, the
+            # epoch's peak of device memory.
+            jax.block_until_ready(ksent_pad)
         steps = max(math.ceil(n_kept / C), 1)
         if max_steps:
             steps = min(steps, max_steps)
         self.kept_words_trained += min(steps * C, n_kept)
         raw_per_step = self._n_tokens / max(math.ceil(n_kept / C), 1)
-        loss_acc = None
-        pair_acc = None
-        # The trainer's own dispatches are one monitor a kind of work a
-        # block; the client calls between them have CLIENT_ISSUE_*, the
-        # waits TABLE_WAIT.
+        # The trainer's own dispatches are one monitor a program; the
+        # client calls between them have CLIENT_ISSUE_*, the waits
+        # TABLE_WAIT.
+        behind = None   # the last block's own loss: see TRAINER_BLOCK_PACE
         for g0 in range(0, steps, G):
-            with monitor("TRAINER_BLOCK_UPLOAD"):
+            with monitor("TRAINER_BLOCK_IDS"):
                 real = min(G, steps - g0)
-                step_key = jax.random.fold_in(key, g0)
                 if G == 1:
-                    base = np.int32(g0 * C)
-                    lr_host = np.float32(model.learning_rate())
+                    lr = np.float32(model.learning_rate())
                     model._account_words(raw_per_step)
                 else:
-                    # Padded tail blocks get base = n_kept (fully
-                    # masked) and lr 0 — exact no-ops, so the program
-                    # set stays one fixed shape.
-                    bases = np.full(G, n_kept, np.int32)
-                    bases[:real] = (np.arange(g0, g0 + real)
-                                    * C).astype(np.int32)
-                    lr_host = np.zeros(G, np.float32)
+                    # Padded tail blocks get lr 0 (and a fully masked
+                    # base, in the program) — exact no-ops, so the
+                    # program set stays one fixed shape.
+                    lr = np.zeros(G, np.float32)
                     for i in range(real):
-                        lr_host[i] = model.learning_rate()
+                        lr[i] = model.learning_rate()
                         model._account_words(raw_per_step)
+                # in_ids: centers (skip-gram) or the band (CBOW);
+                # out_ids: [band | negs] / [centers | negs] / Huffman
+                # path rows — see _block_ids_fn / _block_ids_fn_hs;
+                # leading G axis when grouped.
                 with device_lock.guard():
-                    # The per-group scalar/vector uploads are dispatches
-                    # too — one guarded region keeps them from
-                    # interleaving a sibling rank's program in
-                    # multi-zoo mode.
-                    if G != 1:
-                        base = device_lock.settle(jnp.asarray(bases))
-                    lr = device_lock.settle(jnp.asarray(lr_host))
-                    inv_w = device_lock.settle(
-                        jnp.float32(1.0 / model._num_workers))
-            # in_ids: centers (skip-gram) or the band (CBOW);
-            # out_ids: [band | negs] / [centers | negs] / Huffman
-            # path rows — see _block_ids_fn / _block_ids_fn_hs;
-            # leading G axis when grouped.
-            with monitor("TRAINER_BLOCK_IDS"), device_lock.guard():
-                in_ids, out_ids, pmask = device_lock.settle(self._ids(
-                    kept_pad, ksent_pad, self._aux_tables[0],
-                    self._aux_tables[1], step_key, base, n_kept_dev))
+                    in_ids, out_ids, pmask = device_lock.settle(
+                        self._loop_ids(
+                            kept_pad, ksent_pad, self._aux_tables[0],
+                            self._aux_tables[1], key, np.int32(g0),
+                            np.int32(real), n_kept_dev))
             # Device-key pulls ride the worker->server actor round
             # trip; the replies are lazy device arrays (no host
             # sync).
@@ -1054,29 +1117,34 @@ class PSDeviceCorpusTrainer:
             out_table.wait(mid_out)
             # Per-server shard tuples; the step jit sums them
             # (fused — no separate reassembly dispatch on
-            # multi-server tables).
-            with monitor("TRAINER_BLOCK_STEP"):
-                v = tuple(in_table.take_device_row_parts())
-                u = tuple(out_table.take_device_row_parts())
-                with device_lock.guard():
-                    d_v, d_u, loss, pairs = device_lock.settle(
-                        self._step(v, u, pmask, lr, inv_w))
+            # multi-server tables). The rows are given no name here:
+            # once the step has run, nothing holds them.
+            with monitor("TRAINER_BLOCK_STEP"), device_lock.guard():
+                (d_v, d_u, loss, _, loss_acc,
+                 pair_acc) = device_lock.settle(self._loop_step(
+                    tuple(in_table.take_device_row_parts()),
+                    tuple(out_table.take_device_row_parts()),
+                    pmask, lr, self._inv_workers, loss_acc, pair_acc))
             # Fire-and-forget pushes: waiters self-reap on ack; the
             # trailing drain below bounds the epoch.
             model._pending_pushes.append(
                 (in_table, in_table.add_rows_async(in_ids, d_v)))
             model._pending_pushes.append(
                 (out_table, out_table.add_rows_async(out_ids, d_u)))
-            # Two more dispatches, and the first calls after the Adds
-            # left that give up the GIL: the actors take it from here.
-            with monitor("TRAINER_BLOCK_LOSS"):
-                loss_acc = loss if loss_acc is None else loss_acc + loss
-                pair_acc = pairs if pair_acc is None else pair_acc + pairs
-                self.last_loss = loss  # device scalar; callers sync on it
+            # The host stays one block ahead of the device and no
+            # further: a block in flight holds its ids, rows and deltas
+            # (90 MB at 32768 centers of 128 columns), and where the
+            # device is the pace the runtime's queue would let them
+            # pile up. The wait gives the actors the GIL for the Adds
+            # just issued; where the host is the pace it returns at
+            # once.
+            with monitor("TRAINER_BLOCK_PACE"):
+                if behind is not None:
+                    jax.block_until_ready(behind)
+            behind = self.last_loss = loss  # callers sync on last_loss
             if block_hook is not None:
                 block_hook(raw_per_step * real)
         model._drain_pushes()
         model._flush_word_count()
         model._in_table.zoo.barrier()
-        return (0.0 if loss_acc is None else float(loss_acc),
-                0.0 if pair_acc is None else float(pair_acc))
+        return float(loss_acc), float(pair_acc)

@@ -204,15 +204,18 @@ METRIC_NAMES: Dict[str, str] = {
     # -- device-corpus trainers (models/wordembedding/device_train.py) --
     "TRAINER_EPOCH_PREP": "train_epoch entry to its first block's "
                           "dispatch: _prep (subsample mask, one sort "
-                          "that carries tokens and sentence ids), pad, "
-                          "kept-count readback",
-    "TRAINER_BLOCK_UPLOAD": "a PS block's host arithmetic and the "
-                            "uploads of base, lr and 1/workers",
-    "TRAINER_BLOCK_IDS": "a PS block's ids program dispatched",
+                          "that carries tokens and sentence ids), pad "
+                          "(the PS trainer waits for it), kept-count "
+                          "readback",
+    "TRAINER_BLOCK_IDS": "a PS block's learning rate and word count "
+                         "on the host and its ids program dispatched "
+                         "(the block's key and base computed inside)",
     "TRAINER_BLOCK_STEP": "a PS block's reply parts taken and its step "
-                          "program dispatched",
-    "TRAINER_BLOCK_LOSS": "a PS block's loss and pair counts added to "
-                          "the epoch's (two dispatches)",
+                          "program dispatched (the epoch's loss and "
+                          "pair sums kept inside)",
+    "TRAINER_BLOCK_PACE": "a PS block's wait for the block before it "
+                          "to have run its step: the host stays one "
+                          "block ahead of the device",
     "TRAINER_GROUP_DISPATCH": "the local trainer's group program "
                               "dispatched with its two uploads (G "
                               "blocks)",

@@ -462,11 +462,12 @@ def test_the_trainers_dispatches_count_one_a_block_or_a_group(
     own = {name: moved[name] for name in moved
            if name.startswith("TRAINER_") and moved[name][0]}
     if use_ps:
+        # one monitor a program the block dispatches, ids and step, and
+        # one for its wait for the block before it
         assert {n: c for n, (c, _) in own.items()} == {
-            "TRAINER_EPOCH_PREP": 1, "TRAINER_BLOCK_UPLOAD": len(ticks),
-            "TRAINER_BLOCK_IDS": len(ticks),
+            "TRAINER_EPOCH_PREP": 1, "TRAINER_BLOCK_IDS": len(ticks),
             "TRAINER_BLOCK_STEP": len(ticks),
-            "TRAINER_BLOCK_LOSS": len(ticks)}
+            "TRAINER_BLOCK_PACE": len(ticks)}
         # a block's two Gets and two Adds are the client's to name
         assert moved["CLIENT_ISSUE_GET"][0] == 2 * len(ticks)
         assert moved["CLIENT_ISSUE_ADD"][0] == 2 * len(ticks)
@@ -474,6 +475,74 @@ def test_the_trainers_dispatches_count_one_a_block_or_a_group(
         assert {n: c for n, (c, _) in own.items()} == {
             "TRAINER_EPOCH_PREP": 1, "TRAINER_GROUP_DISPATCH": len(ticks)}
     assert all(ms > 0 for _, ms in own.values())
+
+
+def _launches_of_the_thread_with(trace_dir, span):
+    """From the capture under ``trace_dir``, the host line that holds
+    ``span``: its ``mv:`` spans, the jitted calls its thread made (the
+    outermost ``PjitFunction(<name>)`` events: an eager ``x + y`` or a
+    ``jnp.asarray`` is one too) and its transfers (``DevicePut*``), each
+    as ``(name, start_ns, end_ns)``."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    host, = [plane for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"]
+    line, = [line for line in host.lines
+             if any(e.name == span for e in line.events)]
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events]
+    calls = sorted((e for e in events if e[0].startswith("PjitFunction(")),
+                   key=lambda e: (e[1], -e[2]))
+    outermost = []
+    for call in calls:       # the runtime records a call twice, nested
+        if not outermost or call[1] >= outermost[-1][2]:
+            outermost.append(call)
+    return ([e for e in events if e[0].startswith(dashboard.SPAN_PREFIX)],
+            outermost,
+            [e for e in events if e[0].startswith("DevicePut")])
+
+
+@pytest.mark.parametrize("grouped", [1, 3], ids=["G1", "G3"])
+def test_a_ps_block_is_two_programs_of_the_trainers_own(tmp_path, grouped):
+    """Over the N blocks of an epoch the trainer's thread launches 2 N
+    programs, ids and step, and uploads nothing by a call of its own:
+    the block's number and learning rate are arguments of the two."""
+    from multiverso_tpu.models.wordembedding import (
+        PSDeviceCorpusTrainer, PSWord2Vec, Word2VecConfig)
+    d, tok = _corpus(tmp_path)
+    config = Word2VecConfig(embedding_size=8, window=2, epochs=1,
+                            init_learning_rate=0.01, batch_size=512,
+                            sample=0, use_ps=True)
+    mv.init([])
+    try:
+        trainer = PSDeviceCorpusTrainer(PSWord2Vec(config, d), tok,
+                                        centers_per_step=64,
+                                        blocks_per_dispatch=grouped)
+        trainer.train_epoch(seed=0)        # every program compiled
+        ticks = []
+        with trace_to(str(tmp_path / "trace")):
+            trainer.train_epoch(seed=1, block_hook=ticks.append)
+    finally:
+        mv.shutdown()
+    spans, calls, puts = _launches_of_the_thread_with(
+        str(tmp_path / "trace"), "mv:TRAINER_BLOCK_IDS")
+    blocks = len(ticks)
+    assert blocks > 2
+    (_, _, prep_end), = [s for s in spans if s[0] == "mv:TRAINER_EPOCH_PREP"]
+    in_loop = [c for c in calls if c[1] >= prep_end]
+    assert [c[0] for c in in_loop] == [
+        "PjitFunction(ids)", "PjitFunction(step)"] * blocks
+    # each under its monitor, in the block's order
+    ids = sorted(s for s in spans if s[0] == "mv:TRAINER_BLOCK_IDS")
+    step = sorted(s for s in spans if s[0] == "mv:TRAINER_BLOCK_STEP")
+    assert len(ids) == len(step) == blocks
+    for call, (_, lo, hi) in zip(in_loop, [
+            s for pair in zip(ids, step) for s in pair]):
+        assert lo <= call[1] <= call[2] <= hi
+    # what is transferred in the loop is an argument of one of the two
+    for _, start, end in (p for p in puts if p[1] >= prep_end):
+        assert any(lo <= start <= end <= hi for _, lo, hi in in_loop)
 
 
 # -- named scopes -------------------------------------------------------------

@@ -832,6 +832,176 @@ class TestPSDevicePipeline:
         np.testing.assert_allclose(two, one, rtol=1e-4, atol=1e-6)
 
 
+def _epoch_as_nine_dispatches(trainer, seed, block_hook):
+    """``PSDeviceCorpusTrainer.train_epoch`` as it was before the
+    block's key, base and sums went inside its two programs: the key
+    folded and the scalars uploaded eagerly, ``trainer._ids``,
+    ``trainer._step``, the sums added eagerly. Same requests, same
+    order."""
+    import math
+    import jax
+    import jax.numpy as jnp
+    model, C, G = trainer.model, trainer._C, trainer._G
+    in_table, out_table = model._in_table, model._out_table
+    key = jax.random.PRNGKey(seed)
+    key, prep_key = jax.random.split(key)
+    kept, ksent, n_kept_dev = trainer._corpus.prep_epoch(prep_key)
+    kept_pad, ksent_pad = trainer._pad(kept, ksent)
+    n_kept = int(n_kept_dev)
+    steps = max(math.ceil(n_kept / C), 1)
+    trainer.kept_words_trained += min(steps * C, n_kept)
+    raw_per_step = trainer._n_tokens / steps
+    loss_acc = pair_acc = None
+    for g0 in range(0, steps, G):
+        real = min(G, steps - g0)
+        step_key = jax.random.fold_in(key, g0)
+        if G == 1:
+            base = np.int32(g0 * C)
+            lr_host = np.float32(model.learning_rate())
+            model._account_words(raw_per_step)
+        else:
+            bases = np.full(G, n_kept, np.int32)
+            bases[:real] = (np.arange(g0, g0 + real) * C).astype(np.int32)
+            lr_host = np.zeros(G, np.float32)
+            for i in range(real):
+                lr_host[i] = model.learning_rate()
+                model._account_words(raw_per_step)
+            base = jnp.asarray(bases)
+        lr = jnp.asarray(lr_host)
+        inv_w = jnp.float32(1.0 / model._num_workers)
+        in_ids, out_ids, pmask = trainer._ids(
+            kept_pad, ksent_pad, trainer._aux_tables[0],
+            trainer._aux_tables[1], step_key, base, n_kept_dev)
+        mid_in = in_table.get_rows_device_async(in_ids)
+        mid_out = out_table.get_rows_device_async(out_ids)
+        in_table.wait(mid_in)
+        out_table.wait(mid_out)
+        v = tuple(in_table.take_device_row_parts())
+        u = tuple(out_table.take_device_row_parts())
+        d_v, d_u, loss, pairs = trainer._step(v, u, pmask, lr, inv_w)
+        model._pending_pushes.append(
+            (in_table, in_table.add_rows_async(in_ids, d_v)))
+        model._pending_pushes.append(
+            (out_table, out_table.add_rows_async(out_ids, d_u)))
+        loss_acc = loss if loss_acc is None else loss_acc + loss
+        pair_acc = pairs if pair_acc is None else pair_acc + pairs
+        trainer.last_loss = loss
+        block_hook(raw_per_step * real)
+    model._drain_pushes()
+    model._flush_word_count()
+    model._in_table.zoo.barrier()
+    return float(loss_acc), float(pair_acc)
+
+
+_LOOP_MODES = {
+    "skipgram": ({}, 1),
+    "cbow": ({"cbow": True}, 1),
+    "hs_skipgram": ({"hs": True, "negative": 0}, 1),
+    "hs_cbow": ({"hs": True, "negative": 0, "cbow": True}, 1),
+    "per_pair": ({"per_pair": True}, 1),
+    "grouped4": ({}, 4),
+}
+
+
+class TestPSLoopPrograms:
+    """The PS loop's two programs a block (the block's key and base
+    inside the ids program, the epoch's sums inside the step) against
+    the nine dispatches they took the place of."""
+
+    @staticmethod
+    def _train(d, tok, kw, grouped, epoch_fn):
+        from multiverso_tpu.models.wordembedding import (
+            PSDeviceCorpusTrainer)
+        mv.init([])
+        try:
+            config = Word2VecConfig(embedding_size=16, window=3, epochs=2,
+                                    init_learning_rate=0.01,
+                                    batch_size=1024, sample=1e-2, **kw)
+            model = PSWord2Vec(config, d)
+            trainer = PSDeviceCorpusTrainer(model, tok,
+                                            centers_per_step=128,
+                                            blocks_per_dispatch=grouped)
+            losses, sums = [], []
+            for epoch in range(2):   # the second starts from the first's
+                sums.append(epoch_fn(trainer)(
+                    seed=3 + epoch, block_hook=lambda words: losses.append(
+                        (words, trainer.last_loss))))
+            return (np.array(model._in_table.get(), copy=True),
+                    np.array(model._out_table.get(), copy=True),
+                    [(w, float(x)) for w, x in losses], sums,
+                    trainer.kept_words_trained, model.trained_words)
+        finally:
+            mv.shutdown()
+
+    @pytest.mark.parametrize("mode", list(_LOOP_MODES))
+    def test_two_dispatches_train_what_nine_did(self, tmp_path, mode):
+        import functools
+        from multiverso_tpu.models.wordembedding import TokenizedCorpus
+        path = tmp_path / "corpus.txt"
+        write_topic_corpus(path, n_sentences=120)
+        d = Dictionary.build(str(path), min_count=1)
+        tok = TokenizedCorpus.build(d, str(path))
+        kw, grouped = _LOOP_MODES[mode]
+        new = self._train(d, tok, kw, grouped,
+                          lambda trainer: trainer.train_epoch)
+        old = self._train(d, tok, kw, grouped, lambda trainer:
+                          functools.partial(_epoch_as_nine_dispatches,
+                                            trainer))
+        assert len(new[2]) > (2 if grouped > 1 else 8)   # blocks
+        assert np.abs(new[0]).max() > 0 and np.abs(new[1]).max() > 0
+        assert np.array_equal(new[0], old[0])       # the input table
+        assert np.array_equal(new[1], old[1])       # the output table
+        assert new[2] == old[2]      # every block's words and own loss
+        assert new[3] == old[3]      # (loss, examples) of both epochs
+        assert new[4:] == old[4:]    # the words accounted
+
+    def test_ids_and_step_run_one_block_by_hand(self, tmp_path):
+        # benchmark/reference/sgns_block.py check() runs one block
+        # through trainer._ids and trainer._step with these arguments.
+        import jax
+        import jax.numpy as jnp
+        from multiverso_tpu.models.wordembedding import (
+            PSDeviceCorpusTrainer, TokenizedCorpus)
+        from multiverso_tpu.models.wordembedding import device_train as dt
+        path = tmp_path / "corpus.txt"
+        write_topic_corpus(path, n_sentences=120)
+        d = Dictionary.build(str(path), min_count=1)
+        tok = TokenizedCorpus.build(d, str(path))
+        mv.init([])
+        try:
+            config = Word2VecConfig(embedding_size=16, window=3, epochs=1,
+                                    init_learning_rate=0.01,
+                                    batch_size=1024, sample=0)
+            model = PSWord2Vec(config, d)
+            trainer = PSDeviceCorpusTrainer(model, tok,
+                                            centers_per_step=128)
+            trainer.train_epoch(seed=0, max_steps=2)
+            C, W, K = trainer._C, config.window, config.negative
+            key, prep_key = jax.random.split(jax.random.PRNGKey(7))
+            kept, ksent, n_kept_dev = trainer._corpus.prep_epoch(prep_key)
+            kept_pad, ksent_pad = dt._pad_stream(C, W, kept, ksent)
+            tin, tout = model._in_table, model._out_table
+            ids = trainer._ids(
+                kept_pad, ksent_pad, model._neg_prob_dev,
+                model._neg_alias_dev, key, np.int32(0), n_kept_dev)
+            assert len(ids) == 3
+            in_ids, out_ids, pmask = ids
+            assert in_ids.shape == (C,)
+            assert out_ids.shape == (C + 2 * W + C * K,)
+            assert pmask.shape == (C, 2 * W)
+            v, u = tin.get_rows_device(in_ids), tout.get_rows_device(out_ids)
+            out = trainer._step(
+                (v,), (u,), pmask, jnp.asarray(np.float32(0.01)),
+                jnp.float32(1.0 / model._num_workers))
+            assert len(out) == 4
+            d_v, d_u, loss, pairs = out
+            assert d_v.shape == v.shape and d_u.shape == u.shape
+            assert float(loss) > 0 and float(pairs) == float(pmask.sum())
+            assert float(trainer.last_loss) > 0   # a block's own loss
+        finally:
+            mv.shutdown()
+
+
 class TestBatchGroup:
     @pytest.mark.parametrize("mode", ["sgns", "cbow", "hs"])
     def test_grouped_scan_matches_sequential(self, tmp_path, mode):

@@ -7,9 +7,10 @@ through both actors):
 
 A block enters 18 monitors with a request's id or table (WORKER_PROCESS_
 GET/ADD and SERVER_PROCESS_GET/ADD two each, 2 WORKER_REPLY_GET, 2
-WORKER_REPLY_ADD, 2 TABLE_WAIT, 4 CLIENT_ISSUE_GET/ADD), 8 without
-(the trainer's TRAINER_BLOCK_UPLOAD/IDS/STEP/LOSS, 2 UPDATE_DISPATCH and 2
-TABLE_GATHER_DISPATCH inside the server's handlers), stamps and closes
+WORKER_REPLY_ADD, 2 TABLE_WAIT, 4 CLIENT_ISSUE_GET/ADD), 7 without
+(the trainer's TRAINER_BLOCK_IDS/STEP/PACE, 2 UPDATE_DISPATCH and 2
+TABLE_GATHER_DISPATCH inside the server's handlers; 8 before PR 43, when
+the loop entered TRAINER_BLOCK_UPLOAD/IDS/STEP/LOSS), stamps and closes
 12 MAILBOX_WAIT (8 messages through the worker's mailbox, 4 through the
 server's) and adds 2 TABLE_WAKE (a stamp and a Monitor.add, as a
 mailbox's). Run from a checkout of an older commit it times the sites
@@ -48,6 +49,15 @@ def _has_caller_spans() -> bool:
     return "CLIENT_ISSUE_GET" in METRIC_NAMES
 
 
+def _plain_monitors() -> int:
+    """Monitors without arguments a block enters, in the tree this runs
+    from."""
+    from multiverso_tpu.util.dashboard import METRIC_NAMES
+    if not _has_caller_spans():
+        return 2
+    return 8 if "TRAINER_BLOCK_UPLOAD" in METRIC_NAMES else 7
+
+
 def plain():
     with monitor("TABLE_WAIT"):
         pass
@@ -77,7 +87,7 @@ def main() -> None:
         costs["monitor_with_request_id"] = us(with_args)
         costs["mailbox_wait"] = us(mailbox) - us(queue_only)
         issued = _has_caller_spans()
-        block = ((8 if issued else 2) * costs["monitor"]
+        block = (_plain_monitors() * costs["monitor"]
                  + (18 if issued else 10) * costs["monitor_with_request_id"]
                  + (14 if issued else 12) * costs["mailbox_wait"])
     for name, cost in costs.items():
