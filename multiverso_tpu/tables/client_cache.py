@@ -214,9 +214,15 @@ class RowCache:
 
     def __init__(self, bound: int, server_of: Callable, num_servers: int,
                  tracker: VersionTracker,
-                 capacity: Optional[int] = None) -> None:
+                 capacity: Optional[int] = None,
+                 server_of_rises: Optional[Callable[[], bool]] = None
+                 ) -> None:
         self._bound = int(bound)
         self._server_of = server_of  # vectorized row ids -> server ids
+        # True while server_of never falls as the row id grows (the
+        # frozen division rule; a live shard map need not). None: no
+        # such promise, ever.
+        self._server_of_rises = server_of_rises
         self._num_servers = int(num_servers)
         self._tracker = tracker
         self._capacity = int(capacity if capacity is not None  # guarded_by: _lock
@@ -362,25 +368,57 @@ class RowCache:
                 self._rows.pop(next(iter(self._rows)))
 
     # -- own-add self-invalidation --
-    def begin_add(self, row_ids: Optional[np.ndarray] = None):
+    def _fence_servers(self, row_ids, ends) -> List[int]:
+        """The servers an inactive cache's Add fences: the owners of
+        ``row_ids`` or a superset of them. One server is the answer
+        whatever the ids; under a ``server_of`` that rises with the
+        row id, every server from the smallest id's to the largest's
+        (one call on the two ends); otherwise (a live shard map, whose
+        owners may interleave, or no promise given) every server, as
+        for a whole-table Add."""
+        everyone = list(range(self._num_servers))
+        if row_ids is None:
+            return everyone
+        if ends is None and np.size(row_ids) == 0:
+            return []
+        if self._num_servers == 1:
+            return [0]
+        if self._server_of_rises is None:
+            return everyone
+        if ends is None:
+            ids = np.asarray(row_ids)
+            ends = (ids.min(), ids.max())
+        first, last = self._server_of(np.asarray(ends, dtype=np.int64))
+        # Asked AFTER the call: a map adopted on the worker's thread
+        # meanwhile (one is never dropped again) voids what the two
+        # ends said.
+        if not self._server_of_rises():
+            return everyone
+        return list(range(int(first), int(last) + 1))
+
+    def begin_add(self, row_ids: Optional[np.ndarray] = None,
+                  ends: Optional[Tuple[int, int]] = None):
         """Block the slots an own Add is about to dirty (None = whole
-        table). Returns a token for ``finish_add``.
+        table). Returns a token for ``finish_add``. ``ends`` is the
+        ids' smallest and largest value where the caller has them
+        (``MatrixWorker._check_row_ids``); without it they are taken
+        here.
 
         While INACTIVE there are no entries to block, but the ack must
         still FENCE the owning shards' floors: a Get reply served
         before this add could land after a live activation, store the
         pre-add value, and serve it within the widened bound — a
         read-your-writes violation across the activation edge. The
-        fence token costs O(owning servers), not O(rows)."""
+        fence token holds server ids and costs O(servers): it is named
+        from the ids' two ends and never reads the ids (no copy, no
+        sort, no ``server_of`` over the rows; ``_fence_servers``), so
+        it may name a server that owns none of them. That is sound: a
+        fence only raises ``_floor_all[sid]`` to the version seen at
+        the ack, floors only ever make serving stricter (a fenced
+        server's older entries are refetched, never served), and while
+        the cache is inactive nothing is served or stored at all."""
         if self._bound <= 0:
-            if row_ids is None:
-                sids = list(range(self._num_servers))
-            else:
-                rows = np.unique(np.asarray(
-                    row_ids, dtype=np.int64).reshape(-1))
-                sids = [int(s) for s in np.unique(
-                    self._server_of(rows))]
-            return ("fence", sids)
+            return ("fence", self._fence_servers(row_ids, ends))
         if row_ids is None:
             with self._lock:
                 self._pending_all += 1

@@ -33,7 +33,7 @@ from __future__ import annotations
 import functools
 import threading
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -322,7 +322,8 @@ class MatrixWorker(WorkerTable):
             self._row_cache = RowCache(
                 bound, self._server_of_rows,
                 max(self._zoo.num_servers, self._num_server),
-                self._version_tracker)
+                self._version_tracker,
+                server_of_rises=lambda: self._shard_map is None)
             self._caches.append(self._row_cache)
         # In-flight prefetch registry (+ dedup/join): msg_id -> sorted
         # unique ids being fetched; _pf_by_key dedups identical
@@ -442,16 +443,21 @@ class MatrixWorker(WorkerTable):
         return {int(s): self._version_tracker.latest(int(s))
                 for s in sids}
 
-    def _check_row_ids(self, row_ids: np.ndarray) -> None:
+    def _check_row_ids(self, row_ids: np.ndarray
+                       ) -> Optional[Tuple[int, int]]:
         """Fail fast in the CALLER on out-of-range ids. partition() runs
         inside the worker actor, where an exception is swallowed after
         reset(msg_id, 0) — the caller would see a 'successful' request
         backed by uninitialized memory (stray negative) or block forever
-        on a shard routed to server -1 (negative id in a vector)."""
-        if row_ids.size:
-            lo, hi = int(row_ids.min()), int(row_ids.max())
-            CHECK(lo >= 0 and hi < self.num_row,
-                  "row ids out of range [0, num_row)")
+        on a shard routed to server -1 (negative id in a vector).
+        Returns the ids' smallest and largest value (None when there
+        are no ids): what a row Add's cache fence is named from."""
+        if not row_ids.size:
+            return None
+        lo, hi = int(row_ids.min()), int(row_ids.max())
+        CHECK(lo >= 0 and hi < self.num_row,
+              "row ids out of range [0, num_row)")
+        return lo, hi
 
     def _check_frozen_layout(self, what: str) -> None:
         """Device-resident fast paths bake the frozen per-server
@@ -915,17 +921,19 @@ class MatrixWorker(WorkerTable):
         self._cache_resolve_on(mid, tok)
         return mid
 
-    def _cache_begin_add(self, row_ids: Optional[np.ndarray]):
+    def _cache_begin_add(self, row_ids: Optional[np.ndarray],
+                         ends: Optional[Tuple[int, int]] = None):
         """Block the client-cache slots this Add dirties (None = whole
         table) until its ack resolves them — read-your-writes. NOT
         gated on _live_cache(): an INACTIVE cache still needs the ack
         to fence its shard floors, or a live activation racing an
         in-flight add could serve the pre-add value afterwards
-        (RowCache.begin_add's fence token)."""
+        (RowCache.begin_add's fence token, named from ``ends``, the
+        ids' smallest and largest value)."""
         cache = self._row_cache
         if cache is None:
             return None
-        return cache.begin_add(row_ids)
+        return cache.begin_add(row_ids, ends)
 
     def _cache_resolve_on(self, msg_id: int, token) -> None:
         if token is not None:
@@ -974,7 +982,7 @@ class MatrixWorker(WorkerTable):
             self._cache_resolve_on(mid, tok)
             return mid
         row_ids = np.ascontiguousarray(row_ids, dtype=np.int32).reshape(-1)
-        self._check_row_ids(row_ids)
+        ends = self._check_row_ids(row_ids)
         if self._one_bit or self._lossy:
             # The error-feedback gather/write-back breaks on duplicates;
             # the chunk encoder's own CHECK fires inside the worker
@@ -985,7 +993,7 @@ class MatrixWorker(WorkerTable):
             delta = np.ascontiguousarray(delta, self.dtype).reshape(-1)
         CHECK(int(np.prod(delta.shape)) == row_ids.size * self.num_col,
               "bad delta size")
-        tok = self._cache_begin_add(row_ids)
+        tok = self._cache_begin_add(row_ids, ends)
         mid = self.add_async_raw(Blob(row_ids.view(np.uint8)),
                                  Blob(delta),
                                  self._option_blob(option))

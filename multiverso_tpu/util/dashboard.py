@@ -43,8 +43,10 @@ METRIC_NAMES: Dict[str, str] = {
                         "stamp, error, the waiter's notify",
     "CLIENT_ISSUE_GET": "caller's thread, a table's public async Get "
                         "entry to the message in the worker's mailbox",
-    "CLIENT_ISSUE_ADD": "the same for an Add (the cache's begin_add "
-                        "and the blobs included)",
+    "CLIENT_ISSUE_ADD": "the same for an Add (the ids' range check, "
+                        "the cache's begin_add — an inactive cache's "
+                        "fence is named from the ids' two ends — and "
+                        "the blobs included)",
     "TABLE_WAIT": "calling thread blocked in WorkerTable.wait on replies",
     "TABLE_WAKE": "the completing notify on the worker actor's thread "
                   "to a blocked WorkerTable.wait running again",

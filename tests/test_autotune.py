@@ -481,6 +481,40 @@ class TestLiveRetune:
         assert missing.size == 1, \
             "pre-add value served after the acked write (RYW)"
 
+    def test_activation_edge_ryw_fence_over_two_servers(self):
+        """The same interleaving with two servers and an Add whose ids
+        lie on server 1 alone. This cache was told nothing of how its
+        ``server_of`` runs, so its token fences server 0 too: harmless,
+        a pre-add row of server 0 is refetched, never served stale;
+        and server 1's pre-add row cannot serve either."""
+        from multiverso_tpu.tables.client_cache import (RowCache,
+                                                        VersionTracker)
+        tracker = VersionTracker()
+        cache = RowCache(0, lambda rows: np.asarray(rows) // 8, 2,
+                         tracker)
+        token = cache.begin_add(np.array([9, 13], np.int64))
+        assert token == ("fence", [0, 1])
+        cache._retune_bound(8)  # live activation (Control_Config)
+        # Delayed replies of before the add land on both servers ...
+        tracker.note(0, 2)
+        tracker.note(1, 3)
+        cache.store(np.array([2]), np.ones((1, 4), np.float32), 2, 0)
+        cache.store(np.array([9]), np.ones((1, 4), np.float32), 3, 1)
+        # ... each server's version moves on, the add acks, the fence
+        # fires at what was seen by then.
+        tracker.note(0, 3)
+        tracker.note(1, 4)
+        cache.finish_add(token)
+        assert cache._floor_all == {0: 3, 1: 4}
+        out = np.zeros((2, 4), np.float32)
+        missing = cache.fetch_into(np.array([2, 9], np.int64), out)
+        assert missing.tolist() == [2, 9], \
+            "a row stored before the fence served after it"
+        # A row fetched at or after the fence serves as ever.
+        cache.store(np.array([2]), np.ones((1, 4), np.float32), 3, 0)
+        assert cache.fetch_into(np.array([2], np.int64),
+                                out[:1]).size == 0
+
     def test_row_cache_capacity_retune_evicts(self, env):
         configure.apply_tunable("max_get_staleness", 8)
         table = mv.create_matrix_table(64, 2)

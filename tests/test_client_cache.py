@@ -467,3 +467,190 @@ def test_place_rows_is_the_per_position_loop(case, layout):
     moved = {k: Dashboard.get(n).count - before[k] for k, n in names.items()}
     np.testing.assert_array_equal(out, want)
     assert moved == {k: int(k == counted) for k in names}
+
+
+# ---------------------------------------------------------------------------
+# An inactive cache's fence token: the servers named from the ids' two
+# ends (RowCache._fence_servers).
+
+_FENCE_ROWS = 64
+
+
+def _division(servers):
+    """The frozen division rule over ``_FENCE_ROWS`` rows."""
+    length = _FENCE_ROWS // servers
+
+    def server_of(rows):
+        return np.minimum(np.asarray(rows) // length, servers - 1)
+    return server_of
+
+
+def _inactive_cache(servers, server_of=None, rises=True):
+    from multiverso_tpu.tables.client_cache import RowCache, VersionTracker
+    return RowCache(0, server_of or _division(servers), servers,
+                    VersionTracker(),
+                    server_of_rises=(lambda: rises)
+                    if rises is not None else None)
+
+
+def _fence_ids(kind, servers, rng):
+    """Ids of one ``kind`` and whether they touch every server between
+    their ends."""
+    length = _FENCE_ROWS // servers
+    if kind == "anywhere":
+        ids = rng.integers(0, _FENCE_ROWS, size=int(rng.integers(1, 40)))
+        return ids, None
+    if kind == "one server":
+        sid = int(rng.integers(0, servers))
+        return rng.integers(sid * length, (sid + 1) * length, size=7), True
+    if kind == "every server between":
+        first = int(rng.integers(0, servers))
+        last = int(rng.integers(first, servers))
+        ids = np.concatenate([
+            rng.integers(s * length, (s + 1) * length, size=3)
+            for s in range(first, last + 1)])
+        return ids, True
+    assert kind == "skips a server"  # the two outermost ranges only
+    return np.concatenate([rng.integers(0, length, size=4),
+                           rng.integers(_FENCE_ROWS - length, _FENCE_ROWS,
+                                        size=4)]), servers <= 2
+
+
+@pytest.mark.parametrize("order", ["sorted", "unsorted"])
+@pytest.mark.parametrize("kind", ["anywhere", "one server",
+                                  "every server between",
+                                  "skips a server"])
+@pytest.mark.parametrize("servers", [1, 2, 4])
+def test_inactive_fence_names_a_superset_of_the_owners(servers, kind,
+                                                       order):
+    """The token's servers hold every owner of the ids; they ARE the
+    owners with one server and wherever the ids touch every server
+    between their ends. With or without the caller's ``ends`` the token
+    is the same."""
+    rng = np.random.default_rng(servers * 31 + len(kind) + len(order))
+    server_of = _division(servers)
+    cache = _inactive_cache(servers)
+    for _ in range(25):
+        ids, exact = _fence_ids(kind, servers, rng)
+        ids = np.sort(ids) if order == "sorted" else rng.permutation(ids)
+        ids = ids.astype(np.int32)
+        owners = np.unique(server_of(ids)).tolist()
+        kind_, sids = cache.begin_add(ids, (int(ids.min()), int(ids.max())))
+        assert kind_ == "fence"
+        assert sids == sorted(set(sids))
+        assert set(sids) >= set(owners)
+        if servers == 1 or exact:
+            assert sids == owners
+        if exact is None and len(owners) == owners[-1] - owners[0] + 1:
+            assert sids == owners
+        assert cache.begin_add(ids) == ("fence", sids)
+        assert cache.begin_add(ids.tolist()) == ("fence", sids)
+
+
+@pytest.mark.parametrize("servers", [1, 2, 4])
+@pytest.mark.parametrize("empty", [np.zeros(0, np.int32), []],
+                         ids=["array", "list"])
+def test_inactive_fence_of_no_ids_names_no_server(servers, empty):
+    cache = _inactive_cache(servers)
+    assert cache.begin_add(empty) == ("fence", [])
+    assert cache.begin_add(None) == ("fence", list(range(servers)))
+
+
+@pytest.mark.parametrize("rises", [False, None],
+                         ids=["live shard map", "no promise"])
+@pytest.mark.parametrize("servers", [1, 2, 4])
+def test_inactive_fence_names_every_server_where_owners_may_interleave(
+        servers, rises):
+    """A ``server_of`` that may fall as the row grows (a live shard map;
+    a cache built with no word about it) cannot be read at two ids: every
+    server is fenced, as for a whole-table Add."""
+    interleaved = lambda rows: np.asarray(rows) // 4 % servers  # noqa: E731
+    cache = _inactive_cache(servers, interleaved, rises)
+    ids = np.array([5, 6], np.int32)  # one owner: server 1 % servers
+    assert cache.begin_add(ids, (5, 6)) == ("fence", list(range(servers)))
+    assert cache.begin_add(ids) == ("fence", list(range(servers)))
+
+
+@pytest.mark.parametrize("with_ends", [True, False])
+@pytest.mark.parametrize("servers", [1, 4])
+def test_inactive_begin_add_makes_no_array_of_the_rows(servers, with_ends,
+                                                       monkeypatch):
+    """A million ids: ``server_of`` sees at most two of them, nothing is
+    sorted, and the ids are never copied to another type."""
+    rows = 1_000_000
+    seen = []
+
+    def server_of(ids):
+        seen.append(np.size(ids))
+        return np.minimum(np.asarray(ids) // (rows // servers), servers - 1)
+
+    def refuse(*_args, **_kwargs):
+        raise AssertionError("the inactive branch went over the rows")
+
+    cache = _inactive_cache(servers, server_of)
+    ids = np.arange(rows, dtype=np.int32)[::-1]
+    monkeypatch.setattr(np, "unique", refuse)
+    monkeypatch.setattr(np, "sort", refuse)
+    monkeypatch.setattr(np, "argsort", refuse)
+    ends = (0, rows - 1) if with_ends else None
+    assert cache.begin_add(ids, ends) == ("fence", list(range(servers)))
+    assert max(seen, default=0) <= 2
+    assert len(seen) <= 1
+
+
+@pytest.mark.parametrize("servers", [1, 2])
+def test_host_row_add_on_an_inactive_cache_then_live_activation(servers):
+    """``add_rows_async`` with host ids while the cache is inactive, then
+    ``-max_get_staleness`` raised live, then Gets: an acknowledged Add is
+    in every later Get, before and after the cache serves; ids out of
+    range still fail in the caller."""
+    from multiverso_tpu.util import configure
+    rows, cols = 32, 3
+
+    def body(rank):
+        table = mv.create_matrix_table(rows, cols)
+        zoo = mv.current_zoo()
+        zoo.barrier()
+        if rank == 0:
+            cache = table._row_cache
+            assert not cache.active
+            want = np.zeros((rows, cols), np.float32)
+            everything = np.arange(rows, dtype=np.int32)
+            # On the last server alone, then across the table, unsorted.
+            for ids in ([rows - 1, rows - 3], [rows - 2, 1, 7, 1]):
+                ids = np.asarray(ids, np.int32)
+                delta = np.full((ids.size, cols), 1.5, np.float32)
+                assert table.wait(table.add_rows_async(ids, delta),
+                                  timeout=20)
+                np.add.at(want, ids, delta)
+            for bad in ([rows], [-1, 3]):
+                with pytest.raises(Exception, match="out of range"):
+                    table.add_rows_async(
+                        np.asarray(bad, np.int32),
+                        np.zeros((len(bad), cols), np.float32))
+            try:
+                configure.apply_tunable("max_get_staleness", 8)
+                assert cache.active
+                np.testing.assert_array_equal(
+                    table.get_rows(everything), want)
+                # Served from the cache now; an own Add still shows.
+                np.testing.assert_array_equal(
+                    table.get_rows(everything), want)
+                ids = np.array([rows - 1, 0], np.int32)
+                table.add_rows(ids, np.ones((2, cols), np.float32))
+                want[ids] += 1.0
+                np.testing.assert_array_equal(
+                    table.get_rows(everything), want)
+            finally:
+                configure.apply_tunable("max_get_staleness", 0)
+        zoo.barrier()
+        return True
+
+    if servers == 1:
+        mv.init([])
+        try:
+            assert body(0)
+        finally:
+            mv.shutdown()
+    else:
+        assert LocalCluster(servers).run(body)[0]
