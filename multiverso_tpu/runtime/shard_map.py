@@ -1,7 +1,7 @@
 """Epoch-stamped dynamic shard maps + live row migration (ISSUE 12).
 
 Extension over the reference: Multiverso freezes the row→server layout
-at table creation (``row_offsets`` in tables/matrix_table.py, ref:
+at table creation (``row_offsets`` in sharding/rows.py, ref:
 matrix_table.cpp:23-45) — a production PS must absorb a new server or
 drain a retiring one without a stop-the-world. This module supplies the
 three coordinated pieces (full protocol spec in docs/SHARDING.md,
@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..sharding.rows import row_offsets
 from ..util import log
 from ..util.configure import define_bool, define_double, define_int, get_flag
 
@@ -107,7 +108,6 @@ class ShardMap:
         over the first ``active`` servers (default: all) — a
         never-resharded cluster routes bit-identically to the
         reference's static split."""
-        from ..tables.matrix_table import row_offsets
         n = int(num_servers) if active is None \
             else min(int(active), int(num_servers))
         offsets = row_offsets(int(num_items), max(n, 1))
@@ -194,7 +194,6 @@ def plan_moves(current: ShardMap,
     sid order — the target layout is ``row_offsets`` over the active
     set, so growing back to the full fleet restores the frozen
     reference layout exactly)."""
-    from ..tables.matrix_table import row_offsets
     sids = sorted({int(s) for s in active_sids})
     if not sids:
         return []

@@ -419,8 +419,8 @@ class Worker(Actor):
         # Every shard reply — error or not — counts exactly one notify
         # (the finally), so the waiter completes only after ALL shards
         # report; wait() then raises on any recorded failure. Releasing
-        # early on the first error would let a late sibling reply write
-        # into a subsequent request's destination registers. EXCEPTION:
+        # early on the first error would hand the caller a buffer that a
+        # late sibling reply still writes. EXCEPTION:
         # a replica-routed shard that came back short (holder missing
         # rows / below a read-your-writes floor) TRANSFERS its notify
         # onto the repair request(s) it stages — the waiter then

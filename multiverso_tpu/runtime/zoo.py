@@ -32,6 +32,7 @@ from ..core.node import Node, Role, is_server, is_worker, role_from_string
 # module — a cycle at import time) and is therefore loaded lazily in
 # _start_serving.
 from ..serving import admission as _serving_admission  # noqa: F401
+from ..sharding.rows import row_offsets
 from ..util import log
 from ..util.configure import (define_bool, define_double, define_int,
                               define_string, get_flag, parse_cmd_flags)
@@ -666,7 +667,6 @@ class Zoo:
         # a multi-move plan passes through intermediate maps whose
         # owner set already matches (the first grow move creates the
         # new server's first interval long before the spread evens).
-        from ..tables.matrix_table import row_offsets
         offsets = row_offsets(space, len(target))
         expected = (list(offsets),
                     [target[i] for i in range(len(offsets) - 1)])

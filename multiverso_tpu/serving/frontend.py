@@ -78,9 +78,10 @@ _SERVER, _WORKER, _COMMUNICATOR, _CONTROLLER = (
 
 class _ServedTable:
     """Registry entry: a worker-table handle plus the serving-side
-    per-table state — the index lock (whole-table snapshot fetches
-    still ride the table's one-get-in-flight registers), the batched
-    scatter reader + hot-response cache (serving/batch.py), and the
+    per-table state — the index lock (one whole-table snapshot fetch
+    at a time, and one versioned read, which counts hits around its
+    Get), the batched scatter reader + hot-response cache
+    (serving/batch.py), and the
     lazily refreshed nearest-neighbor index (brute snapshot + the
     optional IVF structure over it, serving/ann.py)."""
 
@@ -324,7 +325,7 @@ class ServingFrontend(HttpServer):
                 rendered = np.asarray(values).tolist()
             else:
                 # -serving_scatter=false escape hatch: the serialized
-                # PR-10 one-get-in-flight path.
+                # PR-10 path, one read a table at a time.
                 with entry.lock:
                     values, meta = entry.table.read_rows_versioned(ids)
                 rendered = np.asarray(values).tolist()

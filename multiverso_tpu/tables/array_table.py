@@ -34,7 +34,8 @@ from ..updater import AddOption, UpdateEngine, create_rule
 from ..util.log import CHECK
 from . import client_cache
 from .client_cache import BlobCache
-from .table_interface import (ServerTable, WorkerTable, issues_add,
+from .table_interface import (CacheOnlySink, DeviceSink, ServerTable,
+                              TableSink, WorkerTable, issues_add,
                               issues_get)
 
 _ALL_KEY = np.array([-1], dtype=np.int32)
@@ -57,11 +58,8 @@ class ArrayWorker(WorkerTable):
         self.dtype = np.dtype(dtype)
         self._num_server = self._zoo.num_servers
         self._offsets = server_offsets(self.size, self._num_server)
-        # One outstanding Get per table, same as the reference's shared
-        # row_index_/data_ destination registers (ref: matrix_table.cpp:
-        # 66-76). _dest xor _device_shards names the reply destination.
-        self._dest: Optional[np.ndarray] = None
-        self._device_shards: Optional[Dict[int, object]] = None
+        # The sink of the last device Get issued, for get_device.
+        self._last_device: Optional[DeviceSink] = None
         # Client cache (-max_get_staleness > 0): whole-blob — one entry
         # per server shard, a hit requires every shard fresh (array Gets
         # are whole-table). Device gets bypass (live jax.Array replies).
@@ -75,31 +73,33 @@ class ArrayWorker(WorkerTable):
 
     # -- public API (ref: array_table.cpp:29-66) --
     def get(self, out: Optional[np.ndarray] = None) -> np.ndarray:
+        if out is None:
+            out = np.empty(self.size, self.dtype)
         self.retrying_wait(lambda: self.get_async(out))
-        return self._dest
+        return out
 
     @issues_get
     def get_async(self, out: Optional[np.ndarray] = None) -> int:
         if out is None:
             out = np.empty(self.size, self.dtype)
         CHECK(out.size == self.size, "output buffer size mismatch")
-        self._dest, self._device_shards = out, None
         if self._blob_cache is not None:
             shards = self._blob_cache.fetch_all()
             if shards is not None:
                 # Same write form as the uncached reply path
-                # (_dest[lo:hi] = values): reshape(-1) would silently
+                # (out[lo:hi] = values): reshape(-1) would silently
                 # COPY a non-contiguous buffer and drop the fill.
                 for sid, values in shards.items():
                     out[self._offsets[sid]:self._offsets[sid + 1]] = \
                         values
                 return self._local_done()
-        return self.get_async_raw(Blob(_ALL_KEY.view(np.uint8)))
+        return self._get_to(TableSink(out, self._offsets),
+                            [Blob(_ALL_KEY.view(np.uint8))])
 
     def prefetch_async(self) -> int:
-        """Warm the whole-blob client cache without touching the Get
-        destination registers; identical in-flight prefetches dedup to
-        one wire request. No-op when the cache is disabled."""
+        """Warm the whole-blob client cache: a Get whose sink is the
+        cache alone; identical in-flight prefetches dedup to one wire
+        request. No-op when the cache is disabled."""
         if self._blob_cache is None:
             return self._local_done()
         if self._pf_id is not None:
@@ -107,6 +107,7 @@ class ArrayWorker(WorkerTable):
         if self._blob_cache.fresh_all():  # counter-free planning check
             return self._local_done()
         msg_id = self._new_request()
+        self._sinks[msg_id] = CacheOnlySink()
         self._pf_id = msg_id
         self.add_completion(msg_id, self._on_prefetch_done)
         self._send_request(MsgType.Request_Get,
@@ -163,9 +164,8 @@ class ArrayWorker(WorkerTable):
         """Whole-table Get returning a device array (no host transfer).
         The reply shards are the servers' jitted snapshots in HBM."""
         self.wait(self.get_device_async())
-        shards = [self._device_shards[sid]
-                  for sid in range(len(self._device_shards))]
-        self._device_shards = None
+        sink, self._last_device = self._last_device, None
+        shards = sink.ordered()
         if len(shards) == 1:
             return shards[0]
         import jax.numpy as jnp
@@ -176,34 +176,28 @@ class ArrayWorker(WorkerTable):
 
     @issues_get
     def get_device_async(self) -> int:
-        self._dest, self._device_shards = None, {}
-        return self.get_async_raw(Blob(_ALL_KEY.view(np.uint8)))
+        self._last_device = DeviceSink()
+        return self._get_to(self._last_device,
+                            [Blob(_ALL_KEY.view(np.uint8))])
 
     # -- reply (ref: array_table.cpp:95-106) --
     def process_reply_get(self, reply_blobs: List[Blob]) -> None:
+        """One server's whole shard, to the sink its request
+        registered: a device sink takes it still in HBM, a host one
+        after the client cache (every host Get refreshes it, a prefetch
+        does nothing else)."""
+        sink = self._reply_sink()
         server_id = int(reply_blobs[0].as_array(np.int32)[0])
-        if self._reply_msg_id >= 0 and self._reply_msg_id == self._pf_id:
-            # Prefetch reply shard: cache only — the destination
-            # registers belong to whatever real Get is in flight.
+        if sink.device:
+            values = reply_blobs[1].typed(self.dtype)
+        else:
+            values = reply_blobs[1].as_array(self.dtype)
+            lo, hi = self._offsets[server_id], self._offsets[server_id + 1]
+            CHECK(values.size == hi - lo, "reply shard size mismatch")
             if self._blob_cache is not None:
-                self._blob_cache.store(
-                    server_id, reply_blobs[1].as_array(self.dtype),
-                    self._reply_version)
-            return
-        if self._device_shards is not None:  # device-resident get
-            self._device_shards[server_id] = reply_blobs[1].typed(self.dtype)
-            return
-        CHECK(self._dest is not None,
-              "Get reply with no outstanding destination — only one Get "
-              "may be in flight per table (as in the reference)")
-        values = reply_blobs[1].as_array(self.dtype)
-        lo, hi = self._offsets[server_id], self._offsets[server_id + 1]
-        CHECK(values.size == hi - lo, "reply shard size mismatch")
-        self._dest[lo:hi] = values
-        if self._blob_cache is not None:
-            # Wire-path population: real Gets refresh the cache too.
-            self._blob_cache.store(server_id, values,
-                                   self._reply_version)
+                self._blob_cache.store(server_id, values,
+                                       self._reply_version)
+        sink.place(None, values, self._reply_version, server_id)
 
 
 class ArrayServer(ServerTable):

@@ -51,15 +51,16 @@ from ..util.dashboard import monitor
 from . import client_cache
 from .client_cache import RowCache
 from ..sharding import mesh as meshlib
+from ..sharding.rows import row_offsets
 from ..updater import AddOption, GetOption, UpdateEngine, create_rule
 from ..updater.engine import DEVICE_KEYS_REFUSED, bucket_size, pad_ids
 from ..util import log, wire_codec
 from ..util.configure import define_bool, get_flag
 from ..util.log import CHECK
 from ..util.quantization import OneBitFilter
-from .table_interface import (RpcTimeoutError, ServerTable,
-                              TableRequestError, WorkerTable, issues_add,
-                              issues_get)
+from .table_interface import (CacheOnlySink, DeviceSink, RpcTimeoutError,
+                              ServerTable, TableRequestError, TableSink,
+                              WorkerTable, issues_add, issues_get)
 from ..runtime.net import PeerLostError
 
 define_bool("sparse_compress", True,
@@ -179,37 +180,36 @@ def _trim_rows(values, n_rows: int):
     return values
 
 
-def row_offsets(num_row: int, num_servers: int) -> List[int]:
-    """Row ranges per server incl. the degenerate rows<servers layout
-    (ref: matrix_table.cpp:24-41). Returns num_actual_servers+1 offsets."""
-    offsets = [0]
-    length = num_row // num_servers
-    if length > 0:
-        offset = length
-        i = 0
-        while length > 0 and offset < num_row and i + 1 < num_servers:
-            offsets.append(offset)
-            offset += length
-            i += 1
-    else:
-        offset = 1
-        i = 0
-        while offset < num_row and i + 1 < num_servers:
-            offsets.append(offset)
-            offset += 1
-            i += 1
-    offsets.append(num_row)
-    return offsets
+class _RowsSink:
+    """A host row Get: every position of ``row_ids`` whose id a reply
+    shard carries gets that row in ``out``. Ids may repeat (power-of-two
+    padded row sets repeat the last id thousands of times), and a shard
+    carries one server's key subset, possibly only the rows a partial
+    cache hit still missed: ``place_rows`` picks the form, one copy where
+    the shard is the request or a run of a sorted one."""
+
+    device = False
+    __slots__ = ("row_ids", "out")
+
+    def __init__(self, row_ids: np.ndarray, out: np.ndarray):
+        self.row_ids = row_ids
+        self.out = out
+
+    def place(self, keys, values, version, server) -> None:
+        with monitor("CLIENT_PLACE_ROWS"):
+            client_cache.place_rows(keys, values, self.row_ids, self.out)
 
 
 class _ScatterRead:
-    """One in-flight scatter-gather serving read (docs/SERVING.md):
-    ``rows`` is the SORTED UNIQUE requested id vector; sub-request
-    replies (worker actor thread) place values and per-row fetch
-    versions at ``searchsorted`` positions. Requester threads read the
-    buffers only after every sub-request's waiter completed, so no
-    locking is needed — each reply writes a disjoint position set."""
+    """One in-flight scatter-gather serving read (docs/SERVING.md), the
+    sink of each of its sub-requests: ``rows`` is the SORTED UNIQUE
+    requested id vector; reply shards (worker actor thread) place values
+    and per-row fetch versions at ``searchsorted`` positions. Requester
+    threads read the buffers only after every sub-request's waiter
+    completed, so no locking is needed — each reply writes a disjoint
+    position set."""
 
+    device = False
     __slots__ = ("rows", "out", "versions")
 
     def __init__(self, rows: np.ndarray, out: np.ndarray,
@@ -217,6 +217,19 @@ class _ScatterRead:
         self.rows = rows
         self.out = out
         self.versions = versions
+
+    def place(self, keys, values, version, server) -> None:
+        if keys.size == 0:
+            return
+        with monitor("CLIENT_PLACE_ROWS"):
+            pos = np.minimum(np.searchsorted(self.rows, keys),
+                             self.rows.size - 1)
+            ok = self.rows[pos] == keys  # repairs may widen to
+            pos = pos[ok]          # rows outside this read's set
+            self.out[pos] = values[ok]
+            if version >= 0:
+                self.versions[pos] = np.maximum(
+                    self.versions[pos], int(version))
 
 
 @dataclass
@@ -293,13 +306,9 @@ class MatrixWorker(WorkerTable):
         # Worker actor thread swaps it; requester threads read it —
         # one attribute, GIL-atomic.
         self._shard_map: Optional[shard_map_mod.ShardMap] = None
-        # One outstanding Get per table (the reference's shared row_index_
-        # registers, ref: matrix_table.cpp:66-76). _dest xor _device_shards
-        # names the reply destination.
-        self._dest: Optional[np.ndarray] = None
-        self._dest_rows: Optional[np.ndarray] = None  # requested row-id vector
-        self._device_shards: Optional[Dict[int, object]] = None
-        self._device_shard_ids: Optional[Dict[int, np.ndarray]] = None
+        # The sink of the last device Get issued, for take_device_rows
+        # (the trainers call it after ``wait``).
+        self._last_device: Optional[DeviceSink] = None
         self._mirror_verified = False  # -verify_device_ids: once per table
         # Client cache (-max_get_staleness > 0): row-granular, DENSE
         # host-path row Gets only. Sparse tables are excluded — their
@@ -327,23 +336,15 @@ class MatrixWorker(WorkerTable):
             self._caches.append(self._row_cache)
         # In-flight prefetch registry (+ dedup/join): msg_id -> sorted
         # unique ids being fetched; _pf_by_key dedups identical
-        # prefetches; _pf_joined holds Gets deferred onto an in-flight
-        # prefetch (served from the cache — or forwarded to the wire —
-        # when it completes). Guarded by _pf_lock: prefetches/joins
-        # issue on the requester's thread, completion runs on the
-        # worker actor's.
+        # prefetches; _pf_joined holds the ids of Gets deferred onto an
+        # in-flight prefetch (served from the cache — or forwarded to
+        # the wire — when it completes; their buffers are their sinks').
+        # Guarded by _pf_lock: prefetches/joins issue on the requester's
+        # thread, completion runs on the worker actor's.
         self._pf_lock = threading.Lock()
         self._pf_rows: Dict[int, np.ndarray] = {}
         self._pf_by_key: Dict[bytes, int] = {}
-        self._pf_joined: Dict[int, List] = {}
-        # Scatter-gather serving reads (read_rows_scatter,
-        # docs/SERVING.md): msg_id -> _ScatterRead. Each sub-request
-        # carries its OWN destination buffer, so any number may be in
-        # flight concurrently — unlike the one-get-in-flight _dest
-        # registers. Registered on the requester thread BEFORE the
-        # send, read on the worker actor thread (dict get/pop,
-        # GIL-atomic; registration happens-before the mailbox push).
-        self._sg: Dict[int, _ScatterRead] = {}
+        self._pf_joined: Dict[int, List[int]] = {}
         # Hot-shard read replication routing (runtime/replica.py,
         # docs/SHARDING.md): the promoted-row map re-routes the
         # replicated subset of a host row Get to holder servers
@@ -470,11 +471,11 @@ class MatrixWorker(WorkerTable):
 
     # -- Get API (ref: matrix_table.cpp:58-105) --
     def get(self, out: Optional[np.ndarray] = None) -> np.ndarray:
+        out = self._table_out(out)
         self.retrying_wait(lambda: self.get_async(out))
-        return self._dest
+        return out
 
-    @issues_get
-    def get_async(self, out: Optional[np.ndarray] = None) -> int:
+    def _table_out(self, out: Optional[np.ndarray]) -> np.ndarray:
         if out is None:
             # Sparse whole-table gets return only dirty rows, so a fresh
             # destination must be zeroed or the clean rows would surface
@@ -483,6 +484,11 @@ class MatrixWorker(WorkerTable):
             alloc = np.zeros if self.is_sparse else np.empty
             out = alloc((self.num_row, self.num_col), self.dtype)
         CHECK(out.shape == (self.num_row, self.num_col), "bad output shape")
+        return out
+
+    @issues_get
+    def get_async(self, out: Optional[np.ndarray] = None) -> int:
+        out = self._table_out(out)
         if self._shard_map is not None and not self.is_sparse:
             # Dynamic map: the whole-table sentinel's reply placement
             # assumes the frozen per-server offsets — route as an
@@ -492,44 +498,43 @@ class MatrixWorker(WorkerTable):
             # hot path (docs/SHARDING.md).
             return MatrixWorker.get_rows_async.__wrapped__(  # one span
                 self, np.arange(self.num_row, dtype=np.int32), out)
-        self._dest, self._dest_rows, self._device_shards = out, None, None
-        return self._request_get(Blob(_ALL_KEY.view(np.uint8)))
+        return self._request_get(Blob(_ALL_KEY.view(np.uint8)),
+                                 TableSink(out, self._offsets))
 
     def get_rows(self, row_ids, out: Optional[np.ndarray] = None
                  ) -> np.ndarray:
+        row_ids, out = self._rows_out(row_ids, out)
         self.retrying_wait(lambda: self.get_rows_async(row_ids, out))
-        return self._dest
+        return out
+
+    def _rows_out(self, row_ids, out: Optional[np.ndarray]):
+        row_ids = np.ascontiguousarray(row_ids, dtype=np.int32).reshape(-1)
+        if out is None:
+            out = np.empty((row_ids.size, self.num_col), self.dtype)
+        CHECK(out.shape == (row_ids.size, self.num_col), "bad output shape")
+        return row_ids, out
 
     @issues_get
     def get_rows_async(self, row_ids,
                        out: Optional[np.ndarray] = None) -> int:
-        row_ids = np.ascontiguousarray(row_ids, dtype=np.int32).reshape(-1)
-        self._check_row_ids(row_ids)
-        if out is None:
-            out = np.empty((row_ids.size, self.num_col), self.dtype)
-        CHECK(out.shape == (row_ids.size, self.num_col), "bad output shape")
-        self._dest = out
-        # The requested id vector, kept for reply placement. Ids may
-        # repeat (e.g. power-of-two padded row sets repeat the last id);
-        # every requested position gets its id's row.
-        self._dest_rows = row_ids
-        self._device_shards = None
+        sink = _RowsSink(*self._rows_out(row_ids, out))
+        self._check_row_ids(sink.row_ids)
         if self._live_cache() is not None:
             # Partial-hit serve: fresh rows fill their positions
             # locally; only the MISSING unique rows go to the wire (the
             # reply placement already handles subset keys). A fully
             # fresh request never leaves the process.
-            missing = self._row_cache.fetch_into(row_ids, out)
+            missing = self._row_cache.fetch_into(sink.row_ids, sink.out)
             if missing.size == 0:
                 return self._local_done()
             # Dedup: missing rows already being fetched by an in-flight
             # prefetch — defer onto its completion instead of issuing a
             # second wire message for the same rows.
-            joined = self._join_inflight(missing, row_ids, out)
+            joined = self._join_inflight(missing, sink)
             if joined is not None:
                 return joined
-            return self._request_get(Blob(missing.view(np.uint8)))
-        return self._request_get(Blob(row_ids.view(np.uint8)))
+            return self._request_get(Blob(missing.view(np.uint8)), sink)
+        return self._request_get(Blob(sink.row_ids.view(np.uint8)), sink)
 
     # -- serving-tier read (serving/frontend.py, docs/SERVING.md) --
     def read_rows_versioned(self, row_ids, out: Optional[np.ndarray]
@@ -563,8 +568,8 @@ class MatrixWorker(WorkerTable):
         measure rows against observations the serve never saw and
         overshoot the bound spuriously.)
 
-        Same concurrency contract as ``get_rows``: one Get in flight
-        per table — the serving frontend serializes calls per table.
+        The hit counters are read around the Get, so the serving
+        frontend serializes calls per table.
         """
         row_ids = np.ascontiguousarray(row_ids,
                                        dtype=np.int32).reshape(-1)
@@ -611,11 +616,10 @@ class MatrixWorker(WorkerTable):
 
     def read_rows_scatter(self, row_ids):
         """Concurrent scatter-gather serving read (docs/SERVING.md
-        fleet section): unlike ``get_rows``/``read_rows_versioned`` —
-        which share the table's one-get-in-flight destination
-        registers and therefore serialize — each call owns its buffers
-        end to end, so any number of serving threads may read
-        concurrently while a trainer Adds.
+        fleet section): one read is several Gets, one per owning
+        server shard, all into the one ``_ScatterRead``; like every Get
+        it owns its buffers end to end, so any number of serving
+        threads may read concurrently while a trainer Adds.
 
         The missing (cache-cold) rows fan out as ONE sub-request per
         owning server shard; ``partition`` routes each exactly as a
@@ -678,32 +682,20 @@ class MatrixWorker(WorkerTable):
             groups = []
             for sid in np.unique(group_sids):
                 grp = np.ascontiguousarray(missing[group_sids == sid])
-                msg_id = self._new_request()
-                self._sg[msg_id] = entry
-                groups.append((msg_id, grp))
+                groups.append((self._get_to(
+                    entry, [Blob(grp.view(np.uint8))]), grp))
             for msg_id, grp in groups:
-                self._send_request(MsgType.Request_Get,
-                                   [Blob(grp.view(np.uint8))], msg_id)
-            try:
-                for msg_id, grp in groups:
-                    try:
-                        self.wait(msg_id)
-                    except (PeerLostError, RpcTimeoutError):
-                        failed_groups.append(grp)
-                    except TableRequestError:
-                        # Non-retryable: kept SEPARATE from the
-                        # retryable groups so a caller can decide per
-                        # ROW — one fatal group must not turn another
-                        # group's transient failure into a hard error.
-                        failed_groups.append(grp)
-                        fatal_groups.append(grp)
-                    finally:
-                        self._sg.pop(msg_id, None)
-            finally:
-                # ClusterAborted mid-loop must not strand later
-                # entries (pop is idempotent).
-                for msg_id, _ in groups:
-                    self._sg.pop(msg_id, None)
+                try:
+                    self.wait(msg_id)
+                except (PeerLostError, RpcTimeoutError):
+                    failed_groups.append(grp)
+                except TableRequestError:
+                    # Non-retryable: kept SEPARATE from the
+                    # retryable groups so a caller can decide per
+                    # ROW — one fatal group must not turn another
+                    # group's transient failure into a hard error.
+                    failed_groups.append(grp)
+                    fatal_groups.append(grp)
         failed = np.unique(np.concatenate(failed_groups)) \
             .astype(np.int32) if failed_groups \
             else np.empty(0, np.int32)
@@ -719,10 +711,9 @@ class MatrixWorker(WorkerTable):
 
     # -- client-cache prefetch + in-flight Get dedup --
     def prefetch_rows_async(self, row_ids) -> int:
-        """Warm the client cache for ``row_ids`` without touching the
-        one-Get-in-flight destination registers: the reply routes into
-        the cache, so a later ``get_rows`` for (a subset of) these rows
-        hits locally or joins the in-flight fetch. Double-buffering
+        """Warm the client cache for ``row_ids``: a Get whose sink is
+        the cache alone, so a later ``get_rows`` for (a subset of) these
+        rows hits locally or joins the in-flight fetch. Double-buffering
         trainers call this for step i+1's rows while step i computes,
         overlapping wire latency with device work. Returns a request id
         (``wait`` is optional — the trainer usually never waits).
@@ -744,6 +735,7 @@ class MatrixWorker(WorkerTable):
             if existing is not None:
                 return existing  # identical prefetch already in flight
             msg_id = self._new_request()
+            self._sinks[msg_id] = CacheOnlySink()
             self._pf_rows[msg_id] = rows
             self._pf_by_key[key] = msg_id
             # Registered BEFORE the send: the completion sweep must be
@@ -754,8 +746,8 @@ class MatrixWorker(WorkerTable):
                            [Blob(rows.view(np.uint8))], msg_id)
         return msg_id
 
-    def _join_inflight(self, missing: np.ndarray, row_ids: np.ndarray,
-                       out: np.ndarray) -> Optional[int]:
+    def _join_inflight(self, missing: np.ndarray,
+                       sink: _RowsSink) -> Optional[int]:
         """If an in-flight prefetch covers every MISSING row, defer
         this Get onto it: completion re-serves from the cache, fetching
         over the wire only what still isn't there. Either way the
@@ -766,8 +758,8 @@ class MatrixWorker(WorkerTable):
             for pf_id, pf_rows in self._pf_rows.items():
                 if np.isin(missing, pf_rows).all():
                     msg_id = self._new_request()
-                    self._pf_joined.setdefault(pf_id, []).append(
-                        (msg_id, row_ids, out))
+                    self._sinks[msg_id] = sink
+                    self._pf_joined.setdefault(pf_id, []).append(msg_id)
                     count_event(client_cache.JOIN)
                     return msg_id
         return None
@@ -782,10 +774,13 @@ class MatrixWorker(WorkerTable):
             if rows is not None:
                 self._pf_by_key.pop(rows.tobytes(), None)
             joined = self._pf_joined.pop(pf_id, [])
-        for msg_id, req_rows, out in joined:
+        for msg_id in joined:
+            sink = self._sinks.get(msg_id)
+            if sink is None:
+                continue  # timed out meanwhile: nobody waits for it
             # count_stats=False: the joined Get already counted its
             # miss at issue time — the re-serve must not double-count.
-            missing = self._row_cache.fetch_into(req_rows, out,
+            missing = self._row_cache.fetch_into(sink.row_ids, sink.out,
                                                  count_stats=False)
             if missing.size == 0:
                 self.notify(msg_id)
@@ -834,10 +829,8 @@ class MatrixWorker(WorkerTable):
                   "keys to host bytes and the reply shape contract "
                   "breaks)")
             CHECK(not self._compress, "device gets bypass wire compression")
-            self._dest, self._dest_rows = None, None
-            self._device_shards = {}
-            self._device_sum = self._num_server > 1
-            return self._request_get(Blob(row_ids))
+            return self._request_get_device(
+                Blob(row_ids), sums=self._num_server > 1)
         row_ids = np.ascontiguousarray(row_ids, dtype=np.int32).reshape(-1)
         CHECK(row_ids.size > 0, "empty device row get")
         self._check_row_ids(row_ids)
@@ -845,11 +838,7 @@ class MatrixWorker(WorkerTable):
         if self._num_server > 1:
             CHECK(bool(np.all(np.diff(row_ids) >= 0)),
                   "device row gets need sorted row ids")
-        self._dest, self._dest_rows = None, None
-        self._device_shards = {}
-        self._device_sum = False  # host-key replies CONCATENATE (a
-        # stale True from an errored device-key get must not survive)
-        return self._request_get(Blob(row_ids.view(np.uint8)))
+        return self._request_get_device(Blob(row_ids.view(np.uint8)))
 
     def take_device_rows(self):
         """Assembled result of the last ``get_rows_device_async`` (call
@@ -857,15 +846,15 @@ class MatrixWorker(WorkerTable):
         replies SUM (each server zero-fills foreign rows); host-key
         multi-server replies concatenate (each server returned its
         contiguous sorted segment)."""
-        ordered = self.take_device_row_parts()
+        sink = self._take_device()
+        ordered = sink.ordered()
         if len(ordered) == 1:
             return ordered[0]
         import jax.numpy as jnp
         # Worker-thread reassembly dispatch: guarded like any other
         # multi-device program (multi-zoo mode only; no-op otherwise).
         with device_lock.guard():
-            if getattr(self, "_device_sum", False):
-                self._device_sum = False
+            if sink.sums:
                 return device_lock.settle(
                     functools.reduce(jnp.add, ordered))
             return device_lock.settle(jnp.concatenate(ordered, axis=0))
@@ -877,19 +866,28 @@ class MatrixWorker(WorkerTable):
         of paying a separate device op (one more per-dispatch launch
         cost). Replies carry the origin server id, so parts return in
         SERVER order (the broadcast sum is order-independent)."""
-        shards = self._device_shards
-        CHECK(shards is not None and len(shards) > 0,
-              "no device row get outstanding")
-        self._device_shards = None
-        return [shards[sid] for sid in sorted(shards)]
+        return self._take_device().ordered()
 
-    def _request_get(self, keys: Blob) -> int:
-        extra = []
+    def _take_device(self) -> DeviceSink:
+        sink, self._last_device = self._last_device, None
+        CHECK(sink is not None and len(sink.parts) > 0,
+              "no device row get outstanding")
+        return sink
+
+    def _request_get(self, keys: Blob, sink) -> int:
+        blobs = [keys]
         if self.is_sparse:
             # Sparse gets carry the asking worker's id
             # (ref: sparse_matrix_table.h:27-43).
-            extra.append(GetOption(self._zoo.worker_id).to_blob())
-        return self.get_async_raw(keys, extra)
+            blobs.append(GetOption(self._zoo.worker_id).to_blob())
+        return self._get_to(sink, blobs)
+
+    def _request_get_device(self, keys: Blob, sums: bool = False) -> int:
+        """A Get whose reply parts stay in HBM, for ``take_device_rows``
+        after ``wait``."""
+        self._last_device = DeviceSink(
+            sums, row_length=self._row_length, num_server=self._num_server)
+        return self._request_get(keys, self._last_device)
 
     # -- Add API (ref: matrix_table.cpp:110-147) --
     def add(self, delta, option: Optional[AddOption] = None) -> None:
@@ -1197,14 +1195,10 @@ class MatrixWorker(WorkerTable):
         CHECK(self._zoo.servers_in_process,
               "device dirty gets need the servers in this process "
               "(the reply payload is a live device array)")
-        self._dest, self._dest_rows = None, None
-        self._device_shards = {}
-        self._device_sum = False
-        self._device_shard_ids = {}
+        sink = DeviceSink(keep_ids=True)
         self.wait(self._request_get(
-            Blob(_ALL_KEY_DEVICE_REPLY.view(np.uint8))))
-        shards, ids = self._device_shards, self._device_shard_ids
-        self._device_shards, self._device_shard_ids = None, None
+            Blob(_ALL_KEY_DEVICE_REPLY.view(np.uint8)), sink))
+        shards, ids = sink.parts, sink.ids
         CHECK(len(shards) == self._num_server,
               "dirty get: one reply per server")
         if self._num_server == 1:
@@ -1260,10 +1254,6 @@ class MatrixWorker(WorkerTable):
               "get_worker out of the consumer-slot range (the "
               "server-side CHECK would fire inside the actor and the "
               "caller would hang)")
-        self._dest, self._dest_rows = None, None
-        self._device_shards = {}
-        self._device_sum = False
-        self._device_shard_ids = {}
         blobs = [Blob(_ADD_GET_DIRTY_KEY.view(np.uint8)),
                  Blob(row_ids.view(np.uint8)), Blob(delta),
                  self._option_blob(option),
@@ -1303,9 +1293,9 @@ class MatrixWorker(WorkerTable):
                       "live rows)")
                 self._mirror_verified = True
             blobs.append(Blob(row_ids_device))
-        self.wait(self.request_async_raw(MsgType.Request_Get, blobs))
-        shards, ids = self._device_shards, self._device_shard_ids
-        self._device_shards, self._device_shard_ids = None, None
+        sink = DeviceSink(keep_ids=True)
+        self.wait(self._get_to(sink, blobs))
+        shards, ids = sink.parts, sink.ids
         CHECK(len(shards) == 1, "fused dirty get: one reply")
         return ids[0], shards[0]
 
@@ -1319,94 +1309,36 @@ class MatrixWorker(WorkerTable):
         self._check_frozen_layout("device whole-table gets")
         CHECK(not self.is_sparse,
               "device get is for dense tables (sparse replies are ragged)")
-        self._dest, self._dest_rows, self._device_shards = None, None, {}
-        self._device_sum = False
-        return self._request_get(Blob(_ALL_KEY.view(np.uint8)))
+        return self._request_get_device(Blob(_ALL_KEY.view(np.uint8)))
 
     # -- replies (ref: matrix_table.cpp:317-341) --
     def process_reply_get(self, reply_blobs: List[Blob]) -> None:
-        if (self._reply_msg_id >= 0
-                and self._pf_rows.get(self._reply_msg_id) is not None):
-            # Prefetch reply shard: one server's [keys, values] segment
-            # routes into the cache ONLY — the destination registers
-            # belong to whatever real Get may be concurrently in
-            # flight. (Prefetches are dense host row Gets, never codec-
-            # compressed or device-resident.)
+        """One server's reply shard: decoded here, placed by the sink
+        its request registered. A whole shard has ``keys`` None; a
+        device sink takes the payload as it is, still in HBM (a shard's
+        server rides in blob 2 where the reply names it); host rows go
+        through the client cache and the replica bookkeeping on the way
+        to the sink."""
+        sink = self._reply_sink()
+        keys = None
+        if not reply_blobs[0].on_device:
             keys = reply_blobs[0].as_array(np.int32)
-            values = reply_blobs[1].as_array(self.dtype).reshape(
-                keys.size, self.num_col)
-            ent = self._replica_sent.get(self._reply_msg_id)
-            if ent is not None:
-                ent.pop(self._reply_server, None)
-                if not ent:
-                    del self._replica_sent[self._reply_msg_id]
-            n_rep = self._reply_replica_rows
-            if self._row_cache is not None:
-                n_own = keys.size - n_rep
-                self._row_cache.store(keys[:n_own], values[:n_own],
-                                      self._reply_version,
-                                      self._reply_server)
-                # Replica groups cache under their OWNER at the group's
-                # version floor; groups below the read-your-writes
-                # floor and holder misses just stay uncached — a
-                # prefetch never repairs (a later real Get fetches
-                # whatever is still missing).
-                for owner, floor, gkeys, gvals in \
-                        self._replica_groups(keys, values, reply_blobs):
-                    if floor < self.add_floor(owner):
-                        continue
-                    self._version_tracker.note(owner, floor)
-                    self._row_cache.store(gkeys, gvals, floor, owner)
+            if keys.size == 1 and keys[0] == -1:
+                keys = None
+        if sink.device:
+            values = reply_blobs[1].typed(self.dtype)
+            if keys is not None:
+                values = _shaped_rows(values, keys.size, self.num_col)
+            sink.place(keys, values, self._reply_version,
+                       int(reply_blobs[2].as_array(np.int32)[0])
+                       if len(reply_blobs) >= 3 else None)
             return
-        sg = self._sg.get(self._reply_msg_id) \
-            if self._reply_msg_id >= 0 else None
-        if sg is not None:
-            # Scatter-gather sub-request shard: values/versions land in
-            # the request's own buffers (never the shared _dest
-            # registers), replica groups and repairs handled exactly
-            # like the classic path.
-            self._process_sg_reply(sg, reply_blobs)
-            return
-        if reply_blobs[0].on_device:
-            # Device-key reply: values arrive shaped
-            # row_ids.shape + (num_col,), still in HBM — keyed by the
-            # origin server id (broadcast replies sum, order-free).
-            CHECK(self._device_shards is not None,
-                  "device reply with no device get outstanding")
-            sid = int(reply_blobs[2].as_array(np.int32)[0]) \
-                if len(reply_blobs) >= 3 else len(self._device_shards)
-            self._device_shards[sid] = reply_blobs[1].typed(self.dtype)
-            return
-        keys = reply_blobs[0].as_array(np.int32)
-        if keys.size == 1 and keys[0] == -1:
+        if keys is None:
             server_id = int(reply_blobs[2].as_array(np.int32)[0])
-            if self._device_shards is not None:  # device-resident get
-                self._device_shards[server_id] = \
-                    reply_blobs[1].typed(self.dtype)
-                return
-            CHECK(self._dest is not None,
-                  "Get reply with no outstanding destination — only one "
-                  "Get may be in flight per table (as in the reference)")
-            lo, hi = self._offsets[server_id], self._offsets[server_id + 1]
-            values = reply_blobs[1].as_array(self.dtype)
-            self._dest[lo:hi] = values.reshape(hi - lo, self.num_col)
-            return
-        if self._device_shards is not None:
-            # Device row pull: keep the server's gather result in HBM,
-            # keyed by the owning server (a shard carries one server's
-            # contiguous key segment). The dirty-device flow replies
-            # [ids, values, server_id] — possibly ZERO rows, so the
-            # server id cannot be inferred from the keys.
-            if len(reply_blobs) >= 3:
-                sid = int(reply_blobs[2].as_array(np.int32)[0])
-            else:
-                sid = 0 if keys.size == 0 else \
-                    int(min(keys[0] // self._row_length,
-                            self._num_server - 1))
-            self._device_shards[sid] = _shaped_rows(
-                reply_blobs[1].typed(self.dtype), keys.size, self.num_col)
-            if self._device_shard_ids is not None:
-                self._device_shard_ids[sid] = keys
+            n_rows = self._offsets[server_id + 1] - self._offsets[server_id]
+            sink.place(None, reply_blobs[1].as_rows(self.dtype, n_rows,
+                                                    self.num_col),
+                       self._reply_version, server_id)
             return
         if self._compress and _is_codec_blob(reply_blobs[1]):
             values = _decompress_values(
@@ -1432,33 +1364,8 @@ class MatrixWorker(WorkerTable):
             requested = ent.pop(self._reply_server, None)
             if not ent:
                 del self._replica_sent[self._reply_msg_id]
-        if self._reply_replica_rows or requested is not None:
-            self._process_replica_reply(keys, values, reply_blobs,
-                                        requested)
-            return
-        if self._row_cache is not None and self._dest_rows is not None:
-            # Wire-path population: every real row Get refreshes the
-            # cache (and, via the reply context, the version tracker) —
-            # prefetch is an accelerant, not a requirement, for hits.
-            self._row_cache.store(keys, values, self._reply_version,
-                                  self._reply_server)
-        if self._dest_rows is None:
-            # Sparse whole-table get: dirty rows land at their global index.
-            self._dest[keys] = values
-        else:
-            # Every requested position whose row id appears in THIS
-            # reply shard gets that row's value (a shard carries one
-            # server's key subset — possibly only the cache-missing rows
-            # of a partial hit; other positions are left for sibling
-            # shards or were cache-filled). place_rows picks the form
-            # from the shard: the request itself, or a run of a sorted
-            # one, is one copy; anything else is searched. Requests may
-            # repeat ids — power-of-two padded row sets repeat the last
-            # id thousands of times, so per-position Python loops go
-            # quadratic and a single reply can burn minutes.
-            with monitor("CLIENT_PLACE_ROWS"):
-                client_cache.place_rows(keys, values, self._dest_rows,
-                                        self._dest)
+        self._serve_reply_groups(keys, values, reply_blobs, requested,
+                                 sink)
 
     # -- hot-shard replication: worker side (runtime/replica.py,
     #    docs/SHARDING.md; all on the worker actor thread) --
@@ -1522,74 +1429,28 @@ class MatrixWorker(WorkerTable):
             pos += n_rows
         return out
 
-    def _process_replica_reply(self, keys: np.ndarray,
-                               values: np.ndarray,
-                               reply_blobs: List[Blob],
-                               requested: Optional[np.ndarray]) -> None:
-        """A holder shard's reply on the CLASSIC (one-get-in-flight)
-        path: placement targets the shared destination registers."""
-
-        def place(gkeys, gvals, version, owner):
-            if self._row_cache is not None \
-                    and self._dest_rows is not None:
-                self._row_cache.store(gkeys, gvals, version, owner)
-            if self._dest is not None and self._dest_rows is not None:
-                with monitor("CLIENT_PLACE_ROWS"):
-                    client_cache.place_rows(gkeys, gvals,
-                                            self._dest_rows, self._dest)
-
-        self._serve_reply_groups(keys, values, reply_blobs, requested,
-                                 place)
-
-    def _process_sg_reply(self, entry: _ScatterRead,
-                          reply_blobs: List[Blob]) -> None:
-        """A scatter-gather sub-request's reply shard: same semantics
-        as the classic path (cache population, replica-group floors,
-        repair staging under the same request id), but placement goes
-        to the sub-request's OWN buffers."""
-        keys = reply_blobs[0].as_array(np.int32)
-        values = reply_blobs[1].as_array(self.dtype).reshape(
-            keys.size, self.num_col)
-        requested = None
-        ent = self._replica_sent.get(self._reply_msg_id)
-        if ent is not None:
-            requested = ent.pop(self._reply_server, None)
-            if not ent:
-                del self._replica_sent[self._reply_msg_id]
-
-        def place(gkeys, gvals, version, owner):
-            if self._row_cache is not None:
-                self._row_cache.store(gkeys, gvals, version, owner)
-            if gkeys.size == 0:
-                return
-            with monitor("CLIENT_PLACE_ROWS"):
-                pos = np.minimum(np.searchsorted(entry.rows, gkeys),
-                                 entry.rows.size - 1)
-                ok = entry.rows[pos] == gkeys  # repairs may widen to
-                pos = pos[ok]          # rows outside this entry's set
-                entry.out[pos] = gvals[ok]
-                if version >= 0:
-                    entry.versions[pos] = np.maximum(
-                        entry.versions[pos], int(version))
-
-        self._serve_reply_groups(keys, values, reply_blobs, requested,
-                                 place)
-
     def _serve_reply_groups(self, keys: np.ndarray, values: np.ndarray,
                             reply_blobs: List[Blob],
                             requested: Optional[np.ndarray],
-                            place) -> None:
-        """Shared reply-shard semantics for the classic and scatter
-        read paths: owned rows attribute to the replying shard at the
-        header version; each replica group attributes to its OWNER at
-        the group's version floor. Groups below this worker's read-
+                            sink) -> None:
+        """A host reply shard's rows, group by group, into the client
+        cache (every host row reply refreshes it: prefetch is an
+        accelerant, not a requirement, for hits) and then to ``sink``,
+        the request's.
+        Owned rows attribute to the replying shard at the header
+        version; each replica group attributes to its OWNER at the
+        group's version floor. Groups below this worker's read-
         your-writes floor are discarded (their values may predate an
         Add the owner already acked to us) and — together with routed
         rows the holder did not serve at all — REPAIR to their owners
-        under the same request id (the worker actor transfers this
-        reply's notify onto the repairs, so wait() completes only when
-        they landed). ``place(keys, values, version, owner)`` is the
-        path-specific sink (cache store + destination placement)."""
+        under the same request id, hence to the same sink (the worker
+        actor transfers this reply's notify onto the repairs, so wait()
+        completes only when they landed)."""
+        def place(gkeys, gvals, version, owner):
+            if self._row_cache is not None:
+                self._row_cache.store(gkeys, gvals, version, owner)
+            sink.place(gkeys, gvals, version, owner)
+
         n_own = keys.size - self._reply_replica_rows
         place(keys[:n_own], values[:n_own], self._reply_version,
               self._reply_server)

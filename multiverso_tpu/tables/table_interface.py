@@ -13,6 +13,12 @@ Contract preserved exactly:
   waiter releases ``wait(msg_id)`` (ref: src/worker.cpp:78-88,
   src/table.cpp:84-111).
 
+Where the reference keeps one Get's destination in registers of the
+table, a Get here carries a SINK: ``_get_to`` registers it under the
+request's id before the request is sent, ``process_reply_get`` decodes a
+reply shard and hands it to ``_reply_sink().place``, and the entry goes
+where the id retires.
+
 ``ServerTable`` is ``Serializable`` — ``store``/``load`` stream the shard
 state for checkpointing (ref: include/multiverso/table_interface.h:61-75).
 """
@@ -82,6 +88,66 @@ class RpcTimeoutError(TableRequestError):
     ``-allreduce_timeout_s`` rich errors)."""
 
 
+class TableSink:
+    """A whole-table Get into a host buffer: a server's shard goes to
+    its offsets (``keys`` None), a sparse table's dirty rows each to
+    their own index."""
+
+    device = False
+
+    def __init__(self, out, offsets):
+        self.out = out
+        self.offsets = offsets
+
+    def place(self, keys, values, version, server) -> None:
+        if keys is None:
+            self.out[self.offsets[server]:self.offsets[server + 1]] = values
+        else:
+            self.out[keys] = values
+
+
+class DeviceSink:
+    """A Get whose reply stays in HBM: ``parts`` by server id, for the
+    caller to add up (``sums``: device keys go to every server alike and
+    each fills the rows of the others with zeros) or to concatenate in
+    server order; ``ids`` keeps each part's row ids where the caller asks
+    for them (a dirty-row pull)."""
+
+    device = True
+
+    def __init__(self, sums: bool = False, keep_ids: bool = False,
+                 row_length: int = 1, num_server: int = 1):
+        self.parts: Dict[int, object] = {}
+        self.sums = sums
+        self.ids: Optional[Dict[int, object]] = {} if keep_ids else None
+        self._row_length = row_length
+        self._num_server = num_server
+
+    def place(self, keys, values, version, server) -> None:
+        if server is None:
+            # A two-blob reply (host row ids, their rows) names no
+            # server: it is one server's run of a sorted request, and its
+            # first key names the server under the division rule.
+            server = 0 if keys.size == 0 else int(
+                min(keys[0] // self._row_length, self._num_server - 1))
+        self.parts[server] = values
+        if self.ids is not None:
+            self.ids[server] = keys
+
+    def ordered(self) -> List:
+        return [self.parts[sid] for sid in sorted(self.parts)]
+
+
+class CacheOnlySink:
+    """A prefetch: the table stores every host reply shard in its client
+    cache before the sink sees it, and no buffer waits for this one."""
+
+    device = False
+
+    def place(self, keys, values, version, server) -> None:
+        pass
+
+
 class WorkerTable:
     """Client-side handle; lives on every worker rank."""
 
@@ -116,6 +182,14 @@ class WorkerTable:
         #: serving threads — int assignment, GIL-atomic.
         self._data_generation = 0
         self._on_complete: Dict[int, List[Callable]] = {}
+        # Where each Get's reply goes: msg_id -> sink, an object with
+        # ``place(keys, values, version, server)`` and ``device``.
+        # Written on the requester's thread BEFORE the request is pushed
+        # to the worker actor's mailbox (the push is the happens-before
+        # edge), read on the worker actor's thread, dropped where the id
+        # retires: completion, RPC timeout, abort. Plain dict
+        # operations, GIL-atomic. Any number of Gets may be in flight.
+        self._sinks: Dict[int, object] = {}
         self._reply_server = -1
         self._reply_version = -1
         self._reply_msg_id = -1
@@ -196,10 +270,7 @@ class WorkerTable:
 
     # -- async API (ref: src/table.cpp:41-82) --
     def get_async_raw(self, keys: Blob, extra: Sequence[Blob] = ()) -> int:
-        msg_id = self._new_request()
-        self._send_request(MsgType.Request_Get,
-                           [keys, *extra], msg_id)
-        return msg_id
+        return self.request_async_raw(MsgType.Request_Get, [keys, *extra])
 
     def add_async_raw(self, keys: Blob, values: Blob,
                       option_blob: Optional[Blob] = None) -> int:
@@ -216,6 +287,24 @@ class WorkerTable:
         msg_id = self._new_request()
         self._send_request(msg_type, blobs, msg_id)
         return msg_id
+
+    def _get_to(self, sink, blobs: Sequence[Blob]) -> int:
+        """Issue a Get whose reply shards go to ``sink``."""
+        msg_id = self._new_request()
+        self._sinks[msg_id] = sink
+        self._send_request(MsgType.Request_Get, blobs, msg_id)
+        return msg_id
+
+    def _reply_sink(self):
+        """The sink of the request whose reply is being processed
+        (worker actor thread, between ``_begin_reply`` and
+        ``_end_reply``). A reply that outlived its request touches no
+        buffer."""
+        sink = self._sinks.get(self._reply_msg_id)
+        log.CHECK(sink is not None,
+                  "Get reply with no outstanding destination: its "
+                  "request timed out or was aborted")
+        return sink
 
     def _send_request(self, msg_type: MsgType, blobs: Sequence[Blob],
                       msg_id: int) -> None:
@@ -300,14 +389,16 @@ class WorkerTable:
             peers = worker.pending_peers(self.table_id, msg_id) \
                 if has_pending else []
             pending = waiter.pending
-            # The request is ABANDONED: reap its waiter, recorded
+            # The request is ABANDONED: reap its waiter, sink, recorded
             # error, and the worker's in-flight entries, or repeated
             # timeouts (the flag's target scenario is a peer that
             # never replies) leak one of each per request and pollute
-            # later pending_peers diagnostics. A late straggler reply
-            # finding no waiter is a no-op in notify().
+            # later pending_peers diagnostics; its completion callbacks
+            # run, or a prefetch that never lands keeps every later Get
+            # of its rows joined to it. A late straggler reply finds no
+            # sink, and no waiter in notify().
+            self._retire(msg_id)
             with self._mutex:
-                self._waitings.pop(msg_id, None)
                 self._errors.pop(msg_id, None)
             if has_pending:
                 worker.forget_request(self.table_id, msg_id)
@@ -342,6 +433,7 @@ class WorkerTable:
         self._abort_reason = reason
         self._trace_open.clear()  # roots of aborted requests never
         # complete; dropping them keeps the dict bounded
+        self._sinks.clear()  # nor will their replies be placed
         with self._mutex:
             waiters = list(self._waitings.values())
         for waiter in waiters:
@@ -353,9 +445,9 @@ class WorkerTable:
         completes. With ``count`` the failure also counts as one shard
         reply (notify) — it must NOT release the waiter outright: a
         multi-shard request with sibling replies still in flight would
-        otherwise unblock early, and a late sibling could write into the
-        NEXT request's destination (the one-get-in-flight registers are
-        shared). Callers whose control flow already notifies (the reply
+        otherwise unblock early, over a buffer its siblings still write
+        (the request's sink stays until every shard is counted).
+        Callers whose control flow already notifies (the reply
         handlers' finally blocks) pass ``count=False``. At most
         ``_MAX_RETAINED_ERRORS`` completed-request entries are retained
         for late ``wait`` calls; past that the oldest completed ones are
@@ -396,9 +488,6 @@ class WorkerTable:
                 self._complete_if_done(msg_id, waiter)
 
     def _complete_if_done(self, msg_id: int, waiter: Waiter) -> None:
-        """Reap the completed waiter (fire-and-forget async adds would
-        otherwise leak one per request) and run any registered
-        completion callbacks exactly once."""
         if not waiter.done:
             return
         opened = self._trace_open.pop(msg_id, None)
@@ -410,8 +499,16 @@ class WorkerTable:
             tracing.end_root(tid, name, self._zoo.rank, t0_ns,
                              args={"table": self.table_id,
                                    "msg_id": msg_id})
+        self._retire(msg_id, waiter)
+
+    def _retire(self, msg_id: int, waiter: Optional[Waiter] = None) -> None:
+        """The id is done with, completed or abandoned: drop its sink,
+        reap its waiter (fire-and-forget async adds would otherwise
+        leak one per request) and run any registered completion
+        callbacks exactly once."""
+        self._sinks.pop(msg_id, None)
         with self._mutex:
-            if self._waitings.get(msg_id) is waiter:
+            if waiter is None or self._waitings.get(msg_id) is waiter:
                 self._waitings.pop(msg_id, None)
             callbacks = self._on_complete.pop(msg_id, None)
         for fn in callbacks or ():

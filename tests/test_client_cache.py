@@ -229,20 +229,26 @@ class TestPrefetchAndDedup:
         ids = np.array([5], np.int32)
         pf = table.prefetch_rows_async(ids)
         table.wait(pf, timeout=10)
-        # Simulate the deferred path directly: register a join (with
-        # the destination registers a real get_rows_async would have
-        # set), block the row, then run the completion handler.
-        out = np.empty((1, 2), np.float32)
-        mid = table._new_request()
-        table._dest, table._dest_rows = out, ids
-        table._device_shards = None
+        # The deferred path: an in-flight prefetch of the row (its id
+        # stands in the registry, its message is held back), the Get
+        # that joins it, the row blocked by an own add, then the
+        # prefetch's completion. The forwarded request carries the
+        # joined Get's id, and its reply goes to that Get's sink
+        # whatever other Get the table has issued since.
+        out = np.full((1, 2), -1.0, np.float32)
         table._pf_rows[99] = ids
-        table._pf_joined[99] = [(mid, ids, out)]
         tok = table._row_cache.begin_add(ids)  # invalidates row 5
+        mid = table.get_rows_async(ids, out)
+        assert table._pf_joined[99] == [mid]
+        other = np.full((2, 2), -1.0, np.float32)
+        table.get_rows(np.array([2, 3], np.int32), other)
+        np.testing.assert_array_equal(out, np.full((1, 2), -1.0))
         table._on_prefetch_done(99)
         table._row_cache.finish_add(tok)
         assert table.wait(mid, timeout=10)
         np.testing.assert_array_equal(out, np.ones((1, 2)))
+        np.testing.assert_array_equal(other, np.ones((2, 2)))
+        assert not table._sinks
 
 
 class TestArrayAndKV:

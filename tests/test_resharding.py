@@ -35,7 +35,7 @@ from multiverso_tpu.core.message import Message, MsgType
 from multiverso_tpu.runtime import replica as rm
 from multiverso_tpu.runtime import shard_map as sm
 from multiverso_tpu.runtime.cluster import LocalCluster
-from multiverso_tpu.tables import row_offsets
+from multiverso_tpu.sharding.rows import row_offsets
 from multiverso_tpu.util import chaos
 from multiverso_tpu.util.configure import set_flag
 

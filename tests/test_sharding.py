@@ -27,7 +27,7 @@ from multiverso_tpu.core.message import (Message, MsgType,
                                          replica_row_count)
 from multiverso_tpu.runtime import replica as rm
 from multiverso_tpu.runtime.cluster import LocalCluster
-from multiverso_tpu.tables import row_offsets
+from multiverso_tpu.sharding.rows import row_offsets
 from multiverso_tpu.util.configure import set_flag
 from multiverso_tpu.util.dashboard import Dashboard, Samples
 from multiverso_tpu.util.waiter import Waiter

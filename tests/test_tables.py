@@ -13,7 +13,8 @@ import multiverso_tpu as mv
 from multiverso_tpu.core.blob import Blob
 from multiverso_tpu.core.message import MsgType
 from multiverso_tpu.runtime.cluster import LocalCluster
-from multiverso_tpu.tables import server_offsets, row_offsets
+from multiverso_tpu.sharding.rows import row_offsets
+from multiverso_tpu.tables import server_offsets
 from multiverso_tpu.updater import AddOption
 
 
@@ -285,13 +286,14 @@ class TestDonationSafety:
         # sync-server drain pattern is get-reply-then-cached-adds
         # (regression: "Array has been deleted" on materialize).
         table = mv.create_array_table(64)  # 64 == padded size on 8 devices
-        msg_id = table.get_async()
+        out = np.full(64, -1.0, np.float32)
+        msg_id = table.get_async(out)
         for _ in range(4):
             table.add(np.ones(64, np.float32))
         assert table.wait(msg_id, timeout=30)
         # Reply content is a consistent snapshot (0..4 adds may have landed
         # first in async mode), not garbage from a deleted buffer.
-        assert float(table._dest[0]) in {0.0, 1.0, 2.0, 3.0, 4.0}
+        assert float(out[0]) in {0.0, 1.0, 2.0, 3.0, 4.0}
 
 
 class TestDeviceResidentPath:
