@@ -30,9 +30,44 @@ Two zero-copy carrier forms beyond the plain host array
 
 from __future__ import annotations
 
-from typing import Any, List
+import functools
+from typing import Any, Iterator, List, Tuple
 
 import numpy as np
+
+#: A device payload of rows that its consumer takes by row ranges
+#: (``Blob.host_row_pieces``) crosses the host boundary in equal pieces
+#: of about ``D2H_PIECE_BYTES``, at least two and at most
+#: ``D2H_MOST_PIECES``; one under ``D2H_WHOLE_UNDER_BYTES`` stays whole.
+#: Chosen on a v5e from ``mperf16m.rows``' 20 MB reply (PR 48, PERF.md
+#: section 6; a Get at the caller, ms, parent 25.1 to 25.6): 2 pieces
+#: 27.5, 4 pieces 22.5 to 23.0, **8 pieces 20.8 to 21.2**, 16 pieces 19.7,
+#: 32 pieces 25.9. A piece costs the thread ~0.5 ms to cut and ask for
+#: (the cut's dispatch with its start, ``copy_to_host_async``), hidden
+#: only while the device still runs what the reply waits for (9.4 ms in
+#: that cell: 16 pieces just fit, 32 do not), so 8: the same cut on an
+#: idle device still beats the whole array (9.1 ms against 11.5 from
+#: dispatch to placed; 16 pieces 13.0). 8 pieces of an 80 MB reply read
+#: 44 ms against the whole's 65 and 16 pieces' 44.5: the count is capped,
+#: not the size. Under 4 MiB two pieces save less than they cost to cut.
+D2H_PIECE_BYTES = 5 << 19  # 2.5 MiB
+D2H_MOST_PIECES = 8
+D2H_WHOLE_UNDER_BYTES = 4 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def _row_piece():
+    """The program that cuts ``rows`` rows from ``first`` out of a device
+    ``[n, c]`` array AND flattens them. The start is an argument, so one
+    program serves every piece of a reply shape. Built at first use:
+    ``core`` stays free of jax until a jax array is here to be cut."""
+    import jax
+
+    @functools.partial(jax.jit, static_argnums=2)
+    @jax.named_scope("mv.blob.row_piece")
+    def row_piece(whole, first, rows):
+        return jax.lax.dynamic_slice_in_dim(whole, first, rows).reshape(-1)
+    return row_piece
 
 
 def is_device_array(x: Any) -> bool:
@@ -174,6 +209,70 @@ class Blob:
             else:
                 self._data = np.asarray(self._data)
         return self._data
+
+    def pieced_rows(self, dtype, n_rows: int, n_col: int) -> bool:
+        """True where ``host_row_pieces`` cuts: the payload is on the
+        device, already ``[n_rows, n_col]`` of ``dtype``, and large
+        enough for a piece to be worth its dispatch."""
+        return (self.on_device
+                and tuple(self._data.shape) == (n_rows, n_col)
+                and np.dtype(self._data.dtype) == np.dtype(dtype)
+                and self.size >= D2H_WHOLE_UNDER_BYTES)
+
+    def host_row_pieces(self, dtype, n_rows: int, n_col: int
+                        ) -> Iterator[Tuple[int, np.ndarray]]:
+        """The payload's rows on the host as ``(first_row, rows)`` in
+        row order, for a consumer that reads every row once (a Get's
+        placement). A large device payload (``pieced_rows``) crosses the
+        host boundary in equal row ranges: each is cut AND flattened on
+        the device (``_row_piece``: a flat array has one layout, so it
+        arrives row-major and its rows are contiguous, whatever layout
+        the chip gave the ``[n, c]`` whole), every piece's copy is asked
+        for before any is waited for, and the runtime copies piece k + 1
+        while the consumer holds piece k. The last range starts early
+        enough to be as long as the others (one program a reply shape);
+        the rows it shares with the one before are skipped on the host.
+        The blob lets go of the device array once it is cut and keeps
+        the host pieces as its parts, so it reads as the same payload
+        afterwards. Anything else is the one array ``as_rows`` gives.
+        Read-only like every device reply's host array (docs/MEMORY.md).
+
+        The monitors count ONE entry a payload as ``_host`` does:
+        BLOB_D2H_READY is the cuts' dispatch and the wait for the first
+        piece's program, BLOB_D2H_COPY the time in ``np.asarray`` of
+        all pieces, BLOB_D2H both and nothing of what the consumer
+        did in between."""
+        if not self.pieced_rows(dtype, n_rows, n_col):
+            yield 0, self.as_rows(dtype, n_rows, n_col)
+            return
+        from ..util.dashboard import count, laps, monitor
+        nbytes = self.size
+        rows = -(-n_rows // min(D2H_MOST_PIECES,
+                                max(2, -(-nbytes // D2H_PIECE_BYTES))))
+        # (a piece's first new row, the row its cut starts at)
+        starts = [(first, min(first, n_rows - rows))
+                  for first in range(0, n_rows, rows)]
+        d2h, copied = laps("BLOB_D2H"), laps("BLOB_D2H_COPY")
+        try:
+            with d2h, monitor("BLOB_D2H_READY"):
+                cut, whole = _row_piece(), self._data
+                cuts = [cut(whole, np.int32(at), rows) for _, at in starts]
+                for piece in cuts:
+                    piece.copy_to_host_async()
+                del whole
+                self._data, self._parts = None, []
+                cuts[0].block_until_ready()
+            cuts.reverse()  # popped: a piece's device array goes with it
+            for first, at in starts:
+                with d2h, copied:
+                    piece = np.asarray(cuts.pop())
+                piece = piece[(first - at) * n_col:]
+                self._parts.append(piece.view(np.uint8))
+                yield first, piece.reshape(-1, n_col)
+        finally:
+            d2h.close()
+            copied.close()
+        count("BLOB_D2H_BYTES", nbytes)
 
     @property
     def size(self) -> int:

@@ -102,7 +102,7 @@ def cache_enabled() -> bool:
     return staleness_bound() > 0
 
 
-def _run_start(keys: np.ndarray, req: np.ndarray) -> int:
+def run_start(keys: np.ndarray, req: np.ndarray) -> int:
     """Where ``keys`` lies in ``req`` as ONE run that holds every
     position of its ids, or -1. Two shapes qualify: the shard is the
     whole request in its order (any ``req``, repeats included — one
@@ -129,6 +129,17 @@ def _run_start(keys: np.ndarray, req: np.ndarray) -> int:
     return a
 
 
+def place_run(start: int, pieces, out) -> None:
+    """The direct form: a shard that ``run_start`` found at ``start`` is
+    copied straight into ``out``, one ``(first_row, rows)`` piece after
+    the other as ``pieces`` hands them over (the whole shard is one
+    piece at row 0; ``Blob.host_row_pieces`` gives a large device reply
+    as several, each while the next is still on its way)."""
+    count(DIRECT)
+    for first, rows in pieces:
+        out[start + first:start + first + len(rows)] = rows
+
+
 def place_rows(keys: np.ndarray, values, req: np.ndarray, out) -> None:
     """Subset placement: every position of ``req`` whose row id appears
     in ``keys`` receives that id's row of ``values``; positions for
@@ -138,18 +149,17 @@ def place_rows(keys: np.ndarray, values, req: np.ndarray, out) -> None:
     are pathological here.
 
     The cheapest form is chosen from ``keys`` and ``req`` alone. A shard
-    that is the request, or a run of a sorted request (``_run_start``),
+    that is the request, or a run of a sorted request (``run_start``),
     is one copy of ``values`` into ``out``, read through whatever
-    strides ``values`` has (GET_REPLY_ROWS_DIRECT). Anything else —
-    subset keys of a partial hit, replica groups, an unsorted request
-    over several servers — is sorted, searched, gathered and scattered
-    (GET_REPLY_ROWS_PLACED)."""
+    strides ``values`` has (``place_run``, GET_REPLY_ROWS_DIRECT).
+    Anything else — subset keys of a partial hit, replica groups, an
+    unsorted request over several servers — is sorted, searched,
+    gathered and scattered (GET_REPLY_ROWS_PLACED)."""
     if len(keys) == 0 or len(req) == 0:
         return
-    start = _run_start(keys, req)
+    start = run_start(keys, req)
     if start >= 0:
-        count(DIRECT)
-        out[start:start + len(keys)] = values
+        place_run(start, [(0, values)], out)
         return
     count(PLACED)
     sorter = np.argsort(keys, kind="stable")

@@ -47,7 +47,7 @@ from ..runtime import shard_map as shard_map_mod
 from ..runtime.zoo import CONTROLLER_RANK
 from ..util import chaos
 from ..util.dashboard import count as count_event
-from ..util.dashboard import monitor
+from ..util.dashboard import laps, monitor
 from . import client_cache
 from .client_cache import RowCache
 from ..sharding import mesh as meshlib
@@ -186,7 +186,10 @@ class _RowsSink:
     padded row sets repeat the last id thousands of times), and a shard
     carries one server's key subset, possibly only the rows a partial
     cache hit still missed: ``place_rows`` picks the form, one copy where
-    the shard is the request or a run of a sorted one."""
+    the shard is the request or a run of a sorted one. That form is
+    known from the keys alone (``run_start``), before any value is on
+    the host, so a shard placed so may also arrive in row-range pieces
+    (``place_run``)."""
 
     device = False
     __slots__ = ("row_ids", "out")
@@ -198,6 +201,27 @@ class _RowsSink:
     def place(self, keys, values, version, server) -> None:
         with monitor("CLIENT_PLACE_ROWS"):
             client_cache.place_rows(keys, values, self.row_ids, self.out)
+
+    def run_start(self, keys) -> int:
+        """Where a shard of ``keys`` is copied straight in, or -1."""
+        return client_cache.run_start(keys, self.row_ids)
+
+    def place_run(self, start: int, pieces) -> None:
+        """``place`` for a shard ``run_start`` found at ``start``, its
+        rows handed over piece by piece: CLIENT_PLACE_ROWS still counts
+        one entry a shard, the placing alone and none of the time the
+        iterator took to produce a piece."""
+        placing = laps("CLIENT_PLACE_ROWS")
+
+        def timed():
+            for piece in pieces:
+                with placing:  # from the hand-over to the next ask
+                    yield piece
+
+        try:
+            client_cache.place_run(start, timed(), self.out)
+        finally:
+            placing.close()
 
 
 class _ScatterRead:
@@ -1340,6 +1364,13 @@ class MatrixWorker(WorkerTable):
                                                     self.num_col),
                        self._reply_version, server_id)
             return
+        start = self._pieced_start(sink, keys, reply_blobs[1])
+        if start >= 0:
+            count_event("GET_REPLY_ROWS_PIECED")
+            sink.place_run(start, reply_blobs[1].host_row_pieces(
+                self.dtype, keys.size, self.num_col))
+            return
+        count_event("GET_REPLY_ROWS_WHOLE")
         if self._compress and _is_codec_blob(reply_blobs[1]):
             values = _decompress_values(
                 reply_blobs[1],
@@ -1366,6 +1397,28 @@ class MatrixWorker(WorkerTable):
                 del self._replica_sent[self._reply_msg_id]
         self._serve_reply_groups(keys, values, reply_blobs, requested,
                                  sink)
+
+    def _pieced_start(self, sink, keys: np.ndarray, payload: Blob) -> int:
+        """Where in its request's buffer this host row reply shard may
+        be placed piece by piece while the rest of it is still leaving
+        the device, or -1: the whole array then goes the way it always
+        has. Pieces need every row to have ONE reader, the copy into
+        the caller's buffer, known before a value is on the host; each
+        condition is read off the reply itself. The payload is a large
+        device array (``pieced_rows``; a codec frame never is); the
+        shard carries no replica groups and is no holder's answer to
+        routed rows (those are cut by group, attributed and repaired);
+        the row cache stores nothing (a store reads every row again);
+        the sink is a host row Get's and the placement is the direct
+        one (a searched shard, a scatter read and a prefetch read the
+        whole)."""
+        if (not payload.pieced_rows(self.dtype, keys.size, self.num_col)
+                or not isinstance(sink, _RowsSink)
+                or self._reply_replica_rows
+                or self._reply_msg_id in self._replica_sent
+                or self._live_cache() is not None):
+            return -1
+        return sink.run_start(keys)
 
     # -- hot-shard replication: worker side (runtime/replica.py,
     #    docs/SHARDING.md; all on the worker actor thread) --

@@ -60,12 +60,20 @@ METRIC_NAMES: Dict[str, str] = {
                            "a request in server order): no copy",
     "ADD_ROWS_SHARD_COPIED": "host row-Add shards partition gathered "
                              "with a mask into a fresh array",
+    "GET_REPLY_ROWS_PIECED": "host row Get reply shards whose payload "
+                             "left the device in row-range pieces, "
+                             "each placed while the next was copied",
+    "GET_REPLY_ROWS_WHOLE": "host row Get reply shards that reached "
+                            "their sink as one array",
     "BLOB_D2H": "device payload copied to host (np.asarray of a "
-                "jax.Array: waits for its program, then copies)",
+                "jax.Array: waits for its program, then copies; one "
+                "entry a payload, whole or in pieces)",
     "BLOB_D2H_READY": "inside BLOB_D2H: the wait for the program that "
-                      "makes the array (block_until_ready)",
+                      "makes the array (block_until_ready; a pieced "
+                      "payload's cuts are dispatched inside it)",
     "BLOB_D2H_COPY": "inside BLOB_D2H: the copy (np.asarray of the "
-                     "ready array)",
+                     "ready array, or of its pieces one after the "
+                     "other: the thread's time in np.asarray alone)",
     "BLOB_D2H_BYTES": "bytes those device-to-host copies moved",
     # -- server actor --
     "SERVER_PROCESS_GET": "server-side Get table op + reply",
@@ -440,6 +448,30 @@ class monitor:
         if self._span is not None:
             self._span.__exit__(*exc)
         return None
+
+
+class laps(monitor):
+    """ONE Monitor entry made of several stretches: every ``with`` is a
+    span in a trace and adds its time to the entry, ``close()`` counts
+    it. For work that comes in pieces with a consumer in between (a
+    reply's device-to-host copy, piece by piece, and the placing of
+    each): the monitor keeps counting one entry a reply, and holds none
+    of what ran between its stretches."""
+
+    __slots__ = ("_ms",)
+
+    def __init__(self, name: str, **args):
+        super().__init__(name, **args)
+        self._ms = 0.0
+
+    def __exit__(self, *exc) -> None:
+        self._ms += (time.perf_counter() - self._begin) * 1e3
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return None
+
+    def close(self) -> None:
+        Dashboard.get(self._name).add(self._ms)
 
 
 class Samples:

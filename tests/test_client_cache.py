@@ -475,6 +475,62 @@ def test_place_rows_is_the_per_position_loop(case, layout):
     assert moved == {k: int(k == counted) for k in names}
 
 
+_DIRECT = sorted(name for name, (_, _, counted) in _PLACEMENTS.items()
+                 if counted == "DIRECT")
+
+
+def _large_direct(name):
+    """The three direct shapes at a size worth cutting: (request, keys)."""
+    rng = np.random.default_rng(11)
+    if name == "keys are the request":
+        req = rng.integers(0, 500, 4000)
+        return req, req
+    if name == "thousands of repeats of the last id":
+        req = np.concatenate([np.sort(rng.choice(499, 96, replace=False)),
+                              np.full(4000, 499)])
+        return req, req
+    assert name == "a maximal run at an offset"
+    req = np.sort(rng.integers(0, 500, 6000))
+    mine = (req >= 200) & (req < 300)  # one server's bucket
+    assert 0 < np.flatnonzero(mine)[0]
+    return req, req[mine]
+
+
+@pytest.mark.parametrize("layout", ["c", "fortran", "column slice"])
+@pytest.mark.parametrize("n_pieces", [1, 2, 5])
+@pytest.mark.parametrize("case", _DIRECT + [
+    "keys are the request", "thousands of repeats of the last id",
+    "a maximal run at an offset"])
+def test_a_run_placed_in_pieces_is_the_run_placed_whole(case, layout,
+                                                        n_pieces):
+    """``place_run`` with the shard's rows handed over as row-range
+    pieces, an iterator's, fills the buffer ``place_rows`` fills from
+    the whole array, bit for bit, and counts the one direct shard."""
+    from multiverso_tpu.tables import client_cache
+    req, keys = _PLACEMENTS[case][:2] if case in _PLACEMENTS \
+        else _large_direct(case)
+    req, keys = np.asarray(req, np.int32), np.asarray(keys, np.int32)
+    values = _row_values(keys, layout)
+    whole = np.full((req.size, _COLS), -1.0, np.float32)
+    client_cache.place_rows(keys, values, req, whole)
+    start = client_cache.run_start(keys, req)
+    assert start >= 0
+    rows = -(-keys.size // n_pieces)
+    handed = []
+
+    def pieces():
+        for first in range(0, keys.size, rows):
+            handed.append(first)
+            yield first, values[first:first + rows]
+
+    out = np.full((req.size, _COLS), -1.0, np.float32)
+    before = Dashboard.get(client_cache.DIRECT).count
+    client_cache.place_run(start, pieces(), out)
+    assert Dashboard.get(client_cache.DIRECT).count - before == 1
+    assert len(handed) == -(-keys.size // rows)
+    assert out.tobytes() == whole.tobytes()
+
+
 # ---------------------------------------------------------------------------
 # An inactive cache's fence token: the servers named from the ids' two
 # ends (RowCache._fence_servers).

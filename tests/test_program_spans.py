@@ -336,6 +336,59 @@ def test_a_capture_around_one_add_holds_the_handler_s_halves(tmp_path):
     assert "mv:TABLE_WAKE" not in by_name     # a Monitor.add: no span
 
 
+def test_a_pieced_reply_is_one_entry_and_a_span_a_piece(tmp_path,
+                                                        monkeypatch):
+    """A reply that leaves the device in pieces (tests cut at a few
+    rows): every monitor counts ONE entry for it, the trace holds a copy
+    span and a placing span a piece, in turn, and BLOB_D2H's time is its
+    two halves', the placing outside it."""
+    from multiverso_tpu.core import blob as blobmod
+    from multiverso_tpu.util.dashboard import laps
+    monkeypatch.setattr(blobmod, "D2H_WHOLE_UNDER_BYTES", 64)
+    monkeypatch.setattr(blobmod, "D2H_PIECE_BYTES", IDS * COLS * 4 // 3 + 4)
+    names = ("BLOB_D2H", "BLOB_D2H_READY", "BLOB_D2H_COPY",
+             "CLIENT_PLACE_ROWS", "GET_REPLY_ROWS_PIECED",
+             "GET_REPLY_ROWS_WHOLE", "WORKER_REPLY_GET")
+    mv.init([])
+    try:
+        table = mv.create_matrix_table(ROWS, COLS)
+        ids = np.arange(IDS, dtype=np.int32)
+        base = np.arange(ROWS * COLS, dtype=np.float32).reshape(ROWS, COLS)
+        table.add(base)
+        table.get_rows(ids)  # programs built
+        before = {n: (Dashboard.get(n).count, Dashboard.get(n).elapse)
+                  for n in names}
+        with trace_to(str(tmp_path)):
+            got = table.get_rows(ids)
+        moved = {n: (Dashboard.get(n).count - before[n][0],
+                     Dashboard.get(n).elapse - before[n][1])
+                 for n in names}
+    finally:
+        mv.shutdown()
+    np.testing.assert_array_equal(got, base[ids])
+    assert {n: c for n, (c, _) in moved.items()} == {
+        **dict.fromkeys(names, 1), "GET_REPLY_ROWS_WHOLE": 0}
+    halves = moved["BLOB_D2H_READY"][1] + moved["BLOB_D2H_COPY"][1]
+    assert 0 < halves <= moved["BLOB_D2H"][1] * (1 + 1e-6)
+    assert moved["BLOB_D2H"][1] + moved["CLIENT_PLACE_ROWS"][1] \
+        <= moved["WORKER_REPLY_GET"][1]
+    by_name = {}
+    for span in _host_spans(str(tmp_path)):
+        by_name.setdefault(span[0], []).append(span)
+    copies = sorted(by_name["mv:BLOB_D2H_COPY"], key=lambda s: s[1])
+    places = sorted(by_name["mv:CLIENT_PLACE_ROWS"], key=lambda s: s[1])
+    assert len(copies) == len(places) == 3
+    assert len(by_name["mv:BLOB_D2H"]) == 4  # the wait, then a piece each
+    (_, r0, r1, _), = by_name["mv:BLOB_D2H_READY"]
+    order = [r0, r1]
+    for copy, place in zip(copies, places):
+        order += [copy[1], copy[2], place[1], place[2]]
+    assert order == sorted(order)
+    # a laps entry that is never closed counts nothing
+    with laps("SPAN_ARGS"):  # mvlint: ignore[metric-name]
+        pass
+
+
 def test_monitor_has_no_trace_parameter():
     params = inspect.signature(monitor.__init__).parameters
     assert "trace" not in params

@@ -7,14 +7,18 @@ and hands each server's reply shard to ``process_reply_get`` under the
 request's id, in the order a case asks for. Nothing here needs a
 server, so every case also runs over three."""
 
+import contextlib
+import gc
 import threading
 import time
 import types
+import weakref
 
 import numpy as np
 import pytest
 
 import multiverso_tpu as mv
+from multiverso_tpu.core import blob as blobmod
 from multiverso_tpu.core.blob import Blob
 from multiverso_tpu.runtime.zoo import ClusterAborted
 from multiverso_tpu.tables.array_table import ArrayWorker
@@ -22,6 +26,7 @@ from multiverso_tpu.tables.matrix_table import MatrixWorker
 from multiverso_tpu.tables.table_interface import (RpcTimeoutError,
                                                    TableRequestError)
 from multiverso_tpu.util.configure import set_flag
+from multiverso_tpu.util.dashboard import Dashboard
 
 ROWS, COLS = 24, 3
 UNTOUCHED = -7.0
@@ -59,6 +64,7 @@ class _Actor:
         self.data = data
         self.shards = {}
         self.device = set(device)  # ids whose values reply from HBM
+        self.codec = False  # host row values as a wire-codec frame
 
     def partition(self):
         sent, self.table.zoo.sent = self.table.zoo.sent, []
@@ -89,16 +95,24 @@ class _Actor:
         values = data[lo:hi] if whole else data[keys]
         if msg_id in self.device:
             values = jnp.asarray(values)
+        if self.codec:
+            from multiverso_tpu.tables.matrix_table import _compress_values
+            return [blobs[0]] + _compress_values(np.asarray(values))[0]
         return [blobs[0], Blob(values)] + ([server] if whole else [])
 
-    def reply(self, msg_id, version=1, reverse=False):
-        """Every shard of ``msg_id``: reply, then the notify."""
+    def reply(self, msg_id, version=1, reverse=False, replica_rows=0):
+        """Every shard of ``msg_id``: reply, then the notify. With
+        ``replica_rows`` a shard declares that many of its last rows one
+        replica group, its own, at the reply's version."""
         shards = self.shards.pop(msg_id)
         for sid, blobs in (reversed(shards) if reverse else shards):
-            self.table._begin_reply(sid, version, msg_id)
+            reply = self._serve(msg_id, sid, blobs)
+            if replica_rows:
+                reply.append(Blob(np.array(
+                    [1, sid, version + 1, replica_rows], np.int32)))
+            self.table._begin_reply(sid, version, msg_id, replica_rows)
             try:
-                self.table.process_reply_get(self._serve(msg_id, sid,
-                                                         blobs))
+                self.table.process_reply_get(reply)
             finally:
                 self.table._end_reply()
             self.table.notify(msg_id)
@@ -410,6 +424,173 @@ def _many_requesters_one_table(make, kind, num_servers):
         mv.shutdown()
 
 
+# -- a large device reply leaves the device in pieces ----------------------
+
+#: every monitor a host row Get's reply moves on the worker's thread
+_REPLY_MONITORS = ("BLOB_D2H", "BLOB_D2H_READY", "BLOB_D2H_COPY",
+                   "CLIENT_PLACE_ROWS", "GET_REPLY_ROWS_PIECED",
+                   "GET_REPLY_ROWS_WHOLE", "GET_REPLY_ROWS_DIRECT",
+                   "GET_REPLY_ROWS_PLACED")
+CUT_FROM, PIECE = 72, 96  # bytes: replies of 6 rows up, in pieces of 8
+
+
+@contextlib.contextmanager
+def _cut_at(whole_under, piece=PIECE):
+    """The blob's two constants, for a table of 24 x 3."""
+    old = blobmod.D2H_WHOLE_UNDER_BYTES, blobmod.D2H_PIECE_BYTES
+    blobmod.D2H_WHOLE_UNDER_BYTES, blobmod.D2H_PIECE_BYTES = whole_under, piece
+    try:
+        yield
+    finally:
+        blobmod.D2H_WHOLE_UNDER_BYTES, blobmod.D2H_PIECE_BYTES = old
+
+
+def _moved(before):
+    return {name: Dashboard.get(name).count - before[name]
+            for name in _REPLY_MONITORS}
+
+
+def _counts():
+    return {name: Dashboard.get(name).count for name in _REPLY_MONITORS}
+
+
+def _keys_are_the_request(rng):
+    return rng.integers(0, ROWS, 40).astype(np.int32)  # any order, repeats
+
+
+def _padded_tail(rng):
+    return np.concatenate([[1, 4, 9, 17], np.full(3000, ROWS - 1)]
+                          ).astype(np.int32)
+
+
+def _sorted_runs(rng):
+    return np.sort(rng.integers(0, ROWS, 90)).astype(np.int32)
+
+
+def _device_get(table, actor, ids, routed=False, **reply):
+    """One host row Get of ``ids`` whose shards reply from HBM: its
+    buffer, the sizes of its shards in bytes, and what the reply moved."""
+    out = _buffer(ids.size, COLS)
+    mid = table.get_rows_async(ids, out)
+    actor.device.add(mid)
+    actor.partition()
+    if routed:
+        table._replica_sent[mid] = {sid: np.empty(0, np.int32)
+                                    for sid, _ in actor.shards[mid]}
+    sizes = [blobs[0].size * COLS for _, blobs in actor.shards[mid]]
+    before = _counts()
+    actor.reply(mid, **reply)
+    assert table.wait(mid, timeout=0) and not table._sinks
+    return out, sizes, _moved(before)
+
+
+def _pieced_equals_whole(make, shape, num_servers):
+    """The three direct shapes: the reply placed by pieces is the reply
+    placed whole, bit for bit, and every monitor counts one entry a
+    shard either way."""
+    ids = shape(np.random.default_rng(7))
+    placed = {}
+    for cut_from in (CUT_FROM, 1 << 30):
+        table, data, actor = _matrix(num_servers)
+        with _cut_at(cut_from):
+            out, sizes, moved = _device_get(table, actor, ids)
+        pieced = sum(size >= cut_from for size in sizes)
+        assert pieced or cut_from > CUT_FROM
+        assert moved == {
+            "BLOB_D2H": len(sizes), "BLOB_D2H_READY": len(sizes),
+            "BLOB_D2H_COPY": len(sizes), "CLIENT_PLACE_ROWS": len(sizes),
+            "GET_REPLY_ROWS_DIRECT": len(sizes), "GET_REPLY_ROWS_PLACED": 0,
+            "GET_REPLY_ROWS_PIECED": pieced,
+            "GET_REPLY_ROWS_WHOLE": len(sizes) - pieced}, (cut_from, sizes)
+        placed[cut_from] = out
+    assert pieced == 0 and len(sizes) == min(
+        num_servers, np.unique(ids * num_servers // ROWS).size)
+    np.testing.assert_array_equal(placed[CUT_FROM], data[ids])
+    assert placed[CUT_FROM].tobytes() == placed[1 << 30].tobytes()
+
+
+def _searched(table, actor):
+    ids = np.random.default_rng(3).permutation(np.repeat(np.arange(ROWS), 2))
+    return ids.astype(np.int32), {}
+
+
+def _replica_rows(table, actor):
+    return _sorted_runs(np.random.default_rng(4)), {"replica_rows": 2}
+
+
+def _routed_to_a_holder(table, actor):
+    """Every shard is a holder's answer to rows routed to it (all of
+    which it served: nothing to repair)."""
+    return _sorted_runs(np.random.default_rng(5)), {"routed": True}
+
+
+def _active_row_cache(table, actor):
+    return _sorted_runs(np.random.default_rng(6)), {}
+
+
+def _takes_the_whole(make, kind, num_servers):
+    """Replies that more than one reader has to see (a search, replica
+    groups, a cache that stores) take the whole array as before, however
+    large, and say so."""
+    table, data, actor = _matrix(num_servers,
+                                 cache=kind is _active_row_cache)
+    ids, reply = kind(table, actor)
+    with _cut_at(CUT_FROM):
+        out, sizes, moved = _device_get(table, actor, ids, **reply)
+    np.testing.assert_array_equal(out, data[ids])
+    assert min(sizes) >= CUT_FROM  # large enough, each of them
+    assert moved["GET_REPLY_ROWS_PIECED"] == 0
+    assert moved["GET_REPLY_ROWS_WHOLE"] == len(sizes)
+    assert moved["BLOB_D2H"] == moved["BLOB_D2H_COPY"] == len(sizes)
+    if kind is _searched and num_servers > 1:
+        assert moved["GET_REPLY_ROWS_PLACED"] == len(sizes)
+    if kind is _active_row_cache:
+        assert table._row_cache.missing_of(np.unique(ids)).size == 0
+
+
+def _codec_reply(make, kind, num_servers):
+    """A sparse table over a wire: the values come as a codec frame, a
+    host payload however large, and are decoded whole."""
+    zoo = _Zoo(num_servers)
+    zoo.net = types.SimpleNamespace(in_process=False)
+    table = MatrixWorker(ROWS, COLS, is_sparse=True, zoo=zoo)
+    assert table._compress
+    data = np.arange(ROWS * COLS, dtype=np.float32).reshape(ROWS, COLS)
+    actor = _Actor(table, data)
+    actor.codec = True
+    ids = _sorted_runs(np.random.default_rng(8))
+    out = _buffer(ids.size, COLS)
+    with _cut_at(CUT_FROM):
+        mid = table.get_rows_async(ids, out)
+        actor.partition()
+        shards = len(actor.shards[mid])
+        before = _counts()
+        actor.reply(mid)
+    moved = _moved(before)
+    np.testing.assert_array_equal(out, data[ids])
+    assert moved["GET_REPLY_ROWS_PIECED"] == moved["BLOB_D2H"] == 0
+    assert moved["GET_REPLY_ROWS_WHOLE"] == shards
+    assert moved["CLIENT_PLACE_ROWS"] == shards
+
+
+def _other_sinks_take_the_whole(make, kind, num_servers):
+    """A scatter read and a prefetch read a reply shard's rows in their
+    own way: large device replies reach them as one array."""
+    table, data, actor = _matrix(num_servers, cache=kind is _cache_only)
+    with _cut_at(4):  # everything is large
+        before = _counts()
+        mids, landed = kind(table, data, actor)
+        actor.device.update(mids)
+        actor.partition()
+        shards = sum(len(actor.shards[mid]) for mid in mids)
+        for mid in mids:
+            actor.reply(mid)
+        landed()
+    moved = _moved(before)
+    assert moved["GET_REPLY_ROWS_PIECED"] == 0
+    assert moved["GET_REPLY_ROWS_WHOLE"] == moved["BLOB_D2H"] == shards
+
+
 _MATRIX_KINDS = [_rows, _whole, _device, _device_keys, _cache_only, _scatter]
 _ARRAY_KINDS = [_whole, _device, _cache_only]
 CASES = (
@@ -424,7 +605,18 @@ CASES = (
     + [(_reply_without_a_sink, make, None, n)
        for make in (_matrix, _array) for n in (1, 3)]
     + [(_two_host_gets_in_flight, None, None, 1),
-       (_many_requesters_one_table, None, None, 1)])
+       (_many_requesters_one_table, None, None, 1)]
+    + [(_pieced_equals_whole, None, shape, n)
+       for shape, n in ((_keys_are_the_request, 1), (_padded_tail, 1),
+                        (_padded_tail, 3), (_sorted_runs, 1),
+                        (_sorted_runs, 3))]
+    + [(_takes_the_whole, None, kind, n)
+       for kind in (_searched, _replica_rows, _routed_to_a_holder,
+                    _active_row_cache) for n in (1, 3)
+       if (kind, n) != (_searched, 1)]  # one server's shard is the request
+    + [(_codec_reply, None, None, n) for n in (1, 3)]
+    + [(_other_sinks_take_the_whole, None, kind, n)
+       for kind in (_scatter, _cache_only) for n in (1, 3)])
 
 
 def _case_id(case):
@@ -437,3 +629,74 @@ def _case_id(case):
 def test_reply_sinks(case):
     scenario, make, kind, num_servers = case
     scenario(make, kind, num_servers)
+
+
+# -- Blob.host_row_pieces: a device payload of rows, by row ranges ----------
+
+@pytest.mark.parametrize("n_col", [50, 128])
+@pytest.mark.parametrize("n_rows, cut_from, pieces", [
+    (64, 1, 4),       # a multiple of the piece
+    (70, 1, 5),       # and not: the last piece is the short one
+    (3, 1, 2),        # never fewer than two
+    (200, 1, 8),      # nor more than D2H_MOST_PIECES: larger pieces then
+    (64, None, 4),    # AT the threshold: cut
+    (63, "64 rows", 1),   # under it: the one array _host gives
+])
+def test_blob_row_pieces(n_rows, n_col, cut_from, pieces):
+    """The pieces of a device [n, c] payload concatenate to np.asarray
+    of the whole; the monitors count one entry a payload; once the
+    pieces are cut the blob holds no reference to the device array and
+    afterwards reads as the same payload."""
+    import jax.numpy as jnp
+    rows = np.random.default_rng(n_rows).normal(
+        size=(n_rows, n_col)).astype(np.float32)
+    row_bytes = n_col * 4
+    cut_from = {1: 1, None: rows.nbytes, "64 rows": 64 * row_bytes}[cut_from]
+    names = ("BLOB_D2H", "BLOB_D2H_READY", "BLOB_D2H_COPY", "BLOB_D2H_BYTES")
+    before = {name: Dashboard.get(name).count for name in names}
+    device = jnp.asarray(rows)
+    alive = weakref.ref(device)
+    blob = Blob(device)
+    del device
+    programs = blobmod._row_piece()._cache_size()
+    with _cut_at(cut_from, piece=16 * row_bytes):
+        assert blob.pieced_rows(np.float32, n_rows, n_col) == (pieces > 1)
+        assert not blob.pieced_rows(np.float32, n_rows, n_col + 1)
+        assert not blob.pieced_rows(np.int32, n_rows, n_col)
+        taken = blob.host_row_pieces(np.float32, n_rows, n_col)
+        first, piece = next(taken)
+        if pieces > 1:  # cut: nothing keeps the whole on the device
+            gc.collect()
+            assert alive() is None and not blob.on_device
+        got = [(first, piece)] + list(taken)
+    assert len(got) == pieces
+    # one cut program a reply shape, the short last piece's too (64 rows
+    # come twice: the second case finds the first's program)
+    built = blobmod._row_piece()._cache_size() - programs
+    assert built == (pieces > 1) or (n_rows == 64 and built == 0)
+    assert [first for first, _ in got] \
+        == np.cumsum([0] + [len(p) for _, p in got[:-1]]).tolist()
+    assert len({len(p) for _, p in got[:-1]}) <= 1  # equal, the last aside
+    for _, piece in got:
+        assert piece.shape[1:] == (n_col,) and not piece.flags.writeable
+    np.testing.assert_array_equal(np.concatenate([p for _, p in got]), rows)
+    moved = {name: Dashboard.get(name).count - before[name]
+             for name in names}
+    assert moved == {"BLOB_D2H": 1, "BLOB_D2H_READY": 1, "BLOB_D2H_COPY": 1,
+                     "BLOB_D2H_BYTES": rows.nbytes}
+    # the same payload afterwards, and no second copy off the device
+    np.testing.assert_array_equal(blob.as_rows(np.float32, n_rows, n_col),
+                                  rows)
+    assert blob.size == rows.nbytes
+    assert Dashboard.get("BLOB_D2H").count - before["BLOB_D2H"] == 1
+
+
+def test_a_host_payload_is_one_piece():
+    """Nothing to overlap: a host payload, however large, is the one
+    array ``as_rows`` gives, in place."""
+    rows = np.arange(600, dtype=np.float32).reshape(100, 6)
+    blob = Blob(rows)
+    with _cut_at(1, piece=48):
+        assert not blob.pieced_rows(np.float32, 100, 6)
+        (first, piece), = blob.host_row_pieces(np.float32, 100, 6)
+    assert first == 0 and np.shares_memory(piece, rows)

@@ -4,8 +4,8 @@ Source of truth: the ``METRIC_NAMES`` literal in
 ``multiverso_tpu/util/dashboard.py`` (parsed, never imported). Checked
 per scanned file:
 
-* ``monitor("X")`` / ``samples("X")`` / ``count("X")`` /
-  ``count_event("X")`` — called as a PLAIN NAME with a literal string
+* ``monitor("X")`` / ``laps("X")`` / ``samples("X")`` / ``count("X")``
+  / ``count_event("X")`` — called as a PLAIN NAME with a literal string
   first argument — must name a registry entry. A trailing-``*`` family
   entry (``DISPATCH_MS[d*]``) covers its per-destination/per-table
   instances (``DISPATCH_MS[d3]``). A typo'd metric name otherwise
@@ -30,7 +30,7 @@ from typing import Dict, Iterator, Optional
 
 from .framework import LintPass, ModuleInfo, Violation
 
-METRIC_FNS = {"monitor", "samples", "count", "count_event"}
+METRIC_FNS = {"monitor", "laps", "samples", "count", "count_event"}
 
 #: A metric-table row is `NAME` followed by its KIND (monitor /
 #: samples / counter) — the kind column is what distinguishes the
