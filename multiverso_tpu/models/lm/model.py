@@ -118,11 +118,16 @@ too: ``vocab`` rows of embedding and of head, the loss over the slice.
 **No token is dropped.** The (token, expert) assignments that fall on
 held experts are sorted by expert and the three products run as grouped
 products over the ragged groups (``grouped_product``: megablox's kernel
-on a TPU, ``jax.lax.ragged_dot`` elsewhere), in buffers sized for every
-assignment (``T * top_k`` rows), so whatever the routing every
+on a TPU, ``jax.lax.ragged_dot`` elsewhere). The held ones lie FIRST in
+the sorted order, so the buffers hold ``experts_capacity`` rows (twice
+the even share of the ``T * top_k`` assignments) where a sequence's held
+assignments fit that, and all ``T * top_k`` rows where they do not: the
+device chooses by the count (``routed_experts``; the short buffer gives
+the numbers of the long one to the bit), and whatever the routing every
 assignment is computed. Rows past the held ones belong to no group: the
 kernel visits the tiles that hold a group's rows and no other, so the
-products' cost follows the assignments that fell on held experts.
+products' cost follows the assignments that fell on held experts; the
+gathers, masks and sums around them follow the buffer.
 
 **Precision.** Matrix products take bfloat16 inputs and accumulate in
 float32 (``mm``, ``grouped_mm``; their weight gradients come out in
@@ -731,15 +736,31 @@ def _grouped_fwd(x, w, sink, group_sizes):
             (x.astype(BF16), w, group_sizes, jnp.zeros((), x.dtype)))
 
 
-def _grouped_bwd(res, g):
+def _grouped_bwd(res, g, kernel=None):
     xb, w, group_sizes, like = res
     gb = g.astype(BF16)
-    dx = grouped_product(gb, w, group_sizes, transpose_w=True).astype(
-        like.dtype)
-    return dx, jnp.zeros_like(w), grouped_outer(xb, gb, group_sizes), None
+    dx = grouped_product(gb, w, group_sizes, transpose_w=True,
+                         kernel=kernel).astype(like.dtype)
+    return (dx, jnp.zeros_like(w),
+            grouped_outer(xb, gb, group_sizes, kernel=kernel), None)
 
 
 grouped_mm.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@jax.custom_vjp
+def grouped_mm_xla(x, w, sink, group_sizes):
+    """``grouped_mm`` by XLA's grouped product on every backend, both
+    passes: no kernel in the program (``routed_experts``' fallback)."""
+    del sink
+    return grouped_product(x.astype(BF16), w, group_sizes, kernel=False)
+
+
+grouped_mm_xla.defvjp(
+    lambda x, w, sink, group_sizes: (
+        grouped_mm_xla(x, w, sink, group_sizes),
+        (x.astype(BF16), w, group_sizes, jnp.zeros((), x.dtype))),
+    functools.partial(_grouped_bwd, kernel=False))
 
 
 def rmsnorm(x, scale, eps):
@@ -1090,28 +1111,41 @@ def _rows_of(x, order, k):
     return x[order // k]
 
 
+def _rows_at(rows, back):
+    """``rows[back]``. A buffer shorter than the assignments ends in a
+    zero row of its own here, and ``back`` was held to it
+    (``_experts_in``): an assignment that has no row reads the zero that
+    it reads in the full buffer. (Left to itself a gather CLAMPS an index
+    past the end, and reads the last row, which is live when the buffer
+    is full.)"""
+    if rows.shape[0] < back.shape[0]:
+        rows = jnp.pad(rows, ((0, 1), (0, 0)))
+    return rows[back]
+
+
 def _sum_back(rows, back, k):
-    return rows[back].reshape(-1, k, rows.shape[-1]).astype(F32).sum(axis=1)
+    return _rows_at(rows, back).reshape(-1, k, rows.shape[-1]).astype(
+        F32).sum(axis=1)
 
 
 @jax.custom_vjp
 def permute(x, order, back):
-    """``x[order]`` for a permutation ``order`` with inverse ``back``:
-    backward ``g[back]``."""
+    """``x[order]`` for a permutation ``order`` with inverse ``back``, or
+    for its first rows: backward ``g[back]`` (``_rows_at``)."""
     return x[order]
 
 
 permute.defvjp(lambda x, order, back: (x[order], back),
-               lambda back, g: (g[back], None, None))
+               lambda back, g: (_rows_at(g, back), None, None))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def dispatch(h, order, back, k):
     """Each sorted assignment's token row: ``h[order // k]``. ``order``
-    is a permutation of the ``T * k`` assignments and ``back`` its
-    inverse, so the backward pass is ``combine``'s sum, a gather, where
-    the gather's own transpose would be a scatter-add (serial on a
-    TPU)."""
+    is a permutation of the ``T * k`` assignments (or its first rows:
+    ``_experts_in``) and ``back`` its inverse, so the backward pass is
+    ``combine``'s sum, a gather, where the gather's own transpose would
+    be a scatter-add (serial on a TPU)."""
     return _rows_of(h, order, k)
 
 
@@ -1157,23 +1191,48 @@ def experts_block(cfg: LMConfig, mats, sinks, norm, a, ids, weights):
     return a + out, sizes
 
 
-def routed_experts(cfg: LMConfig, mats, sinks, h, ids, weights, norm=None):
-    """The held experts' part of ``sum_e w_e E_e(h)`` for one sequence
-    with its routing: ``(sum [T, hidden] float32, each held expert's
-    assignments)``. ``h`` [T, hidden] is the experts' normed input in
-    bfloat16, or with ``norm`` the stream that is normed by it here."""
-    t, k = ids.shape
+#: The routed experts' short buffer holds this many times the even share
+#: of a sequence's assignments (``experts_capacity``). Chosen by the
+#: records (PERF.md section 5): laguna33b.ps-8k's held share is 0.10 to
+#: 0.18 a layer where 0.125 is even, and in sdar30b.ps-bd4k one held
+#: expert takes every masked position (the fullest reads 4.77 times the
+#: mean with 50.3% masked: about 0.21 of the assignments live, 1.7 times
+#: the even eighth). 1.5 would send both to the full buffer in some
+#: layers; PERF.md section 6, PR 49, has the shares read at 2.
+EXPERTS_SHORT_SHARES = 2
+
+
+def experts_capacity(cfg: LMConfig, t: int) -> int:
+    """Rows of the routed experts' short buffer for a sequence of ``t``
+    positions: ``EXPERTS_SHORT_SHARES`` times the even share of its ``t *
+    top_k`` assignments in whole tiles of the grouped products (``_use_gmm``
+    then picks the full buffer's kernel, on the same tiles). Where that is
+    half of them or more there is ONE buffer of them all: ``_by_load``."""
+    every = t * cfg.top_k
+    cap = -(-EXPERTS_SHORT_SHARES * every * cfg.experts_held[1]
+            // (cfg.n_experts * GROUPED_TILE_ROWS)) * GROUPED_TILE_ROWS
+    return cap if 2 * cap < every else every
+
+
+def _experts_in(cfg: LMConfig, n: int, mats, sinks, h, weights, norm,
+                order, back, sizes, product_of=None):
+    """``routed_experts``' sum in buffers of ``n`` rows, which hold every
+    assignment on a held expert (``sum(sizes) <= n``): all ``T * k`` of
+    them, or the first ``n`` of the sorted order, where the held ones
+    lie. ``product_of``: ``grouped_mm`` (when None) or its twin."""
+    t, k = weights.shape
     count = cfg.experts_held[1]
-    order, sizes = held_groups(cfg, ids)
-    back = jnp.argsort(order).astype(jnp.int32)     # assignment -> its row
-    live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+    if n < t * k:   # a row past the buffer is the zero row: ``_rows_at``
+        order, back = order[:n], jnp.minimum(back, n)
+    live = (jnp.arange(n) < jnp.sum(sizes))[:, None]
     if norm is not None:
         h = rmsnorm(h, norm, cfg.eps).astype(BF16)
     rows = jnp.where(live, dispatch(h, order, back, k), 0)
 
     def product(rows, name, n_in, n_out):
-        return grouped_mm(rows, mats[name].reshape(count, n_in, n_out),
-                          sinks[name].reshape(count, n_in, n_out), sizes)
+        return (product_of or grouped_mm)(
+            rows, mats[name].reshape(count, n_in, n_out),
+            sinks[name].reshape(count, n_in, n_out), sizes)
 
     gate = product(rows, "w_gate", cfg.hidden, cfg.expert_width)
     up = product(rows, "w_up", cfg.hidden, cfg.expert_width)
@@ -1184,7 +1243,99 @@ def routed_experts(cfg: LMConfig, mats, sinks, h, ids, weights, norm=None):
     # the tokens and summed there in float32
     w_rows = permute(weights.reshape(t * k, 1), order, back)
     out = (jnp.where(live, out, 0) * w_rows).astype(BF16)
-    return combine(out, order, back, k), sizes
+    return combine(out, order, back, k)
+
+
+def _by_load(cfg: LMConfig, run, weights, sizes, *operands):
+    """``run(n, product, *operands)`` at ``n`` the short buffer's rows
+    where the sequence's held assignments fit them, else at all ``T * k``:
+    chosen on the device, one of the two run. The short buffer takes the
+    grouped products' kernel wherever one buffer of ``T * k`` rows would
+    (``grouped_mm``); the fallback takes XLA's form on every backend
+    (``grouped_mm_xla``): it computes every row, about twice the kernel's
+    cost, and no sequence of the four cells takes it. What a second body
+    costs is SET-UP, tracing and lowering it (PERF.md section 6, PR 49:
+    with the kernel in both, 0.65 to 1.3 s a layer program, 11% of
+    ``sdar30b.ps-bd4k``'s ``setup_s``, over its bound; so, 0.4 to 0.75 s).
+    Hence also ``experts_capacity``'s ONE buffer where the short one would
+    be half the rows: it then saves a step 4% (``st21b.ps-8k``) and would
+    cost four programs' set-up."""
+    t, k = weights.shape
+    cap = experts_capacity(cfg, t)
+    return jax.lax.cond(jnp.sum(sizes) <= cap,
+                        lambda ops: run(cap, grouped_mm, *ops),
+                        lambda ops: run(t * k, grouped_mm_xla, *ops),
+                        operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_by_load(cfg: LMConfig, mats, sinks, h, weights, order, back,
+                     sizes):
+    """``_experts_in`` the short buffer or the full one, by the
+    sequence's routing. Differentiated as ONE function: ``jax.vjp``
+    through a ``cond`` would keep both branches' residuals and fill the
+    untaken one's with zeros at full size, which costs what the short
+    buffer saves. So the forward rule keeps the arguments alone, and the
+    backward rule chooses again and differentiates the chosen branch
+    inside it: a pass costs one forward and one pull, of one path. The
+    matrices' gradients leave through ``sinks`` as everywhere (``mm``);
+    a branch makes the zeros it differentiates against."""
+    del sinks
+
+    def run(n, product_of, mats, *rest):
+        return _experts_in(cfg, n, mats, _zeros_like_f32(mats), *rest,
+                           product_of)
+
+    return _by_load(cfg, run, weights, sizes, mats, h, weights, None, order,
+                    back, sizes)
+
+
+def _experts_by_load_fwd(cfg, mats, sinks, h, weights, order, back, sizes):
+    out = _experts_by_load(cfg, mats, sinks, h, weights, order, back, sizes)
+    return out, (mats, h, weights, order, back, sizes)
+
+
+def _experts_by_load_bwd(cfg, res, g):
+    mats, _, weights, _, _, sizes = res
+
+    def pull(n, product_of, mats, h, weights, order, back, sizes, g):
+        return jax.vjp(
+            lambda s, h, w: _experts_in(cfg, n, mats, s, h, w, None, order,
+                                        back, sizes, product_of),
+            _zeros_like_f32(mats), h, weights)[1](g)
+
+    d_sinks, dh, dw = _by_load(cfg, pull, weights, sizes, *res, g)
+    return (jax.tree_util.tree_map(jnp.zeros_like, mats), d_sinks, dh, dw,
+            None, None, None)
+
+
+_experts_by_load.defvjp(_experts_by_load_fwd, _experts_by_load_bwd)
+
+
+def routed_experts(cfg: LMConfig, mats, sinks, h, ids, weights, norm=None):
+    """The held experts' part of ``sum_e w_e E_e(h)`` for one sequence
+    with its routing: ``(sum [T, hidden] float32, each held expert's
+    assignments)``. ``h`` [T, hidden] is the experts' normed input in
+    bfloat16, or with ``norm`` the stream that is normed by it here.
+
+    The work is done in a buffer of ``experts_capacity`` rows where the
+    sequence's assignments on held experts fit it: the same rows in the
+    same order through the same tiles as in a buffer of all ``T * k``
+    rows, so the same numbers to the bit. Where they do not fit, it is
+    done in one of all ``T * k`` rows (``_experts_by_load``, ``_by_load``:
+    the same sums by XLA's grouped product), so no routing drops an
+    assignment."""
+    t, k = ids.shape
+    order, sizes = held_groups(cfg, ids)
+    back = jnp.argsort(order).astype(jnp.int32)     # assignment -> its row
+    mats, sinks = ({n: of[n] for n in DENSE} for of in (mats, sinks))
+    if experts_capacity(cfg, t) == t * k:       # one buffer: no choice
+        return _experts_in(cfg, t * k, mats, sinks, h, weights, norm, order,
+                           back, sizes), sizes
+    if norm is not None:    # no buffer's own: outside the choice
+        h = rmsnorm(h, norm, cfg.eps).astype(BF16)
+    return _experts_by_load(cfg, mats, sinks, h, weights, order, back,
+                            sizes), sizes
 
 
 def gated_mlp(cfg: LMConfig, mats, sinks, names, h):

@@ -71,7 +71,10 @@ same, or ``2 B T`` under block diffusion), ``LM_GET_BYTES`` and
 ``LM_ADD_BYTES`` (whole-table traffic) at once; ``LM_HELD_ASSIGNMENTS``
 ((token, expert) assignments on held experts, every layer),
 ``LM_EXPERT_MAX_TOKENS`` (the fullest held expert's tokens, summed over
-layers), ``LM_EMBED_ROWS`` (distinct embedding rows) and
+layers), ``LM_EXPERTS_SHORT`` and ``LM_EXPERTS_FULL`` (one a sparse layer
+a sequence: whether its routed experts took the short buffer or the one
+of every assignment, from the same count and ``model.experts_capacity``),
+``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
 computed on the device and read at the start of the next step,
 which waits for the last one's programs anyway: one step is in flight
@@ -422,6 +425,12 @@ class PSLMTrainer:
                 donate_argnums=(0, 1))
             self._enter_back = jax.jit(self._enter_streams_back,
                                        donate_argnums=(0,))
+        # which of a step's stats are a sparse layer's (the module's layer
+        # last), and the rows of its experts' buffer (model.routed_experts)
+        self._sparse = [cfg.sparse(i) for i in range(cfg.n_layers)] \
+            + [1] * bool(self.module)
+        self._experts_cap = lm.experts_capacity(
+            cfg, self.T * (2 if self.diffusion else 1))
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
@@ -678,6 +687,14 @@ class PSLMTrainer:
                                              for s in per_layer)))
         count("LM_EXPERT_MAX_TOKENS", int(sum(s[:, 1].sum()
                                               for s in per_layer)))
+        # which buffer ``model.routed_experts`` took on the device, from
+        # the count it chose by
+        fits = np.concatenate([s[:, 0] <= self._experts_cap for s, sparse
+                               in zip(per_layer, self._sparse) if sparse])
+        for name, n in (("LM_EXPERTS_SHORT", fits.sum()),
+                        ("LM_EXPERTS_FULL", (~fits).sum())):
+            if n:
+                count(name, int(n))
         outputs = self.cfg.n_experts
         fullest = sum(int(s[:, 2:2 + outputs].sum(axis=0).max())
                       for s in per_layer if s.shape[1] >= 2 + outputs)

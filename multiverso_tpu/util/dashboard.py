@@ -248,6 +248,11 @@ METRIC_NAMES: Dict[str, str] = {
                            "experts, every layer and sequence",
     "LM_EXPERT_MAX_TOKENS": "the fullest held expert's tokens, summed "
                             "over layers and sequences",
+    "LM_EXPERTS_SHORT": "sparse layers' sequences whose routed experts "
+                        "worked in the short buffer (the held "
+                        "assignments fit model.experts_capacity)",
+    "LM_EXPERTS_FULL": "sparse layers' sequences whose routed experts "
+                       "took the buffer of every assignment",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
                      "trainer that holds the module)",

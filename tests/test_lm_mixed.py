@@ -491,6 +491,10 @@ def test_what_a_step_counts(run):
     assert delta("LM_ROUTER_LOAD_MAX") == fullest >= 2 * B * T * 2 / 8
     assert delta("LM_HELD_ASSIGNMENTS") == sum(
         int(s[:, 0].sum()) for s in stats) > 0
+    # one a sparse layer a sequence, the dense layer's zeros not among
+    # them; at these widths there is one buffer
+    assert delta("LM_EXPERTS_SHORT") == 2 * B
+    assert delta("LM_EXPERTS_FULL") == 0
     # a fresh gate is half open: 12 + 16 + 12 heads, in thousandths
     opened = delta("LM_GATE_OPEN")
     assert opened == int(round(sum(s[:, -1].mean() for s in stats)))

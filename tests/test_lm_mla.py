@@ -875,6 +875,9 @@ def test_what_a_step_counts(run):
     assert delta("LM_ROUTER_LOAD_MAX") == fullest >= 2 * B * T * 2 / 8
     held = sum(int(s[:, 0].sum()) for s in run["stats"])
     assert delta("LM_HELD_ASSIGNMENTS") == held > 0
+    # the sparse layer's and the module's layer's sequences; not the dense
+    assert delta("LM_EXPERTS_SHORT") == 2 * B
+    assert delta("LM_EXPERTS_FULL") == 0
     tables = len(run["names"])
     # a Get and an Add a table, and the closing row Get
     assert delta("WORKER_PROCESS_GET") == tables + 1

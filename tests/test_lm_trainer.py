@@ -167,6 +167,8 @@ def test_what_a_step_counts(run):
     assert 0 < held <= STEPS * cfg.n_layers * B * T * cfg.top_k
     assert held / (cfg.experts_held[1] * STEPS * cfg.n_layers * B) \
         <= delta("LM_EXPERT_MAX_TOKENS") / (STEPS * cfg.n_layers * B) <= T
+    assert delta("LM_EXPERTS_SHORT") == STEPS * cfg.n_layers * B
+    assert "LM_EXPERTS_FULL" not in after
     assert 0 < delta("LM_EMBED_ROWS") <= STEPS * min(B * T, cfg.vocab)
     # 43 whole or row Gets and 43 Adds a step, and the closing row Get
     assert delta("WORKER_PROCESS_GET") == STEPS * 43 + 1
