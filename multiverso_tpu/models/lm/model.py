@@ -72,6 +72,33 @@ the plain residual, for layer ``l``:
       w_e = routed_scale s_e / sum_S s              ``scoring: "sigmoid"``:
                                                     no bias, no bias table
 
+**A fifth family** (``from_dict`` tells it by ``sa_config``: the language
+model of ``model_type: KeyeVL2``, Kwai-Keye's Keye-VL-2.0-30B-A3B, 2026)
+is the second's block under the next-token objective with a FOURTH thing
+that a layer's attention is described by, WHICH of the keys its mask shows
+a query it reads (``selection``: ``none`` | ``topk_indexer`` with
+``index_heads``, ``index_dim``, ``index_topk``, ``index_tile``), and
+rotary positions in sections (``Rotary.sections``; ``pos`` [3, T]); with
+``h = RMSNorm(x)`` and ``sg`` = stop-gradient (sparse.py):
+
+    q, k, v as the second family's; lane pair i    ``Rotary.sections``
+      of a head takes the position row of its        (16 | 24 | 24 pairs
+      section                                         of 64)
+    qI_j = sg(h) W_qI, kI = LayerNorm(sg(h) W_kI),  16 index heads of 64
+      both turned by position row 0;                  over one index key
+      w = sg(h) W_w 16^-1/2 64^-1/2
+    I[t, s] = sum_j w[t, j] relu(qI_j[t] . kI[s]), s <= t
+    S_t = the ``index_topk`` keys of largest I[t, .] (all while t <
+      index_topk; equal scores: the earlier key), EXACTLY
+    o_i[t] = sum_{s in S_t} softmax_{S_t}(q_i[t] . k[s] d^-0.5) v[s]
+    L_I = sum_t KL(sg(sum_i softmax_i[t, .]) / heads || softmax_{S_t} I[t, .])
+
+  ``L_I`` is a loss INSIDE the layer: the cross entropy reaches no indexer
+  tensor and ``L_I`` the indexer's five alone, so ``layer_grads`` makes
+  their gradients from what the layer recomputes and returns ``L_I`` as
+  a fourth result. The selection is a device array computed in the step:
+  no ``Mask`` kind, and the layer's ``Mask`` stays causal.
+
 **A layer is described by three independent kinds**, and each selects
 functions, not a family's branch: its ATTENTION (``attention``: ``gqa`` ->
 ``attention_inputs`` / ``attention_core`` / ``attention_gate`` /
@@ -153,7 +180,9 @@ third family's: ``mv.lm.attn.mla`` (+ ``.kernel``), ``mv.lm.hc``,
 ``mv.lm.shared_expert``, ``mv.lm.dense_mlp`` (the fourth's too),
 ``mv.lm.mtp`` (+ ``.head``), and its backward programs' sums over the
 sequences ``mv.lm.grad_sum``. The fourth's gate, its product, sigmoid and
-multiply, forward and backward: ``mv.lm.attn.gate``.
+multiply, forward and backward: ``mv.lm.attn.gate``. The fifth's:
+``mv.lm.indexer``, ``mv.lm.select``, ``mv.lm.attn.sparse`` (+
+``.kernel``), ``mv.lm.indexer.loss`` (sparse.py).
 """
 
 from __future__ import annotations
@@ -193,15 +222,20 @@ class Rotary:
     are); with ``yarn`` = (factor, beta_fast, beta_slow, original
     positions) blended as ``yarn_frequencies`` does; cos and sin times
     ``factor`` (YaRN's attention factor, which a score's rotated part
-    then carries squared)."""
+    then carries squared). With ``sections`` the positions come as rows
+    and lane pair ``i`` takes the row of its section."""
     theta: float
     lanes: int
     yarn: Tuple[float, ...] = ()
     factor: float = 1.0
+    sections: Tuple[int, ...] = ()  # lane pairs a ROW of positions, in runs:
+    #                                 the positions are [rows, T] then
 
     def how(self) -> dict:
         """What ``_rotary`` takes beside ``theta`` and the positions."""
         how = {"lanes": self.lanes}
+        if self.sections:
+            how["sections"] = self.sections
         if self.yarn:
             how["inv"] = yarn_frequencies(self.theta, self.lanes, *self.yarn)
         if self.factor != 1.0:
@@ -275,6 +309,15 @@ class LMConfig:
     hc_clamp: Tuple[float, float] = (0.0, 0.0)
     mtp_layers: int = 0             # multi-token modules held here (mtp.py)
     mtp_weight: float = 0.0         # the second loss's weight
+    # -- and, of its attention, WHICH KEYS a query reads among those its
+    # mask shows it ------------------------------------------------------------
+    selection: str = "none"         # | "topk_indexer": the ``index_topk``
+    #                                 keys a learned indexer scores highest
+    #                                 (sparse.py), with the indexer's sizes
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    index_tile: int = 0             # the selection's tiles, a side
 
     @property
     def n_layers(self) -> int:
@@ -331,6 +374,9 @@ class LMConfig:
             attention = GQA_MATRICES + (
                 (ATTN_GATE,) if self.attn_gate == "head" else ())
         shared = self.sparse(layer) and self.shared_width
+        if self.selection != "none":
+            from . import sparse
+            return attention + DENSE + sparse.INDEX_MATRICES
         return attention + DENSE + (SHARED if shared else ())
 
     @property
@@ -356,6 +402,8 @@ class LMConfig:
             return cls._from_mla(c)
         if "num_attention_heads_per_layer" in c:
             return cls._from_laguna(c)
+        if "sa_config" in c:
+            return cls._from_indexed(c)
         if "num_experts" in c:
             return cls._from_qwen3_moe(c)
         n = int(c["num_hidden_layers"])
@@ -535,13 +583,42 @@ class LMConfig:
             scoring="sigmoid",
             routed_scale=float(c["moe_routed_scaling_factor"]))
 
+    @classmethod
+    def _from_indexed(cls, c: dict) -> "LMConfig":
+        """Qwen3-MoE's block as ``model_type: KeyeVL2`` configures its
+        language model (benchmark/configs/keye-vl2-30b-a3b-lm.json):
+        ``_from_qwen3_moe``'s block under the next-token objective, with
+        rotary positions in sections (``rope_scaling.mrope_section``) and
+        in every layer an indexer that selects ``sa_config.topk`` keys a
+        query (sparse.py). Router, experts and shared input are one normed
+        stream (``router_input: "ffn_input"``: the same sums as
+        ``"ffn_norm"``'s, through ``feed_forward_vjp``)."""
+        base = cls._from_qwen3_moe(c)
+        sa, scaling = c["sa_config"], c["rope_scaling"]
+        CHECK(base.objective == "next_token"
+              and int(sa["indexer_num_kv_heads"]) == 1
+              and scaling["rope_type"] == "default"
+              and int(sa["q_chunk_size"]) == int(sa["kv_chunk_size"]),
+              "only the indexer with one key head and square chunks, on "
+              "plain sectioned rotary positions under the next-token "
+              "objective, is written down here")
+        sections = tuple(int(s) for s in scaling["mrope_section"])
+        turned = Rotary(theta=base.rope_theta, lanes=base.head_dim,
+                        sections=sections)
+        return dataclasses.replace(
+            base, router_input="ffn_input", rotary_kinds=(turned, turned),
+            selection="topk_indexer",
+            index_heads=int(sa["indexer_num_heads"]),
+            index_dim=int(sa["indexer_head_dim"]),
+            index_topk=int(sa["topk"]), index_tile=int(sa["q_chunk_size"]))
+
     def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it, built from
         the layer's kinds: a matrix table's (rows, columns) or a small
         tensor's (size,). In order: the attention's, the two norms, the
         stream mixers' (``residual: "mhc"``), the feed-forward's, the q
-        and k norms. The experts' three are stacked by expert along the
-        rows."""
+        and k norms, the indexer's five (``selection``). The experts' three
+        are stacked by expert along the rows."""
         h, d = self.hidden, self.head_dim
         if self.attention == "mla":
             # over the held heads: ``wq_b``, ``wkv_b``, ``wo`` cut by head,
@@ -588,6 +665,9 @@ class LMConfig:
                                "ws_down": (s, h)})
         if self.qk_norm:
             shapes.update({n: (d,) for n in QK_NORMS})
+        if self.selection != "none":
+            from . import sparse
+            shapes.update(sparse.shapes(self))
         return shapes
 
     def mtp_shapes(self) -> dict:
@@ -862,21 +942,28 @@ def yarn_frequencies(theta: float, lanes: int, factor: float, fast: float,
     return own / factor * ramp + own * (1 - ramp)
 
 
-def _rotary(x, theta, pos=None, inv=None, lanes=None, factor=1.0):
+def _rotary(x, theta, pos=None, inv=None, lanes=None, factor=1.0,
+            sections=()):
     """Rotary positions on [T, heads, d] (the halves paired, as the
     published model's ``rotate_half``), float32. ``pos`` [T] gives each
     row's position (``arange(T)`` when None); ``inv`` the pairs'
     frequencies where they are not ``theta``'s own (YaRN's); ``lanes``
     how many of a head's FIRST lanes are turned (all when None; the
     others pass as they are, bit for bit); ``factor`` multiplies cos and
-    sin."""
+    sin. With ``sections`` (lane pairs each, in runs) ``pos`` is [rows, T]
+    and a pair takes the row of its section."""
     t, _, d = x.shape
     lanes = d if lanes is None else lanes
     if inv is None:
         inv = 1.0 / theta ** (np.arange(0, lanes, 2, dtype=np.float64)
                               / lanes)
     pos = np.arange(t) if pos is None else np.asarray(pos)
-    angle = pos.astype(np.float64)[:, None] * inv[None, :]
+    if sections:
+        assert sum(sections) == lanes // 2, (sections, lanes)
+        pos = pos[np.repeat(np.arange(len(sections)), sections)].T
+    else:
+        pos = pos[:, None]
+    angle = pos.astype(np.float64) * inv[None, :]
     cos = jnp.asarray(factor * np.cos(angle), F32)[:, None, :]
     sin = jnp.asarray(factor * np.sin(angle), F32)[:, None, :]
     x1, x2 = x[..., :lanes // 2], x[..., lanes // 2:lanes]
@@ -1418,14 +1505,16 @@ def feed_forward_vjp(cfg: LMConfig, sparse: int, mats, sinks, small, u):
     return (sparse_vjp if sparse else dense_vjp)(cfg, mats, sinks, small, u)
 
 
-def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None):
+def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
+                selected=None):
     """What a forward program reports of one sequence through a layer
     whose feed-forward is ``feed_forward_vjp``'s: ``(stats, ids)``. A
     sparse layer's ``stats`` int32 [2 + n_experts]: assignments on held
     experts, the fullest held expert's, then every router output's; a
     dense layer's two zeros and no ids. With a gate its ``gate_open``
     (the gates' sum over heads of their mean over tokens) comes last, in
-    thousandths."""
+    thousandths; with a ``cfg.selection`` its counts (``selected``:
+    sparse.COUNTS of them) come last."""
     if sparse:
         ids, sizes, load = aux
         stats = jnp.concatenate(
@@ -1436,6 +1525,8 @@ def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None):
     if gate_open is not None:
         stats = jnp.concatenate(
             [stats, jnp.round(1e3 * gate_open)[None].astype(jnp.int32)])
+    if selected is not None:
+        stats = jnp.concatenate([stats, selected])
     return stats, ids
 
 
@@ -1457,30 +1548,47 @@ def _route_layer(cfg: LMConfig, router, norm_ffn, stream):
 def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
                   pos=None):
     """``a = x + Attn(RMSNorm(x))`` for one sequence and what pulls a
-    cotangent back through it: ``(a, gate_open, pull)``, ``pull(da) ->
-    (dx, matrix gradients, small gradients)``; ``gate_open`` is None
-    without a gate. A scope names a backward pass only where it is
-    entered OUTSIDE the differentiated function (inside, JAX writes it as
-    transpose(jvp(..)), which no reader takes for a scope): so the
-    attention's parts are differentiated one by one, the kernel and the
-    gate under their own names."""
-    scope = Mask.of(mask).scope
+    cotangent back through it: ``(a, stats, pull)``, ``pull(da) -> (dx,
+    matrix gradients, small gradients)``; ``stats`` is what
+    ``layer_stats`` takes by name of this attention (``gate_open`` with a
+    gate, ``selected`` with a selection). A scope names a backward pass
+    only where it is entered OUTSIDE the differentiated function (inside,
+    JAX writes it as transpose(jvp(..)), which no reader takes for a
+    scope): so the attention's parts are differentiated one by one, the
+    kernel and the gate under their own names.
+
+    With a ``cfg.selection`` the attention proper runs over the keys that
+    sparse.py's indexer selects of the layer's input (``selection_vjp``,
+    ``attention_vjp`` there), and the pull has a fourth result: the loss
+    that lives INSIDE the layer, whose gradients to the indexer's tensors
+    are made from what the layer recomputed, whatever ``da`` is."""
+    selection = None
+    if cfg.selection != "none":
+        from . import sparse as selection
+    scope = selection.SCOPE if selection else Mask.of(mask).scope
     qkv = {n: sinks[n] for n in ("wq", "wk", "wv")}
     with jax.named_scope(scope):
         (q, k, v, *h), pull_inputs = jax.vjp(
             lambda s, norms, x: attention_inputs(cfg, rope, mats, s, norms,
                                                  x, pos),
             qkv, _attention_norms(cfg, small), x)
+    stats = {}
+    if selection:
+        tiles, stats["selected"], pull_inner = selection.selection_vjp(
+            cfg, mats, sinks, small, x, pos)
     with jax.named_scope(scope + ".kernel"):
-        o, pull_core = jax.vjp(
-            lambda q, k, v: attention_core(q, k, v, mask), q, k, v)
-    gate_open = pull_gate = None
+        if selection:
+            o, lse, pull_core = selection.attention_vjp(q, k, v, tiles)
+        else:
+            o, pull_core = jax.vjp(
+                lambda q, k, v: attention_core(q, k, v, mask), q, k, v)
+    pull_gate = None
     if h:
         with jax.named_scope(GATE_SCOPE):
             o, pull_gate, gate_open = jax.vjp(
                 lambda s, h, o: attention_gate(mats, {ATTN_GATE: s}, h, o),
                 sinks[ATTN_GATE], h[0], o, has_aux=True)
-        gate_open = gate_open / x.shape[0]
+        stats["gate_open"] = gate_open / x.shape[0]
     with jax.named_scope(scope):
         a, pull_output = jax.vjp(
             lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),
@@ -1500,11 +1608,16 @@ def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
         d_attn["wo"], dx = d_wo, dx + dx_inputs
         if pull_gate is not None:
             d_attn[ATTN_GATE] = d_gate
-        if cfg.qk_norm:
-            return dx, d_attn, dict(zip(("norm_attn",) + QK_NORMS, d_norms))
-        return dx, d_attn, {"norm_attn": d_norms}
+        d_small = dict(zip(("norm_attn",) + QK_NORMS, d_norms)) \
+            if cfg.qk_norm else {"norm_attn": d_norms}
+        if not selection:
+            return dx, d_attn, d_small
+        inner, dx_inner, d_mats, d_inner, d_norm_attn = pull_inner(q, k, lse)
+        d_small["norm_attn"] = d_small["norm_attn"] + d_norm_attn
+        return (dx + dx_inner, {**d_attn, **d_mats}, {**d_small, **d_inner},
+                inner)
 
-    return a, gate_open, pull
+    return a, stats, pull
 
 
 def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
@@ -1512,18 +1625,21 @@ def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
     """One sequence through one layer of the plain residual whose
     feed-forward reads one normed input (``cfg.one_ffn_input``): ``y = a
     + F(RMSNorm(a))``, ``a = x + Attn(RMSNorm(x))``: ``(y, (stats, ids),
-    pull)``, ``pull(dy) -> (dx, matrix gradients, small gradients)``."""
+    pull)``, ``pull(dy) -> (dx, matrix gradients, small gradients)`` and
+    after them what the attention's pull gives beyond its three (the loss
+    inside a layer with a ``cfg.selection``)."""
     sinks = _zeros_like_f32(mats)
-    a, gate_open, pull_attention = attention_vjp(cfg, rope, mask, mats,
-                                                 sinks, small, x, pos)
+    a, stats, pull_attention = attention_vjp(cfg, rope, mask, mats, sinks,
+                                             small, x, pos)
     v, aux, pull_ffn = feed_forward_vjp(cfg, sparse, mats, sinks, small, a)
 
     def pull(dy):
         du, (d_mats_ffn, d_small_ffn) = pull_ffn(dy)
-        dx, d_mats, d_small = pull_attention(dy + du)
-        return dx, {**d_mats, **d_mats_ffn}, {**d_small, **d_small_ffn}
+        dx, d_mats, d_small, *inner = pull_attention(dy + du)
+        return (dx, {**d_mats, **d_mats_ffn}, {**d_small, **d_small_ffn},
+                *inner)
 
-    return a + v, layer_stats(cfg, sparse, aux, gate_open), pull
+    return a + v, layer_stats(cfg, sparse, aux, **stats), pull
 
 
 def layer_forward(cfg: LMConfig, rope, mask, mats, small, x, pos=None,
@@ -1564,7 +1680,9 @@ def layer_grads(cfg: LMConfig, rope, mask, mats, small, x, dy, pos=None,
     """The layer recomputed from its input ``x`` and differentiated:
     ``(dx, matrix gradients, small gradients)`` for one sequence. Each
     part's backward pass runs under the scope of its forward pass, so a
-    device trace reads the two together."""
+    device trace reads the two together. With a ``cfg.selection`` there is
+    a fourth result, the loss that lives inside the layer
+    (``attention_vjp``)."""
     if cfg.one_ffn_input:
         return layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos)[2](dy)
     sinks = _zeros_like_f32(mats)

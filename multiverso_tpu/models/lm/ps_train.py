@@ -126,9 +126,14 @@ def _kind(cfg: LMConfig, rope: int, window: int, seq_len: int):
     """``(rotary, mask, positions)`` of a layer's programs: the rotary
     positions are the kind's own description where the model has one a
     kind (``LMConfig.rotary``), and the positions are given where they
-    are not the rows' own numbers."""
+    are not the rows' own numbers: block diffusion's two copies, or the
+    rows of a sectioned rotary (``Rotary.sections``)."""
     mask = cfg.layer_mask(window, seq_len)
-    return cfg.rotary(rope, window), mask, (
+    rotary = cfg.rotary(rope, window)
+    if getattr(rotary, "sections", ()):     # text: the rows equal
+        return rotary, mask, np.tile(np.arange(seq_len),
+                                     (len(rotary.sections), 1))
+    return rotary, mask, (
         mask.positions(2 * seq_len) if mask.kind == "blockdiff" else None)
 
 
@@ -218,13 +223,22 @@ def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
                      sparse: int = 1):
     """``(bfloat16 matrices, small, x, dy) -> (dx, matrix gradients, small
     gradients)``, the gradients float32 and summed over the sequences (a
-    router bias gets none: ``small`` holds it, the gradients do not)."""
+    router bias gets none: ``small`` holds it, the gradients do not). With
+    a ``cfg.selection`` a fourth result: the layer's inner loss (the
+    indexer's divergence, sparse.py), summed over the sequences."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
     def backward(mats, small, x, dy):
-        return _summed_over_sequences(
-            lambda seq: lm.layer_grads(cfg, rope, mask, mats, small, *seq,
-                                       pos, sparse), mats, small, (x, dy))
+        # what a layer's pull gives beyond its three (a loss inside the
+        # layer) rides with dx and is summed after
+        def one(seq):
+            dx, d_mats, d_small, *inner = lm.layer_grads(
+                cfg, rope, mask, mats, small, *seq, pos, sparse)
+            return (dx, *inner), d_mats, d_small
+
+        (dx, *inner), d_mats, d_small = _summed_over_sequences(
+            one, mats, small, (x, dy))
+        return (dx, d_mats, d_small, *(jnp.sum(s) for s in inner))
 
     def backward_streams(mats, small, x, dy):
         # the donated cotangents' stack is the carry: a sequence's dx is
@@ -434,10 +448,13 @@ class PSLMTrainer:
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
+        self._sum_scalars = jax.jit(lambda xs: sum(xs[1:], xs[0]))
         self._pending = []      # (table, msg id) of Adds not yet waited for
         self._stats = []        # device counts of steps not yet read
         self.steps = 0
         self.last_loss = None   # device scalar
+        self.last_inner_loss = None     # the layers' inner losses' sum, with
+        #                                 a ``cfg.selection``: device scalar
         self._last_dx = None    # the last program's result of the last step
 
     # -- the tables, by name (the checks read them) ---------------------------
@@ -623,6 +640,7 @@ class PSLMTrainer:
             if self.streams:
                 dx = _dispatch(self._leave_back, dx)
             pushed = []     # where each layer's Adds begin in _pending
+            inner_losses = []   # a layer's own loss (``cfg.selection``)
             for i in reversed(range(cfg.n_layers)):
                 mats, small, x_in, bias_step = kept.pop()
                 if self.streams and len(pushed) >= 2:
@@ -637,8 +655,9 @@ class PSLMTrainer:
                     # program to run meanwhile.
                     self._drain(pushed[-1])
                 pushed.append(len(self._pending))
-                dx, d_mats, d_small = _dispatch(self._backward[kinds[i]],
-                                                mats, small, x_in, dx)
+                dx, d_mats, d_small, *inner = _dispatch(
+                    self._backward[kinds[i]], mats, small, x_in, dx)
+                inner_losses += inner
                 self._push_layer(self.layers[i], {**d_mats, **d_small},
                                  bias_step)
             if self.streams:    # and the next tokens' rows' gradient
@@ -648,6 +667,8 @@ class PSLMTrainer:
                 self._push(self.embedding, dx, ids)
         self.steps += 1
         self.last_loss, self._last_dx = loss, dx
+        if inner_losses:
+            self.last_inner_loss = _dispatch(self._sum_scalars, inner_losses)
         count("LM_TOKENS", self.B * self.T)
         count("LM_POSITIONS", ids.size)
         count("LM_GET_BYTES", self._whole_bytes)
@@ -704,6 +725,13 @@ class PSLMTrainer:
             # each layer's gates summed over its heads, the step's mean
             count("LM_GATE_OPEN", int(round(sum(
                 s[:, -1].mean() for s in per_layer))))
+        if self.cfg.selection != "none":
+            # a layer's last four (sparse.COUNTS), every layer and sequence
+            for at, name in enumerate(("LM_SELECTED_PAIRS", "LM_CAUSAL_PAIRS",
+                                       "LM_SELECT_TILES_LIVE",
+                                       "LM_SELECT_TILES"), start=-4):
+                count(name, int(sum(s[:, at].astype(np.int64).sum()
+                                    for s in per_layer)))
         count("LM_EMBED_ROWS", int(distinct))
         count("LM_MASKED_TOKENS", int(scored))
 
