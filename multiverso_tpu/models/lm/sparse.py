@@ -44,12 +44,12 @@ lines), and the attention is the library's splash kernel under a DYNAMIC
 mask: the tiles under the diagonal are its ``partial_mask_blocks`` and its
 three small tables say which tile to skip among them (``_mask_infos``,
 built on the device in the step), so a tile with no selected pair is not
-visited and every other tile is computed whole. The divergence goes a
-block of queries at a time in ``jax.numpy`` (``index_loss_vjp``).
-Elsewhere ``index_scores`` + ``search`` a block of queries at a time and
-the same sums over the dense mask. Pallas is imported here and in
-sparse_kernels.py alone, and these modules only where a configuration
-names a selection.
+visited and every other tile is computed whole; the divergence is one
+kernel too (sparse_kernels.index_loss_tiles under ``index_loss_vjp``).
+Elsewhere ``index_scores`` + ``search`` a block of queries at a time, the
+same sums over the dense mask, and the divergence a block of queries at a
+time in ``jax.numpy``. Pallas is imported here and in sparse_kernels.py
+alone, and these modules only where a configuration names a selection.
 
 Scopes: ``mv.lm.indexer`` (the indexer's projections, norm, rotary, and
 the scores), ``mv.lm.select`` (the search and the tiles),
@@ -399,7 +399,11 @@ def index_loss_vjp(qi, ki, w, tiles, q, k, lse):
     """``L_I`` of one sequence and its gradients to the indexer's three:
     ``(L_I, (d qI, d kI, d w))``, a block of queries at a time (the
     block's scores and probabilities are recomputed in its pull: no [T, T]
-    float array)."""
+    float array). On a TPU one kernel, a tile of queries against the key
+    tiles under the diagonal (sparse_kernels.index_loss_tiles)."""
+    if _on_chip(tiles.shape[2]):
+        from . import sparse_kernels
+        return sparse_kernels.index_loss_tiles(qi, ki, w, tiles, q, k, lse)
     t = qi.shape[0]
     rows = _blocks(t, LOSS_BLOCK)
     n = t // rows

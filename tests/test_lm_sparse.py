@@ -1,14 +1,15 @@
 """The fifth family through multiverso_tpu/models/lm (attention over the
 keys a learned indexer selects, sparse.py) against the plain reference
 (benchmark/reference/lm_sparse_step.py) at small widths on the CPU: the
-exact search and its tie rule, the kernel's body in interpret mode, the
-sectioned rotary, a layer's forward pass and every gradient (the
-indexer's among them), which loss reaches which tensor, the causal layer
-below ``topk``, the expert shares, and a step through the server's tables
-and Adam; and that the four older configurations are described as they
-were."""
+exact search and its tie rule, the kernels' bodies in interpret mode (the
+selection's, the divergence's), the sectioned rotary, a layer's forward
+pass and every gradient (the indexer's among them), which loss reaches
+which tensor, the causal layer below ``topk``, the expert shares, and a
+step through the server's tables and Adam; and that the four older
+configurations are described as they were."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -207,7 +208,9 @@ def test_the_kernel_s_body_is_the_search(t, tile, k, ties):
                           np.asarray(want))
 
 
-def test_the_kernel_compiles_for_the_chip_at_the_published_widths():
+def _shapes_on_a_described_v5e():
+    """``shape(*sizes, dtype=float32)`` on one chip of a described v5e;
+    skips where no topology can be described."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     try:
@@ -216,15 +219,148 @@ def test_the_kernel_compiles_for_the_chip_at_the_published_widths():
     except Exception as e:  # noqa: BLE001 - no compiler for it here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     one = SingleDeviceSharding(topo.devices[0])
+    return lambda *s, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        s, dtype, sharding=one)
+
+
+def test_the_kernel_compiles_for_the_chip_at_the_published_widths():
+    shape = _shapes_on_a_described_v5e()
     t = 16384
-
-    def shape(*s):
-        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
-
     compiled = jax.jit(lambda qi, ki, w: sparse_kernels.select_tiles(
         qi, ki, w, topk=2048, tile=512)).lower(
             shape(t, 16, 64), shape(t, 64), shape(t, 16)).compile()
     assert compiled.memory_analysis().output_size_in_bytes == t * t
+
+
+# -- the divergence's kernel --------------------------------------------------------
+# sparse_kernels.index_loss_tiles, interpreted, against ``index_loss_vjp``'s
+# ``jax.numpy`` form (what runs here). The two round alike going INTO the
+# products; the ``jax.numpy`` form's pull also rounds a block's d qI and
+# d kI to bfloat16 (its operands' type), the kernel keeps float32: 2.4e-3
+# and 1.8e-3 of their norms at these sizes, d w and the loss to float32's
+# last digits.
+
+LOSS_CASES = {      # t, tile, topk, tied scores, tiles emptied and filled
+    "every_query_below_topk": (256, 128, 300, False, False),
+    "most_queries_select": (256, 128, 32, False, False),
+    "tied_scores": (512, 128, 64, True, False),
+    "a_tile_empty_and_a_tile_full": (512, 128, 64, False, True),
+    "two_query_blocks_a_tile": (1024, 512, 200, False, False)}
+LOSS_LIMITS = {"loss": 1e-5, "d_qi": 8e-3, "d_ki": 8e-3, "d_w": 1e-5}
+
+
+@functools.lru_cache(maxsize=None)
+def _divergence_inputs(t, tile, topk, ties=False, crafted=False):
+    rng = np.random.default_rng(0)
+    qi = rng.normal(size=(t, 4, 8))
+    ki = rng.normal(size=(t, 8))
+    w = rng.normal(size=(t, 4))
+    if ties:
+        qi, ki, w = np.round(qi), np.round(ki), np.abs(np.round(w))
+    qi, ki, w = (jnp.asarray(a, jnp.float32) for a in (qi, ki, w))
+    tiles = np.array(sparse_kernels.select_tiles(
+        qi, ki, w, topk=topk, tile=tile, interpret=True) != 0)
+    if crafted:     # what an untrained indexer never gives
+        tiles[2, 0] = tiles[3, 0] = False
+        tiles[3, 1] = True
+    q = jnp.asarray(rng.normal(size=(2, 2, t, 16)) * 0.25, jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(2, t, 16)), jnp.bfloat16)
+    tiles = jnp.asarray(tiles)
+    _, lse = sparse._dense_attention(q, k, jnp.zeros_like(k),
+                                     sparse._untiled(tiles))
+    return qi, ki, w, tiles, q, k, lse
+
+
+def _both_forms(args):
+    """``{loss, d_qi, d_ki, d_w: (the kernel's, the jax.numpy form's)}``,
+    traced anew (whatever ``sparse.target_of`` is now)."""
+    loss, grads = jax.jit(lambda *a: sparse_kernels.index_loss_tiles.__wrapped__(
+        *a, interpret=True))(*args)
+    want_loss, want = jax.jit(lambda *a: sparse.index_loss_vjp(*a))(*args)
+    return dict(zip(LOSS_LIMITS, zip((loss,) + grads, (want_loss,) + want)))
+
+
+@functools.lru_cache(maxsize=None)
+def _divergence(case):
+    return _both_forms(_divergence_inputs(*LOSS_CASES[case]))
+
+
+def test_the_divergence_s_cases_hold_what_their_names_say():
+    for case, (t, tile, topk, _, crafted) in LOSS_CASES.items():
+        tiles = np.asarray(_divergence_inputs(*LOSS_CASES[case])[3])
+        chosen = sparse._untiled(jnp.asarray(tiles)).sum(1)
+        assert (chosen[:topk] == np.arange(1, min(topk, t) + 1)).all()
+        assert (chosen >= 1).all()
+        full, empty = tiles.all((2, 3)), ~tiles.any((2, 3))
+        under = np.tril(np.ones(full.shape, bool), -1)
+        if case == "every_query_below_topk":
+            assert chosen.max() < topk and full[under].all()
+        if crafted:
+            assert empty[under].sum() == 2 and full[under].sum() == 1
+        if case == "two_query_blocks_a_tile":
+            assert tile // min(sparse_kernels.LOSS_ROWS, tile) == 2
+
+
+@pytest.mark.parametrize("what", list(LOSS_LIMITS))
+@pytest.mark.parametrize("case", list(LOSS_CASES))
+def test_the_divergence_s_kernel_is_its_jax_numpy_form(case, what):
+    got, want = _divergence(case)[what]
+    assert got.shape == want.shape and got.dtype == want.dtype == jnp.float32
+    assert np.isfinite(np.asarray(got)).all()
+    error = abs(float(got) - float(want)) / float(want) if what == "loss" \
+        else _relative(got, want)
+    assert error < LOSS_LIMITS[what], (case, what, error)
+
+
+def test_the_divergence_s_kernel_follows_target_of(monkeypatch):
+    """benchmark/tools/lm_sparse_controls.py ``target_not_rescaled``
+    replaces ``sparse.target_of``: the kernel calls it from its body, for
+    the target and for the row's sum of it."""
+    args = _divergence_inputs(*LOSS_CASES["most_queries_select"])
+    plain = _both_forms(args)
+    monkeypatch.setattr(sparse, "target_of",
+                        lambda probabilities, heads: probabilities)
+    summed = _both_forms(args)
+    for what, limit in LOSS_LIMITS.items():
+        got, want = summed[what]
+        assert _relative(got, want) < 10 * limit, what
+    # the heads' probabilities sum to 4 over a row's keys, not to 1
+    assert float(summed["loss"][0]) > 4 * float(plain["loss"][0])
+    assert _relative(summed["d_w"][0], plain["d_w"][0]) > 1.0
+
+
+def test_the_kernel_is_taken_by_what_the_code_sees(monkeypatch):
+    """On a TPU at a tile of whole lanes ``index_loss_vjp`` is the kernel,
+    under no flag; at any other tile the ``jax.numpy`` form."""
+    args = _divergence_inputs(*LOSS_CASES["most_queries_select"])
+    want = sparse.index_loss_vjp(*args)
+    calls, interpreted = [], sparse_kernels.index_loss_tiles
+
+    def kernel(*a):
+        calls.append(a)
+        return interpreted(*a, interpret=True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sparse_kernels, "index_loss_tiles", kernel)
+    loss, _ = sparse.index_loss_vjp(*args)
+    assert len(calls) == 1
+    assert abs(float(loss) - float(want[0])) < 1e-5 * float(want[0])
+    sparse.index_loss_vjp(*_divergence_inputs(96, 32, 16))
+    assert len(calls) == 1
+
+
+def test_the_divergence_s_kernel_compiles_for_the_chip_at_the_published_widths():
+    shape = _shapes_on_a_described_v5e()
+    t, bf16 = 16384, jnp.bfloat16
+    compiled = jax.jit(lambda *a: sparse_kernels.index_loss_tiles(*a)).lower(
+        shape(t, 16, 64), shape(t, 64), shape(t, 16),
+        shape(32, 32, 512, 512, dtype=jnp.bool_),
+        shape(4, 8, t, 128, dtype=bf16), shape(4, t, 128, dtype=bf16),
+        shape(4, 8, t)).compile()
+    # L_I, d qI, d kI, d w: float32, and no [T, T] float array beside them
+    assert compiled.memory_analysis().output_size_in_bytes < 4 * t * (
+        16 * 64 + 64 + 16) + 4096
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * t * t // 2
 
 
 # -- the sectioned rotary ------------------------------------------------------
