@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...runtime import device_lock
+from ...runtime import device_lock, thread_roles
 from ...sharding import mesh as meshlib
 from ...updater.rules import fast_rows, scatter_add
 from ...util.dashboard import count, monitor
@@ -648,6 +648,7 @@ class DeviceCorpusTrainer:
         end. ``examples`` counts (center, context) pairs in skip-gram
         mode and trained centers in CBOW mode (one prediction per
         center)."""
+        thread_roles.ensure_heartbeat()    # no actor here to start it
         model, C, G = self.model, self._C, self._G
         with monitor("TRAINER_EPOCH_PREP"):
             key = jax.random.PRNGKey(seed)

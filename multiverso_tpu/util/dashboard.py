@@ -14,7 +14,9 @@ is built.
 
 from __future__ import annotations
 
+import collections
 import math
+import sys
 import threading
 import time
 from typing import Dict, List
@@ -270,6 +272,17 @@ METRIC_NAMES: Dict[str, str] = {
                           "EVENTLOOP thread sat blocked past "
                           "-role_block_budget_ms (per role, "
                           "-debug_locks watchdog)",
+    # -- the heartbeat, the process's one always-on sampler
+    #    (runtime/thread_roles.py; docs/OBSERVABILITY.md "Stalls") --
+    "HOST_BEAT_LATE": "one entry a beat of the heartbeat thread: how "
+                      "many ms after its 10 ms sleep was due it ran "
+                      "again (the wait for the GIL or a core)",
+    "HOST_STALL": "one entry a stall record (overlapping openings are "
+                  "one record), ms what its openings added as each was "
+                  "seen: a late beat's lateness, a working entry's length",
+    "HOST_STALL_FROZEN": "the same for the late beats over which the "
+                         "process used next to no CPU: the machine's part "
+                         "(held and blocked are read from the records)",
     # -- online serving tier (serving/; docs/SERVING.md) --
     "SERVING_REQUESTS": "serving-frontend requests admitted and served",
     "SERVING_SHED": "serving-frontend requests rejected by admission",
@@ -288,6 +301,60 @@ METRIC_NAMES: Dict[str, str] = {
 #: implementation, used by the lint that enforces this registry.
 METRICS_SNAPSHOT_VERSION = 1
 
+#: A monitor entry longer than this (ms) is handed to the heartbeat
+#: (runtime/thread_roles.py), which decides on its own thread whether it
+#: opens a stall record or is context to one. The hot path pays one
+#: comparison; the stalls on record are 112 ms and more, and the
+#: heartbeat's floor for a late beat is the same 40.
+LONG_ENTRY_MS = 40.0
+
+#: ``(name, thread ident, end on time.monotonic(), ms, the monitor's
+#: count and elapsed ms with this entry in)`` of each such entry, oldest
+#: first; the heartbeat drains it. Bounded, so a process that never
+#: starts a thread keeps at most this many.
+long_entries: collections.deque = collections.deque(maxlen=1024)
+
+#: Monitors that only wait: an entry of theirs never opens a stall
+#: record by itself (a TABLE_WAIT of 100 ms behind a backward program is
+#: ordinary), it is context to one. A ``*`` stands for a family's
+#: suffix, as in METRIC_NAMES.
+WAITING = ("TABLE_WAIT", "TABLE_WAKE", "MAILBOX_WAIT[*]", "BLOB_D2H_READY",
+           "TRAINER_BLOCK_PACE", "PS_GET_STALL", "MA_COMM_STALL")
+
+
+def only_waits(name: str) -> bool:
+    for waiting in WAITING:
+        head, star, tail = waiting.partition("*")
+        if name == waiting or (star and name.startswith(head)
+                               and name.endswith(tail)):
+            return True
+    return False
+
+
+#: The last stall records the heartbeat closed, oldest first, and how
+#: many it has closed in all.
+_stalls: collections.deque = collections.deque(maxlen=64)
+_stalls_closed = 0
+
+
+def stalls() -> List[dict]:
+    """The last 64 stall records (docs/OBSERVABILITY.md "Stalls"): this
+    process's own, read here or in ``Dashboard.display()``."""
+    return list(_stalls)
+
+
+def keep_stall(record: dict) -> None:
+    global _stalls_closed
+    _stalls.append(record)
+    _stalls_closed += 1
+
+
+def reset_stalls() -> None:
+    global _stalls_closed
+    _stalls.clear()
+    _stalls_closed = 0
+    long_entries.clear()
+
 
 class Monitor:
     def __init__(self, name: str):
@@ -304,16 +371,19 @@ class Monitor:
         begin = getattr(self._local, "begin", None)
         if begin is None:
             return
-        elapsed = (time.perf_counter() - begin) * 1e3
-        with self._lock:
-            self._count += 1
-            self._elapsed_ms += elapsed
+        self.add((time.perf_counter() - begin) * 1e3)
         self._local.begin = None
 
-    def add(self, elapsed_ms: float) -> None:
+    def add(self, elapsed_ms: float, entries: int = 1) -> None:
+        """One entry of ``elapsed_ms``; ``entries=0`` adds time to an
+        entry already counted (a stall record's later openings)."""
         with self._lock:
-            self._count += 1
+            self._count += entries
             self._elapsed_ms += elapsed_ms
+        if elapsed_ms > LONG_ENTRY_MS:
+            long_entries.append((self.name, threading.get_ident(),
+                                 time.monotonic(), elapsed_ms,
+                                 self._count, self._elapsed_ms))
 
     def add_count(self, n: int) -> None:
         """Bulk count bump with no elapsed time (row-granular event
@@ -391,6 +461,11 @@ class Dashboard:
                     f"p90 = {snap.get('p90', 0.0):.3f} "
                     f"p99 = {snap.get('p99', 0.0):.3f} "
                     f"max = {snap.get('max', 0.0):.3f}")
+        for record in stalls():
+            lines.append(
+                f"[stall] {record['class']} {record['ms']:.1f}ms "
+                f"began_wall_ns = {record['began_wall_ns']} "
+                f"by = {record.get('by')} cpu = {record['cpu_ms']:.1f}ms")
         return "\n".join(lines)
 
     @classmethod
@@ -411,6 +486,18 @@ def _bind_annotation():
     from jax.profiler import TraceAnnotation
     _trace_annotation = TraceAnnotation
     return TraceAnnotation
+
+
+def mark(name: str, **args) -> None:
+    """An instant ``mv:<name>`` annotation with ``args`` as its stats
+    under an open profiler session; nothing without one, and jax is not
+    imported for it (a process that never touched jax has no session)."""
+    if "jax" not in sys.modules:
+        return
+    annotation = _trace_annotation or _bind_annotation()
+    if annotation.is_enabled():
+        with annotation(SPAN_PREFIX + name, **args):
+            pass
 
 
 class monitor:
@@ -585,6 +672,12 @@ def metrics_snapshot(max_samples: int = 256) -> dict:
         reservoirs = list(_samples.items())
     return {
         "v": METRICS_SNAPSHOT_VERSION,
+        # the records stay in their process (1 to 2 KB each, and this
+        # dict goes to the controller whole at every report): how many
+        # it has closed and when the last began say where to look
+        "stalls": {"count": _stalls_closed,
+                   "last_began_wall_ns": _stalls[-1]["began_wall_ns"]
+                   if _stalls else None},
         "monitors": {name: {"count": m.count,
                             "elapsed_ms": round(m.elapse, 3)}
                      for name, m in monitors},

@@ -2,7 +2,8 @@
 
 TPU-native equivalent of the reference logger
 (ref: include/multiverso/util/log.h:22-142, src/util/log.cpp). Levels
-Debug/Info/Error/Fatal, ``[LEVEL] [TIME]`` prefix, optional file tee, and
+Debug/Info/Warning/Error/Fatal (Warning is this port's: the reference has
+four), ``[LEVEL] [TIME]`` prefix, optional file tee, and
 ``CHECK`` / ``CHECK_NOTNULL`` that raise (the reference's Fatal optionally
 kills the process; here it raises ``FatalError`` so tests can assert on it,
 with ``set_kill_fatal(True)`` restoring abort semantics).
@@ -21,8 +22,9 @@ from typing import Optional
 class LogLevel(enum.IntEnum):
     Debug = 0
     Info = 1
-    Error = 2
-    Fatal = 3
+    Warning = 2
+    Error = 3
+    Fatal = 4
 
 
 class FatalError(RuntimeError):
@@ -63,7 +65,7 @@ class Logger:
         if not line.endswith("\n"):
             line += "\n"
         with self._lock:
-            stream = sys.stderr if level >= LogLevel.Error else sys.stdout
+            stream = sys.stderr if level >= LogLevel.Warning else sys.stdout
             stream.write(line)
             stream.flush()
             if self._file is not None:
@@ -80,6 +82,9 @@ class Logger:
     def info(self, fmt: str, *args) -> None:
         self.write(LogLevel.Info, fmt, *args)
 
+    def warning(self, fmt: str, *args) -> None:
+        self.write(LogLevel.Warning, fmt, *args)
+
     def error(self, fmt: str, *args) -> None:
         self.write(LogLevel.Error, fmt, *args)
 
@@ -87,13 +92,18 @@ class Logger:
         self.write(LogLevel.Fatal, fmt, *args)
 
 
+#: ``MV_LOG_LEVEL`` by number keeps the reference's four levels where
+#: they were before Warning came between them; Warning is asked for by
+#: name.
+_BY_NUMBER = {"0": LogLevel.Debug, "1": LogLevel.Info,
+              "2": LogLevel.Error, "3": LogLevel.Fatal}
+
+
 def _env_level() -> LogLevel:
-    raw = os.environ.get("MV_LOG_LEVEL", "")
-    try:
-        return LogLevel(int(raw))
-    except (ValueError, KeyError):
-        by_name = {l.name.lower(): l for l in LogLevel}
-        return by_name.get(raw.strip().lower(), LogLevel.Info)
+    raw = os.environ.get("MV_LOG_LEVEL", "").strip().lower()
+    levels = {level.name.lower(): level for level in LogLevel}
+    levels.update(_BY_NUMBER)
+    return levels.get(raw, LogLevel.Info)
 
 
 _logger = Logger(_env_level())
@@ -109,6 +119,10 @@ def debug(fmt: str, *args) -> None:
 
 def info(fmt: str, *args) -> None:
     _logger.info(fmt, *args)
+
+
+def warning(fmt: str, *args) -> None:
+    _logger.warning(fmt, *args)
 
 
 def error(fmt: str, *args) -> None:

@@ -13,9 +13,13 @@ TABLE_GATHER_DISPATCH inside the server's handlers; 8 before PR 43, when
 the loop entered TRAINER_BLOCK_UPLOAD/IDS/STEP/LOSS), stamps and closes
 12 MAILBOX_WAIT (8 messages through the worker's mailbox, 4 through the
 server's) and adds 2 TABLE_WAKE (a stamp and a Monitor.add, as a
-mailbox's). Run from a checkout of an older commit it times the sites
-that tree has: before PR 37 10 with an id, 2 without, 12 MAILBOX_WAIT;
-before PR 24 the 8 handler monitors, without arguments.
+mailbox's). Since PR 52 every entry's way into its Monitor compares its
+length with the floor over which the heartbeat is told of it
+(`dashboard.LONG_ENTRY_MS`): `monitor_add` is a `Monitor.add` under the
+floor, `monitor_add_long` one over it (a tuple appended to a deque, which
+only an entry of 40 ms pays). Run from a checkout of an older commit it
+times the sites that tree has: before PR 37 10 with an id, 2 without, 12
+MAILBOX_WAIT; before PR 24 the 8 handler monitors, without arguments.
 """
 
 import os
@@ -27,6 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from multiverso_tpu.core.message import Message  # noqa: E402
 from multiverso_tpu.runtime.actor import Actor  # noqa: E402
+from multiverso_tpu.util import dashboard  # noqa: E402
 from multiverso_tpu.util.dashboard import monitor  # noqa: E402
 
 N = 200_000
@@ -52,10 +57,7 @@ def _has_caller_spans() -> bool:
 def _plain_monitors() -> int:
     """Monitors without arguments a block enters, in the tree this runs
     from."""
-    from multiverso_tpu.util.dashboard import METRIC_NAMES
-    if not _has_caller_spans():
-        return 2
-    return 8 if "TRAINER_BLOCK_UPLOAD" in METRIC_NAMES else 7
+    return 7 if _has_caller_spans() else 2
 
 
 def plain():
@@ -65,6 +67,12 @@ def plain():
 
 def main() -> None:
     costs = {"monitor": us(plain)}
+    counted = dashboard.Dashboard.get("TABLE_WAKE")
+    floor = getattr(dashboard, "LONG_ENTRY_MS", None)
+    costs["monitor_add"] = us(lambda: counted.add(0.02))
+    if floor is not None:
+        costs["monitor_add_long"] = us(lambda: counted.add(floor + 1.0))
+        dashboard.long_entries.clear()
     block = 8 * costs["monitor"]
     if hasattr(Actor, "_popped"):
         msg = Message(msg_id=7, table_id=1)
