@@ -942,6 +942,25 @@ def yarn_frequencies(theta: float, lanes: int, factor: float, fast: float,
     return own / factor * ramp + own * (1 - ramp)
 
 
+def rotary_tables(t, d, theta, pos=None, inv=None, lanes=None, factor=1.0,
+                  sections=()):
+    """``(cos, sin)`` float64 [t, lanes / 2], ``factor`` inside, of the
+    rotary pairs of a head of ``d`` lanes at ``t`` rows: ``_rotary``'s
+    arguments say how."""
+    lanes = d if lanes is None else lanes
+    if inv is None:
+        inv = 1.0 / theta ** (np.arange(0, lanes, 2, dtype=np.float64)
+                              / lanes)
+    pos = np.arange(t) if pos is None else np.asarray(pos)
+    if sections:
+        assert sum(sections) == lanes // 2, (sections, lanes)
+        pos = pos[np.repeat(np.arange(len(sections)), sections)].T
+    else:
+        pos = pos[:, None]
+    angle = pos.astype(np.float64) * inv[None, :]
+    return factor * np.cos(angle), factor * np.sin(angle)
+
+
 def _rotary(x, theta, pos=None, inv=None, lanes=None, factor=1.0,
             sections=()):
     """Rotary positions on [T, heads, d] (the halves paired, as the
@@ -954,18 +973,8 @@ def _rotary(x, theta, pos=None, inv=None, lanes=None, factor=1.0,
     and a pair takes the row of its section."""
     t, _, d = x.shape
     lanes = d if lanes is None else lanes
-    if inv is None:
-        inv = 1.0 / theta ** (np.arange(0, lanes, 2, dtype=np.float64)
-                              / lanes)
-    pos = np.arange(t) if pos is None else np.asarray(pos)
-    if sections:
-        assert sum(sections) == lanes // 2, (sections, lanes)
-        pos = pos[np.repeat(np.arange(len(sections)), sections)].T
-    else:
-        pos = pos[:, None]
-    angle = pos.astype(np.float64) * inv[None, :]
-    cos = jnp.asarray(factor * np.cos(angle), F32)[:, None, :]
-    sin = jnp.asarray(factor * np.sin(angle), F32)[:, None, :]
+    cos, sin = (jnp.asarray(table, F32)[:, None, :] for table in
+                rotary_tables(t, d, theta, pos, inv, lanes, factor, sections))
     x1, x2 = x[..., :lanes // 2], x[..., lanes // 2:lanes]
     turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
     return jnp.concatenate(turned + ([x[..., lanes:]] if lanes < d else []),
@@ -1069,6 +1078,39 @@ def attention_core(q, k, v, mask):
     return blockwise_attention(q, k, v, mask)
 
 
+_ROTARY = _rotary
+
+
+def attention_pass_fused(cfg: LMConfig, t: int, rope=True) -> bool:
+    """Whether ``attention_inputs`` takes the one pass of attn_kernels.py
+    for a sequence of ``t`` positions through a layer that is turned or not
+    (``rope``): where its kernels fit (a TPU, whole blocks of tokens, heads
+    of whole lane tiles) and there is a norm or a turn for them to do. With
+    neither, the scale, the rounding and the layout go into the products'
+    own output fusions, and a pass over their float32 results only adds to
+    that (PERF.md section 6, PR 53: 2.66 ms a sequence of 8192 for the
+    chain's 2.54).
+    Everywhere else, and where a caller has put its own ``_rotary`` in this
+    module's place (the checks' controls do), it is the ``jax.numpy`` chain
+    below, which is the definition the kernels are held to.
+    ``PSLMTrainer._count_stats`` counts ``LM_ATTN_PASS_FUSED`` /
+    ``LM_ATTN_PASS_PLAIN`` by it."""
+    from . import attn_kernels
+    return (attn_kernels.fits(t, cfg.head_dim) and bool(rope or cfg.qk_norm)
+            and _rotary is _ROTARY)
+
+
+def attention_pass_name(cfg: LMConfig, t: int, rope=True):
+    """The counter that a sequence of ``t`` positions through one layer
+    (turned or not: ``rope``) adds one to: which form its
+    ``attention_inputs`` took (None under latent attention, which is
+    latent.py's and has neither)."""
+    if cfg.attention == "mla":
+        return None
+    return "LM_ATTN_PASS_FUSED" if attention_pass_fused(cfg, t, rope) \
+        else "LM_ATTN_PASS_PLAIN"
+
+
 def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     """Norm, the three projections, the heads' q and k norms
     (``cfg.qk_norm``), rotary positions (``rope``: a ``Rotary``, or
@@ -1077,26 +1119,37 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     and with ``cfg.attn_gate`` the normed input fourth (the gate reads
     it). The layer's query heads are its ``wq``'s. ``norms`` is the
     attention norm's scale, or with ``cfg.qk_norm`` the three
-    ``(norm_attn, norm_q, norm_k)``."""
-    t = x.shape[0]
-    heads = mats["wq"].shape[1] // cfg.head_dim
+    ``(norm_attn, norm_q, norm_k)``. What follows the products is one
+    pass over memory where ``attention_pass_fused``."""
+    t, d = x.shape[0], cfg.head_dim
+    heads = mats["wq"].shape[1] // d
     g, per = cfg.n_kv_heads, heads // cfg.n_kv_heads
     norm, *qk = norms if cfg.qk_norm else (norms,)
     h = rmsnorm(x, norm, cfg.eps)
-    q = mm(h, mats["wq"], sinks["wq"]).reshape(t, heads, cfg.head_dim)
-    k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, cfg.head_dim)
-    v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, cfg.head_dim)
+    at = () if pos is None else (pos,)
+    theta, how = (rope.theta, rope.how()) if isinstance(rope, Rotary) \
+        else (cfg.rope_theta, {})
+    if attention_pass_fused(cfg, t, rope):
+        from . import attn_kernels
+        tables = tuple(jnp.asarray(table, F32) for table in rotary_tables(
+            t, d, theta, *at, **how)) if rope else ()
+        qkv = attn_kernels.heads_in(
+            attn_kernels.Pass(per, d, how.get("lanes", d) if rope else 0,
+                              bool(qk), cfg.eps, 1.0 / math.sqrt(d), BF16),
+            *(mm(h, mats[n], sinks[n]) for n in ("wq", "wk", "wv")),
+            tuple(qk), tables)
+        return qkv + (h,) if cfg.attn_gate == "head" else qkv
+    q = mm(h, mats["wq"], sinks["wq"]).reshape(t, heads, d)
+    k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, d)
+    v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, d)
     if qk:      # over a head's lanes: each head normed alone
         q, k = rmsnorm(q, qk[0], cfg.eps), rmsnorm(k, qk[1], cfg.eps)
     if rope:    # positions go only where given: ``_rotary``'s short form
-        at = () if pos is None else (pos,)
-        theta, how = (rope.theta, rope.how()) if isinstance(rope, Rotary) \
-            else (cfg.rope_theta, {})
         q = _rotary(q, theta, *at, **how)
         k = _rotary(k, theta, *at, **how)
-    q = (q * (1.0 / math.sqrt(cfg.head_dim))).astype(BF16)
+    q = (q * (1.0 / math.sqrt(d))).astype(BF16)
     # query head i reads key-value head i // per
-    q = q.reshape(t, g, per, cfg.head_dim).transpose(1, 2, 0, 3)
+    q = q.reshape(t, g, per, d).transpose(1, 2, 0, 3)
     qkv = (q, k.astype(BF16).transpose(1, 0, 2),
            v.astype(BF16).transpose(1, 0, 2))
     return qkv + (h,) if cfg.attn_gate == "head" else qkv

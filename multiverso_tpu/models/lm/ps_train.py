@@ -74,6 +74,8 @@ same, or ``2 B T`` under block diffusion), ``LM_GET_BYTES`` and
 layers), ``LM_EXPERTS_SHORT`` and ``LM_EXPERTS_FULL`` (one a sparse layer
 a sequence: whether its routed experts took the short buffer or the one
 of every assignment, from the same count and ``model.experts_capacity``),
+``LM_ATTN_PASS_FUSED`` or ``LM_ATTN_PASS_PLAIN`` (one a layer a sequence:
+which form ``model.attention_inputs`` took, ``model.attention_pass_name``),
 ``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
 computed on the device and read at the start of the next step,
@@ -443,8 +445,12 @@ class PSLMTrainer:
         # last), and the rows of its experts' buffer (model.routed_experts)
         self._sparse = [cfg.sparse(i) for i in range(cfg.n_layers)] \
             + [1] * bool(self.module)
-        self._experts_cap = lm.experts_capacity(
-            cfg, self.T * (2 if self.diffusion else 1))
+        positions = self.T * (2 if self.diffusion else 1)
+        self._experts_cap = lm.experts_capacity(cfg, positions)
+        # and which form each layer's ``model.attention_inputs`` takes of a
+        # sequence: the counter's name (None: latent attention has neither)
+        self._attn_pass = [lm.attention_pass_name(cfg, positions, rope)
+                           for rope in cfg.rope_layout]
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
@@ -716,6 +722,9 @@ class PSLMTrainer:
                         ("LM_EXPERTS_FULL", (~fits).sum())):
             if n:
                 count(name, int(n))
+        for name, s in zip(self._attn_pass, per_layer):
+            if name:            # one a layer a sequence
+                count(name, len(s))
         outputs = self.cfg.n_experts
         fullest = sum(int(s[:, 2:2 + outputs].sum(axis=0).max())
                       for s in per_layer if s.shape[1] >= 2 + outputs)

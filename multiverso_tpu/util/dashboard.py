@@ -255,6 +255,12 @@ METRIC_NAMES: Dict[str, str] = {
                         "assignments fit model.experts_capacity)",
     "LM_EXPERTS_FULL": "sparse layers' sequences whose routed experts "
                        "took the buffer of every assignment",
+    "LM_ATTN_PASS_FUSED": "layers' sequences whose way from the attention's "
+                          "projections to its kernel and back was the one "
+                          "pass of models/lm/attn_kernels.py",
+    "LM_ATTN_PASS_PLAIN": "layers' sequences that took the jax.numpy "
+                          "chain there (no TPU, no whole block of tokens "
+                          "or tile of lanes, neither head norms nor a turn)",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
                      "trainer that holds the module)",

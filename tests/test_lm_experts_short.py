@@ -394,6 +394,7 @@ def test_the_trainer_counts_which_buffer_each_sequence_took(held, sparse,
     capacity, a dense layer's two zeros counted nowhere."""
     trainer = PSLMTrainer.__new__(PSLMTrainer)
     trainer.cfg, trainer._sparse, trainer._experts_cap = CFG, sparse, CAP
+    trainer._attn_pass = []
     stats = [np.stack([np.asarray(row), np.zeros(len(row), int)], axis=1)
              for row in held]
 

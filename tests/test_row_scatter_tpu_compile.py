@@ -337,3 +337,53 @@ def test_the_attention_kernel_compiles_at_both_of_laguna_s_kinds(
     assert "tpu_custom_call" in compiled.as_text()
     # no [heads, T, T] array: 64 x 8192 x 8192 x 4 B would be 17 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 800e6
+
+
+# -- the pass between the attention's projections and its kernel -----------------
+# (models/lm/attn_kernels.py; interpreted against the chain in
+# tests/test_lm_attn_pass.py)
+
+@pytest.mark.parametrize("tokens,groups,per_group,lanes,norm", [
+    (8192, 4, 7, 0, False),         # smallthinker, layer 0: no rotary
+    (8192, 4, 7, 128, False),       # smallthinker
+    (8192, 4, 8, 128, True),        # sdar: head norms, both copies
+    (8192, 8, 6, 64, False),        # laguna, full attention: half the lanes
+    (8192, 8, 8, 128, False),       # laguna, window
+    (16384, 4, 8, 128, True)])      # keye
+def test_the_attention_s_pass_compiles_at_the_published_head_counts(
+        topo, tokens, groups, per_group, lanes, norm):
+    """``heads_in`` and its pull as Mosaic takes them, one sequence: each
+    a kernel, nothing beside it but the float32 the pull's bfloat16 is
+    widened to for ``mm``'s rule (which rounds it back: the pair cancels
+    inside a layer program), and no float32 array of the heads' size."""
+    from multiverso_tpu.models.lm import attn_kernels
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    d = 128
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    how = attn_kernels.Pass(per_group, d, lanes, norm, 1e-6, d ** -0.5,
+                            jnp.bfloat16)
+    products = tuple(shaped(tokens, groups * n * d)
+                     for n in (per_group, 1, 1))
+    scales = (shaped(d), shaped(d)) if norm else ()
+    tables = (shaped(tokens, lanes // 2),) * 2 if lanes else ()
+    forward = jax.jit(lambda *a: attn_kernels.heads_in(how, *a)).lower(
+        *products, scales, tables).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    assert forward.memory_analysis().temp_size_in_bytes < 1e6
+    heads = tuple(shaped(*s, dtype=jnp.bfloat16) for s in (
+        (groups, per_group, tokens, d), (groups, tokens, d),
+        (groups, tokens, d)))
+
+    def pull(qf, kf, vf, scales, tables, cotangents):
+        return jax.vjp(lambda *a: attn_kernels.heads_in(how, *a, tables),
+                       qf, kf, vf, scales)[1](cotangents)
+
+    backward = jax.jit(pull).lower(*products, scales, tables,
+                                   heads).compile()
+    assert backward.as_text().count("tpu_custom_call") == 1
+    # the three cotangents in bfloat16, before they are widened
+    assert backward.memory_analysis().temp_size_in_bytes < 1.1 * 2 * (
+        tokens * groups * (per_group + 2) * d)
