@@ -47,37 +47,60 @@ MATRICES = lm.MLA_MATRICES
 NORMS = ("norm_attn", "norm_q_a", "norm_kv_a")
 
 
+def names(cfg: LMConfig):
+    """``(matrices, norms)`` of the attention by name: with a query latent
+    the five and the three, without one (``q_lora_rank`` 0) ``W_q`` alone
+    before the key-value pair and no norm of a query latent."""
+    if cfg.q_lora_rank:
+        return MATRICES, NORMS
+    return lm.MLA_DIRECT, (NORMS[0], NORMS[2])
+
+
 def softmax_scale(cfg: LMConfig) -> float:
     """``head_dim^-0.5 m^2``; the rotary's own ``mscale / mscale_all_dim``
-    is 1 when the two are equal, which ``inputs`` checks."""
+    is 1 when the two are equal, which ``inputs`` checks. Without YaRN
+    ``head_dim^-0.5``."""
+    if not cfg.yarn:
+        return 1.0 / math.sqrt(cfg.head_dim)
     factor, all_dim = cfg.yarn[0], cfg.yarn[5]
     m = 0.1 * all_dim * math.log(factor) + 1.0 if factor > 1 else 1.0
     return m * m / math.sqrt(cfg.head_dim)
 
 
-def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None):
-    """The sublayer's norm, both low-rank projections with their norms,
-    rotary positions, the scale: ``(q [heads, 1, T, nope + rope], k
+def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None, rope=True):
+    """The sublayer's norm, both low-rank projections with their norms
+    (the query's one product ``h W_q`` where there is no query latent),
+    rotary positions (``rope``: a layer without them leaves ``q_r`` and
+    ``k_r`` as they are), the scale: ``(q [heads, 1, T, nope + rope], k
     [heads, T, nope + rope], v [heads, T, v])`` bfloat16 for
-    ``model.attention_core``, each head a group. ``norms`` is
-    ``(norm_attn, norm_q_a, norm_kv_a)``."""
-    assert cfg.yarn[4] == cfg.yarn[5], "mscale != mscale_all_dim"
+    ``model.attention_core``, each head a group. ``norms`` is ``names``'."""
+    assert not cfg.yarn or cfg.yarn[4] == cfg.yarn[5], \
+        "mscale != mscale_all_dim"
     t, heads = u.shape[0], cfg.n_heads_held
-    nope, rope, latent = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
-    g_attn, g_q, g_kv = norms
+    nope, rope_dim, latent = cfg.qk_nope_dim, cfg.qk_rope_dim, \
+        cfg.kv_lora_rank
+    g_attn, *g_q, g_kv = norms
     h = lm.rmsnorm(u, g_attn, cfg.eps)
-    c_q = lm.rmsnorm(lm.mm(h, mats["wq_a"], sinks["wq_a"]), g_q, cfg.eps)
-    q = lm.mm(c_q, mats["wq_b"], sinks["wq_b"]).reshape(t, heads, nope + rope)
+    if cfg.q_lora_rank:
+        c_q = lm.rmsnorm(lm.mm(h, mats["wq_a"], sinks["wq_a"]), g_q[0],
+                         cfg.eps)
+        q = lm.mm(c_q, mats["wq_b"], sinks["wq_b"])
+    else:
+        q = lm.mm(h, mats["wq"], sinks["wq"])
+    q = q.reshape(t, heads, nope + rope_dim)
     kv_a = lm.mm(h, mats["wkv_a"], sinks["wkv_a"])
     c_kv = lm.rmsnorm(kv_a[:, :latent], g_kv, cfg.eps)
     kv = lm.mm(c_kv, mats["wkv_b"], sinks["wkv_b"]).reshape(
         t, heads, nope + cfg.v_head_dim)
-    inv = lm.yarn_frequencies(cfg.rope_theta, rope, *cfg.yarn[:4])
-    q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
-    k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
+    if rope:
+        inv = lm.yarn_frequencies(cfg.rope_theta, rope_dim, *cfg.yarn[:4])
+        q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
+        k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
+    else:
+        q_r, k_r = q[..., nope:], kv_a[:, None, latent:]
     q = jnp.concatenate([q[..., :nope], q_r], -1) * softmax_scale(cfg)
     k = jnp.concatenate(
-        [kv[..., :nope], jnp.broadcast_to(k_r, (t, heads, rope))], -1)
+        [kv[..., :nope], jnp.broadcast_to(k_r, (t, heads, rope_dim))], -1)
     return (q.astype(lm.BF16).transpose(1, 0, 2)[:, None],
             k.astype(lm.BF16).transpose(1, 0, 2),
             kv[..., nope:].astype(lm.BF16).transpose(1, 0, 2))
@@ -96,16 +119,17 @@ def output(cfg: LMConfig, mats, sinks, o):
     return lm.mm(o, mats["wo"], sinks["wo"])
 
 
-def attention_vjp(cfg: LMConfig, mats, sinks, small, u, pos=None):
+def attention_vjp(cfg: LMConfig, mats, sinks, small, u, pos=None, rope=True):
     """``F(u)`` and what pulls a cotangent back through it: ``(v, pull)``,
     ``pull(dv) -> (du, matrix gradients, small gradients)``. The three
     parts are differentiated one by one so that each part's backward pass
     runs under the scope of its forward pass."""
-    first = {n: sinks[n] for n in MATRICES[:-1]}
+    matrices, norms_named = names(cfg)
+    first = {n: sinks[n] for n in matrices[:-1]}
     with jax.named_scope(SCOPE):
         (q, k, v), pull_inputs = jax.vjp(
-            lambda s, norms, u: inputs(cfg, mats, s, norms, u, pos),
-            first, tuple(small[n] for n in NORMS), u)
+            lambda s, norms, u: inputs(cfg, mats, s, norms, u, pos, rope),
+            first, tuple(small[n] for n in norms_named), u)
     with jax.named_scope(SCOPE + ".kernel"):
         o, pull_core = jax.vjp(core, q, k, v)
     with jax.named_scope(SCOPE):
@@ -119,6 +143,6 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, u, pos=None):
             d_qkv = pull_core(do)
         with jax.named_scope(SCOPE):
             d_mats, d_norms, du = pull_inputs(d_qkv)
-        return du, {**d_mats, "wo": d_wo}, dict(zip(NORMS, d_norms))
+        return du, {**d_mats, "wo": d_wo}, dict(zip(norms_named, d_norms))
 
     return out, pull

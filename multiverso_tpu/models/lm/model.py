@@ -99,11 +99,40 @@ rotary positions in sections (``Rotary.sections``; ``pos`` [3, T]); with
   a fourth result. The selection is a device array computed in the step:
   no ``Mask`` kind, and the layer's ``Mask`` stays causal.
 
+**A sixth family** (``from_dict`` tells it by ``linear_attn_config``: the
+block of ``model_type: kimi_linear``, Moonshot's Kimi Linear, 2025) makes
+the attention's kind a LAYER's (``attention_layout``: ``kda`` | ``mla`` a
+layer, three to one), on the plain residual with the third family's
+feed-forward (``sigmoid_bias``, the shared expert), and uses no position
+anywhere. With ``h = RMSNorm(x)``, ``conv`` a causal depthwise convolution
+over positions (4 weights a channel, zero before the sequence):
+
+    kda (delta.py), heads i of 32, K = V = 128:
+      q~, k~, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))
+      q_i = q~_i / |q~_i|_2 * K^-1/2,   k_i = k~_i / |k~_i|_2
+      g    = -exp(A_log_i) softplus((h W_fa) W_fb + dt_bias)  log decay A CHANNEL
+      beta = sigmoid(h W_b)                                   a head
+      S_i[t] = (I - beta k k^T) Diag(exp g) S_i[t-1] + beta k v^T,  S_i[-1] = 0
+      o_i[t] = S_i[t]^T q_i[t]           the FIRST state a position hands the
+                                          next: a scan over positions, in
+                                          chunks (``delta.scan``)
+      a = x + concat_i(RMSNorm(o_i; g_o) * sigmoid(((h W_ga) W_gb)_i)) W_o
+    mla (latent.py): q = h W_q (``q_lora_rank`` 0: no query latent, no
+      norm of one), [c_kv | k_r] = h W_kva, [k_n | v] = RMSNorm(c_kv) W_kvb,
+      NO rotary turn (``rope_layout`` 0), score (q_n.k_n + q_r.k_r) 192^-0.5
+
+  ``A_log``, ``dt_bias``, the convolutions' weights [channels, 4] and ``g_o``
+  are neither matrices nor norms: float32 tables under Adam, drawn at their
+  own start (ps_train.py ``decay_init``).
+
 **A layer is described by three independent kinds**, and each selects
-functions, not a family's branch: its ATTENTION (``attention``: ``gqa`` ->
-``attention_inputs`` / ``attention_core`` / ``attention_gate`` /
-``attention_output`` under ``attention_vjp``, with ``heads_layout``,
-``rotary_kinds``, ``attn_gate``, ``qk_norm``; ``mla`` -> latent.py), its
+functions, not a family's branch: its ATTENTION (the model's ``attention``,
+or where a model has more than one the LAYER's, ``attention_layout`` /
+``attention_of``: ``gqa`` -> ``attention_inputs`` / ``attention_core`` /
+``attention_gate`` / ``attention_output`` under ``attention_vjp``, with
+``heads_layout``, ``rotary_kinds``, ``attn_gate``, ``qk_norm``; ``mla`` ->
+latent.py, through the streams or, on the plain residual, through
+``attention_vjp`` too; ``kda`` -> delta.py), its
 FEED-FORWARD (``ffn_layout``, ``dense_width``, ``shared_width``,
 ``scoring``, ``routed_scale`` -> ``feed_forward_vjp``: ``dense_vjp`` |
 ``sparse_vjp`` on ONE normed input, for every layer of ``router_input:
@@ -118,7 +147,7 @@ so that their programs lower to the text they had
 
 ``route``, ``routed_experts`` (what ``experts_block`` is around),
 ``gated_mlp`` (dense MLP and shared expert), ``yarn_frequencies`` and the
-head are one code path for all four.
+head are one code path for all six.
 
 **Two objectives** (``LMConfig.objective``). ``next_token``: causal or
 window masks, the loss the mean cross entropy of the next token.
@@ -182,7 +211,9 @@ third family's: ``mv.lm.attn.mla`` (+ ``.kernel``), ``mv.lm.hc``,
 sequences ``mv.lm.grad_sum``. The fourth's gate, its product, sigmoid and
 multiply, forward and backward: ``mv.lm.attn.gate``. The fifth's:
 ``mv.lm.indexer``, ``mv.lm.select``, ``mv.lm.attn.sparse`` (+
-``.kernel``), ``mv.lm.indexer.loss`` (sparse.py).
+``.kernel``), ``mv.lm.indexer.loss`` (sparse.py). The sixth's delta
+layers': ``mv.lm.attn.kda``, ``mv.lm.attn.kda.conv``,
+``mv.lm.attn.kda.scan`` (delta.py); its latent layer's the third's.
 """
 
 from __future__ import annotations
@@ -207,6 +238,7 @@ F32 = jnp.float32
 GQA_MATRICES = ("wq", "wk", "wv", "wo")
 ATTN_GATE = "w_attn_gate"           # with ``LMConfig.attn_gate == "head"``
 MLA_MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
+MLA_DIRECT = ("wq", "wkv_a", "wkv_b", "wo")     # ``q_lora_rank`` 0: no latent
 DENSE = ("w_gate", "w_up", "w_down")    # a dense MLP's, or the routed
 #                                     experts' stacked by expert
 SHARED = ("ws_gate", "ws_up", "ws_down")
@@ -318,6 +350,14 @@ class LMConfig:
     index_dim: int = 0
     index_topk: int = 0
     index_tile: int = 0             # the selection's tiles, a side
+    # -- the attention's kind as a LAYER's, where a model has more than one --------
+    attention_layout: Tuple[str, ...] = ()  # per layer: "mla" | "kda" (the
+    #                                 delta rule's scan, delta.py); ():
+    #                                 ``attention`` in every layer
+    kda_heads: int = 0              # kda: heads, each a state [d x d]
+    kda_head_dim: int = 0
+    kda_conv: int = 0               # kda: the short convolution's weights a
+    #                                 channel
 
     @property
     def n_layers(self) -> int:
@@ -340,6 +380,11 @@ class LMConfig:
         return self.heads_layout[layer] if self.heads_layout \
             else self.n_heads
 
+    def attention_of(self, layer: int) -> str:
+        """The kind of the layer's attention: ``gqa`` | ``mla`` | ``kda``."""
+        return self.attention_layout[layer] if self.attention_layout \
+            else self.attention
+
     def rotary(self, rope, window):
         """What a layer of this kind hands ``attention_inputs`` as its
         rotary positions: its ``Rotary``, or where the model has one kind
@@ -357,19 +402,27 @@ class LMConfig:
     def layer_kinds(self) -> Tuple[tuple, ...]:
         """Per layer what its two programs are built from: ``(rotary,
         window)``; with an ``ffn_layout`` ``(rotary, window, sparse)``; and
-        with a ``heads_layout`` the layer's query heads fourth."""
+        with a ``heads_layout`` the layer's query heads fourth, with an
+        ``attention_layout`` the kind of the layer's attention."""
         kinds = tuple(zip(self.rope_layout, self.window_layout))
         if self.ffn_layout or self.heads_layout:
             kinds = tuple(k + (self.sparse(i),) for i, k in enumerate(kinds))
         if self.heads_layout:
             kinds = tuple(k + (h,) for k, h in zip(kinds, self.heads_layout))
+        if self.attention_layout:
+            kinds = tuple(k + (a,) for k, a in zip(kinds,
+                                                   self.attention_layout))
         return kinds
 
     def matrices(self, layer: int = 0) -> Tuple[str, ...]:
         """The layer's tensors pulled as bfloat16 copies, by name: its
         attention's, then its feed-forward's."""
-        if self.attention == "mla":
-            attention = MLA_MATRICES
+        kind = self.attention_of(layer)
+        if kind == "kda":
+            from . import delta
+            attention = delta.MATRICES
+        elif kind == "mla":
+            attention = MLA_MATRICES if self.q_lora_rank else MLA_DIRECT
         else:
             attention = GQA_MATRICES + (
                 (ATTN_GATE,) if self.attn_gate == "head" else ())
@@ -398,6 +451,8 @@ class LMConfig:
         ``num_experts``, Qwen3-MoE's (``_from_qwen3_moe``). In both the
         key that counts the experts is the number HELD and
         ``router_outputs`` the published number the router still has."""
+        if "linear_attn_config" in c:
+            return cls._from_kda(c)
         if "kv_lora_rank" in c:
             return cls._from_mla(c)
         if "num_attention_heads_per_layer" in c:
@@ -612,6 +667,68 @@ class LMConfig:
             index_dim=int(sa["indexer_head_dim"]),
             index_topk=int(sa["topk"]), index_tile=int(sa["q_chunk_size"]))
 
+    @classmethod
+    def _from_kda(cls, c: dict) -> "LMConfig":
+        """The block of ``model_type: kimi_linear`` (Moonshot's Kimi Linear,
+        benchmark/configs/kimi-linear-48b-a3b-l5.json): the attention's
+        kind is a LAYER's, ``linear_attn_config`` listing (from 1) the
+        layers that mix the sequence by the delta rule's scan (delta.py)
+        and those of latent attention, which here has no query latent
+        (``q_lora_rank`` null) and no rotary positions (``mla_use_nope``):
+        the model uses no position anywhere. ``first_k_dense_replace``
+        dense layers, then sparse ones with a shared expert under a sigmoid
+        router that chooses through a bias the server keeps and
+        renormalises its top-k, on the plain residual. ``num_experts``
+        gives the experts HELD and ``router_outputs`` the published number;
+        ``router_bias_rate`` the published config does not state."""
+        n, dense = int(c["num_hidden_layers"]), int(c["first_k_dense_replace"])
+        linear = c["linear_attn_config"]
+        delta, full = (set(linear[k]) for k in ("kda_layers",
+                                                "full_attn_layers"))
+        CHECK(c["moe_router_activation_func"] == "sigmoid"
+              and c["moe_renormalize"] and int(c["num_expert_group"]) == 1
+              and c["mla_use_nope"] and c["q_lora_rank"] is None
+              and c.get("rope_scaling") is None
+              and int(c.get("moe_layer_freq", 1)) == 1
+              and not c.get("num_nextn_predict_layers")
+              and all((i in delta) != (i in full) for i in range(1, n + 1)),
+              "only the block whose router scores by sigmoid in one group "
+              "and renormalises its top-k, whose latent attention has no "
+              "query latent and no positions, and whose every layer is of "
+              "one of the two kinds, is written down here")
+        nope, rope = int(c["qk_nope_head_dim"]), int(c["qk_rope_head_dim"])
+        return cls(
+            hidden=int(c["hidden_size"]),
+            n_heads=int(c["num_attention_heads"]), n_kv_heads=0,
+            head_dim=nope + rope,
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_token"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["num_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(0,) * n, window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]),
+            eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c["hidden_act"]), router_input="ffn_input",
+            attention="mla",
+            attention_layout=tuple("kda" if i in delta else "mla"
+                                   for i in range(1, n + 1)),
+            kv_lora_rank=int(c["kv_lora_rank"]),
+            qk_nope_dim=nope, qk_rope_dim=rope,
+            v_head_dim=int(c["v_head_dim"]),
+            ffn_layout=(0,) * dense + (1,) * (n - dense),
+            dense_width=int(c["intermediate_size"]),
+            shared_width=int(c["num_shared_experts"])
+            * int(c["moe_intermediate_size"]),
+            scoring="sigmoid_bias",
+            routed_scale=float(c["routed_scaling_factor"]),
+            bias_rate=float(c["router_bias_rate"]),
+            kda_heads=int(linear["num_heads"]),
+            kda_head_dim=int(linear["head_dim"]),
+            kda_conv=int(linear["short_conv_kernel_size"]))
+
     def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it, built from
         the layer's kinds: a matrix table's (rows, columns) or a small
@@ -620,19 +737,25 @@ class LMConfig:
         and k norms, the indexer's five (``selection``). The experts' three
         are stacked by expert along the rows."""
         h, d = self.hidden, self.head_dim
-        if self.attention == "mla":
+        kind = self.attention_of(layer)
+        if kind == "kda":
+            from . import delta
+            shapes = delta.shapes(self)
+        elif kind == "mla":
             # over the held heads: ``wq_b``, ``wkv_b``, ``wo`` cut by head,
             # each head's columns together, ``[nope | rope]``, ``[k nope | v]``
             heads = self.n_heads_held
             shapes = {
                 "wq_a": (h, self.q_lora_rank),
                 "norm_q_a": (self.q_lora_rank,),
-                "wq_b": (self.q_lora_rank, heads * d),
+                "wq_b": (self.q_lora_rank, heads * d)} if self.q_lora_rank \
+                else {"wq": (h, heads * d)}
+            shapes.update({
                 "wkv_a": (h, self.kv_lora_rank + self.qk_rope_dim),
                 "norm_kv_a": (self.kv_lora_rank,),
                 "wkv_b": (self.kv_lora_rank,
                           heads * (self.qk_nope_dim + self.v_head_dim)),
-                "wo": (heads * self.v_head_dim, h)}
+                "wo": (heads * self.v_head_dim, h)})
         else:
             heads = self.heads(layer)
             shapes = {"wq": (h, heads * d), "wk": (h, self.n_kv_heads * d),
@@ -1559,7 +1682,7 @@ def feed_forward_vjp(cfg: LMConfig, sparse: int, mats, sinks, small, u):
 
 
 def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
-                selected=None):
+                selected=None, decay_deep=None):
     """What a forward program reports of one sequence through a layer
     whose feed-forward is ``feed_forward_vjp``'s: ``(stats, ids)``. A
     sparse layer's ``stats`` int32 [2 + n_experts]: assignments on held
@@ -1567,7 +1690,9 @@ def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
     dense layer's two zeros and no ids. With a gate its ``gate_open``
     (the gates' sum over heads of their mean over tokens) comes last, in
     thousandths; with a ``cfg.selection`` its counts (``selected``:
-    sparse.COUNTS of them) come last."""
+    sparse.COUNTS of them) come last; a delta layer's ``decay_deep``
+    (delta.py: the (chunk, head, channel) triples whose summed log decay is
+    under ``delta.DEEP``) comes last."""
     if sparse:
         ids, sizes, load = aux
         stats = jnp.concatenate(
@@ -1580,6 +1705,8 @@ def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
             [stats, jnp.round(1e3 * gate_open)[None].astype(jnp.int32)])
     if selected is not None:
         stats = jnp.concatenate([stats, selected])
+    if decay_deep is not None:
+        stats = jnp.concatenate([stats, decay_deep[None].astype(jnp.int32)])
     return stats, ids
 
 
@@ -1598,8 +1725,30 @@ def _route_layer(cfg: LMConfig, router, norm_ffn, stream):
     return route(cfg, router, stream)
 
 
+def _module_attention_vjp(cfg: LMConfig, kind: str, rope, mats, sinks,
+                          small, x, pos):
+    """``attention_vjp``'s results for the kinds of attention that live in
+    modules of their own and give ``F(x)``: latent.py's (``mla``), delta.py's
+    (``kda``, which reads no position)."""
+    stats = {}
+    if kind == "kda":
+        from . import delta
+        out, stats["decay_deep"], pull_f = delta.attention_vjp(
+            cfg, mats, sinks, small, x)
+    else:
+        from . import latent
+        out, pull_f = latent.attention_vjp(cfg, mats, sinks, small, x, pos,
+                                           rope=bool(rope))
+
+    def pull(da):
+        dx, d_mats, d_small = pull_f(da)
+        return da + dx, d_mats, d_small
+
+    return x + out, stats, pull
+
+
 def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
-                  pos=None):
+                  pos=None, kind=None):
     """``a = x + Attn(RMSNorm(x))`` for one sequence and what pulls a
     cotangent back through it: ``(a, stats, pull)``, ``pull(da) -> (dx,
     matrix gradients, small gradients)``; ``stats`` is what
@@ -1614,7 +1763,14 @@ def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
     sparse.py's indexer selects of the layer's input (``selection_vjp``,
     ``attention_vjp`` there), and the pull has a fourth result: the loss
     that lives INSIDE the layer, whose gradients to the indexer's tensors
-    are made from what the layer recomputed, whatever ``da`` is."""
+    are made from what the layer recomputed, whatever ``da`` is.
+
+    ``kind`` is the layer's attention (``cfg.attention_of``; the model's
+    when None) and selects functions: ``gqa``'s below, or a module's."""
+    kind = kind or cfg.attention
+    if kind != "gqa":
+        return _module_attention_vjp(cfg, kind, rope, mats, sinks, small, x,
+                                     pos)
     selection = None
     if cfg.selection != "none":
         from . import sparse as selection
@@ -1674,20 +1830,26 @@ def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
 
 
 def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
-              pos=None):
+              pos=None, attention=None):
     """One sequence through one layer of the plain residual whose
     feed-forward reads one normed input (``cfg.one_ffn_input``): ``y = a
     + F(RMSNorm(a))``, ``a = x + Attn(RMSNorm(x))``: ``(y, (stats, ids),
     pull)``, ``pull(dy) -> (dx, matrix gradients, small gradients)`` and
     after them what the attention's pull gives beyond its three (the loss
-    inside a layer with a ``cfg.selection``)."""
+    inside a layer with a ``cfg.selection``). ``attention``: the layer's
+    kind of attention where it is a layer's to say."""
     sinks = _zeros_like_f32(mats)
     a, stats, pull_attention = attention_vjp(cfg, rope, mask, mats, sinks,
-                                             small, x, pos)
+                                             small, x, pos, attention)
     v, aux, pull_ffn = feed_forward_vjp(cfg, sparse, mats, sinks, small, a)
 
     def pull(dy):
         du, (d_mats_ffn, d_small_ffn) = pull_ffn(dy)
+        if attention is not None:
+            # the feed-forward's gradients all made, and what it kept for
+            # them let go, before the attention's pull makes its own keep
+            du, d_mats_ffn, d_small_ffn = jax.lax.optimization_barrier(
+                (du, d_mats_ffn, d_small_ffn))
         dx, d_mats, d_small, *inner = pull_attention(dy + du)
         return (dx, {**d_mats, **d_mats_ffn}, {**d_small, **d_small_ffn},
                 *inner)
@@ -1696,7 +1858,7 @@ def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
 
 
 def layer_forward(cfg: LMConfig, rope, mask, mats, small, x, pos=None,
-                  sparse: int = 1):
+                  sparse: int = 1, attention=None):
     """One sequence through one layer under ``mask`` (a ``Mask``, or an
     int: a window, 0 causal) at rotary positions ``pos``: ``(y, stats,
     ids)``, ``stats`` int32[2] = (assignments on held experts, the
@@ -1705,7 +1867,8 @@ def layer_forward(cfg: LMConfig, rope, mask, mats, small, x, pos=None,
     token's experts (a check hands them to its reference; a step drops
     them)."""
     if cfg.one_ffn_input:
-        y, stats, _ = layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos)
+        y, stats, _ = layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos,
+                                attention)
         return (y,) + stats
     sinks = _zeros_like_f32(mats)
     early = cfg.router_input == "input"
@@ -1729,7 +1892,7 @@ def layer_forward(cfg: LMConfig, rope, mask, mats, small, x, pos=None,
 
 
 def layer_grads(cfg: LMConfig, rope, mask, mats, small, x, dy, pos=None,
-                sparse: int = 1):
+                sparse: int = 1, attention=None):
     """The layer recomputed from its input ``x`` and differentiated:
     ``(dx, matrix gradients, small gradients)`` for one sequence. Each
     part's backward pass runs under the scope of its forward pass, so a
@@ -1737,7 +1900,8 @@ def layer_grads(cfg: LMConfig, rope, mask, mats, small, x, dy, pos=None,
     a fourth result, the loss that lives inside the layer
     (``attention_vjp``)."""
     if cfg.one_ffn_input:
-        return layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos)[2](dy)
+        return layer_vjp(cfg, rope, mask, sparse, mats, small, x, pos,
+                         attention)[2](dy)
     sinks = _zeros_like_f32(mats)
     early = cfg.router_input == "input"
 

@@ -117,6 +117,24 @@ def zipf_tokens(key, shape, vocab: int, exponent: float = 1.0):
     return jnp.minimum(jnp.searchsorted(cdf, u), vocab - 1).astype(jnp.int32)
 
 
+#: A delta layer's tensors (delta.py) that start from a draw of their own.
+DECAY_INITS = ("a_log", "dt_bias")
+
+
+def decay_init(name: str, size: int, seed: int) -> np.ndarray:
+    """Where a delta layer's log decay ``-exp(a_log) softplus(. + dt_bias)``
+    starts, as the published model's released implementation draws it:
+    ``a_log`` the log of uniform(1, 16) a head; ``dt_bias`` the inverse
+    softplus of a step drawn log-uniformly on [0.001, 0.1] a channel, so
+    that a fresh layer's decay a position is ``exp(-1.6)`` at the fastest
+    and mostly near 1."""
+    rng = np.random.RandomState(seed % (2 ** 32))
+    if name == "a_log":
+        return np.log(rng.uniform(1.0, 16.0, size)).astype(np.float32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), size))
+    return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+
+
 def _dispatch(fn, *args):
     """A trainer-thread dispatch (guarded like every other: a no-op but
     where one process' threads must not overlap device programs)."""
@@ -150,7 +168,7 @@ def bias_step(cfg: LMConfig, stats):
 
 
 def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
-                    sparse: int = 1):
+                    sparse: int = 1, *, attention=None):
     """``(float32 matrices, small, x [B, T, hidden]) -> (y, stats [B, 2],
     the matrices' bfloat16 copies, each token's experts [B, T, k])``, for a
     layer of the kind ``LMConfig.layer_kinds`` names (``sparse``: its
@@ -158,15 +176,24 @@ def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
     Where the feed-forward is ``model.feed_forward_vjp``'s the ``stats``
     hold more (model.layer_stats). The streams' (``cfg.residual ==
     "mhc"``) takes and gives [B, n hidden, T], and its sparse layers give a
-    fifth result, the bias's step (``bias_step``)."""
+    fifth result, the bias's step (``bias_step``); so do the plain
+    residual's where an ``attention`` says the layer's kind of attention
+    (``LMConfig.attention_layout``) and the router chooses through a bias."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
+
+    biased = attention is not None and sparse \
+        and cfg.scoring == "sigmoid_bias"
 
     def forward(mats32, small, x):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
         y, stats, ids = jax.lax.map(
             lambda seq: lm.layer_forward(cfg, rope, mask, mats, small, seq,
-                                         pos, sparse), x)
-        return y, stats, mats, ids
+                                         pos, sparse, attention), x)
+        # the router outputs' counts alone: a delta layer's stats end in
+        # another count (model.layer_stats)
+        more = (bias_step(cfg, stats[:, :2 + cfg.n_experts]),) if biased \
+            else ()
+        return (y, stats, mats, ids) + more
 
     def forward_streams(mats32, small, x):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
@@ -222,7 +249,7 @@ def _learned(small):
 
 
 def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
-                     sparse: int = 1):
+                     sparse: int = 1, *, attention=None):
     """``(bfloat16 matrices, small, x, dy) -> (dx, matrix gradients, small
     gradients)``, the gradients float32 and summed over the sequences (a
     router bias gets none: ``small`` holds it, the gradients do not). With
@@ -235,11 +262,11 @@ def backward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
         # layer) rides with dx and is summed after
         def one(seq):
             dx, d_mats, d_small, *inner = lm.layer_grads(
-                cfg, rope, mask, mats, small, *seq, pos, sparse)
+                cfg, rope, mask, mats, small, *seq, pos, sparse, attention)
             return (dx, *inner), d_mats, d_small
 
         (dx, *inner), d_mats, d_small = _summed_over_sequences(
-            one, mats, small, (x, dy))
+            one, mats, _learned(small), (x, dy))
         return (dx, d_mats, d_small, *(jnp.sum(s) for s in inner))
 
     def backward_streams(mats, small, x, dy):
@@ -395,6 +422,14 @@ class PSLMTrainer:
             under the PLAIN rule, every other under the flag's Adam."""
             if name == "router_bias":   # ``scoring: "sigmoid_bias"`` alone
                 return create_array_table(shape[0], updater_type="default")
+            if name in DECAY_INITS:     # a delta layer's log decay: no
+                #                         constant starts it (``decay_init``)
+                return create_array_table(shape[0], fill=decay_init(
+                    name, shape[0], next(seeds)))
+            if name.startswith("conv_"):    # [channels, weights]: uniform
+                #                         on +- weights^-1/2, a convolution's
+                #                         usual start
+                return matrix(shape, (3 * shape[1]) ** -0.5)
             if len(shape) == 2:
                 return matrix(shape, shape[1] ** -0.5
                               if name.endswith("_phi") else init_std)
@@ -423,11 +458,13 @@ class PSLMTrainer:
 
         kinds = sorted(set(cfg.layer_kinds()))
         # a kind is (rotary, window[, sparse[, query heads]]); the heads
-        # tell two kinds apart and are their tensors' to say
-        self._forward = {k: forward_program(cfg, *k[:2], self.T, *k[2:3])
-                         for k in kinds}
-        self._backward = {k: backward_program(cfg, *k[:2], self.T, *k[2:3])
-                          for k in kinds}
+        # tell two kinds apart and are their tensors' to say. With an
+        # ``attention_layout`` the kind's last says the layer's attention,
+        # which its programs are built from.
+        self._forward, self._backward = (
+            {k: build(cfg, *k[:2], self.T, *k[2:3],
+                      **({"attention": k[3]} if cfg.attention_layout else {}))
+             for k in kinds} for build in (forward_program, backward_program))
         self._split = jax.jit(self._split_more if cfg.mtp_layers
                               else self._split_tokens)
         self.streams = cfg.residual == "mhc"
@@ -741,6 +778,22 @@ class PSLMTrainer:
                                        "LM_SELECT_TILES"), start=-4):
                 count(name, int(sum(s[:, at].astype(np.int64).sum()
                                     for s in per_layer)))
+        if "kda" in self.cfg.attention_layout:
+            # a delta layer's last: the (chunk, head, channel) triples whose
+            # summed log decay is under delta.DEEP, of all it has
+            from . import delta
+            scanned = [s for s, kind in zip(per_layer,
+                                            self.cfg.attention_layout)
+                       if kind == "kda"]
+            sequences = sum(len(s) for s in scanned)
+            chunks = sequences * (self.T // delta.chunk_of(self.T))
+            count("LM_KDA_TOKENS", sequences * self.T)
+            count("LM_KDA_CHUNKS", chunks)
+            count("LM_KDA_DECAY_CHANNELS",
+                  chunks * self.cfg.kda_heads * self.cfg.kda_head_dim)
+            deep = int(sum(s[:, -1].astype(np.int64).sum() for s in scanned))
+            if deep:
+                count("LM_KDA_DECAY_DEEP", deep)
         count("LM_EMBED_ROWS", int(distinct))
         count("LM_MASKED_TOKENS", int(scored))
 

@@ -204,7 +204,8 @@ class ArrayServer(ServerTable):
     def __init__(self, size: int, dtype=np.float32, zoo=None,
                  updater_type: Optional[str] = None, fill: float = 0.0):
         """``fill`` is the value every element starts at (a norm's
-        scale starts at 1), written on the devices."""
+        scale starts at 1), written on the devices; an array of ``size``
+        values gives each element its own (this server's part of it)."""
         super().__init__(zoo=zoo)
         self.dtype = np.dtype(dtype)
         num_servers = self._zoo.num_servers
@@ -221,7 +222,14 @@ class ArrayServer(ServerTable):
         padded = meshlib.padded_size(my_size, meshlib.device_count(mesh))
         self._data = meshlib.zeros_sharded((padded,), self.dtype,
                                            self._sharding)
-        if fill:
+        if np.ndim(fill):
+            first = server_id * (size // num_servers)
+            host = np.zeros((padded,), self.dtype)
+            host[:my_size] = np.asarray(fill)[first:first + my_size]
+            with device_lock.guard():
+                self._data = device_lock.settle(
+                    jax.device_put(host, self._sharding))
+        elif fill:
             with device_lock.guard():
                 self._data = device_lock.settle(
                     self._data + self.dtype.type(fill))

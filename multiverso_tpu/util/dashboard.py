@@ -261,6 +261,15 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_ATTN_PASS_PLAIN": "layers' sequences that took the jax.numpy "
                           "chain there (no TPU, no whole block of tokens "
                           "or tile of lanes, neither head norms nor a turn)",
+    "LM_KDA_TOKENS": "tokens through delta layers (models/lm/delta.py), "
+                     "every such layer and sequence",
+    "LM_KDA_CHUNKS": "chunks the delta layers' scans walked, a layer a "
+                     "sequence",
+    "LM_KDA_DECAY_CHANNELS": "(chunk, head, channel) triples of the delta "
+                             "layers' scans",
+    "LM_KDA_DECAY_DEEP": "of those, the triples whose log decay summed over "
+                         "the chunk is under delta.DEEP (computed in the "
+                         "scan)",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
                      "trainer that holds the module)",
