@@ -41,6 +41,18 @@ it started from before it is transposed (a run is under
 the sequence's q, k, v, g, beta and the state at each run's boundary, never
 a state a position nor a [T, T] array).
 
+**On a TPU** at whole chunks of 64 and heads of one 128-lane tile
+(``scan_in_kernels``) none of ``M``, ``U``, ``W``, ``A``, ``B`` nor the state
+between chunks goes to HBM: ``scan`` is delta_kernels.py's two Pallas
+kernels, a head's chunks walked with the state and the chunk's matrices in
+fast memory, forward and backward, q, k, g, v read from ``[T, H K]`` as
+``gates`` leaves them. The same algebra, sub-blocks and precision; the
+functions below are every other shape's path and the kernels' definition.
+Around it the heads' arrays stay [T, H K], the layout the projections
+leave: ``gates`` and ``output`` work a head at a time on ``heads_apart``'s
+view, and ``attention_vjp`` hands [T, H K] from part to part, so that no
+copy turns 8 positions by 128 lanes into 8 heads by 128 lanes and back.
+
 **No division by a decay.** Every exponent is a difference of summed log
 decays that is <= 0: ``G_t``, ``G_last - G_s``, and ``G_t - G_s`` for ``s
 <= t``. The last sits INSIDE the sum over channels, so ``A`` and ``B`` are
@@ -91,6 +103,8 @@ DEEP = -20.0
 CHUNKS_AT_ONCE = 8
 #: The state's dtype from chunk to chunk (a check's control lowers it).
 CARRY = F32
+#: Positions a tile of a float32 [T, lanes] array holds on a TPU.
+SUBLANES = 8
 
 
 def shapes(cfg: LMConfig) -> dict:
@@ -254,11 +268,42 @@ def _across(state, w, u, b, qg, kg, fall):
     return state.astype(F32), o
 
 
+def _sizes(t: int, chunk: int, block: int):
+    """``scan``'s chunk and sub-block where they are left to it."""
+    chunk = chunk or chunk_of(t)
+    return chunk, block or (BLOCK if chunk % BLOCK == 0 else chunk)
+
+
+def scan_in_kernels(t: int, lanes: int, v_lanes: int, chunk: int = 0,
+                    block: int = 0) -> bool:
+    """Whether ``scan`` runs as delta_kernels' Pallas kernels: on a TPU, at
+    whole chunks of 64 in sub-blocks of 16 and heads of one 128-lane tile,
+    with the state in float32 (``CARRY`` as it is). By what the code can
+    see: no flag chooses."""
+    if jax.default_backend() != "tpu" or CARRY != F32:
+        return False
+    from . import delta_kernels     # Pallas: imported where it can run
+    return delta_kernels.shapes_fit(t, lanes, v_lanes,
+                                    *_sizes(t, chunk, block))
+
+
+def scan_counter(cfg: LMConfig, t: int) -> str:
+    """The counter a delta layer's sequence of ``t`` tokens counts: which
+    form ``scan`` took (``PSLMTrainer._count_stats``)."""
+    d = cfg.kda_head_dim
+    return "LM_KDA_SCAN_KERNEL" if scan_in_kernels(t, d, d) \
+        else "LM_KDA_SCAN_PLAIN"
+
+
 def scan(q, k, v, g, beta, chunk: int = 0, block: int = 0):
     """The delta rule's outputs ``o`` [T, H, V] float32 and the count of
     deep (chunk, head, channel) triples, for q, k, g [T, H, K], v [T, H,
     V], beta [T, H] float32, in chunks of ``chunk`` positions
     (``chunk_of(T)`` when 0) and sub-blocks of ``block``.
+
+    Where ``scan_in_kernels``, delta_kernels.py's two kernels: a head's
+    chunks walked with the state and the chunk's matrices in fast memory,
+    read from [T, H K] as it lies. Everywhere else the lines below.
 
     The chunks go in runs of ``CHUNKS_AT_ONCE``: a run's chunks through
     ``_within`` together, then the state through them in order. A run is
@@ -266,8 +311,10 @@ def scan(q, k, v, g, beta, chunk: int = 0, block: int = 0):
     at each run's start and the run's inputs; it walks the runs the other
     way and makes each again before it transposes it."""
     t, heads, lanes = q.shape
-    chunk = chunk or chunk_of(t)
-    block = block or (BLOCK if chunk % BLOCK == 0 else chunk)
+    if scan_in_kernels(t, lanes, v.shape[-1], chunk, block):
+        from . import delta_kernels
+        return delta_kernels.scan(q, k, v, g, beta, DEEP)
+    chunk, block = _sizes(t, chunk, block)
     assert t % chunk == 0 and chunk % block == 0, (t, chunk, block)
     n = t // chunk
     at_once = next(m for m in range(min(CHUNKS_AT_ONCE, n), 0, -1)
@@ -317,6 +364,19 @@ def short_conv(x, w):
     return jax.nn.silu(sum(padded[j:j + t] * w[:, j] for j in range(n)))
 
 
+def heads_apart(a, heads: int):
+    """[T, H d] -> [T / 8, 8, H, d], a head's lanes the last axis with the
+    positions' groups of ``SUBLANES`` kept whole ([T, 1, H, d] where 8 does
+    not divide T). A TPU stores float32 [T, H d] in tiles of 8 positions by
+    128 lanes, so this view is the array as it lies, and a sum over a
+    head's lanes or a factor a head reads and writes [T, H d] once; [T, H,
+    d] lies in tiles of 8 HEADS by 128 lanes, a copy each way (0.4 ms for
+    [8192, 32, 128], and the broadcasts written out beside it)."""
+    t = a.shape[0]
+    rows = SUBLANES if t % SUBLANES == 0 else 1
+    return a.reshape(t // rows, rows, heads, -1)
+
+
 def gates(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
     """From the convolved q, k, v [T, H K], the decay's logits ``f`` and
     beta's ``b``: ``(q, k, v, g [T, H, K], beta [T, H])`` float32 as
@@ -324,14 +384,14 @@ def gates(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
     t, heads, d = q.shape[0], cfg.kda_heads, cfg.kda_head_dim
 
     def by_head(a):
-        return a.reshape(t, heads, d)
+        return heads_apart(a, heads)
 
     def unit(a):
         return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True))
 
     g = -jnp.exp(a_log)[:, None] * by_head(jax.nn.softplus(f + dt_bias))
-    return (unit(by_head(q)) * d ** -0.5, unit(by_head(k)), by_head(v), g,
-            jax.nn.sigmoid(b))
+    out = (unit(by_head(q)) * d ** -0.5, unit(by_head(k)), by_head(v), g)
+    return (*(a.reshape(t, heads, d) for a in out), jax.nn.sigmoid(b))
 
 
 def output(cfg: LMConfig, mats, sinks, norm_o, o, gate):
@@ -339,7 +399,8 @@ def output(cfg: LMConfig, mats, sinks, norm_o, o, gate):
     head normed alone, times the sigmoid of its lanes of ``gate`` [T, H
     V]) and ``W_o``: [T, hidden]."""
     t, heads, d = o.shape
-    o = lm.rmsnorm(o, norm_o, cfg.eps).reshape(t, heads * d)
+    o = lm.rmsnorm(heads_apart(o.reshape(t, heads * d), heads), norm_o,
+                   cfg.eps).reshape(t, heads * d)
     return lm.mm(o * jax.nn.sigmoid(gate), mats["wo"], sinks["wo"])
 
 
@@ -360,7 +421,17 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
     nor beside the others'."""
     first = {n: sinks[n] for n in MATRICES[:-1]}
     convs = tuple(small[n] for n in CONVS)
-    chunk = chunk_of(x.shape[0])
+    t, heads = x.shape[0], cfg.kda_heads
+    chunk = chunk_of(t)
+
+    # between the parts (what is differentiated, kept, held behind a
+    # barrier) the heads' arrays go as [T, H d], the layout the projections
+    # leave and the kernels read; [T, H, d] is a view inside a part
+    def by_head(a):
+        return a.reshape(t, heads, -1)
+
+    def flat(a):
+        return a.reshape(t, -1)
 
     def convolved(convs, q, k, v):
         return tuple(short_conv(x, w) for x, w in zip((q, k, v), convs))
@@ -372,11 +443,13 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
         vjp = vjp or (lambda f, *a: (f(*a), None))
         with jax.named_scope(SCOPE + ".conv"):
             qkv, pull_conv = vjp(convolved, convs, q, k, v)
+
+        def of(a_log, dt_bias, qkv, f, b):
+            *wide, beta = gates(cfg, a_log, dt_bias, *qkv, f, b)
+            return (*map(flat, wide), beta)
+
         with jax.named_scope(SCOPE):
-            scanned, pull_gates = vjp(
-                lambda a_log, dt_bias, qkv, f, b: gates(cfg, a_log, dt_bias,
-                                                        *qkv, f, b),
-                a_log, dt_bias, qkv, f, b)
+            scanned, pull_gates = vjp(of, a_log, dt_bias, qkv, f, b)
 
         def pull(d_scanned):
             with jax.named_scope(SCOPE):
@@ -388,8 +461,10 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
         return scanned, pull
 
     def scanned_through(*scanned):
+        *wide, beta = scanned
         with jax.named_scope(SCOPE + ".scan"):
-            return scan(*scanned, chunk)
+            o, deep = scan(*map(by_head, wide), beta, chunk)
+            return flat(o), deep
 
     with jax.named_scope(SCOPE):
         (q, k, v, f, gate, b), pull_projections = jax.vjp(
@@ -399,8 +474,8 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
     o, deep = scanned_through(*gated(*kept)[0])
     with jax.named_scope(SCOPE):
         out, pull_output = jax.vjp(
-            lambda s, norm_o, o, gate: output(cfg, mats, {"wo": s}, norm_o, o,
-                                              gate),
+            lambda s, norm_o, o, gate: output(cfg, mats, {"wo": s}, norm_o,
+                                              by_head(o), gate),
             sinks["wo"], small["norm_o"], o, gate)
 
     def pull(d_out):

@@ -76,6 +76,8 @@ a sequence: whether its routed experts took the short buffer or the one
 of every assignment, from the same count and ``model.experts_capacity``),
 ``LM_ATTN_PASS_FUSED`` or ``LM_ATTN_PASS_PLAIN`` (one a layer a sequence:
 which form ``model.attention_inputs`` took, ``model.attention_pass_name``),
+``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
+sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
 ``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
 computed on the device and read at the start of the next step,
@@ -788,6 +790,9 @@ class PSLMTrainer:
             sequences = sum(len(s) for s in scanned)
             chunks = sequences * (self.T // delta.chunk_of(self.T))
             count("LM_KDA_TOKENS", sequences * self.T)
+            # which form each of their scans took: the test delta.scan
+            # chose by
+            count(delta.scan_counter(self.cfg, self.T), sequences)
             count("LM_KDA_CHUNKS", chunks)
             count("LM_KDA_DECAY_CHANNELS",
                   chunks * self.cfg.kda_heads * self.cfg.kda_head_dim)

@@ -270,6 +270,12 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_KDA_DECAY_DEEP": "of those, the triples whose log decay summed over "
                          "the chunk is under delta.DEEP (computed in the "
                          "scan)",
+    "LM_KDA_SCAN_KERNEL": "delta layers' sequences whose scan ran as the "
+                          "Pallas kernels of models/lm/delta_kernels.py "
+                          "(delta.scan_in_kernels)",
+    "LM_KDA_SCAN_PLAIN": "delta layers' sequences whose scan took the "
+                         "jax.numpy runs of chunks (no TPU, a chunk that "
+                         "is not 64, a head that is not one 128-lane tile)",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
                      "trainer that holds the module)",

@@ -602,5 +602,9 @@ def test_what_a_step_counts(run):
         int(stats[i][:, -1].sum()) for i in (0, 1, 3))
     fullest = sum(int(stats[i][:, 2:10].sum(0).max()) for i in (1, 2, 3))
     assert counted("LM_ROUTER_LOAD_MAX") == fullest
+    # one a delta layer a sequence, by the test delta.scan chose by: the
+    # CPU takes the jax.numpy scan
+    assert counted("LM_KDA_SCAN_PLAIN") == 3 * B
+    assert counted("LM_KDA_SCAN_KERNEL") == 0
     assert counted("LM_ATTN_PASS_FUSED") == 0
     assert counted("LM_ATTN_PASS_PLAIN") == 0

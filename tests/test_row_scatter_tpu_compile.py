@@ -387,3 +387,89 @@ def test_the_attention_s_pass_compiles_at_the_published_head_counts(
     # the three cotangents in bfloat16, before they are widened
     assert backward.memory_analysis().temp_size_in_bytes < 1.1 * 2 * (
         tokens * groups * (per_group + 2) * d)
+
+
+# -- the delta rule's scan ------------------------------------------------------------
+# (models/lm/delta_kernels.py; interpreted against the plain scan and the
+# recurrence in tests/test_lm_kda_kernels.py)
+
+def test_the_delta_scan_s_kernels_compile_at_the_cell_s_shapes(topo):
+    """Both kernels as Mosaic takes them at 8192 positions, 32 heads of 128
+    lanes, chunks of 64 (``kimi48b.ps-8k``): the forward pass alone is one
+    kernel; made again and pulled it is two (the forward that keeps a
+    chunk's state, ``M`` and diagonal blocks a head, and the backward
+    walk), and what lives between them is what was kept (537 MB: 268 of
+    states, ``M``'s 64 lanes padded to 128) and the turns of [T, H, 128] to
+    [T, H 128] that a program's own parameters need (a layer program's
+    fusions write the kernels' layout themselves)."""
+    from multiverso_tpu.models.lm import delta, delta_kernels
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    tokens, heads, lanes = 8192, 32, 128
+    assert delta_kernels.shapes_fit(tokens, lanes, lanes, delta.CHUNK,
+                                    delta.BLOCK)
+    wide = jax.ShapeDtypeStruct((tokens, heads, lanes), jnp.float32,
+                                sharding=one)
+    beta = jax.ShapeDtypeStruct((tokens, heads), jnp.float32, sharding=one)
+
+    def scan(*args):
+        return delta_kernels.scan(*args, delta.DEEP)
+
+    forward = jax.jit(scan).lower(wide, wide, wide, wide, beta).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    assert "mv_kda_scan_fwd" in forward.as_text()
+
+    def pulled(q, k, v, g, beta, cotangent):
+        return jax.vjp(lambda *a: scan(*a)[0], q, k, v, g, beta)[1](cotangent)
+
+    backward = jax.jit(pulled).lower(wide, wide, wide, wide, beta,
+                                     wide).compile()
+    text = backward.as_text()
+    assert text.count("tpu_custom_call") == 2 and "mv_kda_scan_bwd" in text
+    kept = 4 * heads * (tokens // delta.CHUNK) * sum(
+        rows * max(columns, lanes) for rows, columns in delta_kernels.KEPT)
+    assert kept < backward.memory_analysis().temp_size_in_bytes \
+        < kept + 6 * 4 * tokens * heads * lanes
+
+
+def test_a_delta_sublayer_holds_its_heads_as_the_projections_leave_them(
+        topo, monkeypatch):
+    """``delta.attention_vjp`` and its pull at the cell's widths, as the
+    layer programs hold them: three kernels (the forward scan, and in the
+    pull the forward that keeps and the backward walk) and NO array in [T,
+    H, 128]'s own tiles (8 heads by 128 lanes), which is a copy from and to
+    the projections' [T, H 128] (8 positions by 128 lanes): ``gates`` and
+    ``output`` work on ``heads_apart``'s view, and the parts hand each
+    other [T, H 128]. (The parent held 16 such arrays a sublayer and the
+    convolutions' fusions lost their scope to the turn: 258 ms a step under
+    no scope, 68 after, PERF.md section 5.)"""
+    from multiverso_tpu.models.lm import delta, model as lm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "kimi-linear-48b-a3b-l5.json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    tokens = 8192
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shapes = delta.shapes(cfg)
+    mats = {n: shaped(shapes[n], jnp.bfloat16) for n in delta.MATRICES}
+    small = {n: shaped(s, jnp.float32) for n, s in shapes.items()
+             if n not in delta.MATRICES}
+    small["norm_attn"] = shaped((cfg.hidden,), jnp.float32)
+    x = shaped((tokens, cfg.hidden), jnp.float32)
+
+    def sublayer(mats, small, x, d):
+        sinks = {n: jnp.zeros(m.shape, jnp.float32)
+                 for n, m in mats.items()}
+        out, deep, pull = delta.attention_vjp(cfg, mats, sinks, small, x)
+        return out, deep, pull(d)
+
+    text = jax.jit(sublayer).lower(mats, small, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert text.count("mv_kda_scan_bwd") >= 1
+    heads, d = cfg.kda_heads, cfg.kda_head_dim
+    assert f"f32[{tokens},{heads * d}]" in text
+    assert f"f32[{tokens},{heads},{d}]" not in text
