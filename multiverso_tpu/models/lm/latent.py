@@ -1,18 +1,24 @@
-"""Multi-head latent attention (DeepSeek-V2/V3's, as ``model_type:
-xing4_0`` configures it), one chip's heads of it, as the sublayer ``F`` of
-the third family's layer (streams.py): for a sublayer's normed input
-``h`` [T, hidden]
+"""Multi-head latent attention (DeepSeek-V2/V3's), one chip's heads of it,
+as the sublayer ``F`` of a layer on either residual (the streams',
+streams.py, or the plain one's, ``model.layer_vjp``): for a sublayer's normed
+input ``h`` [T, hidden]
 
     c_q          = RMSNorm(h W_qa; g_qa)                 [q_lora_rank]
     [q_n | q_r]  = c_q W_qb, a head                      [nope | rope]
     [c_kv | k_r] = h W_kva                               [kv_lora_rank | rope]
     [k_n | v]    = RMSNorm(c_kv; g_kva) W_kvb, a head    [nope | v]
-    q_r, k_r     = rotary(q_r), rotary(k_r)   YaRN's frequencies
+    q_r, k_r     = rotary(q_r), rotary(k_r)   ``cfg.yarn``: YaRN's
+                                              frequencies
                                               (``model.yarn_frequencies``);
+                                              empty: ``rope_theta``'s own;
+                                              a layer without positions
+                                              (``rope`` false) turns neither;
                                               ``k_r`` is one for all heads
     score        = (q_n . k_n + q_r . k_r) (nope + rope)^-0.5 m^2, causal,
-                   m = 0.1 mscale_all_dim ln(factor) + 1
+                   m = 0.1 mscale_all_dim ln(factor) + 1 under YaRN, else 1
     F(h)         = softmax(score) v, the heads side by side, W_o
+
+Without a query latent (``q_lora_rank`` 0) ``[q_n | q_r] = h W_q``.
 
 **The share.** ``cfg.heads_held = (first, count)`` of the ``n_heads``:
 ``W_qb``, ``W_kvb`` and ``W_o`` hold the held heads' columns and rows,
@@ -20,9 +26,10 @@ the third family's layer (streams.py): for a sublayer's normed input
 layer adds its own heads' part of ``W_o``'s sum; the shares add up to the
 uncut attention (tests/test_lm_mla.py, eight of eight).
 
-**The kernel.** A head's q and k are ``nope + rope`` = 192 wide and its v
-128: the library's splash kernel takes them as they are (it compiles for a
-v5e at 192 beside 128, tests/test_row_scatter_tpu_compile.py), each head a
+**The kernel.** A head's q and k are ``nope + rope`` wide and its v
+``v_head_dim``, whatever the configuration says (192 beside 128, or 256
+beside 256): the library's splash kernel takes them as they are (it compiles
+for a v5e at both, tests/test_row_scatter_tpu_compile.py), each head a
 group of its own since its keys are its own; elsewhere
 ``blockwise_attention``. No lane is padded.
 
@@ -92,8 +99,9 @@ def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None, rope=True):
     c_kv = lm.rmsnorm(kv_a[:, :latent], g_kv, cfg.eps)
     kv = lm.mm(c_kv, mats["wkv_b"], sinks["wkv_b"]).reshape(
         t, heads, nope + cfg.v_head_dim)
-    if rope:
-        inv = lm.yarn_frequencies(cfg.rope_theta, rope_dim, *cfg.yarn[:4])
+    if rope:    # YaRN's frequencies, or (None) ``rope_theta``'s own
+        inv = lm.yarn_frequencies(cfg.rope_theta, rope_dim, *cfg.yarn[:4]) \
+            if cfg.yarn else None
         q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
         k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
     else:

@@ -28,7 +28,12 @@ post-attention stream, q and k norms, every layer rotary).
 **A third family** (``from_dict`` tells it by ``kv_lora_rank``:
 DeepSeek-V3's block as ``model_type: xing4_0`` has it, XingChen-AGI 2026)
 is described by kinds that the two above leave at their defaults, and its
-mechanisms live in modules of their own:
+mechanisms live in modules of their own. The same keys WITHOUT ``hc_mult``
+(``model_type: glm4_moe_lite``, zai-org's GLM-4.7-Flash, 2026) are the same
+block on the plain residual, ``x + F(RMSNorm(x))`` through ``layer_vjp``,
+and with ``rope_scaling`` null its rotary pairs turn at ``rope_theta``'s
+own frequencies under the scale ``head_dim^-0.5``; the multi-token module
+then reads and writes ``[T, C]`` (mtp.py):
 
     X [n C, T]: ``hc_mult`` residual streams     ``residual: "mhc"``,
       a column a token; a sublayer F reads             streams.py: r = RMSNorm(X);
@@ -519,24 +524,35 @@ class LMConfig:
 
     @classmethod
     def _from_mla(cls, c: dict) -> "LMConfig":
-        """DeepSeek-V3's block as ``model_type: xing4_0`` configures it
-        (benchmark/configs/xing4-29b-a4b-l5.json): latent attention under
-        YaRN, ``first_k_dense_replace`` dense layers and then sparse ones
-        with a shared expert, a sigmoid router chosen through a bias,
-        ``hc_mult`` residual streams, a multi-token module. The keys that
-        count experts and heads give the numbers HELD; ``router_outputs``
-        and ``attention_heads`` the published ones. ``router_bias_rate`` and
+        """DeepSeek-V3's block: latent attention (with a query latent where
+        ``q_lora_rank`` is not null), ``first_k_dense_replace`` dense layers
+        and then sparse ones with a shared expert, a sigmoid router chosen
+        through a bias, a multi-token module. Its residual and its rotary
+        positions are told by the keys: with ``hc_mult`` that many residual
+        streams (``model_type: xing4_0``,
+        benchmark/configs/xing4-29b-a4b-l5.json), without it the plain
+        residual (``model_type: glm4_moe_lite``,
+        benchmark/configs/glm47-flash-30b-a3b-l5.json); under
+        ``rope_scaling`` YaRN's frequencies and scale, with none (null)
+        ``rope_theta``'s own. A config without ``scoring_func`` scores by
+        sigmoid (``noaux_tc`` is DeepSeek-V3's router). The keys that count
+        experts and heads give the numbers HELD; ``router_outputs`` and
+        ``attention_heads`` the published ones. ``router_bias_rate`` and
         ``mtp_loss_weight`` the published config does not state."""
         n, dense = int(c["num_hidden_layers"]), int(c["first_k_dense_replace"])
-        CHECK(c["scoring_func"] == "sigmoid" and c["topk_method"] == "noaux_tc"
+        y = c.get("rope_scaling")
+        CHECK(c.get("scoring_func", "sigmoid") == "sigmoid"
+              and c["topk_method"] == "noaux_tc"
               and int(c["n_group"]) == 1 and c["norm_topk_prob"]
               and int(c.get("moe_layer_freq", 1)) == 1
-              and c["rope_scaling"]["type"] == "yarn",
+              and (y is None or y["type"] == "yarn")
+              and float(c.get("partial_rotary_factor", 1)) == 1,
               "only the block whose router scores by sigmoid, chooses "
-              "through a bias in one group and normalises its top-k, under "
-              "YaRN, is written down here")
-        y = c["rope_scaling"]
+              "through a bias in one group and normalises its top-k, whose "
+              "latent attention turns every rotary lane, under YaRN or under "
+              "no scaling, is written down here")
         held = int(c["num_attention_heads"])
+        streams = "hc_mult" in c
         return cls(
             hidden=int(c["hidden_size"]),
             n_heads=int(c.get("attention_heads", held)), n_kv_heads=0,
@@ -551,10 +567,11 @@ class LMConfig:
             rope_theta=float(c["rope_theta"]),
             eps=float(c["rms_norm_eps"]),
             loss_block=int(c.get("loss_block", 2048)),
-            activation=str(c["hidden_act"]), router_input="ffn_norm",
+            activation=str(c["hidden_act"]),
+            router_input="ffn_norm" if streams else "ffn_input",
             attention="mla",
             heads_held=(int(c.get("first_head_held", 0)), held),
-            q_lora_rank=int(c["q_lora_rank"]),
+            q_lora_rank=int(c["q_lora_rank"] or 0),
             kv_lora_rank=int(c["kv_lora_rank"]),
             qk_nope_dim=int(c["qk_nope_head_dim"]),
             qk_rope_dim=int(c["qk_rope_head_dim"]),
@@ -562,7 +579,8 @@ class LMConfig:
             yarn=(float(y["factor"]), float(y["beta_fast"]),
                   float(y["beta_slow"]),
                   float(y["original_max_position_embeddings"]),
-                  float(y["mscale"]), float(y["mscale_all_dim"])),
+                  float(y["mscale"]), float(y["mscale_all_dim"]))
+            if y else (),
             ffn_layout=(0,) * dense + (1,) * (n - dense),
             dense_width=int(c["intermediate_size"]),
             shared_width=int(c["n_shared_experts"])
@@ -570,12 +588,14 @@ class LMConfig:
             scoring="sigmoid_bias",
             routed_scale=float(c["routed_scaling_factor"]),
             bias_rate=float(c["router_bias_rate"]),
-            residual="mhc", hc_mult=int(c["hc_mult"]),
-            hc_iters=int(c["hc_sinkhorn_iters"]), hc_eps=float(c["hc_eps"]),
-            hc_clamp=(float(c["mhc_h_res_clamp_min"]),
-                      float(c["mhc_h_res_clamp_max"])),
             mtp_layers=int(c["num_nextn_predict_layers"]),
-            mtp_weight=float(c["mtp_loss_weight"]))
+            mtp_weight=float(c["mtp_loss_weight"]),
+            **(dict(residual="mhc", hc_mult=int(c["hc_mult"]),
+                    hc_iters=int(c["hc_sinkhorn_iters"]),
+                    hc_eps=float(c["hc_eps"]),
+                    hc_clamp=(float(c["mhc_h_res_clamp_min"]),
+                              float(c["mhc_h_res_clamp_max"])))
+               if streams else {}))
 
     @classmethod
     def _from_laguna(cls, c: dict) -> "LMConfig":
@@ -694,8 +714,9 @@ class LMConfig:
               and all((i in delta) != (i in full) for i in range(1, n + 1)),
               "only the block whose router scores by sigmoid in one group "
               "and renormalises its top-k, whose latent attention has no "
-              "query latent and no positions, and whose every layer is of "
-              "one of the two kinds, is written down here")
+              "query latent and no positions, whose every layer is of one "
+              "of the two kinds, and which holds no multi-token module, is "
+              "written down here")
         nope, rope = int(c["qk_nope_head_dim"]), int(c["qk_rope_head_dim"])
         return cls(
             hidden=int(c["hidden_size"]),

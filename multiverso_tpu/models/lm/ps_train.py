@@ -65,7 +65,8 @@ kind). Sequences go through a layer one at a time
 step.
 
 Monitors (each an ``mv:`` span in a trace): ``LM_STEP``,
-``LM_GET_PARAMS``, ``LM_ADD_GRADS``. Counters: ``LM_TOKENS`` (tokens
+``LM_GET_PARAMS``, ``LM_ADD_GRADS``, and around a multi-token module's
+Gets, three programs and Adds ``LM_MTP_STEP``. Counters: ``LM_TOKENS`` (tokens
 trained, ``B T``), ``LM_POSITIONS`` (positions through the layers: the
 same, or ``2 B T`` under block diffusion), ``LM_GET_BYTES`` and
 ``LM_ADD_BYTES`` (whole-table traffic) at once; ``LM_HELD_ASSIGNMENTS``
@@ -78,7 +79,8 @@ of every assignment, from the same count and ``model.experts_capacity``),
 which form ``model.attention_inputs`` took, ``model.attention_pass_name``),
 ``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
 sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
-``LM_EMBED_ROWS`` (distinct embedding rows) and
+``LM_MTP_TOKENS`` (positions a multi-token module predicted from: ``B T``
+a step that held one), ``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
 computed on the device and read at the start of the next step,
 which waits for the last one's programs anyway: one step is in flight
@@ -179,12 +181,12 @@ def forward_program(cfg: LMConfig, rope: int, window: int, seq_len: int,
     hold more (model.layer_stats). The streams' (``cfg.residual ==
     "mhc"``) takes and gives [B, n hidden, T], and its sparse layers give a
     fifth result, the bias's step (``bias_step``); so do the plain
-    residual's where an ``attention`` says the layer's kind of attention
-    (``LMConfig.attention_layout``) and the router chooses through a bias."""
+    residual's where the router chooses through a bias. ``attention`` says
+    the layer's kind of attention where it is a layer's to say
+    (``LMConfig.attention_layout``)."""
     rope, mask, pos = _kind(cfg, rope, window, seq_len)
 
-    biased = attention is not None and sparse \
-        and cfg.scoring == "sigmoid_bias"
+    biased = sparse and cfg.scoring == "sigmoid_bias"
 
     def forward(mats32, small, x):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
@@ -307,11 +309,12 @@ def module_programs(cfg: LMConfig):
         mats = {n: w.astype(BF16) for n, w in mats32.items()}
 
         def one(seq):
-            y, aux, _ = mtp.module_vjp(cfg, mats, small, *seq)
-            return (y,) + lm.layer_stats(cfg, 1, aux)
+            y, stats, _ = mtp.module_vjp(cfg, mats, small, *seq)
+            return (y,) + stats
 
         y, stats, ids = jax.lax.map(one, (xs, e_next))
-        return y, stats, mats, ids, bias_step(cfg, stats)
+        return y, stats, mats, ids, bias_step(
+            cfg, stats[:, :2 + cfg.n_experts])
 
     def mtp_head(head32, norm, y, targets):
         loss, dy, d_head, d_norm = lm.head_loss_and_grads(
@@ -448,9 +451,11 @@ class PSLMTrainer:
         # the multi-token module: its own tensors, then its sparse layer's
         self.module: Dict[str, object] = {}
         if cfg.mtp_layers:
-            CHECK(cfg.mtp_layers == 1 and cfg.residual == "mhc"
-                  and not self.diffusion, "one multi-token module, after a "
-                  "stack of streams, under the next-token objective")
+            CHECK(cfg.mtp_layers == 1 and not self.diffusion
+                  and (cfg.residual == "mhc" or cfg.one_ffn_input),
+                  "one multi-token module, after a stack of streams or of "
+                  "plain layers whose feed-forward reads one normed input, "
+                  "under the next-token objective")
             shapes = {**cfg.mtp_shapes(),
                       **cfg.layer_shapes(cfg.n_layers - 1)}
             self.module = {name: table(name, shape)
@@ -470,16 +475,26 @@ class PSLMTrainer:
         self._split = jax.jit(self._split_more if cfg.mtp_layers
                               else self._split_tokens)
         self.streams = cfg.residual == "mhc"
+        # the embedding's rows' way into the first layer and back out of
+        # it: the streams', or with a module on the plain residual the
+        # split from the next tokens' rows; None where the rows ARE the
+        # first layer's input
+        self._enter = self._enter_back = None
         if self.streams:
             self._enter = jax.jit(self._enter_streams)
             self._leave = jax.jit(self._leave_streams, donate_argnums=(0,))
             self._leave_back = jax.jit(self._leave_streams_back,
                                        donate_argnums=(0,))
+            self._enter_back = jax.jit(self._enter_streams_back,
+                                       donate_argnums=(0,))
+        elif self.module:
+            self._enter = jax.jit(self._enter_rows)
+            self._enter_back = jax.jit(self._enter_rows_back,
+                                       donate_argnums=(0,))
+        if self.streams or self.module:
             self._sum = jax.jit(
                 lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
                 donate_argnums=(0, 1))
-            self._enter_back = jax.jit(self._enter_streams_back,
-                                       donate_argnums=(0,))
         # which of a step's stats are a sparse layer's (the module's layer
         # last), and the rows of its experts' buffer (model.routed_experts)
         self._sparse = [cfg.sparse(i) for i in range(cfg.n_layers)] \
@@ -555,8 +570,25 @@ class PSLMTrainer:
             d_rows = streams.collapse(self.cfg, dx)
             if de_next is None:
                 return d_rows
-            return jnp.pad(d_rows, ((0, 0), (0, 1), (0, 0))) \
-                + jnp.pad(de_next, ((0, 0), (1, 0), (0, 0)))
+            return self._rows_back(d_rows, de_next)
+
+    # -- and, with a module, the plain residual's (under mv.lm.embed too) --------
+    def _enter_rows(self, rows):
+        """The embedding's rows [B, T+1, hidden] -> the first layer's input
+        [B, T, hidden] and the next tokens' rows."""
+        with jax.named_scope("mv.lm.embed"):
+            return rows[:, :self.T], rows[:, 1:]
+
+    def _enter_rows_back(self, dx, de_next):
+        with jax.named_scope("mv.lm.embed"):
+            return self._rows_back(dx, de_next)
+
+    @staticmethod
+    def _rows_back(d_rows, de_next):
+        """The rows' gradient [B, T+1, hidden]: position ``i``'s as the
+        first layer's input and as position ``i - 1``'s next token."""
+        return jnp.pad(d_rows, ((0, 0), (0, 1), (0, 0))) \
+            + jnp.pad(de_next, ((0, 0), (1, 0), (0, 0)))
 
     def noised(self, tokens):
         """Block diffusion's batch for ``tokens`` as the step about to run
@@ -649,7 +681,7 @@ class PSLMTrainer:
             ids, targets, weights, distinct, scored = self.prepare(tokens)
             with monitor("LM_GET_PARAMS"):
                 x = self.embedding.get_rows_device(ids)
-            if self.streams:
+            if self._enter:
                 x, e_next = _dispatch(self._enter, x)
             kinds = cfg.layer_kinds()
             kept, stats = [], []
@@ -705,7 +737,7 @@ class PSLMTrainer:
                 inner_losses += inner
                 self._push_layer(self.layers[i], {**d_mats, **d_small},
                                  bias_step)
-            if self.streams:    # and the next tokens' rows' gradient
+            if self._enter_back:    # and the next tokens' rows' gradient
                 dx = _dispatch(self._enter_back, dx,
                                de_next if self.module else None)
             with monitor("LM_ADD_GRADS"):
@@ -724,24 +756,27 @@ class PSLMTrainer:
         return loss
 
     def _module_step(self, head32, xs, e_next, targets, stats):
-        """The multi-token module from the summed streams ``xs`` to its
+        """The multi-token module from the last layer's stream ``xs`` [B,
+        T, hidden] (the summed streams where there are streams) to its
         Adds, all but the head's and the embedding's: ``(weighted second
         loss, dxs, the head's second gradient, the next tokens' rows'
         gradient)``. Its layer's counts join ``stats``."""
         forward, head, backward = self._module
-        mats32, small = self._pull_module()
-        with monitor("LM_GET_PARAMS"):
-            norm = self.module["final_norm"].get_device()
-        y, layer_stats, mats, _, bias_step = _dispatch(
-            forward, mats32, small, xs, e_next)
-        del mats32
-        stats.append(layer_stats)
-        loss, dy, d_head, d_norm = _dispatch(head, head32, norm, y, targets)
-        (dxs, de_next), d_mats, d_small = _dispatch(
-            backward, mats, small, xs, e_next, dy)
-        self._push_layer(self.module,
-                         {**d_mats, **d_small, "final_norm": d_norm},
-                         [bias_step])
+        with monitor("LM_MTP_STEP"):
+            mats32, small = self._pull_module()
+            with monitor("LM_GET_PARAMS"):
+                norm = self.module["final_norm"].get_device()
+            y, layer_stats, mats, _, bias_step = _dispatch(
+                forward, mats32, small, xs, e_next)
+            del mats32
+            stats.append(layer_stats)
+            loss, dy, d_head, d_norm = _dispatch(head, head32, norm, y,
+                                                 targets)
+            (dxs, de_next), d_mats, d_small = _dispatch(
+                backward, mats, small, xs, e_next, dy)
+            self._push_layer(self.module,
+                             {**d_mats, **d_small, "final_norm": d_norm},
+                             [bias_step])
         return loss, dxs, d_head, de_next
 
     def _count_stats(self, entry) -> None:

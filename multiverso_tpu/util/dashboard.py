@@ -279,6 +279,9 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",
     "LM_MTP_TOKENS": "positions the multi-token module predicted (a "
                      "trainer that holds the module)",
+    "LM_MTP_STEP": "PSLMTrainer._module_step: the multi-token module's "
+                   "Gets, three programs and Adds dispatched, inside "
+                   "LM_STEP",
     "LM_ROUTER_BIAS_ADDS": "plain Adds of a router bias's step, one a "
                            "sparse layer a step",
     "LM_ROUTER_LOAD_MAX": "the fullest router output's assignments over "

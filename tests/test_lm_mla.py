@@ -632,7 +632,7 @@ def test_the_module_equals_the_reference(float32_products):
     mats = {n: p[n] for n in names}
     small = {n: p[n] for n in p if n not in names and n != "final_norm"}
     with ref.PRECISION:
-        y, (ids, _, _), pull = mtp.module_vjp(CFG, mats, small, xs, e)
+        y, (_, ids), pull = mtp.module_vjp(CFG, mats, small, xs, e)
         dxs, de, d_mats, d_small = pull(dy)
         want_y, back = jax.vjp(
             lambda p, xs, e: ref.mtp(c, p, xs, e, ids), p, xs, e)

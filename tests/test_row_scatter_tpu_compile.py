@@ -339,6 +339,37 @@ def test_the_attention_kernel_compiles_at_both_of_laguna_s_kinds(
     assert compiled.memory_analysis().temp_size_in_bytes < 800e6
 
 
+# -- latent attention's kernel (models/lm/latent.py): q and k wider than v, or
+# all three of two lane tiles; each head a group of its own ----------------------
+
+@pytest.mark.parametrize("heads,qk,v,tokens", [
+    (4, 192, 128, 4096),        # xing4-29b-a4b-l5: 4 of 32 heads held
+    (32, 192, 128, 8192),       # kimi-linear-48b-a3b-l5's latent layer
+    (20, 256, 256, 8192)])      # glm47-flash-30b-a3b-l5: every head
+def test_latent_attention_s_kernel_compiles_at_the_published_lanes(
+        topo, heads, qk, v, tokens):
+    """Splash attention forward and backward for one sequence as
+    ``latent.core`` calls it: q [heads, 1, T, nope + rope], k [heads, T,
+    nope + rope], v [heads, T, v_head_dim], no lane padded."""
+    from multiverso_tpu.models.lm import model as lm
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+
+    def loss(q, k, v):
+        out = jax.vmap(lm._splash(tokens, 1, 0))(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shaped((heads, 1, tokens, qk), jnp.bfloat16, sharding=one),
+        shaped((heads, tokens, qk), jnp.bfloat16, sharding=one),
+        shaped((heads, tokens, v), jnp.bfloat16, sharding=one)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # no [heads, T, T] array (20 x 8192 x 8192 x 4 B would be 5.4 GB): the
+    # output and the three gradients' float32 beside their bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 16 * heads * tokens * (qk + v)
+
+
 # -- the pass between the attention's projections and its kernel -----------------
 # (models/lm/attn_kernels.py; interpreted against the chain in
 # tests/test_lm_attn_pass.py)
