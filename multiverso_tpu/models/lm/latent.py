@@ -33,6 +33,17 @@ for a v5e at both, tests/test_row_scatter_tpu_compile.py), each head a
 group of its own since its keys are its own; elsewhere
 ``blockwise_attention``. No lane is padded.
 
+**Between the products and the kernel.** On a TPU, at whole blocks of 512
+tokens, where ``nope + rope`` and ``v`` are each whole 128-lane tiles and the
+layer is turned (``pass_fused``: GLM's ``192 | 64 | 256``; heads of ``128 |
+64 | 128`` and a layer without positions are not), the turn of a head's last
+lanes and of the shared ``k_r``, the joins, the split of ``[k_n | v]``, the
+scale, the rounding and the kernel's layout are ONE Pallas pass each way
+(latent_kernels.py ``heads_in`` and its pull); everywhere else the
+``jax.numpy`` chain of ``inputs``, which is the definition
+(tests/test_lm_mla_pass.py). ``PSLMTrainer`` counts ``LM_ATTN_PASS_FUSED``
+or ``LM_ATTN_PASS_PLAIN`` a layer a sequence by ``pass_name``.
+
 Scopes: ``mv.lm.attn.mla`` (projections, latent norms, rotary, ``W_o``),
 the attention proper under ``mv.lm.attn.mla.kernel``; the backward pass
 under the same names (``attention_vjp`` differentiates the three parts
@@ -74,6 +85,39 @@ def softmax_scale(cfg: LMConfig) -> float:
     return m * m / math.sqrt(cfg.head_dim)
 
 
+def _pass(cfg: LMConfig):
+    from . import latent_kernels
+    return latent_kernels.Pass(cfg.qk_nope_dim, cfg.qk_rope_dim,
+                               cfg.v_head_dim, softmax_scale(cfg), lm.BF16)
+
+
+def pass_fused(cfg: LMConfig, t: int, rope=True) -> bool:
+    """Whether ``inputs`` takes the one pass of latent_kernels.py for a
+    sequence of ``t`` positions through a layer that is turned or not
+    (``rope``): on a TPU, where its kernels fit (whole blocks of tokens,
+    ``nope + rope`` and ``v`` each whole lane tiles) and there is a turn
+    for them to do: without one the scale, the rounding and the layout go
+    into the products' own output fusions (``model.attention_pass_fused``).
+    Everywhere else, and where a caller has put its own ``_rotary`` in
+    model.py's place (the checks' controls do), it is the ``jax.numpy``
+    chain of ``inputs``, which is the definition the kernels are held
+    to."""
+    if not (rope and lm._rotary is lm._ROTARY
+            and jax.default_backend() == "tpu"):
+        return False
+    from . import latent_kernels        # Pallas, where it is wanted
+    return latent_kernels.fits(t, cfg.n_heads_held, _pass(cfg))
+
+
+def pass_name(cfg: LMConfig, t: int, rope=True) -> str:
+    """The counter that a sequence of ``t`` positions through one latent
+    layer (turned or not: ``rope``) adds one to: which form its ``inputs``
+    took (``PSLMTrainer._count_stats``; ``model.attention_pass_name`` is
+    the other attention's)."""
+    return "LM_ATTN_PASS_FUSED" if pass_fused(cfg, t, rope) \
+        else "LM_ATTN_PASS_PLAIN"
+
+
 def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None, rope=True):
     """The sublayer's norm, both low-rank projections with their norms
     (the query's one product ``h W_q`` where there is no query latent),
@@ -99,9 +143,20 @@ def inputs(cfg: LMConfig, mats, sinks, norms, u, pos=None, rope=True):
     c_kv = lm.rmsnorm(kv_a[:, :latent], g_kv, cfg.eps)
     kv = lm.mm(c_kv, mats["wkv_b"], sinks["wkv_b"]).reshape(
         t, heads, nope + cfg.v_head_dim)
-    if rope:    # YaRN's frequencies, or (None) ``rope_theta``'s own
-        inv = lm.yarn_frequencies(cfg.rope_theta, rope_dim, *cfg.yarn[:4]) \
-            if cfg.yarn else None
+    # YaRN's frequencies, or (None) ``rope_theta``'s own
+    inv = lm.yarn_frequencies(cfg.rope_theta, rope_dim, *cfg.yarn[:4]) \
+        if rope and cfg.yarn else None
+    if pass_fused(cfg, t, rope):
+        from . import latent_kernels
+        tables = tuple(jnp.asarray(table, lm.F32) for table in
+                       lm.rotary_tables(t, rope_dim, cfg.rope_theta, pos,
+                                        inv))
+        # the products' results as they left them (the two reshapes fold),
+        # ``kv`` rounded as the chain rounds it: in the product's own fusion
+        return latent_kernels.heads_in(
+            _pass(cfg), q.reshape(t, -1), kv.reshape(t, -1).astype(lm.BF16),
+            kv_a[:, latent:], tables)
+    if rope:
         q_r = lm._rotary(q[..., nope:], cfg.rope_theta, pos, inv)
         k_r = lm._rotary(kv_a[:, None, latent:], cfg.rope_theta, pos, inv)
     else:

@@ -1248,7 +1248,7 @@ def attention_pass_name(cfg: LMConfig, t: int, rope=True):
     """The counter that a sequence of ``t`` positions through one layer
     (turned or not: ``rope``) adds one to: which form its
     ``attention_inputs`` took (None under latent attention, which is
-    latent.py's and has neither)."""
+    latent.py's: ``latent.pass_name`` there)."""
     if cfg.attention == "mla":
         return None
     return "LM_ATTN_PASS_FUSED" if attention_pass_fused(cfg, t, rope) \

@@ -75,8 +75,9 @@ same, or ``2 B T`` under block diffusion), ``LM_GET_BYTES`` and
 layers), ``LM_EXPERTS_SHORT`` and ``LM_EXPERTS_FULL`` (one a sparse layer
 a sequence: whether its routed experts took the short buffer or the one
 of every assignment, from the same count and ``model.experts_capacity``),
-``LM_ATTN_PASS_FUSED`` or ``LM_ATTN_PASS_PLAIN`` (one a layer a sequence:
-which form ``model.attention_inputs`` took, ``model.attention_pass_name``),
+``LM_ATTN_PASS_FUSED`` or ``LM_ATTN_PASS_PLAIN`` (one a layer a sequence,
+the module's layer too: which form ``model.attention_inputs`` took,
+``model.attention_pass_name``, or ``latent.inputs``, ``latent.pass_name``),
 ``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
 sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
 ``LM_MTP_TOKENS`` (positions a multi-token module predicted from: ``B T``
@@ -105,7 +106,7 @@ from ...updater.rules import create_rule
 from ...util.dashboard import count, monitor
 from ...util.log import CHECK
 from . import model as lm
-from . import mtp, streams
+from . import latent, mtp, streams
 from .model import LMConfig
 
 BF16 = jnp.bfloat16
@@ -159,6 +160,23 @@ def _kind(cfg: LMConfig, rope: int, window: int, seq_len: int):
                                      (len(rotary.sections), 1))
     return rotary, mask, (
         mask.positions(2 * seq_len) if mask.kind == "blockdiff" else None)
+
+
+def attn_pass_names(cfg: LMConfig, positions: int, module: bool):
+    """A layer each, and with ``module`` the multi-token module's layer
+    last (it is of the last layer's kinds): the counter one sequence of
+    ``positions`` through it adds one to, by the form that the way from
+    its attention's products to the kernel took (None where there is none:
+    a delta layer)."""
+    def name(layer):
+        kind, rope = cfg.attention_of(layer), cfg.rope_layout[layer]
+        if kind == "mla":
+            return latent.pass_name(cfg, positions, rope)
+        return None if kind == "kda" \
+            else lm.attention_pass_name(cfg, positions, rope)
+
+    names = [name(layer) for layer in range(cfg.n_layers)]
+    return names + names[-1:] * bool(module)
 
 
 def bias_step(cfg: LMConfig, stats):
@@ -501,10 +519,9 @@ class PSLMTrainer:
             + [1] * bool(self.module)
         positions = self.T * (2 if self.diffusion else 1)
         self._experts_cap = lm.experts_capacity(cfg, positions)
-        # and which form each layer's ``model.attention_inputs`` takes of a
-        # sequence: the counter's name (None: latent attention has neither)
-        self._attn_pass = [lm.attention_pass_name(cfg, positions, rope)
-                           for rope in cfg.rope_layout]
+        # and which form each layer's ``model.attention_inputs`` (or
+        # ``latent.inputs``) takes of a sequence: the counter's name
+        self._attn_pass = attn_pass_names(cfg, positions, bool(self.module))
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)

@@ -257,7 +257,8 @@ METRIC_NAMES: Dict[str, str] = {
                        "took the buffer of every assignment",
     "LM_ATTN_PASS_FUSED": "layers' sequences whose way from the attention's "
                           "projections to its kernel and back was the one "
-                          "pass of models/lm/attn_kernels.py",
+                          "pass of models/lm/attn_kernels.py (latent "
+                          "attention: latent_kernels.py)",
     "LM_ATTN_PASS_PLAIN": "layers' sequences that took the jax.numpy "
                           "chain there (no TPU, no whole block of tokens "
                           "or tile of lanes, neither head norms nor a turn)",

@@ -574,8 +574,10 @@ def test_what_a_step_counts(run):
     assert delta("LM_ROUTER_BIAS_ADDS") == 2
     held = sum(int(s[:, 0].sum()) for s in run["stats"])
     assert delta("LM_HELD_ASSIGNMENTS") == held > 0
-    # latent attention has neither form of the grouped-query pass
-    assert not delta("LM_ATTN_PASS_FUSED") and not delta("LM_ATTN_PASS_PLAIN")
+    # one a latent layer a sequence, the module's layer too, by the form
+    # ``latent.inputs`` took: the CPU takes the jax.numpy chain
+    assert not delta("LM_ATTN_PASS_FUSED")
+    assert delta("LM_ATTN_PASS_PLAIN") == B * (CFG.n_layers + 1)
     tables = len(run["names"])
     # a Get and an Add a table, and the closing row Get
     assert delta("WORKER_PROCESS_GET") == tables + 1

@@ -606,5 +606,6 @@ def test_what_a_step_counts(run):
     # CPU takes the jax.numpy scan
     assert counted("LM_KDA_SCAN_PLAIN") == 3 * B
     assert counted("LM_KDA_SCAN_KERNEL") == 0
+    # the one latent layer's sequences: no turn, so ``latent.inputs``' chain
     assert counted("LM_ATTN_PASS_FUSED") == 0
-    assert counted("LM_ATTN_PASS_PLAIN") == 0
+    assert counted("LM_ATTN_PASS_PLAIN") == B

@@ -420,6 +420,44 @@ def test_the_attention_s_pass_compiles_at_the_published_head_counts(
         tokens * groups * (per_group + 2) * d)
 
 
+@pytest.mark.parametrize("tokens,heads", [(8192, 20), (1024, 4)])
+def test_the_latent_pass_compiles_at_glm_s_heads(topo, tokens, heads):
+    """``latent_kernels.heads_in`` and its pull as Mosaic takes them at
+    ``192 | 64 | 256`` lanes a head (``glm30b.ps-8k``: a ``kv`` head of 448
+    lanes starts mid-tile every second time, so a grid step takes a pair):
+    each a kernel, nothing beside it but the float32 that the pull's
+    bfloat16 is widened to for ``mm``'s rule, and no array of the heads'
+    size (interpreted against the chain in tests/test_lm_mla_pass.py)."""
+    from multiverso_tpu.models.lm import latent_kernels
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    how = latent_kernels.Pass(192, 64, 256, 256 ** -0.5, jnp.bfloat16)
+    assert latent_kernels.fits(tokens, heads, how) and how.together == 2
+    products = (shaped(tokens, heads * 256),
+                shaped(tokens, heads * 448, dtype=jnp.bfloat16),
+                shaped(tokens, 64))
+    tables = (shaped(tokens, 32),) * 2
+    forward = jax.jit(lambda *a: latent_kernels.heads_in(how, *a)).lower(
+        *products, tables).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    assert forward.memory_analysis().temp_size_in_bytes < 1e6
+    laid = tuple(shaped(*s, dtype=jnp.bfloat16) for s in (
+        (heads, 1, tokens, 256), (heads, tokens, 256), (heads, tokens, 256)))
+
+    def pull(qf, kvf, k_r, tables, cotangents):
+        return jax.vjp(lambda *a: latent_kernels.heads_in(how, *a, tables),
+                       qf, kvf, k_r)[1](cotangents)
+
+    backward = jax.jit(pull).lower(*products, tables, laid).compile()
+    assert backward.as_text().count("tpu_custom_call") == 1
+    # ``W_qb``'s product's cotangent in bfloat16, before it is widened
+    assert backward.memory_analysis().temp_size_in_bytes < 1.1 * 2 * (
+        tokens * heads * 256)
+
+
 # -- the delta rule's scan ------------------------------------------------------------
 # (models/lm/delta_kernels.py; interpreted against the plain scan and the
 # recurrence in tests/test_lm_kda_kernels.py)
