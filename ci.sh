@@ -191,13 +191,13 @@ echo "== server-fusion subset (mailbox drain / fused dispatch / fused == serial)
 # docs/SERVER_ENGINE.md).
 python -m pytest tests/test_server_fusion.py -x -q -m 'not slow'
 
-echo "== obs subset (tracing / metrics export / scrape surface) =="
-# Observability invariants get their own named gate: trace-id sampling
-# and wire propagation (TRACE_SLOT, byte-identity when off), the span
-# ring buffer + slow-request watchdog, snapshot/cluster aggregation +
-# Prometheus text exposition validity, the /metrics//trace.json HTTP
-# surface, and the 3-process TCP integration proof (cross-rank nested
-# Get trace; cluster SERVER_PROCESS_GET == sum of per-rank dumps).
+echo "== obs subset (wire header / metrics export / scrape surface) =="
+# Observability invariants get their own named gate: the ten-int header
+# (golden frame bytes; an older peer's nonzero slot 9 is ignored),
+# snapshot/cluster aggregation + Prometheus text exposition validity,
+# the /metrics HTTP surface, and the 3-process TCP integration proof
+# (cluster SERVER_PROCESS_GET == sum of per-rank dumps). The request's
+# mv: spans are tests/test_program_spans.py's.
 # docs/OBSERVABILITY.md.
 python -m pytest tests/test_observability.py -x -q -m 'not slow'
 

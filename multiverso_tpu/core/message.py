@@ -144,7 +144,7 @@ class MsgType(enum.IntEnum):
     Control_Shm_Announce = 44
 
 HEADER_SIZE = 10  # ints (8 in the reference; slot 8 added for
-#                   replication, slot 9 for request tracing)
+#                   replication; slot 9 is reserved and always 0)
 
 
 class Message:
@@ -235,14 +235,9 @@ class Message:
 
     def create_reply_message(self) -> "Message":
         """Reply with src/dst swapped and type negated (ref: message.h:51-59)."""
-        reply = Message(src=self.dst, dst=self.src,
-                        msg_type=MsgType(-self.header[2]),
-                        table_id=self.table_id, msg_id=self.msg_id)
-        # The reply leg belongs to the same sampled request: carrying
-        # the trace id back lets the requester's rank pair reply-side
-        # spans under one trace (0 = unsampled, the common case).
-        reply.header[TRACE_SLOT] = self.header[TRACE_SLOT]
-        return reply
+        return Message(src=self.dst, dst=self.src,
+                       msg_type=MsgType(-self.header[2]),
+                       table_id=self.table_id, msg_id=self.msg_id)
 
     def __repr__(self) -> str:
         return (f"Message(src={self.src}, dst={self.dst}, type={self.type.name}, "
@@ -315,7 +310,6 @@ WIRE_SLOTS: dict = {
     "CODEC_SLOT": 6,
     "VERSION_SLOT": 7,
     "REPLICA_SLOT": 8,
-    "TRACE_SLOT": 9,
 }
 
 assert ERROR_SLOT == WIRE_SLOTS["ERROR_SLOT"]
@@ -351,28 +345,10 @@ def replica_row_count(msg: "Message") -> int:
     return raw - 1 if raw > 0 else 0
 
 
-# Header slot 9 carries the DISTRIBUTED TRACE ID of a sampled request
-# (util/tracing.py, docs/OBSERVABILITY.md): 0 — the header default, and
-# the only value a -trace_sample_rate=0 build (or a pre-trace peer)
-# ever sends — means "unsampled"; a nonzero id is carried verbatim on
-# every shard/batch/reply message the request spawns so span events
-# recorded on different ranks pair under one trace. Growing the header
-# from 9 to 10 ints is a declared WIRE BREAK for mixed-build TCP
-# clusters (docs/WIRE_FORMAT.md), the same class as the PR-7 slot-8
-# bump.
-TRACE_SLOT = 9
-
-assert TRACE_SLOT == WIRE_SLOTS["TRACE_SLOT"]
-
-
-def stamp_trace(msg: "Message", trace_id: int) -> None:
-    msg.header[TRACE_SLOT] = int(trace_id)
-
-
-def trace_of(msg: "Message") -> int:
-    """The trace id a message carries (0 = unsampled / pre-trace
-    peer)."""
-    return int(msg.header[TRACE_SLOT])
+# Header slot 9 is RESERVED: this build always sends 0 there and never
+# reads it, so a nonzero value from an older peer (which carried a
+# sampled request's trace id in it) is ignored. The header keeps its
+# ten ints: the frames are what they were (docs/WIRE_FORMAT.md).
 
 
 def stamp_version(reply: "Message", version: int) -> None:
@@ -402,14 +378,6 @@ def pack_add_batch(subs: List["Message"]) -> "Message":
     first = subs[0]
     batch = Message(src=first.src, dst=first.dst,
                     msg_type=MsgType.Request_BatchAdd)
-    for sub in subs:
-        # The batch inherits the first SAMPLED sub's trace id: a trace
-        # that lands in a coalesced flush keeps its wire spans (the
-        # batch is that sub's wire message; sibling sampled subs are
-        # attributed by their own issue/reply spans).
-        if sub.header[TRACE_SLOT]:
-            batch.header[TRACE_SLOT] = sub.header[TRACE_SLOT]
-            break
     desc = [len(subs)]
     for sub in subs:
         desc.extend((sub.table_id, sub.msg_id, len(sub.data)))

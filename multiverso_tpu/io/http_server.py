@@ -2,7 +2,7 @@
 
 One ``ThreadingHTTPServer`` wrapper used by both HTTP frontends in the
 tree — the observability scrape surface (``io/metrics_http.py``:
-/metrics, /trace.json) and the online serving tier
+/metrics) and the online serving tier
 (``serving/frontend.py``: /v1/tables/...; docs/SERVING.md). Factoring
 it here keeps the two surfaces byte-for-byte consistent on the parts
 that are pure protocol: route dispatch, Content-Type/Content-Length

@@ -4,10 +4,7 @@ Serves the controller's cluster-aggregated metrics view
 (docs/OBSERVABILITY.md) on ``-metrics_port``:
 
 - ``GET /metrics`` — Prometheus text exposition format 0.0.4 (the
-  contract every Prometheus-compatible scraper speaks);
-- ``GET /trace.json`` — the merged Chrome-trace/Perfetto JSON of every
-  rank's shipped span events (load in ``chrome://tracing`` or
-  https://ui.perfetto.dev).
+  contract every Prometheus-compatible scraper speaks).
 
 The HTTP plumbing itself (ThreadingHTTPServer lifecycle, dispatch,
 404/500 handling) lives in the shared ``io/http_server.py`` base,
@@ -21,7 +18,6 @@ stays a leaf: renderers are plain callables injected by the runtime
 
 from __future__ import annotations
 
-import json
 from typing import Callable, Dict, Tuple
 
 from .http_server import HttpServer, Response
@@ -59,11 +55,4 @@ def prometheus_route(render: Callable[[], str]):
     def route():
         return ("text/plain; version=0.0.4; charset=utf-8",
                 render().encode())
-    return route
-
-
-def json_route(render: Callable[[], dict]):
-    def route():
-        return ("application/json; charset=utf-8",
-                json.dumps(render()).encode())
     return route

@@ -29,9 +29,8 @@ from ..core.blob import Blob
 from ..util import chaos
 from ..core.message import (PEER_LOST_MARK, Message, MsgType,
                             is_controller_bound, is_server_bound,
-                            is_wire_encoded, is_worker_bound, mark_error,
-                            trace_of)
-from ..util import log, tracing
+                            is_wire_encoded, is_worker_bound, mark_error)
+from ..util import log
 from ..util.configure import get_flag
 from ..util.wire_codec import (CAP_WIRE_CODEC, decode_message,
                                encode_message)
@@ -369,11 +368,6 @@ class Communicator:
                     self._zoo.route(name, copy)
             return
         if is_server_bound(msg_type):
-            # Hop marker for sampled requests: the gap between this
-            # enqueue and the server span's start is mailbox queue time
-            # in the merged trace.
-            tracing.event(trace_of(msg), "server_mailbox_enqueue",
-                          self._zoo.rank)
             try:
                 self._zoo.route(actors.SERVER, msg)
             except RuntimeError as exc:

@@ -3,10 +3,9 @@
 The read side of the observability layer (docs/OBSERVABILITY.md). Each
 rank runs a ``MetricsReporter`` thread (enabled by
 ``-metrics_interval_s``) that serializes its ``Dashboard``/``Samples``
-registries (``util.dashboard.metrics_snapshot``) plus the trace events
-recorded since its last report (``util.tracing.drain_since``) into a
-JSON blob and ships it to the controller as a fire-and-forget
-``Control_Metrics`` message. Remote ranks send via ``net.send_async``
+registries (``util.dashboard.metrics_snapshot``) into a JSON blob and
+ships it to the controller as a fire-and-forget ``Control_Metrics``
+message. Remote ranks send via ``net.send_async``
 — the same non-blocking path the liveness heartbeats take, for the
 same reason: the communicator's dispatch thread can park in a
 connect-retry toward a dead peer, and a metrics report queued behind
@@ -15,15 +14,12 @@ that would stall (and, worse, add to the backlog).
 The controller folds every report into a ``ClusterMetrics`` view:
 per-rank and summed monitor counters, cluster percentiles merged from
 the raw sample windows each report carries (summary snapshots cannot
-be merged; windows can), and one bounded merged trace-event buffer.
-``io/metrics_http.py`` serves that view as ``/metrics`` (Prometheus
-text exposition) and ``/trace.json`` (Chrome-trace JSON) on
-``-metrics_port``.
+be merged; windows can). ``io/metrics_http.py`` serves that view as
+``/metrics`` (Prometheus text exposition) on ``-metrics_port``.
 """
 
 from __future__ import annotations
 
-import collections
 import json
 import os
 import threading
@@ -34,7 +30,7 @@ import numpy as np
 
 from ..core.blob import Blob
 from ..core.message import Message, MsgType
-from ..util import log, tracing
+from ..util import log
 from ..util.configure import define_double, define_int, get_flag
 from ..util.dashboard import (METRICS_SNAPSHOT_VERSION, Samples, count,
                               metrics_snapshot)
@@ -42,21 +38,15 @@ from ..util.lock_witness import named_condition, named_lock
 from . import thread_roles
 
 define_double("metrics_interval_s", 0.0,
-              "ship this rank's Dashboard/Samples snapshot (+ new "
-              "trace events) to the controller as a Control_Metrics "
-              "message at this period, feeding the cluster-aggregated "
-              "/metrics and /trace.json scrape surfaces "
+              "ship this rank's Dashboard/Samples snapshot to the "
+              "controller as a Control_Metrics message at this period, "
+              "feeding the cluster-aggregated /metrics scrape surface "
               "(docs/OBSERVABILITY.md). 0 (default) disables the "
               "reporter; per-rank registries still accumulate locally")
 define_int("metrics_port", 0,
            "serve /metrics (Prometheus text exposition, cluster "
-           "aggregate) and /trace.json (merged Chrome trace) over "
-           "HTTP on this port ON THE CONTROLLER RANK "
+           "aggregate) over HTTP on this port ON THE CONTROLLER RANK "
            "(io/metrics_http.py). 0 (default) = no scrape surface")
-
-#: Merged trace events the controller retains (newest win) — a
-#: multiple of the per-rank ring so a short cluster's full windows fit.
-MERGED_TRACE_CAP = 32768
 
 
 class MetricsReporter:
@@ -70,11 +60,9 @@ class MetricsReporter:
         self._stopped = False  # guarded_by: _stop_cond
         self._thread: Optional[threading.Thread] = None
         # flush() runs on app threads while the reporter thread ticks:
-        # serializing reports keeps _sent_seq consistent (a racing pair
-        # would ship the same trace events twice).
+        # serializing reports keeps _report_seq in send order.
         self._report_lock = named_lock(
             f"metrics_reporter[r{zoo.rank}].report")
-        self._sent_seq = 0  # guarded_by: _report_lock
         # Report ordering guard: every report carries this reporter
         # INCARNATION (unique per reporter lifetime — a restarted/
         # rejoined rank gets a fresh one) plus a monotonic sequence,
@@ -127,10 +115,8 @@ class MetricsReporter:
         try:
             from . import actor as actors
             from .zoo import CONTROLLER_RANK
-            events = tracing.drain_since(self._sent_seq)
             payload = metrics_snapshot()
             payload["rank"] = self._zoo.rank
-            payload["trace_events"] = events
             self._report_seq += 1
             payload["inc"] = self._incarnation
             payload["seq"] = self._report_seq
@@ -148,12 +134,10 @@ class MetricsReporter:
                 # communicator's dispatch thread can park toward a dead
                 # peer, and this thread must never block on the wire.
                 self._zoo.net.send_async(msg)
-            if events:
-                self._sent_seq = max(e["seq"] for e in events)
             count("METRICS_REPORT")
         except Exception as exc:  # noqa: BLE001 - a failed report is a
             # lost sample, never a crashed reporter (the next tick
-            # retries; drain_since re-sends undelivered events).
+            # retries).
             log.debug("rank %d: metrics report failed: %s",
                       self._zoo.rank, exc)
 
@@ -201,8 +185,6 @@ class ClusterMetrics:
         self._lock = named_lock("cluster_metrics")
         # rank -> latest snapshot
         self._ranks: Dict[int, Dict] = {}  # guarded_by: _lock
-        self._trace: collections.deque = collections.deque(  # guarded_by: _lock
-            maxlen=MERGED_TRACE_CAP)
         # Per-rank report-ordering watermark: (incarnation, seq) of
         # the newest report folded in. A report whose seq does not
         # advance WITHIN the same incarnation is out-of-order or stale
@@ -228,7 +210,6 @@ class ClusterMetrics:
 
     def ingest(self, payload: Dict) -> None:
         rank = int(payload.get("rank", -1))
-        events = payload.get("trace_events") or []
         inc = payload.get("inc")
         seq = payload.get("seq")
         dropped = False
@@ -259,7 +240,6 @@ class ClusterMetrics:
                     "monitors": dict(payload.get("monitors") or {}),
                     "samples": dict(payload.get("samples") or {}),
                 }
-                self._trace.extend(events)
         if dropped:
             log.debug("cluster metrics: dropped stale/out-of-order "
                       "report from rank %d (seq %s)", rank, seq)
@@ -378,11 +358,3 @@ class ClusterMetrics:
             lines.append(f'mv_cluster_samples_count{{{label}}} '
                          f'{_fmt(int(snap.get("count", 0)))}')
         return "\n".join(lines) + "\n"
-
-    def chrome_trace_json(self) -> Dict:
-        """Merged Chrome-trace JSON of every rank's shipped span
-        events (plus nothing else: the controller's own events arrive
-        through its local reporter like any rank's)."""
-        with self._lock:
-            events = list(self._trace)
-        return tracing.chrome_trace([events])

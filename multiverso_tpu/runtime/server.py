@@ -21,9 +21,9 @@ import numpy as np
 
 from ..core.blob import Blob
 from ..core.message import (PEER_LOST_MARK, Message, MsgType, mark_error,
-                            mark_replica_reply, stamp_trace,
-                            stamp_version, trace_of, unpack_add_batch)
-from ..util import log, mt_queue, tracing
+                            mark_replica_reply, stamp_version,
+                            unpack_add_batch)
+from ..util import log, mt_queue
 from ..util.configure import define_double, get_flag
 from ..util.dashboard import count, monitor, samples
 from . import actor as actors
@@ -445,10 +445,7 @@ class Server(Actor):
     # ref: src/server.cpp:36-46
     def _process_get(self, msg: Message) -> None:
         with monitor("SERVER_PROCESS_GET", msg_id=msg.msg_id,
-                     table=msg.table_id), \
-                tracing.span(trace_of(msg), "server_process_get",
-                             self._zoo.rank,
-                             args={"table": msg.table_id}):
+                     table=msg.table_id):
             reply = msg.create_reply_message()
             # The reply goes out even if table logic raises — a swallowed
             # reply would deadlock the requester's waiter forever — and a
@@ -476,9 +473,7 @@ class Server(Actor):
                     for out in outs:
                         self.send_to(actors.COMMUNICATOR, out)
                     return
-                with self._lock_for(table), \
-                        tracing.span(trace_of(msg), "table_op:get",
-                                     self._zoo.rank):
+                with self._lock_for(table):
                     reply.data = table.process_get(msg.data)
                     # Multi-zoo mode: the gather must finish before the
                     # lock releases, or its execution overlaps a sibling
@@ -658,18 +653,12 @@ class Server(Actor):
         moved rows ride the reply as a replica group attributed to
         THIS shard (core/message.py Request_FwdGet)."""
         with monitor("SERVER_PROCESS_GET", msg_id=msg.msg_id,
-                     table=msg.table_id), \
-                tracing.span(trace_of(msg), "server_process_fwd_get",
-                             self._zoo.rank,
-                             args={"table": msg.table_id}):
+                     table=msg.table_id):
             src_rank = int(msg.data[0].as_array(np.int64)[0]) \
                 if msg.data else msg.src
             reply = Message(src=src_rank, dst=msg.src,
                             msg_type=MsgType.Reply_Get,
                             table_id=msg.table_id, msg_id=msg.msg_id)
-            tid = trace_of(msg)
-            if tid:
-                stamp_trace(reply, tid)
             try:
                 table = self._table(msg.table_id)
                 with self._lock_for(table):
@@ -701,10 +690,7 @@ class Server(Actor):
         generation-regression guard spuriously). msg_id < 0 marks a
         secondary-window forward: applied, never acked."""
         with monitor("SERVER_PROCESS_ADD", msg_id=msg.msg_id,
-                     table=msg.table_id), \
-                tracing.span(trace_of(msg), "server_process_fwd_add",
-                             self._zoo.rank,
-                             args={"table": msg.table_id}):
+                     table=msg.table_id):
             src_rank = int(msg.data[0].as_array(np.int64)[0]) \
                 if msg.data else msg.src
             reply = None
@@ -713,9 +699,6 @@ class Server(Actor):
                                 msg_type=MsgType.Reply_Add,
                                 table_id=msg.table_id,
                                 msg_id=msg.msg_id)
-                tid = trace_of(msg)
-                if tid:
-                    stamp_trace(reply, tid)
             try:
                 table = self._table(msg.table_id)
                 with self._lock_for(table):
@@ -734,10 +717,7 @@ class Server(Actor):
     # ref: src/server.cpp:48-58
     def _process_add(self, msg: Message) -> None:
         with monitor("SERVER_PROCESS_ADD", msg_id=msg.msg_id,
-                     table=msg.table_id), \
-                tracing.span(trace_of(msg), "server_process_add",
-                             self._zoo.rank,
-                             args={"table": msg.table_id}):
+                     table=msg.table_id):
             reply = msg.create_reply_message()
             silent = False
             try:
@@ -771,9 +751,7 @@ class Server(Actor):
                                 getattr(table, "_data", None))
                             table.version += 1
                     return
-                with self._lock_for(table), \
-                        tracing.span(trace_of(msg), "table_op:add",
-                                     self._zoo.rank):
+                with self._lock_for(table):
                     table.process_add(msg.data)
                     # Multi-zoo mode: the update program (new table
                     # state) must land before the lock releases.
@@ -811,9 +789,7 @@ class Server(Actor):
         _process_get/_process_add above) — so a batch whose payload
         blobs fail to unpack still acks each sub the descriptor names,
         all marked failed."""
-        with monitor("SERVER_PROCESS_BATCH_ADD"), \
-                tracing.span(trace_of(msg), "server_process_batch_add",
-                             self._zoo.rank):
+        with monitor("SERVER_PROCESS_BATCH_ADD"):
             reply = msg.create_reply_message()
             desc: List[int] = [0]
             err_blobs: List[Blob] = []

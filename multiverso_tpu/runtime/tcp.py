@@ -58,8 +58,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core.blob import Blob
-from ..core.message import HEADER_SIZE, Message, trace_of
-from ..util import chaos, log, tracing
+from ..core.message import HEADER_SIZE, Message
+from ..util import chaos, log
 from ..util.buffer_pool import BufferPool
 from ..util.configure import (define_double, define_int, define_string,
                               get_flag)
@@ -586,7 +586,6 @@ class _Conn:
         self._lease = None  # pooled frame lease
         self._body: Optional[memoryview] = None  # fill target
         self._body_got = 0
-        self._t0_ns = 0
         self.peer: Optional[int] = None  # rank learned from frames
 
     def on_conn_io(self, mask: int) -> None:
@@ -617,7 +616,6 @@ class _Conn:
                     self._close(clean=True)
                     return
                 self._total = total
-                self._t0_ns = tracing.now_ns()
                 self._lease = self._net._pool.lease(total)
                 self._body = self._lease.view(total)
                 self._body_got = 0
@@ -640,13 +638,6 @@ class _Conn:
         self._total = 0
         with monitor("tcp_deserialize"):
             msg = _deserialize_frame(lease.view(total), lease)
-        tid = trace_of(msg)
-        if tid:
-            # The trace id is only known after the parse; the span
-            # still covers the read+deserialize window.
-            tracing.add_span(tid, "tcp_recv", self._net.rank,
-                             self._t0_ns, tracing.now_ns() - self._t0_ns,
-                             args={"bytes": total})
         # Every inbound frame names its sender; remembering it lets a
         # dirty close report WHICH peer died (the zoo's rejoin path
         # fails only that rank's in-flight requests instead of aborting
@@ -1135,16 +1126,11 @@ class TcpNet(NetInterface):
         dst = msg.dst
         if not 0 <= dst < self.size:
             raise ValueError(f"bad dst rank {dst}")
-        tid = trace_of(msg)
-        with monitor("tcp_serialize"), \
-                tracing.span(tid, "tcp_serialize", self._rank):
+        with monitor("tcp_serialize"):
             views, nbytes = serialize_views(msg)
-        with tracing.span(tid, "tcp_send", self._rank,
-                          args={"dst": dst, "bytes": nbytes}
-                          if tid else None):
-            peer = self._peer(dst)
-            peer.submit(views, nbytes)
-            peer.flush(timeout=60.0)
+        peer = self._peer(dst)
+        peer.submit(views, nbytes)
+        peer.flush(timeout=60.0)
         return nbytes
 
     def send_async(self, msg: Message) -> int:
@@ -1169,16 +1155,8 @@ class TcpNet(NetInterface):
 
     def _send_async_real(self, msg: Message) -> int:
         dst = msg.dst
-        tid = trace_of(msg)
-        with monitor("tcp_serialize"), \
-                tracing.span(tid, "tcp_serialize", self._rank):
+        with monitor("tcp_serialize"):
             views, nbytes = serialize_views(msg)
-        if tid:
-            # The actual socket write happens on the event loop, which
-            # only sees bytes — the submit marker is the async path's
-            # wire hop for sampled traces.
-            tracing.event(tid, "tcp_send_async_submit", self._rank,
-                          args={"dst": dst, "bytes": nbytes})
         self._peer(dst).submit(views, nbytes)
         return nbytes
 

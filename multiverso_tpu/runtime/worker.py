@@ -27,9 +27,8 @@ import numpy as np
 
 from ..core.message import (PEER_LOST_MARK, Message, MsgType,
                             pack_add_batch, replica_row_count,
-                            reply_version, stamp_trace, take_error,
-                            trace_of)
-from ..util import mt_queue, tracing
+                            reply_version, take_error)
+from ..util import mt_queue
 from ..util.configure import (define_bool, define_double, define_int,
                               get_flag, register_tunable_hook)
 from ..util.dashboard import count as count_event
@@ -282,16 +281,11 @@ class Worker(Actor):
         table.reset(msg.msg_id,
                     num_servers if pad_sync else len(partitions))
         targets = range(num_servers) if pad_sync else partitions.keys()
-        tid = trace_of(msg)
         for server_id in targets:
             dst = self._zoo.server_rank(server_id)
             shard = Message(src=self._zoo.rank, dst=dst,
                             msg_type=msg_type,
                             table_id=msg.table_id, msg_id=msg.msg_id)
-            if tid:
-                # Every shard of a sampled request carries the trace id
-                # on the wire so the serving rank's spans pair with it.
-                stamp_trace(shard, tid)
             blobs = partitions.get(server_id)
             if blobs is not None:
                 shard.data = list(blobs)
@@ -334,9 +328,6 @@ class Worker(Actor):
             return
         with monitor("WORKER_COALESCE_FLUSH"):
             batch = pack_add_batch(staged)
-            tracing.event(trace_of(batch), "coalesce_flush",
-                          self._zoo.rank,
-                          args={"batched": len(staged), "dst": dst})
             self.send_to(actors.COMMUNICATOR, batch)
 
     def _reply_server_id(self, msg: Message) -> int:
@@ -450,9 +441,7 @@ class Worker(Actor):
                     # holding the lock across that wait starves the
                     # producing side.
                     with monitor("WORKER_REPLY_GET", msg_id=msg.msg_id,
-                                 table=msg.table_id), \
-                            tracing.span(trace_of(msg), "reply_handle:get",
-                                         self._zoo.rank):
+                                 table=msg.table_id):
                         table.process_reply_get(msg.data)
                 finally:
                     table._end_reply()
@@ -463,9 +452,6 @@ class Worker(Actor):
             raise
         finally:
             if not handoff:
-                tracing.event(trace_of(msg), "waiter_notify",
-                              self._zoo.rank,
-                              args={"from": msg.src})
                 table.notify(msg.msg_id)
 
     def _send_repairs(self, table, msg: Message) -> bool:
@@ -507,8 +493,6 @@ class Worker(Actor):
             error = take_error(msg)
             if error is not None:
                 table.fail(msg.msg_id, error, count=False)
-            tracing.event(trace_of(msg), "waiter_notify", self._zoo.rank,
-                          args={"from": msg.src})
             table.notify(msg.msg_id)
 
     def _process_reply_batch_add(self, msg: Message) -> None:
@@ -550,9 +534,6 @@ class Worker(Actor):
         err_blobs = msg.data[1:]
         err_idx = 0
         server_id = self._reply_server_id(msg)
-        tracing.event(trace_of(msg), "waiter_notify:batch",
-                      self._zoo.rank,
-                      args={"from": msg.src, "subs": int(desc[0])})
         for i in range(int(desc[0])):
             table_id, msg_id, failed, version = (
                 int(v) for v in desc[1 + 4 * i:5 + 4 * i])

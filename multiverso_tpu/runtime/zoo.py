@@ -215,15 +215,12 @@ class Zoo:
         if port > 0 and self.rank == CONTROLLER_RANK \
                 and controller is not None:
             from ..io.metrics_http import (MetricsHttpServer,
-                                           json_route,
                                            prometheus_route)
             self._metrics_http = MetricsHttpServer(port, {
                 "/metrics": prometheus_route(
                     lambda c=controller:
                     c.metrics.prometheus_text()
                     + c.autotune.prometheus_text()),
-                "/trace.json": json_route(
-                    controller.metrics.chrome_trace_json),
             })
 
     def metrics_flush(self) -> None:

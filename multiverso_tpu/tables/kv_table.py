@@ -34,8 +34,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core.blob import Blob
-from ..core.message import (PEER_LOST_MARK, Message, MsgType,
-                            stamp_trace, trace_of)
+from ..core.message import PEER_LOST_MARK, Message, MsgType
 from ..runtime import shard_map as shard_map_mod
 from ..util import chaos, log
 from ..util.dashboard import count as count_event
@@ -499,9 +498,6 @@ class KVServer(shard_map_mod.ElasticServerMixin, ServerTable):
         fwd = Message(src=msg.src, dst=int(dst_rank[mask][0]),
                       msg_type=MsgType.Request_FwdGet,
                       table_id=self.table_id, msg_id=msg.msg_id)
-        tid = trace_of(msg)
-        if tid:
-            stamp_trace(fwd, tid)
         fwd.push(Blob(meta))
         fwd.push(Blob(np.ascontiguousarray(keys[mask]).view(np.uint8)))
         fwd.push(Blob(pig_keys.view(np.uint8)))
@@ -566,9 +562,6 @@ class KVServer(shard_map_mod.ElasticServerMixin, ServerTable):
                           msg_type=MsgType.Request_FwdAdd,
                           table_id=self.table_id,
                           msg_id=msg.msg_id if first else -1)
-            tid = trace_of(msg)
-            if tid:
-                stamp_trace(fwd, tid)
             fwd.push(Blob(np.asarray([self._zoo.rank], dtype=np.int64)))
             fwd.push(Blob(np.ascontiguousarray(keys[m]).view(np.uint8)))
             fwd.push(Blob(np.ascontiguousarray(values[m])

@@ -33,11 +33,11 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 from ..core.blob import Blob
-from ..core.message import PEER_LOST_MARK, Message, MsgType, stamp_trace
+from ..core.message import PEER_LOST_MARK, Message, MsgType
 from ..runtime import actor as actors
 from ..runtime.net import PeerLostError
 from ..runtime.zoo import current_zoo
-from ..util import log, tracing
+from ..util import log
 from ..util.configure import get_flag
 from ..util.dashboard import Dashboard, monitor
 from ..util.lock_witness import named_lock
@@ -211,11 +211,6 @@ class WorkerTable:
         # actor around ``partition``): replica-routing tables key their
         # per-request routing bookkeeping by it.
         self._partition_msg_id = -1
-        # Sampled requests' open ROOT spans: msg_id -> (trace id, issue
-        # timestamp, span name). Written on the requester thread at
-        # issue, popped on the worker actor thread at completion —
-        # plain dict ops, GIL-atomic (util/tracing.py).
-        self._trace_open: Dict[int, tuple] = {}
 
     # -- public sync API (ref: src/table.cpp:29-38) --
     def get_raw(self, keys: Blob, extra: Sequence[Blob] = ()) -> None:
@@ -314,18 +309,6 @@ class WorkerTable:
         and sends later (possibly from a completion callback)."""
         msg = Message(src=self._zoo.rank, dst=-1, msg_type=msg_type,
                       table_id=self.table_id, msg_id=msg_id)
-        # Distributed-trace sampling happens HERE, at request issue
-        # (util/tracing.py): the id rides TRACE_SLOT on this message
-        # and every shard/batch/reply it spawns, and the ROOT span
-        # (worker issue -> waiter completion) opens now and closes in
-        # ``_complete_if_done``. 0 (the default-off common case) skips
-        # all bookkeeping.
-        tid = tracing.new_trace(self._zoo.rank)
-        if tid:
-            stamp_trace(msg, tid)
-            self._trace_open[msg_id] = (
-                tid, tracing.now_ns(),
-                f"worker_issue:{msg_type.name}[t{self.table_id}]")
         for blob in blobs:
             msg.push(blob)
         self._zoo.send_to(actors.WORKER, msg)
@@ -431,8 +414,6 @@ class WorkerTable:
         request to a dead rank blocks forever; the reference has no
         failure detection at all, SURVEY.md section 5.3)."""
         self._abort_reason = reason
-        self._trace_open.clear()  # roots of aborted requests never
-        # complete; dropping them keeps the dict bounded
         self._sinks.clear()  # nor will their replies be placed
         with self._mutex:
             waiters = list(self._waitings.values())
@@ -490,15 +471,6 @@ class WorkerTable:
     def _complete_if_done(self, msg_id: int, waiter: Waiter) -> None:
         if not waiter.done:
             return
-        opened = self._trace_open.pop(msg_id, None)
-        if opened is not None:
-            tid, t0_ns, name = opened
-            # Root span closure + the -trace_slow_ms watchdog: the
-            # request's whole issue-to-completion window, enveloping
-            # every hop span the shards recorded.
-            tracing.end_root(tid, name, self._zoo.rank, t0_ns,
-                             args={"table": self.table_id,
-                                   "msg_id": msg_id})
         self._retire(msg_id, waiter)
 
     def _retire(self, msg_id: int, waiter: Optional[Waiter] = None) -> None:

@@ -105,11 +105,8 @@ CANONICAL_FLAGS: Dict[str, Any] = {
     #    runtime/thread_roles.py) --
     "debug_locks": False,
     "role_block_budget_ms": 250.0,
-    # -- observability (util/tracing.py, runtime/metrics.py,
-    #    io/metrics_http.py; docs/OBSERVABILITY.md) --
-    "trace_sample_rate": 0.0,
-    "trace_slow_ms": 0.0,
-    "trace_buffer": 4096,
+    # -- observability (runtime/metrics.py, io/metrics_http.py;
+    #    docs/OBSERVABILITY.md) --
     "metrics_interval_s": 0.0,
     "metrics_port": 0,
     # -- closed-loop self-tuning (runtime/autotune.py;

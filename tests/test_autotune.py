@@ -552,7 +552,7 @@ class TestLiveRetune:
 def _report(rank, seq, inc="inc-a", value=1):
     return {"v": 1, "rank": rank, "inc": inc, "seq": seq,
             "monitors": {"X": {"count": value, "elapsed_ms": 0.0}},
-            "samples": {}, "trace_events": []}
+            "samples": {}}
 
 
 class TestIngestHardening:

@@ -1,13 +1,12 @@
 """Observability layer tests (docs/OBSERVABILITY.md).
 
-Distributed request tracing (util/tracing.py + the TRACE_SLOT wire
-plumbing), the metrics export/aggregation pipeline
+The wire header's reserved slot 9 (what an older, sampling peer sends
+there is ignored), the metrics export/aggregation pipeline
 (runtime/metrics.py), the HTTP scrape surface (io/metrics_http.py),
-and the PR's acceptance integration: a 3-process TCP PS cluster
-(1 worker + 2 servers) whose merged /trace.json shows one Get's spans
-crossing rank boundaries under one trace id, and whose /metrics
-scrape exposes cluster-aggregated SERVER_PROCESS_GET counts equal to
-the sum of the per-rank dumps.
+and the acceptance integration: a 3-process TCP PS cluster
+(1 worker + 2 servers) whose /metrics scrape exposes
+cluster-aggregated SERVER_PROCESS_GET counts equal to the sum of the
+per-rank dumps.
 """
 
 import json
@@ -23,18 +22,13 @@ import pytest
 import multiverso_tpu as mv
 from multiverso_tpu.core.blob import Blob
 from multiverso_tpu.core.message import (HEADER_SIZE, Message, MsgType,
-                                         TRACE_SLOT, WIRE_SLOTS,
-                                         pack_add_batch, stamp_trace,
-                                         trace_of)
+                                         WIRE_SLOTS)
 from multiverso_tpu.io.metrics_http import (MetricsHttpServer,
-                                            json_route,
                                             prometheus_route)
 from multiverso_tpu.runtime.metrics import (ClusterMetrics,
                                             parse_report,
                                             split_family)
 from multiverso_tpu.runtime.tcp import _serialize
-from multiverso_tpu.util import tracing
-from multiverso_tpu.util.configure import set_flag
 from multiverso_tpu.util.dashboard import (Dashboard, metrics_snapshot,
                                            reset_samples, samples)
 
@@ -43,149 +37,20 @@ from test_net_integration import run_cluster, write_machine_file
 
 @pytest.fixture(autouse=True)
 def _clean_registries():
-    tracing.reset()
     Dashboard.reset()
     reset_samples()
     yield
-    tracing.reset()
     Dashboard.reset()
     reset_samples()
 
 
 # ---------------------------------------------------------------------------
-# trace ids + sampling
-# ---------------------------------------------------------------------------
-
-class TestTraceIds:
-    def test_default_off_draws_nothing(self):
-        assert tracing.new_trace(rank=0) == 0
-        assert tracing.new_trace(rank=3) == 0
-        assert tracing.snapshot_events() == []
-
-    def test_full_sampling_ids_unique_and_rank_tagged(self):
-        set_flag("trace_sample_rate", 1.0)
-        ids = [tracing.new_trace(rank=5) for _ in range(100)]
-        assert all(i > 0 for i in ids)
-        assert len(set(ids)) == 100
-        assert all(tracing.trace_rank(i) == 5 for i in ids)
-        assert all(i < 2 ** 31 for i in ids)  # rides an int32 slot
-
-    def test_partial_sampling_is_a_subset(self):
-        set_flag("trace_sample_rate", 0.3)
-        drawn = sum(1 for _ in range(500)
-                    if tracing.new_trace(rank=0))
-        assert 0 < drawn < 500  # statistically certain at 0.3/500
-
-
-# ---------------------------------------------------------------------------
-# span recording + ring bound + watchdog
-# ---------------------------------------------------------------------------
-
-class TestSpanRecording:
-    def test_span_and_event_record(self):
-        with tracing.span(7, "table_op:get", rank=1,
-                          args={"table": 0}):
-            time.sleep(0.001)
-        tracing.event(7, "waiter_notify", rank=1)
-        events = tracing.snapshot_events()
-        assert [e["name"] for e in events] == ["table_op:get",
-                                              "waiter_notify"]
-        x, i = events
-        assert x["ph"] == "X" and x["dur"] >= 1_000_000  # >= 1ms in ns
-        assert x["args"] == {"table": 0}
-        assert i["ph"] == "i"
-        assert all(e["trace"] == 7 and e["rank"] == 1 for e in events)
-
-    def test_untraced_span_is_inert_and_shared(self):
-        a = tracing.span(0, "x", rank=0)
-        b = tracing.span(0, "y", rank=0)
-        assert a is b  # the shared null singleton: no per-call alloc
-        with a:
-            pass
-        tracing.event(0, "z", rank=0)
-        assert tracing.snapshot_events() == []
-
-    def test_ring_buffer_bounds_memory(self):
-        set_flag("trace_buffer", 32)
-        for k in range(100):
-            tracing.event(1, f"e{k}", rank=0)
-        events = tracing.snapshot_events()
-        assert len(events) == 32
-        # Newest retained: the last 32 of the 100.
-        assert events[0]["name"] == "e68"
-        assert events[-1]["name"] == "e99"
-
-    def test_drain_since_is_incremental(self):
-        tracing.event(1, "a", rank=0)
-        first = tracing.drain_since(0)
-        assert [e["name"] for e in first] == ["a"]
-        tracing.event(1, "b", rank=0)
-        fresh = tracing.drain_since(max(e["seq"] for e in first))
-        assert [e["name"] for e in fresh] == ["b"]
-
-    def test_slow_watchdog_logs_timeline(self, capsys):
-        set_flag("trace_slow_ms", 1.0)
-        t0 = tracing.now_ns()
-        tracing.event(9, "server_mailbox_enqueue", rank=1)
-        time.sleep(0.01)
-        tracing.end_root(9, "worker_issue:Request_Get[t0]", 0, t0)
-        err = capsys.readouterr().err
-        assert "slow request" in err
-        assert "worker_issue:Request_Get[t0]" in err
-        assert "server_mailbox_enqueue" in err
-
-    def test_fast_root_stays_quiet(self, capsys):
-        set_flag("trace_slow_ms", 10_000.0)
-        tracing.end_root(9, "worker_issue:Request_Get[t0]", 0,
-                         tracing.now_ns())
-        assert "slow request" not in capsys.readouterr().err
-
-
-# ---------------------------------------------------------------------------
-# chrome trace export schema
-# ---------------------------------------------------------------------------
-
-def validate_chrome_trace(doc):
-    """Schema check for the merged Chrome-trace JSON (the acceptance
-    test loads /trace.json through this)."""
-    assert isinstance(doc, dict)
-    assert isinstance(doc["traceEvents"], list)
-    for e in doc["traceEvents"]:
-        assert isinstance(e["name"], str) and e["name"]
-        assert e["ph"] in ("X", "i")
-        assert isinstance(e["ts"], (int, float))
-        assert isinstance(e["pid"], int)
-        assert isinstance(e["tid"], str)
-        assert isinstance(e["args"]["trace"], int)
-        if e["ph"] == "X":
-            assert isinstance(e["dur"], (int, float)) and e["dur"] >= 0
-    return doc["traceEvents"]
-
-
-class TestChromeExport:
-    def test_export_schema_and_merge(self):
-        with tracing.span(3, "tcp_send", rank=0):
-            pass
-        rank0 = tracing.snapshot_events()
-        rank1 = [{"trace": 3, "name": "server_process_get", "ph": "X",
-                  "rank": 1, "ts": tracing.now_ns(), "dur": 500,
-                  "thread": "mv-server-r1", "seq": 1}]
-        doc = tracing.chrome_trace([rank0, rank1])
-        events = validate_chrome_trace(doc)
-        assert {e["pid"] for e in events} == {0, 1}
-        assert {e["args"]["trace"] for e in events} == {3}
-        # ns -> us conversion
-        assert events[0]["ts"] == pytest.approx(
-            min(rank0[0]["ts"], rank1[0]["ts"]) / 1e3)
-
-
-# ---------------------------------------------------------------------------
-# wire: TRACE_SLOT plumbing + byte identity at sample rate 0
+# wire: header slot 9 is reserved, the frames are what they were
 # ---------------------------------------------------------------------------
 
 def _serialize_9int(msg):
-    """What the pre-trace (9-int header) build put on the wire — the
-    reference layout the byte-identity acceptance compares against."""
+    """What the 9-int-header build put on the wire — the reference
+    layout the byte-identity acceptance compares against."""
     blobs = [b.wire_bytes().tobytes() for b in msg.data]
     legacy = msg.header[:9]  # mvlint: ignore[wire-slot] - the legacy
     # 9-int layout is exactly what this helper reconstructs
@@ -199,37 +64,17 @@ def _serialize_9int(msg):
 
 class TestWirePlumbing:
     def test_trace_slot_registered(self):
-        assert WIRE_SLOTS["TRACE_SLOT"] == TRACE_SLOT == 9
+        """The header keeps its ten ints; the tenth is reserved, in no
+        registry."""
         assert HEADER_SIZE == 10
-
-    def test_reply_carries_request_trace(self):
-        msg = Message(src=0, dst=1, msg_type=MsgType.Request_Get,
-                      table_id=2, msg_id=3)
-        stamp_trace(msg, 1234)
-        reply = msg.create_reply_message()
-        assert trace_of(reply) == 1234
-        untraced = Message(src=0, dst=1,
-                           msg_type=MsgType.Request_Get)
-        assert trace_of(untraced.create_reply_message()) == 0
-
-    def test_batch_inherits_first_sampled_sub(self):
-        subs = []
-        for k in range(3):
-            sub = Message(src=0, dst=1, msg_type=MsgType.Request_Add,
-                          table_id=k, msg_id=k)
-            sub.push(Blob(np.ones(2, np.float32)))
-            subs.append(sub)
-        stamp_trace(subs[1], 77)
-        batch = pack_add_batch(subs)
-        assert trace_of(batch) == 77
-        assert trace_of(pack_add_batch([subs[0], subs[2]])) == 0
+        assert sorted(WIRE_SLOTS.values()) == [5, 6, 7, 8]
 
     def test_untraced_wire_bytes_identical_modulo_header_bump(self):
-        """Acceptance: with -trace_sample_rate=0 (default) the wire
-        bytes of a Get/Add exchange are byte-identical to a pre-trace
-        build everywhere except the declared header-length bump — i.e.
-        the frame differs ONLY by four zero bytes of header slot 9 and
-        the total-length prefix that grows with them."""
+        """Acceptance: the wire bytes of a Get/Add exchange are
+        byte-identical to a 9-int-header build everywhere except the
+        declared header-length bump — i.e. the frame differs ONLY by
+        four zero bytes of header slot 9 and the total-length prefix
+        that grows with them."""
         for msg_type in (MsgType.Request_Get, MsgType.Request_Add):
             msg = Message(src=0, dst=1, msg_type=msg_type,
                           table_id=2, msg_id=3)
@@ -243,21 +88,79 @@ class TestWirePlumbing:
             (old_total,) = struct.unpack_from("<Q", old, 0)
             assert total == old_total + 4
             header = struct.unpack_from(f"<{HEADER_SIZE}i", frame, 8)
-            assert header[TRACE_SLOT] == 0
+            assert header[9] == 0
             # Splicing the 10th header int out reproduces the old
             # frame exactly, byte for byte.
             spliced = struct.pack("<Q", old_total) \
                 + frame[8:8 + 9 * 4] + frame[8 + 10 * 4:]
             assert spliced == old
 
-    def test_sampled_trace_id_survives_the_frame(self):
-        from multiverso_tpu.runtime.tcp import _deserialize
-        msg = Message(src=0, dst=1, msg_type=MsgType.Request_Get)
-        msg.push(Blob(np.ones(3, np.float32)))
-        stamp_trace(msg, 4242)
-        frame = _serialize(msg)
-        out = _deserialize(frame[8:])
-        assert trace_of(out) == 4242
+    @pytest.mark.parametrize("stamped", [
+        "Request_Get", "Request_Add", "Request_BatchAdd",
+        "Reply_Get", "Reply_Add"])
+    def test_an_older_peers_nonzero_slot_9_is_served_like_any_other(
+            self, stamped, monkeypatch):
+        """An older, sampling peer sends a request's trace id in header
+        slot 9. Over real TCP (rank 0 the worker, rank 1 the server)
+        every frame of one type goes out stamped as that peer would
+        send it: the frame deserialises, the traffic gives the sums it
+        gives unstamped, every waiter completes, and no other frame —
+        the stamped requests' replies among them — carries anything but
+        0 there."""
+        from multiverso_tpu.runtime import actor as actors
+        from multiverso_tpu.runtime import tcp
+        from multiverso_tpu.runtime.cluster import LocalCluster
+        from multiverso_tpu.util.net_util import free_listen_port
+        slot_9 = tcp._LEN.size + 9 * 4
+        seen = {}   # type -> the slot-9 values that arrived
+        serialize_views, deserialize_frame = (tcp.serialize_views,
+                                              tcp._deserialize_frame)
+
+        def views_of_an_older_peer(msg):
+            views, nbytes = serialize_views(msg)
+            if msg.type == MsgType[stamped]:
+                struct.pack_into("<i", views[0], slot_9, 4242)
+            return views, nbytes
+
+        def deserialize_and_note(body, lease):
+            msg = deserialize_frame(body, lease)
+            seen.setdefault(MsgType(msg.type).name, set()).add(
+                struct.unpack_from(f"<{HEADER_SIZE}i", body, 0)[9])
+            return msg
+
+        monkeypatch.setattr(tcp, "serialize_views", views_of_an_older_peer)
+        monkeypatch.setattr(tcp, "_deserialize_frame", deserialize_and_note)
+
+        def body(rank):
+            table = mv.create_matrix_table(16, 4)
+            got = None
+            if rank == 0:
+                ids = np.arange(16, dtype=np.int32)
+                ones = np.ones((16, 4), np.float32)
+                # A burst the worker's thread pops slowly is staged
+                # whole and leaves as one Request_BatchAdd; the Add
+                # that follows alone, as a plain Request_Add.
+                worker = mv.current_zoo()._actors[actors.WORKER]
+                popped = worker._popped
+                worker._popped = lambda m: (time.sleep(0.05), popped(m))
+                for msg_id in [table.add_rows_async(ids, ones)
+                               for _ in range(3)]:
+                    table.wait(msg_id)
+                table.add_rows(ids, ones)
+                del worker._popped
+                got = table.get_rows(ids)
+            mv.barrier()
+            return got
+
+        eps = [f"127.0.0.1:{free_listen_port()}" for _ in range(2)]
+        got, _ = LocalCluster(
+            2, roles=["worker", "server"],
+            nets=[tcp.TcpNet(r, eps) for r in range(2)]).run(body)
+        np.testing.assert_array_equal(got, np.full((16, 4), 4.0, np.float32))
+        assert {"Request_Get", "Request_Add", "Request_BatchAdd",
+                "Reply_Get", "Reply_Add", "Reply_BatchAdd"} <= set(seen)
+        assert seen.pop(stamped) == {4242}
+        assert set().union(*seen.values()) == {0}, seen
 
 
 # ---------------------------------------------------------------------------
@@ -290,15 +193,25 @@ def validate_prometheus(text):
     return series
 
 
-def _fake_report(rank, gets, window):
-    return {"v": 1, "rank": rank,
-            "monitors": {"SERVER_PROCESS_GET":
-                         {"count": gets, "elapsed_ms": gets * 1.5}},
-            "samples": {"DISPATCH_MS[d1]":
-                        {"count": len(window), "recent": window}},
-            "trace_events": [
-                {"trace": 5, "name": "server_process_get", "ph": "X",
-                 "rank": rank, "ts": 1000, "dur": 10, "seq": rank}]}
+def _fake_report(rank, gets, window, older_rank=False):
+    """A rank's report; an older rank's still carries the span events
+    it shipped for the merged trace, which the controller ignores."""
+    report = {"v": 1, "rank": rank,
+              "monitors": {"SERVER_PROCESS_GET":
+                           {"count": gets, "elapsed_ms": gets * 1.5}},
+              "samples": {"DISPATCH_MS[d1]":
+                          {"count": len(window), "recent": window}}}
+    if older_rank:
+        report["trace_events"] = [
+            {"trace": 5, "name": "server_process_get", "ph": "X",
+             "rank": rank, "ts": 1000, "dur": 10, "seq": rank}]
+    return report
+
+
+#: The cluster's snapshot is the same whether the ranks that report are
+#: of this build or still ship a ``trace_events`` key.
+OLDER_RANKS = pytest.mark.parametrize(
+    "older_rank", [False, True], ids=["this_build", "older_rank"])
 
 
 class TestClusterMetrics:
@@ -321,11 +234,13 @@ class TestClusterMetrics:
         assert parse_report(bad) is None
         assert parse_report(Message()) is None
 
-    def test_cluster_sum_and_merged_percentiles(self):
+    @OLDER_RANKS
+    def test_cluster_sum_and_merged_percentiles(self, older_rank):
         cm = ClusterMetrics()
-        cm.ingest(_fake_report(1, 30, [1.0, 2.0]))
-        cm.ingest(_fake_report(2, 12, [100.0, 200.0]))
-        cm.ingest(_fake_report(1, 31, [1.0, 2.0]))  # newest per rank wins
+        cm.ingest(_fake_report(1, 30, [1.0, 2.0], older_rank))
+        cm.ingest(_fake_report(2, 12, [100.0, 200.0], older_rank))
+        # newest per rank wins
+        cm.ingest(_fake_report(1, 31, [1.0, 2.0], older_rank))
         view = cm.cluster_view()
         agg = view["monitors_sum"]["SERVER_PROCESS_GET"]
         assert agg["count"] == 31 + 12
@@ -336,10 +251,11 @@ class TestClusterMetrics:
         assert view["ranks"][2]["monitors"][
             "SERVER_PROCESS_GET"]["count"] == 12
 
-    def test_prometheus_text_is_valid_and_sums(self):
+    @OLDER_RANKS
+    def test_prometheus_text_is_valid_and_sums(self, older_rank):
         cm = ClusterMetrics()
-        cm.ingest(_fake_report(1, 30, [1.0]))
-        cm.ingest(_fake_report(2, 12, [3.0]))
+        cm.ingest(_fake_report(1, 30, [1.0], older_rank))
+        cm.ingest(_fake_report(2, 12, [3.0], older_rank))
         series = validate_prometheus(cm.prometheus_text())
         name = 'name="SERVER_PROCESS_GET"'
         per_rank = [v for (metric, labels), v in series.items()
@@ -359,13 +275,6 @@ class TestClusterMetrics:
         assert split_family("SERVER_PROCESS_GET") \
             == ("SERVER_PROCESS_GET", "")
 
-    def test_merged_trace_feeds_chrome_export(self):
-        cm = ClusterMetrics()
-        cm.ingest(_fake_report(1, 1, []))
-        cm.ingest(_fake_report(2, 1, []))
-        events = validate_chrome_trace(cm.chrome_trace_json())
-        assert {e["pid"] for e in events} == {1, 2}
-
 
 # ---------------------------------------------------------------------------
 # HTTP scrape surface
@@ -375,8 +284,6 @@ class TestMetricsHttp:
     def test_routes_content_and_404(self):
         server = MetricsHttpServer(0, {
             "/metrics": prometheus_route(lambda: "mv_up 1\n"),
-            "/trace.json": json_route(
-                lambda: {"traceEvents": []}),
         }, host="127.0.0.1")
         try:
             base = f"http://127.0.0.1:{server.port}"
@@ -386,11 +293,6 @@ class TestMetricsHttp:
                 assert resp.headers["Content-Type"].startswith(
                     "text/plain; version=0.0.4")
                 assert resp.read() == b"mv_up 1\n"
-            with urllib.request.urlopen(f"{base}/trace.json",
-                                        timeout=10) as resp:
-                assert resp.headers["Content-Type"].startswith(
-                    "application/json")
-                assert json.loads(resp.read()) == {"traceEvents": []}
             with pytest.raises(urllib.error.HTTPError) as exc:
                 urllib.request.urlopen(f"{base}/nope", timeout=10)
             assert exc.value.code == 404
@@ -413,65 +315,24 @@ class TestMetricsHttp:
 
 
 # ---------------------------------------------------------------------------
-# in-process end to end: root span envelops the server-side spans
-# ---------------------------------------------------------------------------
-
-class TestInProcessEndToEnd:
-    def test_sampled_get_produces_nested_spans(self):
-        mv.init(["-trace_sample_rate=1.0"])
-        try:
-            table = mv.create_matrix_table(32, 4)
-            table.add_rows(np.arange(8, dtype=np.int32),
-                           np.ones((8, 4), np.float32))
-            table.get_rows(np.arange(8, dtype=np.int32))
-        finally:
-            mv.shutdown()
-        events = tracing.snapshot_events()
-        roots = [e for e in events
-                 if e["name"].startswith("worker_issue:Request_Get")]
-        assert roots, [e["name"] for e in events]
-        root = roots[-1]
-        nested = [e for e in events
-                  if e["trace"] == root["trace"]
-                  and e["name"] == "table_op:get"]
-        assert nested, [e["name"] for e in events]
-        for inner in nested:
-            assert root["ts"] <= inner["ts"]
-            assert inner["ts"] + inner["dur"] \
-                <= root["ts"] + root["dur"]
-
-    def test_default_rate_records_nothing(self):
-        mv.init([])
-        try:
-            table = mv.create_matrix_table(16, 4)
-            table.get_rows(np.arange(4, dtype=np.int32))
-        finally:
-            mv.shutdown()
-        assert tracing.snapshot_events() == []
-
-
-# ---------------------------------------------------------------------------
 # acceptance: 3-process TCP cluster (1 worker + 2 servers)
 # ---------------------------------------------------------------------------
 
-def test_three_process_trace_and_metrics_scrape(tmp_path):
-    """The PR's acceptance integration: full sampling + metrics export
-    over a real 3-process TCP cluster. The worker writes the /metrics
-    and /trace.json scrapes to files this process then validates:
-    (a) at least one Get's spans cross rank boundaries and nest under
-    one trace id; (b) the Prometheus scrape is valid text exposition
-    and its cluster-aggregated SERVER_PROCESS_GET equals the sum of
-    the per-rank dumps the servers print."""
+def test_three_process_metrics_scrape(tmp_path):
+    """The acceptance integration: metrics export over a real
+    3-process TCP cluster. The worker writes the /metrics scrape to a
+    file this process then validates: the Prometheus scrape is valid
+    text exposition and its cluster-aggregated SERVER_PROCESS_GET
+    equals the sum of the per-rank dumps the servers print."""
     from multiverso_tpu.util.net_util import free_listen_port
     n = 3
     mf, _ = write_machine_file(tmp_path, n)
     mport = free_listen_port()
-    trace_path = tmp_path / "trace.json"
     prom_path = tmp_path / "metrics.txt"
     common = f"""
 role = "worker" if rank == 0 else "server"
 mv.init(["-machine_file={mf}", "-rank=" + str(rank),
-         "-ps_role=" + role, "-trace_sample_rate=1.0",
+         "-ps_role=" + role,
          "-metrics_interval_s=0.2", "-metrics_port={mport}"])
 from multiverso_tpu.runtime.zoo import current_zoo
 from multiverso_tpu.util.dashboard import Dashboard
@@ -503,10 +364,7 @@ for _ in range(50):
         break
     prev = cur
     time.sleep(0.3)
-trace = urllib.request.urlopen(base + "/trace.json",
-                               timeout=10).read()
 open(r"{prom_path}", "wb").write(prom)
-open(r"{trace_path}", "wb").write(trace)
 mv.barrier()            # keep the scrape inside the cluster lifetime
 mv.shutdown()
 print("WORKER_OK")
@@ -528,7 +386,7 @@ print("SERVER_OK")
                 if m]
     assert len(per_rank) == 2 and all(c > 0 for c in per_rank), outs
 
-    # (b) valid Prometheus exposition; cluster aggregate == sum of the
+    # valid Prometheus exposition; cluster aggregate == sum of the
     # per-rank dumps, and the per-rank series match them too.
     series = validate_prometheus(prom_path.read_text())
     name = 'name="SERVER_PROCESS_GET"'
@@ -540,28 +398,3 @@ print("SERVER_OK")
         if metric == "mv_monitor_count_total" and name in labels
         and 'rank="0"' not in labels)
     assert scraped_ranks == sorted(float(c) for c in per_rank)
-
-    # (a) merged chrome trace: a Get whose spans cross rank boundaries
-    # and nest under one trace id (worker issue envelops the server
-    # span recorded on ANOTHER rank).
-    events = validate_chrome_trace(
-        json.loads(trace_path.read_text()))
-    by_trace = {}
-    for e in events:
-        by_trace.setdefault(e["args"]["trace"], []).append(e)
-    nested_cross_rank = 0
-    for tid, group in by_trace.items():
-        roots = [e for e in group
-                 if e["name"].startswith("worker_issue:Request_Get")]
-        if not roots:
-            continue
-        root = roots[0]
-        for e in group:
-            if (e["pid"] != root["pid"]
-                    and e["name"] == "server_process_get"
-                    and e["ts"] >= root["ts"]
-                    and e["ts"] + e["dur"]
-                    <= root["ts"] + root["dur"]):
-                nested_cross_rank += 1
-    assert nested_cross_rank > 0, (
-        f"no cross-rank nested Get trace among {len(by_trace)} traces")

@@ -253,86 +253,149 @@ def _host_spans(trace_dir):
     return spans
 
 
-def test_a_capture_around_one_get_holds_the_servers_span(tmp_path):
-    mv.init([])
-    try:
+#: The request forms the cells send, (Get, Add) of one MatrixTable: host
+#: row ids, device keys with a device delta, the whole table on the
+#: device.
+FORMS = {
+    "host_rows": (
+        lambda t, ids: t.get_rows(ids),
+        lambda t, ids: t.add_rows(ids, np.ones((IDS, COLS), np.float32))),
+    "device_keys": (
+        lambda t, ids: t.get_rows_device(jnp.asarray(ids)),
+        lambda t, ids: t.wait(t.add_rows_async(
+            jnp.asarray(ids), jnp.ones((IDS, COLS), jnp.float32)))),
+    "whole_table": (
+        lambda t, ids: t.get_device(),
+        lambda t, ids: t.wait(t.add_async(
+            jnp.ones((ROWS, COLS), jnp.float32)))),
+}
+
+#: (form, servers, -sync): every form in the one-process zoo, and the
+#: host rows over two servers' shards and under the BSP server.
+ONE_REQUEST = [pytest.param(form, 1, False, id=form) for form in FORMS] + [
+    pytest.param("host_rows", 2, False, id="host_rows-2servers"),
+    pytest.param("host_rows", 1, True, id="host_rows-sync"),
+    pytest.param("host_rows", 2, True, id="host_rows-2servers-sync")]
+
+
+def _capture_one(trace_dir, form, servers, sync, op):
+    """One Get (``op`` 0) or Add (1) of ``form`` inside a profiler
+    session, in a zoo of ``servers`` servers and one worker: ``(spans by
+    name, the request's msg_id, the table's id)``."""
+    from multiverso_tpu.runtime.cluster import LocalCluster
+    seen = {}
+
+    def body(rank):
         table = mv.create_matrix_table(ROWS, COLS)
-        ids = np.arange(IDS, dtype=np.int32)
-        out = np.empty((IDS, COLS), np.float32)
-        # no session open: the same calls raise nothing, record nothing
-        table.get_rows(ids, out)
-        table.add_rows(ids, np.ones((IDS, COLS), np.float32))
-        with trace_to(str(tmp_path)):
-            with monitor("caller_window"):  # mvlint: ignore[metric-name]
-                table.get_rows(ids, out)
-            msg_id = table._msg_id
-    finally:
-        mv.shutdown()
-    spans = _host_spans(str(tmp_path))
+        if rank == 0:
+            # rows of every server's shard
+            ids = np.arange(0, ROWS, ROWS // IDS, dtype=np.int32)
+            # no session open: the same calls raise nothing, record
+            # nothing
+            for call in FORMS[form]:
+                call(table, ids)
+            with trace_to(trace_dir):
+                with monitor("caller_window"):  # mvlint: ignore[metric-name]
+                    FORMS[form][op](table, ids)
+                seen.update(msg_id=table._msg_id, table=table.table_id)
+        mv.barrier()
+
+    LocalCluster(servers, argv=["-sync=true"] if sync else [],
+                 roles=["all"] + ["server"] * (servers - 1)).run(body)
     by_name = {}
-    for span in spans:
+    for span in _host_spans(trace_dir):
         by_name.setdefault(span[0], []).append(span)
+    return by_name, seen["msg_id"], seen["table"]
+
+
+def _hops_carry_the_request(by_name, kind, servers, msg_id, table_id):
+    """One worker span, one wait, and a server span and a reply span a
+    shard, every one under the request's ``msg_id`` and ``table``; the
+    issue names the table (the id is drawn inside it)."""
+    (_, _, _, issue_stats), = by_name[f"mv:CLIENT_ISSUE_{kind}"]
+    assert issue_stats == {"table": table_id}
+    for name, n in ((f"mv:WORKER_PROCESS_{kind}", 1),
+                    (f"mv:SERVER_PROCESS_{kind}", servers),
+                    (f"mv:WORKER_REPLY_{kind}", servers),
+                    ("mv:TABLE_WAIT", 1)):
+        assert [s[3] for s in by_name[name]] == [
+            {"msg_id": msg_id, "table": table_id}] * n, name
+
+
+def _each_inside_one(inner, outer):
+    return all(any(lo <= a <= b <= hi for _, lo, hi, _ in outer)
+               for _, a, b, _ in inner)
+
+
+@pytest.mark.parametrize("form, servers, sync", ONE_REQUEST)
+def test_a_capture_around_one_get_holds_the_servers_span(
+        tmp_path, form, servers, sync):
+    by_name, msg_id, table_id = _capture_one(
+        str(tmp_path), form, servers, sync, op=0)
     # only what ran inside the session is there: one Get, no Add
     assert "mv:SERVER_PROCESS_ADD" not in by_name
-    (_, lo, hi, _), = by_name["mv:caller_window"]
-    (_, start, end, stats), = by_name["mv:SERVER_PROCESS_GET"]
-    assert lo <= start <= end <= hi
-    assert stats["msg_id"] == msg_id and stats["table"] == table.table_id
-    # the caller's wait and the worker's reply handling lie in the same
-    # window, on threads of their own
+    _hops_carry_the_request(by_name, "GET", servers, msg_id, table_id)
+    window = by_name["mv:caller_window"]
+    assert len(window) == 1
+    # the caller's wait, the server's handling and the worker's reply
+    # handling lie in the same window, on threads of their own
     for name in ("mv:TABLE_WAIT", "mv:WORKER_PROCESS_GET",
-                 "mv:WORKER_REPLY_GET", "mv:BLOB_D2H",
-                 "mv:CLIENT_PLACE_ROWS"):
-        (_, a, b, _), = by_name[name]
-        assert lo <= a <= b <= hi, name
-    (_, a, b, reply_stats), = by_name["mv:WORKER_REPLY_GET"]
-    (_, c, d, _), = by_name["mv:BLOB_D2H"]
-    assert a <= c <= d <= b and reply_stats["msg_id"] == msg_id
-    # the copy's two halves nest in it, the wait before the copy
-    (_, r0, r1, _), = by_name["mv:BLOB_D2H_READY"]
-    (_, c0, c1, _), = by_name["mv:BLOB_D2H_COPY"]
-    assert c <= r0 <= r1 <= c0 <= c1 <= d
-    (_, g0, g1, _), = by_name["mv:TABLE_GATHER_DISPATCH"]
-    assert start <= g0 <= g1 <= end
-    # the caller's own spans carry the request's identifiers too: one
-    # request is matched across its three threads
-    (_, i0, i1, issue_stats), = by_name["mv:CLIENT_ISSUE_GET"]
-    (_, w0, w1, wait_stats), = by_name["mv:TABLE_WAIT"]
-    assert lo <= i0 <= i1 <= w0 <= w1 <= hi     # one thread's, in order
-    assert issue_stats["table"] == table.table_id
-    assert wait_stats["msg_id"] == msg_id \
-        and wait_stats["table"] == table.table_id
-    # ... and the Monitor never sees them
+                 "mv:SERVER_PROCESS_GET", "mv:WORKER_REPLY_GET"):
+        assert _each_inside_one(by_name[name], window), name
+    if form != "whole_table":
+        assert len(by_name["mv:TABLE_GATHER_DISPATCH"]) == servers
+        assert _each_inside_one(by_name["mv:TABLE_GATHER_DISPATCH"],
+                                by_name["mv:SERVER_PROCESS_GET"])
+    # a reply that stays on the device is copied and placed by nobody
+    assert ("mv:BLOB_D2H" in by_name) == (form == "host_rows")
+    if form == "host_rows":
+        for name in ("mv:BLOB_D2H", "mv:CLIENT_PLACE_ROWS"):
+            assert len(by_name[name]) == servers
+            assert _each_inside_one(by_name[name],
+                                    by_name["mv:WORKER_REPLY_GET"]), name
+        # the copy's two halves nest in it, the wait before the copy
+        # (the worker's thread handles the shards' replies in turn)
+        for (_, c, d, _), (_, r0, r1, _), (_, c0, c1, _) in zip(
+                *(sorted(by_name[name], key=lambda s: s[1])
+                  for name in ("mv:BLOB_D2H", "mv:BLOB_D2H_READY",
+                               "mv:BLOB_D2H_COPY"))):
+            assert c <= r0 <= r1 <= c0 <= c1 <= d
+    # the caller's own spans are one thread's, in order: one request is
+    # matched across its three threads
+    (_, lo, hi, _), = window
+    (_, i0, i1, _), = by_name["mv:CLIENT_ISSUE_GET"]
+    (_, w0, w1, _), = by_name["mv:TABLE_WAIT"]
+    assert lo <= i0 <= i1 <= w0 <= w1 <= hi
+    # ... and the Monitor never sees the arguments
     assert set(vars(Dashboard.get("TABLE_WAIT"))) == set(
         vars(dashboard.Monitor("x")))
 
 
-def test_a_capture_around_one_add_holds_the_handler_s_halves(tmp_path):
-    mv.init([])
-    try:
-        table = mv.create_matrix_table(ROWS, COLS)
-        ids = np.arange(IDS, dtype=np.int32)
-        delta = np.ones((IDS, COLS), np.float32)
-        table.add_rows(ids, delta)
-        with trace_to(str(tmp_path)):
-            table.add_rows(ids, delta)
-            msg_id = table._msg_id
-    finally:
-        mv.shutdown()
-    by_name = {}
-    for span in _host_spans(str(tmp_path)):
-        by_name.setdefault(span[0], []).append(span)
-    (_, lo, hi, stats), = by_name["mv:SERVER_PROCESS_ADD"]
-    (_, p0, p1, _), = by_name["mv:UPDATE_PAD_ROWS"]
-    (_, d0, d1, _), = by_name["mv:UPDATE_DISPATCH"]
-    assert lo <= p0 <= p1 <= d0 <= d1 <= hi and stats["msg_id"] == msg_id
-    (_, i0, i1, issue_stats), = by_name["mv:CLIENT_ISSUE_ADD"]
-    # issued before the server has it, acknowledged after (the ack
+@pytest.mark.parametrize("form, servers, sync", ONE_REQUEST)
+def test_a_capture_around_one_add_holds_the_handler_s_halves(
+        tmp_path, form, servers, sync):
+    by_name, msg_id, table_id = _capture_one(
+        str(tmp_path), form, servers, sync, op=1)
+    assert "mv:SERVER_PROCESS_GET" not in by_name
+    _hops_carry_the_request(by_name, "ADD", servers, msg_id, table_id)
+    handled = by_name["mv:SERVER_PROCESS_ADD"]
+    # host ids are padded on the host, device ids nowhere
+    assert ("mv:UPDATE_PAD_ROWS" in by_name) == (form == "host_rows")
+    for name in ("mv:UPDATE_PAD_ROWS", "mv:UPDATE_DISPATCH"):
+        if name in by_name:
+            assert len(by_name[name]) == servers
+            assert _each_inside_one(by_name[name], handled), name
+    if form == "host_rows":     # padded, then dispatched
+        padded = min(p1 for _, _, p1, _ in by_name["mv:UPDATE_PAD_ROWS"])
+        assert all(padded <= d0
+                   for _, d0, _, _ in by_name["mv:UPDATE_DISPATCH"])
+    # issued before a server has it, acknowledged after (the ack
     # leaves inside the server's span, so only the starts are ordered)
-    assert i0 <= i1 and i0 <= lo and issue_stats["table"] == table.table_id
-    (_, a0, a1, ack_stats), = by_name["mv:WORKER_REPLY_ADD"]
-    assert lo <= a0 <= a1 and ack_stats["msg_id"] == msg_id \
-        and ack_stats["table"] == table.table_id
+    (_, i0, i1, _), = by_name["mv:CLIENT_ISSUE_ADD"]
+    first = min(lo for _, lo, _, _ in handled)
+    assert i0 <= i1 and i0 <= first
+    assert all(first <= a0 <= a1
+               for _, a0, a1, _ in by_name["mv:WORKER_REPLY_ADD"])
     assert "mv:TABLE_WAKE" not in by_name     # a Monitor.add: no span
 
 

@@ -47,6 +47,22 @@ def shm_entries():
         return []
 
 
+#: What a cluster's rank prints so that its segments can be told from
+#: those of other test files' clusters, which run beside this file's
+#: under xdist (every segment is named by its cluster's token).
+PRINT_TOKEN = ('print("SHM_TOKEN %08x" % ((getattr(mv.current_zoo().net, '
+               '"_token", None) or 0) & 0xFFFFFFFF))')
+
+
+def entries_of(outs):
+    """The entries of /dev/shm that belong to the clusters whose ranks
+    printed ``outs``."""
+    tokens = {line.split()[1] for o in outs for line in o.splitlines()
+              if line.startswith("SHM_TOKEN")} - {"00000000"}
+    assert tokens, outs
+    return [f for f in shm_entries() if any(t in f for t in tokens)]
+
+
 class _Pair:
     """Two loopback TcpNet endpoints wrapped in ShmNet, shm-negotiated
     both ways — the whole transport stack minus the actor layer."""
@@ -370,6 +386,7 @@ def write_machine_file(tmp_path, n):
 
 _TABLE_BODY = """
 mv.init(["-machine_file={mf}", "-rank=" + str(rank){extra}])
+{token}
 table = mv.create_array_table(16)
 table.add((np.arange(16, dtype=np.float32) + 1.0) * (rank + 1))
 mv.barrier()
@@ -395,11 +412,12 @@ def test_mixed_transport_cluster_byte_identical(tmp_path):
     ride the rings."""
     n = 3
     mixed = [_TABLE_BODY.format(mf=write_machine_file(tmp_path, n),
+                                token=PRINT_TOKEN,
                                 extra=', "-shm=0"' if r == 2 else "")
              for r in range(n)]
     outs_mixed = run_cluster(mixed)
     all_tcp = [_TABLE_BODY.format(mf=write_machine_file(tmp_path, n),
-                                  extra=', "-shm=0"')
+                                  token=PRINT_TOKEN, extra=', "-shm=0"')
                for _ in range(n)]
     outs_tcp = run_cluster(all_tcp)
     assert all("TABLE_OK" in o for o in outs_mixed + outs_tcp)
@@ -415,7 +433,7 @@ def test_mixed_transport_cluster_byte_identical(tmp_path):
     assert all(int(line.split()[1]) == 0 for o in outs_tcp
                for line in o.splitlines()
                if line.startswith("SHM_FRAMES"))
-    assert not shm_entries(), shm_entries()
+    assert not entries_of(outs_mixed), shm_entries()
 
 
 def test_sigkill_and_survivor_reap(tmp_path):
@@ -426,6 +444,7 @@ def test_sigkill_and_survivor_reap(tmp_path):
     survivor = f"""
 from multiverso_tpu.runtime.zoo import ClusterAborted
 mv.init(["-machine_file={mf}", "-rank=" + str(rank)])
+{PRINT_TOKEN}
 table = mv.create_array_table(4)
 table.add(np.ones(4, np.float32))
 mv.barrier()
@@ -446,4 +465,4 @@ os.kill(os.getpid(), signal.SIGKILL)
     outs = run_cluster([survivor, dier],
                        expect_rc={0: 0, 1: -9})
     assert "ABORTED_OK" in outs[0], outs[0]
-    assert not shm_entries(), shm_entries()
+    assert not entries_of(outs), shm_entries()

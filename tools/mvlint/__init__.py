@@ -5,7 +5,7 @@ Twelve passes over ``multiverso_tpu/`` and ``tests/``
 
 * ``flag-lint`` — every flag access names a canonical registered flag
   with the canonical default (``util/configure.py CANONICAL_FLAGS``).
-* ``wire-slot`` — reserved header slots 5-9 are accessed by registered
+* ``wire-slot`` — reserved header slots 5-8 are accessed by registered
   name only (``core/message.py WIRE_SLOTS``), and the registry matches
   the slot table in ``docs/WIRE_FORMAT.md``.
 * ``device-dispatch`` — multi-zoo-reachable eager dispatch sits inside
