@@ -1490,12 +1490,12 @@ def experts_capacity(cfg: LMConfig, t: int) -> int:
     """Rows of the routed experts' short buffer for a sequence of ``t``
     positions: ``EXPERTS_SHORT_SHARES`` times the even share of its ``t *
     top_k`` assignments in whole tiles of the grouped products (``_use_gmm``
-    then picks the full buffer's kernel, on the same tiles). Where that is
-    half of them or more there is ONE buffer of them all: ``_by_load``."""
+    then picks the full buffer's kernel, on the same tiles), and never more
+    than all of them: there is then ONE buffer, and no choice."""
     every = t * cfg.top_k
     cap = -(-EXPERTS_SHORT_SHARES * every * cfg.experts_held[1]
             // (cfg.n_experts * GROUPED_TILE_ROWS)) * GROUPED_TILE_ROWS
-    return cap if 2 * cap < every else every
+    return min(cap, every)
 
 
 def _experts_in(cfg: LMConfig, n: int, mats, sinks, h, weights, norm,
@@ -1541,9 +1541,9 @@ def _by_load(cfg: LMConfig, run, weights, sizes, *operands):
     costs is SET-UP, tracing and lowering it (PERF.md section 6, PR 49:
     with the kernel in both, 0.65 to 1.3 s a layer program, 11% of
     ``sdar30b.ps-bd4k``'s ``setup_s``, over its bound; so, 0.4 to 0.75 s).
-    Hence also ``experts_capacity``'s ONE buffer where the short one would
-    be half the rows: it then saves a step 4% (``st21b.ps-8k``) and would
-    cost four programs' set-up."""
+    Where the short one is half the rows (``st21b.ps-8k``) it is paid too
+    since PR 59: four programs' set-up, ~2 s, for a step 4% shorter (one
+    buffer of them all until then)."""
     t, k = weights.shape
     cap = experts_capacity(cfg, t)
     return jax.lax.cond(jnp.sum(sizes) <= cap,

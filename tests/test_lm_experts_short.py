@@ -75,38 +75,40 @@ def form(request):
 def test_the_capacity_is_twice_the_even_share_in_whole_tiles():
     assert (CAP, EVERY) == (512, 2048)
     # the four cells' sizes (ISSUE 49): positions, k, held of outputs;
-    # st21b.ps-8k holds a quarter: twice that is half, so one buffer
+    # st21b.ps-8k holds a quarter: twice that is half (all of them until
+    # PR 59: tests/test_lm_experts_half.py has the two buffers there)
     for t, k, held, outputs, want in (
             (8192, 8, 16, 128, 16384), (8192, 8, 32, 256, 16384),
-            (8192, 6, 16, 64, 49152), (4096, 8, 32, 256, 8192)):
+            (8192, 6, 16, 64, 24576), (4096, 8, 32, 256, 8192)):
         cfg = dataclasses.replace(CFG, n_experts=outputs, top_k=k,
                                   experts_held=(0, held))
         assert lm.experts_capacity(cfg, t) == want
-    # a rehearsal's sequence, and a chip that holds a quarter or more
+    # a rehearsal's sequence, and a chip that holds a quarter, half, all
     assert lm.experts_capacity(CFG, 32) == 32 * K
-    for held in (EXPERTS // 4, EXPERTS // 2):
+    for held, want in ((EXPERTS // 4, EVERY // 2), (EXPERTS // 2, EVERY),
+                       (EXPERTS, EVERY)):
         more = dataclasses.replace(CFG, experts_held=(0, held))
-        assert lm.experts_capacity(more, T) == EVERY
+        assert lm.experts_capacity(more, T) == want
     less = dataclasses.replace(CFG, experts_held=(0, EXPERTS // 4 - 1))
     assert lm.experts_capacity(less, 4 * T) == 7 * 512     # of 8,192
 
 
 # -- routings with a given number of assignments on held experts ------------
 
-def _routing(n_live, one_expert=False):
-    """ids [T, K] with ``n_live`` assignments on the held experts, from
+def _routing(n_live, one_expert=False, held=HELD):
+    """ids [T, K] with ``n_live`` assignments on the ``held`` experts, from
     the first token on (or one a token, all on ONE held expert); every
     other assignment on experts this chip does not hold."""
     ids = np.empty((T, K), np.int32)
-    away = [e for e in range(EXPERTS) if not FIRST <= e < FIRST + HELD]
+    away = [e for e in range(EXPERTS) if not FIRST <= e < FIRST + held]
     left = n_live
     for i in range(T):
         here = min(1 if one_expert else K, left)
         left -= here
-        held = [FIRST + 1] if one_expert else \
-            [FIRST + (i + j) % HELD for j in range(here)]
-        ids[i] = held[:here] + [away[(i + j) % len(away)]
-                                for j in range(K - here)]
+        on = [FIRST + 1] if one_expert else \
+            [FIRST + (i + j) % held for j in range(here)]
+        ids[i] = on[:here] + [away[(i + j) % len(away)]
+                              for j in range(K - here)]
     assert left == 0
     return jnp.asarray(ids)
 
@@ -129,14 +131,14 @@ ROUTINGS = {
     "zipf": lambda: _zipf_routing(3)}
 
 
-def _operands(dtype, seed=0):
+def _operands(dtype, seed=0, cfg=CFG):
     rng = np.random.default_rng(seed)
     draw = lambda *shape: jnp.asarray(      # noqa: E731
         rng.normal(0, 0.3, shape), jnp.float32)
-    h, w = CFG.hidden, CFG.expert_width
-    mats = {"w_gate": draw(HELD * h, w).astype(lm.BF16),
-            "w_up": draw(HELD * h, w).astype(lm.BF16),
-            "w_down": draw(HELD * w, h).astype(lm.BF16)}
+    h, w, held = cfg.hidden, cfg.expert_width, cfg.experts_held[1]
+    mats = {"w_gate": draw(held * h, w).astype(lm.BF16),
+            "w_up": draw(held * h, w).astype(lm.BF16),
+            "w_down": draw(held * w, h).astype(lm.BF16)}
     weights = jax.nn.softmax(draw(T, K), axis=-1)
     # float32: the stream, normed inside; bfloat16: the normed input
     norm = 1 + draw(h) if dtype == "float32" else None
@@ -155,19 +157,20 @@ def _pulled(fn, mats, h, weights, norm, dy):
     return got
 
 
-def _chosen(mats, ids, *rest):
+def _chosen(mats, ids, *rest, cfg=CFG):
     return jax.jit(lambda: _pulled(
-        lambda s, h, w, norm: lm.routed_experts(CFG, mats, s, h, ids, w,
+        lambda s, h, w, norm: lm.routed_experts(cfg, mats, s, h, ids, w,
                                                 norm)[0], mats, *rest))()
 
 
-def _full(mats, ids, *rest):
-    """Today's lines over all ``T * K`` rows, differentiated as they were:
+def _full(mats, ids, *rest, cfg=CFG, n=EVERY):
+    """Today's lines in ONE buffer of ``n`` rows (all ``T * K``, or fewer
+    that hold every held assignment), differentiated as they were:
     ``jax.vjp`` straight through them."""
-    order, sizes = lm.held_groups(CFG, ids)
+    order, sizes = lm.held_groups(cfg, ids)
     back = jnp.argsort(order).astype(jnp.int32)
     return jax.jit(lambda: _pulled(
-        lambda s, h, w, norm: lm._experts_in(CFG, EVERY, mats, s, h, w, norm,
+        lambda s, h, w, norm: lm._experts_in(cfg, n, mats, s, h, w, norm,
                                              order, back, sizes),
         mats, *rest))()
 
@@ -220,13 +223,7 @@ def test_the_short_buffer_alone_equals_the_full_one(routing, kernel):
     pass for it."""
     ids = ROUTINGS[routing]()
     mats, *rest = _operands("bfloat16", seed=1)
-    order, sizes = lm.held_groups(CFG, ids)
-    back = jnp.argsort(order).astype(jnp.int32)
-    short = jax.jit(lambda: _pulled(
-        lambda s, h, w, norm: lm._experts_in(CFG, CAP, mats, s, h, w, norm,
-                                             order, back, sizes),
-        mats, *rest))()
-    _assert_equal(short, _full(mats, ids, *rest))
+    _assert_equal(_full(mats, ids, *rest, n=CAP), _full(mats, ids, *rest))
 
 
 # -- PR 42's suspected fault, built on purpose -------------------------------
