@@ -16,6 +16,22 @@ sequence's first position, ``n = kda_conv`` weights a channel, no bias):
     o_i[t] = S_i[t]^T q_i[t]
     F(x) = concat_i(RMSNorm(o_i; g_o) * sigmoid(((h W_ga) W_gb)_i)) W_o
 
+**beta's range** (``cfg.kda_beta_scale``): 1, or 2 (``beta = 2 sigmoid(h
+W_b)``, the released layers' ``allow_neg_eigval``): with ``k`` of unit
+length the step's matrix ``(I - beta k k^T) Diag(exp g)`` has the eigenvalue
+``1 - beta`` along ``k``, in (-1, 1) at 2, so a state can flip sign along a
+key. ``scan`` and the kernels take beta as data: ``(I + A)``'s entries below
+the diagonal double, and the solve is built by halves so that it holds
+there (``unit_lower_inverse``; tests/test_lm_solar.py).
+
+**The share** (``cfg.heads_held = (first, count)`` of ``kda_heads``):
+``W_q``, ``W_k``, ``W_v``, ``W_fb``, ``W_gb``, ``W_b``, the three
+convolutions, ``A_log`` and ``dt_bias`` hold the held heads' columns and
+``W_o`` their rows; ``W_fa``, ``W_ga`` and the output norm are whole on every
+chip. A head reads no other head, so the layer adds its heads' part of
+``W_o``'s sum and the shares add up to the uncut attention
+(tests/test_lm_solar.py, four of four).
+
 **The scan in chunks** (``scan``). Position by position the recurrence is
 T steps of rank-one work; over a chunk of ``CHUNK`` positions it is matrix
 products. With ``G_t`` the log decays summed from the chunk's first
@@ -68,9 +84,9 @@ gives zeros where the factored form ``(x exp(G)) (k exp(-G))^T`` gives
 **Precision.** Matrix products take bfloat16 inputs and accumulate in
 float32 (``bdot``, and ``model.mm`` for the projections); the log decay,
 its sums within a chunk and every exponential of them, softplus, both L2
-norms, beta, the state from chunk to chunk, the solve ``(I + A)^-1`` (a
-product of ``I + A^(2^j)``: ``A`` is strictly lower, so the series ends;
-float32 at "highest") and the gated norm are float32.
+norms, beta, the state from chunk to chunk, the solve ``(I + A)^-1``
+(``unit_lower_inverse``: by halves, float32 at "highest") and the gated norm
+are float32.
 
 Scopes: ``mv.lm.attn.kda`` (norm, projections, gates, L2 norms, the gated
 output norm, ``W_o``), ``mv.lm.attn.kda.conv`` (the three convolutions and
@@ -111,7 +127,7 @@ def shapes(cfg: LMConfig) -> dict:
     """A delta layer's attention tensors as the server stores them: the
     nine matrices, then a convolution's weights a channel a row, the log
     decay's scale a head, its bias a channel, the output norm a lane."""
-    h, heads, d = cfg.hidden, cfg.kda_heads, cfg.kda_head_dim
+    h, heads, d = cfg.hidden, cfg.kda_heads_held, cfg.kda_head_dim
     lanes = heads * d
     out = {"wq": (h, lanes), "wk": (h, lanes), "wv": (h, lanes),
            "w_fa": (h, d), "w_fb": (d, lanes), "w_ga": (h, d),
@@ -169,15 +185,25 @@ def _highest(a, b):
 @jax.custom_vjp
 def unit_lower_inverse(a):
     """``(I + a)^-1`` for ``a`` [..., n, n] strictly lower triangular,
-    float32: ``(I - a)(I + a^2)(I + a^4)...``, which ends because ``a^n``
-    is zero."""
+    float32, by halves: with ``M_b`` the inverse of ``I +`` the part of
+    ``a`` inside diagonal blocks of ``b``, a block of ``2 b`` is ``[[M11,
+    0], [-M22 a21 M11, M22]]``, so ``M_2b = M_b - M_b D_b M_b`` with ``D_b``
+    the lower-left quarters of ``a``'s blocks of ``2 b``: two products a
+    doubling, and nothing larger than the inverses of ``a``'s own blocks is
+    ever formed. (The series ``(I - a)(I + a^2)(I + a^4)..`` costs the same
+    ten products at 64 and sums powers of ``a``: at 64 positions whose keys
+    are near each other under a beta near 2 they pass 1e20 and cancel to
+    nothing in float32, where this reads 1e-6; tests/test_lm_solar.py.)"""
     n = a.shape[-1]
-    inverse = jnp.eye(n, dtype=F32) - a
-    power, covered = a, 2
-    while covered < n:
-        power = _highest(power, power)
-        inverse = inverse + _highest(inverse, power)
-        covered *= 2
+    rows, cols = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    inverse, b = jnp.eye(n, dtype=F32), 1
+    while b < n:
+        quarter = (rows // (2 * b) == cols // (2 * b)) \
+            & (rows // b % 2 == 1) & (cols // b % 2 == 0)
+        part = jnp.where(quarter, a, 0.0)
+        inverse = inverse - (_highest(_highest(inverse, part), inverse)
+                             if b > 1 else part)
+        b *= 2
     return inverse
 
 
@@ -381,7 +407,7 @@ def gates(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
     """From the convolved q, k, v [T, H K], the decay's logits ``f`` and
     beta's ``b``: ``(q, k, v, g [T, H, K], beta [T, H])`` float32 as
     ``scan`` takes them: the L2 norms, the scale on q, the log decay."""
-    t, heads, d = q.shape[0], cfg.kda_heads, cfg.kda_head_dim
+    t, heads, d = q.shape[0], cfg.kda_heads_held, cfg.kda_head_dim
 
     def by_head(a):
         return heads_apart(a, heads)
@@ -391,7 +417,11 @@ def gates(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
 
     g = -jnp.exp(a_log)[:, None] * by_head(jax.nn.softplus(f + dt_bias))
     out = (unit(by_head(q)) * d ** -0.5, unit(by_head(k)), by_head(v), g)
-    return (*(a.reshape(t, heads, d) for a in out), jax.nn.sigmoid(b))
+    out = tuple(a.reshape(t, heads, d) for a in out)
+    beta = jax.nn.sigmoid(b)
+    if cfg.kda_beta_scale != 1:
+        beta = cfg.kda_beta_scale * beta
+    return (*out, beta)
 
 
 def output(cfg: LMConfig, mats, sinks, norm_o, o, gate):
@@ -406,10 +436,11 @@ def output(cfg: LMConfig, mats, sinks, norm_o, o, gate):
 
 def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
     """``F(x)`` for one sequence and what pulls a cotangent back through
-    it: ``(F(x), the deep triples' count, pull)``, ``pull(d) -> (dx,
-    matrix gradients, small gradients)``. The parts are differentiated one
-    by one so that each part's backward pass runs under the scope of its
-    forward pass.
+    it: ``(F(x), counts, pull)``, ``pull(d) -> (dx, matrix gradients, small
+    gradients)``; ``counts`` by ``model.layer_stats``' names: ``decay_deep``,
+    and where beta can pass 1 ``beta_over_one``, the (position, head) pairs
+    at which it does. The parts are differentiated one by one so that each
+    part's backward pass runs under the scope of its forward pass.
 
     What is kept from the forward pass to the pull is the six projections
     and the scan's outputs: the convolutions, the gates and the scan are
@@ -421,7 +452,7 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
     nor beside the others'."""
     first = {n: sinks[n] for n in MATRICES[:-1]}
     convs = tuple(small[n] for n in CONVS)
-    t, heads = x.shape[0], cfg.kda_heads
+    t, heads = x.shape[0], cfg.kda_heads_held
     chunk = chunk_of(t)
 
     # between the parts (what is differentiated, kept, held behind a
@@ -471,7 +502,13 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
             lambda s, norm, x: projections(cfg, mats, s, norm, x),
             first, small["norm_attn"], x)
     kept = (convs, small["a_log"], small["dt_bias"], q, k, v, f, b)
-    o, deep = scanned_through(*gated(*kept)[0])
+    scanned = gated(*kept)[0]
+    o, deep = scanned_through(*scanned)
+    counts = {"decay_deep": deep}
+    if cfg.kda_beta_scale != 1:
+        with jax.named_scope(SCOPE):
+            counts["beta_over_one"] = jnp.sum(scanned[-1] > 1.0,
+                                              dtype=jnp.int32)
     with jax.named_scope(SCOPE):
         out, pull_output = jax.vjp(
             lambda s, norm_o, o, gate: output(cfg, mats, {"wo": s}, norm_o,
@@ -498,4 +535,4 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
                    **dict(zip(CONVS, d_convs))}
         return dx, {**d_mats, "wo": d_wo}, d_small
 
-    return out, deep, pull
+    return out, counts, pull

@@ -135,14 +135,19 @@ def _highest(a, b):
 
 
 def _inverse(a):
-    """delta.unit_lower_inverse: ``(I + a)^-1`` for ``a`` strictly lower."""
+    """delta.unit_lower_inverse: ``(I + a)^-1`` for ``a`` strictly lower, by
+    halves (``M_2b = M_b - M_b D_b M_b``, ``D_b`` the lower-left quarters of
+    ``a``'s diagonal blocks of ``2 b``)."""
     n = a.shape[-1]
-    inverse = _eye(n) - a
-    power, covered = a, 2
-    while covered < n:
-        power = _highest(power, power)
-        inverse = inverse + _highest(inverse, power)
-        covered *= 2
+    rows, cols = _iota((n, n), 0), _iota((n, n), 1)
+    inverse, b = None, 1
+    while b < n:
+        quarter = (rows // (2 * b) == cols // (2 * b)) \
+            & (rows // b % 2 == 1) & (cols // b % 2 == 0)
+        part = jnp.where(quarter, a, 0.0)
+        inverse = _eye(n) - part if inverse is None \
+            else inverse - _highest(_highest(inverse, part), inverse)
+        b *= 2
     return inverse
 
 

@@ -130,6 +130,25 @@ over positions (4 weights a channel, zero before the sequence):
   are neither matrices nor norms: float32 tables under Adam, drawn at their
   own start (ps_train.py ``decay_init``).
 
+**An eighth family** (``from_dict`` tells it by ``model_type: solar_open2``:
+upstage's Solar-Open2-250B, 2026; the seventh is the third's block on the
+plain residual, above) puts grouped-query attention beside the delta rule
+(``attention_layout``: ``gqa`` | ``kda`` a layer, the softmax layer FIRST of
+every four), every layer sparse with the third family's feed-forward, and
+holds BOTH kinds of attention as a share of their heads:
+
+    gqa: NO turn by position (``rope_layout`` 0), no q or k norm;
+      o <- o * sigmoid(h W_g), W_g [hidden, heads x head_dim]: a gate a
+      LANE (``attn_gate: "lane"``)
+    kda: beta = 2 sigmoid(h W_b) in (0, 2) (``kda_beta_scale`` 2): the
+      step's matrix has the eigenvalue 1 - beta along k, so a state can
+      flip sign along a key
+    ``heads_held = (first, count)``: the delta heads ``first .. first +
+      count - 1`` of ``kda_heads``, as many query heads of ``n_heads`` with
+      the ``n_kv_heads_held`` key-value heads they read; a head reads no
+      other head, so the layer adds its heads' part of ``W_o``'s sum
+      (``heads_of``; latent attention's share since the third family)
+
 **A layer is described by three independent kinds**, and each selects
 functions, not a family's branch: its ATTENTION (the model's ``attention``,
 or where a model has more than one the LAYER's, ``attention_layout`` /
@@ -182,7 +201,9 @@ products over the ragged groups (``grouped_product``: megablox's kernel
 on a TPU, ``jax.lax.ragged_dot`` elsewhere). The held ones lie FIRST in
 the sorted order, so the buffers hold ``experts_capacity`` rows (twice
 the even share of the ``T * top_k`` assignments) where a sequence's held
-assignments fit that, and all ``T * top_k`` rows where they do not: the
+assignments fit that, and all ``T * top_k`` rows where they do not (walked
+in slabs of the short buffer's rows where one buffer of them all would be
+more than ``FALLBACK_SLABS_OVER`` short ones, ``_in_slabs``): the
 device chooses by the count (``routed_experts``; the short buffer gives
 the numbers of the long one to the bit), and whatever the routing every
 assignment is computed. Rows past the held ones belong to no group: the
@@ -241,7 +262,7 @@ F32 = jnp.float32
 #: as bfloat16 copies and their gradients are float32; the rest is small and
 #: kept in float32.
 GQA_MATRICES = ("wq", "wk", "wv", "wo")
-ATTN_GATE = "w_attn_gate"           # with ``LMConfig.attn_gate == "head"``
+ATTN_GATE = "w_attn_gate"           # with an ``LMConfig.attn_gate``
 MLA_MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo")
 MLA_DIRECT = ("wq", "wkv_a", "wkv_b", "wo")     # ``q_lora_rank`` 0: no latent
 DENSE = ("w_gate", "w_up", "w_down")    # a dense MLP's, or the routed
@@ -317,9 +338,15 @@ class LMConfig:
     #                                 window layers'); (): ``rope_theta``
     #                                 over every lane
     attn_gate: str = "none"         # | "head": each head's output times
-    #                                 sigmoid(h W_g), W_g [hidden, heads]
-    heads_held: Tuple[int, int] = (0, 0)    # mla: (first, count) of the
-    #                                 n_heads held here; (0, 0): all
+    #                                 sigmoid(h W_g), W_g [hidden, heads] |
+    #                                 "lane": each LANE's, W_g [hidden,
+    #                                 heads x head_dim]
+    heads_held: Tuple[int, int] = (0, 0)    # (first, count) of the heads
+    #                                 held here, of every kind of attention
+    #                                 the model has (mla's ``n_heads``; kda's
+    #                                 ``kda_heads``; gqa's ``n_heads`` query
+    #                                 heads with the key-value heads they
+    #                                 read); (0, 0): all
     q_lora_rank: int = 0            # mla: the five latent sizes
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -356,13 +383,16 @@ class LMConfig:
     index_topk: int = 0
     index_tile: int = 0             # the selection's tiles, a side
     # -- the attention's kind as a LAYER's, where a model has more than one --------
-    attention_layout: Tuple[str, ...] = ()  # per layer: "mla" | "kda" (the
-    #                                 delta rule's scan, delta.py); ():
-    #                                 ``attention`` in every layer
+    attention_layout: Tuple[str, ...] = ()  # per layer: "mla" | "gqa" |
+    #                                 "kda" (the delta rule's scan,
+    #                                 delta.py); (): ``attention`` in every
+    #                                 layer
     kda_heads: int = 0              # kda: heads, each a state [d x d]
     kda_head_dim: int = 0
     kda_conv: int = 0               # kda: the short convolution's weights a
     #                                 channel
+    kda_beta_scale: int = 1         # kda: beta = scale * sigmoid(h W_b); 2
+    #                                 lets a state flip sign along a key
 
     @property
     def n_layers(self) -> int:
@@ -377,13 +407,32 @@ class LMConfig:
     def n_heads_held(self) -> int:
         return self.heads_held[1] or self.n_heads
 
+    @property
+    def n_kv_heads_held(self) -> int:
+        """gqa: the key-value heads that the held query heads read (query
+        head ``i`` reads ``i // (n_heads / n_kv_heads)``)."""
+        return self.n_kv_heads * self.n_heads_held // self.n_heads
+
+    @property
+    def kda_heads_held(self) -> int:
+        return self.heads_held[1] or self.kda_heads
+
+    def heads_of(self, layer: int) -> Tuple[int, int]:
+        """``(held here, all)`` of the heads of the layer's attention."""
+        kind = self.attention_of(layer)
+        if kind == "kda":
+            return self.kda_heads_held, self.kda_heads
+        if kind == "gqa" and self.heads_layout:
+            return (self.heads_layout[layer],) * 2
+        return self.n_heads_held, self.n_heads
+
     def sparse(self, layer: int) -> int:
         return self.ffn_layout[layer] if self.ffn_layout else 1
 
     def heads(self, layer: int) -> int:
         """The layer's query heads (grouped-query attention)."""
         return self.heads_layout[layer] if self.heads_layout \
-            else self.n_heads
+            else self.n_heads_held
 
     def attention_of(self, layer: int) -> str:
         """The kind of the layer's attention: ``gqa`` | ``mla`` | ``kda``."""
@@ -430,7 +479,7 @@ class LMConfig:
             attention = MLA_MATRICES if self.q_lora_rank else MLA_DIRECT
         else:
             attention = GQA_MATRICES + (
-                (ATTN_GATE,) if self.attn_gate == "head" else ())
+                (ATTN_GATE,) if self.attn_gate != "none" else ())
         shared = self.sparse(layer) and self.shared_width
         if self.selection != "none":
             from . import sparse
@@ -456,6 +505,8 @@ class LMConfig:
         ``num_experts``, Qwen3-MoE's (``_from_qwen3_moe``). In both the
         key that counts the experts is the number HELD and
         ``router_outputs`` the published number the router still has."""
+        if c.get("model_type") == "solar_open2":
+            return cls._from_solar(c)
         if "linear_attn_config" in c:
             return cls._from_kda(c)
         if "kv_lora_rank" in c:
@@ -750,6 +801,78 @@ class LMConfig:
             kda_head_dim=int(linear["head_dim"]),
             kda_conv=int(linear["short_conv_kernel_size"]))
 
+    @classmethod
+    def _from_solar(cls, c: dict) -> "LMConfig":
+        """The block of ``model_type: solar_open2`` (upstage's
+        Solar-Open2-250B, benchmark/configs/solar-open2-250b-a15b-l4.json):
+        of every ``gqa_interval + 1`` layers the FIRST is grouped-query
+        softmax attention with no positions (``use_rope`` false) and an
+        output gate (``use_gqa_gate``; ``attn_gate``: a gate a ``"lane"``,
+        the reading taken, or a ``"head"``), the others the delta rule's
+        scan (delta.py) with ``beta = 2 sigmoid`` (``kda_allow_neg_eigval``)
+        and low-rank decay and gate projections (``kda_use_full_proj``
+        false); every layer sparse with a shared expert under DeepSeek-V3's
+        router. The keys that count experts and heads give the numbers HELD
+        (``num_attention_heads`` query heads from ``first_head_held`` with
+        the ``num_key_value_heads`` they read, ``linear_attn_config.
+        num_heads`` delta heads from the same first); ``router_outputs``,
+        ``attention_heads``, ``key_value_heads`` and
+        ``linear_attention_heads`` the published ones. ``router_bias_rate``
+        and ``hidden_act`` the published config does not state."""
+        n, linear = int(c["num_hidden_layers"]), c["linear_attn_config"]
+        full, every = list(c["gqa_layers"]), int(c["gqa_interval"]) + 1
+        heads, kv = int(c["attention_heads"]), int(c["key_value_heads"])
+        first, held = int(c.get("first_head_held", 0)), int(
+            c["num_attention_heads"])
+        CHECK(not c["use_rope"] and int(c["first_k_dense_replace"]) == 0
+              and full == list(range(0, full[-1] + 1, every))
+              and not c["kda_use_full_proj"]
+              and linear["num_kv_heads"] is None and c["norm_topk_prob"]
+              and c.get("scoring_func", "sigmoid") == "sigmoid",
+              "only the block whose softmax layers use no position and lead "
+              "each period, whose every layer is sparse, whose delta layers "
+              "have low-rank decay and gate projections and as many value "
+              "heads as key heads, and whose router scores by sigmoid and "
+              "normalises its top-k, is written down here")
+        CHECK(heads % kv == 0 and first % (heads // kv) == 0
+              and held % (heads // kv) == 0
+              and int(c["num_key_value_heads"]) == held * kv // heads
+              and int(linear["num_heads"]) == held
+              and int(c["linear_attention_heads"]) == heads,
+              "the held heads are whole groups of query heads with the "
+              "key-value heads they read, and as many delta heads")
+        return cls(
+            hidden=int(c["hidden_size"]), n_heads=heads, n_kv_heads=kv,
+            head_dim=int(c["head_dim"]),
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_tok"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["n_routed_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(0,) * n, window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]),
+            eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c.get("hidden_act", "silu")),
+            router_input="ffn_input",
+            attn_gate=str(c.get("attn_gate", "lane"))
+            if c["use_gqa_gate"] else "none",
+            heads_held=(first, held),
+            attention_layout=tuple("gqa" if i in full else "kda"
+                                   for i in range(n)),
+            ffn_layout=(1,) * n,
+            dense_width=int(c["intermediate_size"]),
+            shared_width=int(c["n_shared_experts"])
+            * int(c["moe_intermediate_size"]),
+            scoring="sigmoid_bias",
+            routed_scale=float(c["routed_scaling_factor"]),
+            bias_rate=float(c["router_bias_rate"]),
+            kda_heads=int(c["linear_attention_heads"]),
+            kda_head_dim=int(linear["head_dim"]),
+            kda_conv=int(linear["short_conv_kernel_size"]),
+            kda_beta_scale=2 if c["kda_allow_neg_eigval"] else 1)
+
     def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it, built from
         the layer's kinds: a matrix table's (rows, columns) or a small
@@ -778,11 +901,13 @@ class LMConfig:
                           heads * (self.qk_nope_dim + self.v_head_dim)),
                 "wo": (heads * self.v_head_dim, h)})
         else:
-            heads = self.heads(layer)
-            shapes = {"wq": (h, heads * d), "wk": (h, self.n_kv_heads * d),
-                      "wv": (h, self.n_kv_heads * d), "wo": (heads * d, h)}
-            if self.attn_gate == "head":
-                shapes[ATTN_GATE] = (h, heads)
+            # over the held query heads and the key-value heads they read
+            heads, kv = self.heads(layer), self.n_kv_heads_held
+            shapes = {"wq": (h, heads * d), "wk": (h, kv * d),
+                      "wv": (h, kv * d), "wo": (heads * d, h)}
+            if self.attn_gate != "none":
+                shapes[ATTN_GATE] = (
+                    h, heads * (d if self.attn_gate == "lane" else 1))
         shapes.update({"norm_attn": (h,), "norm_ffn": (h,)})
         if self.residual == "mhc":
             # a sublayer's ``phi`` a coefficient a row, ``a`` three scalars
@@ -1267,7 +1392,8 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     pass over memory where ``attention_pass_fused``."""
     t, d = x.shape[0], cfg.head_dim
     heads = mats["wq"].shape[1] // d
-    g, per = cfg.n_kv_heads, heads // cfg.n_kv_heads
+    g = cfg.n_kv_heads_held
+    per = heads // g
     norm, *qk = norms if cfg.qk_norm else (norms,)
     h = rmsnorm(x, norm, cfg.eps)
     at = () if pos is None else (pos,)
@@ -1282,7 +1408,7 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
                               bool(qk), cfg.eps, 1.0 / math.sqrt(d), BF16),
             *(mm(h, mats[n], sinks[n]) for n in ("wq", "wk", "wv")),
             tuple(qk), tables)
-        return qkv + (h,) if cfg.attn_gate == "head" else qkv
+        return qkv + (h,) if cfg.attn_gate != "none" else qkv
     q = mm(h, mats["wq"], sinks["wq"]).reshape(t, heads, d)
     k = mm(h, mats["wk"], sinks["wk"]).reshape(t, g, d)
     v = mm(h, mats["wv"], sinks["wv"]).reshape(t, g, d)
@@ -1296,7 +1422,7 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     q = q.reshape(t, g, per, d).transpose(1, 2, 0, 3)
     qkv = (q, k.astype(BF16).transpose(1, 0, 2),
            v.astype(BF16).transpose(1, 0, 2))
-    return qkv + (h,) if cfg.attn_gate == "head" else qkv
+    return qkv + (h,) if cfg.attn_gate != "none" else qkv
 
 
 GATE_SCOPE = "mv.lm.attn.gate"
@@ -1306,9 +1432,15 @@ def attention_gate(mats, sinks, h, o):
     """Each head's output times its gate ``sigmoid(h W_g)`` (``W_g``
     [hidden, heads], a logit a head from the layer's normed input ``h``):
     ``(o [groups, per group, T, d] gated, the gates' sum over tokens and
-    heads)``."""
-    groups, per, t, _ = o.shape
+    heads)``. A ``W_g`` [hidden, heads x d] is a gate a LANE (``attn_gate:
+    "lane"``), and the second result the count of lanes whose gate is over
+    a half."""
+    groups, per, t, d = o.shape
     gate = jax.nn.sigmoid(mm(h, mats[ATTN_GATE], sinks[ATTN_GATE]))
+    if gate.shape[1] != groups * per:
+        by_lane = gate.reshape(t, groups, per, d).transpose(1, 2, 0, 3)
+        return ((o.astype(F32) * by_lane).astype(BF16),
+                jnp.sum(gate > 0.5, dtype=jnp.int32))
     by_head = gate.reshape(t, groups, per).transpose(1, 2, 0)[..., None]
     return (o.astype(F32) * by_head).astype(BF16), jnp.sum(gate)
 
@@ -1499,14 +1631,23 @@ def experts_capacity(cfg: LMConfig, t: int) -> int:
 
 
 def _experts_in(cfg: LMConfig, n: int, mats, sinks, h, weights, norm,
-                order, back, sizes, product_of=None):
+                order, back, sizes, product_of=None, lo=None):
     """``routed_experts``' sum in buffers of ``n`` rows, which hold every
     assignment on a held expert (``sum(sizes) <= n``): all ``T * k`` of
     them, or the first ``n`` of the sorted order, where the held ones
-    lie. ``product_of``: ``grouped_mm`` (when None) or its twin."""
+    lie. ``product_of``: ``grouped_mm`` (when None) or its twin. With
+    ``lo`` (a device scalar) the buffers hold rows ``lo .. lo + n - 1`` of
+    the sorted order, a SLAB, and the sum is the part those rows give
+    (``_in_slabs``)."""
     t, k = weights.shape
     count = cfg.experts_held[1]
-    if n < t * k:   # a row past the buffer is the zero row: ``_rows_at``
+    if lo is not None:      # an assignment outside the slab: the zero row
+        order = jax.lax.dynamic_slice(jnp.pad(order, (0, n)), (lo,), (n,))
+        back = jnp.where((back >= lo) & (back < lo + n), back - lo, n)
+        ends = jnp.cumsum(sizes)
+        sizes = jnp.clip(ends, lo, lo + n) - jnp.clip(ends - sizes, lo,
+                                                      lo + n)
+    elif n < t * k:   # a row past the buffer is the zero row: ``_rows_at``
         order, back = order[:n], jnp.minimum(back, n)
     live = (jnp.arange(n) < jnp.sum(sizes))[:, None]
     if norm is not None:
@@ -1530,6 +1671,34 @@ def _experts_in(cfg: LMConfig, n: int, mats, sinks, h, weights, norm,
     return combine(out, order, back, k)
 
 
+#: The full buffer in short ones past which the fallback walks it in slabs
+#: (``_in_slabs``): a buffer of every assignment is sized in every program
+#: whether taken or not, and ``kimi48b.ps-8k`` fits with one of 16.
+FALLBACK_SLABS_OVER = 16
+
+
+def _in_slabs(run, cap: int, sizes, operands):
+    """``run``'s result (``_by_load``) summed over the slabs of ``cap`` rows
+    of the sorted order that hold a held expert's assignment, one slab at a
+    time in buffers of ``cap`` rows by XLA's grouped product: the fallback
+    where a buffer of all ``T * k`` rows is too large to be sized beside the
+    step (``solar250b.ps-8k``: 65,536 rows of 4,096 against a short buffer of
+    3,584). The same sums as the full buffer's in another order: a token's
+    assignments in two slabs meet in float32, what a slab gives in bfloat16
+    (a pull's ``dh``) is rounded a slab."""
+    def slab(s):
+        return run(cap, grouped_mm_xla, *operands, lo=s * cap)
+
+    like = jax.eval_shape(slab, jnp.int32(0))
+    total = jax.lax.fori_loop(
+        0, -(-jnp.sum(sizes) // cap),
+        lambda s, total: jax.tree_util.tree_map(
+            lambda a, b: a + b.astype(F32), total, slab(s)),
+        jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, F32), like))
+    return jax.tree_util.tree_map(lambda a, to: a.astype(to.dtype), total,
+                                  like)
+
+
 def _by_load(cfg: LMConfig, run, weights, sizes, *operands):
     """``run(n, product, *operands)`` at ``n`` the short buffer's rows
     where the sequence's held assignments fit them, else at all ``T * k``:
@@ -1546,9 +1715,14 @@ def _by_load(cfg: LMConfig, run, weights, sizes, *operands):
     buffer of them all until then)."""
     t, k = weights.shape
     cap = experts_capacity(cfg, t)
+    if t * k > FALLBACK_SLABS_OVER * cap:   # never a buffer of them all
+        def fallback(ops):
+            return _in_slabs(run, cap, sizes, ops)
+    else:
+        def fallback(ops):
+            return run(t * k, grouped_mm_xla, *ops)
     return jax.lax.cond(jnp.sum(sizes) <= cap,
-                        lambda ops: run(cap, grouped_mm, *ops),
-                        lambda ops: run(t * k, grouped_mm_xla, *ops),
+                        lambda ops: run(cap, grouped_mm, *ops), fallback,
                         operands)
 
 
@@ -1566,9 +1740,9 @@ def _experts_by_load(cfg: LMConfig, mats, sinks, h, weights, order, back,
     a branch makes the zeros it differentiates against."""
     del sinks
 
-    def run(n, product_of, mats, *rest):
+    def run(n, product_of, mats, *rest, lo=None):
         return _experts_in(cfg, n, mats, _zeros_like_f32(mats), *rest,
-                           product_of)
+                           product_of, lo)
 
     return _by_load(cfg, run, weights, sizes, mats, h, weights, None, order,
                     back, sizes)
@@ -1582,10 +1756,11 @@ def _experts_by_load_fwd(cfg, mats, sinks, h, weights, order, back, sizes):
 def _experts_by_load_bwd(cfg, res, g):
     mats, _, weights, _, _, sizes = res
 
-    def pull(n, product_of, mats, h, weights, order, back, sizes, g):
+    def pull(n, product_of, mats, h, weights, order, back, sizes, g,
+             lo=None):
         return jax.vjp(
             lambda s, h, w: _experts_in(cfg, n, mats, s, h, w, None, order,
-                                        back, sizes, product_of),
+                                        back, sizes, product_of, lo),
             _zeros_like_f32(mats), h, weights)[1](g)
 
     d_sinks, dh, dw = _by_load(cfg, pull, weights, sizes, *res, g)
@@ -1703,7 +1878,8 @@ def feed_forward_vjp(cfg: LMConfig, sparse: int, mats, sinks, small, u):
 
 
 def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
-                selected=None, decay_deep=None):
+                selected=None, decay_deep=None, gate_lanes_open=None,
+                beta_over_one=None):
     """What a forward program reports of one sequence through a layer
     whose feed-forward is ``feed_forward_vjp``'s: ``(stats, ids)``. A
     sparse layer's ``stats`` int32 [2 + n_experts]: assignments on held
@@ -1713,7 +1889,10 @@ def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
     thousandths; with a ``cfg.selection`` its counts (``selected``:
     sparse.COUNTS of them) come last; a delta layer's ``decay_deep``
     (delta.py: the (chunk, head, channel) triples whose summed log decay is
-    under ``delta.DEEP``) comes last."""
+    under ``delta.DEEP``) comes last, after its ``beta_over_one`` (the
+    (position, head) pairs whose beta is over 1, where ``kda_beta_scale``
+    lets it be); a lane gate's ``gate_lanes_open`` (the lanes whose gate is
+    over a half) comes last."""
     if sparse:
         ids, sizes, load = aux
         stats = jnp.concatenate(
@@ -1726,6 +1905,9 @@ def layer_stats(cfg: LMConfig, sparse: int, aux, gate_open=None,
             [stats, jnp.round(1e3 * gate_open)[None].astype(jnp.int32)])
     if selected is not None:
         stats = jnp.concatenate([stats, selected])
+    for count in (gate_lanes_open, beta_over_one):
+        if count is not None:
+            stats = jnp.concatenate([stats, count[None].astype(jnp.int32)])
     if decay_deep is not None:
         stats = jnp.concatenate([stats, decay_deep[None].astype(jnp.int32)])
     return stats, ids
@@ -1754,8 +1936,7 @@ def _module_attention_vjp(cfg: LMConfig, kind: str, rope, mats, sinks,
     stats = {}
     if kind == "kda":
         from . import delta
-        out, stats["decay_deep"], pull_f = delta.attention_vjp(
-            cfg, mats, sinks, small, x)
+        out, stats, pull_f = delta.attention_vjp(cfg, mats, sinks, small, x)
     else:
         from . import latent
         out, pull_f = latent.attention_vjp(cfg, mats, sinks, small, x, pos,
@@ -1818,7 +1999,10 @@ def attention_vjp(cfg: LMConfig, rope, mask, mats, sinks, small, x,
             o, pull_gate, gate_open = jax.vjp(
                 lambda s, h, o: attention_gate(mats, {ATTN_GATE: s}, h, o),
                 sinks[ATTN_GATE], h[0], o, has_aux=True)
-        stats["gate_open"] = gate_open / x.shape[0]
+        if cfg.attn_gate == "lane":
+            stats["gate_lanes_open"] = gate_open
+        else:
+            stats["gate_open"] = gate_open / x.shape[0]
     with jax.named_scope(scope):
         a, pull_output = jax.vjp(
             lambda s, x, o: attention_output(cfg, mats, {"wo": s}, x, o),

@@ -80,6 +80,11 @@ the module's layer too: which form ``model.attention_inputs`` took,
 ``model.attention_pass_name``, or ``latent.inputs``, ``latent.pass_name``),
 ``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
 sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
+``LM_KDA_BETA_OVER_ONE`` of ``LM_KDA_BETA`` ((position, head) pairs of the
+delta layers whose beta is over 1, where ``kda_beta_scale`` lets it be),
+``LM_GATE_LANES_OPEN`` of ``LM_GATE_LANES`` (a lane gate's lanes over a
+half), ``LM_HEADS_HELD`` of ``LM_HEADS`` (a layer a sequence: the heads of
+its attention held here, of all it has; from the host),
 ``LM_MTP_TOKENS`` (positions a multi-token module predicted from: ``B T``
 a step that held one), ``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
@@ -522,6 +527,12 @@ class PSLMTrainer:
         # and which form each layer's ``model.attention_inputs`` (or
         # ``latent.inputs``) takes of a sequence: the counter's name
         self._attn_pass = attn_pass_names(cfg, positions, bool(self.module))
+        # (held, all) of a step's heads, a layer a sequence (the module's
+        # layer is of the last layer's kind)
+        layers = list(range(cfg.n_layers)) \
+            + [cfg.n_layers - 1] * bool(self.module)
+        self._heads = tuple(self.B * sum(heads) for heads in zip(
+            *(cfg.heads_of(i) for i in layers)))
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
@@ -821,10 +832,21 @@ class PSLMTrainer:
                       for s in per_layer if s.shape[1] >= 2 + outputs)
         if fullest:
             count("LM_ROUTER_LOAD_MAX", fullest)
+        count("LM_HEADS_HELD", self._heads[0])
+        count("LM_HEADS", self._heads[1])
         if self.cfg.attn_gate == "head":
             # each layer's gates summed over its heads, the step's mean
             count("LM_GATE_OPEN", int(round(sum(
                 s[:, -1].mean() for s in per_layer))))
+        if self.cfg.attn_gate == "lane":
+            # a gated layer's last: the lanes whose gate is over a half
+            gated = [s for s, kind in zip(per_layer,
+                                          self.cfg.attention_layout)
+                     if kind == "gqa"]
+            count("LM_GATE_LANES", sum(len(s) for s in gated) * self.T
+                  * self.cfg.n_heads_held * self.cfg.head_dim)
+            count("LM_GATE_LANES_OPEN",
+                  int(sum(s[:, -1].astype(np.int64).sum() for s in gated)))
         if self.cfg.selection != "none":
             # a layer's last four (sparse.COUNTS), every layer and sequence
             for at, name in enumerate(("LM_SELECTED_PAIRS", "LM_CAUSAL_PAIRS",
@@ -846,8 +868,15 @@ class PSLMTrainer:
             # chose by
             count(delta.scan_counter(self.cfg, self.T), sequences)
             count("LM_KDA_CHUNKS", chunks)
+            heads = self.cfg.kda_heads_held
             count("LM_KDA_DECAY_CHANNELS",
-                  chunks * self.cfg.kda_heads * self.cfg.kda_head_dim)
+                  chunks * heads * self.cfg.kda_head_dim)
+            if self.cfg.kda_beta_scale != 1:
+                # before the deep count: the (position, head) pairs whose
+                # beta is over 1, of all
+                count("LM_KDA_BETA", sequences * self.T * heads)
+                count("LM_KDA_BETA_OVER_ONE", int(sum(
+                    s[:, -2].astype(np.int64).sum() for s in scanned)))
             deep = int(sum(s[:, -1].astype(np.int64).sum() for s in scanned))
             if deep:
                 count("LM_KDA_DECAY_DEEP", deep)

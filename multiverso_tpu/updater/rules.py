@@ -308,6 +308,16 @@ def distinct_rows(row_ids, delta, num_rows: int):
     return unique, sums
 
 
+#: A stored row at or over which ``AdamRule.rows`` keeps the formula out of
+#: the writes' fusions: at 4,096 float32 columns the TPU's compiler gives the
+#: scatter fused with it 16.12 MB of scoped vector memory, of the 16 it has,
+#: and refuses the program (3,584 columns compile whole). The rows then lie
+#: in memory once more before they are written: 18.3 ms an Add of 16,384 ids
+#: at [24576, 4096], where 2,304 columns take 9.2 whole and 10.3 so (my chip
+#: run, PR 60); the same rows in two column blocks took 397.
+WIDE_ROW_BYTES = 16384
+
+
 class AdamRule(UpdaterRule):
     """Adam (Kingma & Ba 2015) in the server: the Add carries the raw
     gradient ``g`` and the rule owns the rest.
@@ -364,6 +374,9 @@ class AdamRule(UpdaterRule):
                                  indices_are_sorted=True)
 
         def write(x, rows):
+            if x.ndim == 2 and x.shape[1] * x.dtype.itemsize \
+                    >= WIDE_ROW_BYTES:      # a wide row: no fused formula
+                rows = jax.lax.optimization_barrier(rows)
             return x.at[ids].set(rows, mode="drop",
                                  indices_are_sorted=True)
 

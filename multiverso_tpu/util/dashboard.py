@@ -291,6 +291,19 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_GATE_OPEN": "the per-head attention gate's value: each layer's "
                     "gates summed over its heads, the step's mean over "
                     "tokens, summed over layers, in thousandths",
+    "LM_KDA_BETA": "(position, head) pairs of the delta layers' scans, "
+                   "where beta can pass 1 (LMConfig.kda_beta_scale 2)",
+    "LM_KDA_BETA_OVER_ONE": "of those, the pairs whose beta is over 1: a "
+                            "state can flip sign along their key (computed "
+                            "on the device)",
+    "LM_GATE_LANES": "lanes of the per-lane attention gate "
+                     "(LMConfig.attn_gate \"lane\"): a position a held head "
+                     "a lane, every gated layer and sequence",
+    "LM_GATE_LANES_OPEN": "of those, the lanes whose gate is over a half "
+                          "(computed on the device)",
+    "LM_HEADS": "heads of the layers' attention as published, a layer a "
+                "sequence",
+    "LM_HEADS_HELD": "of those, the heads held here (LMConfig.heads_held)",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"

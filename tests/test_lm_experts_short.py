@@ -391,7 +391,7 @@ def test_the_trainer_counts_which_buffer_each_sequence_took(held, sparse,
     capacity, a dense layer's two zeros counted nowhere."""
     trainer = PSLMTrainer.__new__(PSLMTrainer)
     trainer.cfg, trainer._sparse, trainer._experts_cap = CFG, sparse, CAP
-    trainer._attn_pass = []
+    trainer._attn_pass, trainer._heads = [], (1, 1)
     stats = [np.stack([np.asarray(row), np.zeros(len(row), int)], axis=1)
              for row in held]
 

@@ -175,7 +175,9 @@ def test_an_older_latent_configuration_builds_what_it_built(name, rehearse):
     if rehearse:
         config.update(tiny)
     cfg = lm.LMConfig.from_dict(config)
-    assert hashlib.sha256(repr(cfg).encode()).hexdigest()[:16] \
+    # a field added since (PR 60) stands at its default and is last
+    built = repr(cfg).replace(", kda_beta_scale=1)", ")")
+    assert hashlib.sha256(built.encode()).hexdigest()[:16] \
         == PARENT_BUILT[name, rehearse]
 
 

@@ -200,8 +200,8 @@ def test_gates_and_output_on_the_tiles_view_are_a_head_at_a_time(t):
     does not divide T) is a view: the L2 norms, the log decay and the gated
     norm are what [T, H, d] gives, head by head."""
     heads, d = 3, 8
-    cfg = type("Cfg", (), {"kda_heads": heads, "kda_head_dim": d,
-                           "eps": 1e-6})()
+    cfg = type("Cfg", (), {"kda_heads_held": heads, "kda_head_dim": d,
+                           "kda_beta_scale": 1, "eps": 1e-6})()
     rng = np.random.default_rng(t)
     q, k, v, f, gate = (jnp.asarray(rng.normal(size=(t, heads * d)),
                                     jnp.float32) for _ in range(5))

@@ -278,14 +278,19 @@ def test_the_published_widths_are_a_pair_of_heads_a_step():
 # and backward program at the configuration's rehearsal widths, made from
 # the parent commit (ce7e096) by ``_digests`` under the same JAX
 # (tests/test_lm_mixed.py's pattern: after a change that is MEANT to move
-# them, run ``_digests`` on the parent and replace these).
+# them, run ``_digests`` on the parent and replace these). PR 60 MEANT to
+# move kimi's two delta kinds: the solve inside a chunk is built by halves
+# (delta.unit_lower_inverse); with the series put back in its place both
+# programs lower to ce7e096's text to the byte (a8761706279d97be,
+# b5ab187450d7bff8; 1821d80da19179f0, e8053f7d1b03b8fc), so these two pairs
+# are the solve's and nothing else's. Its latent kind stands unmoved.
 PARENT_TEXT = {
     ("xing4-29b-a4b-l5", "lm-ps-step-4k"): {
         (1, 0, 0): ("431bf4dbc52fa4dc", "186d4f0a9fc791f5"),
         (1, 0, 1): ("7a2a627f49c34e43", "a073891544c7dca7")},
     ("kimi-linear-48b-a3b-l5", "lm-ps-step-8k"): {
-        (0, 0, 0, "kda"): ("a8761706279d97be", "b5ab187450d7bff8"),
-        (0, 0, 1, "kda"): ("1821d80da19179f0", "e8053f7d1b03b8fc"),
+        (0, 0, 0, "kda"): ("cdd5847363f02634", "0842be3b972adbd9"),
+        (0, 0, 1, "kda"): ("5a1831e91e86bd0f", "45f0a696ec938514"),
         (0, 0, 1, "mla"): ("f3dddf4d44b823f7", "7fc81dcbab08ab9d")}}
 
 
@@ -346,7 +351,7 @@ def test_the_trainer_counts_one_a_latent_layer_a_sequence(
     cfg = _file_config(config)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     trainer = PSLMTrainer.__new__(PSLMTrainer)
-    trainer.cfg, trainer.T = cfg, t
+    trainer.cfg, trainer.T, trainer._heads = cfg, t, (1, 1)
     trainer._attn_pass = ps_train.attn_pass_names(cfg, t, module)
     layers = cfg.n_layers + module
     assert len(trainer._attn_pass) == layers

@@ -61,6 +61,35 @@ def test_the_rows_program_compiles_for_a_v5e(topo, chips):
     assert memory.temp_size_in_bytes < 50e6
 
 
+@pytest.mark.parametrize("whole", [False, True])
+def test_adam_s_rows_form_compiles_at_a_row_of_4096_columns(topo, whole,
+                                                            monkeypatch):
+    """An embedding at hidden 4,096 (solar250b.ps-8k): the lazy rows form
+    keeps Adam's formula out of the writes of a row that wide, because the
+    TPU's compiler refuses the write fused with it (16.12 MB of scoped
+    vector memory, of 16): the second case pins that refusal, so that a
+    compiler that takes it fused shows here."""
+    if whole:
+        monkeypatch.setattr(rules, "WIDE_ROW_BYTES", 1 << 30)
+    rows, cols, k = 2048, 4096, (2, 8192)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    engine = UpdateEngine(rules.AdamRule(), (rows, cols), np.float32, 1)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    table = shaped((rows, cols), jnp.float32)
+    lowered = engine._rows.lower(
+        table, (table, table, shaped((), jnp.int32)), shaped(k, jnp.int32),
+        shaped(k + (cols,), jnp.float32), np.zeros(4, np.float32),
+        np.int32(0))
+    if whole:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
+    else:
+        assert "scatter" in lowered.compile().as_text()
+
+
 # The local trainer's group program (device_train._group_fn) and the
 # model-average one (_ma_group_fn) end every step in the same
 # scatter-add, with no mesh: the platform rules.fast_rows asks for is
