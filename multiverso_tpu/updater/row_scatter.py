@@ -8,19 +8,26 @@ it is told about them (73-78 ns a 128-lane float32 row on a v5e,
 tools/scatter_bench.py; PERF.md section 6, PR 28). Here the ids are
 sorted first with their positions as payload, so that equal ids are
 adjacent (a *run*) and ids outside the table's rows sort to the end and
-are never visited. The sorted positions are then taken a chunk at a
-time: XLA gathers the chunk's delta rows into sorted order, and a Pallas
-kernel (row_scatter_kernel.py) walks them a tile at a time: it reads
-the table row of every run into VMEM by a DMA of its own, sums a run's
-delta rows in sorted-position order in float32, adds the sum to the
-table row and writes the row back. No table row depends on another, so
-a tile's reads, and the writes of the tile before it, are all in flight
-together. A run of one row is ``table + delta``, bit for bit what XLA's
-scatter-add gives.
+are never visited. What XLA does well it does: beside the sort, the list
+of every tile's run ends and their rows (``sorted_runs``: a second sort,
+within tiles), and, a chunk of sorted positions at a time, the gather of
+the chunk's delta rows into sorted order. The Pallas kernel
+(row_scatter_kernel.py) walks a chunk a tile at a time, and its scalar
+core does what only it can: it reads the table row of every listed end
+into VMEM by a DMA of its own, a tile ahead, sums a run's delta rows in
+sorted-position order in float32, adds the sum to the table row and
+writes the row back. No table row depends on another, so the reads of
+the next tile and the writes of the tile before are in flight while a
+tile is summed. A run of one row is ``table + delta``, bit for bit what
+XLA's scatter-add gives.
 
 The table is aliased to the kernel's output and stays in HBM. The
-temporaries are the sorted ids and positions (8 bytes an id) and one
-chunk of delta rows (``CHUNK`` x columns), whatever the id count.
+temporaries are the sorted ids and positions and the listed ends and
+their rows (16 bytes an id) and one chunk of delta rows (``CHUNK`` x
+columns), whatever the id count. The table rows do not come by XLA's
+gather as the delta rows do: a gather is a position's (8 to 11 ns), the
+kernel's read a distinct row's (14 ns to issue), so the gather loses
+wherever ids repeat (PERF.md section 6, PR 61).
 
 On a row-sharded table the same code runs under ``shard_map`` over the
 table's mesh with the ids and deltas replicated: every chip sorts the
@@ -44,7 +51,7 @@ from jax.sharding import PartitionSpec
 from ..util import log
 
 #: Sorted positions a grid step handles: its delta rows, table rows and
-#: results are VMEM tiles of this many rows (4 x TILE x columns x 4 B).
+#: results are VMEM tiles of this many rows (6 x TILE x columns x 4 B).
 TILE = 1024
 #: Sorted positions whose delta rows are gathered at a time: the Add's
 #: temporaries are CHUNK x columns x 4 B (8 MB at 128 columns).
@@ -59,14 +66,18 @@ def sorted_runs(row_ids, lo, num_rows):
 
     Ids are global; the rows ``[lo, lo + num_rows)`` are the caller's
     (``lo`` may be traced: a shard's first row). Returns ``(code, perm,
-    n_live)``, the first two of the ids' length padded to whole tiles
-    (whole chunks past one chunk): ``perm[i]`` is
-    the position in ``row_ids`` of the i-th smallest id (ties by
-    position, so the order is a function of the ids alone),
-    ``code[i] = (id - lo) * 4 + 2 * head + end`` where ``head``/``end``
-    say whether i is the first/last position of its run, and -1 at the
-    positions of ids outside the rows, which all sort behind the
-    ``n_live`` positions of the ids inside."""
+    n_live, ends, end_rows, counts)``, all but ``n_live`` and ``counts``
+    of the ids' length padded to whole tiles (whole chunks past one
+    chunk): ``perm[i]`` is the position in ``row_ids`` of the i-th
+    smallest id (ties by position, so the order is a function of the ids
+    alone), ``code[i] = (id - lo) * 4 + 2 * head + end`` where ``head``
+    and ``end`` say whether i is the first/last position of its run, and
+    -1 at the positions of ids outside the rows, which all sort behind
+    the ``n_live`` positions of the ids inside. A tile of ``ends`` lists
+    the positions within the tile of its live run ends, in order, then
+    repeats the last of them (0 where it has none), ``end_rows`` their
+    rows ``id - lo`` the same way; ``counts`` has a tile's count of
+    them."""
     k = row_ids.shape[0]
     ids = row_ids.astype(jnp.int32)
     local = ids - lo
@@ -84,7 +95,19 @@ def sorted_runs(row_ids, lo, num_rows):
     if pad:
         code = jnp.concatenate([code, jnp.full((pad,), -1, jnp.int32)])
         perm = jnp.concatenate([perm, jnp.zeros((pad,), jnp.int32)])
-    return code, perm, jnp.sum(live, dtype=jnp.int32)
+    tiles = code.reshape(-1, TILE)
+    at = lax.broadcasted_iota(jnp.int32, tiles.shape, 1)
+    is_end = (tiles >= 0) & (tiles & 1 == 1)
+    # The ends sort to the front in order, and behind them goes the last.
+    ends, end_rows = lax.sort(
+        (jnp.where(is_end, at, TILE), jnp.where(is_end, tiles >> 2, 0)),
+        num_keys=1, is_stable=False)
+    listed = ends < TILE
+    ends = jnp.where(listed, ends, jnp.max(ends * listed, 1, keepdims=True))
+    end_rows = jnp.where(listed, end_rows,
+                         jnp.max(end_rows, 1, keepdims=True))
+    return (code, perm, jnp.sum(live, dtype=jnp.int32), ends.reshape(-1),
+            end_rows.reshape(-1), jnp.sum(is_end, axis=1, dtype=jnp.int32))
 
 
 def _artifact(cache_dir: str, *key) -> str:
@@ -102,8 +125,9 @@ def _artifact(cache_dir: str, *key) -> str:
 
 @functools.lru_cache(maxsize=None)
 def _chunk_program(shape, dtype, chunk: int, cache_dir: str):
-    """``(table, code, rows, carry) -> (table, carry)``: the kernel on
-    one chunk of a ``shape`` table, as an exported program.
+    """``(table, code, ends, end_rows, counts, rows, carry) -> (table,
+    carry)``: the kernel on one chunk of a ``shape`` table, as an
+    exported program.
 
     Tracing and lowering the kernel is Python work of a second or two
     in every process that builds a rows program, beside the import of
@@ -120,15 +144,16 @@ def _chunk_program(shape, dtype, chunk: int, cache_dir: str):
             log.error("row_scatter: %s unreadable (%r); rebuilding", path, e)
     from . import row_scatter_kernel
 
-    def on_chunk(table, code, rows, carry):
+    def on_chunk(*args):
         with jax.named_scope("mv.update.scatter_add"):
-            return row_scatter_kernel.rmw_chunk(table, code, rows, carry,
-                                                False)
+            return row_scatter_kernel.rmw_chunk(*args, interpret=False)
 
     shaped = jax.ShapeDtypeStruct
+    ids, rows = shaped((chunk,), jnp.int32), shaped((chunk, shape[1]), dtype)
     program = export.export(jax.jit(on_chunk), platforms=["tpu"])(
-        shaped(shape, dtype), shaped((chunk,), jnp.int32),
-        shaped((chunk, shape[1]), dtype), shaped((8, shape[1]), dtype))
+        shaped(shape, dtype), ids, ids, ids,
+        shaped((chunk // TILE,), jnp.int32), rows,
+        shaped((8, shape[1]), dtype))
     if path:
         scratch = f"{path}.{os.getpid()}.{threading.get_ident()}"
         try:
@@ -141,12 +166,13 @@ def _chunk_program(shape, dtype, chunk: int, cache_dir: str):
     return program.call
 
 
-def _rmw(table, code, perm, n_live, delta, interpret):
+def _rmw(table, code, perm, n_live, ends, end_rows, counts, delta,
+         interpret):
     """The live positions a chunk at a time: a chunk's delta rows are
-    gathered into sorted order (XLA's gather moves a row in a tenth of
-    the time a DMA of the kernel's own would take to issue), so the
-    temporaries are a chunk's rows whatever the id count, and the
-    chunks behind the last live position are not visited at all."""
+    gathered into sorted order (XLA's gather moves a row in 8 ns, a DMA
+    of the kernel's own takes 14 to issue), so the temporaries are a
+    chunk's rows whatever the id count, and the chunks behind the last
+    live position are not visited at all."""
     chunk = min(CHUNK, code.shape[0])
     if interpret:
         from . import row_scatter_kernel
@@ -157,12 +183,15 @@ def _rmw(table, code, perm, n_live, delta, interpret):
             table.shape, table.dtype.name, chunk,
             jax.config.jax_compilation_cache_dir or "")
 
+    tiles = chunk // TILE
+
     def one(i, state):
         table, carry = state
-        at = i * chunk
-        rows = delta[lax.dynamic_slice(perm, (at,), (chunk,))]
-        return on_chunk(table, lax.dynamic_slice(code, (at,), (chunk,)),
-                        rows, carry)
+        own = [lax.dynamic_slice(part, (i * chunk,), (chunk,))
+               for part in (code, ends, end_rows, perm)]
+        return on_chunk(table, *own[:3],
+                        lax.dynamic_slice(counts, (i * tiles,), (tiles,)),
+                        delta[own[3]], carry)
 
     carry = jnp.zeros((8, table.shape[1]), table.dtype)
     table, _ = lax.fori_loop(0, -(-n_live // chunk), one, (table, carry))
@@ -171,9 +200,9 @@ def _rmw(table, code, perm, n_live, delta, interpret):
 
 def _scatter_add_runs(table, row_ids, delta, lo, interpret):
     with jax.named_scope("mv.update.dedup"):
-        code, perm, n_live = sorted_runs(row_ids, lo, table.shape[0])
+        runs = sorted_runs(row_ids, lo, table.shape[0])
     with jax.named_scope("mv.update.scatter_add"):
-        return _rmw(table, code, perm, n_live, delta, interpret)
+        return _rmw(table, *runs, delta, interpret)
 
 
 def scatter_add(table, row_ids, delta, mesh=None, interpret=False):

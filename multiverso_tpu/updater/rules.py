@@ -115,11 +115,14 @@ def scatter_add(data, row_ids, step, mesh=None):
 
     One algorithm in two forms, chosen by ``fast_rows``. Small id
     counts, other dtypes and other backends take XLA's scatter, which
-    applies the ids as they come. Otherwise the ids are sorted (scope
-    ``mv.update.dedup``), the deltas of equal ids are summed in float32
-    in the order of their positions in ``row_ids``, and each table row
-    is read and written once: a row named once gets ``row + delta`` bit
-    for bit as XLA's scatter gives it; a row named n times gets
+    applies the ids as they come. Otherwise the ids are sorted and every
+    tile's run ends listed (scope ``mv.update.dedup``, all XLA's), the
+    deltas of equal ids are summed in float32 in the order of their
+    positions in ``row_ids``, and each table row is read and written
+    once, by one DMA each of the kernel's own (scope
+    ``mv.update.scatter_add``: XLA's gather of the delta rows into
+    sorted order, and the kernel): a row named once gets ``row + delta``
+    bit for bit as XLA's scatter gives it; a row named n times gets
     ``row + (d1 + ... + dn)`` where XLA's gives ``((row + d1) + ...)``."""
     n_ids = int(np.prod(row_ids.shape))
     if not fast_rows(data.shape, data.dtype, n_ids, mesh):

@@ -143,9 +143,10 @@ def test_the_trainers_group_program_compiles_for_a_v5e(
     # Both tables are updated in place through the scan and the
     # kernel's loop: a copy of one would be a table's size (512 MB) of
     # temporaries. What is there is what the program had with XLA's
-    # scatter (121.6 MB then, 122.1 now): the padded stream and its
-    # sentences, a step's gathered rows and gradients, and now the
-    # concatenated deltas and one chunk of sorted delta rows.
+    # scatter (121.6 MB then, 122.1 with the kernel, 123.4 with the
+    # listed ends): the padded stream and its sentences, a step's
+    # gathered rows and gradients, the concatenated deltas, the sorted
+    # ids' lists and one chunk of sorted delta rows.
     assert memory.alias_size_in_bytes >= 2 * ROWS * COLS * 4
     assert memory.temp_size_in_bytes < 150e6
 

@@ -221,7 +221,8 @@ class TestDCASGD:
 # equal ids and writes every row once (rules.fast_rows picks the path).
 # Here the same kernel runs in Pallas' interpreter on the CPU and is
 # held to XLA's scatter: bit for bit where every id is named once,
-# within float32 rounding where ids repeat (the sum's order differs).
+# within float32 rounding where ids repeat (the sum's order differs);
+# and bit for bit, everywhere, to the sums in the order it states.
 
 import functools  # noqa: E402
 
@@ -266,6 +267,12 @@ RUNS_CASES = {
     "more_than_a_chunk": (lambda rng: rng.integers(
         0, ROWS, row_scatter.CHUNK + 2616), False),
     "fewer_ids_than_a_tile": (lambda rng: rng.integers(0, 50, 40), False),
+    # two tiles whose every position is a run end: no tail in their lists
+    "every_position_an_end": (lambda rng: rng.permutation(ROWS)[:2 * TILE],
+                              True),
+    # each tile is one run and its last position the only end
+    "a_run_ends_with_its_tile": (lambda rng: rng.permutation(
+        np.repeat([9, 2077], TILE)), False),
 }
 
 
@@ -301,6 +308,99 @@ def test_sorted_runs_scatter_add_equals_xlas(case):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
         once = np.setdiff1d(np.arange(ROWS), ids[ids >= 0])
         np.testing.assert_array_equal(got[once], want[once])
+
+
+def _stated_sums(table, ids, delta):
+    """``row + (d1 + d2 + ...)`` for every row named, its deltas in the
+    order of their positions, in float32: what the sorted-runs form has
+    given since it was written, one step of numpy at a time."""
+    flat = ids.reshape(-1)
+    flat = np.where(flat < 0, flat + ROWS, flat)
+    sums = {}
+    for row, d in zip(flat.tolist(), delta.reshape(-1, COLS)):
+        if 0 <= row < ROWS:
+            sums[row] = sums[row] + d if row in sums else d
+    want = table.copy()
+    for row, acc in sums.items():
+        want[row] = table[row] + acc
+    return want
+
+
+@pytest.mark.parametrize("devices", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(RUNS_CASES))
+def test_sorted_runs_scatter_add_is_the_stated_sum_bit_for_bit(
+        case, devices):
+    """The run ends come as a list and the reads a tile ahead: the
+    sums, their order and every bit of the result stay."""
+    from multiverso_tpu.sharding import mesh as meshlib
+    rng = np.random.default_rng(61)
+    ids = np.asarray(RUNS_CASES[case][0](rng), np.int32)
+    table, delta = _table_and_delta(rng, ids)
+    mesh, placed = None, table
+    if devices > 1:
+        mesh = meshlib.local_mesh(devices)
+        placed = jax.device_put(table, meshlib.row_sharded(mesh))
+    got = jax.jit(functools.partial(_interpreted, mesh=mesh))(
+        placed, ids, delta)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _stated_sums(table, ids, delta))
+
+
+def _listed_ends(ids, lo, num_rows):
+    """sorted_runs' ``(n_live, ends, end_rows, counts)`` by numpy: a
+    tile's run ends first and in order, then the last of them again."""
+    local = np.sort(ids[(ids >= lo) & (ids < lo + num_rows)]) - lo
+    pad = row_scatter.CHUNK if ids.size > row_scatter.CHUNK else TILE
+    is_end = np.zeros(-(-ids.size // pad) * pad, bool)
+    is_end[:local.size] = np.append(local[1:] != local[:-1], True)[
+        :local.size]
+    row = np.zeros(is_end.size, np.int64)
+    row[:local.size] = local
+    ends, end_rows, counts = [], [], []
+    for tile, rows in zip(is_end.reshape(-1, TILE), row.reshape(-1, TILE)):
+        (at,) = np.nonzero(tile)
+        counts.append(at.size)
+        at = np.append(at, np.full(TILE - at.size,
+                                   at[-1] if at.size else 0))
+        ends.append(at)
+        end_rows.append(rows[at] if counts[-1] else 0 * at)
+    return (local.size, np.concatenate(ends), np.concatenate(end_rows),
+            np.array(counts))
+
+
+@pytest.mark.parametrize("lo, num_rows", [(0, ROWS), (750, 750)])
+@pytest.mark.parametrize("case", sorted(RUNS_CASES))
+def test_sorted_runs_lists_every_tiles_run_ends(case, lo, num_rows):
+    """What the kernel walks for its writes, the whole table's and a
+    shard's (the second of four): a tile with no end because one run
+    covers it, a tile whose every position is one, the dead tail, a run
+    across a tile and a chunk."""
+    ids = np.asarray(RUNS_CASES[case][0](np.random.default_rng(61)),
+                     np.int32).reshape(-1)
+    ids = np.where(ids < 0, ids + ROWS, ids)
+    code, perm, n_live, ends, end_rows, counts = jax.jit(
+        row_scatter.sorted_runs, static_argnums=2)(ids, lo, num_rows)
+    want_live, want_ends, want_rows, want_counts = _listed_ends(
+        ids, lo, num_rows)
+    assert int(n_live) == want_live
+    np.testing.assert_array_equal(ends, want_ends)
+    np.testing.assert_array_equal(end_rows, want_rows)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert ends.shape == end_rows.shape == code.shape == perm.shape
+    # the listed ends are the code's ends, and the listed rows theirs
+    code = np.asarray(code).reshape(-1, TILE)
+    for tile, listed, rows, n in zip(code, want_ends.reshape(-1, TILE),
+                                     want_rows.reshape(-1, TILE),
+                                     want_counts):
+        at = tile[listed[:n]]
+        assert np.all(at & 1 == 1) and np.all(np.diff(at >> 2) > 0)
+        np.testing.assert_array_equal(at >> 2, rows[:n])
+    assert counts.sum() == np.unique(
+        ids[(ids >= lo) & (ids < lo + num_rows)]).size
+    if case == "one_long_run" and lo == 0:
+        assert list(want_counts) == [0, 0, 1]
+    if case == "every_position_an_end" and lo == 0:
+        assert list(want_counts) == [TILE, TILE]
 
 
 def test_a_run_sums_in_the_order_of_its_positions():
@@ -454,10 +554,14 @@ def build_chunk_program():
 
 
 def _lowers_for_the_tpu(program):
+    """``(table, code, ends, end_rows, counts, rows, carry)`` at a chunk
+    of two tiles."""
     shaped = jax.ShapeDtypeStruct
+    ids = shaped((2048,), jnp.int32)
     text = jax.jit(program).trace(
-        shaped((ROWS, COLS), np.float32), shaped((2048,), jnp.int32),
-        shaped((2048, COLS), np.float32), shaped((8, COLS), np.float32)
+        shaped((ROWS, COLS), np.float32), ids, ids, ids,
+        shaped((2,), jnp.int32), shaped((2048, COLS), np.float32),
+        shaped((8, COLS), np.float32)
     ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
     return "tpu_custom_call" in text and "mv.update.scatter_add" in text
 

@@ -104,10 +104,11 @@ def kernel(table, ids, delta):
 
 
 def dedup_only(table, ids, delta):
-    """(c)'s sort and run marks alone: what the kernel starts from."""
-    code, perm, n_live = row_scatter.sorted_runs(ids, 0, table.shape[0])
-    return table.at[0, 0].add((code[0] + perm[0] + n_live).astype(
-        table.dtype) * 0)
+    """(c)'s sort, run marks and listed ends alone: what the kernel
+    starts from."""
+    runs = row_scatter.sorted_runs(ids, 0, table.shape[0])
+    return table.at[0, 0].add(sum(part.reshape(-1)[0] for part in runs)
+                              .astype(table.dtype) * 0)
 
 
 SCAN_STEPS = 8
