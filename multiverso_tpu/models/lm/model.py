@@ -219,9 +219,9 @@ softmaxes, the router's product and the loss are float32.
 **Attention.** On a TPU the Pallas splash-attention kernel of
 ``jax.experimental.pallas.ops.tpu`` with the library's causal or local
 mask or ``_BlockDiffusionMask`` (the same predicate computed in the
-kernel from index arithmetic), which visits no block that is wholly
-masked, so a window layer costs less than a full one and a
-block-diffusion layer 80 of 256 tiles at 8192 positions; elsewhere
+kernel from index arithmetic), which visits no tile that is wholly
+masked, at tile sizes fitted to the call (``attention_blocks``: the mask,
+the length, the heads' lanes; 512 where nothing else won); elsewhere
 ``blockwise_attention``, the same sum over the same unmasked blocks in
 ``jax.numpy``. Neither materialises a [heads, T, T] array.
 
@@ -247,7 +247,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1311,14 +1311,119 @@ def _block_diffusion_mask(mask: Mask):
     return _block_diffusion_mask_class()(mask)
 
 
+class Blocks(NamedTuple):
+    """The splash kernels' tile sizes (the library's ``BlockSizes`` fields
+    of the same names): queries by keys a grid step of the forward, the dkv
+    and the dq kernel, and the keys a product inside a step of the first
+    two."""
+    block_q: int
+    block_kv: int
+    block_kv_compute: int
+    block_q_dkv: int
+    block_kv_dkv: int
+    block_kv_dkv_compute: int
+    block_q_dq: int
+    block_kv_dq: int
+
+
+PLAIN_BLOCK = 512
+PLAIN_BLOCKS = Blocks(*[PLAIN_BLOCK] * 8)
+
+
+def _wanted_blocks(mask: Mask, t: int, qk_lanes: int, v_lanes: int,
+                   per_group: int) -> Blocks:
+    """The sizes measured to win for a kind of call on a v5e
+    (``tools/splash_bench.py``, each kernel's time apart in a trace;
+    PERF.md section 6, PR 62, has the tables with what lost), and
+    ``PLAIN_BLOCKS`` for every kind that was not measured or where nothing
+    beat 512 by 2%. All at 8192 positions, against 512 everywhere:
+
+    - a mask that reaches far (causal; a window of 4096; block diffusion,
+      whose clean half is causal): tiles of 1024 x 1024 in the forward and
+      the dkv kernel, -16 to -27% and -6 to -22% of their time (a grid
+      step's cost and its fetches of q, k and v are paid a quarter as often;
+      2048 on either side is refused for fast memory or slower);
+    - the forward kernel's product: 512 keys at a time at 128 | 128 lanes
+      (256: the same), 256 at wider heads (192 | 128 -19% for 512's -16%,
+      256 | 256 -21% for -18%) and under block diffusion (-12% for -7%:
+      more of a diagonal tile's 256-key slices are masked whole); the dkv
+      kernel's 512 everywhere (1024 and 256 are within 0.7% of it, 1.6%
+      under block diffusion, and a product of all 1024 keys at 256 | 256
+      lanes passes the compiler's 16 MiB of fast memory by 0.9 in one
+      program and fits in another);
+    - the dq kernel: 1024 x 1024 too (-10% at 256 | 256 lanes to -26%),
+      but under block diffusion, where it is 3% SLOWER than 512;
+    - a window of 512 keeps 512 in all three kernels: every other size
+      lost by 15% or more (128 x 128 takes 3.8 to 5 times as long: a grid
+      step costs more than the masked half of a tile saves);
+    - 6 and 7 query heads a group chose alike, so ``per_group`` is not
+      read; at 4096 positions of 4 heads (192 | 128 lanes) every candidate
+      is within the noise of 512, so shorter sequences keep 512."""
+    del v_lanes, per_group
+    far = mask.kind in ("causal", "blockdiff") or mask.window >= 4096
+    if not far or t < 8192:
+        return PLAIN_BLOCKS
+    diffusion = mask.kind == "blockdiff"
+    compute = 512 if qk_lanes <= 128 and not diffusion else 256
+    dq = 512 if diffusion else 1024
+    return Blocks(1024, 1024, compute, 1024, 1024, 512, dq, dq)
+
+
+def _blocks_within(wanted: Blocks, t: int) -> Blocks:
+    """``wanted`` at length ``t``: a size that does not divide ``t``, or
+    exceeds it, falls to the largest multiple of 128 that does, and a
+    step's product to the largest that divides its step."""
+    def fit(size, whole):
+        return max(b for b in range(128, min(size, whole) + 1, 128)
+                   if whole % b == 0)
+
+    w = wanted
+    kv, kv_dkv = fit(w.block_kv, t), fit(w.block_kv_dkv, t)
+    return Blocks(fit(w.block_q, t), kv, fit(w.block_kv_compute, kv),
+                  fit(w.block_q_dkv, t), kv_dkv,
+                  fit(w.block_kv_dkv_compute, kv_dkv),
+                  fit(w.block_q_dq, t), fit(w.block_kv_dq, t))
+
+
+def attention_blocks(mask, t: int, qk_lanes: int = 128, v_lanes: int = 128,
+                     per_group: int = 1) -> Blocks:
+    """The tile sizes ``_splash`` gives the library's kernels for
+    ``per_group`` query heads a key-value head at length ``t`` (a multiple
+    of 128) under ``mask``, q and k of ``qk_lanes`` and v of ``v_lanes``: a
+    function of these alone, every size dividing ``t``
+    (``_blocks_within``)."""
+    return _blocks_within(_wanted_blocks(Mask.of(mask), t, qk_lanes,
+                                         v_lanes, per_group), t)
+
+
+def _in_kernel(t: int) -> bool:
+    """Whether ``attention_core`` takes the library's kernel at ``t``
+    positions."""
+    return jax.default_backend() == "tpu" and t % 128 == 0
+
+
+def attention_blocks_name(mask, t: int, qk_lanes: int = 128,
+                          v_lanes: int = 128, per_group: int = 1):
+    """The counter that one sequence of ``t`` positions through one layer's
+    ``attention_core`` adds one to (``PSLMTrainer._count_stats``): whether
+    its kernels' sizes are the rule's own or the plain 512 everywhere (None
+    where ``attention_core`` takes no kernel)."""
+    if not _in_kernel(t):
+        return None
+    fitted = attention_blocks(mask, t, qk_lanes, v_lanes, per_group) \
+        != _blocks_within(PLAIN_BLOCKS, t)
+    return "LM_ATTN_BLOCKS_FITTED" if fitted else "LM_ATTN_BLOCKS_PLAIN"
+
+
 @functools.lru_cache(maxsize=None)
-def _splash(t: int, per_group: int, mask):
+def _splash_at(t: int, per_group: int, mask, blocks: Blocks,
+               interpret: bool = False):
     """The TPU kernel for one key-value head and its ``per_group`` query
     heads at length ``t`` under ``mask`` (a ``Mask``, or an int: a window,
-    0 causal). Its mask is worked out on the host, once, and kept as
-    arrays; the first call comes from inside a program's trace, so they
-    are made under ``ensure_compile_time_eval``: a tracer kept here would
-    leak into the next program that takes the kernel."""
+    0 causal) at tiles of ``blocks``. Its mask is worked out on the host,
+    once, and kept as arrays; the first call comes from inside a program's
+    trace, so they are made under ``ensure_compile_time_eval``: a tracer
+    kept here would leak into the next program that takes the kernel."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
     mask = Mask.of(mask)
@@ -1328,13 +1433,20 @@ def _splash(t: int, per_group: int, mask):
     else:
         one = masks.LocalMask((t, t), (mask.window - 1, 0), 0) \
             if mask.kind == "window" else masks.CausalMask((t, t))
-    b = min(512, t)
-    sizes = kernel.BlockSizes(
-        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
-        block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b, block_kv_dq=b)
+    # the fused backward kernel stays off: it sums dq over key blocks in
+    # q's dtype (bfloat16) and holds t / block_kv_dkv copies of dq
+    sizes = kernel.BlockSizes(**blocks._asdict(), use_fused_bwd_kernel=False)
     with jax.ensure_compile_time_eval():
         return kernel.make_splash_mqa_single_device(
-            masks.MultiHeadMask([one] * per_group), block_sizes=sizes)
+            masks.MultiHeadMask([one] * per_group), block_sizes=sizes,
+            interpret=interpret)
+
+
+def _splash(t: int, per_group: int, mask, qk_lanes: int, v_lanes: int):
+    """``_splash_at`` the sizes ``attention_blocks`` chooses for the
+    call."""
+    return _splash_at(t, per_group, mask, attention_blocks(
+        mask, t, qk_lanes, v_lanes, per_group))
 
 
 def attention_core(q, k, v, mask):
@@ -1342,8 +1454,9 @@ def attention_core(q, k, v, mask):
     v [groups, T, d], bfloat16, under ``mask`` (a ``Mask``, or an int: a
     window, 0 causal)."""
     t = q.shape[2]
-    if jax.default_backend() == "tpu" and t % 128 == 0:
-        return jax.vmap(_splash(t, q.shape[1], mask))(q, k, v)
+    if _in_kernel(t):
+        return jax.vmap(_splash(t, q.shape[1], mask, q.shape[-1],
+                                v.shape[-1]))(q, k, v)
     return blockwise_attention(q, k, v, mask)
 
 

@@ -78,6 +78,9 @@ of every assignment, from the same count and ``model.experts_capacity``),
 ``LM_ATTN_PASS_FUSED`` or ``LM_ATTN_PASS_PLAIN`` (one a layer a sequence,
 the module's layer too: which form ``model.attention_inputs`` took,
 ``model.attention_pass_name``, or ``latent.inputs``, ``latent.pass_name``),
+``LM_ATTN_BLOCKS_FITTED`` or ``LM_ATTN_BLOCKS_PLAIN`` (the same: whether
+the attention kernels' tile sizes are ``model.attention_blocks``' own or
+512 everywhere; ``attn_blocks_names``),
 ``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
 sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
 ``LM_KDA_BETA_OVER_ONE`` of ``LM_KDA_BETA`` ((position, head) pairs of the
@@ -179,6 +182,32 @@ def attn_pass_names(cfg: LMConfig, positions: int, module: bool):
             return latent.pass_name(cfg, positions, rope)
         return None if kind == "kda" \
             else lm.attention_pass_name(cfg, positions, rope)
+
+    names = [name(layer) for layer in range(cfg.n_layers)]
+    return names + names[-1:] * bool(module)
+
+
+def attn_blocks_names(cfg: LMConfig, seq_len: int, module: bool):
+    """A layer each, and with ``module`` the multi-token module's layer
+    last: the counter one sequence of ``seq_len`` tokens through it adds
+    one to, by the tile sizes ``model._splash`` gives the attention's
+    kernels (``model.attention_blocks_name``: the rule the program chose
+    by, asked what ``attention_core`` asks it; None where the attention is
+    not ``attention_core``'s: a delta layer, a selected one, no TPU)."""
+    positions = seq_len * (2 if cfg.objective == "block_diffusion" else 1)
+
+    def name(layer):
+        kind = cfg.attention_of(layer)
+        if kind == "kda" or cfg.selection != "none":
+            return None
+        if kind == "mla":       # latent.core: each head a group, causal
+            return lm.attention_blocks_name(
+                0, positions, cfg.qk_nope_dim + cfg.qk_rope_dim,
+                cfg.v_head_dim, 1)
+        return lm.attention_blocks_name(
+            cfg.layer_mask(cfg.window_layout[layer], seq_len), positions,
+            cfg.head_dim, cfg.head_dim,
+            cfg.heads(layer) // cfg.n_kv_heads_held)
 
     names = [name(layer) for layer in range(cfg.n_layers)]
     return names + names[-1:] * bool(module)
@@ -527,6 +556,8 @@ class PSLMTrainer:
         # and which form each layer's ``model.attention_inputs`` (or
         # ``latent.inputs``) takes of a sequence: the counter's name
         self._attn_pass = attn_pass_names(cfg, positions, bool(self.module))
+        # and which tile sizes its attention's kernels run at
+        self._attn_blocks = attn_blocks_names(cfg, self.T, bool(self.module))
         # (held, all) of a step's heads, a layer a sequence (the module's
         # layer is of the last layer's kind)
         layers = list(range(cfg.n_layers)) \
@@ -824,7 +855,8 @@ class PSLMTrainer:
                         ("LM_EXPERTS_FULL", (~fits).sum())):
             if n:
                 count(name, int(n))
-        for name, s in zip(self._attn_pass, per_layer):
+        for name, s in zip(self._attn_pass + self._attn_blocks,
+                           per_layer * 2):
             if name:            # one a layer a sequence
                 count(name, len(s))
         outputs = self.cfg.n_experts

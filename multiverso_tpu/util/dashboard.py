@@ -262,6 +262,12 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_ATTN_PASS_PLAIN": "layers' sequences that took the jax.numpy "
                           "chain there (no TPU, no whole block of tokens "
                           "or tile of lanes, neither head norms nor a turn)",
+    "LM_ATTN_BLOCKS_FITTED": "layers' sequences whose attention kernels ran "
+                             "at tile sizes fitted to the call "
+                             "(model.attention_blocks: the mask's kind and "
+                             "reach, the length, the heads' lanes)",
+    "LM_ATTN_BLOCKS_PLAIN": "layers' sequences whose attention kernels "
+                            "kept tiles of 512 everywhere",
     "LM_KDA_TOKENS": "tokens through delta layers (models/lm/delta.py), "
                      "every such layer and sequence",
     "LM_KDA_CHUNKS": "chunks the delta layers' scans walked, a layer a "

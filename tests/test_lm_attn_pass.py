@@ -264,6 +264,7 @@ def test_the_trainer_counts_one_a_layer_a_sequence(forms, sequences, fused,
     trainer.cfg = _family("rotary_alone")[0]
     trainer._sparse, trainer._experts_cap = [1] * len(forms), 1 << 30
     trainer._attn_pass, trainer._heads = forms, (1, 1)
+    trainer._attn_blocks = []
     before = _counted()
     trainer._count_stats(([np.zeros((sequences, 2), int)] * len(forms), 5, 7))
     assert [a - b for a, b in zip(_counted(), before)] == [fused, plain]

@@ -392,6 +392,7 @@ def test_the_trainer_counts_which_buffer_each_sequence_took(held, sparse,
     trainer = PSLMTrainer.__new__(PSLMTrainer)
     trainer.cfg, trainer._sparse, trainer._experts_cap = CFG, sparse, CAP
     trainer._attn_pass, trainer._heads = [], (1, 1)
+    trainer._attn_blocks = []
     stats = [np.stack([np.asarray(row), np.zeros(len(row), int)], axis=1)
              for row in held]
 

@@ -353,6 +353,7 @@ def test_the_trainer_counts_one_a_latent_layer_a_sequence(
     trainer = PSLMTrainer.__new__(PSLMTrainer)
     trainer.cfg, trainer.T, trainer._heads = cfg, t, (1, 1)
     trainer._attn_pass = ps_train.attn_pass_names(cfg, t, module)
+    trainer._attn_blocks = []
     layers = cfg.n_layers + module
     assert len(trainer._attn_pass) == layers
     trainer._sparse, trainer._experts_cap = [1] * layers, 1 << 30

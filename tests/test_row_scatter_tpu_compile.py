@@ -196,18 +196,33 @@ def test_adams_rows_program_compiles_for_a_v5e_at_the_embeddings_width(
     assert memory.temp_size_in_bytes < 8 * 16384 * LM_COLS * 4
 
 
+def _splash_kernel(sizes, t, per_group, mask, qk_lanes=128, v_lanes=128):
+    """``model._splash`` at the sizes its rule chooses for the call
+    (``"chosen"``), or the same kernel at 512 everywhere (``"plain"``, what
+    every call ran until PR 62 and what a kind not in the rule's table
+    still runs)."""
+    from multiverso_tpu.models.lm import model as lm
+    if sizes == "chosen":
+        return lm._splash(t, per_group, mask, qk_lanes, v_lanes)
+    return lm._splash_at(t, per_group, mask,
+                         lm._blocks_within(lm.PLAIN_BLOCKS, t))
+
+
+SIZES = pytest.mark.parametrize("sizes", ["plain", "chosen"])
+
+
+@SIZES
 @pytest.mark.parametrize("window", [0, 4096])
 def test_the_attention_kernel_compiles_for_a_v5e_at_8192_positions(
-        topo, window):
+        topo, window, sizes):
     """Splash attention forward and backward for one sequence of the
     published head counts (4 key-value heads, 7 query heads each)."""
-    from multiverso_tpu.models.lm import model as lm
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     shaped = jax.ShapeDtypeStruct
     t = 8192
 
     def loss(q, k, v):
-        out = jax.vmap(lm._splash(t, 7, window))(q, k, v)
+        out = jax.vmap(_splash_kernel(sizes, t, 7, window))(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
@@ -246,7 +261,9 @@ def test_the_grouped_products_compile_for_a_v5e_at_the_experts_widths(
 
 # -- block diffusion (benchmark/configs/sdar-30b-a3b-l6.json) --------------------
 
-def test_the_attention_kernel_compiles_under_the_block_diffusion_mask(topo):
+@SIZES
+def test_the_attention_kernel_compiles_under_the_block_diffusion_mask(
+        topo, sizes):
     """Splash attention forward and backward under the program's own mask
     object (``lm.Mask.blockdiff``: integer division and comparisons on
     the positions' indices inside the kernel) for one sequence's 2 x 4096
@@ -258,7 +275,7 @@ def test_the_attention_kernel_compiles_under_the_block_diffusion_mask(topo):
     t, mask = 8192, lm.Mask.blockdiff(4096, 4)
 
     def loss(q, k, v):
-        out = jax.vmap(lm._splash(t, 8, mask))(q, k, v)
+        out = jax.vmap(_splash_kernel(sizes, t, 8, mask))(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
@@ -344,20 +361,20 @@ def test_the_mixers_passes_compile_for_a_v5e_at_the_published_widths(
 
 # -- the fourth family's attention (benchmark/configs/laguna-xs2-33b-a3b-l5.json) --
 
+@SIZES
 @pytest.mark.parametrize("per_group,window", [(6, 0), (8, 512)])
 def test_the_attention_kernel_compiles_at_both_of_laguna_s_kinds(
-        topo, per_group, window):
+        topo, per_group, window, sizes):
     """Splash attention forward and backward for one sequence of 8192 at
     the published head counts of each kind of layer (8 key-value heads: 6
     query heads each under the causal mask, 8 each under a window of 512,
     which is no wider than the kernel's block)."""
-    from multiverso_tpu.models.lm import model as lm
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     shaped = jax.ShapeDtypeStruct
     t = 8192
 
     def loss(q, k, v):
-        out = jax.vmap(lm._splash(t, per_group, window))(q, k, v)
+        out = jax.vmap(_splash_kernel(sizes, t, per_group, window))(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
@@ -376,17 +393,18 @@ def test_the_attention_kernel_compiles_at_both_of_laguna_s_kinds(
     (4, 192, 128, 4096),        # xing4-29b-a4b-l5: 4 of 32 heads held
     (32, 192, 128, 8192),       # kimi-linear-48b-a3b-l5's latent layer
     (20, 256, 256, 8192)])      # glm47-flash-30b-a3b-l5: every head
+@SIZES
 def test_latent_attention_s_kernel_compiles_at_the_published_lanes(
-        topo, heads, qk, v, tokens):
+        topo, heads, qk, v, tokens, sizes):
     """Splash attention forward and backward for one sequence as
     ``latent.core`` calls it: q [heads, 1, T, nope + rope], k [heads, T,
     nope + rope], v [heads, T, v_head_dim], no lane padded."""
-    from multiverso_tpu.models.lm import model as lm
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     shaped = jax.ShapeDtypeStruct
 
     def loss(q, k, v):
-        out = jax.vmap(lm._splash(tokens, 1, 0))(q, k, v)
+        out = jax.vmap(_splash_kernel(sizes, tokens, 1, 0, q.shape[-1],
+                                      v.shape[-1]))(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
