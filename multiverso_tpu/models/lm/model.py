@@ -149,6 +149,26 @@ holds BOTH kinds of attention as a share of their heads:
       other head, so the layer adds its heads' part of ``W_o``'s sum
       (``heads_of``; latent attention's share since the third family)
 
+**A ninth family** (``from_dict`` tells it by ``model_type: lfm2_moe``:
+LiquidAI's LFM2-8B-A1B, 2025) has a mixer that is NOT attention in three
+layers of four (``attention_layout``: ``conv`` | ``gqa`` a layer, as
+``layer_types`` publishes them, no period assumed), heads of 64 lanes, half a
+tile, in the others, ``num_dense_layers`` leading dense layers and ONE table
+for embedding and head (``tied``):
+
+    conv (shortconv.py): (B, C, X) = split_3(h W_in); z = B * X;
+      c[t] = sum_j w[:, j] z[t - 2 + j] (``conv_taps`` 3 a channel, zero
+      before the sequence, no bias, no activation); a = x + (C * c) W_out
+    gqa: q and k through an RMSNorm a head (``qk_norm``), every lane turned
+      at ``rope_theta``; the second family's attention at ``head_dim`` 64:
+      the library's kernel takes the half tile as it is
+      (``attention_core``), the one pass of attn_kernels.py does not
+      (``attention_pass_fused``: whole tiles) and the chain runs
+    the third family's feed-forward without a shared expert
+      (``sigmoid_bias``, ``shared_width`` 0); logits ``= . E^T`` with E the
+      embedding table itself: the trainer pulls it by rows AND whole and
+      makes one Add of both uses' gradients (ps_train.py)
+
 **A layer is described by three independent kinds**, and each selects
 functions, not a family's branch: its ATTENTION (the model's ``attention``,
 or where a model has more than one the LAYER's, ``attention_layout`` /
@@ -156,7 +176,7 @@ or where a model has more than one the LAYER's, ``attention_layout`` /
 ``attention_gate`` / ``attention_output`` under ``attention_vjp``, with
 ``heads_layout``, ``rotary_kinds``, ``attn_gate``, ``qk_norm``; ``mla`` ->
 latent.py, through the streams or, on the plain residual, through
-``attention_vjp`` too; ``kda`` -> delta.py), its
+``attention_vjp`` too; ``kda`` -> delta.py; ``conv`` -> shortconv.py), its
 FEED-FORWARD (``ffn_layout``, ``dense_width``, ``shared_width``,
 ``scoring``, ``routed_scale`` -> ``feed_forward_vjp``: ``dense_vjp`` |
 ``sparse_vjp`` on ONE normed input, for every layer of ``router_input:
@@ -239,7 +259,9 @@ multiply, forward and backward: ``mv.lm.attn.gate``. The fifth's:
 ``mv.lm.indexer``, ``mv.lm.select``, ``mv.lm.attn.sparse`` (+
 ``.kernel``), ``mv.lm.indexer.loss`` (sparse.py). The sixth's delta
 layers': ``mv.lm.attn.kda``, ``mv.lm.attn.kda.conv``,
-``mv.lm.attn.kda.scan`` (delta.py); its latent layer's the third's.
+``mv.lm.attn.kda.scan`` (delta.py); its latent layer's the third's. The
+ninth's convolution layers': ``mv.lm.attn.shortconv``,
+``mv.lm.attn.shortconv.taps`` (shortconv.py).
 """
 
 from __future__ import annotations
@@ -385,14 +407,17 @@ class LMConfig:
     # -- the attention's kind as a LAYER's, where a model has more than one --------
     attention_layout: Tuple[str, ...] = ()  # per layer: "mla" | "gqa" |
     #                                 "kda" (the delta rule's scan,
-    #                                 delta.py); (): ``attention`` in every
-    #                                 layer
+    #                                 delta.py) | "conv" (the gated short
+    #                                 convolution, shortconv.py); ():
+    #                                 ``attention`` in every layer
     kda_heads: int = 0              # kda: heads, each a state [d x d]
     kda_head_dim: int = 0
     kda_conv: int = 0               # kda: the short convolution's weights a
     #                                 channel
     kda_beta_scale: int = 1         # kda: beta = scale * sigmoid(h W_b); 2
     #                                 lets a state flip sign along a key
+    conv_taps: int = 0              # conv: the positions a channel reads
+    tied: bool = False              # ONE table is embedding and head
 
     @property
     def n_layers(self) -> int:
@@ -420,6 +445,8 @@ class LMConfig:
     def heads_of(self, layer: int) -> Tuple[int, int]:
         """``(held here, all)`` of the heads of the layer's attention."""
         kind = self.attention_of(layer)
+        if kind == "conv":      # a mixer without heads
+            return 0, 0
         if kind == "kda":
             return self.kda_heads_held, self.kda_heads
         if kind == "gqa" and self.heads_layout:
@@ -435,7 +462,8 @@ class LMConfig:
             else self.n_heads_held
 
     def attention_of(self, layer: int) -> str:
-        """The kind of the layer's attention: ``gqa`` | ``mla`` | ``kda``."""
+        """The kind of the layer's attention: ``gqa`` | ``mla`` | ``kda`` |
+        ``conv``."""
         return self.attention_layout[layer] if self.attention_layout \
             else self.attention
 
@@ -475,6 +503,9 @@ class LMConfig:
         if kind == "kda":
             from . import delta
             attention = delta.MATRICES
+        elif kind == "conv":
+            from . import shortconv
+            attention = shortconv.MATRICES
         elif kind == "mla":
             attention = MLA_MATRICES if self.q_lora_rank else MLA_DIRECT
         else:
@@ -507,6 +538,8 @@ class LMConfig:
         ``router_outputs`` the published number the router still has."""
         if c.get("model_type") == "solar_open2":
             return cls._from_solar(c)
+        if c.get("model_type") == "lfm2_moe":
+            return cls._from_lfm2(c)
         if "linear_attn_config" in c:
             return cls._from_kda(c)
         if "kv_lora_rank" in c:
@@ -873,6 +906,59 @@ class LMConfig:
             kda_conv=int(linear["short_conv_kernel_size"]),
             kda_beta_scale=2 if c["kda_allow_neg_eigval"] else 1)
 
+    @classmethod
+    def _from_lfm2(cls, c: dict) -> "LMConfig":
+        """The block of ``model_type: lfm2_moe`` (LiquidAI's LFM2-8B-A1B,
+        benchmark/configs/lfm2-8b-a1b-l8.json): ``layer_types`` says layer
+        by layer, as published and read up to ``num_hidden_layers``, whether
+        the mixer is a gated short convolution of ``conv_L_cache`` taps
+        (shortconv.py) or grouped-query attention with an RMSNorm a head on
+        q and k and every lane turned at ``rope_theta``; the first
+        ``num_dense_layers`` layers have a dense MLP, the others silu experts
+        under a sigmoid router that chooses through a bias the server keeps
+        (``use_expert_bias``) and normalises its top-k, with no shared
+        expert; embedding and head are ONE table (``tie_word_embeddings``).
+        ``num_experts`` gives the experts HELD and ``router_outputs`` the
+        published number; ``head_dim`` (hidden / heads when absent),
+        ``tie_word_embeddings``, ``router_bias_rate`` and ``hidden_act`` the
+        published config does not state."""
+        n, dense = int(c["num_hidden_layers"]), int(c["num_dense_layers"])
+        kinds = {"conv": "conv", "full_attention": "gqa"}
+        types = list(c["layer_types"][:n])
+        CHECK(not c["conv_bias"] and c["norm_topk_prob"]
+              and c["use_expert_bias"] and len(types) == n
+              and all(t in kinds for t in types) and 0 <= dense <= n,
+              "only the block whose convolutions have no bias, whose router "
+              "chooses through a bias and normalises its top-k, and whose "
+              "every layer is a convolution or full attention, is written "
+              "down here")
+        layout = tuple(kinds[t] for t in types)
+        hidden, heads = int(c["hidden_size"]), int(c["num_attention_heads"])
+        return cls(
+            hidden=hidden, n_heads=heads,
+            n_kv_heads=int(c["num_key_value_heads"]),
+            head_dim=int(c.get("head_dim") or hidden // heads),
+            n_experts=int(c["router_outputs"]),
+            top_k=int(c["num_experts_per_tok"]),
+            expert_width=int(c["moe_intermediate_size"]),
+            experts_held=(int(c.get("first_expert_held", 0)),
+                          int(c["num_experts"])),
+            vocab=int(c["vocab_size"]),
+            rope_layout=tuple(int(k == "gqa") for k in layout),
+            window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]), eps=float(c["norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c.get("hidden_act", "silu")),
+            router_input="ffn_input", qk_norm=True,
+            attention_layout=layout,
+            ffn_layout=(0,) * dense + (1,) * (n - dense),
+            dense_width=int(c["intermediate_size"]), shared_width=0,
+            scoring="sigmoid_bias",
+            routed_scale=float(c["routed_scaling_factor"]),
+            bias_rate=float(c["router_bias_rate"]),
+            conv_taps=int(c["conv_L_cache"]),
+            tied=bool(c.get("tie_word_embeddings", True)))
+
     def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it, built from
         the layer's kinds: a matrix table's (rows, columns) or a small
@@ -885,6 +971,9 @@ class LMConfig:
         if kind == "kda":
             from . import delta
             shapes = delta.shapes(self)
+        elif kind == "conv":
+            from . import shortconv
+            shapes = shortconv.shapes(self)
         elif kind == "mla":
             # over the held heads: ``wq_b``, ``wkv_b``, ``wo`` cut by head,
             # each head's columns together, ``[nope | rope]``, ``[k nope | v]``
@@ -932,7 +1021,7 @@ class LMConfig:
                 s = self.shared_width
                 shapes.update({"ws_gate": (h, s), "ws_up": (h, s),
                                "ws_down": (s, h)})
-        if self.qk_norm:
+        if self.qk_norm and kind == "gqa":
             shapes.update({n: (d,) for n in QK_NORMS})
         if self.selection != "none":
             from . import sparse
@@ -953,7 +1042,9 @@ class LMConfig:
         layers = sum(size(self.layer_shapes(i)) for i in range(self.n_layers))
         module = self.mtp_layers * (size(self.mtp_shapes()) + size(
             self.layer_shapes(self.n_layers - 1))) if self.mtp_layers else 0
-        return layers + module + 2 * self.vocab * self.hidden + self.hidden
+        tables = 1 if self.tied else 2      # of [vocab, hidden]
+        return (layers + module + tables * self.vocab * self.hidden
+                + self.hidden)
 
 
 # -- products -------------------------------------------------------------
@@ -1358,7 +1449,12 @@ def _wanted_blocks(mask: Mask, t: int, qk_lanes: int, v_lanes: int,
       step costs more than the masked half of a tile saves);
     - 6 and 7 query heads a group chose alike, so ``per_group`` is not
       read; at 4096 positions of 4 heads (192 | 128 lanes) every candidate
-      is within the noise of 512, so shorter sequences keep 512."""
+      is within the noise of 512, so shorter sequences keep 512;
+    - heads of 64 | 64 lanes, half a tile (8 groups of 4, causal; PERF.md
+      section 6, PR 63), choose the causal row's sizes in all three kernels:
+      forward -17%, dkv -21%, dq -20% of their time at 512, and nothing
+      else within 2% of them (2048 on either side of the dq kernel and of
+      the forward is refused for fast memory there too)."""
     del v_lanes, per_group
     far = mask.kind in ("causal", "blockdiff") or mask.window >= 4096
     if not far or t < 8192:
@@ -2045,11 +2141,15 @@ def _module_attention_vjp(cfg: LMConfig, kind: str, rope, mats, sinks,
                           small, x, pos):
     """``attention_vjp``'s results for the kinds of attention that live in
     modules of their own and give ``F(x)``: latent.py's (``mla``), delta.py's
-    (``kda``, which reads no position)."""
+    (``kda``) and shortconv.py's (``conv``), which read no position."""
     stats = {}
     if kind == "kda":
         from . import delta
         out, stats, pull_f = delta.attention_vjp(cfg, mats, sinks, small, x)
+    elif kind == "conv":
+        from . import shortconv
+        out, stats, pull_f = shortconv.attention_vjp(cfg, mats, sinks, small,
+                                                     x)
     else:
         from . import latent
         out, pull_f = latent.attention_vjp(cfg, mats, sinks, small, x, pos,

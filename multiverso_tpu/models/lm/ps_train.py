@@ -51,6 +51,14 @@ half gets its gradients through the keys and values the noised half
 reads. The embedding's Get and Add name ``2 B T`` ids, the mask token's
 about a quarter of them.
 
+**One table for embedding and head** (``LMConfig.tied``). The step keeps
+its device-key Get of the step's rows and its whole-table Get at the head,
+of the SAME table, and makes ONE Add to it, last: the head's gradient
+[vocab, hidden] with the rows' gradients added into it (``_tie``, a program
+under ``mv.lm.embed``), under dense Adam. Two Adds would be two Adam steps
+for a row that a step both reads and scores; the rows form has nothing left
+to do there. ``LM_TIED_ADDS`` counts the Add.
+
 The Adds are asynchronous; the next step's Gets of the same tables wait
 for them by the server's own order (an acknowledged Add is in every
 later Get). A layer's gradients leave for the server as soon as its
@@ -88,6 +96,12 @@ delta layers whose beta is over 1, where ``kda_beta_scale`` lets it be),
 ``LM_GATE_LANES_OPEN`` of ``LM_GATE_LANES`` (a lane gate's lanes over a
 half), ``LM_HEADS_HELD`` of ``LM_HEADS`` (a layer a sequence: the heads of
 its attention held here, of all it has; from the host),
+``LM_MIXERS_CONV`` of ``LM_MIXERS`` (a layer a sequence: the mixers that are
+gated short convolutions, of all; with a ``conv`` layer, from the host),
+``LM_ATTN_LANES`` of ``LM_ATTN_LANES_TILED`` (the same models' attention
+layers, a layer a sequence: the lanes a head holds, of the lanes the
+attention kernel is handed a head: equal where a head under a 128-lane tile
+goes to it unpadded),
 ``LM_MTP_TOKENS`` (positions a multi-token module predicted from: ``B T``
 a step that held one), ``LM_EMBED_ROWS`` (distinct embedding rows) and
 ``LM_MASKED_TOKENS`` (positions that carry a loss: the masked ones) are
@@ -175,12 +189,12 @@ def attn_pass_names(cfg: LMConfig, positions: int, module: bool):
     last (it is of the last layer's kinds): the counter one sequence of
     ``positions`` through it adds one to, by the form that the way from
     its attention's products to the kernel took (None where there is none:
-    a delta layer)."""
+    a delta layer, a convolution layer)."""
     def name(layer):
         kind, rope = cfg.attention_of(layer), cfg.rope_layout[layer]
         if kind == "mla":
             return latent.pass_name(cfg, positions, rope)
-        return None if kind == "kda" \
+        return None if kind in ("kda", "conv") \
             else lm.attention_pass_name(cfg, positions, rope)
 
     names = [name(layer) for layer in range(cfg.n_layers)]
@@ -198,7 +212,7 @@ def attn_blocks_names(cfg: LMConfig, seq_len: int, module: bool):
 
     def name(layer):
         kind = cfg.attention_of(layer)
-        if kind == "kda" or cfg.selection != "none":
+        if kind in ("kda", "conv") or cfg.selection != "none":
             return None
         if kind == "mla":       # latent.core: each head a group, causal
             return lm.attention_blocks_name(
@@ -493,13 +507,18 @@ class PSLMTrainer:
             return create_array_table(
                 shape[0], fill=0.0 if name.endswith("_b") else 1.0)
 
+        # Where ONE table is embedding and head (``cfg.tied``) this draw is
+        # both, and the configuration gives ``embedding_std`` at the
+        # matrices' size: at 1 a row's logit against itself would be
+        # ``hidden``.
         self.embedding = matrix((cfg.vocab, cfg.hidden), embedding_std)
         self.layers: List[Dict[str, object]] = []
         for i in range(cfg.n_layers):
             self.layers.append({name: table(name, shape) for name, shape
                                 in cfg.layer_shapes(i).items()})
         self.final_norm = create_array_table(cfg.hidden, fill=1.0)
-        self.head = matrix((cfg.vocab, cfg.hidden))
+        self.head = self.embedding if cfg.tied \
+            else matrix((cfg.vocab, cfg.hidden))
         # the multi-token module: its own tensors, then its sparse layer's
         self.module: Dict[str, object] = {}
         if cfg.mtp_layers:
@@ -513,7 +532,14 @@ class PSLMTrainer:
             self.module = {name: table(name, shape)
                            for name, shape in shapes.items()}
             self._module = module_programs(cfg)
-        self._whole_bytes = 4 * (cfg.parameters() - cfg.vocab * cfg.hidden)
+        CHECK(not cfg.tied or not (cfg.mtp_layers or self.diffusion
+                                   or cfg.residual == "mhc"),
+              "one table for embedding and head: on the plain residual under "
+              "the next-token objective, without a multi-token module")
+        # whole-table traffic: every table but the embedding, which goes by
+        # rows; a tied table is pulled whole at the head and pushed whole
+        self._whole_bytes = 4 * (cfg.parameters() - (
+            0 if cfg.tied else cfg.vocab * cfg.hidden))
 
         kinds = sorted(set(cfg.layer_kinds()))
         # a kind is (rotary, window[, sparse[, query heads]]); the heads
@@ -564,6 +590,14 @@ class PSLMTrainer:
             + [cfg.n_layers - 1] * bool(self.module)
         self._heads = tuple(self.B * sum(heads) for heads in zip(
             *(cfg.heads_of(i) for i in layers)))
+        # the mixers that are convolutions, of all, and the lanes a head of
+        # the others holds, a layer a sequence (``_count_stats``)
+        kinds_of = [cfg.attention_of(i) for i in range(cfg.n_layers)]
+        self._mixers = (self.B * kinds_of.count("conv"),
+                        self.B * cfg.n_layers)
+        self._attn_lanes = self.B * kinds_of.count("gqa") * cfg.head_dim
+        self._tie = jax.jit(self._tie_gradients, donate_argnums=(0,)) \
+            if cfg.tied else None
         self._noise = noise_program(cfg) if self.diffusion else None
         self._noise_key = jax.random.PRNGKey(seed)
         self._head_program = head_program(cfg)
@@ -581,7 +615,9 @@ class PSLMTrainer:
         out = {"embedding": self.embedding}
         for i, layer in enumerate(self.layers):
             out.update({f"layer{i}.{name}": t for name, t in layer.items()})
-        out.update({"final_norm": self.final_norm, "head": self.head})
+        out["final_norm"] = self.final_norm
+        if not self.cfg.tied:       # tied: the embedding IS the head
+            out["head"] = self.head
         own = self.cfg.mtp_shapes() if self.module else ()
         out.update({f"mtp.{name}" if name in own else f"mtp.layer.{name}": t
                     for name, t in self.module.items()})
@@ -604,6 +640,15 @@ class PSLMTrainer:
             ids = tokens[:, :-1]
             return ids, (tokens[:, 1:-1].reshape(-1),
                          tokens[:, 2:].reshape(-1)), _distinct(ids)
+
+    @staticmethod
+    def _tie_gradients(d_head, ids, d_rows):
+        """One table's gradient from both of its uses: the head's [vocab,
+        hidden] with the gradient of each position's embedding row added to
+        the row its id names."""
+        with jax.named_scope("mv.lm.embed"):
+            return d_head.at[ids.reshape(-1)].add(
+                d_rows.reshape(-1, d_rows.shape[-1]))
 
     # -- the streams' two ends (streams.py; under mv.lm.embed) -------------------
     def _enter_streams(self, rows):
@@ -706,6 +751,16 @@ class PSLMTrainer:
             msg_id = table.add_rows_async(ids, delta, self.option)
         self._pending.append((table, msg_id))
 
+    def _push_embedding(self, d_head, ids, d_rows) -> None:
+        """The embedding's Add, the step's last: its rows' gradient by the
+        step's ids as device keys; a tied table's ONE Add, whole, of both
+        uses' gradients summed (``_tie_gradients``; under Adam two Adds
+        would be two steps of the moments)."""
+        if not self.cfg.tied:
+            return self._push(self.embedding, d_rows, ids)
+        self._push(self.embedding, _dispatch(self._tie, d_head, ids, d_rows))
+        count("LM_TIED_ADDS")
+
     def _drain(self, upto=None) -> None:
         """Wait for the pending Adds' acknowledgements: all, or the first
         ``upto`` (which stay listed; waiting again costs nothing)."""
@@ -771,7 +826,8 @@ class PSLMTrainer:
                 (loss, dx, d_head), de_next = _dispatch(
                     self._sum, (loss, dx, d_head), second[:3]), second[3]
             with monitor("LM_ADD_GRADS"):
-                self._push(self.head, d_head)
+                if not cfg.tied:    # tied: with the rows' gradient, below
+                    self._push(self.head, d_head)
                 self._push(self.final_norm, d_norm)
             if self.streams:
                 dx = _dispatch(self._leave_back, dx)
@@ -800,7 +856,7 @@ class PSLMTrainer:
                 dx = _dispatch(self._enter_back, dx,
                                de_next if self.module else None)
             with monitor("LM_ADD_GRADS"):
-                self._push(self.embedding, dx, ids)
+                self._push_embedding(d_head, ids, dx)
         self.steps += 1
         self.last_loss, self._last_dx = loss, dx
         if inner_losses:
@@ -912,6 +968,12 @@ class PSLMTrainer:
             deep = int(sum(s[:, -1].astype(np.int64).sum() for s in scanned))
             if deep:
                 count("LM_KDA_DECAY_DEEP", deep)
+        if "conv" in self.cfg.attention_layout:
+            count("LM_MIXERS_CONV", self._mixers[0])
+            count("LM_MIXERS", self._mixers[1])
+            # a head goes to ``model.attention_core`` at its own lanes
+            count("LM_ATTN_LANES", self._attn_lanes)
+            count("LM_ATTN_LANES_TILED", self._attn_lanes)
         count("LM_EMBED_ROWS", int(distinct))
         count("LM_MASKED_TOKENS", int(scored))
 

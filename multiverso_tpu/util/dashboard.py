@@ -310,6 +310,16 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_HEADS": "heads of the layers' attention as published, a layer a "
                 "sequence",
     "LM_HEADS_HELD": "of those, the heads held here (LMConfig.heads_held)",
+    "LM_MIXERS": "mixers of a model with a convolution layer "
+                 "(LMConfig.attention_layout \"conv\"), a layer a sequence",
+    "LM_MIXERS_CONV": "of those, the gated short convolutions "
+                      "(models/lm/shortconv.py)",
+    "LM_ATTN_LANES": "the same models' attention layers, a layer a "
+                     "sequence: the lanes a head holds",
+    "LM_ATTN_LANES_TILED": "the lanes the attention kernel is handed a "
+                           "head: the same where no head is padded",
+    "LM_TIED_ADDS": "the one Add a step to a table that is embedding and "
+                    "head (LMConfig.tied)",
     # -- thread-role blocking watchdog (runtime/thread_roles.py;
     #    docs/THREADS.md) --
     "ROLE_BLOCKED_MS[*]": "wall-clock ms a DISPATCH/LIVENESS/"

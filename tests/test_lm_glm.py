@@ -175,8 +175,9 @@ def test_an_older_latent_configuration_builds_what_it_built(name, rehearse):
     if rehearse:
         config.update(tiny)
     cfg = lm.LMConfig.from_dict(config)
-    # a field added since (PR 60) stands at its default and is last
-    built = repr(cfg).replace(", kda_beta_scale=1)", ")")
+    # the fields added since (PRs 60, 63) stand at their defaults, last
+    built = repr(cfg).replace(
+        ", kda_beta_scale=1, conv_taps=0, tied=False)", ")")
     assert hashlib.sha256(built.encode()).hexdigest()[:16] \
         == PARENT_BUILT[name, rehearse]
 

@@ -418,6 +418,69 @@ def test_latent_attention_s_kernel_compiles_at_the_published_lanes(
         < 16 * heads * tokens * (qk + v)
 
 
+# -- heads under a tile (lfm2-8b-a1b-l8: 8 groups of 4 query heads of 64
+# lanes, PR 63) ------------------------------------------------------------------
+
+@SIZES
+def test_the_attention_kernel_compiles_at_heads_of_half_a_tile(topo, sizes):
+    """The library's three kernels (forward, dkv, dq) take q, k and v of 64
+    lanes as they are: no lane is padded at the kernel's door."""
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    shaped = jax.ShapeDtypeStruct
+    t = 8192
+
+    def loss(q, k, v):
+        out = jax.vmap(_splash_kernel(sizes, t, 4, 0, 64, 64))(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shaped((8, 4, t, 64), jnp.bfloat16, sharding=one),
+        shaped((8, t, 64), jnp.bfloat16, sharding=one),
+        shaped((8, t, 64), jnp.bfloat16, sharding=one)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # no head padded to a tile: no bfloat16 array of 128 lanes (the float32
+    # [.., 8192, 128] beside the kernels is the library's log-sum-exp)
+    assert "bf16[8,4,8192,128]" not in text and "bf16[8,8192,128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+
+
+def test_a_convolution_sublayer_s_chain_is_one_pass_each_way(topo):
+    """models/lm/shortconv.py at the published widths, one sequence of 8192
+    tokens, forward and pull: between the two products the compiler leaves
+    fusions alone (no copy of a [8192, 2048] float32 array for the shifts
+    along positions), and what the program holds is a few such arrays."""
+    from multiverso_tpu.models.lm import model as lm, shortconv
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "lfm2-8b-a1b-l8.json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    t, h = 8192, cfg.hidden
+    assert (h, cfg.conv_taps) == (2048, 3)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def sublayer(mats, small, x, d):
+        out, _, pull = shortconv.attention_vjp(
+            cfg, mats, lm._zeros_like_f32(mats), small, x)
+        return out, pull(d)
+
+    shapes = cfg.layer_shapes(0)
+    mats = {n: shaped(shapes[n], jnp.bfloat16) for n in shortconv.MATRICES}
+    small = {n: shaped(shapes[n], jnp.float32)
+             for n in ("norm_attn", shortconv.TAPS)}
+    compiled = jax.jit(sublayer).lower(
+        mats, small, shaped((t, h), jnp.float32),
+        shaped((t, h), jnp.float32)).compile()
+    top = [line for line in compiled.as_text().splitlines()
+           if "shortconv.taps" in line and " copy(" in line]
+    assert not top, top[:3]
+    # h W_in [8192, 6144] float32 is 201 MB; a handful of its size, not dozens
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 # -- the pass between the attention's projections and its kernel -----------------
 # (models/lm/attn_kernels.py; interpreted against the chain in
 # tests/test_lm_attn_pass.py)

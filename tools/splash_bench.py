@@ -74,6 +74,9 @@ KINDS = {
                           mask=0),
     "causal-4k-192": dict(t=4096, groups=4, per_group=1, qk=192, v=128,
                           mask=0),
+    # lfm8b.ps-8k's two attention layers: heads of 64 lanes, half a tile
+    "causal-8k-64": dict(t=8192, groups=8, per_group=4, qk=64, v=64,
+                         mask=0),
     # sdar30b.ps-bd4k: 2 x 4096 positions, blocks of 4
     "blockdiff-8k-128": dict(t=8192, groups=4, per_group=8, qk=128, v=128,
                              mask=("blockdiff", 4096, 4)),
