@@ -68,6 +68,12 @@ Around it the heads' arrays stay [T, H K], the layout the projections
 leave: ``gates`` and ``output`` work a head at a time on ``heads_apart``'s
 view, and ``attention_vjp`` hands [T, H K] from part to part, so that no
 copy turns 8 positions by 128 lanes into 8 heads by 128 lanes and back.
+There too, at whole blocks of 512 tokens (``passes_fused``), ``gates`` and
+``output``'s gated norm are delta_passes.py's Pallas passes, one over memory
+each way with hand-written pulls, and where nothing is pulled through them
+q's and k's convolutions go inside the gates' pass (``conv_gates_flat``);
+``short_conv``, ``gates`` and ``output`` below stay their definition and
+every other shape's path.
 
 **No division by a decay.** Every exponent is a difference of summed log
 decays that is <= 0: ``G_t``, ``G_last - G_s``, and ``G_t - G_s`` for ``s
@@ -434,6 +440,85 @@ def output(cfg: LMConfig, mats, sinks, norm_o, o, gate):
     return lm.mm(o * jax.nn.sigmoid(gate), mats["wo"], sinks["wo"])
 
 
+#: ``short_conv``, ``gates`` and ``output`` as this module defines them: a
+#: caller that has put its own in their place (the checks' controls do)
+#: keeps the chain.
+_DEFINED = short_conv, gates, output
+
+
+def passes_fused(cfg: LMConfig, t: int) -> bool:
+    """Whether ``gates`` (with q's and k's convolutions where nothing is
+    pulled through them) and ``output``'s gated norm run as
+    delta_passes.py's Pallas passes, one over memory each way: on a TPU, at
+    whole blocks of tokens and heads of one 128-lane tile, with the three
+    functions the ones above. By what the code can see: no flag chooses.
+    Everywhere else the ``jax.numpy`` lines above, which are the
+    definition."""
+    if (jax.default_backend() != "tpu"
+            or (short_conv, gates, output) != _DEFINED):
+        return False
+    from . import delta_passes      # Pallas: imported where it can run
+    return delta_passes.fits(t, cfg.kda_head_dim)
+
+
+def pass_counter(cfg: LMConfig, t: int) -> str:
+    """The counter a delta layer's sequence of ``t`` tokens counts: which
+    form its gates and gated norm took (``PSLMTrainer._count_stats``)."""
+    return "LM_KDA_PASS_FUSED" if passes_fused(cfg, t) \
+        else "LM_KDA_PASS_PLAIN"
+
+
+def _passes(cfg: LMConfig):
+    from . import delta_passes
+    return delta_passes, delta_passes.Pass(
+        cfg.kda_heads_held, float(cfg.kda_beta_scale), cfg.eps)
+
+
+def gates_flat(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
+    """``gates`` with the heads' arrays [T, H K] in and out, as
+    ``attention_vjp``'s parts exchange them: one pass over memory where
+    ``passes_fused``."""
+    t = q.shape[0]
+    if passes_fused(cfg, t):
+        passes, how = _passes(cfg)
+        q, k, g, beta = passes.gates(how, a_log, dt_bias, q, k, f, b)
+        return q, k, v, g, beta
+    *wide, beta = gates(cfg, a_log, dt_bias, q, k, v, f, b)
+    return (*(a.reshape(t, -1) for a in wide), beta)
+
+
+def conv_gates_flat(cfg: LMConfig, convs, a_log, dt_bias, q, k, v, f, b):
+    """``gates_flat`` of the three products' results through ``short_conv``
+    (``convs``: the three convolutions' weights), where nothing is pulled
+    through either: v's convolution under its scope, and where
+    ``passes_fused`` q's and k's INSIDE the gates' pass, which reads the
+    products' results and writes no convolved copy of them."""
+    if not passes_fused(cfg, q.shape[0]):
+        with jax.named_scope(SCOPE + ".conv"):
+            q, k, v = (short_conv(x, w) for x, w in zip((q, k, v), convs))
+        with jax.named_scope(SCOPE):
+            return gates_flat(cfg, a_log, dt_bias, q, k, v, f, b)
+    with jax.named_scope(SCOPE + ".conv"):
+        v = short_conv(v, convs[2])
+    with jax.named_scope(SCOPE):
+        passes, how = _passes(cfg)
+        q, k, g, beta = passes.conv_gates(how, *convs[:2], a_log, dt_bias,
+                                          q, k, f, b)
+    return q, k, v, g, beta
+
+
+def output_flat(cfg: LMConfig, mats, sinks, norm_o, o, gate):
+    """``output`` for ``o`` [T, H V] as the scan leaves it: the gated norm
+    as one pass where ``passes_fused``."""
+    t = o.shape[0]
+    if passes_fused(cfg, t):
+        passes, how = _passes(cfg)
+        return lm.mm(passes.gated_norm(how, norm_o, o, gate), mats["wo"],
+                     sinks["wo"])
+    return output(cfg, mats, sinks, norm_o,
+                  o.reshape(t, cfg.kda_heads_held, -1), gate)
+
+
 def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
     """``F(x)`` for one sequence and what pulls a cotangent back through
     it: ``(F(x), counts, pull)``, ``pull(d) -> (dx, matrix gradients, small
@@ -471,13 +556,14 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
         """``scan``'s five arguments from the projections (and with
         ``vjp=jax.vjp`` what pulls their cotangents back), each part under
         its scope."""
-        vjp = vjp or (lambda f, *a: (f(*a), None))
+        if vjp is None:
+            return conv_gates_flat(cfg, convs, a_log, dt_bias, q, k, v, f,
+                                   b), None
         with jax.named_scope(SCOPE + ".conv"):
             qkv, pull_conv = vjp(convolved, convs, q, k, v)
 
         def of(a_log, dt_bias, qkv, f, b):
-            *wide, beta = gates(cfg, a_log, dt_bias, *qkv, f, b)
-            return (*map(flat, wide), beta)
+            return gates_flat(cfg, a_log, dt_bias, *qkv, f, b)
 
         with jax.named_scope(SCOPE):
             scanned, pull_gates = vjp(of, a_log, dt_bias, qkv, f, b)
@@ -511,8 +597,8 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
                                               dtype=jnp.int32)
     with jax.named_scope(SCOPE):
         out, pull_output = jax.vjp(
-            lambda s, norm_o, o, gate: output(cfg, mats, {"wo": s}, norm_o,
-                                              by_head(o), gate),
+            lambda s, norm_o, o, gate: output_flat(cfg, mats, {"wo": s},
+                                                   norm_o, o, gate),
             sinks["wo"], small["norm_o"], o, gate)
 
     def pull(d_out):

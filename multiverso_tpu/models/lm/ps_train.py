@@ -91,6 +91,8 @@ the attention kernels' tile sizes are ``model.attention_blocks``' own or
 512 everywhere; ``attn_blocks_names``),
 ``LM_KDA_SCAN_KERNEL`` or ``LM_KDA_SCAN_PLAIN`` (one a delta layer a
 sequence: which form ``delta.scan`` took, ``delta.scan_counter``),
+``LM_KDA_PASS_FUSED`` or ``LM_KDA_PASS_PLAIN`` (the same: whether its gates
+and gated output norm ran as delta_passes.py's, ``delta.pass_counter``),
 ``LM_KDA_BETA_OVER_ONE`` of ``LM_KDA_BETA`` ((position, head) pairs of the
 delta layers whose beta is over 1, where ``kda_beta_scale`` lets it be),
 ``LM_GATE_LANES_OPEN`` of ``LM_GATE_LANES`` (a lane gate's lanes over a
@@ -952,9 +954,11 @@ class PSLMTrainer:
             sequences = sum(len(s) for s in scanned)
             chunks = sequences * (self.T // delta.chunk_of(self.T))
             count("LM_KDA_TOKENS", sequences * self.T)
-            # which form each of their scans took: the test delta.scan
+            # which form each of their scans, and of their gates and gated
+            # norms, took: the tests delta.scan and delta.attention_vjp
             # chose by
             count(delta.scan_counter(self.cfg, self.T), sequences)
+            count(delta.pass_counter(self.cfg, self.T), sequences)
             count("LM_KDA_CHUNKS", chunks)
             heads = self.cfg.kda_heads_held
             count("LM_KDA_DECAY_CHANNELS",

@@ -618,7 +618,8 @@ def test_a_delta_sublayer_holds_its_heads_as_the_projections_leave_them(
     pull the forward that keeps and the backward walk) and NO array in [T,
     H, 128]'s own tiles (8 heads by 128 lanes), which is a copy from and to
     the projections' [T, H 128] (8 positions by 128 lanes): ``gates`` and
-    ``output`` work on ``heads_apart``'s view, and the parts hand each
+    ``output`` are delta_passes.py's kernels on [T, H 128] (``heads_apart``'s
+    view where they are not), and the parts hand each
     other [T, H 128]. (The parent held 16 such arrays a sublayer and the
     convolutions' fusions lost their scope to the turn: 258 ms a step under
     no scope, 68 after, PERF.md section 5.)"""
@@ -648,8 +649,124 @@ def test_a_delta_sublayer_holds_its_heads_as_the_projections_leave_them(
         return out, deep, pull(d)
 
     text = jax.jit(sublayer).lower(mats, small, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
+    # the scan's three, and since PR 64 the passes around it: the gates
+    # twice, q's and k's convolutions in them (the forward's; made again
+    # for the scan's transpose: their own pull keeps its inputs alone and
+    # reads no third), their pull, the gated norm and its pull
+    assert text.count("tpu_custom_call") == 8
     assert text.count("mv_kda_scan_bwd") >= 1
+    for name in ("mv_kda_gates_pull", "mv_kda_out_pull"):
+        assert name in text
     heads, d = cfg.kda_heads, cfg.kda_head_dim
     assert f"f32[{tokens},{heads * d}]" in text
     assert f"f32[{tokens},{heads},{d}]" not in text
+
+
+# -- the delta layers' passes ------------------------------------------------------------
+# (models/lm/delta_passes.py; interpreted against ``delta.gates`` and
+# ``delta.output``'s gated norm in tests/test_lm_kda_passes.py)
+
+#: held heads and beta's scale: ``kimi48b.ps-8k``, ``solar250b.ps-8k``
+DELTA_CELLS = {"kimi": (32, 1.0), "solar": (8, 2.0)}
+
+
+@pytest.mark.parametrize("cell", list(DELTA_CELLS))
+@pytest.mark.parametrize("which, kernel, results_bf16", [
+    ("gates", "mv_kda_gates", 0), ("conv_gates", "mv_kda_gates", 0),
+    ("gates_pull", "mv_kda_gates_pull", 1),
+    ("norm", "mv_kda_out", 1), ("norm_pull", "mv_kda_out_pull", 1)])
+def test_a_delta_pass_compiles_at_a_cell_s_shapes(topo, cell, which, kernel,
+                                                  results_bf16):
+    """Each of the four passes (the gates' also with q's and k's
+    convolutions of four weights in it: a roll along the rows, the tile
+    before a block as a second view) as Mosaic takes it at 8192 tokens of
+    32 and of 8 heads of 128 lanes: ONE kernel, and no float32 intermediate
+    beside
+    it: what the program holds besides its arguments and results is the
+    bfloat16 array that a pass hands on widened (``mm`` rounds it back:
+    the pair folds away in a layer program) and the small tensors' partial
+    sums a block of tokens."""
+    from multiverso_tpu.models.lm import delta_passes
+    heads, scale = DELTA_CELLS[cell]
+    tokens, lanes = 8192, heads * delta_passes.LANES
+    assert delta_passes.fits(tokens, delta_passes.LANES)
+    how = delta_passes.Pass(heads, scale, 1e-6)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    wide, thin = shaped(tokens, lanes), shaped(tokens, heads)
+    gates_in = (shaped(heads), shaped(lanes), wide, wide, wide, thin)
+    norm_in = (shaped(delta_passes.LANES), wide, wide)
+
+    def gates(*a):
+        return delta_passes.gates(how, *a)
+
+    def norm(*a):
+        return delta_passes.gated_norm(how, *a)
+
+    taps = shaped(lanes, 4)
+    program, args = {
+        "gates": (gates, gates_in),
+        "conv_gates": (lambda *a: delta_passes.conv_gates(how, *a),
+                       (taps, taps) + gates_in),
+        "gates_pull": (lambda *a: jax.vjp(gates, *a[:6])[1](a[6:]),
+                       gates_in + (wide, wide, wide, thin)),
+        "norm": (norm, norm_in),
+        "norm_pull": (lambda *a: jax.vjp(norm, *a[:3])[1](a[3]),
+                      norm_in + (wide,))}[which]
+    compiled = jax.jit(program).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and kernel in text
+    sums = 2 * (tokens // delta_passes.TOKENS) * 8 * lanes * 4
+    # beta's cotangent, 32 of 128 lanes wide, lies in whole tiles
+    thin_bf16 = tokens * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        results_bf16 * tokens * lanes * 2 + thin_bf16 + sums + (1 << 20))
+
+
+#: ``backward_program``'s temporaries for a sparse delta layer at the parent
+#: of PR 64 (d4b688c), compiled for the same described v5e: bytes
+PARENT_BACKWARD_TEMPORARIES = {
+    "kimi-linear-48b-a3b-l5": 4_137_762_304,
+    "solar-open2-250b-a15b-l4": 3_002_636_288}
+
+
+@pytest.mark.parametrize("config", list(PARENT_BACKWARD_TEMPORARIES))
+def test_a_delta_layer_s_backward_program_is_no_larger_with_the_passes(
+        topo, config, monkeypatch):
+    """A sparse delta layer's backward program at the cell's sizes (two
+    sequences of 8192), the passes in it: its temporaries are no more than
+    the chain's were (the rules keep their inputs alone, which
+    ``attention_vjp`` holds anyway, and no float32 intermediate lies
+    between a pass and its consumer)."""
+    from multiverso_tpu.models.lm import delta, model as lm, ps_train
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            f"{config}.json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    tokens = 8192
+    assert delta.passes_fused(cfg, tokens)
+    kinds = cfg.layer_kinds()
+    kind = next(k for k in kinds if k[2] and k[3] == "kda")
+    shapes = cfg.layer_shapes(kinds.index(kind))
+
+    def shaped(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    mats = {n: shaped(shapes[n], jnp.bfloat16)
+            for n in cfg.matrices(kinds.index(kind))}
+    small = {n: shaped(s) for n, s in shapes.items() if n not in mats}
+    x = shaped((2, tokens, cfg.hidden))
+    compiled = ps_train.backward_program(
+        cfg, *kind[:2], tokens, kind[2], attention=kind[3]).lower(
+        mats, small, x, x).compile()
+    text = compiled.as_text()
+    for name in ("mv_kda_gates", "mv_kda_gates_pull", "mv_kda_out",
+                 "mv_kda_out_pull", "mv_kda_scan_bwd"):
+        assert name in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= PARENT_BACKWARD_TEMPORARIES[config]

@@ -58,19 +58,23 @@ def _ms(fn, *args):
     return float(np.median(times))
 
 
-def _ms_looped(step, first, loops=21):
+def _ms_looped(step, first, operands=None, loops=21):
     """Milliseconds a call of ``step`` INSIDE a program: ``loops`` calls in
     one ``fori_loop``, each fed something of the last so that none is
     hoisted, less a loop of one, over the calls between. A call from the
     host costs ~0.6 ms here whatever it runs (as much as a kernel of a
     millisecond; ``_ms`` counts it, which is fair between two forms of a
-    layer and wrong beside a bound)."""
-    def program(n):
-        return jax.jit(lambda carry: jax.lax.fori_loop(
-            0, n, lambda _, c: step(c), carry))
+    layer and wrong beside a bound). With ``operands`` the step is
+    ``step(carry, operands)`` and they are the program's arguments."""
+    if operands is None:
+        operands, step = (), (lambda c, _, step=step: step(c))
 
-    return (_ms(program(loops), first) - _ms(program(1), first)) \
-        / (loops - 1)
+    def program(n):
+        return jax.jit(lambda carry, ops: jax.lax.fori_loop(
+            0, n, lambda _, c: step(c, ops), carry))
+
+    return (_ms(program(loops), first, operands)
+            - _ms(program(1), first, operands)) / (loops - 1)
 
 
 def _both_forms(forms, plain, mats, small, x, da):
@@ -240,6 +244,131 @@ def _latent(cfg, described, t, rng):
         print(json.dumps(line), flush=True)
 
 
+def _delta_passes_alone(cfg, t, rng, peak):
+    """The kernels of ``delta_passes.py`` (the gates' once more with q's
+    and k's convolutions in it) and the ``jax.numpy`` chain
+    they stand for, on drawn products and cotangents: milliseconds inside a
+    program, the bytes a pass moves, the share of the memory bound it
+    reaches (``peak``: its bytes a second), each result's distance from the
+    chain's. The kernels' loops
+    are carried by a small operand (beta's logits or cotangent, the output
+    norm's scale); the chain's by EVERY result, or the compiler computes
+    the part that is read and drops the rest (so ``chain_ms`` holds the
+    loop's own copies too, and on arrays that fit the chip's fast memory
+    reads under what a layer program's chain takes: the cell's
+    ``trainer.attn_kda_ms_per_step.lm`` is the comparison that counts)."""
+    from multiverso_tpu.models.lm import delta
+    passes, how = delta._passes(cfg)
+    heads, d = cfg.kda_heads_held, cfg.kda_head_dim
+
+    def drawn(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    a_log, dt_bias, norm_o = drawn(heads), drawn(heads * d), 1 + 0.1 * drawn(d)
+    q, k, f, gate, o = (drawn(t, heads * d) for _ in range(5))
+    b = drawn(t, heads)
+    ins, cots = (a_log, dt_bias, q, k, f, b), (q[::-1], k[::-1], f[::-1],
+                                               b[::-1])
+    norm_ins, dy = (norm_o, o, gate), gate[::-1]
+
+    def gates_chain(a_log, dt_bias, q, k, f, b):
+        qo, ko, _, g, beta = delta.gates(cfg, a_log, dt_bias, q, k, q, f, b)
+        return qo.reshape(t, -1), ko.reshape(t, -1), g.reshape(t, -1), beta
+
+    def norm_chain(norm_o, o, gate):
+        return lm.rmsnorm(delta.heads_apart(o, heads), norm_o,
+                          cfg.eps).reshape(t, -1) * jax.nn.sigmoid(gate)
+
+    def gates_fused(*a):
+        return passes.gates(how, *a)
+
+    def norm_fused(*a):
+        return passes.gated_norm(how, *a)
+
+    def pull(fn, n):
+        """``fn``'s pull as a function of its ``n`` inputs and then its
+        cotangents, all arguments (a closed-over array is a constant of
+        the program: hundreds of megabytes to compile and cache)."""
+        return lambda *a: jax.vjp(fn, *a[:n])[1](
+            a[n:] if len(a) > n + 1 else a[n])
+
+    # name: (the pass, the chain, their arguments, bytes moved in units of a
+    # wide float32 array, the pass's loop, the chain's loop); a loop is
+    # (step(carry, operands), first carry, operands)
+    def gates_pull_chain(c, ops):
+        d_a_log, d_bias, dq, dk, df, db = pull(gates_chain, 6)(*ops, *c)
+        return dq, dk, df + 0.0 * d_bias, db + 0.0 * d_a_log
+
+    def norm_pull_chain(dy, ops):
+        d_norm, do, d_gate = pull(norm_chain, 3)(*ops, dy)
+        return do + 0.0 * (d_gate + jnp.tile(d_norm, heads))
+
+    taps = drawn(heads * d, cfg.kda_conv) * 0.5, drawn(heads * d,
+                                                       cfg.kda_conv) * 0.5
+
+    def conv_fused(wq, wk, *a):
+        return passes.conv_gates(how, wq, wk, *a)
+
+    def conv_chain(wq, wk, a_log, dt_bias, q, k, f, b):
+        return gates_chain(a_log, dt_bias, delta.short_conv(q, wq),
+                           delta.short_conv(k, wk), f, b)
+
+    forms = {
+        "conv_gates": (
+            conv_fused, conv_chain, taps + ins, 6,
+            (lambda b, ops: b + 0.0 * conv_fused(*ops, b)[3], b,
+             taps + ins[:5]),
+            (lambda c, ops: conv_chain(*ops, *c), (q, k, f, b),
+             taps + ins[:2])),
+        "gates": (
+            gates_fused, gates_chain, ins, 6,
+            (lambda b, ops: b + 0.0 * gates_fused(*ops, b)[3], b, ins[:5]),
+            (lambda c, ops: gates_chain(*ops, *c), (q, k, f, b), ins[:2])),
+        "gates_pull": (
+            pull(gates_fused, 6), pull(gates_chain, 6), ins + cots, 8.5,
+            (lambda db, ops: db + 0.0 * pull(gates_fused, 6)(*ops, db)[5],
+             cots[3], ins + cots[:3]),
+            (gates_pull_chain, cots, ins)),
+        "norm": (
+            norm_fused, norm_chain, norm_ins, 2.5,
+            (lambda n, ops: n + 0.0 * norm_fused(n, *ops)[0, :d], norm_o,
+             norm_ins[1:]),
+            (lambda o, ops: norm_chain(ops[0], o, ops[1]), o,
+             (norm_o, gate))),
+        "norm_pull": (
+            pull(norm_fused, 3), pull(norm_chain, 3), norm_ins + (dy,), 4.5,
+            (lambda n, ops: n + 0.0 * pull(norm_fused, 3)(n, *ops)[0],
+             norm_o, norm_ins[1:] + (dy,)),
+            (norm_pull_chain, dy, norm_ins))}
+    wide, out = 4 * t * heads * d, {}
+    for name, (fused, chain, args, arrays, own, chains) in forms.items():
+        got, want = jax.jit(fused)(*args), jax.jit(chain)(*args)
+        ms = _ms_looped(*own)
+        out[name] = {
+            "ms": ms, "chain_ms": _ms_looped(*chains),
+            "bytes": int(arrays * wide),
+            "bound_share": arrays * wide / peak / (1e-3 * ms),
+            "relative": [_relative(a, b) for a, b in zip(
+                jax.tree_util.tree_leaves(got),
+                jax.tree_util.tree_leaves(want))]}
+    return out
+
+
+def _delta(cfg, described, t, rng):
+    """A delta layer's line: whether its gates and gated norm are
+    ``delta_passes.py``'s, and the passes alone."""
+    from multiverso_tpu.models.lm import delta
+    line = {"config": described.get("name"), "kind": "kda", "tokens": t,
+            "heads": cfg.kda_heads_held, "beta_scale": cfg.kda_beta_scale,
+            "fused": delta.passes_fused(cfg, t)}
+    if line["fused"]:
+        with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+            peak = json.load(f)[jax.devices()[0].device_kind]
+        line["pass_ms"] = _delta_passes_alone(cfg, t, rng,
+                                              peak["hbm_bytes_per_s"])
+    print(json.dumps(line), flush=True)
+
+
 def main(paths) -> int:
     if jax.default_backend() != "tpu":
         print("attn_pass_bench: needs a TPU", file=sys.stderr)
@@ -251,9 +380,13 @@ def main(paths) -> int:
         seq_len = CELLS.get(described.get("name"), 8192)
         t = seq_len * (2 if cfg.objective == "block_diffusion" else 1)
         rng = np.random.default_rng(0)
+        if "kda" in cfg.attention_layout:
+            _delta(cfg, described, t, rng)
         if cfg.attention == "mla":
             _latent(cfg, described, t, rng)
             continue
+        if "kda" in cfg.attention_layout:
+            continue    # its other layers are of a kind held by heads: no line
         kinds = cfg.layer_kinds()
         for kind in sorted(set(kinds)):
             shapes = cfg.layer_shapes(kinds.index(kind))
