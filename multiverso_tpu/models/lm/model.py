@@ -169,6 +169,22 @@ for embedding and head (``tied``):
       embedding table itself: the trainer pulls it by rows AND whole and
       makes one Add of both uses' gradients (ps_train.py)
 
+**A tenth family** (``from_dict`` tells it by ``model_type:
+granitemoehybrid``: ibm-granite's Granite 4.0-H Micro, 2025) mixes by a
+selective state-space layer in nine layers of ten (``attention_layout``:
+``ssd`` | ``gqa`` a layer, as ``layer_types`` publishes them), has NO router
+(``n_experts`` 0: every feed-forward the dense MLP) and four scalars that
+the nine above leave at 1:
+
+    ssd (ssd.py): (z, xBC, dt) = split(h W_in); xBC = silu(conv_4(xBC) +
+      b_c); S[t] = exp(dt_t A_i) S[t-1] + dt_t X_t (x) B_t a head [64 x 128],
+      B and C shared by the heads; Y = S C + D X; the gate ``Y * silu(z)``
+      INSIDE an RMSNorm over all heads' lanes; ``W_out``
+    gqa: no position, no head norm, scores times ``attn_scale`` (1/64 at
+      heads of 64 lanes, not 64^-1/2)
+    h_0 = ``embed_scale`` E[ids]; a = x + ``residual_scale`` Mix(.), y = a +
+      ``residual_scale`` MLP(.); logits = . E^T / ``logits_scale``
+
 **A layer is described by three independent kinds**, and each selects
 functions, not a family's branch: its ATTENTION (the model's ``attention``,
 or where a model has more than one the LAYER's, ``attention_layout`` /
@@ -176,7 +192,8 @@ or where a model has more than one the LAYER's, ``attention_layout`` /
 ``attention_gate`` / ``attention_output`` under ``attention_vjp``, with
 ``heads_layout``, ``rotary_kinds``, ``attn_gate``, ``qk_norm``; ``mla`` ->
 latent.py, through the streams or, on the plain residual, through
-``attention_vjp`` too; ``kda`` -> delta.py; ``conv`` -> shortconv.py), its
+``attention_vjp`` too; ``kda`` -> delta.py; ``conv`` -> shortconv.py;
+``ssd`` -> ssd.py: ``MIXER_MODULES``), its
 FEED-FORWARD (``ffn_layout``, ``dense_width``, ``shared_width``,
 ``scoring``, ``routed_scale`` -> ``feed_forward_vjp``: ``dense_vjp`` |
 ``sparse_vjp`` on ONE normed input, for every layer of ``router_input:
@@ -261,7 +278,8 @@ multiply, forward and backward: ``mv.lm.attn.gate``. The fifth's:
 layers': ``mv.lm.attn.kda``, ``mv.lm.attn.kda.conv``,
 ``mv.lm.attn.kda.scan`` (delta.py); its latent layer's the third's. The
 ninth's convolution layers': ``mv.lm.attn.shortconv``,
-``mv.lm.attn.shortconv.taps`` (shortconv.py).
+``mv.lm.attn.shortconv.taps`` (shortconv.py). The tenth's state-space
+layers': ``mv.lm.attn.ssd``, ``.conv``, ``.scan``, ``.gate`` (ssd.py).
 """
 
 from __future__ import annotations
@@ -293,6 +311,15 @@ SHARED = ("ws_gate", "ws_up", "ws_down")
 LAYER_MATRICES = GQA_MATRICES + DENSE   # the first two families' layer
 LAYER_SMALL = ("router", "norm_attn", "norm_ffn")
 QK_NORMS = ("norm_q", "norm_k")     # with ``LMConfig.qk_norm``, float32 too
+#: The kinds of a layer's attention that live in modules of their own and give
+#: ``MATRICES``, ``shapes(cfg)`` and ``attention_vjp(cfg, mats, sinks, small,
+#: x) -> (F(x), counts, pull)``: the kind -> the module's name.
+MIXER_MODULES = {"kda": "delta", "conv": "shortconv", "ssd": "ssd"}
+
+
+def mixer_module(kind: str):
+    import importlib
+    return importlib.import_module("." + MIXER_MODULES[kind], __package__)
 
 
 @dataclasses.dataclass(frozen=True, order=True)
@@ -408,8 +435,9 @@ class LMConfig:
     attention_layout: Tuple[str, ...] = ()  # per layer: "mla" | "gqa" |
     #                                 "kda" (the delta rule's scan,
     #                                 delta.py) | "conv" (the gated short
-    #                                 convolution, shortconv.py); ():
-    #                                 ``attention`` in every layer
+    #                                 convolution, shortconv.py) | "ssd" (the
+    #                                 selective state-space mixer, ssd.py);
+    #                                 (): ``attention`` in every layer
     kda_heads: int = 0              # kda: heads, each a state [d x d]
     kda_head_dim: int = 0
     kda_conv: int = 0               # kda: the short convolution's weights a
@@ -418,6 +446,22 @@ class LMConfig:
     #                                 lets a state flip sign along a key
     conv_taps: int = 0              # conv: the positions a channel reads
     tied: bool = False              # ONE table is embedding and head
+    ssd_heads: int = 0              # ssd: heads, each a state [P x N] under
+    #                                 one scalar decay a position
+    ssd_head_dim: int = 0           # ssd: P, a head's lanes
+    ssd_state: int = 0              # ssd: N, the state's other side
+    ssd_groups: int = 0             # ssd: groups of B and C (1: every head
+    #                                 reads the same two)
+    ssd_conv: int = 0               # ssd: the convolution's taps a channel
+    ssd_chunk: int = 0              # ssd: positions a chunk of the scan, the
+    #                                 blocking and no part of the function;
+    #                                 0: ``ssd.CHUNK``
+    # -- four scalars (Granite's multipliers), each at what every other model
+    # has and folded into an operand that is there ----------------------------
+    residual_scale: float = 1.0     # a = x + r Mix(.), y = a + r F(.)
+    attn_scale: float = 0.0         # gqa: the scores' scale; 0: head_dim^-0.5
+    logits_scale: float = 1.0       # logits = . E^T / logits_scale
+    embed_scale: float = 1.0        # the first layer's input = scale * rows
 
     @property
     def n_layers(self) -> int:
@@ -447,6 +491,8 @@ class LMConfig:
         kind = self.attention_of(layer)
         if kind == "conv":      # a mixer without heads
             return 0, 0
+        if kind == "ssd":       # whole here: the gated norm's mean runs
+            return self.ssd_heads, self.ssd_heads   # over all of them
         if kind == "kda":
             return self.kda_heads_held, self.kda_heads
         if kind == "gqa" and self.heads_layout:
@@ -463,7 +509,7 @@ class LMConfig:
 
     def attention_of(self, layer: int) -> str:
         """The kind of the layer's attention: ``gqa`` | ``mla`` | ``kda`` |
-        ``conv``."""
+        ``conv`` | ``ssd``."""
         return self.attention_layout[layer] if self.attention_layout \
             else self.attention
 
@@ -500,12 +546,8 @@ class LMConfig:
         """The layer's tensors pulled as bfloat16 copies, by name: its
         attention's, then its feed-forward's."""
         kind = self.attention_of(layer)
-        if kind == "kda":
-            from . import delta
-            attention = delta.MATRICES
-        elif kind == "conv":
-            from . import shortconv
-            attention = shortconv.MATRICES
+        if kind in MIXER_MODULES:
+            attention = mixer_module(kind).MATRICES
         elif kind == "mla":
             attention = MLA_MATRICES if self.q_lora_rank else MLA_DIRECT
         else:
@@ -540,6 +582,8 @@ class LMConfig:
             return cls._from_solar(c)
         if c.get("model_type") == "lfm2_moe":
             return cls._from_lfm2(c)
+        if c.get("model_type") == "granitemoehybrid":
+            return cls._from_granite(c)
         if "linear_attn_config" in c:
             return cls._from_kda(c)
         if "kv_lora_rank" in c:
@@ -959,6 +1003,65 @@ class LMConfig:
             conv_taps=int(c["conv_L_cache"]),
             tied=bool(c.get("tie_word_embeddings", True)))
 
+    @classmethod
+    def _from_granite(cls, c: dict) -> "LMConfig":
+        """The block of ``model_type: granitemoehybrid`` as Granite 4.0-H
+        Micro has it (ibm-granite, benchmark/configs/
+        granite-4.0-h-micro-l10.json): ``layer_types`` says layer by layer,
+        as published and read up to ``num_hidden_layers``, whether the mixer
+        is a selective state-space layer (``mamba``: ssd.py) or grouped-query
+        attention with NO position, no head norm and the scores' scale
+        ``attention_multiplier``; every layer's feed-forward is the dense
+        silu-gated MLP of ``shared_intermediate_size`` and there is NO router
+        (``num_local_experts`` 0: ``n_experts`` 0, ``top_k`` 0, nothing
+        held); embedding and head are ONE table; the embedding's rows times
+        ``embedding_multiplier``, each sublayer's result times
+        ``residual_multiplier``, the logits over ``logits_scaling``.
+        ``head_dim`` (hidden / heads when absent) and ``scan_chunk`` (the
+        scan's blocking; ``mamba_chunk_size`` is the released kernels' and is
+        not read) the published config does not state."""
+        n = int(c["num_hidden_layers"])
+        kinds = {"mamba": "ssd", "attention": "gqa"}
+        types = list(c["layer_types"][:n])
+        hidden, heads = int(c["hidden_size"]), int(c["num_attention_heads"])
+        ssd_heads, lanes = int(c["mamba_n_heads"]), int(c["mamba_d_head"])
+        CHECK(int(c["num_local_experts"]) == 0
+              and int(c["num_experts_per_tok"]) == 0
+              and int(c["mamba_n_groups"]) == 1 and not c["mamba_proj_bias"]
+              and c["mamba_conv_bias"]
+              and c["position_embedding_type"] == "nope"
+              and not c["attention_bias"] and len(types) == n
+              and all(t in kinds for t in types)
+              and int(c["mamba_expand"]) * hidden == ssd_heads * lanes,
+              "only the block with no experts, one group of B and C, a "
+              "convolution with a bias and projections without, attention "
+              "with no positions and no bias, and an inner width of "
+              "mamba_expand * hidden_size = mamba_n_heads * mamba_d_head, "
+              "whose every layer is mamba or attention, is written down here")
+        return cls(
+            hidden=hidden, n_heads=heads,
+            n_kv_heads=int(c["num_key_value_heads"]),
+            head_dim=int(c.get("head_dim") or hidden // heads),
+            n_experts=0, top_k=0, expert_width=0, experts_held=(0, 0),
+            vocab=int(c["vocab_size"]),
+            rope_layout=(0,) * n, window_layout=(0,) * n, window=0,
+            rope_theta=float(c["rope_theta"]), eps=float(c["rms_norm_eps"]),
+            loss_block=int(c.get("loss_block", 2048)),
+            activation=str(c["hidden_act"]), router_input="ffn_input",
+            attention_layout=tuple(kinds[t] for t in types),
+            ffn_layout=(0,) * n,
+            dense_width=int(c["shared_intermediate_size"]),
+            tied=bool(c["tie_word_embeddings"]),
+            ssd_heads=ssd_heads, ssd_head_dim=lanes,
+            ssd_state=int(c["mamba_d_state"]),
+            ssd_groups=int(c["mamba_n_groups"]),
+            ssd_conv=int(c["mamba_d_conv"]),
+            ssd_chunk=int(c.get("scan_chunk", 0)),
+            residual_scale=float(c["residual_multiplier"]),
+            attn_scale=float(c["attention_multiplier"]),
+            logits_scale=float(c["logits_scaling"]),
+            embed_scale=float(c["embedding_multiplier"]))
+
     def layer_shapes(self, layer: int = 0) -> dict:
         """Every tensor of one layer as the server stores it, built from
         the layer's kinds: a matrix table's (rows, columns) or a small
@@ -968,12 +1071,8 @@ class LMConfig:
         are stacked by expert along the rows."""
         h, d = self.hidden, self.head_dim
         kind = self.attention_of(layer)
-        if kind == "kda":
-            from . import delta
-            shapes = delta.shapes(self)
-        elif kind == "conv":
-            from . import shortconv
-            shapes = shortconv.shapes(self)
+        if kind in MIXER_MODULES:
+            shapes = mixer_module(kind).shapes(self)
         elif kind == "mla":
             # over the held heads: ``wq_b``, ``wkv_b``, ``wo`` cut by head,
             # each head's columns together, ``[nope | rope]``, ``[k nope | v]``
@@ -1603,6 +1702,7 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     heads = mats["wq"].shape[1] // d
     g = cfg.n_kv_heads_held
     per = heads // g
+    scale = cfg.attn_scale or 1.0 / math.sqrt(d)
     norm, *qk = norms if cfg.qk_norm else (norms,)
     h = rmsnorm(x, norm, cfg.eps)
     at = () if pos is None else (pos,)
@@ -1614,7 +1714,7 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
             t, d, theta, *at, **how)) if rope else ()
         qkv = attn_kernels.heads_in(
             attn_kernels.Pass(per, d, how.get("lanes", d) if rope else 0,
-                              bool(qk), cfg.eps, 1.0 / math.sqrt(d), BF16),
+                              bool(qk), cfg.eps, scale, BF16),
             *(mm(h, mats[n], sinks[n]) for n in ("wq", "wk", "wv")),
             tuple(qk), tables)
         return qkv + (h,) if cfg.attn_gate != "none" else qkv
@@ -1626,7 +1726,7 @@ def attention_inputs(cfg: LMConfig, rope, mats, sinks, norms, x, pos=None):
     if rope:    # positions go only where given: ``_rotary``'s short form
         q = _rotary(q, theta, *at, **how)
         k = _rotary(k, theta, *at, **how)
-    q = (q * (1.0 / math.sqrt(d))).astype(BF16)
+    q = (q * scale).astype(BF16)
     # query head i reads key-value head i // per
     q = q.reshape(t, g, per, d).transpose(1, 2, 0, 3)
     qkv = (q, k.astype(BF16).transpose(1, 0, 2),
@@ -1655,10 +1755,13 @@ def attention_gate(mats, sinks, h, o):
 
 
 def attention_output(cfg: LMConfig, mats, sinks, x, o):
-    """``x`` plus the heads' outputs through the output projection."""
+    """``x`` plus the heads' outputs through the output projection (times
+    ``cfg.residual_scale``)."""
     t = x.shape[0]
     o = o.transpose(2, 0, 1, 3).reshape(t, mats["wo"].shape[0])
-    return x + mm(o, mats["wo"], sinks["wo"])
+    out = mm(o, mats["wo"], sinks["wo"])
+    return x + (out if cfg.residual_scale == 1.0
+                else cfg.residual_scale * out)
 
 
 def _attention_norms(cfg: LMConfig, small):
@@ -1832,10 +1935,12 @@ def experts_capacity(cfg: LMConfig, t: int) -> int:
     positions: ``EXPERTS_SHORT_SHARES`` times the even share of its ``t *
     top_k`` assignments in whole tiles of the grouped products (``_use_gmm``
     then picks the full buffer's kernel, on the same tiles), and never more
-    than all of them: there is then ONE buffer, and no choice."""
+    than all of them: there is then ONE buffer, and no choice. A model of
+    no experts has no assignments and a buffer of no rows."""
     every = t * cfg.top_k
     cap = -(-EXPERTS_SHORT_SHARES * every * cfg.experts_held[1]
-            // (cfg.n_experts * GROUPED_TILE_ROWS)) * GROUPED_TILE_ROWS
+            // (max(cfg.n_experts, 1) * GROUPED_TILE_ROWS)) \
+        * GROUPED_TILE_ROWS
     return min(cap, every)
 
 
@@ -2140,23 +2245,23 @@ def _route_layer(cfg: LMConfig, router, norm_ffn, stream):
 def _module_attention_vjp(cfg: LMConfig, kind: str, rope, mats, sinks,
                           small, x, pos):
     """``attention_vjp``'s results for the kinds of attention that live in
-    modules of their own and give ``F(x)``: latent.py's (``mla``), delta.py's
-    (``kda``) and shortconv.py's (``conv``), which read no position."""
+    modules of their own and give ``F(x)``: latent.py's (``mla``) and
+    ``MIXER_MODULES``' (``kda``, ``conv``, ``ssd``), which read no position;
+    ``cfg.residual_scale`` on ``F(x)``."""
     stats = {}
-    if kind == "kda":
-        from . import delta
-        out, stats, pull_f = delta.attention_vjp(cfg, mats, sinks, small, x)
-    elif kind == "conv":
-        from . import shortconv
-        out, stats, pull_f = shortconv.attention_vjp(cfg, mats, sinks, small,
-                                                     x)
+    if kind in MIXER_MODULES:
+        out, stats, pull_f = mixer_module(kind).attention_vjp(
+            cfg, mats, sinks, small, x)
     else:
         from . import latent
         out, pull_f = latent.attention_vjp(cfg, mats, sinks, small, x, pos,
                                            rope=bool(rope))
+    r = cfg.residual_scale
+    if r != 1.0:    # on the output product's result: its own fusion
+        out = r * out
 
     def pull(da):
-        dx, d_mats, d_small = pull_f(da)
+        dx, d_mats, d_small = pull_f(da if r == 1.0 else r * da)
         return da + dx, d_mats, d_small
 
     return x + out, stats, pull
@@ -2260,9 +2365,12 @@ def layer_vjp(cfg: LMConfig, rope, mask, sparse: int, mats, small, x,
     a, stats, pull_attention = attention_vjp(cfg, rope, mask, mats, sinks,
                                              small, x, pos, attention)
     v, aux, pull_ffn = feed_forward_vjp(cfg, sparse, mats, sinks, small, a)
+    r = cfg.residual_scale
+    if r != 1.0:    # on the down product's result: its own fusion
+        v = r * v
 
     def pull(dy):
-        du, (d_mats_ffn, d_small_ffn) = pull_ffn(dy)
+        du, (d_mats_ffn, d_small_ffn) = pull_ffn(dy if r == 1.0 else r * dy)
         if attention is not None:
             # the feed-forward's gradients all made, and what it kept for
             # them let go, before the attention's pull makes its own keep
@@ -2375,6 +2483,8 @@ def head_loss_and_grads(cfg: LMConfig, head, norm, x, targets, weights=None,
 
     def block_loss(x, norm, sink, targets, weights):
         h = rmsnorm(x, norm, cfg.eps)
+        if cfg.logits_scale != 1.0:     # on the product's narrow operand
+            h = h * (1.0 / cfg.logits_scale)
         logits = mm_nt(h, head, sink)
         picked = jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
         each = jax.nn.logsumexp(logits, axis=-1) - picked

@@ -59,6 +59,19 @@ under ``mv.lm.embed``), under dense Adam. Two Adds would be two Adam steps
 for a row that a step both reads and scores; the rows form has nothing left
 to do there. ``LM_TIED_ADDS`` counts the Add.
 
+**Four scalars** (``LMConfig.embed_scale``, ``residual_scale``,
+``attn_scale``, ``logits_scale``; 1, 1, ``head_dim^-0.5``, 1 in every model
+but Granite's). The last three are the layer programs' and the head's
+(model.py). ``embed_scale``: the rows the Get brings go through a program
+under ``mv.lm.embed`` that multiplies them (``_enter_scaled``), and the first
+layer's input's gradient through its twin before it goes into the table's
+Add: a tied table's one Add carries ``scale * d_rows`` beside the head's.
+
+**A model with no router** (``n_experts`` 0, every ``ffn_layout`` 0) is a
+case of the same code: no layer has a ``router`` or ``router_bias`` table,
+no forward program returns a bias's step, the experts' counters count
+nothing.
+
 The Adds are asynchronous; the next step's Gets of the same tables wait
 for them by the server's own order (an acknowledged Add is in every
 later Get). A layer's gradients leave for the server as soon as its
@@ -100,6 +113,12 @@ half), ``LM_HEADS_HELD`` of ``LM_HEADS`` (a layer a sequence: the heads of
 its attention held here, of all it has; from the host),
 ``LM_MIXERS_CONV`` of ``LM_MIXERS`` (a layer a sequence: the mixers that are
 gated short convolutions, of all; with a ``conv`` layer, from the host),
+``LM_MIXERS_SSD`` of ``LM_MIXERS`` (the same for selective state-space
+mixers, ssd.py), ``LM_SSD_CHUNKS`` and ``LM_SSD_DEEP`` (a state-space layer
+a sequence: the chunks its scan walked; the (chunk, head) pairs whose summed
+log decay is under ``delta.DEEP``, counted on the device),
+``LM_SSD_SCAN_KERNEL`` or ``LM_SSD_SCAN_PLAIN`` (which form ``ssd.scan``
+took, ``ssd.scan_counter``),
 ``LM_ATTN_LANES`` of ``LM_ATTN_LANES_TILED`` (the same models' attention
 layers, a layer a sequence: the lanes a head holds, of the lanes the
 attention kernel is handed a head: equal where a head under a 128-lane tile
@@ -191,12 +210,12 @@ def attn_pass_names(cfg: LMConfig, positions: int, module: bool):
     last (it is of the last layer's kinds): the counter one sequence of
     ``positions`` through it adds one to, by the form that the way from
     its attention's products to the kernel took (None where there is none:
-    a delta layer, a convolution layer)."""
+    a delta layer, a convolution layer, a state-space layer)."""
     def name(layer):
         kind, rope = cfg.attention_of(layer), cfg.rope_layout[layer]
         if kind == "mla":
             return latent.pass_name(cfg, positions, rope)
-        return None if kind in ("kda", "conv") \
+        return None if kind in lm.MIXER_MODULES \
             else lm.attention_pass_name(cfg, positions, rope)
 
     names = [name(layer) for layer in range(cfg.n_layers)]
@@ -214,7 +233,7 @@ def attn_blocks_names(cfg: LMConfig, seq_len: int, module: bool):
 
     def name(layer):
         kind = cfg.attention_of(layer)
-        if kind in ("kda", "conv") or cfg.selection != "none":
+        if kind in lm.MIXER_MODULES or cfg.selection != "none":
             return None
         if kind == "mla":       # latent.core: each head a group, causal
             return lm.attention_blocks_name(
@@ -499,9 +518,10 @@ class PSLMTrainer:
                 #                         constant starts it (``decay_init``)
                 return create_array_table(shape[0], fill=decay_init(
                     name, shape[0], next(seeds)))
-            if name.startswith("conv_"):    # [channels, weights]: uniform
-                #                         on +- weights^-1/2, a convolution's
-                #                         usual start
+            if name.startswith("conv_") and len(shape) == 2:    # uniform
+                #                         on +- weights^-1/2 for [channels,
+                #                         weights], a convolution's usual
+                #                         start (its bias, ``conv_b``: 0)
                 return matrix(shape, (3 * shape[1]) ** -0.5)
             if len(shape) == 2:
                 return matrix(shape, shape[1] ** -0.5
@@ -538,6 +558,12 @@ class PSLMTrainer:
                                    or cfg.residual == "mhc"),
               "one table for embedding and head: on the plain residual under "
               "the next-token objective, without a multi-token module")
+        CHECK((cfg.residual_scale == 1.0 and cfg.embed_scale == 1.0)
+              or (cfg.one_ffn_input and cfg.residual == "plain"
+                  and not cfg.mtp_layers),
+              "a residual or embedding multiplier: on the plain residual "
+              "whose feed-forward reads one normed input, without a "
+              "multi-token module")
         # whole-table traffic: every table but the embedding, which goes by
         # rows; a tied table is pulled whole at the head and pushed whole
         self._whole_bytes = 4 * (cfg.parameters() - (
@@ -571,6 +597,10 @@ class PSLMTrainer:
             self._enter = jax.jit(self._enter_rows)
             self._enter_back = jax.jit(self._enter_rows_back,
                                        donate_argnums=(0,))
+        elif cfg.embed_scale != 1.0:
+            self._enter = jax.jit(self._enter_scaled)
+            self._enter_back = jax.jit(self._enter_scaled_back,
+                                       donate_argnums=(0,))
         if self.streams or self.module:
             self._sum = jax.jit(
                 lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
@@ -592,11 +622,15 @@ class PSLMTrainer:
             + [cfg.n_layers - 1] * bool(self.module)
         self._heads = tuple(self.B * sum(heads) for heads in zip(
             *(cfg.heads_of(i) for i in layers)))
-        # the mixers that are convolutions, of all, and the lanes a head of
-        # the others holds, a layer a sequence (``_count_stats``)
+        # the mixers that are convolutions or state-space layers, of all,
+        # and the lanes a head of the others holds, a layer a sequence
+        # (``_count_stats``)
         kinds_of = [cfg.attention_of(i) for i in range(cfg.n_layers)]
-        self._mixers = (self.B * kinds_of.count("conv"),
-                        self.B * cfg.n_layers)
+        self._mixers = {"LM_MIXERS": self.B * cfg.n_layers, **{
+            name: self.B * kinds_of.count(kind)
+            for kind, name in (("conv", "LM_MIXERS_CONV"),
+                               ("ssd", "LM_MIXERS_SSD"))
+            if kind in kinds_of}}
         self._attn_lanes = self.B * kinds_of.count("gqa") * cfg.head_dim
         self._tie = jax.jit(self._tie_gradients, donate_argnums=(0,)) \
             if cfg.tied else None
@@ -688,6 +722,17 @@ class PSLMTrainer:
     def _enter_rows_back(self, dx, de_next):
         with jax.named_scope("mv.lm.embed"):
             return self._rows_back(dx, de_next)
+
+    # -- and a model whose first layer reads ``embed_scale`` times the rows ------
+    def _enter_scaled(self, rows):
+        with jax.named_scope("mv.lm.embed"):
+            return self.cfg.embed_scale * rows, None
+
+    def _enter_scaled_back(self, dx, _):
+        """The rows' gradient: the first layer's input's times the scale,
+        before it goes into the table's Add."""
+        with jax.named_scope("mv.lm.embed"):
+            return self.cfg.embed_scale * dx
 
     @staticmethod
     def _rows_back(d_rows, de_next):
@@ -907,8 +952,9 @@ class PSLMTrainer:
                                               for s in per_layer)))
         # which buffer ``model.routed_experts`` took on the device, from
         # the count it chose by
-        fits = np.concatenate([s[:, 0] <= self._experts_cap for s, sparse
-                               in zip(per_layer, self._sparse) if sparse])
+        fits = np.concatenate([np.zeros(0, bool)] + [
+            s[:, 0] <= self._experts_cap for s, sparse
+            in zip(per_layer, self._sparse) if sparse])
         for name, n in (("LM_EXPERTS_SHORT", fits.sum()),
                         ("LM_EXPERTS_FULL", (~fits).sum())):
             if n:
@@ -918,7 +964,7 @@ class PSLMTrainer:
             if name:            # one a layer a sequence
                 count(name, len(s))
         outputs = self.cfg.n_experts
-        fullest = sum(int(s[:, 2:2 + outputs].sum(axis=0).max())
+        fullest = sum(int(s[:, 2:2 + outputs].sum(axis=0).max(initial=0))
                       for s in per_layer if s.shape[1] >= 2 + outputs)
         if fullest:
             count("LM_ROUTER_LOAD_MAX", fullest)
@@ -972,9 +1018,25 @@ class PSLMTrainer:
             deep = int(sum(s[:, -1].astype(np.int64).sum() for s in scanned))
             if deep:
                 count("LM_KDA_DECAY_DEEP", deep)
-        if "conv" in self.cfg.attention_layout:
-            count("LM_MIXERS_CONV", self._mixers[0])
-            count("LM_MIXERS", self._mixers[1])
+        if "ssd" in self.cfg.attention_layout:
+            # a state-space layer's last: the (chunk, head) pairs whose
+            # summed log decay is under delta.DEEP, of LM_SSD_CHUNKS times
+            # the heads
+            from . import ssd
+            scanned = [s for s, kind in zip(per_layer,
+                                            self.cfg.attention_layout)
+                       if kind == "ssd"]
+            sequences = sum(len(s) for s in scanned)
+            count(ssd.scan_counter(self.cfg, self.T), sequences)
+            count("LM_SSD_CHUNKS", sequences
+                  * (self.T // ssd.chunk_of(self.cfg, self.T)))
+            deep = int(sum(s[:, -1].astype(np.int64).sum() for s in scanned))
+            if deep:
+                count("LM_SSD_DEEP", deep)
+        if {"conv", "ssd"} & set(self.cfg.attention_layout):
+            # a model with a mixer that is not attention
+            for name, n in self._mixers.items():
+                count(name, n)
             # a head goes to ``model.attention_core`` at its own lanes
             count("LM_ATTN_LANES", self._attn_lanes)
             count("LM_ATTN_LANES_TILED", self._attn_lanes)

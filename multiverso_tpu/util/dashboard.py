@@ -317,10 +317,23 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_HEADS": "heads of the layers' attention as published, a layer a "
                 "sequence",
     "LM_HEADS_HELD": "of those, the heads held here (LMConfig.heads_held)",
-    "LM_MIXERS": "mixers of a model with a convolution layer "
-                 "(LMConfig.attention_layout \"conv\"), a layer a sequence",
+    "LM_MIXERS": "mixers of a model with a layer whose mixer is not "
+                 "attention (LMConfig.attention_layout \"conv\" or "
+                 "\"ssd\"), a layer a sequence",
     "LM_MIXERS_CONV": "of those, the gated short convolutions "
                       "(models/lm/shortconv.py)",
+    "LM_MIXERS_SSD": "of those, the selective state-space mixers "
+                     "(models/lm/ssd.py)",
+    "LM_SSD_CHUNKS": "chunks the state-space layers' scans walked, a layer "
+                     "a sequence (LM_SSD_CHUNKS times the layer's heads: "
+                     "the (chunk, head) pairs)",
+    "LM_SSD_DEEP": "the (chunk, head) pairs of those scans whose log decay "
+                   "summed over the chunk is under delta.DEEP (computed in "
+                   "the scan)",
+    "LM_SSD_SCAN_KERNEL": "state-space layers' sequences whose scan ran as "
+                          "a kernel (ssd.scan_counter: none is written)",
+    "LM_SSD_SCAN_PLAIN": "state-space layers' sequences whose scan took "
+                         "the jax.numpy runs of chunks (models/lm/ssd.py)",
     "LM_ATTN_LANES": "the same models' attention layers, a layer a "
                      "sequence: the lanes a head holds",
     "LM_ATTN_LANES_TILED": "the lanes the attention kernel is handed a "

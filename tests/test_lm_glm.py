@@ -175,9 +175,12 @@ def test_an_older_latent_configuration_builds_what_it_built(name, rehearse):
     if rehearse:
         config.update(tiny)
     cfg = lm.LMConfig.from_dict(config)
-    # the fields added since (PRs 60, 63) stand at their defaults, last
+    # the fields added since (PRs 60, 63, 65) stand at their defaults, last
     built = repr(cfg).replace(
-        ", kda_beta_scale=1, conv_taps=0, tied=False)", ")")
+        ", kda_beta_scale=1, conv_taps=0, tied=False, ssd_heads=0, "
+        "ssd_head_dim=0, ssd_state=0, ssd_groups=0, ssd_conv=0, ssd_chunk=0, "
+        "residual_scale=1.0, attn_scale=0.0, logits_scale=1.0, "
+        "embed_scale=1.0)", ")")
     assert hashlib.sha256(built.encode()).hexdigest()[:16] \
         == PARENT_BUILT[name, rehearse]
 

@@ -513,7 +513,8 @@ def test_the_reader_is_the_fused_share(fused, plain, want):
 def test_the_reader_s_entry_is_the_benchmark_s_last():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["per_layer"][-1] == {
+    # found by NAME: the list grows at its end (PR 65 appended to it)
+    assert next(m for m in bench["per_layer"] if m["name"] == NAME) == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "trainer",
         "moves": "words_per_s",

@@ -481,6 +481,40 @@ def test_a_convolution_sublayer_s_chain_is_one_pass_each_way(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+def test_a_state_space_sublayer_compiles_at_the_published_widths(topo):
+    """models/lm/ssd.py at granite-4.0-h-micro's widths, one sequence of 8192
+    tokens in chunks of 256, forward and pull: what the program holds is a
+    few arrays of the projection's size ([8192, 8512] float32 is 279 MB) and
+    ONE run's within-chunk factor ([8, 64, 256, 256] float32, 134 MB), never
+    a layer's 1.07 GB of it."""
+    from multiverso_tpu.models.lm import model as lm, ssd
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "granite-4.0-h-micro-l10.json")) as f:
+        cfg = lm.LMConfig.from_dict(json.load(f))
+    t, h = 8192, cfg.hidden
+    assert (h, cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state) == (
+        2048, 64, 64, 128) and ssd.chunk_of(cfg, t) == 256
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def sublayer(mats, small, x, d):
+        out, counts, pull = ssd.attention_vjp(
+            cfg, mats, lm._zeros_like_f32(mats), small, x)
+        return out, counts, pull(d)
+
+    shapes = cfg.layer_shapes(0)
+    mats = {n: shaped(shapes[n], jnp.bfloat16) for n in ssd.MATRICES}
+    small = {n: shaped(shapes[n], jnp.float32)
+             for n in ("norm_attn",) + ssd.SMALL}
+    compiled = jax.jit(sublayer).lower(
+        mats, small, shaped((t, h), jnp.float32),
+        shaped((t, h), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
 # -- the pass between the attention's projections and its kernel -----------------
 # (models/lm/attn_kernels.py; interpreted against the chain in
 # tests/test_lm_attn_pass.py)
