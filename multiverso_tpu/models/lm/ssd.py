@@ -34,8 +34,21 @@ state the chunk starts from:
 Every exponent is a difference of summed log decays that is <= 0 (the mask
 is put on the exponent BEFORE it is taken), so a chunk whose decay
 underflows gives zeros, never ``0 * inf`` (``LM_SSD_DEEP`` counts the
-(chunk, head) pairs whose summed log decay is under ``delta.DEEP``). The
-chunks go in runs of ``CHUNKS_AT_ONCE`` as ``delta.scan``'s do: a run's
+(chunk, head) pairs whose summed log decay is under ``delta.DEEP``).
+
+**Which form runs where** (``scan_in_kernels``: the backend and the shapes
+choose, no flag; ``scan_counter`` names the form, ``LM_SSD_SCAN_KERNEL`` |
+``LM_SSD_SCAN_PLAIN``). On a TPU, at whole chunks of 256, heads of 64 lanes
+by twos, a state of 128 and ``CARRY`` and ``DECAY`` float32 (the cell's
+layers; any whole number of chunks), ``scan`` is ssd_kernels.py's two Pallas
+kernels: the chunks walked in order with ``C B^T``, a head's ``L`` and EVERY
+head's state in fast memory, X read and Y written as [T, H P] lies on a TPU
+(positions along the lanes), the states that the chunks started from kept
+for the pull (67 MB a sequence of 8,192) and the pull written by hand.
+Everywhere else (the CPU, the tests' and the rehearsal's shapes, one head
+under ``vmap``, a control's bfloat16 state) the ``jax.numpy`` lines below,
+which are the definition the tests hold the kernels to: the chunks go in runs
+of ``CHUNKS_AT_ONCE`` as ``delta.scan``'s do: a run's
 within-chunk factors made together ([run, H, c, c] float32: 134 MB at 8
 chunks of 256, never a layer's 1.07 GB), then the state through the run's
 chunks in order; a run is under ``jax.checkpoint``, so the backward pass
@@ -102,12 +115,25 @@ def chunk_of(cfg: LMConfig, t: int) -> int:
     return chunk if t % chunk == 0 else t
 
 
+def scan_in_kernels(t: int, heads: int, lanes: int, state: int,
+                    chunk: int = 0) -> bool:
+    """Whether ``scan`` runs as ssd_kernels' Pallas kernels: on a TPU, at
+    whole chunks of 256, heads of 64 lanes in pairs and a state of 128,
+    with ``L`` and the state in float32 (``DECAY`` and ``CARRY`` as they
+    are). By what the code can see: no flag chooses."""
+    if jax.default_backend() != "tpu" or CARRY != F32 or DECAY != F32:
+        return False
+    from . import ssd_kernels       # Pallas: imported where it can run
+    return ssd_kernels.shapes_fit(t, heads, lanes, state,
+                                  chunk or (CHUNK if t % CHUNK == 0 else t))
+
+
 def scan_counter(cfg: LMConfig, t: int) -> str:
     """The counter a state-space layer's sequence of ``t`` tokens counts:
-    which form ``scan`` took. There is one form, ``jax.numpy`` products
-    under ``delta.scan``'s kind of walk; a kernel would be chosen here."""
-    del cfg, t
-    return "LM_SSD_SCAN_PLAIN"
+    which form ``scan`` took (``PSLMTrainer._count_stats``)."""
+    return "LM_SSD_SCAN_KERNEL" if scan_in_kernels(
+        t, cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state,
+        chunk_of(cfg, t)) else "LM_SSD_SCAN_PLAIN"
 
 
 # -- the parts ---------------------------------------------------------------------
@@ -170,10 +196,17 @@ def scan(x, dt, a_log, b, c, chunk: int = 0):
     count of deep (chunk, head) pairs, for x [T, H, P], dt [T, H] (> 0),
     a_log [H], b, c [T, N] float32, in chunks of ``chunk`` positions
     (``CHUNK`` where it divides T, else T, when 0): the module's
-    docstring."""
+    docstring.
+
+    Where ``scan_in_kernels``, ssd_kernels.py's two kernels: the chunks
+    walked with ``L`` and every head's state in fast memory, X read from [T,
+    H P] as it lies. Everywhere else the lines below."""
     t, heads, lanes = x.shape
     chunk = chunk or (CHUNK if t % CHUNK == 0 else t)
     assert t % chunk == 0, (t, chunk)
+    if scan_in_kernels(t, heads, lanes, b.shape[-1], chunk):
+        from . import ssd_kernels
+        return ssd_kernels.scan(x, dt, log_decay(dt, a_log), b, c, DEEP)
     n = t // chunk
     at_once = next(m for m in range(min(CHUNKS_AT_ONCE, n), 0, -1)
                    if n % m == 0)
@@ -193,11 +226,14 @@ def scanned(cfg: LMConfig, dt_bias, a_log, d, xbc, dt):
     [T, H]: ``(Y [T, H P] with the skip, deep)``."""
     t, heads = dt.shape
     inner = heads * cfg.ssd_head_dim
-    x = xbc[:, :inner].reshape(t, heads, -1)
-    # ONE group: every head reads the same B and C [T, N]
-    y, deep = scan(x, step(dt, dt_bias), a_log,
+    x = xbc[:, :inner]
+    # ONE group: every head reads the same B and C [T, N]. [T, H, P] is a
+    # view for ``scan``'s sake: the kernels read X and write Y as [T, H P]
+    # lies, and the skip is taken there (a reshape that splits the lane
+    # axis is a copy on a TPU)
+    y, deep = scan(x.reshape(t, heads, -1), step(dt, dt_bias), a_log,
                    *jnp.split(xbc[:, inner:], 2, axis=-1), chunk_of(cfg, t))
-    return (y + d[:, None] * x).reshape(t, inner), deep
+    return y.reshape(t, inner) + jnp.repeat(d, cfg.ssd_head_dim) * x, deep
 
 
 def attention_vjp(cfg: LMConfig, mats, sinks, small, x):

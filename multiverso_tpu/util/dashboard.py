@@ -331,7 +331,7 @@ METRIC_NAMES: Dict[str, str] = {
                    "summed over the chunk is under delta.DEEP (computed in "
                    "the scan)",
     "LM_SSD_SCAN_KERNEL": "state-space layers' sequences whose scan ran as "
-                          "a kernel (ssd.scan_counter: none is written)",
+                          "ssd_kernels.py's kernels (ssd.scan_counter)",
     "LM_SSD_SCAN_PLAIN": "state-space layers' sequences whose scan took "
                          "the jax.numpy runs of chunks (models/lm/ssd.py)",
     "LM_ATTN_LANES": "the same models' attention layers, a layer a "

@@ -286,6 +286,57 @@ def test_a_deep_decay_within_a_chunk_stays_finite():
     assert _relative(got, want) < ROUNDED
 
 
+# -- which form the scan takes (ssd.scan_in_kernels; the kernels themselves:
+# tests/test_lm_ssd_kernels.py) ---------------------------------------------------
+
+@pytest.mark.parametrize("t,heads,lanes,state,chunk,carry,decay,want", [
+    (8192, 64, 64, 128, 256, jnp.float32, jnp.float32, True),   # the cell's
+    (16384, 64, 64, 128, 256, jnp.float32, jnp.float32, True),  # scan.carry's
+    (8192, 64, 64, 128, 0, jnp.float32, jnp.float32, True),
+    (8192, 64, 64, 128, 256, jnp.bfloat16, jnp.float32, False),     # the
+    (8192, 64, 64, 128, 256, jnp.float32, jnp.bfloat16, False),     # control
+    (8192, 1, 64, 128, 256, jnp.float32, jnp.float32, False),   # b_c_a_head
+    (8192 + 128, 64, 64, 128, 0, jnp.float32, jnp.float32, False),
+    (8192, 64, 64, 64, 256, jnp.float32, jnp.float32, False),
+    (8192, 32, 128, 128, 256, jnp.float32, jnp.float32, False),
+    (32, 8, 16, 32, 8, jnp.float32, jnp.float32, False),    # the rehearsal's
+])
+def test_the_scan_s_kernels_take_the_cell_s_shapes_on_a_tpu_alone(
+        t, heads, lanes, state, chunk, carry, decay, want, monkeypatch):
+    monkeypatch.setattr(ssd, "CARRY", carry)
+    monkeypatch.setattr(ssd, "DECAY", decay)
+    assert not ssd.scan_in_kernels(t, heads, lanes, state, chunk)  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssd.scan_in_kernels(t, heads, lanes, state, chunk) is want
+
+
+@pytest.mark.parametrize("config,t,carry,name", [
+    (PUBLISHED, 8192, jnp.float32, "LM_SSD_SCAN_KERNEL"),
+    (PUBLISHED, 8192, jnp.bfloat16, "LM_SSD_SCAN_PLAIN"),
+    (PUBLISHED, 8192 + 128, jnp.float32, "LM_SSD_SCAN_PLAIN"),
+    (CONFIG, 32, jnp.float32, "LM_SSD_SCAN_PLAIN")])
+def test_the_scan_s_counter_names_the_form_the_scan_takes(config, t, carry,
+                                                          name, monkeypatch):
+    cfg = lm.LMConfig.from_dict(config)
+    monkeypatch.setattr(ssd, "CARRY", carry)
+    assert ssd.scan_counter(cfg, t) == "LM_SSD_SCAN_PLAIN"      # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssd.scan_counter(cfg, t) == name
+    assert name in dashboard.METRIC_NAMES
+
+
+def test_on_the_cpu_the_scan_is_the_plain_one(monkeypatch):
+    from multiverso_tpu.models.lm import ssd_kernels
+
+    def never(*args):
+        raise AssertionError("the kernels were taken on the CPU")
+
+    monkeypatch.setattr(ssd_kernels, "scan", never)
+    x, dt, a_log, b, c = _scan_inputs(9, t=512, heads=2, lanes=64, n=128)
+    y, deep = ssd.scan(x, dt, a_log, b, c)
+    assert y.shape == x.shape and int(deep) >= 0
+
+
 # -- causality: the convolution's reach, the state past it ---------------------------------
 
 def _mixer(seed):

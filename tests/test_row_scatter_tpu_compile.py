@@ -481,21 +481,40 @@ def test_a_convolution_sublayer_s_chain_is_one_pass_each_way(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-def test_a_state_space_sublayer_compiles_at_the_published_widths(topo):
-    """models/lm/ssd.py at granite-4.0-h-micro's widths, one sequence of 8192
-    tokens in chunks of 256, forward and pull: what the program holds is a
-    few arrays of the projection's size ([8192, 8512] float32 is 279 MB) and
-    ONE run's within-chunk factor ([8, 64, 256, 256] float32, 134 MB), never
-    a layer's 1.07 GB of it."""
-    from multiverso_tpu.models.lm import model as lm, ssd
-    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+def _granite():
+    from multiverso_tpu.models.lm import model as lm
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
             "granite-4.0-h-micro-l10.json")) as f:
-        cfg = lm.LMConfig.from_dict(json.load(f))
+        return lm.LMConfig.from_dict(json.load(f))
+
+
+def _wide_copies(text, t, inner):
+    """The program's copies of a [T, inner] float32 array, either way
+    round."""
+    return [line for line in text.splitlines() if " copy(" in line and (
+        f"f32[{t},{inner}]" in line.split(" copy(")[0]
+        or f"f32[{inner},{t}]" in line.split(" copy(")[0])]
+
+
+def test_a_state_space_sublayer_compiles_at_the_published_widths(
+        topo, monkeypatch):
+    """models/lm/ssd.py at granite-4.0-h-micro's widths, one sequence of 8192
+    tokens in chunks of 256, forward and pull, the scan as
+    models/lm/ssd_kernels.py's two kernels (the backend patched to say
+    ``tpu``): what the program holds is a few arrays of the projection's
+    size ([8192, 8512] float32 is 279 MB), and neither a run's within-chunk
+    factor ([8, 64, 256, 256] float32, 134 MB) nor a [8192, 64, 64] array
+    nor a turning copy of X, Y or their cotangents (the kernels read them
+    turned, as the convolution leaves them)."""
+    from multiverso_tpu.models.lm import model as lm, ssd
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    cfg = _granite()
     t, h = 8192, cfg.hidden
     assert (h, cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state) == (
         2048, 64, 64, 128) and ssd.chunk_of(cfg, t) == 256
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssd.scan_counter(cfg, t) == "LM_SSD_SCAN_KERNEL"
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -512,7 +531,53 @@ def test_a_state_space_sublayer_compiles_at_the_published_widths(topo):
     compiled = jax.jit(sublayer).lower(
         mats, small, shaped((t, h), jnp.float32),
         shaped((t, h), jnp.float32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "mv_ssd_scan_fwd" in text and "mv_ssd_scan_bwd" in text
+    assert "f32[8,64,256,256]" not in text and "[8192,64,64]" not in text
+    assert not _wide_copies(text, t, 4096)
+    # 1.40e9 (the plain scan's program: 1.28e9, and 2.2e9 was its bound)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+
+
+@pytest.mark.parametrize("pulled", [False, True])
+def test_the_state_space_scan_is_two_kernels_at_the_cell_s_shapes(
+        topo, pulled, monkeypatch):
+    """``ssd.scan`` and its pull at 8,192 positions, 64 heads of 64 lanes, a
+    state of 128, the arrays handed over turned as the layer program has
+    them: one kernel forward, the keeping forward walk and the backward one
+    with the pull, no [8, 64, 256, 256] array, and for temporaries the
+    states kept (67 MB: the compiler reads 68.0) and the turned [T, 128]
+    arrays, not one array of [8192, 4096] (134 MB)."""
+    from multiverso_tpu.models.lm import ssd
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    t, heads, lanes, state = 8192, 64, 64, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssd.scan_in_kernels(t, heads, lanes, state)
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)
+
+    def scan(x, dt, a_log, b, c):
+        y, deep = ssd.scan(x.T.reshape(t, heads, lanes), dt.T, a_log, b.T,
+                           c.T)
+        return y.reshape(t, -1).T, deep
+
+    def pull(x, dt, a_log, b, c, dy):
+        return jax.vjp(lambda *a: scan(*a)[0], x, dt, a_log, b, c)[1](dy)
+
+    args = (shaped(heads * lanes, t), shaped(heads, t), shaped(heads),
+            shaped(state, t), shaped(state, t))
+    compiled = jax.jit(pull).lower(*args, args[0]).compile() if pulled \
+        else jax.jit(scan).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + pulled
+    assert "mv_ssd_scan_fwd" in text
+    assert ("mv_ssd_scan_bwd" in text) is pulled
+    assert "f32[8,64,256,256]" not in text
+    assert not _wide_copies(text, t, heads * lanes)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (100e6 if pulled else 16e6)
 
 
 # -- the pass between the attention's projections and its kernel -----------------
