@@ -97,7 +97,7 @@ class Driver:
         jax.block_until_ready(self.batches)
         c = self.cfg
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="lm", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             heads=c.n_heads, kv_heads=c.n_kv_heads, head_dim=c.head_dim,
             router_outputs=c.n_experts, held=c.experts_held[1],
             expert_width=c.expert_width, vocab=c.vocab, layers=c.n_layers,

@@ -63,10 +63,10 @@ class Driver(lm.Driver):
         self.check_batch = pool[n]
         jax.block_until_ready(self.batches)
         c = self.cfg
-        # no ``window_layout``: the causal and window readers
-        # (trainer.mfu.lm, trainer.attn_roofline.lm) find nothing here
+        # ``family``: the counting module the merged readers take
+        # (trainer.mfu.lm, trainer.attn_roofline.lm: lib/bdshapes.py)
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="bd", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             heads=c.n_heads, kv_heads=c.n_kv_heads, head_dim=c.head_dim,
             router_outputs=c.n_experts, held=c.experts_held[1],
             expert_width=c.expert_width, vocab=c.vocab, layers=c.n_layers,

@@ -98,11 +98,10 @@ class Driver(lm.Driver):
         sparse = sum(c.ffn_layout)
         # drivers/lm_mla.py's keys (mlashapes.py counts by them): ``layers``
         # the layers with routed experts, the module's among them; no
-        # streams, so no mixer is counted; no ``window_layout`` and no
-        # ``block_length``: the causal, window and block-diffusion readers
-        # find nothing here
+        # streams, so no mixer is counted; ``family``: xing's, the counting
+        # module the merged readers take
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="mla", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             heads_held=c.n_heads_held, qk_dim=c.head_dim, v_dim=c.v_head_dim,
             q_rank=c.q_lora_rank, kv_rank=c.kv_lora_rank,
             rope_dim=c.qk_rope_dim, router_outputs=c.n_experts,

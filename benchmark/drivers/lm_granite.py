@@ -90,17 +90,15 @@ class Driver(lm.Driver):
             and not c.n_experts and not any(c.ffn_layout)
         # ``layers`` 0, ``held`` 0: no layer has routed experts, and the
         # experts' and routers' shared readers are not asked here;
-        # ``conv_taps``: the taps a channel of the mixer's convolution, the
-        # key trainer.attn_full_roofline_d64.lm tells a model of 64-lane
-        # heads by; ``ssd_chunk``: the chunk the scan ran at
+        # ``ssd_chunk``: the chunk the scan ran at
         # (benchmark/lib/ssdshapes.py counts the function at it)
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="ssd", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             attention_layout=list(c.attention_layout),
             ssd_heads=c.ssd_heads, ssd_head_dim=c.ssd_head_dim,
             ssd_state=c.ssd_state, ssd_chunk=ssd.chunk_of(c, self.T),
-            conv_taps=c.ssd_conv, heads=c.n_heads, kv_heads=c.n_kv_heads,
+            heads=c.n_heads, kv_heads=c.n_kv_heads,
             head_dim=c.head_dim, router_outputs=0, top_k=0, held=0,
             expert_width=0, dense_width=c.dense_width, vocab=c.vocab,
             layers=0, sparse_layers=0, dense_layers=c.n_layers,

@@ -101,7 +101,7 @@ class Driver(lm.Driver):
         # another family's attention find nothing here
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="kda", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             attention_layout=list(c.attention_layout),
             kda_heads=c.kda_heads, kda_head_dim=c.kda_head_dim,
             kda_conv=c.kda_conv, mla_heads=c.n_heads, qk_dim=c.head_dim,

@@ -87,7 +87,7 @@ class Driver(lm.Driver):
         # nothing here (benchmark/lib/convshapes.py counts this one's)
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="conv", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             attention_layout=list(c.attention_layout),
             conv_taps=c.conv_taps, heads=c.n_heads, kv_heads=c.n_kv_heads,
             head_dim=c.head_dim, router_outputs=c.n_experts, top_k=c.top_k,

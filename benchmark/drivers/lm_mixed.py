@@ -72,12 +72,12 @@ class Driver(lm.Driver):
         assert c.one_ffn_input and c.attn_gate == "head" and c.rotary_kinds
         sparse = sum(c.ffn_layout)
         # ``layers``: the layers with routed experts (what the experts'
-        # and the routers' shared readers count by); no ``heads``: the
-        # readers that count one head count a model (trainer.mfu.lm,
-        # trainer.attn_roofline.lm) find nothing here
+        # and the routers' shared readers count by); ``family``: the
+        # counting module the merged readers take (trainer.mfu.lm,
+        # trainer.attn_roofline.lm: lib/mixedshapes.py, a head count a layer)
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="mixed", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             heads_layout=list(c.heads_layout), kv_heads=c.n_kv_heads,
             head_dim=c.head_dim, window=c.window,
             windowed=list(c.window_layout),

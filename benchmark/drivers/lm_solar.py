@@ -81,7 +81,7 @@ class Driver(lm.Driver):
         # the readers that count another family's attention find nothing
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="solar", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             attention_layout=list(c.attention_layout),
             kda_heads=c.kda_heads_held, kda_heads_all=c.kda_heads,
             kda_head_dim=c.kda_head_dim, kda_conv=c.kda_conv,

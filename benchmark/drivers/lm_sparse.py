@@ -69,11 +69,11 @@ class Driver(lm.Driver):
         super().build()
         c = self.cfg
         assert c.selection == "topk_indexer" and c.one_ffn_input
-        # no ``window_layout``, no ``block_length``: the causal, window and
-        # block-diffusion readers find nothing here
+        # ``family``: the counting module the merged readers take
+        # (lib/sparseshapes.py)
         self.ctx.shapes.clear()
         self.ctx.shapes.update(
-            sequences=self.B, seq_len=self.T, hidden=c.hidden,
+            family="sparse", sequences=self.B, seq_len=self.T, hidden=c.hidden,
             heads=c.n_heads, kv_heads=c.n_kv_heads, head_dim=c.head_dim,
             router_outputs=c.n_experts, top_k=c.top_k,
             held=c.experts_held[1], expert_width=c.expert_width,

@@ -16,6 +16,9 @@ it is counted; PERF.md section 7 names it as work a later PR can skip.
 
 from benchmark.lib import lmshapes
 
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = ("mv.lm.attn.blockdiff.kernel",)
+
 
 def attention_pairs(seq_len: int, block: int) -> int:
     """Unmasked (query, key) pairs of one head over one sequence's two
@@ -45,12 +48,16 @@ def dense_flops(s: dict) -> int:
         2 * s["layers"] * layer + 2 * s["hidden"] * s["vocab"])
 
 
+def attention_step_flops(s: dict) -> int:
+    """ONE step's attention proper, every layer."""
+    return s["layers"] * attention_flops(
+        s["sequences"], s["seq_len"], s["heads"], s["head_dim"],
+        s["block_length"])
+
+
 def step_flops(steps: int, assignments: int, s: dict) -> int:
     """Operations of ``steps`` steps whose layers saw ``assignments``
     assignments on held experts in all."""
-    attention = s["layers"] * attention_flops(
-        s["sequences"], s["seq_len"], s["heads"], s["head_dim"],
-        s["block_length"])
-    return (steps * (attention + dense_flops(s))
+    return (steps * (attention_step_flops(s) + dense_flops(s))
             + lmshapes.expert_flops(assignments, s["hidden"],
                                     s["expert_width"]))

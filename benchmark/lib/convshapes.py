@@ -26,6 +26,9 @@ from benchmark.lib import kdashapes, lmshapes, solarshapes
 PASSES = lmshapes.PASSES
 layers_of, tokens = kdashapes.layers_of, kdashapes.tokens
 attention_flops = solarshapes.attention_flops   # ONE attention layer's proper
+attention_step_flops = solarshapes.attention_step_flops     # every gqa layer's
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = solarshapes.ATTENTION_SCOPES
 
 
 def conv_dense_flops(s: dict) -> int:
@@ -69,7 +72,7 @@ def step_flops(steps: int, assignments: int, s: dict) -> int:
     """Operations of ``steps`` steps whose sparse layers saw ``assignments``
     assignments on held experts in all."""
     mixers = (layers_of(s, "conv") * chain_flops(s)
-              + layers_of(s, "gqa") * attention_flops(s))
+              + attention_step_flops(s))
     return (steps * (mixers + PASSES * tokens(s) * token_flops(s))
             + lmshapes.expert_flops(assignments, s["hidden"],
                                     s["expert_width"]))

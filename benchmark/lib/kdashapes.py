@@ -22,6 +22,9 @@ only read low, and no share can pass 100% whatever the chunk.
 from benchmark.lib import lmshapes
 
 PASSES = lmshapes.PASSES
+#: This family's row of lib/families.py. No ATTENTION_SCOPES: the one latent
+#: layer's kernel has had no roofline of its own in this family's cell.
+COUNTERS = lmshapes.COUNTERS
 
 
 def layers_of(s: dict, kind: str) -> int:

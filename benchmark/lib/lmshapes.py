@@ -18,6 +18,10 @@ ADAM_BYTES_PER_PARAMETER = 28   # read w, m, v, g; write w, m, v: float32
 UPDATE_SCOPES = ("mv.update.rule", "mv.update.dedup", "mv.update.scatter_add",
                  "mv.update.pad")
 PASSES = 3                      # forward, and a backward of twice its work
+#: This family's row of lib/families.py: the counters ``step_flops`` counts
+#: from, and the attention kernels' scopes.
+COUNTERS = ("LM_STEP", "LM_HELD_ASSIGNMENTS")
+ATTENTION_SCOPES = ("mv.lm.attn.full.kernel", "mv.lm.attn.window.kernel")
 
 
 def attention_pairs(seq_len: int, window: int) -> int:
@@ -52,14 +56,20 @@ def dense_flops(tokens: int, s: dict) -> int:
                               + 2 * s["hidden"] * s["vocab"])
 
 
-def step_flops(steps: int, assignments: int, s: dict) -> int:
-    """Operations of ``steps`` steps whose layers saw ``assignments``
-    assignments on held experts in all."""
-    attention = sum(
+def attention_step_flops(s: dict) -> int:
+    """ONE step's attention proper: every layer's, causal or under the
+    window by ``window_layout``."""
+    return sum(
         attention_flops(s["sequences"], s["seq_len"], s["heads"],
                         s["head_dim"], s["window"] if windowed else 0)
         for windowed in s["window_layout"])
-    return (steps * (attention + dense_flops(s["sequences"] * s["seq_len"], s))
+
+
+def step_flops(steps: int, assignments: int, s: dict) -> int:
+    """Operations of ``steps`` steps whose layers saw ``assignments``
+    assignments on held experts in all."""
+    return (steps * (attention_step_flops(s)
+                     + dense_flops(s["sequences"] * s["seq_len"], s))
             + expert_flops(assignments, s["hidden"], s["expert_width"]))
 
 

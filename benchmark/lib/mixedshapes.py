@@ -17,6 +17,8 @@ experts count by).
 from benchmark.lib import lmshapes
 
 PASSES = lmshapes.PASSES
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = lmshapes.ATTENTION_SCOPES    # both kinds' kernels
 
 
 def pairs(s: dict, windowed) -> int:
@@ -35,6 +37,9 @@ def attention_flops(s: dict) -> int:
                * pairs(s, windowed)
                for heads, windowed in zip(s["heads_layout"],
                                           s["windowed"]))
+
+
+attention_step_flops = attention_flops    # it counts every layer already
 
 
 def layer_token_flops(s: dict, heads: int, sparse) -> int:

@@ -16,6 +16,8 @@ and two stream sublayers.
 from benchmark.lib import lmshapes
 
 PASSES = lmshapes.PASSES
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = ("mv.lm.attn.mla.kernel",)
 
 
 def blocks(s: dict) -> int:
@@ -30,6 +32,12 @@ def attention_flops(s: dict) -> int:
     pairs = lmshapes.attention_pairs(s["seq_len"], 0)
     return (PASSES * 2 * (s["qk_dim"] + s["v_dim"]) * s["heads_held"]
             * s["sequences"] * pairs)
+
+
+def attention_step_flops(s: dict) -> int:
+    """ONE step's attention proper: every layer's, the modules' among
+    them."""
+    return blocks(s) * attention_flops(s)
 
 
 def attention_dense_flops(s: dict) -> int:
@@ -70,7 +78,7 @@ def step_flops(steps: int, assignments: int, s: dict) -> int:
     """Operations of ``steps`` steps whose sparse layers saw
     ``assignments`` assignments on held experts in all."""
     tokens = s["sequences"] * s["seq_len"]
-    return (steps * (blocks(s) * attention_flops(s)
+    return (steps * (attention_step_flops(s)
                      + PASSES * tokens * token_flops(s))
             + lmshapes.expert_flops(assignments, s["hidden"],
                                     s["expert_width"]))

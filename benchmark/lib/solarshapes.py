@@ -20,6 +20,8 @@ from benchmark.lib import kdashapes, lmshapes
 
 PASSES = lmshapes.PASSES
 layers_of, tokens = kdashapes.layers_of, kdashapes.tokens
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = ("mv.lm.attn.full.kernel",)
 
 
 def attention_flops(s: dict) -> int:
@@ -27,6 +29,11 @@ def attention_flops(s: dict) -> int:
     causal pairs, forward and backward."""
     return lmshapes.attention_flops(s["sequences"], s["seq_len"], s["heads"],
                                     s["head_dim"], 0)
+
+
+def attention_step_flops(s: dict) -> int:
+    """ONE step's attention proper: every softmax layer's."""
+    return layers_of(s, "gqa") * attention_flops(s)
 
 
 def gqa_dense_flops(s: dict) -> int:
@@ -53,7 +60,7 @@ def step_flops(steps: int, assignments: int, s: dict) -> int:
     assignments on held experts in all."""
     delta = layers_of(s, "kda") * (kdashapes.scan_flops(s)
                                    + kdashapes.conv_flops(s))
-    softmax = layers_of(s, "gqa") * attention_flops(s)
+    softmax = attention_step_flops(s)
     return (steps * (delta + softmax + PASSES * tokens(s) * token_flops(s))
             + lmshapes.expert_flops(assignments, s["hidden"],
                                     s["expert_width"]))

@@ -18,6 +18,9 @@ masked, padded or repeated work.
 
 from benchmark.lib import lmshapes
 
+COUNTERS = lmshapes.COUNTERS            # this family's row of lib/families.py
+ATTENTION_SCOPES = ("mv.lm.attn.sparse.kernel",)
+
 
 def causal_pairs(seq_len: int) -> int:
     return seq_len * (seq_len + 1) // 2
@@ -36,6 +39,11 @@ def attention_flops(s: dict) -> int:
     operations a pair and a lane), forward and backward, one layer."""
     return (lmshapes.PASSES * 4 * s["head_dim"] * s["heads"] * s["sequences"]
             * selected_pairs(s["seq_len"], s["index_topk"]))
+
+
+def attention_step_flops(s: dict) -> int:
+    """ONE step's attention proper, every layer."""
+    return s["layers"] * attention_flops(s)
 
 
 def indexer_flops(s: dict) -> int:
@@ -63,7 +71,7 @@ def dense_flops(s: dict) -> int:
 def step_flops(steps: int, assignments: int, s: dict) -> int:
     """Operations of ``steps`` steps whose layers saw ``assignments``
     assignments on held experts in all."""
-    layers = s["layers"] * (attention_flops(s) + indexer_flops(s))
+    layers = attention_step_flops(s) + s["layers"] * indexer_flops(s)
     return (steps * (layers + dense_flops(s))
             + lmshapes.expert_flops(assignments, s["hidden"],
                                     s["expert_width"]))

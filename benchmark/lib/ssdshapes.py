@@ -22,8 +22,9 @@ products, and its scan AS THE FUNCTION DEFINES IT at the chunk ``c`` that ran
 (the pairs ABOVE the diagonal, which a product of whole ``c x c`` tiles also
 computes, are masked work and are not counted; ``L``'s exponentials, the
 cumulative sums, the convolution, the gate and the norm are no products and
-are not counted: PERF.md section 7 has the bytes a reader of each would
-set their time against, once ``BENCHMARK.json`` has room for one).
+are not counted as operations). The scan and the gate are bound by their
+BYTES, which ``scan_bytes`` and ``gate_bytes`` count as the layer's own
+arrays once a pass, whatever the chunk and whatever implements them.
 """
 
 from benchmark.lib import convshapes, kdashapes, lmshapes
@@ -31,7 +32,10 @@ from benchmark.lib import convshapes, kdashapes, lmshapes
 PASSES = lmshapes.PASSES
 layers_of, tokens = kdashapes.layers_of, kdashapes.tokens
 attention_flops = convshapes.attention_flops    # ONE attention layer's proper
+attention_step_flops = convshapes.attention_step_flops      # every gqa layer's
 gqa_dense_flops = convshapes.gqa_dense_flops
+COUNTERS = ("LM_STEP",)     # this family's row of lib/families.py: no experts
+ATTENTION_SCOPES = convshapes.ATTENTION_SCOPES
 
 def inner(s: dict) -> int:
     return s["ssd_heads"] * s["ssd_head_dim"]
@@ -52,6 +56,24 @@ def scan_flops(s: dict) -> int:
     return int(PASSES * position * tokens(s))
 
 
+def scan_bytes(s: dict) -> int:
+    """The least ONE state-space layer's scan moves over a step's tokens,
+    forward and backward: X in and Y out (``2 H P``), B and C (``2 N``) and
+    dt (``H``) a position a pass, float32 as the layer holds them. ``L``,
+    the cumulative sums and the states between chunks are the
+    implementation's and are not counted."""
+    return (PASSES * 4 * (2 * inner(s) + 2 * s["ssd_state"] + s["ssd_heads"])
+            * tokens(s))
+
+
+def gate_bytes(s: dict) -> int:
+    """The least ONE state-space layer's gate and norm move over a step's
+    tokens: Y and z read and the normed product written forward; Y, z and
+    the product's gradient read and two gradients written backward: eight
+    float32 arrays of ``H P`` a position."""
+    return 4 * 8 * inner(s) * tokens(s)
+
+
 def token_flops(s: dict) -> int:
     """The products every token goes through in a step, forward: each
     layer's projections by its kind, every layer's dense MLP, the head (ONE
@@ -66,5 +88,5 @@ def token_flops(s: dict) -> int:
 def step_flops(steps: int, s: dict) -> int:
     """Operations of ``steps`` steps."""
     mixers = (layers_of(s, "ssd") * scan_flops(s)
-              + layers_of(s, "gqa") * attention_flops(s))
+              + attention_step_flops(s))
     return steps * (mixers + PASSES * tokens(s) * token_flops(s))

@@ -46,7 +46,8 @@ MV_PREFIX = "mv:"
 SCOPE_PREFIX = "mv."
 NO_SCOPE = "no-scope"
 # monitors that time a thread blocked on another's work
-WAITS = ("mv:TABLE_WAIT", "mv:PS_GET_STALL", "mv:MA_COMM_STALL")
+WAITS = ("mv:TABLE_WAIT", "mv:PS_GET_STALL", "mv:MA_COMM_STALL",
+         "mv:BLOB_D2H_READY")
 
 _HASH = re.compile(r"[(_]\d{5,}[)_]?$")
 _COLLECTIVE = re.compile(

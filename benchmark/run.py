@@ -178,8 +178,14 @@ def main(argv=None) -> int:
     traced, reduced = None, None
     if args.trace:
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+        # The Python tracer records every call of every thread and slows
+        # the host it shares with the loop, so the traced window would read
+        # the profiler's idle time as the program's. The host tracer stays
+        # as it is: the ``mv:`` and ``bench:`` spans are its events.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         try:
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
             ctx.annotate(True)
             try:
                 traced = driver.measure(TRACE_SECONDS)

@@ -3,7 +3,16 @@ runs on: the repo's `BENCHMARK.json` with a configuration, a cell, a
 traffic mix and two metrics appended last (and the cell's name last in
 the `workloads` lists of what it reports), as new files beside a copy of
 `benchmark/`. `conftest.py` makes the tree once a session (`root`,
-`appended_root`); `bench_at` gives the lists while tests are collected."""
+`appended_root`); `bench_at` gives the lists while tests are collected.
+
+What a later PR does NOT append: an entry for a question the list already
+asks. A new family of language model brings `benchmark/lib/<family>shapes.py`
+(a row of `benchmark/lib/families.py`'s table: `COUNTERS`, `step_flops`,
+`ATTENTION_SCOPES`, `attention_step_flops`), a driver that writes
+`family="<family>"` into `ctx.shapes`, and its cell's name appended to the
+`workloads` of `trainer.mfu.lm` and `trainer.attn_roofline.lm`: not two new
+entries. `per_layer` holds at most 128 (`entries.MOST_PER_LAYER`), and
+`entries.check_all` refuses a fuller list on this copy too."""
 
 import json
 import os

@@ -64,8 +64,9 @@ def test_it_is_an_entry_found_by_name_with_its_cells(root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", NAME)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == ["st21b.ps-8k", "sdar30b.ps-bd4k",
-                                   "xing29b.ps-4k", "laguna33b.ps-8k"]
+    # by membership: later cells were appended to the list
+    assert {"st21b.ps-8k", "sdar30b.ps-bd4k", "xing29b.ps-4k",
+            "laguna33b.ps-8k"} <= set(metric["workloads"])
     assert (metric["unit"], metric["better"]) == ("%", "higher")
     load = entries.named(bench, "per_layer",
                          "trainer.expert_load_max_over_mean.lm")

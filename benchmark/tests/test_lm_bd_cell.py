@@ -22,14 +22,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CELL = "sdar30b.ps-bd4k"
 CONFIG = "sdar-30b-a3b-l6"
-SHAPES = {"sequences": 2, "seq_len": 4096, "hidden": 2048, "heads": 32,
+SHAPES = {"family": "bd", "sequences": 2, "seq_len": 4096, "hidden": 2048,
+          "heads": 32,
           "kv_heads": 4, "head_dim": 128, "router_outputs": 128, "held": 16,
           "expert_width": 768, "vocab": 18992, "layers": 6,
           "block_length": 4, "parameters": 645623296}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.attn_blockdiff_ms_per_step.lm",
-       "trainer.attn_blockdiff_roofline.lm", "trainer.noise_ms_per_step.lm",
-       "trainer.masked_share.lm", "trainer.mfu_blockdiff.lm"]
+NEW = ["trainer.attn_blockdiff_ms_per_step.lm", "trainer.masked_share.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_blockdiff_roofline.lm` and
+# `trainer.mfu_blockdiff.lm` until then
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "trainer.experts_ms_per_step.lm", "trainer.head_ms_per_step.lm",
@@ -45,8 +48,7 @@ OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "device.idle_share.train", "trainer.block_ms.train",
          "trainer.programs_built_in_window.train", "setup.table_init_s"]
 # causal and window pairs: they must find nothing to read here
-NOT_THIS_CELL = ["trainer.mfu.lm", "trainer.attn_roofline.lm",
-                 "trainer.attn_full_ms_per_step.lm",
+NOT_THIS_CELL = ["trainer.attn_full_ms_per_step.lm",
                  "trainer.attn_window_ms_per_step.lm"]
 
 
@@ -128,21 +130,20 @@ def _read(name, obs):
 
 WANT = {
     "trainer.attn_blockdiff_ms_per_step.lm": 1500.0 / STEPS,
-    "trainer.attn_blockdiff_roofline.lm":
+    "trainer.attn_roofline.lm":
         100 * STEPS * 6 * bdshapes.attention_flops(2, 4096, 32, 128, 4)
         / 197e12 / 1.2,
-    "trainer.noise_ms_per_step.lm": 2.0 / STEPS,
     "trainer.masked_share.lm": 100 * 4100 / 8192,
-    "trainer.mfu_blockdiff.lm":
+    "trainer.mfu.lm":
         100 * bdshapes.step_flops(30, 30 * 6 * 16384, SHAPES) / 197e12 / 20.0,
 }
 
 
 def test_the_wanted_values_are_all_the_new_metrics():
-    assert sorted(WANT) == sorted(NEW)
+    assert sorted(WANT) == sorted(NEW + MERGED)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_reader(name):
     value = _read(name, _obs())
     assert value == pytest.approx(WANT[name])
@@ -150,7 +151,7 @@ def test_reader(name):
         assert 0 < value < 100
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     """A parent commit runs the readers too, and so does the other
     language-model cell: no scope, no counter, no shape of this
@@ -162,7 +163,9 @@ def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     assert _read(name, _obs(trace=None, traced={}, window={}, shapes={})) \
         is None
     # st21b.ps-8k: the trainer's older counters and scopes, its own shapes
-    other = dict(SHAPES, window=4096, window_layout=[0, 1, 1, 1])
+    if name in MERGED:
+        return      # one reader for both cells: it reads st21b.ps-8k's too
+    other = dict(SHAPES, family="lm", window=4096, window_layout=[0, 1, 1, 1])
     other.pop("block_length")
     causal = {"window_s": 3.0, "programs": {}, "scopes": {"jit_forward": {
         "mv.lm.attn.full.kernel": 0.06, "mv.lm.experts": 0.2}}}
@@ -179,12 +182,13 @@ def test_the_causal_readers_find_nothing_in_this_cell(name):
 
 # -- the entries, the configuration, the controls, the parent -----------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == [CELL] and metric["moves"] == "words_per_s"
+    # by membership: a later cell may be appended to any reader's list
+    assert CELL in metric["workloads"] and metric["moves"] == "words_per_s"
     assert metric["layer"] == "trainer"
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}

@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CELL = "st21b.ps-8k"
 CONFIG = "smallthinker-21ba3b-l4"
-SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 2560, "heads": 28,
+SHAPES = {"family": "lm", "sequences": 2, "seq_len": 8192, "hidden": 2560,
+          "heads": 28,
           "kv_heads": 4, "head_dim": 128, "router_outputs": 64, "held": 16,
           "expert_width": 768, "vocab": 37984, "layers": 4, "window": 4096,
           "window_layout": [0, 1, 1, 1], "parameters": 656529920}
@@ -198,7 +199,8 @@ def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == [CELL] and metric["moves"] == "words_per_s"
+    # by membership: later cells were appended to these readers' lists
+    assert CELL in metric["workloads"] and metric["moves"] == "words_per_s"
     assert metric["layer"] in ("trainer", "table programs")
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}

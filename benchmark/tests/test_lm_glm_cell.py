@@ -23,7 +23,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CELL = "glm30b.ps-8k"
 CONFIG = "glm47-flash-30b-a3b-l5"
-SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 2048, "heads_held": 20,
+SHAPES = {"family": "mla", "sequences": 2, "seq_len": 8192, "hidden": 2048,
+          "heads_held": 20,
           "qk_dim": 256, "v_dim": 256, "q_rank": 768, "kv_rank": 512,
           "rope_dim": 64, "router_outputs": 64, "top_k": 4, "held": 8,
           "expert_width": 1536, "shared_width": 1536, "dense_width": 10240,
@@ -35,8 +36,8 @@ NEW = ["trainer.mtp_head_ms_per_step.lm", "trainer.mtp_step_share.lm",
        "trainer.mtp_positions_share.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "trainer.attn_mla_ms_per_step.lm",
-         "trainer.attn_mla_roofline.lm", "trainer.mtp_ms_per_step.lm",
-         "trainer.mfu_mla.lm", "trainer.shared_expert_ms_per_step.lm",
+         "trainer.attn_roofline.lm", "trainer.mtp_ms_per_step.lm",
+         "trainer.mfu.lm", "trainer.shared_expert_ms_per_step.lm",
          "trainer.router_load_max_over_mean.lm",
          "trainer.router_ms_per_step.lm", "trainer.experts_ms_per_step.lm",
          "trainer.head_ms_per_step.lm", "trainer.experts_roofline.lm",
@@ -56,12 +57,11 @@ OLDER = ["words_per_s", "peak_hbm_gb", "trainer.attn_mla_ms_per_step.lm",
          "host.beat_late_ms.train"]
 # the streams', other attentions' and block diffusion's: nothing to read
 NOT_THIS_CELL = ["trainer.hc_ms_per_step.lm", "trainer.hc_roofline.lm",
-                 "trainer.mfu.lm", "trainer.attn_roofline.lm",
                  "trainer.attn_full_ms_per_step.lm",
                  "trainer.attn_window_ms_per_step.lm",
                  "trainer.attn_blockdiff_ms_per_step.lm",
                  "trainer.attn_pass_fused_share.lm",
-                 "trainer.attn_kda_ms_per_step.lm", "trainer.mfu_kda.lm",
+                 "trainer.attn_kda_ms_per_step.lm",
                  "trainer.attn_sparse_ms_per_step.lm",
                  "trainer.attn_gate_ms_per_step.lm"]
 TOKENS = 2 * 8192
@@ -226,10 +226,10 @@ def test_the_shared_readers_count_this_cell():
     programs, both attention scopes in every program, the main head's pass
     alone under ``mv.lm.head``."""
     kernel = 0.236 + 0.916 + 0.047 + 0.183
-    assert _read("trainer.attn_mla_roofline.lm", _obs()) == pytest.approx(
+    assert _read("trainer.attn_roofline.lm", _obs()) == pytest.approx(
         100 * STEPS * BLOCKS * mlashapes.attention_flops(SHAPES) / 197e12
         / kernel)
-    assert 0 < _read("trainer.attn_mla_roofline.lm", _obs()) < 100
+    assert 0 < _read("trainer.attn_roofline.lm", _obs()) < 100
     assert _read("trainer.attn_mla_ms_per_step.lm", _obs()) \
         == pytest.approx(1e3 * (kernel + 0.165 + 0.464 + 0.033 + 0.093)
                          / STEPS)
@@ -237,7 +237,11 @@ def test_the_shared_readers_count_this_cell():
         == pytest.approx(1e3 * MODULE_S / STEPS)
     assert _read("trainer.head_ms_per_step.lm", _obs()) \
         == pytest.approx(115.0 / STEPS)
-    assert 0 < _read("trainer.mfu_mla.lm", _obs()) < 100
+    assert _read("trainer.mfu.lm", _obs()) == pytest.approx(
+        100 * mlashapes.step_flops(*(WINDOW[c]["count"]
+                                     for c in mlashapes.COUNTERS), SHAPES)
+        / 197e12 / _obs().window.seconds)
+    assert 0 < _read("trainer.mfu.lm", _obs()) < 100
     assert _read("trainer.router_load_max_over_mean.lm", _obs()) \
         == pytest.approx(2560 / 1024)
     assert lmshapes.expert_bytes(1, 0, SHAPES) \

@@ -1,4 +1,4 @@
-"""The cell `granite3b.ps-8k`: its reader on hand-built ``Observations``, the
+"""The cell `granite3b.ps-8k`: its readers on hand-built ``Observations``, the
 counting functions at this cell's shapes by hand (BY LAYER KIND: nine
 state-space layers, one of attention at 64 lanes, ten dense MLPs, no
 experts), the older readers' counts there, its entries by name, its
@@ -27,19 +27,27 @@ CELL = "granite3b.ps-8k"
 CONFIG = "granite-4.0-h-micro-l10"
 LAYOUT = ["ssd"] * 5 + ["gqa"] + ["ssd"] * 4
 # what benchmark/drivers/lm_granite.py fills: no layer has routed experts
-SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 2048,
+SHAPES = {"family": "ssd", "sequences": 2, "seq_len": 8192, "hidden": 2048,
           "attention_layout": LAYOUT, "ssd_heads": 64, "ssd_head_dim": 64,
-          "ssd_state": 128, "ssd_chunk": 256, "conv_taps": 4, "heads": 32,
+          "ssd_state": 128, "ssd_chunk": 256, "heads": 32,
           "kv_heads": 8, "head_dim": 64, "router_outputs": 0, "top_k": 0,
           "held": 0, "expert_width": 0, "dense_width": 8192, "vocab": 12544,
           "layers": 0, "sparse_layers": 0, "dense_layers": 10,
           "parameters": 772160448}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.mfu_granite.lm"]
+# the state-space layer's eight readers (PR 67: PR 65 wrote them, read them
+# once on the chip and took them out of a list that was full)
+NEW = ["trainer.ssd_ms_per_step.lm", "trainer.ssd_scan_ms_per_step.lm",
+       "trainer.ssd_scan_roofline.lm", "trainer.ssd_conv_ms_per_step.lm",
+       "trainer.ssd_gate_ms_per_step.lm", "trainer.ssd_gate_roofline.lm",
+       "trainer.ssd_decay_deep_share.lm", "trainer.ssd_scan_kernel_share.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_full_roofline_d64.lm` and
+# `trainer.mfu_granite.lm` until then
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "setup.table_init_s",
          "trainer.attn_full_ms_per_step.lm",
-         "trainer.attn_full_roofline_d64.lm",
          "trainer.attn_lanes_used_share.lm",
          "trainer.attn_blocks_fitted_share.lm",
          "trainer.shared_expert_ms_per_step.lm",
@@ -61,18 +69,16 @@ NOT_JOINED = ["trainer.router_ms_per_step.lm", "trainer.experts_ms_per_step.lm",
               "trainer.experts_roofline.lm", "trainer.experts_short_share.lm",
               "trainer.expert_load_max_over_mean.lm",
               "trainer.router_load_max_over_mean.lm",
-              "trainer.attn_pass_fused_share.lm", "trainer.mfu.lm",
-              "trainer.mfu_lfm2.lm", "trainer.mfu_kda.lm",
-              "trainer.mfu_solar.lm", "trainer.mixers_conv_share.lm",
+              "trainer.attn_pass_fused_share.lm",
               "trainer.shortconv_ms_per_step.lm",
               "trainer.kda_scan_ms_per_step.lm",
               "table.scatter_ms_per_round.train",
               "table.update_fast_share.train"]
-NOTHING_TO_READ = ["trainer.mfu_mixed.lm", "trainer.mfu_mla.lm",
-                   "trainer.mfu_kda.lm", "trainer.mfu_solar.lm",
-                   "trainer.kda_scan_roofline.lm",
+NOTHING_TO_READ = ["trainer.kda_scan_roofline.lm",
+                   "trainer.kda_scan_kernel_share.lm",
+                   "trainer.kda_decay_deep_share.lm",
                    "trainer.shortconv_ms_per_step.lm",
-                   "trainer.mixers_conv_share.lm"]
+                   "trainer.shortconv_roofline.lm"]
 TOKENS = 2 * 8192
 PAIRS = 8192 * 8193 // 2
 
@@ -127,16 +133,22 @@ def _count(**kw):
 
 
 STEPS, RUNS = 3, 19
-SCOPES = {"jit_forward": {"mv.lm.attn.ssd": 0.38, "mv.lm.attn.ssd.scan": 0.30,
+SCOPES = {"jit_forward": {"mv.lm.attn.ssd": 0.38, "mv.lm.attn.ssd.scan": 0.06,
+                          "mv.lm.attn.ssd.conv": 0.05,
+                          "mv.lm.attn.ssd.gate": 0.03,
                           "mv.lm.attn.full.kernel": 0.05,
                           "mv.lm.dense_mlp": 0.28},
-          "jit_backward": {"mv.lm.attn.ssd": 0.39, "mv.lm.attn.ssd.scan": 0.35,
+          "jit_backward": {"mv.lm.attn.ssd": 0.39, "mv.lm.attn.ssd.scan": 0.20,
+                           "mv.lm.attn.ssd.conv": 0.21,
+                           "mv.lm.attn.ssd.gate": 0.06,
                            "mv.lm.attn.full.kernel": 0.11,
                            "mv.lm.dense_mlp": 0.77}}
 TRACE = {"window_s": 3.6, "scopes": SCOPES, "programs": {}}
 TRACED = _count(LM_STEP=STEPS, LM_TOKENS=STEPS * TOKENS)
 WINDOW = _count(LM_STEP=RUNS, LM_TOKENS=RUNS * TOKENS,
                 LM_MIXERS_SSD=RUNS * 18, LM_MIXERS=RUNS * 20,
+                LM_SSD_CHUNKS=RUNS * 18 * 32, LM_SSD_DEEP=RUNS * 18 * 32 * 33,
+                LM_SSD_SCAN_KERNEL=RUNS * 18,
                 LM_ATTN_LANES=RUNS * 2 * 64, LM_ATTN_LANES_TILED=RUNS * 2 * 64)
 
 
@@ -150,21 +162,88 @@ def _read(name, obs):
     return load_module("metrics", name).read(obs)
 
 
-def test_reader():
-    want = 100 * ssdshapes.step_flops(RUNS, SHAPES) / 197e12 / 20.9
-    assert _read("trainer.mfu_granite.lm", _obs()) == pytest.approx(want)
-    assert 30 < want < 40       # my chip run, PR 65: 36.4
+WANT = {
+    "trainer.ssd_ms_per_step.lm": (380 + 60 + 50 + 30 + 390 + 200 + 210 + 60)
+    / STEPS,
+    "trainer.ssd_scan_ms_per_step.lm": 260.0 / STEPS,
+    # X in and Y out, B, C and dt a position a pass: 12 x 8,512 bytes
+    "trainer.ssd_scan_roofline.lm":
+        100 * STEPS * 9 * 12 * 8512 * TOKENS / 819e9 / 0.26,
+    "trainer.ssd_conv_ms_per_step.lm": 260.0 / STEPS,
+    "trainer.ssd_gate_ms_per_step.lm": 90.0 / STEPS,
+    # eight float32 arrays of 4,096 lanes a position
+    "trainer.ssd_gate_roofline.lm":
+        100 * STEPS * 9 * 32 * 4096 * TOKENS / 819e9 / 0.09,
+    "trainer.ssd_decay_deep_share.lm": 100 * 33 / 64,
+    "trainer.ssd_scan_kernel_share.lm": 100.0,
+    "trainer.attn_roofline.lm":
+        100 * STEPS * ssdshapes.attention_flops(SHAPES) / 197e12 / 0.16,
+    "trainer.mfu.lm": 100 * ssdshapes.step_flops(RUNS, SHAPES) / 197e12 / 20.9,
+}
 
 
-def test_the_reader_reads_nothing_from_a_program_without_its_shapes():
-    """A parent commit runs the reader too, and so could another cell: no
-    such counter, no such shape, and no exception."""
-    name = "trainer.mfu_granite.lm"
+def test_the_wanted_values_are_all_the_new_metrics():
+    assert sorted(WANT) == sorted(NEW + MERGED)
+    assert 30 < WANT["trainer.mfu.lm"] < 40     # my chip run, PR 65: 36.4
+
+
+@pytest.mark.parametrize("name", NEW + MERGED)
+def test_reader(name):
+    assert _read(name, _obs()) == pytest.approx(WANT[name])
+    if "roofline" in name or "mfu" in name:
+        assert 0 < WANT[name] < 100
+
+
+def test_the_byte_counts_by_hand():
+    assert ssdshapes.scan_bytes(SHAPES) == 3 * 4 * (2 * 4096 + 2 * 128 + 64) \
+        * TOKENS
+    assert ssdshapes.gate_bytes(SHAPES) == 4 * 8 * 4096 * TOKENS
+    # the chunk is the implementation's: it does not move what the layer needs
+    assert ssdshapes.scan_bytes(dict(SHAPES, ssd_chunk=128)) \
+        == ssdshapes.scan_bytes(SHAPES)
+    # memory-bound: the bytes need 2.5 times what the operations do
+    bytes_s = ssdshapes.scan_bytes(SHAPES) / 819e9
+    assert 2.4 < bytes_s / (ssdshapes.scan_flops(SHAPES) / 197e12) < 2.7
+
+
+def test_a_scan_at_its_bytes_reads_a_hundred_and_no_more():
+    """The least time is the layers' bytes at the memory's peak: a scan or a
+    gate that took no longer would read 100."""
+    for scope, least in (
+            ("mv.lm.attn.ssd.scan", ssdshapes.scan_bytes(SHAPES)),
+            ("mv.lm.attn.ssd.gate", ssdshapes.gate_bytes(SHAPES))):
+        trace = {"window_s": 1.0, "programs": {}, "scopes": {
+            "jit_backward": {scope: STEPS * 9 * least / 819e9}}}
+        name = f"trainer.ssd_{scope.rsplit('.', 1)[1]}_roofline.lm"
+        assert _read(name, _obs(trace=trace)) == pytest.approx(100.0)
+
+
+def test_no_deep_pair_reads_zero_and_the_plain_scan_reads_zero_not_nothing():
+    window = {k: v for k, v in WINDOW.items() if k != "LM_SSD_DEEP"}
+    assert _read("trainer.ssd_decay_deep_share.lm", _obs(window=window)) == 0.0
+    plain = dict(window, **_count(LM_SSD_SCAN_PLAIN=RUNS * 18))
+    del plain["LM_SSD_SCAN_KERNEL"]
+    assert _read("trainer.ssd_scan_kernel_share.lm", _obs(window=plain)) == 0.0
+
+
+@pytest.mark.parametrize("name", NEW + MERGED)
+def test_a_reader_reads_nothing_from_a_program_without_its_shapes(name):
+    """A parent commit runs the readers too, and so could another cell: no
+    such scope, no such counter, no such shape, and no exception."""
     assert _read(name, _obs(trace=None, traced={}, window={}, shapes={})) \
         is None
-    lfm = {"sequences": 2, "seq_len": 8192, "hidden": 2048, "conv_taps": 3,
+    if name in MERGED:
+        return      # one reader for every cell: it reads lfm8b.ps-8k's too
+    # lfm8b.ps-8k: attention at 64 lanes too, its own mixers and counters
+    lfm = {"family": "conv", "sequences": 2, "seq_len": 8192, "hidden": 2048,
+           "conv_taps": 3, "heads": 32, "kv_heads": 8, "head_dim": 64,
            "attention_layout": ["conv", "conv", "gqa", "conv"] * 2}
-    assert _read(name, _obs(shapes=lfm)) is None
+    other = {"window_s": 3.0, "programs": {}, "scopes": {"jit_backward": {
+        "mv.lm.attn.full.kernel": 0.05, "mv.lm.attn.shortconv": 0.3}}}
+    counts = _count(LM_STEP=8, LM_TOKENS=8 * TOKENS, LM_MIXERS=8 * 16,
+                    LM_HELD_ASSIGNMENTS=8 * 6 * 16384)
+    assert _read(name, _obs(trace=other, traced=counts, window=counts,
+                            shapes=lfm)) is None
 
 
 @pytest.mark.parametrize("name", NOTHING_TO_READ)
@@ -178,8 +257,6 @@ def test_the_shared_readers_count_this_cell_by_its_own_shapes():
     reader every layer's, the lanes' share 100, Adam's bytes every parameter
     but the table's rows, which the steps' counted rows bring."""
     obs = _obs()
-    assert _read("trainer.attn_full_roofline_d64.lm", obs) == pytest.approx(
-        100 * STEPS * ssdshapes.attention_flops(SHAPES) / 197e12 / 0.16)
     assert _read("trainer.shared_expert_ms_per_step.lm", obs) \
         == pytest.approx(1050 / STEPS)
     assert _read("trainer.attn_lanes_used_share.lm", obs) == 100.0
@@ -189,14 +266,17 @@ def test_the_shared_readers_count_this_cell_by_its_own_shapes():
 
 # -- the entries, by name --------------------------------------------------------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == [CELL] and metric["moves"] == "words_per_s"
-    assert (metric["layer"], metric["unit"], metric["source"]) == (
-        "trainer", "%", "host_clock")
+    assert CELL in metric["workloads"] and metric["moves"] == "words_per_s"
+    assert metric["layer"] == "trainer"
+    assert metric["unit"] == ("ms" if "_ms_" in name else "%")
+    if name in NEW:     # a traced or a counted number
+        assert metric["source"] == ("program_counter" if "share" in name
+                                    else "device_trace")
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}
 

@@ -27,17 +27,20 @@ CELL = "lfm8b.ps-8k"
 CONFIG = "lfm2-8b-a1b-l8"
 LAYOUT = ["conv", "conv", "gqa", "conv"] * 2
 # what benchmark/drivers/lm_lfm2.py fills: ``layers`` the SPARSE ones
-SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 2048,
+SHAPES = {"family": "conv", "sequences": 2, "seq_len": 8192, "hidden": 2048,
           "attention_layout": LAYOUT, "conv_taps": 3, "heads": 32,
           "kv_heads": 8, "head_dim": 64, "router_outputs": 32, "top_k": 4,
           "held": 8, "expert_width": 1792, "dense_width": 7168,
           "vocab": 16384, "layers": 6, "sparse_layers": 6, "dense_layers": 2,
           "parameters": 772217280}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.mfu_lfm2.lm", "trainer.shortconv_ms_per_step.lm",
-       "trainer.shortconv_roofline.lm",
-       "trainer.attn_full_roofline_d64.lm", "trainer.mixers_conv_share.lm",
+NEW = ["trainer.shortconv_ms_per_step.lm", "trainer.shortconv_roofline.lm",
        "trainer.attn_lanes_used_share.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_full_roofline_d64.lm` and
+# `trainer.mfu_lfm2.lm` until then (`trainer.mixers_conv_share.lm`, 75 from
+# the configuration, was retired)
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "setup.table_init_s",
          "trainer.attn_full_ms_per_step.lm", "trainer.router_ms_per_step.lm",
@@ -60,23 +63,14 @@ OLDER = ["words_per_s", "peak_hbm_gb", "setup.table_init_s",
          "trainer.programs_built_in_window.train",
          "host.stall_ms_per_s.train", "host.frozen_ms_per_s.train",
          "host.beat_late_ms.train"]
-# they count 128-lane heads, other kinds of layer or two vocabulary tables;
-# the last two read the rows form's writes, and the one table's Add is dense
-NOT_JOINED = ["trainer.mfu.lm", "trainer.mfu_blockdiff.lm",
-              "trainer.mfu_mla.lm", "trainer.mfu_mixed.lm",
-              "trainer.mfu_sparse.lm", "trainer.mfu_kda.lm",
-              "trainer.mfu_solar.lm", "trainer.attn_roofline.lm",
-              "trainer.attn_full_roofline_held.lm",
-              "trainer.kda_conv_ms_per_step.lm",
-              "trainer.heads_held_share.lm",
+# they count other kinds of layer; the last two read the rows form's writes,
+# and the one table's Add is dense
+NOT_JOINED = ["trainer.kda_conv_ms_per_step.lm",
               "trainer.attn_window_ms_per_step.lm",
               "table.scatter_ms_per_round.train",
               "table.update_fast_share.train"]
 # of those, the ones whose reader finds nothing in this cell's observations
-NOTHING_TO_READ = ["trainer.mfu_mixed.lm", "trainer.mfu_mla.lm",
-                   "trainer.mfu_solar.lm", "trainer.attn_mixed_roofline.lm",
-                   "trainer.attn_full_roofline_held.lm",
-                   "trainer.kda_conv_ms_per_step.lm",
+NOTHING_TO_READ = ["trainer.kda_conv_ms_per_step.lm",
                    "trainer.attn_window_ms_per_step.lm",
                    "table.scatter_ms_per_round.train",
                    "table.update_fast_share.train"]
@@ -192,22 +186,21 @@ def _read(name, obs):
 
 
 WANT = {
-    "trainer.mfu_lfm2.lm": 100 * convshapes.step_flops(
+    "trainer.mfu.lm": 100 * convshapes.step_flops(
         RUNS, RUNS * 6 * EVEN, SHAPES) / 197e12 / 20.0,
     "trainer.shortconv_ms_per_step.lm": (120 + 12 + 330 + 36) / STEPS,
     "trainer.shortconv_roofline.lm":
         100 * STEPS * 6 * convshapes.mixer_flops(SHAPES) / 197e12 / 0.498,
-    "trainer.attn_full_roofline_d64.lm":
+    "trainer.attn_roofline.lm":
         100 * STEPS * 2 * convshapes.attention_flops(SHAPES) / 197e12 / 0.180,
-    "trainer.mixers_conv_share.lm": 75.0,
     "trainer.attn_lanes_used_share.lm": 100.0}
 
 
 def test_the_wanted_values_are_all_the_new_metrics():
-    assert sorted(WANT) == sorted(NEW)
+    assert sorted(WANT) == sorted(NEW + MERGED)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_reader(name):
     assert _read(name, _obs()) == pytest.approx(WANT[name])
     if name.endswith("roofline.lm") or "mfu" in name:
@@ -220,14 +213,7 @@ def test_heads_padded_to_a_tile_would_read_fifty():
                  _obs(window=window)) == 50.0
 
 
-def test_the_fall_back_s_six_layers_would_read_five_sixths():
-    window = dict(WINDOW, **_count(LM_MIXERS_CONV=RUNS * 5 * 2,
-                                   LM_MIXERS=RUNS * 6 * 2))
-    assert _read("trainer.mixers_conv_share.lm", _obs(window=window)) \
-        == pytest.approx(83.333, rel=1e-4)
-
-
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     """A parent commit runs the readers too, and so could another cell: no
     such scope, no such counter, no such shape, and no exception."""
@@ -292,7 +278,7 @@ def test_the_shared_readers_count_this_cell_by_its_sparse_layers():
 
 # -- the entries, the configuration, the controls, the parent -----------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)

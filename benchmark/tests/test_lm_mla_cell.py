@@ -26,7 +26,8 @@ CELL = "xing29b.ps-4k"
 CONFIG = "xing4-29b-a4b-l5"
 with open(os.path.join(ROOT, "benchmark", "configs", f"{CONFIG}.json")) as f:
     MODULES = int(json.load(f)["num_nextn_predict_layers"])
-SHAPES = {"sequences": 2, "seq_len": 4096, "hidden": 3584, "heads_held": 4,
+SHAPES = {"family": "mla", "sequences": 2, "seq_len": 4096, "hidden": 3584,
+          "heads_held": 4,
           "qk_dim": 192, "v_dim": 128, "q_rank": 768, "kv_rank": 512,
           "rope_dim": 64, "router_outputs": 64, "top_k": 4, "held": 8,
           "expert_width": 1024, "shared_width": 1024, "dense_width": 9216,
@@ -34,10 +35,14 @@ SHAPES = {"sequences": 2, "seq_len": 4096, "hidden": 3584, "heads_held": 4,
           "dense_layers": 1, "modules": MODULES, "streams": 4,
           "parameters": 656127246 + MODULES * 133483382}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.attn_mla_ms_per_step.lm", "trainer.attn_mla_roofline.lm",
+NEW = ["trainer.attn_mla_ms_per_step.lm",
        "trainer.hc_ms_per_step.lm", "trainer.hc_roofline.lm",
        "trainer.shared_expert_ms_per_step.lm", "trainer.mtp_ms_per_step.lm",
-       "trainer.router_load_max_over_mean.lm", "trainer.mfu_mla.lm"]
+       "trainer.router_load_max_over_mean.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_mla_roofline.lm` and
+# `trainer.mfu_mla.lm` until then
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "trainer.experts_ms_per_step.lm", "trainer.head_ms_per_step.lm",
@@ -54,13 +59,10 @@ OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "device.idle_share.train", "trainer.block_ms.train",
          "trainer.programs_built_in_window.train", "setup.table_init_s"]
 # causal-with-a-window and block-diffusion counts: nothing to read here
-NOT_THIS_CELL = ["trainer.mfu.lm", "trainer.attn_roofline.lm",
-                 "trainer.attn_full_ms_per_step.lm",
+NOT_THIS_CELL = ["trainer.attn_full_ms_per_step.lm",
                  "trainer.attn_window_ms_per_step.lm",
                  "trainer.attn_blockdiff_ms_per_step.lm",
-                 "trainer.attn_blockdiff_roofline.lm",
-                 "trainer.mfu_blockdiff.lm", "trainer.masked_share.lm",
-                 "trainer.noise_ms_per_step.lm"]
+                 "trainer.masked_share.lm"]
 TOKENS = 2 * 4096
 BLOCKS = 5 + MODULES
 
@@ -159,7 +161,7 @@ def _read(name, obs):
 
 WANT = {
     "trainer.attn_mla_ms_per_step.lm": 390.0 / STEPS,
-    "trainer.attn_mla_roofline.lm":
+    "trainer.attn_roofline.lm":
         100 * STEPS * BLOCKS * mlashapes.attention_flops(SHAPES)
         / 197e12 / 0.190,
     "trainer.hc_ms_per_step.lm": 960.0 / STEPS,
@@ -168,17 +170,17 @@ WANT = {
     "trainer.shared_expert_ms_per_step.lm": 200.0 / STEPS,
     "trainer.mtp_ms_per_step.lm": 310.0 / STEPS,
     "trainer.router_load_max_over_mean.lm": 1280 / 512,
-    "trainer.mfu_mla.lm":
+    "trainer.mfu.lm":
         100 * mlashapes.step_flops(30, 30 * LAYERS * 4096, SHAPES)
         / 197e12 / 20.0,
 }
 
 
 def test_the_wanted_values_are_all_the_new_metrics():
-    assert sorted(WANT) == sorted(NEW)
+    assert sorted(WANT) == sorted(NEW + MERGED)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_reader(name):
     value = _read(name, _obs())
     assert value == pytest.approx(WANT[name])
@@ -186,7 +188,7 @@ def test_reader(name):
         assert 0 < value < 100
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     """A parent commit runs the readers too, and so do the other
     language-model cells: no scope, no counter, no shape of this model,
@@ -197,8 +199,11 @@ def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
                             shapes={})) is None
     assert _read(name, _obs(trace=None, traced={}, window={}, shapes={})) \
         is None
+    if name in MERGED:
+        return      # one reader for every cell: it reads st21b.ps-8k's too
     # st21b.ps-8k: the trainer's older counters and scopes, its own shapes
-    other = {"sequences": 2, "seq_len": 8192, "hidden": 2560, "heads": 28,
+    other = {"family": "lm", "sequences": 2, "seq_len": 8192, "hidden": 2560,
+             "heads": 28,
              "kv_heads": 4, "head_dim": 128, "router_outputs": 64, "held": 16,
              "expert_width": 768, "vocab": 37984, "layers": 4, "window": 4096,
              "window_layout": [0, 1, 1, 1], "parameters": 656500000}
@@ -247,12 +252,13 @@ def test_the_shared_readers_count_this_cell_s_layers():
 
 # -- the entries, the configuration, the controls, the parent -----------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == [CELL] and metric["moves"] == "words_per_s"
+    # by membership: later cells were appended to these readers' lists
+    assert CELL in metric["workloads"] and metric["moves"] == "words_per_s"
     assert metric["layer"] == "trainer"
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}

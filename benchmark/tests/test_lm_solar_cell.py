@@ -25,7 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 CELL = "solar250b.ps-8k"
 CONFIG = "solar-open2-250b-a15b-l4"
 # what benchmark/drivers/lm_solar.py fills: the heads are the HELD ones
-SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 4096,
+SHAPES = {"family": "solar", "sequences": 2, "seq_len": 8192, "hidden": 4096,
           "attention_layout": ["gqa", "kda", "kda", "kda"],
           "kda_heads": 8, "kda_heads_all": 64, "kda_head_dim": 128,
           "kda_conv": 4, "heads": 8, "heads_all": 64, "kv_heads": 1,
@@ -33,9 +33,13 @@ SHAPES = {"sequences": 2, "seq_len": 8192, "hidden": 4096,
           "expert_width": 1280, "shared_width": 1280, "vocab": 24576,
           "layers": 4, "sparse_layers": 4, "parameters": 840872600}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.mfu_solar.lm", "trainer.attn_full_roofline_held.lm",
-       "trainer.kda_beta_over_one_share.lm",
-       "trainer.gate_lanes_open_share.lm", "trainer.heads_held_share.lm"]
+NEW = ["trainer.kda_beta_over_one_share.lm",
+       "trainer.gate_lanes_open_share.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_full_roofline_held.lm` and
+# `trainer.mfu_solar.lm` until then (`trainer.heads_held_share.lm`, 12.5 from
+# the configuration, was retired)
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "setup.table_init_s",
          "trainer.attn_kda_ms_per_step.lm", "trainer.kda_conv_ms_per_step.lm",
@@ -62,18 +66,13 @@ OLDER = ["words_per_s", "peak_hbm_gb", "setup.table_init_s",
          "trainer.programs_built_in_window.train",
          "host.stall_ms_per_s.train", "host.frozen_ms_per_s.train",
          "host.beat_late_ms.train"]
-# they count a latent layer, whole heads, one head count a model or 288
-# gated heads: not joined
-NOT_JOINED = ["trainer.mfu_kda.lm", "trainer.mfu_mixed.lm",
-              "trainer.attn_mixed_roofline.lm", "trainer.gate_open_share.lm",
-              "trainer.mfu.lm", "trainer.attn_roofline.lm",
-              "trainer.attn_mla_ms_per_step.lm", "trainer.mfu_mla.lm",
+# they count a latent layer or 288 gated heads: not joined
+NOT_JOINED = ["trainer.gate_open_share.lm", "trainer.attn_mla_ms_per_step.lm",
               "trainer.mla_pass_fused_share.lm",
               "trainer.attn_window_ms_per_step.lm"]
 # of those, the ones whose reader finds nothing in this cell's observations
-NOTHING_TO_READ = ["trainer.mfu_mixed.lm", "trainer.attn_mixed_roofline.lm",
-                   "trainer.gate_open_share.lm",
-                   "trainer.attn_mla_ms_per_step.lm", "trainer.mfu_mla.lm",
+NOTHING_TO_READ = ["trainer.gate_open_share.lm",
+                   "trainer.attn_mla_ms_per_step.lm",
                    "trainer.attn_window_ms_per_step.lm"]
 TOKENS = 2 * 8192
 PAIRS = 8192 * 8193 // 2
@@ -174,22 +173,21 @@ def _read(name, obs):
 
 
 WANT = {
-    "trainer.mfu_solar.lm": 100 * solarshapes.step_flops(
+    "trainer.mfu.lm": 100 * solarshapes.step_flops(
         RUNS, RUNS * 4 * 3276, SHAPES) / 197e12 / 20.0,
-    "trainer.attn_full_roofline_held.lm":
+    "trainer.attn_roofline.lm":
         100 * STEPS * solarshapes.attention_flops(SHAPES) / 197e12 / 0.060,
     "trainer.kda_beta_over_one_share.lm":
         100 * (RUNS * 3 * TOKENS * 8 // 2 + 77) / (RUNS * 3 * TOKENS * 8),
     "trainer.gate_lanes_open_share.lm":
-        100 * (RUNS * TOKENS * 1024 // 2 - 1000) / (RUNS * TOKENS * 1024),
-    "trainer.heads_held_share.lm": 12.5}
+        100 * (RUNS * TOKENS * 1024 // 2 - 1000) / (RUNS * TOKENS * 1024)}
 
 
 def test_the_wanted_values_are_all_the_new_metrics():
-    assert sorted(WANT) == sorted(NEW)
+    assert sorted(WANT) == sorted(NEW + MERGED)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_reader(name):
     assert _read(name, _obs()) == pytest.approx(WANT[name])
     assert 0 < WANT[name] < 100
@@ -202,7 +200,7 @@ def test_a_beta_that_never_passes_one_reads_zero_not_nothing():
                  _obs(window=window)) == 0.0
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     """A parent commit runs the readers too, and so could another cell: no
     such scope, no such counter, no such shape, and no exception."""
@@ -255,7 +253,7 @@ def test_the_shared_readers_count_this_cell_over_its_held_heads():
 
 # -- the entries, the configuration, the controls, the parent -----------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)

@@ -24,16 +24,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CELL = "keye30b.ps-16k"
 CONFIG = "keye-vl2-30b-a3b-lm"
-SHAPES = {"sequences": 1, "seq_len": 16384, "hidden": 2048, "heads": 32,
+SHAPES = {"family": "sparse", "sequences": 1, "seq_len": 16384,
+          "hidden": 2048, "heads": 32,
           "kv_heads": 4, "head_dim": 128, "router_outputs": 128, "top_k": 8,
           "held": 16, "expert_width": 768, "vocab": 18992, "layers": 5,
           "index_heads": 16, "index_dim": 64, "index_topk": 2048,
           "index_tile": 512, "parameters": 562290560}
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-NEW = ["trainer.attn_sparse_ms_per_step.lm", "trainer.attn_sparse_roofline.lm",
+NEW = ["trainer.attn_sparse_ms_per_step.lm",
        "trainer.indexer_ms_per_step.lm", "trainer.indexer_roofline.lm",
        "trainer.select_ms_per_step.lm", "trainer.selected_share.lm",
-       "trainer.select_tiles_live_share.lm", "trainer.mfu_sparse.lm"]
+       "trainer.select_tiles_live_share.lm"]
+# ONE reader for every family since PR 67 (benchmark/lib/families.py): this
+# cell's share of them was `trainer.attn_sparse_roofline.lm` and
+# `trainer.mfu_sparse.lm` until then
+MERGED = ["trainer.attn_roofline.lm", "trainer.mfu.lm"]
 # the older readers the cell reports unedited
 OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "trainer.experts_ms_per_step.lm", "trainer.head_ms_per_step.lm",
@@ -50,11 +55,10 @@ OLDER = ["words_per_s", "peak_hbm_gb", "trainer.router_ms_per_step.lm",
          "device.idle_share.train", "trainer.block_ms.train",
          "trainer.programs_built_in_window.train", "setup.table_init_s"]
 # other masks' and families' readers: they must find nothing to read here
-NOT_THIS_CELL = ["trainer.mfu.lm", "trainer.attn_roofline.lm",
-                 "trainer.attn_full_ms_per_step.lm",
-                 "trainer.attn_blockdiff_roofline.lm",
-                 "trainer.mfu_blockdiff.lm", "trainer.mfu_mla.lm",
-                 "trainer.mfu_mixed.lm"]
+NOT_THIS_CELL = ["trainer.attn_full_ms_per_step.lm",
+                 "trainer.attn_blockdiff_ms_per_step.lm",
+                 "trainer.attn_mla_ms_per_step.lm",
+                 "trainer.attn_gate_ms_per_step.lm"]
 SELECTED = 2048 * 2049 // 2 + (16384 - 2048) * 2048     # a head, a layer
 CAUSAL = 16384 * 16385 // 2
 
@@ -145,7 +149,7 @@ def _read(name, obs):
 
 WANT = {
     "trainer.attn_sparse_ms_per_step.lm": 1400.0 / STEPS,
-    "trainer.attn_sparse_roofline.lm":
+    "trainer.attn_roofline.lm":
         100 * STEPS * 5 * sparseshapes.attention_flops(SHAPES) / 197e12 / 1.2,
     "trainer.indexer_ms_per_step.lm": 740.0 / STEPS,
     "trainer.indexer_roofline.lm":
@@ -153,17 +157,17 @@ WANT = {
     "trainer.select_ms_per_step.lm": 210.0 / STEPS,
     "trainer.selected_share.lm": 100 * SELECTED / CAUSAL,
     "trainer.select_tiles_live_share.lm": 100 * 500 / 528,
-    "trainer.mfu_sparse.lm":
+    "trainer.mfu.lm":
         100 * sparseshapes.step_flops(12, 12 * 5 * 16384, SHAPES)
         / 197e12 / 20.0,
 }
 
 
 def test_the_wanted_values_are_all_the_new_metrics():
-    assert sorted(WANT) == sorted(NEW)
+    assert sorted(WANT) == sorted(NEW + MERGED)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_reader(name):
     value = _read(name, _obs())
     assert value == pytest.approx(WANT[name])
@@ -176,7 +180,7 @@ def test_the_selected_share_is_twenty_three_in_a_hundred():
         == pytest.approx(23.437, abs=1e-3)
 
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
     """A parent commit runs the readers too, and so do the other
     language-model cells: no scope, no counter, no shape of this
@@ -187,9 +191,11 @@ def test_a_reader_reads_nothing_from_a_program_without_its_spans(name):
                             shapes={})) is None
     assert _read(name, _obs(trace=None, traced={}, window={}, shapes={})) \
         is None
+    if name in MERGED:
+        return      # one reader for every cell: it reads sdar30b.ps-bd4k's too
     # sdar30b.ps-bd4k: the same block, its own scopes, counters and shapes
     other = {k: v for k, v in SHAPES.items() if not k.startswith("index_")}
-    other["block_length"] = 4
+    other.update(family="bd", block_length=4)
     blockdiff = {"window_s": 3.0, "programs": {}, "scopes": {"jit_forward": {
         "mv.lm.attn.blockdiff.kernel": 0.06, "mv.lm.experts": 0.2}}}
     counts = _count(LM_STEP=8, LM_HELD_ASSIGNMENTS=8 * 98304,
@@ -205,12 +211,13 @@ def test_the_other_families_readers_find_nothing_in_this_cell(name):
 
 # -- the entries, the configuration, the controls, the parent -----------------
 
-@pytest.mark.parametrize("name", NEW)
+@pytest.mark.parametrize("name", NEW + MERGED)
 def test_entry(name, root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", name)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == [CELL] and metric["moves"] == "words_per_s"
+    # by membership: later cells were appended to these readers' lists
+    assert CELL in metric["workloads"] and metric["moves"] == "words_per_s"
     assert metric["layer"] == "trainer"
     assert set(metric) == {"name", "unit", "better", "source", "layer",
                            "moves", "workloads"}

@@ -199,8 +199,13 @@ def test_a_pin_on_a_place_fails_on_the_appended_copy(pin, appended_root):
     assert not PINS[pin](entries.bench_of(appended_root), last)
 
 
-def _a_second_four_chip_cell(bench):
-    entries.named(bench, "workloads", "sgns8m.local")["chips"] = 4
+def _a_four_chip_cell_over_the_quarter(bench):
+    """One more than the quarter of the cells (rounded down, one always)
+    that may ask for four chips, whatever the count of cells has grown to."""
+    cells = bench["workloads"]
+    room = max(1, len(cells) // 4) - sum(c["chips"] == 4 for c in cells)
+    for cell in [c for c in cells if c["chips"] == 1][:room + 1]:
+        cell["chips"] = 4
 
 
 def _a_long_why(bench):
@@ -235,7 +240,7 @@ def _twenty_five_cells(bench):
 
 
 @pytest.mark.parametrize("breach", [
-    _a_second_four_chip_cell, _a_long_why, _a_name_with_a_space,
+    _a_four_chip_cell_over_the_quarter, _a_long_why, _a_name_with_a_space,
     _a_name_twice, _a_mix_that_is_no_file, _a_configuration_no_cell_uses,
     _twenty_five_cells], ids=lambda f: f.__name__.strip("_"))
 def test_the_rules_for_cells_refuse(breach, root):

@@ -64,7 +64,7 @@ def test_it_is_an_entry_found_by_name_with_its_cell(root):
     bench = entries.bench_of(root)
     metric = entries.named(bench, "per_layer", NAME)
     entries.check_entry(root, bench, "per_layer", metric)
-    assert metric["workloads"] == ["mperf16m.rows"]
+    assert "mperf16m.rows" in metric["workloads"]
     assert (metric["unit"], metric["better"]) == ("%", "higher")
     assert (metric["source"], metric["layer"], metric["moves"]) \
         == ("program_span", "worker actor and client", "rows_per_s")

@@ -161,6 +161,40 @@ def test_a_gap_is_cut_by_the_innermost_working_mv_span():
                                   ["all:mv:BLOB_D2H", pytest.approx(0.048)]]
 
 
+def test_the_wait_for_a_copy_loses_to_a_working_span_on_another_thread():
+    """`mv:BLOB_D2H_READY` only waits (for the program that fills the
+    buffer, and for the copy): an idle piece under it goes to a working
+    `mv:` span open on another thread at that instant, however much longer
+    that span is, and stays the wait's only where nothing else is open."""
+    ms = 1_000_000
+    device = {"modules": [("jit_rows_padded(123456)", 0, 10 * ms),
+                          ("jit_rows_padded(123456)", 90 * ms, 100 * ms)],
+              "ops": [("%fusion.1", 0, 10 * ms, ""),
+                      ("%fusion.2", 90 * ms, 100 * ms, "")]}
+    spans = [
+        ("bench:window", 0, 100 * ms, 1),
+        ("bench:get_rows", 5 * ms, 95 * ms, 1),
+        ("mv:BLOB_D2H", 10 * ms, 90 * ms, 2),
+        ("mv:BLOB_D2H_READY", 12 * ms, 80 * ms, 2),       # inside it, shorter
+        ("mv:UPDATE_PAD_ROWS", 20 * ms, 50 * ms, 3),      # working, elsewhere
+        ("mv:CLIENT_ISSUE_ADD", 45 * ms, 60 * ms, 4)]     # working, shorter
+    assert "mv:BLOB_D2H_READY" in xplane.WAITS
+    got = xplane.reduce(_trace({"/device:TPU:0": device}, spans))
+    assert got["idle_by_span"] == pytest.approx({
+        # 10-12 and 80-90 alone; 12-20 and 60-80 over the wait inside it,
+        # which is shorter: the innermost WORKING span wins
+        "mv:BLOB_D2H": 0.002 + 0.010 + 0.008 + 0.020,
+        "mv:UPDATE_PAD_ROWS": 0.025,          # 20-45
+        "mv:CLIENT_ISSUE_ADD": 0.015})        # 45-60: the shorter working one
+    assert "mv:BLOB_D2H_READY" not in got["idle_by_span"]
+    # alone, the wait keeps its piece: a wait is better than no name
+    alone = [s for s in spans if s[0] in ("bench:window", "bench:get_rows",
+                                          "mv:BLOB_D2H_READY")]
+    got = xplane.reduce(_trace({"/device:TPU:0": device}, alone))
+    assert got["idle_by_span"] == pytest.approx({
+        "get_rows": 0.002 + 0.010, "mv:BLOB_D2H_READY": 0.068})
+
+
 def test_scopes_are_the_busiest_chips_inside_the_window():
     """A table over two chips: the scope's seconds and the collectives'
     part of them on the busiest chip, clipped to the window; a program
