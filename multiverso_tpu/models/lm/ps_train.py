@@ -475,6 +475,12 @@ class PSLMTrainer:
                  beta2: float = 0.95, eps: float = 1e-8,
                  init_std: float = 0.02, embedding_std: float = 1.0,
                  warmup_steps: int = 0):
+        # TRAINER_BUILD, entered and left by hand: a decorator's frame
+        # between the caller and this constructor made the tables' init
+        # programs lower a third slower on the chip machine's host
+        # (PERF.md section 6, PR 68), and a ``with`` would indent it all.
+        building = monitor("TRAINER_BUILD")
+        building.__enter__()
         zoo = current_zoo()
         CHECK(zoo.servers_in_process,
               "PSLMTrainer needs in-process servers (device keys and "
@@ -645,6 +651,7 @@ class PSLMTrainer:
         self.last_inner_loss = None     # the layers' inner losses' sum, with
         #                                 a ``cfg.selection``: device scalar
         self._last_dx = None    # the last program's result of the last step
+        building.__exit__(None, None, None)
 
     # -- the tables, by name (the checks read them) ---------------------------
     def tables(self) -> Dict[str, object]:

@@ -571,6 +571,10 @@ class DeviceCorpusTrainer:
     def __init__(self, model, tokenized: TokenizedCorpus,
                  centers_per_step: int = 32768,
                  steps_per_dispatch: int = 8):
+        # TRAINER_BUILD, entered and left by hand as PSLMTrainer's is
+        # (models/lm/ps_train.py says why no decorator and no ``with``)
+        building = monitor("TRAINER_BUILD")
+        building.__enter__()
         config = model.config
         self.model = model
         self.config = config
@@ -638,6 +642,7 @@ class DeviceCorpusTrainer:
         # Post-subsampling tokens actually trained (centers), across
         # epochs — the exact basis for utilization accounting.
         self.kept_words_trained = 0
+        building.__exit__(None, None, None)
 
     def train_epoch(self, seed: int, group_hook=None,
                     max_steps: int = 0) -> Tuple[float, float]:
@@ -963,6 +968,8 @@ class PSDeviceCorpusTrainer:
         (ref: distributed_wordembedding.cpp:203-224,
         LogisticRegression configure.h sync_frequency). G=1 keeps exact
         per-block semantics."""
+        building = monitor("TRAINER_BUILD")     # as DeviceCorpusTrainer's
+        building.__enter__()
         config = model.config
         if not getattr(model, "_device_path", False):
             raise ValueError("PS device pipeline needs in-process "
@@ -1040,6 +1047,7 @@ class PSDeviceCorpusTrainer:
             self._inv_workers = device_lock.settle(jax.device_put(
                 np.float32(1.0 / model._num_workers), self._on_rows))
         self.kept_words_trained = 0
+        building.__exit__(None, None, None)
 
     def train_epoch(self, seed: int, block_hook=None,
                     max_steps: int = 0) -> Tuple[float, float]:

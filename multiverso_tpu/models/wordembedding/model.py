@@ -231,8 +231,9 @@ class Word2Vec:
         # vectorized. The inverse-CDF searchsorted it replaces costs
         # ~26 ms per 160K draws inside the jitted step on TPU (binary
         # search lowers badly); alias sampling is ~0.1 ms.
-        self._neg_prob_host, self._neg_alias_host = build_alias(
-            dictionary.negative_table())
+        with monitor("DICT_ALIAS_BUILD"):
+            self._neg_prob_host, self._neg_alias_host = build_alias(
+                dictionary.negative_table())
         return dictionary.size
 
     def _init_embeddings(self) -> None:

@@ -332,6 +332,7 @@ class Stalls:
         item = (name, ident, began, end, ms)
         opened = None
         if not dashboard.only_waits(name) \
+                and name not in dashboard.BUILDS \
                 and count > BLOCKED_MIN_ENTRIES:
             own = ms - 1e3 * sum(
                 _overlap(began, end, b, e) for b, e in self._late_spans())

@@ -9,7 +9,11 @@ directory.
 
 Call ``enable()`` from a process entry point before the first jit
 compiles: JAX opens the cache at the first compilation and keeps that
-directory for the life of the process.
+directory for the life of the process. The same call starts the
+Dashboard's listeners to every program's trace, lowering, cache read and
+compile (``util/dashboard.py`` ``listen_to_program_builds``: the
+``PROGRAM_*`` monitors, ``mv:PROGRAM_*`` spans and ``program_builds()``),
+once a process.
 """
 
 from __future__ import annotations
@@ -17,6 +21,8 @@ from __future__ import annotations
 import os
 
 import jax
+
+from . import dashboard
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -34,6 +40,7 @@ MIN_COMPILE_SECS = 0.0
 
 def enable() -> str:
     """Turn the persistent cache on and return the directory in effect."""
+    dashboard.listen_to_program_builds()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",

@@ -15,7 +15,9 @@ is built.
 from __future__ import annotations
 
 import collections
+import functools
 import math
+import re
 import sys
 import threading
 import time
@@ -185,6 +187,30 @@ METRIC_NAMES: Dict[str, str] = {
     "TABLE_INIT": "MatrixServer random_init: the uniform draw on the "
                   "devices, one program a table, each shard its own "
                   "rows (sharding/mesh.py uniform_sharded), to ready",
+    # -- set-up seen from inside: jax.monitoring's stage events of every
+    # program a thread makes, heard by the listeners below (registered by
+    # util/compile_cache.py enable()). Exclusive: their sum is the
+    # THREAD-seconds spent making programs (two threads that build at
+    # once both count, so a process that compiles can read more than its
+    # wall clock) --
+    "PROGRAM_TRACE": "a program traced to a jaxpr: the OUTERMOST trace of "
+                     "a thread alone, the jitted functions traced inside "
+                     "it included",
+    "PROGRAM_LOWER": "a module lowered to MLIR, its Pallas kernels' "
+                     "lowering to Mosaic and the tracing of their bodies "
+                     "inside it",
+    "PROGRAM_CACHE_READ": "a program's whole backend stage where the "
+                          "persistent cache supplied it: key, retrieval, "
+                          "load",
+    "PROGRAM_COMPILE": "a program's whole backend stage where it did not: "
+                       "key and XLA's compile",
+    # -- set-up that is not a program's build --
+    "TRAINER_BUILD": "a trainer's constructor (PSLMTrainer, "
+                     "DeviceCorpusTrainer, PSDeviceCorpusTrainer), the "
+                     "program builds inside it included",
+    "DICT_ALIAS_BUILD": "models/wordembedding/model.py build_alias at its "
+                        "call in Word2Vec: the negative sampler's alias "
+                        "tables on the host",
     # -- row scatter-adds by the path their shapes chose: a table's Add
     # (updater/engine.py), a block of the local word2vec trainer's
     # group (models/wordembedding/device_train.py) --
@@ -521,9 +547,11 @@ class Dashboard:
         """Full registry report: monitors AND sample reservoirs, each
         section sorted by name so successive dumps diff cleanly (dict
         insertion order made the report depend on which code path ran
-        first)."""
+        first); after the monitors the ten programs whose making took
+        longest (``program_builds``), then the stall records."""
         with cls._lock:
             lines = [str(m) for _, m in sorted(cls._monitors.items())]
+        lines += program_lines(DISPLAYED_PROGRAMS)
         with _samples_lock:
             reservoirs = sorted(_samples.items())
         for name, s in reservoirs:
@@ -752,6 +780,8 @@ def metrics_snapshot(max_samples: int = 256) -> dict:
         "stalls": {"count": _stalls_closed,
                    "last_began_wall_ns": _stalls[-1]["began_wall_ns"]
                    if _stalls else None},
+        # the rows stay in their process too: ``program_builds()``
+        "program_builds": {"programs": len(_programs)},
         "monitors": {name: {"count": m.count,
                             "elapsed_ms": round(m.elapse, 3)}
                      for name, m in monitors},
@@ -770,6 +800,213 @@ def count(name: str, n: int = 1) -> None:
     per-row Python loop."""
     if n > 0:
         Dashboard.get(name).add_count(n)
+
+
+# -- program builds: set-up seen from inside ------------------------------
+#
+# JAX times each stage of making a program where the work happens and
+# hands the time to any listener with the program's name
+# (``jax.monitoring``; jax 0.9.0 ``_src/dispatch.py`` ``log_elapsed_time``):
+# a SCALAR event when a stage begins and a DURATION event when it ends,
+# for tracing to a jaxpr, lowering to MLIR and the backend stage (cache
+# key, then the persistent cache's read or XLA's compile), and a plain
+# event ``cache_hits`` inside a backend stage that the cache supplied.
+# The listeners below turn them into four Dashboard monitors, ``mv:``
+# spans under a profiler session, and one row a program. Nothing is built
+# inside a steady loop, so they run in set-up alone.
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: stage event -> (monitor, span). The backend stage's monitor is chosen
+#: when it ends: PROGRAM_CACHE_READ where a cache hit fired inside it.
+_STAGES = {_TRACE_EVENT: ("PROGRAM_TRACE", "PROGRAM_TRACE"),
+           _LOWER_EVENT: ("PROGRAM_LOWER", "PROGRAM_LOWER"),
+           _BACKEND_EVENT: ("PROGRAM_COMPILE", "PROGRAM_BACKEND")}
+
+#: monitor -> a row's (count, milliseconds) fields
+_ROW_FIELDS = {"PROGRAM_TRACE": ("traces", "trace_ms"),
+               "PROGRAM_LOWER": ("lowerings", "lower_ms"),
+               "PROGRAM_CACHE_READ": ("cache_reads", "cache_read_ms"),
+               "PROGRAM_COMPILE": ("compiles", "compile_ms")}
+
+#: The four monitors. An entry is 1 ms or many seconds by the program it
+#: makes, so "8 times the monitor's mean" says nothing and none opens a
+#: stall record (runtime/thread_roles.py); a long one is context to a
+#: record, by name, as a wait's is.
+BUILDS = tuple(_ROW_FIELDS)
+
+#: The table holds this many programs by name (the cell with the most
+#: makes 209); what comes after goes to one row, OTHER_PROGRAMS.
+MAX_PROGRAMS = 512
+OTHER_PROGRAMS = "(other programs)"
+DISPLAYED_PROGRAMS = 10
+
+_programs: Dict[str, Dict[str, float]] = {}
+_programs_lock = named_lock("dashboard.program_builds")
+_NOT_OF_A_MODULE_NAME = re.compile(r"[^\w.-]")
+
+
+class _Building(threading.local):
+    """The stages open on one thread: the server actor's thread builds
+    the update programs while the trainer's builds the layers'."""
+
+    def __init__(self):
+        self.depth = 0          # stages open, of any kind
+        self.backend_at = 0     # the depth a backend stage opened at
+        self.hit = False        # the cache supplied that one
+        self.inside_s = 0.0     # backend stages closed inside the outermost
+        self.spans = []         # of the stages that will be counted
+
+
+_building = _Building()
+_listening = False
+
+
+@functools.lru_cache(maxsize=4096)
+def program_key(fun_name: str) -> str:
+    """``backward`` and ``jit(backward)`` -> ``jit_backward``: the name
+    the device trace prints the program under, less its hash (what
+    ``benchmark/lib/xplane.py`` ``stem`` leaves)."""
+    if "(" not in fun_name:     # a trace event names the function alone
+        fun_name = f"jit({fun_name})"
+    return _NOT_OF_A_MODULE_NAME.sub("_", fun_name).rstrip("_")
+
+
+def _stage_begins(event: str, started: float, fun_name: str = "",
+                  **_) -> None:
+    """Scalar listener. Tracing a program emits one event for every
+    jitted function traced inside it, ``jax.numpy``'s included, tens of
+    thousands a process: a nested one is a depth bump and a return."""
+    if event not in _STAGES:
+        return
+    state = _building
+    depth = state.depth = state.depth + 1
+    if depth > 1 and (event != _BACKEND_EVENT or state.backend_at):
+        return
+    if event == _BACKEND_EVENT:
+        state.backend_at = depth
+        state.hit = False
+    annotation = _trace_annotation or _bind_annotation()
+    if annotation.is_enabled():
+        span = annotation(SPAN_PREFIX + _STAGES[event][1],
+                          program=program_key(fun_name))
+        span.__enter__()
+    else:
+        span = None
+    state.spans.append(span)
+
+
+def _stage_ends(event: str, seconds: float, fun_name: str = "",
+                **_) -> None:
+    """Duration listener. One stack for the three stages: a trace or a
+    lowering entered inside another stage adds nothing of its own (the
+    outermost holds it); a backend stage inside another (an eager
+    operation while a program is traced) is a program made, so it is an
+    entry of its own and the outermost stage's entry is shorter by it.
+    The four monitors so stay exclusive (of a thread's time: two threads
+    that build at once both count) and the two backend monitors count
+    every program."""
+    stage = _STAGES.get(event)
+    state = _building
+    depth = state.depth
+    if stage is None or not depth:  # another event, or a stage that began
+        return                      # before the listeners were there
+    state.depth = depth - 1
+    name = stage[0]
+    if state.backend_at == depth:
+        state.backend_at = 0
+        if state.hit:
+            name = "PROGRAM_CACHE_READ"
+        if depth > 1:
+            state.inside_s += seconds
+    elif depth > 1:
+        return
+    else:
+        seconds = max(seconds - state.inside_s, 0.0)
+    if depth == 1:
+        state.inside_s = 0.0
+    span = state.spans.pop()
+    if span is not None:
+        span.__exit__(None, None, None)
+    ms = seconds * 1e3
+    Dashboard.get(name).add(ms)
+    counted, timed = _ROW_FIELDS[name]
+    key = program_key(fun_name)
+    with _programs_lock:
+        row = _programs.get(key)
+        if row is None:
+            if len(_programs) >= MAX_PROGRAMS:
+                key = OTHER_PROGRAMS
+            row = _programs.setdefault(
+                key, {field: 0 for pair in _ROW_FIELDS.values()
+                      for field in pair})
+        row[counted] += 1
+        row[timed] += ms
+
+
+def _cache_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _building.hit = True
+
+
+def listen_to_program_builds() -> bool:
+    """Register the three listeners, once a process (``compile_cache.
+    enable()`` calls this; a second call registers nothing). True where
+    this call registered them."""
+    global _listening
+    if _listening:
+        return False
+    import jax.monitoring
+    jax.monitoring.register_scalar_listener(_stage_begins)
+    jax.monitoring.register_event_duration_secs_listener(_stage_ends)
+    jax.monitoring.register_event_listener(_cache_event)
+    for name in BUILDS:     # a process that compiled nothing reads 0, and
+        Dashboard.get(name)  # one that does not listen reads nothing
+    _listening = True
+    return True
+
+
+def stop_listening_to_program_builds() -> None:
+    """Take the listeners off again (the tests')."""
+    global _listening
+    if not _listening:
+        return
+    import jax.monitoring
+    jax.monitoring.unregister_scalar_listener(_stage_begins)
+    jax.monitoring.unregister_event_duration_listener(_stage_ends)
+    jax.monitoring.unregister_event_listener(_cache_event)
+    _listening = False
+
+
+def program_builds() -> Dict[str, Dict[str, float]]:
+    """``{program: {traces, trace_ms, lowerings, lower_ms, cache_reads,
+    cache_read_ms, compiles, compile_ms}}`` of every program this process
+    made, by the name the device trace prints it under (``jit_backward``).
+    The rows stay in their process, as the stall records do."""
+    with _programs_lock:
+        return {key: dict(row) for key, row in _programs.items()}
+
+
+def program_ms(row: Dict[str, float]) -> float:
+    return sum(row[timed] for _, timed in _ROW_FIELDS.values())
+
+
+def program_lines(limit: int = 0) -> List[str]:
+    """The table's rows, largest total first (``limit`` 0: all)."""
+    rows = sorted(program_builds().items(),
+                  key=lambda item: -program_ms(item[1]))
+    return [f"[program {key}] total = {program_ms(row):.1f}ms " + " ".join(
+                f"{counted} = {row[counted]} {timed} = {row[timed]:.1f}"
+                for counted, timed in _ROW_FIELDS.values())
+            for key, row in rows[:limit or None]]
+
+
+def reset_program_builds() -> None:
+    with _programs_lock:
+        _programs.clear()
 
 
 def trace_to(log_dir: str):

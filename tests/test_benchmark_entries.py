@@ -5,16 +5,21 @@ moves, a reader file for every entry, each cell's configuration, traffic
 mix and driver, the share of four-chip cells) on the repo's
 `BENCHMARK.json` and on the copy a later PR appended a configuration, a
 cell, a mix and two metrics to (`benchmark/tests/later_pr.py`). The
-driver's own test run never enters `benchmark/tests`."""
+driver's own test run never enters `benchmark/tests`, so the cases of
+`benchmark/tests/test_families.py` (the two readers that are one metric
+for every family of language model, the retired names, the list's room)
+are taken in here by name and run on this file's `root`."""
 
 import json
 import os
 
 import pytest
 
-from benchmark.tests import entries, later_pr
+from benchmark.tests import entries, later_pr, test_families
 
 ROOT = later_pr.ROOT
+globals().update({name: case for name, case in vars(test_families).items()
+                  if name.startswith("test_")})
 
 
 @pytest.fixture(scope="module")

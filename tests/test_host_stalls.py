@@ -235,6 +235,29 @@ def an_entry_a_stall_lengthened_is_context():
             ("TABLE_WAIT", "mv-caller", True)]
 
 
+def a_long_build_opens_nothing():
+    """A program whose lowering takes 900 ms where the mean is 5: the
+    build monitors' entries are 1 ms or seconds by the program they make,
+    and none opens a record."""
+    machine = Machine()
+    machine.run(60)
+    for name in dashboard.BUILDS:
+        machine.entry(name, MAIN, 900.0, 201, 1900.0)
+    assert machine.run(60) == []
+
+
+def a_build_inside_a_stall_is_context_by_name():
+    """A program built in a measured window is on the record of the
+    stall it lies under, by its monitor's name, as working time."""
+    machine = Machine(late={50: 0.112})
+    machine.run(51)
+    machine.entry("PROGRAM_LOWER", MAIN, 118.0, 201, 1118.0)
+    (record,) = machine.run(80)
+    assert record["class"] == "frozen"
+    assert [(e["name"], e["thread"], e["waits"]) for e in record["entries"]] \
+        == [("PROGRAM_LOWER", "MainThread", False)]
+
+
 def two_stalls_apart_are_two_records():
     first, second = Machine(late={50: 0.112, 150: 0.2}).run(260)
     assert (first["class"], second["class"]) == ("frozen", "frozen")
@@ -420,6 +443,7 @@ CASES = [frozen, held_names_the_thread, held_by_no_registered_thread,
          a_monitor_of_two_kinds_of_entry_opens_once,
          no_mean_yet_opens_nothing, overlapping_openings_are_one_record,
          an_entry_a_stall_lengthened_is_context,
+         a_long_build_opens_nothing, a_build_inside_a_stall_is_context_by_name,
          two_stalls_apart_are_two_records, the_ring_keeps_the_last_64,
          the_slow_readings_bracket_a_late_beat,
          monitor_add_appends_nothing_under_the_floor,

@@ -20,6 +20,15 @@ floor, `monitor_add_long` one over it (a tuple appended to a deque, which
 only an entry of 40 ms pays). Run from a checkout of an older commit it
 times the sites that tree has: before PR 37 10 with an id, 2 without, 12
 MAILBOX_WAIT; before PR 24 the 8 handler monitors, without arguments.
+
+Since PR 68 it also times what the listeners to program builds
+(`dashboard.listen_to_program_builds`) cost an EVENT of `jax.monitoring`,
+a stage's begin and end together, less what JAX's own empty call costs:
+`stage_nested` is a trace event inside an open trace (a depth bump and a
+return, twice: tracing a program emits one for every jitted function
+traced inside it, tens of thousands a process), `stage_outermost` one
+that is counted (a `Monitor.add` and the program's row). Both run in
+set-up alone: nothing is built inside a steady loop.
 """
 
 import os
@@ -65,6 +74,36 @@ def plain():
         pass
 
 
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+
+
+def _stage(name: str):
+    import jax.monitoring
+
+    def event():
+        jax.monitoring.record_scalar(_TRACE, 0.0, fun_name=name)
+        jax.monitoring.record_event_duration_secs(_TRACE, 1e-4,
+                                                  fun_name=name)
+    return event
+
+
+def stage_costs() -> dict:
+    """Microseconds a stage event (begin and end) with the listeners on,
+    less the same calls with nobody listening; {} in a tree without
+    them."""
+    if not hasattr(dashboard, "listen_to_program_builds"):
+        return {}
+    nested, outermost = _stage("sin"), _stage("backward")
+    bare = us(nested)
+    dashboard.listen_to_program_builds()
+    costs = {"stage_outermost": us(outermost) - bare}
+    dashboard._stage_begins(_TRACE, 0.0, fun_name="outer")   # held open
+    costs["stage_nested"] = us(nested) - bare
+    dashboard._stage_ends(_TRACE, 0.0, fun_name="outer")
+    dashboard.stop_listening_to_program_builds()
+    return costs
+
+
 def main() -> None:
     costs = {"monitor": us(plain)}
     counted = dashboard.Dashboard.get("TABLE_WAKE")
@@ -98,6 +137,7 @@ def main() -> None:
         block = (_plain_monitors() * costs["monitor"]
                  + (18 if issued else 10) * costs["monitor_with_request_id"]
                  + (14 if issued else 12) * costs["mailbox_wait"])
+    costs.update(stage_costs())
     for name, cost in costs.items():
         print(f"{name}: {cost:.3f} us")
     print(f"sites of one block: {block:.2f} us")
