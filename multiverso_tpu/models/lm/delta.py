@@ -70,10 +70,13 @@ view, and ``attention_vjp`` hands [T, H K] from part to part, so that no
 copy turns 8 positions by 128 lanes into 8 heads by 128 lanes and back.
 There too, at whole blocks of 512 tokens (``passes_fused``), ``gates`` and
 ``output``'s gated norm are delta_passes.py's Pallas passes, one over memory
-each way with hand-written pulls, and where nothing is pulled through them
-q's and k's convolutions go inside the gates' pass (``conv_gates_flat``);
-``short_conv``, ``gates`` and ``output`` below stay their definition and
-every other shape's path.
+each way with hand-written pulls, and so is every short convolution
+(``short_conv_flat``: one read and one write, and a pull that keeps the
+convolution's INPUT alone, makes the sum and the silu's slope again and hands
+the weights' gradient back as a partial sum a block of tokens); where nothing
+is pulled through them q's and k's convolutions go inside the gates' pass
+(``conv_gates_flat``); ``short_conv``, ``gates`` and ``output`` below stay
+their definition and every other shape's path.
 
 **No division by a decay.** Every exponent is a difference of summed log
 decays that is <= 0: ``G_t``, ``G_last - G_s``, and ``G_t - G_s`` for ``s
@@ -448,12 +451,12 @@ _DEFINED = short_conv, gates, output
 
 def passes_fused(cfg: LMConfig, t: int) -> bool:
     """Whether ``gates`` (with q's and k's convolutions where nothing is
-    pulled through them) and ``output``'s gated norm run as
-    delta_passes.py's Pallas passes, one over memory each way: on a TPU, at
-    whole blocks of tokens and heads of one 128-lane tile, with the three
-    functions the ones above. By what the code can see: no flag chooses.
-    Everywhere else the ``jax.numpy`` lines above, which are the
-    definition."""
+    pulled through them), every other ``short_conv`` and its pull, and
+    ``output``'s gated norm run as delta_passes.py's Pallas passes, one over
+    memory each way: on a TPU, at whole blocks of tokens and heads of one
+    128-lane tile, with the three functions the ones above. By what the code
+    can see: no flag chooses. Everywhere else the ``jax.numpy`` lines above,
+    which are the definition."""
     if (jax.default_backend() != "tpu"
             or (short_conv, gates, output) != _DEFINED):
         return False
@@ -463,7 +466,8 @@ def passes_fused(cfg: LMConfig, t: int) -> bool:
 
 def pass_counter(cfg: LMConfig, t: int) -> str:
     """The counter a delta layer's sequence of ``t`` tokens counts: which
-    form its gates and gated norm took (``PSLMTrainer._count_stats``)."""
+    form its short convolutions, gates and gated norm took
+    (``PSLMTrainer._count_stats``)."""
     return "LM_KDA_PASS_FUSED" if passes_fused(cfg, t) \
         else "LM_KDA_PASS_PLAIN"
 
@@ -472,6 +476,16 @@ def _passes(cfg: LMConfig):
     from . import delta_passes
     return delta_passes, delta_passes.Pass(
         cfg.kda_heads_held, float(cfg.kda_beta_scale), cfg.eps)
+
+
+def short_conv_flat(cfg: LMConfig, x, w):
+    """``short_conv`` of a product's result [T, H K]: one pass over memory
+    each way where ``passes_fused`` (its pull reads the cotangent and ``x``
+    again and keeps nothing else)."""
+    if passes_fused(cfg, x.shape[0]):
+        passes, how = _passes(cfg)
+        return passes.conv(how, x, w)
+    return short_conv(x, w)
 
 
 def gates_flat(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
@@ -490,16 +504,17 @@ def gates_flat(cfg: LMConfig, a_log, dt_bias, q, k, v, f, b):
 def conv_gates_flat(cfg: LMConfig, convs, a_log, dt_bias, q, k, v, f, b):
     """``gates_flat`` of the three products' results through ``short_conv``
     (``convs``: the three convolutions' weights), where nothing is pulled
-    through either: v's convolution under its scope, and where
-    ``passes_fused`` q's and k's INSIDE the gates' pass, which reads the
-    products' results and writes no convolved copy of them."""
+    through either: v's convolution under its scope (a pass of its own
+    where ``passes_fused``), and there q's and k's INSIDE the gates' pass,
+    which reads the products' results and writes no convolved copy of
+    them."""
     if not passes_fused(cfg, q.shape[0]):
         with jax.named_scope(SCOPE + ".conv"):
             q, k, v = (short_conv(x, w) for x, w in zip((q, k, v), convs))
         with jax.named_scope(SCOPE):
             return gates_flat(cfg, a_log, dt_bias, q, k, v, f, b)
     with jax.named_scope(SCOPE + ".conv"):
-        v = short_conv(v, convs[2])
+        v = short_conv_flat(cfg, v, convs[2])
     with jax.named_scope(SCOPE):
         passes, how = _passes(cfg)
         q, k, g, beta = passes.conv_gates(how, *convs[:2], a_log, dt_bias,
@@ -550,7 +565,8 @@ def attention_vjp(cfg: LMConfig, mats, sinks, small, x):
         return a.reshape(t, -1)
 
     def convolved(convs, q, k, v):
-        return tuple(short_conv(x, w) for x, w in zip((q, k, v), convs))
+        return tuple(short_conv_flat(cfg, x, w)
+                     for x, w in zip((q, k, v), convs))
 
     def gated(convs, a_log, dt_bias, q, k, v, f, b, vjp=None):
         """``scan``'s five arguments from the projections (and with

@@ -22,26 +22,26 @@ head's inverse norm and the softplus's slope again and writes dq, dk
 (float32, the convolutions') and df, db in bfloat16, which is what
 ``model.mm``'s backward rule rounds them to first thing; ``d a_log`` and ``d
 dt_bias`` leave as one [8, lanes] partial sum a block of tokens, summed
-outside.
-
+outside. ``conv`` (``mv_kda_conv``, at the file's end) is every other short
+convolution: one read, one write; its pull (``mv_kda_conv_pull``) reads the
+cotangent and the INPUT again (the tile AFTER a block too), writes dx in
+bfloat16 and the weights' gradient as an [8, lanes] partial sum a weight.
 ``gated_norm`` (``mv_kda_out``): reads the scan's o and the gate's logits
 once and writes ``rmsnorm_head(o) norm_o sigmoid(gate)`` in bfloat16, which
 is what ``mm`` rounds ``W_o``'s input to. Its pull (``mv_kda_out_pull``)
 reads ``W_o``'s cotangent, o and the gate again and writes do (float32, the
 scan's), d gate (bfloat16, the projections') and ``d norm_o`` as partial
-sums.
+sums. A head's sum over its 128 lanes is a lane reduction a row
+(``_head_sum``). As a product with a [128, 128] matrix of ones at the highest
+precision on the otherwise idle matrix unit it was SLOWER on the chip in
+three of the four kernels (PERF.md section 6, PR 64: 1.77 against 1.66 ms
+for the gates' pull at 32 heads) and is not kept. No float32 intermediate of
+either chain is written in either direction; the results differ from the
+chain's by the order of a sum alone. The transposes are written out by hand.
 
-A head's sum over its 128 lanes is a lane reduction a row (``_head_sum``).
-As a product with a [128, 128] matrix of ones at the highest precision on
-the otherwise idle matrix unit it was SLOWER on the chip in three of the
-four kernels (PERF.md section 6, PR 64: 1.77 against 1.66 ms for the
-gates' pull at 32 heads) and is not kept. No float32 intermediate of either
-chain is written in either direction; the results differ from the chain's
-by the order of a head's sum alone. The transposes are written out by hand.
-
-The ``pallas_call``s run under the caller's scope ``mv.lm.attn.kda`` and
-add none of their own; their callers are jitted for their TRACE, as
-delta_kernels' are.
+The ``pallas_call``s run under the caller's scope (``mv.lm.attn.kda``, the
+convolutions' ``.conv``) and add none of their own; their callers are jitted
+for their TRACE, as delta_kernels' are.
 """
 
 from __future__ import annotations
@@ -399,3 +399,130 @@ def _gated_norm_bwd(how, inputs, dy):
 
 
 gated_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+# -- the short convolutions ------------------------------------------------------------
+
+def _conv_kernel(x_ref, before_ref, w_ref, y_ref, *, how: Pass):
+    first = pl.program_id(0) == 0
+
+    def chunk(rows, c):
+        for lanes in _heads(how):
+            y_ref[rows, lanes] = _convolved(how, x_ref, before_ref, w_ref,
+                                            rows, c, lanes, first)
+
+    _chunks(chunk)
+
+
+def _tile(start):
+    return pl.ds(pl.multiple_of(start, SUB), SUB)
+
+
+def _tile_before(ref, before_ref, c, lanes, first):
+    """The 8 rows before chunk ``c`` of the block in ``ref``: the block's
+    own, or before its first chunk ``before_ref``'s (zeros before the
+    sequence's first position), as ``_convolved`` reads them."""
+    return jnp.where(c > 0, ref[_tile(jnp.maximum(c * ROWS - SUB, 0)), lanes],
+                     jnp.where(first, 0.0, before_ref[:, lanes]))
+
+
+def _tile_after(ref, after_ref, c, lanes, last):
+    """The 8 rows after chunk ``c``: the block's own, or after its last
+    chunk ``after_ref``'s (zeros past the sequence's last position)."""
+    return jnp.where(
+        c < TOKENS // ROWS - 1,
+        ref[_tile(jnp.minimum((c + 1) * ROWS, TOKENS - SUB)), lanes],
+        jnp.where(last, 0.0, after_ref[:, lanes]))
+
+
+def _conv_pull_kernel(g_ref, x_ref, g_after, x_before, x_after, w_ref, dx_ref,
+                      dw_ref, *, how: Pass):
+    first = pl.program_id(0) == 0
+    last = pl.program_id(0) == pl.num_programs(0) - 1
+    dw_ref[...] = jnp.zeros_like(dw_ref)
+    leads = [how.taps - 1 - j for j in range(how.taps)]
+
+    def chunk(rows, c):
+        for lanes in _heads(how):
+            # y = silu(u), u[t] = sum_j w[j] x[t - lead_j]: with gu = g
+            # silu'(u), dx[t] = sum_j w[j] gu[t + lead_j] and dw[j] = sum_t
+            # gu[t] x[t - lead_j]. u and gu are made for the chunk's rows
+            # and the tile after them, which the chunk's last rows' dx reads
+            lagged = jnp.concatenate([
+                _tile_before(x_ref, x_before, c, lanes, first),
+                x_ref[rows, lanes],
+                _tile_after(x_ref, x_after, c, lanes, last)], 0)
+            lags = [(pltpu.roll(lagged, lead, 0) if lead else lagged)[SUB:]
+                    for lead in leads]
+            u = 0.0
+            for j, x in enumerate(lags):    # short_conv's order of summation
+                u = u + x * w_ref[j:j + 1, lanes]
+            s = jax.nn.sigmoid(u)
+            g = jnp.concatenate([g_ref[rows, lanes], _tile_after(
+                g_ref, g_after, c, lanes, last)], 0)
+            gu = g * (s * (1.0 + u * (1.0 - s)))
+            dx = 0.0
+            for j, lead in enumerate(leads):
+                ahead = pltpu.roll(gu, ROWS + SUB - lead, 0) if lead else gu
+                dx = dx + ahead[:ROWS] * w_ref[j:j + 1, lanes]
+                dw_ref[j, :, lanes] += _folded(gu[:ROWS] * lags[j][:ROWS])
+            dx_ref[rows, lanes] = dx.astype(dx_ref.dtype)
+
+    _chunks(chunk)
+
+
+def _after_spec(how: Pass, t: int):
+    """The 8-row tile after a block of tokens (the last block's is its own
+    last tile, which the kernel does not read)."""
+    tiles, end = TOKENS // SUB, t // SUB - 1
+    return pl.BlockSpec(
+        (SUB, how.per * LANES),
+        lambda ti, h: (jnp.minimum((ti + 1) * tiles, end), h))
+
+
+@functools.partial(jax.jit, static_argnames=("how", "interpret"))
+def _conv(how: Pass, x, w, interpret):
+    del interpret
+    wide = _gates_specs(how)[0]
+    return _call(_conv_kernel, how, "mv_kda_conv", x.shape[0],
+                 [wide, *_conv_specs(how)], wide,
+                 jax.ShapeDtypeStruct(x.shape, F32))(x, x, w.T)
+
+
+@functools.partial(jax.jit, static_argnames=("how", "interpret"))
+def _conv_pull(how: Pass, x, w, g, interpret):
+    """``(dx, dw, the taps' partial sums [blocks, taps, 8, H 128])``."""
+    del interpret
+    t = x.shape[0]
+    wide = _gates_specs(how)[0]
+    before, weights = _conv_specs(how)
+    after = _after_spec(how, t)
+    dx, sums = _call(
+        _conv_pull_kernel, how, "mv_kda_conv_pull", t,
+        [wide, wide, after, before, after, weights],
+        [wide, pl.BlockSpec((None, how.taps, SUB, how.per * LANES),
+                            lambda ti, h: (ti, 0, 0, h))],
+        [jax.ShapeDtypeStruct(x.shape, BF16), jax.ShapeDtypeStruct(
+            (t // TOKENS, how.taps, SUB, x.shape[1]), F32)])(
+        g, x, g, x, x, w.T)
+    return dx.astype(F32), sums.sum((0, 2)).T, sums
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def conv(how: Pass, x, w):
+    """delta.short_conv of x [T, H 128] float32 as a product leaves it
+    under the weights w [H 128, taps]: one read and one write, and pulled
+    (``mv_kda_conv_pull``) two reads and one."""
+    return _conv(how._replace(taps=w.shape[1]), x, w, INTERPRET)
+
+
+def _conv_fwd(how, *inputs):
+    return conv(how, *inputs), inputs
+
+
+def _conv_bwd(how, inputs, g):
+    x, w = inputs
+    return _conv_pull(how._replace(taps=w.shape[1]), x, w, g, INTERPRET)[:2]
+
+
+conv.defvjp(_conv_fwd, _conv_bwd)

@@ -309,11 +309,13 @@ METRIC_NAMES: Dict[str, str] = {
     "LM_KDA_SCAN_PLAIN": "delta layers' sequences whose scan took the "
                          "jax.numpy runs of chunks (no TPU, a chunk that "
                          "is not 64, a head that is not one 128-lane tile)",
-    "LM_KDA_PASS_FUSED": "delta layers' sequences whose gates and gated "
-                         "output norm ran as the Pallas passes of "
-                         "models/lm/delta_passes.py (delta.passes_fused)",
-    "LM_KDA_PASS_PLAIN": "delta layers' sequences whose gates and gated "
-                         "output norm took the jax.numpy chain (no TPU, a "
+    "LM_KDA_PASS_FUSED": "delta layers' sequences whose short "
+                         "convolutions, gates and gated output norm ran as "
+                         "the Pallas passes of models/lm/delta_passes.py "
+                         "(delta.passes_fused)",
+    "LM_KDA_PASS_PLAIN": "delta layers' sequences whose short "
+                         "convolutions, gates and gated output norm took "
+                         "the jax.numpy chain (no TPU, a "
                          "length that 512 does not divide, a head that is "
                          "not one 128-lane tile)",
     "LM_EMBED_ROWS": "distinct embedding rows a step named, summed",

@@ -5,7 +5,10 @@ CPU, against ``delta.gates`` and ``delta.output``'s gated norm, the
 definition: each of the four passes and ``jax.vjp`` of the chain at kimi's
 and solar's head counts and beta scales over several blocks of tokens, a
 zero row, ``attention_vjp`` whole with and without them, which form runs
-where, and what counts it."""
+where, and what counts it. The same for a short convolution alone
+(``mv_kda_conv`` and its pull against ``delta.short_conv`` and its
+``jax.vjp``: a block's first rows read the tile before it, its last rows'
+cotangent the tile after it, the weights' gradient a partial sum a block)."""
 
 import dataclasses
 import json
@@ -79,8 +82,14 @@ def _norm_chain(cfg, t=T):
 GATES = ("q", "k", "g", "beta")
 GATES_PULL = ("d_a_log", "d_dt_bias", "dq", "dk", "df", "db")
 NORM_PULL = ("d_norm_o", "do", "d_gate")
+CONV = ("conv_y", "conv_dx", "conv_dw")
 #: what leaves a pass in bfloat16
-IN_BF16 = {"df", "db", "d_gate", "y"}
+IN_BF16 = {"df", "db", "d_gate", "y", "conv_dx"}
+
+
+def _taps(heads, taps=4, seed=9):
+    return jnp.asarray(np.random.default_rng(seed).uniform(
+        -0.5, 0.5, (heads * D, taps)), F32)
 
 
 @pytest.fixture(scope="module")
@@ -110,6 +119,11 @@ def both_forms():
             out["y"] = (got, want)
             out.update(zip(NORM_PULL, zip(pull(x["dy"]),
                                           pull_chain(x["dy"]))))
+            ins = x["q"], _taps(heads)
+            got, pull = jax.vjp(lambda *a: delta_passes.conv(how, *a), *ins)
+            want, pull_chain = jax.vjp(delta.short_conv, *ins)
+            out.update(zip(CONV, zip((got, *pull(x["dq"])),
+                                     (want, *pull_chain(x["dq"])))))
         finally:
             delta_passes.INTERPRET = False
         made[model] = out
@@ -118,13 +132,14 @@ def both_forms():
     return of
 
 
-@pytest.mark.parametrize("result", GATES + GATES_PULL + ("y",) + NORM_PULL)
+@pytest.mark.parametrize("result",
+                         GATES + GATES_PULL + ("y",) + NORM_PULL + CONV)
 @pytest.mark.parametrize("model", list(MODELS))
 def test_a_pass_s_result_is_the_chain_s(model, result, both_forms):
     """Forward results and every cotangent, the small tensors' partial sums
-    (``d a_log``, ``d dt_bias``, ``d norm_o``) among them, over three
-    blocks of tokens: the float32 ones to the order of a head's sum, the
-    bfloat16 ones to their rounding."""
+    (``d a_log``, ``d dt_bias``, ``d norm_o``, a convolution's weights')
+    among them, over three blocks of tokens: the float32 ones to the order
+    of a head's sum, the bfloat16 ones to their rounding."""
     got, want = both_forms(model)[result]
     assert got.shape == want.shape and got.dtype == want.dtype == F32
     assert bool(jnp.all(jnp.isfinite(got)))
@@ -186,6 +201,71 @@ def test_a_convolution_of_any_taps_reads_the_rows_before_it(taps):
         assert _relative(mine, theirs) < SUMMED
 
 
+def _conv_by_hand(x, w, g):
+    """``short_conv``'s pull in float64: ``(dx, gu, the lagged inputs a
+    weight)`` for x, g [T, C] and w [C, n]."""
+    x, w, g = (np.asarray(a, np.float64) for a in (x, w, g))
+    t, n = x.shape[0], w.shape[1]
+    padded = np.concatenate([np.zeros((n - 1, x.shape[1])), x])
+    lagged = [padded[j:j + t] for j in range(n)]
+    u = sum(lag * w[:, j] for j, lag in enumerate(lagged))
+    s = 1 / (1 + np.exp(-u))
+    gu = g * s * (1 + u * (1 - s))
+    ahead = np.concatenate([gu, np.zeros((n - 1, x.shape[1]))])
+    dx = sum(ahead[n - 1 - j:n - 1 - j + t] * w[:, j] for j in range(n))
+    return dx, gu, lagged
+
+
+@pytest.mark.parametrize("taps", [2, 3, 4])
+@pytest.mark.parametrize("model", ["kimi", "solar"])
+def test_a_convolution_alone_reads_the_tiles_beside_its_block(model, taps):
+    """Three blocks of tokens at kimi's 32 heads and solar's 8: the rows at
+    a block's start read the tile before it (zeros before the sequence's
+    first position), the cotangent at a block's end reads ``g silu'(u)`` of
+    the tile after it (zeros past the sequence's end), and the weights'
+    gradient leaves as one [8, lanes] partial sum a weight a block, each
+    position counted in its own block."""
+    heads = MODELS[model][0]
+    x = _drawn(heads, taps)
+    w = _taps(heads, taps, taps)
+    how = delta_passes.Pass(heads, 1.0, EPS, taps)
+    y = delta_passes.conv(how, x["q"], w)
+    dx, dw, sums = delta_passes._conv_pull(how, x["q"], w, x["dq"], True)
+    tokens, blocks = delta_passes.TOKENS, T // delta_passes.TOKENS
+    assert sums.shape == (blocks, taps, 8, heads * D)
+    want_dx, gu, lagged = _conv_by_hand(x["q"], w, x["dq"])
+    edges = np.r_[0:8, tokens - 8:tokens + 8, 2 * tokens - 8:2 * tokens + 8,
+                  T - 8:T]
+    np.testing.assert_allclose(
+        y[edges], delta.short_conv(x["q"], w)[edges], rtol=2e-5, atol=1e-6)
+    assert bool(jnp.all(dx.astype(jnp.bfloat16).astype(F32) == dx))
+    np.testing.assert_allclose(dx[edges], want_dx[edges], rtol=1e-2,
+                               atol=1e-2)
+    assert _relative(dx, want_dx) < ROUNDED
+    by_block = np.stack([np.stack([
+        (gu * lagged[j])[b * tokens:(b + 1) * tokens].sum(0)
+        for j in range(taps)]) for b in range(blocks)])
+    assert _relative(np.asarray(sums, np.float64).sum(2), by_block) < SUMMED
+    assert _relative(dw, by_block.sum(0).T) < SUMMED
+
+
+def test_a_convolution_s_zero_rows_are_the_chain_s_answer_too():
+    """A position whose product is zero and one whose cotangent is: silu
+    of the rows before it alone, and nothing pulled from it."""
+    heads, t = 4, delta_passes.TOKENS
+    x, w = _drawn(heads, 2, t), _taps(heads)
+    q, g = x["q"].at[7].set(0.0), x["dq"].at[t - 1].set(0.0)
+    how = delta_passes.Pass(heads, 1.0, EPS)
+    got, pull = jax.vjp(lambda *a: delta_passes.conv(how, *a), q, w)
+    want, pull_chain = jax.vjp(delta.short_conv, q, w)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert _relative(got, want) < SUMMED
+    for mine, theirs, limit in zip(pull(g), pull_chain(g),
+                                   (ROUNDED, SUMMED)):
+        assert bool(jnp.all(jnp.isfinite(mine)))
+        assert _relative(mine, theirs) < limit
+
+
 @pytest.mark.parametrize("scale", [1, 2])
 def test_beta_is_the_scale_times_the_sigmoid(scale):
     heads = 4
@@ -236,6 +316,9 @@ def test_the_kernels_hold_no_derived_transpose():
     assert all(a is b for a, b in zip(kept, ins))
     ins = tuple(x[n] for n in ("norm_o", "o", "gate"))
     _, kept = delta_passes._gated_norm_fwd(how, *ins)
+    assert all(a is b for a, b in zip(kept, ins))
+    ins = x["q"], _taps(heads)
+    _, kept = delta_passes._conv_fwd(how, *ins)
     assert all(a is b for a, b in zip(kept, ins))
 
 
@@ -340,10 +423,10 @@ def test_beta_over_one_is_counted_from_the_pass_s_beta(sublayers):
 
 
 def test_the_sublayer_s_passes_are_the_kernels(monkeypatch):
-    """Forward: the gates with q's and k's convolutions in them, and the
-    gated norm; with the pull the same once more (for the scan's
-    transpose), the gates behind the convolutions' own pull (whose forward
-    nothing reads), their pull and the norm's."""
+    """Forward: the gates with q's and k's convolutions in them, v's
+    convolution, and the gated norm; with the pull the same once more (for
+    the scan's transpose), the three convolutions and behind them the gates
+    (whose forward nothing reads), their pulls and the norm's."""
     monkeypatch.setattr(delta, "passes_fused", lambda cfg, t: True)
     shapes = delta.shapes(CFG)
     mats = {n: jnp.zeros(shapes[n], jnp.bfloat16) for n in delta.MATRICES}
@@ -362,11 +445,12 @@ def test_the_sublayer_s_passes_are_the_kernels(monkeypatch):
     def calls(fn):
         """The jitted callers' calls (a jaxpr prints a shared body once)."""
         text = str(jax.make_jaxpr(fn)(x))
-        return [len(re.findall(rf"\bname={name}\n", text)) for name in (
-            "_gates", "_gates_pull", "_gated_norm", "_gated_norm_pull")]
+        return [len(re.findall(rf"\bname={name}\b", text)) for name in (
+            "_gates", "_gates_pull", "_gated_norm", "_gated_norm_pull",
+            "_conv", "_conv_pull")]
 
-    assert calls(forward) == [1, 0, 1, 0]
-    assert calls(pulled) == [3, 1, 1, 1]
+    assert calls(forward) == [1, 0, 1, 0, 1, 0]
+    assert calls(pulled) == [3, 1, 1, 1, 5, 3]
 
 
 # -- which form runs where, and the counters --------------------------------------------
@@ -423,7 +507,7 @@ def test_off_the_chip_the_chain_runs_and_no_kernel_is_traced(monkeypatch):
     def never(*args):
         raise AssertionError("a pass was taken on the CPU")
 
-    for name in ("gates", "conv_gates", "gated_norm"):
+    for name in ("gates", "conv_gates", "gated_norm", "conv"):
         monkeypatch.setattr(delta_passes, name, never)
     heads = CFG.kda_heads_held
     x = _drawn(heads, 4, SUBLAYER_T)
@@ -443,6 +527,8 @@ def test_off_the_chip_the_chain_runs_and_no_kernel_is_traced(monkeypatch):
         x["f"], x["b"])
     for mine, theirs in zip(through, want):
         assert np.array_equal(mine, theirs)
+    assert np.array_equal(delta.short_conv_flat(CFG, x["q"], convs[0]),
+                          delta.short_conv(x["q"], convs[0]))
     wo = jnp.eye(heads * D, dtype=jnp.bfloat16)
     sinks = {"wo": jnp.zeros(wo.shape, F32)}
     got = delta.output_flat(CFG, {"wo": wo}, sinks, x["norm_o"], x["o"],
@@ -450,6 +536,30 @@ def test_off_the_chip_the_chain_runs_and_no_kernel_is_traced(monkeypatch):
     want = delta.output(CFG, {"wo": wo}, sinks, x["norm_o"],
                         x["o"].reshape(SUBLAYER_T, heads, D), x["gate"])
     assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("why", ["short_conv_replaced", "a_part_block",
+                                 "heads_of_half_a_tile"])
+def test_a_convolution_that_does_not_fit_keeps_the_chain(why, monkeypatch):
+    """On a TPU too: a control's own ``short_conv`` is the one called, and
+    a sequence that is no whole number of blocks or a head that is no one
+    tile never reaches the kernel."""
+    def never(*args):
+        raise AssertionError("the kernel was taken")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(delta_passes, "conv", never)
+    t, d = {"short_conv_replaced": (512, D), "a_part_block": (96, D),
+            "heads_of_half_a_tile": (512, 64)}[why]
+    cfg = _cfg(2, 1, d)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(t, 2 * d)), F32)
+    w = _taps(2)[:2 * d]
+    want = delta.short_conv(x, w)
+    if why == "short_conv_replaced":
+        exact = delta.short_conv
+        monkeypatch.setattr(delta, "short_conv", lambda x, w: 2 * exact(x, w))
+        want = 2 * want
+    assert np.array_equal(delta.short_conv_flat(cfg, x, w), want)
 
 
 def _counted():
@@ -537,8 +647,8 @@ def test_the_bench_s_delta_line_holds_every_pass_to_the_chain(monkeypatch):
     wide = 4 * t * cfg.kda_heads_held * D
     assert {n: line["bytes"] / wide for n, line in out.items()} == {
         "conv_gates": 6, "gates": 6, "gates_pull": 8.5, "norm": 2.5,
-        "norm_pull": 4.5}
-    assert [len(out[n]["relative"]) for n in out] == [4, 4, 6, 1, 3]
+        "norm_pull": 4.5, "conv": 2, "conv_pull": 2.5}
+    assert [len(out[n]["relative"]) for n in out] == [4, 4, 6, 1, 3, 1, 2]
     for line in out.values():
         assert max(line["relative"]) < ROUNDED
         assert line["bound_share"] == pytest.approx(

@@ -751,10 +751,14 @@ def test_a_delta_sublayer_holds_its_heads_as_the_projections_leave_them(
     # the scan's three, and since PR 64 the passes around it: the gates
     # twice, q's and k's convolutions in them (the forward's; made again
     # for the scan's transpose: their own pull keeps its inputs alone and
-    # reads no third), their pull, the gated norm and its pull
-    assert text.count("tpu_custom_call") == 8
+    # reads no third), their pull, the gated norm and its pull; since PR 69
+    # v's convolution beside the gates (twice) and, where the convolutions
+    # are differentiated, q's and k's (v's feeds nothing there and is
+    # dropped) and the three pulls
+    assert text.count("tpu_custom_call") == 15
     assert text.count("mv_kda_scan_bwd") >= 1
-    for name in ("mv_kda_gates_pull", "mv_kda_out_pull"):
+    for name in ("mv_kda_gates_pull", "mv_kda_out_pull", "mv_kda_conv",
+                 "mv_kda_conv_pull"):
         assert name in text
     heads, d = cfg.kda_heads, cfg.kda_head_dim
     assert f"f32[{tokens},{heads * d}]" in text
@@ -773,18 +777,20 @@ DELTA_CELLS = {"kimi": (32, 1.0), "solar": (8, 2.0)}
 @pytest.mark.parametrize("which, kernel, results_bf16", [
     ("gates", "mv_kda_gates", 0), ("conv_gates", "mv_kda_gates", 0),
     ("gates_pull", "mv_kda_gates_pull", 1),
-    ("norm", "mv_kda_out", 1), ("norm_pull", "mv_kda_out_pull", 1)])
+    ("norm", "mv_kda_out", 1), ("norm_pull", "mv_kda_out_pull", 1),
+    ("conv", "mv_kda_conv", 0), ("conv_pull", "mv_kda_conv_pull", 1)])
 def test_a_delta_pass_compiles_at_a_cell_s_shapes(topo, cell, which, kernel,
                                                   results_bf16):
     """Each of the four passes (the gates' also with q's and k's
     convolutions of four weights in it: a roll along the rows, the tile
-    before a block as a second view) as Mosaic takes it at 8192 tokens of
-    32 and of 8 heads of 128 lanes: ONE kernel, and no float32 intermediate
-    beside
+    before a block as a second view) and a short convolution alone with its
+    pull (the tile AFTER a block as a third view) as Mosaic takes it at 8192
+    tokens of 32 and of 8 heads of 128 lanes: ONE kernel, and no float32
+    intermediate beside
     it: what the program holds besides its arguments and results is the
     bfloat16 array that a pass hands on widened (``mm`` rounds it back:
     the pair folds away in a layer program) and the small tensors' partial
-    sums a block of tokens."""
+    sums a block of tokens (a convolution's: one a weight)."""
     from multiverso_tpu.models.lm import delta_passes
     heads, scale = DELTA_CELLS[cell]
     tokens, lanes = 8192, heads * delta_passes.LANES
@@ -805,6 +811,9 @@ def test_a_delta_pass_compiles_at_a_cell_s_shapes(topo, cell, which, kernel,
     def norm(*a):
         return delta_passes.gated_norm(how, *a)
 
+    def conv(x, w):
+        return delta_passes.conv(how, x, w)
+
     taps = shaped(lanes, 4)
     program, args = {
         "gates": (gates, gates_in),
@@ -814,11 +823,15 @@ def test_a_delta_pass_compiles_at_a_cell_s_shapes(topo, cell, which, kernel,
                        gates_in + (wide, wide, wide, thin)),
         "norm": (norm, norm_in),
         "norm_pull": (lambda *a: jax.vjp(norm, *a[:3])[1](a[3]),
-                      norm_in + (wide,))}[which]
+                      norm_in + (wide,)),
+        "conv": (conv, (wide, taps)),
+        "conv_pull": (lambda x, w, g: jax.vjp(conv, x, w)[1](g),
+                      (wide, taps, wide))}[which]
     compiled = jax.jit(program).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and kernel in text
-    sums = 2 * (tokens // delta_passes.TOKENS) * 8 * lanes * 4
+    sums = (4 if which == "conv_pull" else 2) * (
+        tokens // delta_passes.TOKENS) * 8 * lanes * 4
     # beta's cotangent, 32 of 128 lanes wide, lies in whole tiles
     thin_bf16 = tokens * 128 * 2
     assert compiled.memory_analysis().temp_size_in_bytes <= (
@@ -826,20 +839,28 @@ def test_a_delta_pass_compiles_at_a_cell_s_shapes(topo, cell, which, kernel,
 
 
 #: ``backward_program``'s temporaries for a sparse delta layer at the parent
-#: of PR 64 (d4b688c), compiled for the same described v5e: bytes
+#: of PR 69 (6e79fbd: PR 64's passes in it, the convolutions XLA's),
+#: compiled for the same described v5e: bytes
 PARENT_BACKWARD_TEMPORARIES = {
-    "kimi-linear-48b-a3b-l5": 4_137_762_304,
-    "solar-open2-250b-a15b-l4": 3_002_636_288}
+    "kimi-linear-48b-a3b-l5": 4_082_397_184,
+    "solar-open2-250b-a15b-l4": 2_997_893_120}
+#: One bfloat16 [8192, 2304] array: with q's and k's pulls custom calls the
+#: compiler's memory-space assignment keeps one more such array in fast
+#: memory through kimi's experts and evicts a third to HBM there (the live
+#: set at the program's peak is the parent's and this copy). On the chip
+#: ``peak_hbm_gb`` FELL (12.1780 -> 12.1725, PERF.md section 6, PR 69).
+EVICTED = {"kimi-linear-48b-a3b-l5": 8192 * 2304 * 2 + (1 << 16)}
 
 
 @pytest.mark.parametrize("config", list(PARENT_BACKWARD_TEMPORARIES))
 def test_a_delta_layer_s_backward_program_is_no_larger_with_the_passes(
         topo, config, monkeypatch):
     """A sparse delta layer's backward program at the cell's sizes (two
-    sequences of 8192), the passes in it: its temporaries are no more than
-    the chain's were (the rules keep their inputs alone, which
-    ``attention_vjp`` holds anyway, and no float32 intermediate lies
-    between a pass and its consumer)."""
+    sequences of 8192), the passes and the convolutions' kernels in it: its
+    temporaries are no more than they were with the convolutions XLA's
+    (the rules keep their inputs alone, which ``attention_vjp`` holds
+    anyway, no slope is kept, and no float32 intermediate lies between a
+    pass and its consumer), but for ``EVICTED``."""
     from multiverso_tpu.models.lm import delta, model as lm, ps_train
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
@@ -865,7 +886,8 @@ def test_a_delta_layer_s_backward_program_is_no_larger_with_the_passes(
         mats, small, x, x).compile()
     text = compiled.as_text()
     for name in ("mv_kda_gates", "mv_kda_gates_pull", "mv_kda_out",
-                 "mv_kda_out_pull", "mv_kda_scan_bwd"):
+                 "mv_kda_out_pull", "mv_kda_scan_bwd", "mv_kda_conv",
+                 "mv_kda_conv_pull"):
         assert name in text
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= PARENT_BACKWARD_TEMPORARIES[config]
+        <= PARENT_BACKWARD_TEMPORARIES[config] + EVICTED.get(config, 0)

@@ -246,7 +246,8 @@ def _latent(cfg, described, t, rng):
 
 def _delta_passes_alone(cfg, t, rng, peak):
     """The kernels of ``delta_passes.py`` (the gates' once more with q's
-    and k's convolutions in it) and the ``jax.numpy`` chain
+    and k's convolutions in it; a short convolution alone and its pull,
+    whose loops the weights carry) and the ``jax.numpy`` chain
     they stand for, on drawn products and cotangents: milliseconds inside a
     program, the bytes a pass moves, the share of the memory bound it
     reaches (``peak``: its bytes a second), each result's distance from the
@@ -313,6 +314,13 @@ def _delta_passes_alone(cfg, t, rng, peak):
         return gates_chain(a_log, dt_bias, delta.short_conv(q, wq),
                            delta.short_conv(k, wk), f, b)
 
+    def alone_fused(x, w):
+        return passes.conv(how, x, w)
+
+    def conv_pull_chain(g, ops):
+        dx, dw = pull(delta.short_conv, 2)(*ops, g)
+        return dx + 0.0 * jnp.sum(dw, 1)
+
     forms = {
         "conv_gates": (
             conv_fused, conv_chain, taps + ins, 6,
@@ -339,7 +347,19 @@ def _delta_passes_alone(cfg, t, rng, peak):
             pull(norm_fused, 3), pull(norm_chain, 3), norm_ins + (dy,), 4.5,
             (lambda n, ops: n + 0.0 * pull(norm_fused, 3)(n, *ops)[0],
              norm_o, norm_ins[1:] + (dy,)),
-            (norm_pull_chain, dy, norm_ins))}
+            (norm_pull_chain, dy, norm_ins)),
+        "conv": (
+            alone_fused, delta.short_conv, (q, taps[0]), 2,
+            (lambda w, ops: w + 0.0 * alone_fused(ops[0], w)[:w.shape[1]].T,
+             taps[0], (q,)),
+            (lambda x, ops: delta.short_conv(x, ops[0]), q, taps[:1])),
+        "conv_pull": (
+            pull(alone_fused, 2), pull(delta.short_conv, 2),
+            (q, taps[0], dy), 2.5,
+            (lambda w, ops: w + 0.0 * pull(alone_fused, 2)(ops[0], w,
+                                                           ops[1])[1],
+             taps[0], (q, dy)),
+            (conv_pull_chain, dy, (q, taps[0])))}
     wide, out = 4 * t * heads * d, {}
     for name, (fused, chain, args, arrays, own, chains) in forms.items():
         got, want = jax.jit(fused)(*args), jax.jit(chain)(*args)
